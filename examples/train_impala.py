@@ -35,8 +35,11 @@ from scalerl_tpu.config import ImpalaArguments, parse_args
 from scalerl_tpu.envs import make_jax_vec_env, make_vect_envs
 
 
-def main() -> None:
-    args = parse_args(ImpalaArguments)
+def main(argv=None):
+    """Train from ``argv`` (default ``sys.argv[1:]``); returns
+    ``(trainer, final metrics)`` so a caller — ``chip_smoke.py`` — can check
+    the run it just drove."""
+    args = parse_args(ImpalaArguments, argv)
     from scalerl_tpu.utils.platform import setup_platform
 
     print("backend:", setup_platform(args.platform))
@@ -122,6 +125,7 @@ def main() -> None:
             print("checkpoint:", path)
     finally:
         trainer.close()
+    return trainer, result
 
 
 if __name__ == "__main__":

@@ -51,8 +51,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from scalerl_tpu.config import GenRLArguments, parse_args
 
 
-def main() -> None:
-    args = parse_args(GenRLArguments)
+def main(argv=None):
+    """Train from ``argv`` (default ``sys.argv[1:]``); returns
+    ``(trainer, final metrics)`` so a caller — ``chip_smoke.py`` — can check
+    the run it just drove."""
+    args = parse_args(GenRLArguments, argv)
     from scalerl_tpu.utils.platform import setup_platform
 
     print("backend:", setup_platform(args.platform))
@@ -67,6 +70,7 @@ def main() -> None:
             os.path.join(args.work_dir, "genrl_ckpt_final")
         )
         print("checkpoint:", path)
+    return trainer, result
 
 
 if __name__ == "__main__":

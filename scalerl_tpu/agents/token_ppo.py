@@ -405,6 +405,15 @@ class TokenPPOAgent:
 
         mesh = resolve_mesh(mesh_or_spec)
         param_specs = None
+        meshed = {}  # model fields that depend on the mesh
+        if self.model.segment_attn_fn is not None:
+            # the packed-row kernel is a Mosaic call, which GSPMD cannot
+            # partition: run it per (dp rows, mp heads) shard
+            from scalerl_tpu.ops.pallas_attention import shard_segment_attn
+
+            meshed["segment_attn_fn"] = shard_segment_attn(
+                self.model.segment_attn_fn, mesh
+            )
         if mesh.shape.get("mp", 1) > 1:
             if not has_mp_params(self.state.params):
                 raise ValueError(
@@ -412,11 +421,11 @@ class TokenPPOAgent:
                     "model-parallel shardable params"
                 )
             if self.model.constrain is None:
-                self.model = self.model.clone(
-                    constrain=activation_constraint(mesh)
-                )
-                self._learn_fn = self.make_learn_fn()
+                meshed["constrain"] = activation_constraint(mesh)
             param_specs = mp_param_sharding(self.state, mesh)
+        if meshed:
+            self.model = self.model.clone(**meshed)
+            self._learn_fn = self.make_learn_fn()
         plearn = make_parallel_learn_fn(
             self._learn_fn, mesh, self.state,
             batch_example=batch_example,
@@ -435,6 +444,13 @@ class TokenPPOAgent:
             batch = self._shard_batch(batch)
         self.state, metrics = self._learn(self.state, batch)  # graftlint: disable=JG002 (single-threaded learner loop; genrl has no actor threads)
         return metrics
+
+    def lower_learn(self, batch):
+        """Lower the learn step for ``batch`` against the live state —
+        nothing runs and nothing is donated."""
+        if self._shard_batch is not None:
+            batch = self._shard_batch(batch)
+        return self._learn.lower(self.state, batch)
 
     def learn(self, batch) -> Dict[str, float]:
         from scalerl_tpu.runtime.dispatch import get_metrics

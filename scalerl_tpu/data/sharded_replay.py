@@ -37,7 +37,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -278,7 +278,7 @@ class ShardedPrioritizedReplay:
             mesh=mesh,
             in_specs=(self._state_spec, P(), P()),
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(fn)
 
@@ -459,7 +459,7 @@ class ShardedSequenceReplay:
             mesh=self.mesh,
             in_specs=(self._state_spec, P()),
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(fn)
 

@@ -283,11 +283,18 @@ class ContinuousEngine(ParamSnapshotPlane):
         self.model = model
         self.iter_mode = resolve_iter_mode(iter_mode)
         self._dispatch_guard = dispatch_guard or nullcontext
+        # generation is single-chip: pools, lane state and the param
+        # snapshots all live on this one device, whatever mesh the learner
+        # shards its own copy over (_place re-places every push)
+        self._device = jax.devices()[0]
         self._paged_attn = make_paged_attn_fn(config.paged_attn)
         if model.paged_attn_fn is None:
             # route the model's paged decode reads through the resolved impl
             # (clone shares the param structure: same names, same shapes)
             self.model = model.clone(paged_attn_fn=self._paged_attn)
+        if self.model.constrain is not None:
+            # a meshed learner's model pins activations to ITS mesh
+            self.model = self.model.clone(constrain=None)
         self._init_param_plane(params)
         L = config.lanes
         ps = config.page_size
@@ -1200,6 +1207,29 @@ class ContinuousEngine(ParamSnapshotPlane):
             )
 
         return jax.jit(verify, donate_argnums=(1, 2, 3, 4, 5, 6))
+
+    def _place(self, snapshot: Any) -> Any:
+        """The train-layout -> infer-layout step of a param push: gather a
+        (possibly dp×mp-sharded) learner tree onto the engine's device.  A
+        sharded tree would make the decode program SPMD, and its Mosaic
+        paged-attention kernel cannot be partitioned."""
+        return jax.device_put(snapshot, self._device)
+
+    def lower_decode(self):
+        """Lower the decode macro-step against the engine's live state —
+        nothing runs and nothing is donated.  The returned
+        ``jax.stages.Lowered`` is how a caller reads what the program
+        actually contains (``chip_smoke.py`` looks for the Mosaic custom
+        call of the paged-attention kernel in its text)."""
+        params, _gen = self._snapshot_params()
+        traces = self._decode_traces
+        lowered = self._decode_fn.lower(
+            params, self._pools, self._logits_st, self._value_st,
+            self._cl, self._done, self._resp, self._table, self._key,
+        )
+        # lowering re-traces the body; the counter pins DISPATCH retraces
+        self._decode_traces = traces
+        return lowered
 
     # -- param plane -----------------------------------------------------
     def push_params(

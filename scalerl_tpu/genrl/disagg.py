@@ -492,6 +492,10 @@ class ScriptedSequenceEngine:
 class ScriptedEngineFactory:
     """Picklable factory for spawn-mode fleets (the soak's engine)."""
 
+    # its engines are numpy-only: a host process built from this factory
+    # never opens an accelerator (LocalGenerationFleet.start reads this)
+    jax_free = True
+
     def __init__(
         self,
         lanes: int = 4,
@@ -1965,8 +1969,29 @@ class LocalGenerationFleet:
         if not self.use_threads:
             import multiprocessing as mp
 
-            from scalerl_tpu.utils.platform import safe_mp_context
+            from scalerl_tpu.utils.platform import (
+                jax_runtime_initialized,
+                safe_mp_context,
+            )
 
+            if jax_runtime_initialized() and not getattr(
+                self.engine_factory, "jax_free", False
+            ):
+                import jax
+
+                if jax.default_backend() == "tpu":
+                    # one process per chip: this process holds the TPU, so
+                    # a spawned host building jitted engines would fail or
+                    # hang opening it.  Hosts are not placed on chips of
+                    # their own here — run them as threads instead.
+                    raise RuntimeError(
+                        "process generation hosts with a jax engine factory "
+                        "cannot start from a process that holds the TPU: a "
+                        "chip belongs to one process at a time.  Use "
+                        "use_threads=True (one process drives the chip), or "
+                        "start the fleet from a parent that never "
+                        "initializes jax."
+                    )
             self._ctx = mp.get_context(safe_mp_context(self.mp_context))
         for _ in range(self.config.num_hosts):
             self._spawn(self._assign_host_id())

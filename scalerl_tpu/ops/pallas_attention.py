@@ -90,9 +90,9 @@ def _fwd_kernel(
 
     @pl.when(live)
     def _attend():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale  # [bq, D]
-        k_blk = k_ref[0, :, 0, :].astype(jnp.float32)  # [bk, D]
-        v_blk = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32) * scale  # [bq, D]
+        k_blk = k_ref[...].astype(jnp.float32)  # [bk, D]
+        v_blk = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [bq, bk]
@@ -114,18 +114,38 @@ def _fwd_kernel(
     def _finish():
         l = l_sc[:]
         m = m_sc[:]
-        o_ref[0, :, 0, :] = (acc_sc[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        lse = jnp.where(
-            l[:, 0] > 0.0, m[:, 0] + jnp.log(jnp.maximum(l[:, 0], 1e-30)), _NEG_INF
+        o_ref[...] = (acc_sc[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        lse_ref[...] = jnp.where(
+            l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)), _NEG_INF
         )
-        lse_ref[0, 0, :] = lse
 
 
-def _pad_t(x: jnp.ndarray, t_pad: int) -> jnp.ndarray:
-    T = x.shape[1]
+# Mosaic tiles the LAST TWO dims of every block, and each must be a multiple
+# of the (8, 128) vreg tile or span its whole axis.  The public [B, T, H, D]
+# layout would leave the head axis second-to-last at block size 1, so the
+# wrappers move heads forward ([B, H, T, D], blocks of [rows, D]) and every
+# per-row vector (lse, delta, q-side segment ids) rides as a [.., T, 1]
+# column, never as a lane vector that the kernel would have to relayout.
+def _heads_first(x: jnp.ndarray, t_pad: int) -> jnp.ndarray:
+    """``[B, T, H, D]`` -> ``[B, H, t_pad, D]`` (zero tail)."""
+    x = jnp.swapaxes(x, 1, 2)
+    T = x.shape[2]
     if T == t_pad:
         return x
-    return jnp.pad(x, ((0, 0), (0, t_pad - T), (0, 0), (0, 0)))
+    return jnp.pad(x, ((0, 0), (0, 0), (0, t_pad - T), (0, 0)))
+
+
+def _heads_last(x: jnp.ndarray, T: int) -> jnp.ndarray:
+    """``[B, H, t_pad, D]`` -> ``[B, T, H, D]``."""
+    return jnp.swapaxes(x[:, :, :T], 1, 2)
+
+
+def _tile(rows: int, width: int, axis: int) -> pl.BlockSpec:
+    """``[rows, width]`` block of a head-major ``[B, H, T, width]`` operand
+    whose T axis is walked by grid axis ``axis``."""
+    return pl.BlockSpec(
+        (None, None, rows, width), lambda *g: (g[0], g[1], g[axis], 0)
+    )
 
 
 def _blocks(Tq: int, Tk: int, block_q: int, block_k: int):
@@ -142,7 +162,8 @@ def _fwd(
     Tk = k.shape[1]
     bq, bk, Tq_p, Tk_p = _blocks(Tq, Tk, block_q, block_k)
     nq, nk = Tq_p // bq, Tk_p // bk
-    qp, kp, vp = _pad_t(q, Tq_p), _pad_t(k, Tk_p), _pad_t(v, Tk_p)
+    qh = _heads_first(q, Tq_p)
+    kh, vh = _heads_first(k, Tk_p), _heads_first(v, Tk_p)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, q_len=Tq, k_len=Tk,
@@ -151,18 +172,11 @@ def _fwd(
     o, lse = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
-        ],
+        in_specs=[_tile(bq, D, 2), _tile(bk, D, 3), _tile(bk, D, 3)],
+        out_specs=[_tile(bq, D, 2), _tile(bq, 1, 2)],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Tq_p, H, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Tq_p), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Tq_p, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Tq_p, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),
@@ -170,8 +184,8 @@ def _fwd(
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(qp, kp, vp)
-    return o[:, :Tq], lse
+    )(qh, kh, vh)
+    return _heads_last(o, Tq), lse
 
 
 # ----------------------------------------------------------------------
@@ -192,13 +206,13 @@ def _bwd_dq_kernel(
 
     @pl.when(live)
     def _accumulate():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :][:, None]
-        delta = delta_ref[0, 0, :][:, None]
+        q = q_ref[...].astype(jnp.float32) * scale
+        do = do_ref[...].astype(jnp.float32)
+        lse = lse_ref[...]
+        delta = delta_ref[...]
         safe_lse = jnp.where(jnp.isneginf(lse), 0.0, lse)
-        k_blk = k_ref[0, :, 0, :].astype(jnp.float32)
-        v_blk = v_ref[0, :, 0, :].astype(jnp.float32)
+        k_blk = k_ref[...].astype(jnp.float32)
+        v_blk = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -214,7 +228,7 @@ def _bwd_dq_kernel(
 
     @pl.when(j == nk - 1)
     def _finish():
-        dq_ref[0, :, 0, :] = (dq_sc[:] * scale).astype(dq_ref.dtype)
+        dq_ref[...] = (dq_sc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(
@@ -234,12 +248,12 @@ def _bwd_dkv_kernel(
 
     @pl.when(live)
     def _accumulate():
-        k_blk = k_ref[0, :, 0, :].astype(jnp.float32)  # [bk, D]
-        v_blk = v_ref[0, :, 0, :].astype(jnp.float32)
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale  # [bq, D]
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :][:, None]
-        delta = delta_ref[0, 0, :][:, None]
+        k_blk = k_ref[...].astype(jnp.float32)  # [bk, D]
+        v_blk = v_ref[...].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32) * scale  # [bq, D]
+        do = do_ref[...].astype(jnp.float32)
+        lse = lse_ref[...]
+        delta = delta_ref[...]
         safe_lse = jnp.where(jnp.isneginf(lse), 0.0, lse)
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -261,22 +275,22 @@ def _bwd_dkv_kernel(
 
     @pl.when(i == nq - 1)
     def _finish():
-        dk_ref[0, :, 0, :] = dk_sc[:].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_sc[:].astype(dv_ref.dtype)
+        dk_ref[...] = dk_sc[:].astype(dk_ref.dtype)
+        dv_ref[...] = dv_sc[:].astype(dv_ref.dtype)
 
 
 def _bwd(causal, scale, block_q, block_k, interpret, residuals, g):
-    q, k, v, o, lse = residuals
+    q, k, v, o, lse = residuals  # lse: [B, H, Tq_p, 1] as the forward wrote it
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     bq, bk, Tq_p, Tk_p = _blocks(Tq, Tk, block_q, block_k)
     nq, nk = Tq_p // bq, Tk_p // bk
-    qp, kp, vp = _pad_t(q, Tq_p), _pad_t(k, Tk_p), _pad_t(v, Tk_p)
-    dop, op = _pad_t(g, Tq_p), _pad_t(o, Tq_p)
-    lse_p = jnp.pad(lse, ((0, 0), (0, 0), (0, Tq_p - Tq)))
+    qh = _heads_first(q, Tq_p)
+    kh, vh = _heads_first(k, Tk_p), _heads_first(v, Tk_p)
+    doh, oh = _heads_first(g, Tq_p), _heads_first(o, Tq_p)
     # delta_i = rowsum(dO_i * O_i) — the softmax-jacobian correction term
-    delta = jnp.einsum(
-        "bqhd,bqhd->bhq", dop.astype(jnp.float32), op.astype(jnp.float32)
+    delta = jnp.sum(
+        doh.astype(jnp.float32) * oh.astype(jnp.float32), axis=-1, keepdims=True
     )
 
     dq_kernel = functools.partial(
@@ -287,49 +301,39 @@ def _bwd(causal, scale, block_q, block_k, interpret, residuals, g):
         dq_kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            _tile(bq, D, 2), _tile(bk, D, 3), _tile(bk, D, 3),
+            _tile(bq, D, 2), _tile(bq, 1, 2), _tile(bq, 1, 2),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Tq_p, H, D), q.dtype),
+        out_specs=_tile(bq, D, 2),
+        out_shape=jax.ShapeDtypeStruct((B, H, Tq_p, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
-    )(qp, kp, vp, dop, lse_p, delta)
+    )(qh, kh, vh, doh, lse, delta)
 
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal, q_len=Tq, k_len=Tk,
         block_q=bq, block_k=bk, nq=nq,
     )
+    # k blocks outermost here: grid axis 2 walks k, axis 3 walks q
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(B, H, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, j, i: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, j, i: (b, j, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, j, i: (b, j, h, 0)),
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, j, i: (b, i, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i)),
+            _tile(bq, D, 3), _tile(bk, D, 2), _tile(bk, D, 2),
+            _tile(bq, D, 3), _tile(bq, 1, 3), _tile(bq, 1, 3),
         ],
-        out_specs=[
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, j, i: (b, j, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, j, i: (b, j, h, 0)),
-        ],
+        out_specs=[_tile(bk, D, 2), _tile(bk, D, 2)],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Tk_p, H, D), k.dtype),
-            jax.ShapeDtypeStruct((B, Tk_p, H, D), v.dtype),
+            jax.ShapeDtypeStruct((B, H, Tk_p, D), k.dtype),
+            jax.ShapeDtypeStruct((B, H, Tk_p, D), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
         interpret=interpret,
-    )(qp, kp, vp, dop, lse_p, delta)
-    return dq[:, :Tq], dk[:, :Tk], dv[:, :Tk]
+    )(qh, kh, vh, doh, lse, delta)
+    return _heads_last(dq, Tq), _heads_last(dk, Tk), _heads_last(dv, Tk)
 
 
 # ----------------------------------------------------------------------
@@ -420,7 +424,7 @@ def _seg_mask_block(
 ):
     """[bq, bk] validity: in-bounds, causal, same nonzero segment."""
     mask = _mask_block(i, j, q_len, q_len, block_q, block_k, causal=True)
-    return mask & (q_seg[:, None] == k_seg[None, :]) & (q_seg[:, None] > 0)
+    return mask & (q_seg == k_seg) & (q_seg > 0)
 
 
 def _seg_fwd_kernel(
@@ -437,15 +441,15 @@ def _seg_fwd_kernel(
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    q_seg = qseg_ref[0, :]
-    k_seg = kseg_ref[0, :]
+    q_seg = qseg_ref[...]  # [bq, 1]
+    k_seg = kseg_ref[...]  # [1, bk]
     live = _seg_block_live(i, j, q_seg, k_seg, block_q, block_k)
 
     @pl.when(live)
     def _attend():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-        k_blk = k_ref[0, :, 0, :].astype(jnp.float32)
-        v_blk = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32) * scale
+        k_blk = k_ref[...].astype(jnp.float32)
+        v_blk = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -471,31 +475,38 @@ def _seg_fwd_kernel(
         m = m_sc[:]
         # fully-masked rows (pad queries) emit exact zeros, matching the
         # reference — their outputs are unused but must stay finite
-        o_ref[0, :, 0, :] = (
+        o_ref[...] = (
             acc_sc[:] / jnp.maximum(l, 1e-30)
         ).astype(o_ref.dtype)
-        lse = jnp.where(
-            l[:, 0] > 0.0,
-            m[:, 0] + jnp.log(jnp.maximum(l[:, 0], 1e-30)),
-            _NEG_INF,
+        lse_ref[...] = jnp.where(
+            l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)), _NEG_INF
         )
-        lse_ref[0, 0, :] = lse
 
 
-def _pad_seg(seg: jnp.ndarray, t_pad: int) -> jnp.ndarray:
+def _seg_operands(seg: jnp.ndarray, t_pad: int):
+    """Segment ids as a q-side ``[B, t_pad, 1]`` column and a k-side
+    ``[B, 1, t_pad]`` row, so the kernels compare them by broadcast."""
+    seg = seg.astype(jnp.int32)
     T = seg.shape[1]
-    if T == t_pad:
-        return seg
-    # pad tail rides segment id 0 -> masked everywhere by construction
-    return jnp.pad(seg, ((0, 0), (0, t_pad - T)))
+    if T != t_pad:
+        # pad tail rides segment id 0 -> masked everywhere by construction
+        seg = jnp.pad(seg, ((0, 0), (0, t_pad - T)))
+    return seg[:, :, None], seg[:, None, :]
+
+
+def _seg_specs(bq: int, bk: int, q_axis: int, k_axis: int):
+    return [
+        pl.BlockSpec((None, bq, 1), lambda *g: (g[0], g[q_axis], 0)),
+        pl.BlockSpec((None, 1, bk), lambda *g: (g[0], 0, g[k_axis])),
+    ]
 
 
 def _seg_fwd(q, k, v, seg, scale, block_q, block_k, interpret):
     B, T, H, D = q.shape
     bq, bk, T_p, _ = _blocks(T, T, block_q, block_k)
     nq, nk = T_p // bq, T_p // bk
-    qp, kp, vp = _pad_t(q, T_p), _pad_t(k, T_p), _pad_t(v, T_p)
-    segp = _pad_seg(seg.astype(jnp.int32), T_p)
+    qh, kh, vh = (_heads_first(x, T_p) for x in (q, k, v))
+    qseg, kseg = _seg_operands(seg, T_p)
 
     kernel = functools.partial(
         _seg_fwd_kernel, scale=scale, q_len=T,
@@ -505,19 +516,13 @@ def _seg_fwd(q, k, v, seg, scale, block_q, block_k, interpret):
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bq), lambda b, h, i, j: (b, i)),
-            pl.BlockSpec((1, bk), lambda b, h, i, j: (b, j)),
+            _tile(bq, D, 2), _tile(bk, D, 3), _tile(bk, D, 3),
+            *_seg_specs(bq, bk, 2, 3),
         ],
-        out_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
-        ],
+        out_specs=[_tile(bq, D, 2), _tile(bq, 1, 2)],
         out_shape=[
-            jax.ShapeDtypeStruct((B, T_p, H, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, T_p), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, T_p, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, T_p, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),
@@ -525,8 +530,8 @@ def _seg_fwd(q, k, v, seg, scale, block_q, block_k, interpret):
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(qp, kp, vp, segp, segp)
-    return o[:, :T], lse
+    )(qh, kh, vh, qseg, kseg)
+    return _heads_last(o, T), lse
 
 
 def _seg_bwd_dq_kernel(
@@ -541,19 +546,19 @@ def _seg_bwd_dq_kernel(
     def _init():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
-    q_seg = qseg_ref[0, :]
-    k_seg = kseg_ref[0, :]
+    q_seg = qseg_ref[...]  # [bq, 1]
+    k_seg = kseg_ref[...]  # [1, bk]
     live = _seg_block_live(i, j, q_seg, k_seg, block_q, block_k)
 
     @pl.when(live)
     def _accumulate():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :][:, None]
-        delta = delta_ref[0, 0, :][:, None]
+        q = q_ref[...].astype(jnp.float32) * scale
+        do = do_ref[...].astype(jnp.float32)
+        lse = lse_ref[...]
+        delta = delta_ref[...]
         safe_lse = jnp.where(jnp.isneginf(lse), 0.0, lse)
-        k_blk = k_ref[0, :, 0, :].astype(jnp.float32)
-        v_blk = v_ref[0, :, 0, :].astype(jnp.float32)
+        k_blk = k_ref[...].astype(jnp.float32)
+        v_blk = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -572,7 +577,7 @@ def _seg_bwd_dq_kernel(
 
     @pl.when(j == nk - 1)
     def _finish():
-        dq_ref[0, :, 0, :] = (dq_sc[:] * scale).astype(dq_ref.dtype)
+        dq_ref[...] = (dq_sc[:] * scale).astype(dq_ref.dtype)
 
 
 def _seg_bwd_dkv_kernel(
@@ -588,18 +593,18 @@ def _seg_bwd_dkv_kernel(
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
-    q_seg = qseg_ref[0, :]
-    k_seg = kseg_ref[0, :]
+    q_seg = qseg_ref[...]  # [bq, 1]
+    k_seg = kseg_ref[...]  # [1, bk]
     live = _seg_block_live(i, j, q_seg, k_seg, block_q, block_k)
 
     @pl.when(live)
     def _accumulate():
-        k_blk = k_ref[0, :, 0, :].astype(jnp.float32)
-        v_blk = v_ref[0, :, 0, :].astype(jnp.float32)
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :][:, None]
-        delta = delta_ref[0, 0, :][:, None]
+        k_blk = k_ref[...].astype(jnp.float32)
+        v_blk = v_ref[...].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32) * scale
+        do = do_ref[...].astype(jnp.float32)
+        lse = lse_ref[...]
+        delta = delta_ref[...]
         safe_lse = jnp.where(jnp.isneginf(lse), 0.0, lse)
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
@@ -625,21 +630,19 @@ def _seg_bwd_dkv_kernel(
 
     @pl.when(i == nq - 1)
     def _finish():
-        dk_ref[0, :, 0, :] = dk_sc[:].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_sc[:].astype(dv_ref.dtype)
+        dk_ref[...] = dk_sc[:].astype(dk_ref.dtype)
+        dv_ref[...] = dv_sc[:].astype(dv_ref.dtype)
 
 
 def _seg_bwd(scale, block_q, block_k, interpret, residuals, g):
-    q, k, v, seg, o, lse = residuals
+    q, k, v, seg, o, lse = residuals  # lse: [B, H, T_p, 1]
     B, T, H, D = q.shape
     bq, bk, T_p, _ = _blocks(T, T, block_q, block_k)
     nq, nk = T_p // bq, T_p // bk
-    qp, kp, vp = _pad_t(q, T_p), _pad_t(k, T_p), _pad_t(v, T_p)
-    segp = _pad_seg(seg.astype(jnp.int32), T_p)
-    dop, op = _pad_t(g, T_p), _pad_t(o, T_p)
-    lse_p = jnp.pad(lse, ((0, 0), (0, 0), (0, T_p - T)))
-    delta = jnp.einsum(
-        "bqhd,bqhd->bhq", dop.astype(jnp.float32), op.astype(jnp.float32)
+    qh, kh, vh, doh, oh = (_heads_first(x, T_p) for x in (q, k, v, g, o))
+    qseg, kseg = _seg_operands(seg, T_p)
+    delta = jnp.sum(
+        doh.astype(jnp.float32) * oh.astype(jnp.float32), axis=-1, keepdims=True
     )
 
     dq_kernel = functools.partial(
@@ -650,55 +653,41 @@ def _seg_bwd(scale, block_q, block_k, interpret, residuals, g):
         dq_kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bq), lambda b, h, i, j: (b, i)),
-            pl.BlockSpec((1, bk), lambda b, h, i, j: (b, j)),
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            _tile(bq, D, 2), _tile(bk, D, 3), _tile(bk, D, 3),
+            *_seg_specs(bq, bk, 2, 3),
+            _tile(bq, D, 2), _tile(bq, 1, 2), _tile(bq, 1, 2),
         ],
-        out_specs=pl.BlockSpec(
-            (1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, T_p, H, D), q.dtype),
+        out_specs=_tile(bq, D, 2),
+        out_shape=jax.ShapeDtypeStruct((B, H, T_p, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
-    )(qp, kp, vp, segp, segp, dop, lse_p, delta)
+    )(qh, kh, vh, qseg, kseg, doh, lse, delta)
 
     dkv_kernel = functools.partial(
         _seg_bwd_dkv_kernel, scale=scale, q_len=T,
         block_q=bq, block_k=bk, nq=nq,
     )
+    # k blocks outermost here: grid axis 2 walks k, axis 3 walks q
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(B, H, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, j, i: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, j, i: (b, j, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, j, i: (b, j, h, 0)),
-            pl.BlockSpec((1, bq), lambda b, h, j, i: (b, i)),
-            pl.BlockSpec((1, bk), lambda b, h, j, i: (b, j)),
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, j, i: (b, i, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i)),
+            _tile(bq, D, 3), _tile(bk, D, 2), _tile(bk, D, 2),
+            *_seg_specs(bq, bk, 3, 2),
+            _tile(bq, D, 3), _tile(bq, 1, 3), _tile(bq, 1, 3),
         ],
-        out_specs=[
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, j, i: (b, j, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, j, i: (b, j, h, 0)),
-        ],
+        out_specs=[_tile(bk, D, 2), _tile(bk, D, 2)],
         out_shape=[
-            jax.ShapeDtypeStruct((B, T_p, H, D), k.dtype),
-            jax.ShapeDtypeStruct((B, T_p, H, D), v.dtype),
+            jax.ShapeDtypeStruct((B, H, T_p, D), k.dtype),
+            jax.ShapeDtypeStruct((B, H, T_p, D), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
         interpret=interpret,
-    )(qp, kp, vp, segp, segp, dop, lse_p, delta)
-    return dq[:, :T], dk[:, :T], dv[:, :T]
+    )(qh, kh, vh, qseg, kseg, doh, lse, delta)
+    return _heads_last(dq, T), _heads_last(dk, T), _heads_last(dv, T)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -805,6 +794,29 @@ def resolve_segment_attn(impl: str = "auto") -> str:
             f"{impl!r}"
         )
     return impl
+
+
+def shard_segment_attn(attn_fn: Callable, mesh) -> Callable:
+    """``attn_fn(q, k, v, segment_ids)`` run per shard of a dp×mp learner
+    mesh: rows over the data axes, heads over the model axis.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map"), so a
+    sharded learn step must place the kernel itself.  Attention is
+    independent per (row, head) — the layout the logical rules already give
+    q/k/v — so the shards need no collective.  The row count must divide
+    by the data axes and the head count by ``mp``.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    qkv = P(("dp", "fsdp"), None, "mp", None)
+    return jax.shard_map(
+        attn_fn,
+        mesh=mesh,
+        in_specs=(qkv, qkv, qkv, P(("dp", "fsdp"), None)),
+        out_specs=qkv,
+        check_vma=False,
+    )
 
 
 def make_segment_attn_fn(impl: str = "auto") -> Optional[Callable]:

@@ -12,13 +12,14 @@ each lane's context through its table instead of slicing a dense
   f32 softmax.  The parity oracle and the CPU-backend default (Pallas
   interpret mode would re-interpret the kernel per decode sub-step).
 - :func:`paged_decode_attention` — the Pallas kernel: grid
-  ``(B, H, num_pages_per_lane)`` with the page table and lengths as
-  *scalar-prefetch* operands, so each kv step's ``BlockSpec`` index map
-  reads ``page_table[b, j]`` and DMAs exactly that page from the pool into
-  VMEM — HBM traffic is O(live tokens), never O(pool).  Online softmax
-  with float32 accumulators in VMEM scratch persisting across the
-  (innermost, sequential) page dimension; pages past a lane's length are
-  skipped entirely via ``pl.when``.  Interpret mode off-TPU; Mosaic on TPU.
+  ``(B, num_pages_per_lane)``, all heads per step, with the page table and
+  lengths as *scalar-prefetch* operands, so each kv step's ``BlockSpec``
+  index map reads ``page_table[b, j]`` and DMAs exactly that
+  ``[page_size, H, D]`` page from the pool into VMEM — HBM traffic is
+  O(live tokens), never O(pool).  Online softmax with float32 accumulators
+  in VMEM scratch persisting across the (innermost, sequential) page
+  dimension; pages past a lane's length are skipped entirely via
+  ``pl.when``.  Interpret mode off-TPU; Mosaic on TPU.
 
 Grad-free by construction: decode is inference-only, no ``custom_vjp`` is
 defined, and differentiating through ``pallas_call`` raises — the learner
@@ -109,8 +110,14 @@ def _decode_kernel(
     pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, acc_sc, m_sc, l_sc,
     *, scale, page_size, num_pages_per_lane,
 ):
+    """One (lane, page) grid step over ALL heads: ``q_ref``/``o_ref`` are
+    ``[H, D]``, ``k_ref``/``v_ref`` one ``[ps, H, D]`` page.  A single
+    query row cannot feed the MXU, so scores and the weighted sum are VPU
+    products reduced over lanes (D) and over the page axis; the softmax
+    state is a ``[H, 1]`` column per head, heads staying on sublanes
+    throughout."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -123,34 +130,25 @@ def _decode_kernel(
 
     @pl.when(live)
     def _attend():
-        q = q_ref[0, 0, 0, :].astype(jnp.float32)[None, :] * scale  # [1, D]
-        k_blk = k_ref[0, :, 0, :].astype(jnp.float32)  # [ps, D]
-        v_blk = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [1, ps]
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1
-        )
+        q = q_ref[...].astype(jnp.float32) * scale  # [H, D]
+        k_blk = k_ref[...].astype(jnp.float32)  # [ps, H, D]
+        v_blk = v_ref[...].astype(jnp.float32)
+        s = jnp.sum(q[None] * k_blk, axis=-1, keepdims=True)  # [ps, H, 1]
+        pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         s = jnp.where(pos < length, s, jnp.float32(_NEG_BIG))
-        m = m_sc[:]
-        l = l_sc[:]
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        m = m_sc[:]  # [H, 1]
+        m_new = jnp.maximum(m, s.max(axis=0))
+        p = jnp.exp(s - m_new[None])  # [ps, H, 1]
         corr = jnp.exp(m - m_new)
-        l_sc[:] = l * corr + p.sum(axis=-1, keepdims=True)
+        l_sc[:] = l_sc[:] * corr + p.sum(axis=0)
         m_sc[:] = m_new
-        acc_sc[:] = acc_sc[:] * corr + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_sc[:] = acc_sc[:] * corr + jnp.sum(p * v_blk, axis=0)
 
     @pl.when(j == num_pages_per_lane - 1)
     def _finish():
-        o_ref[0, 0, 0, :] = (
+        o_ref[...] = (
             acc_sc[:] / jnp.maximum(l_sc[:], 1e-30)
-        )[0].astype(o_ref.dtype)
+        ).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -182,25 +180,28 @@ def paged_decode_attention(
     kernel = functools.partial(
         _decode_kernel, scale=scale, page_size=ps, num_pages_per_lane=M,
     )
+    # Mosaic tiles the last two block dims, which must be (8, 128)-divisible
+    # or span their axis: every block therefore carries the WHOLE [H, D]
+    # head plane (a per-head block would leave H second-to-last at size 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, M),
+        grid=(B, M),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, D), lambda b, h, j, pt, ln: (b, 0, h, 0)),
+            pl.BlockSpec((None, None, H, D), lambda b, j, pt, ln: (b, 0, 0, 0)),
             pl.BlockSpec(
-                (1, ps, 1, D), lambda b, h, j, pt, ln: (pt[b, j], 0, h, 0)
+                (None, ps, H, D), lambda b, j, pt, ln: (pt[b, j], 0, 0, 0)
             ),
             pl.BlockSpec(
-                (1, ps, 1, D), lambda b, h, j, pt, ln: (pt[b, j], 0, h, 0)
+                (None, ps, H, D), lambda b, j, pt, ln: (pt[b, j], 0, 0, 0)
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, 1, D), lambda b, h, j, pt, ln: (b, 0, h, 0)
+            (None, None, H, D), lambda b, j, pt, ln: (b, 0, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((H, D), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
         ],
     )
     return pl.pallas_call(

@@ -63,17 +63,53 @@ def hierarchical_sample(
     ).astype(jnp.int32)
 
 
+_LANES = 128
+
+
+def _block_tiles(block_size: int) -> Tuple[int, int]:
+    """A priority block as ``[rows, lanes]`` (row-major).  Mosaic tiles the
+    last two block dims, so a block rides as whole 128-lane rows; a block
+    size that is not a lane multiple (interpret-mode tests) stays one row.
+    """
+    if block_size % _LANES == 0:
+        return block_size // _LANES, _LANES
+    return 1, block_size
+
+
+def _lane_cumsum(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum along the last axis by log-step shifts (Mosaic
+    has no cumsum lowering; ``pltpu.roll`` runs on the XLU)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes = x.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    shift = 1
+    while shift < lanes:
+        x = x + jnp.where(lane >= shift, pltpu.roll(x, shift, x.ndim - 1), 0.0)
+        shift *= 2
+    return x
+
+
 def _within_block_kernel(b_idx_ref, t_ref, p_ref, out_ref):
-    """One sample per grid step: search the prefetch-selected block."""
+    """One sample per grid step: search the prefetch-selected block.
+
+    ``p_ref`` is the block as ``[rows, lanes]``; the count of prefix sums
+    below the target is taken row by row, each row's running offset being
+    the total of the rows before it."""
     import jax.experimental.pallas as pl
 
     i = pl.program_id(0)
-    t = t_ref[i, 0]
-    cum = jnp.cumsum(p_ref[0, :])
-    w = jnp.sum((cum < t).astype(jnp.int32))
-    bs = p_ref.shape[-1]
-    w = jnp.minimum(w, bs - 1)
-    out_ref[i, 0] = b_idx_ref[i] * bs + w
+    t = t_ref[i]
+    rows, lanes = p_ref.shape
+    bs = rows * lanes
+    cum = _lane_cumsum(p_ref[...])
+    offset = jnp.zeros((1, 1), jnp.float32)
+    w = jnp.int32(0)
+    for r in range(rows):
+        row = cum[r:r + 1, :] + offset
+        w = w + jnp.sum((row < t).astype(jnp.int32))
+        offset = row[:, lanes - 1:]
+    out_ref[i] = b_idx_ref[i] * bs + jnp.minimum(w, bs - 1)
 
 
 def pallas_sample(
@@ -97,24 +133,25 @@ def pallas_sample(
 
     blocks, b_idx, within_t = _split_targets(flat_p, targets, block_size)
     S = targets.shape[0]
+    rows, lanes = _block_tiles(block_size)
+    # block ids AND residual targets are scalar-prefetched: the ids steer
+    # the DMA index map, the targets are read as SMEM scalars; the result
+    # is one SMEM word per sample (no scalar stores into VMEM on Mosaic)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,              # b_idx steers the DMA index map
+        num_scalar_prefetch=2,
         grid=(S,),
         in_specs=[
-            pl.BlockSpec((S, 1), lambda i, b_idx_ref: (0, 0)),
-            pl.BlockSpec(
-                (1, block_size), lambda i, b_idx_ref: (b_idx_ref[i], 0)
-            ),
+            pl.BlockSpec((None, rows, lanes), lambda i, b, t: (b[i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((S, 1), lambda i, b_idx_ref: (0, 0)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
     )
     out = pl.pallas_call(
         _within_block_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, 1), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((S,), jnp.int32),
         interpret=interpret,
-    )(b_idx, within_t[:, None], blocks)
-    return jnp.clip(out[:, 0], 0, flat_p.shape[0] - 1)
+    )(b_idx, within_t.astype(jnp.float32), blocks.reshape(-1, rows, lanes))
+    return jnp.clip(out, 0, flat_p.shape[0] - 1)
 
 
 _SAMPLE_METHODS = ("cumsum", "hierarchical", "pallas")
@@ -222,88 +259,78 @@ def _pad_to_blocks(flat_p: jnp.ndarray, block_size: int) -> jnp.ndarray:
     return jnp.pad(flat_p, (0, pad)) if pad else flat_p
 
 
-def _update_kernel_factory(M: int, with_sums: bool):
+def _update_kernel(
+    b_idx_ref, w_idx_ref, newp_ref, blocks_ref, out_blocks_ref, out_sums_ref
+):
     """Grid step i owns block ``b_idx[i]`` and applies EVERY update whose
     block matches — idempotent per block, so a block revisited by a later
     grid step (whose input DMA races the earlier step's writeback under the
     double-buffered pipeline) recomputes the identical final content
     instead of losing the earlier write.  Updates apply in ascending order,
-    so duplicate (block, lane) pairs are deterministic last-wins."""
+    so duplicate (block, slot) pairs are deterministic last-wins.  The
+    block's refreshed sum leaves as one SMEM word per update."""
     import jax.experimental.pallas as pl
 
-    def kernel(b_idx_ref, w_idx_ref, blocks_ref, *rest):
-        if with_sums:
-            _sums_ref, newp_ref, out_blocks_ref, out_sums_ref = rest
-        else:
-            newp_ref, out_blocks_ref = rest
-        i = pl.program_id(0)
-        my_b = b_idx_ref[i]
-        blk = blocks_ref[:]
-        lane = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    i = pl.program_id(0)
+    my_b = b_idx_ref[i]
+    blk = blocks_ref[...]  # [rows, lanes], row-major within the block
+    rows, lanes = blk.shape
+    slot = (
+        jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0) * lanes
+        + jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    )
 
-        def body(j, blk):
-            sel = (b_idx_ref[j] == my_b) & (lane == w_idx_ref[j])
-            return jnp.where(sel, newp_ref[j, 0], blk)
+    def body(j, blk):
+        sel = (b_idx_ref[j] == my_b) & (slot == w_idx_ref[j])
+        return jnp.where(sel, newp_ref[j], blk)
 
-        blk = jax.lax.fori_loop(0, M, body, blk)
-        out_blocks_ref[:] = blk
-        if with_sums:
-            out_sums_ref[0, 0] = jnp.sum(blk)
-
-    return kernel
+    blk = jax.lax.fori_loop(0, b_idx_ref.shape[0], body, blk)
+    out_blocks_ref[...] = blk
+    out_sums_ref[i] = jnp.sum(blk)
 
 
 def _pallas_update(
     blocks: jnp.ndarray,  # [nb, bs]
-    block_sums,  # [nb] or None
     b_idx: jnp.ndarray,  # [M]
     w_idx: jnp.ndarray,  # [M]
     new_p: jnp.ndarray,  # [M]
     interpret: bool,
-):
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Returns ``(new blocks [nb, bs], per-update block sums [M])``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     nb, bs = blocks.shape
     M = b_idx.shape[0]
-    with_sums = block_sums is not None
-    in_specs = [
-        pl.BlockSpec((1, bs), lambda i, b, w: (b[i], 0)),
-    ]
-    out_specs = [pl.BlockSpec((1, bs), lambda i, b, w: (b[i], 0))]
-    out_shape = [jax.ShapeDtypeStruct((nb, bs), jnp.float32)]
-    operands = [blocks.astype(jnp.float32)]
-    # the outputs alias their inputs (indices count the scalar-prefetch
-    # operands): untouched blocks/sums keep their values with zero copies
-    aliases = {2: 0}
-    if with_sums:
-        in_specs.append(pl.BlockSpec((1, 1), lambda i, b, w: (b[i], 0)))
-        out_specs.append(pl.BlockSpec((1, 1), lambda i, b, w: (b[i], 0)))
-        out_shape.append(jax.ShapeDtypeStruct((nb, 1), jnp.float32))
-        operands.append(block_sums.astype(jnp.float32).reshape(nb, 1))
-        aliases[3] = 1
-    in_specs.append(
-        pl.BlockSpec((M, 1), lambda i, b, w: (0, 0))  # all updates, VMEM
+    rows, lanes = _block_tiles(bs)
+    block_spec = pl.BlockSpec(
+        (None, rows, lanes), lambda i, b, w, p: (b[i], 0, 0)
     )
-    operands.append(new_p.astype(jnp.float32)[:, None])
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        # block ids steer the DMA; slots and new values are SMEM scalars
+        num_scalar_prefetch=3,
         grid=(M,),
-        in_specs=in_specs,
-        # out_specs/out_shape pytrees must match exactly: a bare leaf for
-        # the plane-only variant, a 2-tuple when sums ride along
-        out_specs=tuple(out_specs) if with_sums else out_specs[0],
+        in_specs=[block_spec],
+        out_specs=(block_spec, pl.BlockSpec(memory_space=pltpu.SMEM)),
     )
-    out = pl.pallas_call(
-        _update_kernel_factory(M, with_sums),
+    new_blocks, sums = pl.pallas_call(
+        _update_kernel,
         grid_spec=grid_spec,
-        out_shape=tuple(out_shape) if with_sums else out_shape[0],
-        input_output_aliases=aliases,
+        out_shape=(
+            jax.ShapeDtypeStruct((nb, rows, lanes), jnp.float32),
+            jax.ShapeDtypeStruct((M,), jnp.float32),
+        ),
+        # the plane aliases its input (the index counts the scalar-prefetch
+        # operands): untouched blocks keep their values with zero copies
+        input_output_aliases={3: 0},
         interpret=interpret,
-    )(b_idx.astype(jnp.int32), w_idx.astype(jnp.int32), *operands)
-    if with_sums:
-        return out[0], out[1][:, 0]
-    return out, None
+    )(
+        b_idx.astype(jnp.int32),
+        w_idx.astype(jnp.int32),
+        new_p.astype(jnp.float32),
+        blocks.astype(jnp.float32).reshape(nb, rows, lanes),
+    )
+    return new_blocks.reshape(nb, bs), sums
 
 
 def update_priorities_blocks(
@@ -359,11 +386,14 @@ def update_priorities_blocks(
             )
         return padded[:n], new_sums
 
-    blocks = padded.reshape(nb, block_size)
-    new_blocks, new_sums = _pallas_update(
-        blocks, block_sums, b_idx, w_idx, new_p,
+    new_blocks, touched_sums = _pallas_update(
+        padded.reshape(nb, block_size), b_idx, w_idx, new_p,
         interpret=(
             jax.default_backend() != "tpu" if interpret is None else interpret
         ),
     )
+    new_sums = None
+    if block_sums is not None:
+        # duplicates of one block carry the identical (final) sum
+        new_sums = block_sums.astype(jnp.float32).at[b_idx].set(touched_sums)
     return new_blocks.reshape(-1)[:n], new_sums

@@ -126,11 +126,11 @@ def full_attention(
 def make_ring_attention_fn(mesh: Mesh, causal: bool = False, axis_name: str = "sp"):
     """shard_map ``ring_attention`` over global ``[B, T, H, D]`` arrays
     sequence-sharded on ``axis_name``."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     spec = P(None, axis_name, None, None)
     fn = functools.partial(ring_attention, axis_name=axis_name, causal=causal)
     return shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )

@@ -52,19 +52,11 @@ def initialize_multihost(
     # but the FIRST multi-process computation dies with "Multiprocess
     # computations aren't implemented on the CPU backend".  Gloo ships in
     # jaxlib; select it before the backend initializes.  TPU/GPU runtimes
-    # bring their own collectives and ignore this knob, and older jax
-    # versions without the option fall through to the previous behavior
-    # (the multihost tests skip via tests/multihost_support.py's probe).
+    # bring their own collectives and ignore this knob.
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu") or (
         jax.config.jax_platforms or ""
     ).startswith("cpu"):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 — option absent in this jax version
-            logger.warning(
-                "jax_cpu_collectives_implementation unavailable; "
-                "multi-process CPU collectives may be unsupported"
-            )
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

@@ -25,7 +25,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 # stage_fn(stage_params, x[mb, ...]) -> y[mb, ...] (same shape)
@@ -163,7 +163,7 @@ def make_hetero_pipeline_apply(
         mesh=mesh,
         in_specs=({"embed": P(), "block": P(axis_name), "head": P()}, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     pp = mesh.shape[axis_name]
 

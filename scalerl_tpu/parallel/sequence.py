@@ -14,7 +14,7 @@ from __future__ import annotations
 import functools
 
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from scalerl_tpu.models.transformer import TransformerPolicy, TransformerOutput
@@ -49,7 +49,7 @@ def make_sequence_parallel_apply(
         mesh=mesh,
         in_specs=(P(), P(None, axis_name, None)),
         out_specs=TransformerOutput(P(None, axis_name, None), seq),
-        check_rep=False,
+        check_vma=False,
     )
     sp = mesh.shape[axis_name]
 

@@ -224,7 +224,7 @@ class Autoscaler:
     # -- flap accounting -----------------------------------------------
     def actions_per_min(self, window_s: float = 60.0, now: Optional[float] = None) -> float:
         """Actions issued over the trailing window, per minute — the soak
-        gate's flap metric (tpu_watch marks ``!elastic(flap=...)``)."""
+        verdict's flap metric (``tools/elastic_soak.py``)."""
         now = time.monotonic() if now is None else now
         recent = sum(1 for t in self._action_times if now - t <= window_s)
         return recent * 60.0 / window_s

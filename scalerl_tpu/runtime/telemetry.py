@@ -38,8 +38,7 @@ into the registry (:func:`observe_train_metrics`).
 Process-wide access: :func:`get_registry` / :func:`get_recorder` return the
 default instances (created on first use); :func:`reset` swaps in fresh ones
 (tests).  When ``SCALERL_TELEMETRY_DIR`` is set, the process writes a
-``final_snapshot.json`` at exit — ``tools/tpu_watch.py`` attaches it to the
-payload step summary.
+``final_snapshot.json`` at exit, for whatever harness started it to read.
 """
 
 from __future__ import annotations
@@ -791,7 +790,7 @@ _ENV_DUMP_INSTALLED = False
 
 def _maybe_install_env_dump() -> None:
     """When ``SCALERL_TELEMETRY_DIR`` is set, write a final snapshot +
-    flight-recorder tail at interpreter exit (the tpu_watch attachment)."""
+    flight-recorder tail at interpreter exit."""
     global _ENV_DUMP_INSTALLED
     if _ENV_DUMP_INSTALLED:
         return

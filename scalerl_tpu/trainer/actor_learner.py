@@ -785,6 +785,7 @@ class DeviceActorLearnerTrainer(BaseTrainer):
             if guard is not None:
                 guard.restore()
         self.agent.state = state
+        self.carry = carry  # the env lanes as the last chunk left them
         # chunks_done < num_calls after a preemption: checkpoint the frames
         # actually trained, not the requested budget, so resume restores
         # matching counters

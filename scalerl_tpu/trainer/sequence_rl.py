@@ -386,6 +386,29 @@ class SequenceRLTrainer:
         self.reward_history.append(mean_reward)
         return metrics
 
+    def lowered_programs(self) -> Dict[str, Any]:
+        """The round's three kernel-bearing programs, lowered against the
+        trainer's live state: ``decode`` (continuous engine only), the
+        replay ``sample`` and the ``learn`` step.  Nothing is trained or
+        donated; the sample runs once to give the learn step its batch."""
+        key = jax.random.PRNGKey(0)
+        n = self.args.genrl_sample_batch
+        programs = {
+            "sample": seq_sample.lower(
+                self.replay, key, n, method=self._seq_method
+            )
+        }
+        with self._dispatch_guard():
+            batch, _core, _idx, weights = seq_sample(
+                self.replay, key, n, method=self._seq_method
+            )
+            programs["learn"] = self.agent.lower_learn(
+                dict(batch, is_weight=weights)
+            )
+        if self.continuous:
+            programs["decode"] = self.engine.lower_decode()
+        return programs
+
     def train(self, rounds: Optional[int] = None) -> Dict[str, float]:
         rounds = rounds if rounds is not None else self.args.genrl_rounds
         metrics: Dict[str, float] = {}

@@ -71,6 +71,40 @@ def _by_prompt(completions):
     return {tuple(c.prompt.tolist()): c for c in completions}
 
 
+def test_engine_is_single_device_under_a_meshed_learner(setup):
+    """Generation is single-chip: handed a meshed learner's model (its
+    activations pinned to the learner mesh) and mesh-sharded params, the
+    engine drops the pin and gathers every push onto its one device — a
+    sharded tree would make decode SPMD, and the Mosaic paged-attention
+    kernel cannot be partitioned."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from scalerl_tpu.parallel import activation_constraint, make_mesh
+
+    mesh = make_mesh("dp=2,mp=2", jax.devices()[:4])
+    spread = jax.device_put(setup["params"], NamedSharding(mesh, P()))
+    engine = ContinuousEngine(
+        setup["model"].clone(constrain=activation_constraint(mesh)),
+        spread,
+        ContinuousConfig(
+            vocab_size=V, max_prompt_len=P_MAX, max_new_tokens=R_MAX,
+            lanes=4, page_size=4,
+        ),
+    )
+    assert engine.model.constrain is None
+
+    def devices_of(tree):
+        return {
+            d for leaf in jax.tree_util.tree_leaves(tree)
+            for d in leaf.devices()
+        }
+
+    assert devices_of(engine._snapshot_params()[0]) == {engine._device}
+    engine.push_params(spread)
+    assert devices_of(engine._snapshot_params()[0]) == {engine._device}
+    assert devices_of(engine._pools) == {engine._device}
+
+
 def test_greedy_parity_fixed_vs_continuous(setup):
     """The acceptance pin: at temperature 0 the continuous engine's
     token-level outputs for any single sequence are IDENTICAL to the

@@ -406,7 +406,7 @@ def test_token_ppo_agent_learn_one_batched_transfer(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# trainer e2e (also run standalone by the tpu_watch genrl soak via -k e2e)
+# trainer e2e (also runnable standalone via -k e2e)
 
 
 @pytest.mark.slow

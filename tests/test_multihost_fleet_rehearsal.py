@@ -56,7 +56,7 @@ _RANK = textwrap.dedent(
     import numpy as np
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.experimental.multihost_utils import process_allgather
 
     from scalerl_tpu.parallel.multihost import initialize_multihost

@@ -241,6 +241,54 @@ def test_packed_forward_flash_matches_dense():
     )
 
 
+def test_segment_kernel_runs_per_shard_under_a_learner_mesh():
+    """GSPMD cannot partition a Mosaic kernel, so a meshed learner runs
+    the packed-row kernel per (dp rows, mp heads) shard: the shard_map
+    wrap equals the plain kernel in value and gradient, and
+    ``enable_mesh`` installs it whenever the model carries the kernel."""
+    from scalerl_tpu.ops.pallas_attention import (
+        segment_flash_attention,
+        shard_segment_attn,
+    )
+    from scalerl_tpu.parallel import make_mesh
+    from scalerl_tpu.trainer.sequence_rl import build_genrl_model
+
+    mesh = make_mesh("dp=2,mp=2", jax.devices()[:4])
+    B, T, H, D = 4, 24, 4, 8
+    q, k, v = (
+        jax.random.normal(kk, (B, T, H, D))
+        for kk in jax.random.split(jax.random.PRNGKey(0), 3)
+    )
+    seg = np.zeros((B, T), np.int32)
+    seg[:, :7], seg[:, 7:19] = 1, 2
+    seg = jnp.asarray(seg)
+    sharded = shard_segment_attn(segment_flash_attention, mesh)
+
+    def loss(fn, q, k, v):
+        return jnp.sum(fn(q, k, v, seg) ** 2)
+
+    for argnum in (None, 0, 1, 2):
+        f = loss if argnum is None else jax.grad(loss, argnums=argnum + 1)
+        np.testing.assert_allclose(
+            np.asarray(jax.jit(f, static_argnums=0)(sharded, q, k, v)),
+            np.asarray(jax.jit(f, static_argnums=0)(segment_flash_attention, q, k, v)),
+            atol=1e-5, rtol=1e-5,
+        )
+
+    args = _args(
+        learner_packing=True, learner_packed_attn="pallas", n_heads=2,
+        dp_size=2, mp_size=2,
+    )
+    agent = TokenPPOAgent(args, build_genrl_model(args))
+    assert agent.model.segment_attn_fn is segment_flash_attention
+    agent.enable_mesh(mesh)
+    assert agent.model.segment_attn_fn is not segment_flash_attention
+    _, _, pk = _ragged_batches(5, V=args.vocab_size, P=4, R=4, B=4)
+    fields, _ = pk.bucketed(4).fields()  # rows divide by dp
+    packed = {k: jnp.asarray(v) for k, v in fields.items()}
+    assert np.isfinite(agent.learn(packed)["total_loss"])
+
+
 # ---------------------------------------------------------------------------
 # packed-vs-padded loss/grad parity
 

@@ -279,7 +279,7 @@ def test_grad_axis_psum_matches_single_device():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from scalerl_tpu.agents.impala import ImpalaAgent, make_impala_learn_fn
@@ -318,7 +318,7 @@ def test_grad_axis_psum_matches_single_device():
         mesh=mesh,
         in_specs=(state_spec, traj_spec),
         out_specs=(state_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     state_sharded, m_sharded = jax.jit(fn)(agent.state, traj)
 

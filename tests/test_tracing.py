@@ -4,7 +4,7 @@ lifecycle end to end (ISSUE 13).
 jax-free on purpose — the tracer, the wire piggyback, the span files, and
 ``tools/trace_report.py`` all live on the host side, so these tests run in
 milliseconds and double as the artifact-schema gate for the trace_report
-verdict line the tpu_watch trace-soak step parses.
+verdict line a trace-soak caller parses.
 """
 
 import json
@@ -371,7 +371,7 @@ def test_disagg_lifecycle_yields_complete_traces(monkeypatch, tmp_path):
         s["name"] == "snapshot.fetch" for s in snap[0]["spans"]
     )
 
-    # -- verdict line schema (what tpu_watch's _trace_marker parses) ----
+    # -- verdict line schema (what a trace-soak caller parses) ----------
     line = json.loads(json.dumps(v))
     for key, typ in VERDICT_SCHEMA.items():
         assert key in line, f"verdict missing {key}"

@@ -23,8 +23,8 @@ ping/pong RTTs).  Output, in one pass:
    EXACTLY, and the report can never double-count overlap.
 
 The last stdout line is a one-line JSON verdict
-(``{"metric": "trace_report", ...}``) that ``tools/tpu_watch.py`` gates
-its trace-soak step on: ``sequence_traces`` vs ``complete_sequences``
+(``{"metric": "trace_report", ...}``) for a caller to gate
+the trace soak on: ``sequence_traces`` vs ``complete_sequences``
 (root -> learn_step present) and ``orphan_spans``.
 
 ``--traffic`` additionally runs the tier-attribution walk
@@ -369,7 +369,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         )
         print_traffic_report(traffic)
         print(json.dumps(traffic), flush=True)
-    # the gate line LAST: tpu_watch scans for the newest matching object
+    # the gate line LAST: callers read the newest matching object
     print(json.dumps(report["verdict"]), flush=True)
     ok = (
         report["verdict"]["orphan_spans"] == 0

@@ -17,8 +17,8 @@ sampled request into named tier edges ONLINE — per-edge durations sum to
 the end-to-end latency exactly — and the final verdict names the
 ``bottleneck_tier`` (largest p95 share of the critical path).  The last
 stdout line is a one-line JSON verdict (``{"metric": "traffic_replay",
-...}``) that ``tools/tpu_watch.py`` gates its ``traffic-replay`` soak
-step on:
+...}``) for a caller to gate the traffic-replay soak
+on:
 
 - **exact accounting**: ``admitted == answered + shed + orphaned`` at
   quiesce (the chaos e2e's equation);
@@ -730,7 +730,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = build_parser().parse_args(argv)
     verdict = run_replay(args)
     print_verdict(verdict)
-    # the gate line LAST: tpu_watch scans for the newest matching object
+    # the gate line LAST: callers read the newest matching object
     print(json.dumps(verdict), flush=True)
     ok = (
         verdict["accounting_balanced"]
