@@ -44,6 +44,8 @@ from scalerl_tpu.models.transformer import (
     sequence_attention_mask,
     sequence_positions,
 )
+from scalerl_tpu.runtime import tracing
+from scalerl_tpu.utils import profiling  # noqa: F401  (installs the spans' profiler half)
 from scalerl_tpu.utils.checkpoint import load_checkpoint, save_checkpoint
 
 
@@ -440,9 +442,10 @@ class TokenPPOAgent:
     def learn_device(self, batch) -> Dict[str, Any]:
         """One train step, metrics left as device arrays (the hot-loop
         half of the one-batched-transfer discipline)."""
-        if self._shard_batch is not None:
-            batch = self._shard_batch(batch)
-        self.state, metrics = self._learn(self.state, batch)  # graftlint: disable=JG002 (single-threaded learner loop; genrl has no actor threads)
+        with tracing.span("learn.dispatch", kind="learn"):
+            if self._shard_batch is not None:
+                batch = self._shard_batch(batch)
+            self.state, metrics = self._learn(self.state, batch)  # graftlint: disable=JG002 (single-threaded learner loop; genrl has no actor threads)
         return metrics
 
     def lower_learn(self, batch):
@@ -455,7 +458,8 @@ class TokenPPOAgent:
     def learn(self, batch) -> Dict[str, float]:
         from scalerl_tpu.runtime.dispatch import get_metrics
 
-        return get_metrics(self.learn_device(batch))  # one batched transfer
+        with tracing.span("learn.step", kind="learn"):
+            return get_metrics(self.learn_device(batch))  # one batched transfer
 
     def get_weights(self):
         return self.state.params

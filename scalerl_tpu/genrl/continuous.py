@@ -121,6 +121,7 @@ from scalerl_tpu.serving.batcher import (
     ServingConfig,
     ServingRequest,
 )
+from scalerl_tpu.utils import profiling  # noqa: F401  (installs the spans' profiler half)
 from scalerl_tpu.utils.buckets import bucket_for, default_buckets
 
 # module seams: tests monkeypatch these to count host transfers and assert
@@ -1243,9 +1244,10 @@ class ContinuousEngine(ParamSnapshotPlane):
         the temperature-0 token-identity contract.  Live lanes keep their
         shared pages (their own refs) until harvest — only the cache's
         index drops."""
-        gen = super().push_params(params, learner_step, quantize)
-        if self._prefix_cache is not None:
-            self._prefix_cache.flush()
+        with tracing.span("genrl.push_params", kind="genrl"):
+            gen = super().push_params(params, learner_step, quantize)
+            if self._prefix_cache is not None:
+                self._prefix_cache.flush()
         return gen
 
     # -- the macro-step --------------------------------------------------
@@ -1295,10 +1297,19 @@ class ContinuousEngine(ParamSnapshotPlane):
         loop instead (:meth:`_spec_step`) — same admission, same harvest,
         same one-upload-one-read transfer discipline, but synchronous by
         construction (next pass's drafts need this pass's tokens)."""
-        if self._spec_k:
-            return self._spec_step()
-        t_step0 = time.monotonic()
-        self._admit()
+        # ONE live span per cycle and per phase of it -- never per token,
+        # never per lane; host stamps only (graftlint JG001)
+        cycle, kind = (
+            (self._spec_step, "genrl-spec")
+            if self._spec_k
+            else (self._plain_step, "genrl")
+        )
+        with tracing.span("genrl.macro_step", kind=kind) as span:
+            return cycle(span)
+
+    def _plain_step(self, step_span) -> List[CompletedSequence]:
+        with tracing.span("genrl.admit", kind="genrl"):
+            self._admit()
         dispatched = False
         occ = 0.0
         if self.live_lanes > 0:
@@ -1309,7 +1320,9 @@ class ContinuousEngine(ParamSnapshotPlane):
             self._occupancy_sum += occ
             guard = steady_state_guard() if self._warm else nullcontext()
             with guard:
-                with self._dispatch_guard():
+                with self._dispatch_guard(), tracing.span(
+                    "genrl.dispatch", kind="genrl"
+                ):
                     self._key, sub = jax.random.split(self._key)
                     # ONE explicit batched host->device upload per macro
                     table_dev = _device_put(self._table)
@@ -1348,25 +1361,19 @@ class ContinuousEngine(ParamSnapshotPlane):
         ):
             macro_idx, outputs = self._inflight.popleft()
             guard = steady_state_guard() if self._warm else nullcontext()
-            with guard:
+            with guard, tracing.span("genrl.read", kind="genrl"):
                 # ... and ONE explicit batched device->host read
                 host = _device_get(outputs)
             completions.extend(self._harvest(host, macro_idx))
             if dispatched:
                 break  # steady state: exactly one read per step
-        if tracing.sampling_enabled():
-            # ONE head-sampled span per macro-step/harvest — never per
-            # token, never per lane; stamps are the host monotonic reads
-            # this method already pays (graftlint JG001 good twin)
-            tracing.record_span(
-                "genrl.macro_step", None, t_step0, time.monotonic(),
-                kind="genrl", completed=len(completions),
-                live_lanes=self.live_lanes, occupancy=round(occ, 4),
-                in_flight=len(self._inflight),
-            )
+        step_span.set(
+            completed=len(completions), live_lanes=self.live_lanes,
+            occupancy=round(occ, 4), in_flight=len(self._inflight),
+        )
         return completions
 
-    def _spec_step(self) -> List[CompletedSequence]:
+    def _spec_step(self, step_span) -> List[CompletedSequence]:
         """One speculative cycle (ISSUE 16): admit -> draft (host-side
         n-gram lookups, jax-free) -> ONE batched upload + verify dispatch
         -> ONE batched read -> feed the drafter, harvest, and rewind the
@@ -1377,8 +1384,8 @@ class ContinuousEngine(ParamSnapshotPlane):
         read is synchronous (``steps_in_flight`` is ignored here) because
         pass ``m+1``'s drafts are functions of pass ``m``'s emitted
         tokens."""
-        t_step0 = time.monotonic()
-        self._admit()
+        with tracing.span("genrl.admit", kind="genrl-spec"):
+            self._admit()
         completions: List[CompletedSequence] = []
         occ = 0.0
         draft_s = verify_s = 0.0
@@ -1396,61 +1403,62 @@ class ContinuousEngine(ParamSnapshotPlane):
             # host numpy (the gap between read and dispatch the device
             # decodes through in plain mode is spent drafting here)
             t_draft0 = time.monotonic()
-            drafts = np.zeros((L, k), np.int32)
-            draft_len = np.zeros((L,), np.int32)
-            busy = np.zeros((L,), bool)
-            cl_host = np.zeros((L,), np.int64)
-            proposed = 0
-            for lane_id, lane in enumerate(self._lanes):
-                if not lane.busy:
-                    continue
-                busy[lane_id] = True
-                cl_host[lane_id] = lane.context_len
-                # the bonus token always fits (a live lane has budget
-                # room by the done latch); drafts are clamped so the
-                # whole accepted run stays within the response budget
-                room = (
-                    lane.prompt_len
-                    + self._response_budget
-                    - lane.context_len
-                    - 1
-                )
-                if room > 0:
-                    d = self._drafter.propose(lane_id)
-                    if d is not None:
-                        dl = min(len(d), room, k)
-                        if dl:
-                            drafts[lane_id, :dl] = d[:dl]
-                            draft_len[lane_id] = dl
-                            proposed += dl
-            # bucket the pass to the smallest ladder width that fits its
-            # longest draft: a ramp pass whose best proposal is 1 token
-            # verifies through the 2-wide program, not the k-wide one —
-            # on a compute-bound substrate the unused slots of a too-wide
-            # program are pure wall-clock waste.  Each bucket is its own
-            # compiled program (shape-static), so this never retraces
-            dmax = int(draft_len.max())
-            kb = next(b for b in self._spec_buckets if b >= dmax)
-            fn = self._verify_fns.get(kb)
-            if fn is None:
-                fn = self._verify_fns[kb] = self._build_verify(kb)
-            T = kb + 1
-            drafts = drafts[:, :kb]
-            # slot j writes K/V at flat position cl + j; slots past the
-            # draft length (and the whole row of a dead lane) route to
-            # the null page.  ``self._table[lane, pos // ps]`` already
-            # IS the padded page matrix (0 where unheld), so routing is
-            # one vectorized [L, T] gather — no per-lane numpy traffic
-            # in the host gap the device sits idle through
-            slot = np.arange(T)
-            gpos = cl_host[:, None] + slot[None, :]
-            page_idx = np.minimum(gpos // ps, self._table.shape[1] - 1)
-            writable = (slot[None, :] <= draft_len[:, None]) & busy[:, None]
-            rows = np.arange(L)[:, None]
-            page_ids = np.where(
-                writable, self._table[rows, page_idx], 0
-            ).astype(np.int32)
-            offsets = np.where(writable, gpos % ps, 0).astype(np.int32)
+            with tracing.span("seq.draft", kind="genrl-spec"):
+                drafts = np.zeros((L, k), np.int32)
+                draft_len = np.zeros((L,), np.int32)
+                busy = np.zeros((L,), bool)
+                cl_host = np.zeros((L,), np.int64)
+                proposed = 0
+                for lane_id, lane in enumerate(self._lanes):
+                    if not lane.busy:
+                        continue
+                    busy[lane_id] = True
+                    cl_host[lane_id] = lane.context_len
+                    # the bonus token always fits (a live lane has budget
+                    # room by the done latch); drafts are clamped so the
+                    # whole accepted run stays within the response budget
+                    room = (
+                        lane.prompt_len
+                        + self._response_budget
+                        - lane.context_len
+                        - 1
+                    )
+                    if room > 0:
+                        d = self._drafter.propose(lane_id)
+                        if d is not None:
+                            dl = min(len(d), room, k)
+                            if dl:
+                                drafts[lane_id, :dl] = d[:dl]
+                                draft_len[lane_id] = dl
+                                proposed += dl
+                # bucket the pass to the smallest ladder width that fits its
+                # longest draft: a ramp pass whose best proposal is 1 token
+                # verifies through the 2-wide program, not the k-wide one —
+                # on a compute-bound substrate the unused slots of a too-wide
+                # program are pure wall-clock waste.  Each bucket is its own
+                # compiled program (shape-static), so this never retraces
+                dmax = int(draft_len.max())
+                kb = next(b for b in self._spec_buckets if b >= dmax)
+                fn = self._verify_fns.get(kb)
+                if fn is None:
+                    fn = self._verify_fns[kb] = self._build_verify(kb)
+                T = kb + 1
+                drafts = drafts[:, :kb]
+                # slot j writes K/V at flat position cl + j; slots past the
+                # draft length (and the whole row of a dead lane) route to
+                # the null page.  ``self._table[lane, pos // ps]`` already
+                # IS the padded page matrix (0 where unheld), so routing is
+                # one vectorized [L, T] gather — no per-lane numpy traffic
+                # in the host gap the device sits idle through
+                slot = np.arange(T)
+                gpos = cl_host[:, None] + slot[None, :]
+                page_idx = np.minimum(gpos // ps, self._table.shape[1] - 1)
+                writable = (slot[None, :] <= draft_len[:, None]) & busy[:, None]
+                rows = np.arange(L)[:, None]
+                page_ids = np.where(
+                    writable, self._table[rows, page_idx], 0
+                ).astype(np.int32)
+                offsets = np.where(writable, gpos % ps, 0).astype(np.int32)
             draft_s = time.monotonic() - t_draft0
             # -- verify: ONE batched upload, ONE dispatch, ONE read
             t_verify0 = time.monotonic()
@@ -1463,33 +1471,35 @@ class ContinuousEngine(ParamSnapshotPlane):
                 if kb in self._spec_warm
                 else nullcontext()
             )
-            with guard:
-                with self._dispatch_guard():
-                    self._key, sub = jax.random.split(self._key)
-                    up = _device_put(
-                        (drafts, draft_len, page_ids, offsets,
-                         self._table, self._banned)
-                    )
-                    (
-                        self._pools,
-                        self._logits_st,
-                        self._value_st,
-                        self._cl,
-                        self._done,
-                        self._resp,
-                        outputs,
-                    ) = fn(
-                        params,
-                        self._pools,
-                        self._logits_st,
-                        self._value_st,
-                        self._cl,
-                        self._done,
-                        self._resp,
-                        *up,
-                        sub,
-                    )
-                host = _device_get(outputs)
+            with tracing.span("seq.verify", kind="genrl-spec"):
+                with guard:
+                    with self._dispatch_guard():
+                        self._key, sub = jax.random.split(self._key)
+                        up = _device_put(
+                            (drafts, draft_len, page_ids, offsets,
+                             self._table, self._banned)
+                        )
+                        (
+                            self._pools,
+                            self._logits_st,
+                            self._value_st,
+                            self._cl,
+                            self._done,
+                            self._resp,
+                            outputs,
+                        ) = fn(
+                            params,
+                            self._pools,
+                            self._logits_st,
+                            self._value_st,
+                            self._cl,
+                            self._done,
+                            self._resp,
+                            *up,
+                            sub,
+                        )
+                    with tracing.span("genrl.read", kind="genrl-spec"):
+                        host = _device_get(outputs)
             verify_s = time.monotonic() - t_verify0
             macro_idx = self.macro_steps
             self.macro_steps += 1
@@ -1546,27 +1556,11 @@ class ContinuousEngine(ParamSnapshotPlane):
             if freed:
                 self._spec_rollback_counter.inc(freed)
             self._spec_accept_gauge.set(self.spec_acceptance_rate)
-        if tracing.sampling_enabled():
-            # ONE head-sampled span per pass with draft/verify child
-            # spans — never per token, never per lane (stamps are host
-            # monotonic reads this method already pays)
-            t_end = time.monotonic()
-            ctx = tracing.record_span(
-                "genrl.macro_step", None, t_step0, t_end,
-                kind="genrl-spec", completed=len(completions),
-                live_lanes=self.live_lanes, occupancy=round(occ, 4),
-                acceptance_rate=round(self.spec_acceptance_rate, 4),
-            )
-            if draft_s or verify_s:
-                t_d0 = t_step0
-                tracing.record_span(
-                    "seq.draft", ctx, t_d0, t_d0 + draft_s,
-                    kind="genrl-spec",
-                )
-                tracing.record_span(
-                    "seq.verify", ctx, t_d0 + draft_s,
-                    t_d0 + draft_s + verify_s, kind="genrl-spec",
-                )
+        step_span.set(
+            completed=len(completions), live_lanes=self.live_lanes,
+            occupancy=round(occ, 4),
+            acceptance_rate=round(self.spec_acceptance_rate, 4),
+        )
         return completions
 
     @property
@@ -1605,64 +1599,65 @@ class ContinuousEngine(ParamSnapshotPlane):
     def _harvest(
         self, host: Dict[str, np.ndarray], macro_idx: int
     ) -> List[CompletedSequence]:
-        mask = np.asarray(host["mask"], np.float32)
-        tokens = np.asarray(host["tokens"], np.int32)
-        logp = np.asarray(host["logp"], np.float32)
-        value = np.asarray(host["value"], np.float32)
-        done = np.asarray(host["done"], bool)
-        cl = np.asarray(host["cl"], np.int32)
-        finish = time.monotonic()
-        completions: List[CompletedSequence] = []
-        decode_tokens = 0
-        for lane_id, lane in enumerate(self._lanes):
-            if not lane.busy:
-                continue
-            if lane.admit_macro > macro_idx:
-                # this read predates the lane's current occupancy (the id
-                # was recycled while this macro was in flight): the row
-                # belongs to the finished previous occupant, already
-                # harvested — never apply it to the new one
-                continue
-            count = int(mask[lane_id].sum())
-            decode_tokens += count
-            if count > 0:
-                lane.tokens.append(tokens[lane_id, :count])
-                lane.logps.append(logp[lane_id, :count])
-                lane.values.append(value[lane_id, :count])
-            lane.context_len = int(cl[lane_id])
-            if done[lane_id]:
-                completions.append(
-                    CompletedSequence(
-                        prompt=lane.prompt,
-                        prompt_len=lane.prompt_len,
-                        response_tokens=np.concatenate(lane.tokens)
-                        if lane.tokens
-                        else np.zeros((0,), np.int32),
-                        behavior_logp=np.concatenate(lane.logps)
-                        if lane.logps
-                        else np.zeros((0,), np.float32),
-                        values=np.concatenate(lane.values)
-                        if lane.values
-                        else np.zeros((0,), np.float32),
-                        generation=lane.generation,
-                        submit_time=lane.submit_time,
-                        admit_time=lane.admit_time,
-                        finish_time=finish,
-                        tag=lane.tag,
+        with tracing.span("genrl.harvest", kind="genrl"):
+            mask = np.asarray(host["mask"], np.float32)
+            tokens = np.asarray(host["tokens"], np.int32)
+            logp = np.asarray(host["logp"], np.float32)
+            value = np.asarray(host["value"], np.float32)
+            done = np.asarray(host["done"], bool)
+            cl = np.asarray(host["cl"], np.int32)
+            finish = time.monotonic()
+            completions: List[CompletedSequence] = []
+            decode_tokens = 0
+            for lane_id, lane in enumerate(self._lanes):
+                if not lane.busy:
+                    continue
+                if lane.admit_macro > macro_idx:
+                    # this read predates the lane's current occupancy (the id
+                    # was recycled while this macro was in flight): the row
+                    # belongs to the finished previous occupant, already
+                    # harvested — never apply it to the new one
+                    continue
+                count = int(mask[lane_id].sum())
+                decode_tokens += count
+                if count > 0:
+                    lane.tokens.append(tokens[lane_id, :count])
+                    lane.logps.append(logp[lane_id, :count])
+                    lane.values.append(value[lane_id, :count])
+                lane.context_len = int(cl[lane_id])
+                if done[lane_id]:
+                    completions.append(
+                        CompletedSequence(
+                            prompt=lane.prompt,
+                            prompt_len=lane.prompt_len,
+                            response_tokens=np.concatenate(lane.tokens)
+                            if lane.tokens
+                            else np.zeros((0,), np.int32),
+                            behavior_logp=np.concatenate(lane.logps)
+                            if lane.logps
+                            else np.zeros((0,), np.float32),
+                            values=np.concatenate(lane.values)
+                            if lane.values
+                            else np.zeros((0,), np.float32),
+                            generation=lane.generation,
+                            submit_time=lane.submit_time,
+                            admit_time=lane.admit_time,
+                            finish_time=finish,
+                            tag=lane.tag,
+                        )
                     )
-                )
-                # release the lane: every page hold returns to the pool
-                # (shared prefix pages just drop one ref; exclusively
-                # owned pages go back to the free list immediately — the
-                # memory-scales-with-live-tokens half)
-                self.allocator.free(lane.pages, holder=f"lane[{lane_id}]")
-                self.allocator.release(lane.reserved)
-                self._table[lane_id] = 0
-                self._lanes[lane_id] = _Lane()
-        self._decode_meter.mark(decode_tokens)
-        self.completed_total += len(completions)
-        if completions:
-            self._completed_counter.inc(len(completions))
+                    # release the lane: every page hold returns to the pool
+                    # (shared prefix pages just drop one ref; exclusively
+                    # owned pages go back to the free list immediately — the
+                    # memory-scales-with-live-tokens half)
+                    self.allocator.free(lane.pages, holder=f"lane[{lane_id}]")
+                    self.allocator.release(lane.reserved)
+                    self._table[lane_id] = 0
+                    self._lanes[lane_id] = _Lane()
+            self._decode_meter.mark(decode_tokens)
+            self.completed_total += len(completions)
+            if completions:
+                self._completed_counter.inc(len(completions))
         return completions
 
     @property

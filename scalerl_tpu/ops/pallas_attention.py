@@ -171,6 +171,7 @@ def _fwd(
     )
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(B, H, nq, nk),
         in_specs=[_tile(bq, D, 2), _tile(bk, D, 3), _tile(bk, D, 3)],
         out_specs=[_tile(bq, D, 2), _tile(bq, 1, 2)],
@@ -299,6 +300,7 @@ def _bwd(causal, scale, block_q, block_k, interpret, residuals, g):
     )
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         grid=(B, H, nq, nk),
         in_specs=[
             _tile(bq, D, 2), _tile(bk, D, 3), _tile(bk, D, 3),
@@ -317,6 +319,7 @@ def _bwd(causal, scale, block_q, block_k, interpret, residuals, g):
     # k blocks outermost here: grid axis 2 walks k, axis 3 walks q
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         grid=(B, H, nk, nq),
         in_specs=[
             _tile(bq, D, 3), _tile(bk, D, 2), _tile(bk, D, 2),
@@ -514,6 +517,7 @@ def _seg_fwd(q, k, v, seg, scale, block_q, block_k, interpret):
     )
     o, lse = pl.pallas_call(
         kernel,
+        name="segment_flash_fwd",
         grid=(B, H, nq, nk),
         in_specs=[
             _tile(bq, D, 2), _tile(bk, D, 3), _tile(bk, D, 3),
@@ -651,6 +655,7 @@ def _seg_bwd(scale, block_q, block_k, interpret, residuals, g):
     )
     dq = pl.pallas_call(
         dq_kernel,
+        name="segment_flash_bwd_dq",
         grid=(B, H, nq, nk),
         in_specs=[
             _tile(bq, D, 2), _tile(bk, D, 3), _tile(bk, D, 3),
@@ -670,6 +675,7 @@ def _seg_bwd(scale, block_q, block_k, interpret, residuals, g):
     # k blocks outermost here: grid axis 2 walks k, axis 3 walks q
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="segment_flash_bwd_dkv",
         grid=(B, H, nk, nq),
         in_specs=[
             _tile(bq, D, 3), _tile(bk, D, 2), _tile(bk, D, 2),

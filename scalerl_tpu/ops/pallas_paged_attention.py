@@ -206,6 +206,7 @@ def paged_decode_attention(
     )
     return pl.pallas_call(
         kernel,
+        name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, H, D), q.dtype),
         interpret=interpret,

@@ -147,6 +147,7 @@ def pallas_sample(
     )
     out = pl.pallas_call(
         _within_block_kernel,
+        name="per_sample",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S,), jnp.int32),
         interpret=interpret,
@@ -315,6 +316,7 @@ def _pallas_update(
     )
     new_blocks, sums = pl.pallas_call(
         _update_kernel,
+        name="per_update",
         grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((nb, rows, lanes), jnp.float32),

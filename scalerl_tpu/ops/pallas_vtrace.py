@@ -137,6 +137,7 @@ def vtrace_from_importance_weights_pallas(
     )
     vs, pg = pl.pallas_call(
         kernel,
+        name="vtrace",
         out_shape=(
             jax.ShapeDtypeStruct((T, B), jnp.float32),
             jax.ShapeDtypeStruct((T, B), jnp.float32),

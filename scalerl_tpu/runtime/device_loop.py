@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from scalerl_tpu.agents.impala import ImpalaTrainState
 from scalerl_tpu.data.trajectory import Trajectory
 from scalerl_tpu.envs.jax_envs.base import JaxVecEnv
-from scalerl_tpu.runtime import dispatch, telemetry
+from scalerl_tpu.runtime import dispatch, telemetry, tracing
 from scalerl_tpu.runtime.dispatch import MetricsPipeline, get_metrics
 from scalerl_tpu.utils.profiling import step_marker
 
@@ -558,7 +558,7 @@ class DeviceActorLearnerLoop:
             with dispatch.steady_state_guard() if i > 0 else nullcontext():
                 # step_marker: per-chunk device-trace alignment (a cheap
                 # profiler annotation — a no-op unless a trace is active)
-                with step_marker(i):
+                with step_marker(i), tracing.span("loop.dispatch", kind="loop"):
                     key, sub = jax.random.split(key)
                     state, carry, m = self.train_chunk(state, carry, sub)
                 frames += frames_per_call
@@ -639,7 +639,7 @@ class DeviceActorLearnerLoop:
             # syncs raise; get_metrics' one explicit batched get passes
             with dispatch.steady_state_guard() if i > 0 else nullcontext():
                 # per-chunk trace step (no-op without an active trace)
-                with step_marker(i):
+                with step_marker(i), tracing.span("loop.dispatch", kind="loop"):
                     key, sub = jax.random.split(key)
                     state, carry, dev_metrics = self.train_chunk(state, carry, sub)
                 chunks_done += 1

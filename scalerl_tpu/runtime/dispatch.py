@@ -34,6 +34,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from scalerl_tpu.runtime import tracing
+from scalerl_tpu.utils import profiling  # noqa: F401  (installs the spans' profiler half)
+
 # Module-level seam: tests monkeypatch this to count host transfers.
 _device_get = jax.device_get
 
@@ -94,10 +97,12 @@ def get_metrics(metrics: Any) -> Any:
             stacked = jnp.stack(
                 [leaves[i].astype(jnp.float32).reshape(()) for i in idx]
             )
-            host = np.asarray(_device_get(stacked))
+            with tracing.span("dispatch.read", kind="dispatch"):
+                host = np.asarray(_device_get(stacked))
             fetched: List[Any] = [float(host[j]) for j in range(len(idx))]
         else:
-            host = _device_get([leaves[i] for i in idx])
+            with tracing.span("dispatch.read", kind="dispatch"):
+                host = _device_get([leaves[i] for i in idx])
             fetched = [
                 float(v) if getattr(v, "ndim", 1) == 0 else np.asarray(v)
                 for v in host

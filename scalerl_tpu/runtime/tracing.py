@@ -31,6 +31,15 @@ substrate, in the telemetry idiom:
   stamp ``time.monotonic()`` at the boundaries they already cross and emit
   the span after the fact, so tracing never adds a blocking call to a hot
   loop.
+- **Live spans on the profiler's clock** (:func:`span`) — the hot paths
+  (engine macro-step, learn step, fused chunk, round) open one context
+  manager per phase.  It always opens a profiler annotation named
+  ``"scalerl." + name`` through a hook (:func:`set_annotator`;
+  ``utils/profiling.py`` installs ``jax.profiler.TraceAnnotation``), so a
+  device trace carries the program's own phases on the profiler's clock,
+  and records the :class:`Span` too when the trace's root was sampled.
+  With the profiler stopped and sampling at 0 it is one annotation object
+  made and dropped.
 - **Per-host JSONL export** — when ``SCALERL_TRACE_DIR`` is set every
   finished span is appended (line-buffered) to
   ``spans_<host>.jsonl``, so a SIGTERM'd generation host loses at most the
@@ -608,6 +617,86 @@ def record_span(
     )
     span.end(t_end=t_end)
     return span
+
+
+# the profiler hook: a factory ``name -> context manager`` that opens a
+# host annotation in the device profiler's trace.  This module stays
+# jax-free, so ``utils/profiling.py`` (which imports jax already) installs
+# ``jax.profiler.TraceAnnotation`` here when it is imported; with nothing
+# installed a live span has no profiler half.
+_ANNOTATOR: Optional[Callable[[str], Any]] = None
+ANNOTATION_PREFIX = "scalerl."
+
+
+def set_annotator(factory: Optional[Callable[[str], Any]]) -> None:
+    global _ANNOTATOR
+    _ANNOTATOR = factory
+
+
+def get_annotator() -> Optional[Callable[[str], Any]]:
+    return _ANNOTATOR
+
+
+class _LiveSpan:
+    """What :func:`span` returns: one ``with`` block, two sinks."""
+
+    __slots__ = ("_name", "_kind", "_attrs", "_annotation", "_span")
+
+    def __init__(self, name: str, kind: str, attrs: Dict[str, Any]) -> None:
+        self._name = name
+        self._kind = kind
+        self._attrs = attrs
+        self._annotation = None
+        self._span = None
+
+    def __enter__(self) -> "_LiveSpan":
+        if _ANNOTATOR is not None:
+            self._annotation = _ANNOTATOR(ANNOTATION_PREFIX + self._name)
+            self._annotation.__enter__()
+        tracer = get_tracer()
+        parent = tracer.current_span()
+        if parent is None and tracer.sample_rate <= 0.0:
+            return self  # the hot loops' case: nothing else happens
+        # the rule start_span has: head decision at the root, children
+        # follow the span active on this thread (an unsampled root is
+        # active too, so that its children stay unsampled)
+        span = (
+            NOOP_SPAN
+            if parent is NOOP_SPAN
+            else tracer.start_span(
+                self._name, parent=parent, kind=self._kind, **self._attrs
+            )
+        )
+        tracer._push_active(span)
+        self._span = span
+        return self
+
+    def set(self, **attrs: Any) -> None:
+        """Attributes known only at the block's end (host values only)."""
+        if self._span is not None and self._span.sampled:
+            self._span.attrs.update(attrs)
+
+    def __exit__(self, *exc: Any) -> None:
+        span = self._span
+        if span is not None:
+            get_tracer()._pop_active(span)
+            span.end()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+
+
+def span(name: str, kind: str = "", **attrs: Any) -> _LiveSpan:
+    """A live span around work as it happens::
+
+        with tracing.span("genrl.read", kind="genrl"):
+            host = _device_get(outputs)
+
+    Entering it opens the profiler annotation ``"scalerl." + name`` (a
+    no-op object unless the profiler runs) and, when this thread's trace
+    was sampled at its root, records the :class:`Span` as a child of
+    :func:`current_span`.  Host-side stamps only: never force a device
+    value to open, annotate or close a span (graftlint JG001)."""
+    return _LiveSpan(name, kind, attrs)
 
 
 def current_trace_id() -> Optional[str]:

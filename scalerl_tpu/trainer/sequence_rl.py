@@ -238,10 +238,13 @@ class SequenceRLTrainer:
             lengths = np.repeat(lengths, spp, axis=0)
         else:
             prompts, lengths = self.task.sample_prompts(B, self._rng)
-        result = self.engine.generate(prompts, lengths)
-        rewards = self.task.score(
-            prompts, lengths, result.response_tokens, result.response_len
-        )
+        with tracing.span("round.generate", kind="genrl") as gen_span:
+            result = self.engine.generate(prompts, lengths)
+            gen_span.set(decode_tokens=float(result.decode_tokens))
+        with tracing.span("round.score", kind="genrl"):
+            rewards = self.task.score(
+                prompts, lengths, result.response_tokens, result.response_len
+            )
         return result, rewards
 
     def _round_cohort(self):
@@ -274,35 +277,38 @@ class SequenceRLTrainer:
         their extras in the backlog — insert batches stay shape-stable)."""
         B = self.args.genrl_batch
         spp = self.args.samples_per_prompt
-        while len(self._completion_backlog) < B:
-            deficit = (
-                B
-                - len(self._completion_backlog)
-                - self.engine.live_lanes
-                - self.engine.pending
-            )
-            if deficit > 0:
-                # group sampling: one submit_group per distinct prompt
-                # fans out into spp lanes sharing the prompt KV
-                # copy-on-write (overshoot banks in the backlog)
-                n_groups = -(-deficit // spp)
-                prompts, lengths = self.task.sample_prompts(
-                    n_groups, self._rng
+        with tracing.span("round.generate", kind="genrl") as gen_span:
+            while len(self._completion_backlog) < B:
+                deficit = (
+                    B
+                    - len(self._completion_backlog)
+                    - self.engine.live_lanes
+                    - self.engine.pending
                 )
-                for i in range(n_groups):
-                    self.engine.submit_group(prompts[i], spp, lengths[i])
-            self._completion_backlog.extend(self.engine.step())
-        batch = self._completion_backlog[:B]
-        self._completion_backlog = self._completion_backlog[B:]
-        packed = pack_completions(
-            batch, self._prompt_pad, self._response_pad
-        )
-        rewards = self.task.score(
-            packed.prompts,
-            packed.prompt_len,
-            packed.response_tokens,
-            packed.response_len,
-        )
+                if deficit > 0:
+                    # group sampling: one submit_group per distinct prompt
+                    # fans out into spp lanes sharing the prompt KV
+                    # copy-on-write (overshoot banks in the backlog)
+                    n_groups = -(-deficit // spp)
+                    prompts, lengths = self.task.sample_prompts(
+                        n_groups, self._rng
+                    )
+                    for i in range(n_groups):
+                        self.engine.submit_group(prompts[i], spp, lengths[i])
+                self._completion_backlog.extend(self.engine.step())
+            batch = self._completion_backlog[:B]
+            self._completion_backlog = self._completion_backlog[B:]
+            packed = pack_completions(
+                batch, self._prompt_pad, self._response_pad
+            )
+            gen_span.set(decode_tokens=float(packed.decode_tokens))
+        with tracing.span("round.score", kind="genrl"):
+            rewards = self.task.score(
+                packed.prompts,
+                packed.prompt_len,
+                packed.response_tokens,
+                packed.response_len,
+            )
         if self.packing:
             pk = packed_rows_from_completions(
                 packed, rewards, self._pack_len
@@ -321,54 +327,41 @@ class SequenceRLTrainer:
 
     def train_round(self) -> Dict[str, float]:
         """One generate -> score -> insert -> sample -> learn round."""
-        # head-sampled per-round trace (SCALERL_TRACE_SAMPLE): monotonic
-        # stamps around work the round already does — tracing off is a
-        # handful of no-op calls, never a transfer (JG001 twin)
-        root = tracing.start_span("genrl.round", kind="genrl")
-        t_gen0 = time.monotonic()
-        fields, priorities, rewards, decode_tokens = (
-            self._round_continuous()
-            if self.continuous
-            else self._round_cohort()
-        )
-        t_add0 = time.monotonic()
-        with self._dispatch_guard():
-            self.replay = seq_add(self.replay, fields, (), priorities)
-            self._sample_key, sub = jax.random.split(self._sample_key)
-            batch, _core, _idx, weights = seq_sample(
-                self.replay,
-                sub,
-                self.args.genrl_sample_batch,
-                method=self._seq_method,
+        # one live span per phase (runtime/tracing.span): each is a profiler
+        # annotation, and a recorded span when the round was head-sampled
+        # (SCALERL_TRACE_SAMPLE); none forces a device value (JG001)
+        with tracing.span("genrl.round", kind="genrl") as root:
+            fields, priorities, rewards, decode_tokens = (
+                self._round_continuous()
+                if self.continuous
+                else self._round_cohort()
             )
-            batch = dict(batch)
-            batch["is_weight"] = weights
-            t_learn0 = time.monotonic()
-            metrics = self.agent.learn(batch)  # ONE batched transfer
-        if root.sampled:
-            t_learn1 = time.monotonic()
-            tracing.record_span(
-                "round.generate", parent=root, t_start=t_gen0, t_end=t_add0,
-                kind="genrl", decode_tokens=float(decode_tokens),
-            )
-            tracing.record_span(
-                "round.seq_add", parent=root, t_start=t_add0,
-                t_end=t_learn0, kind="genrl",
-            )
-            tracing.record_span(
-                "round.learn", parent=root, t_start=t_learn0,
-                t_end=t_learn1, kind="genrl",
-            )
-            root.end(step=self.learn_steps + 1)
-        self.learn_steps += 1
-        self._learn_meter.mark()
-        if self.learn_steps % self.args.genrl_push_every == 0:
-            # learner_step feeds the plane's gen -> step map, so staleness
-            # below reports the UNIFIED definition (learner steps behind
-            # the newest generation, docs/OBSERVABILITY.md)
-            self.engine.push_params(
-                self.agent.get_weights(), learner_step=self.learn_steps
-            )
+            with self._dispatch_guard():
+                with tracing.span("round.seq_add", kind="genrl"):
+                    self.replay = seq_add(self.replay, fields, (), priorities)
+                with tracing.span("round.sample", kind="genrl"):
+                    self._sample_key, sub = jax.random.split(self._sample_key)
+                    batch, _core, _idx, weights = seq_sample(
+                        self.replay,
+                        sub,
+                        self.args.genrl_sample_batch,
+                        method=self._seq_method,
+                    )
+                    batch = dict(batch)
+                    batch["is_weight"] = weights
+                with tracing.span("round.learn", kind="genrl"):
+                    metrics = self.agent.learn(batch)  # ONE batched transfer
+            self.learn_steps += 1
+            self._learn_meter.mark()
+            if self.learn_steps % self.args.genrl_push_every == 0:
+                # learner_step feeds the plane's gen -> step map, so
+                # staleness below reports the UNIFIED definition (learner
+                # steps behind the newest generation, docs/OBSERVABILITY.md)
+                with tracing.span("round.push", kind="genrl"):
+                    self.engine.push_params(
+                        self.agent.get_weights(), learner_step=self.learn_steps
+                    )
+            root.set(step=self.learn_steps)
         # staleness off the metric that already crossed the host boundary
         # inside the batched read — no extra transfer
         staleness = self.engine.staleness_steps(

@@ -17,7 +17,6 @@ _EXPORTS = {
     "LinearDecayScheduler": "scalerl_tpu.utils.schedulers",
     "MultiStepScheduler": "scalerl_tpu.utils.schedulers",
     "PiecewiseScheduler": "scalerl_tpu.utils.schedulers",
-    "annotate": "scalerl_tpu.utils.profiling",
     "maybe_trace": "scalerl_tpu.utils.profiling",
     "step_marker": "scalerl_tpu.utils.profiling",
     "trace": "scalerl_tpu.utils.profiling",

@@ -4,8 +4,11 @@ The TPU half of the observability story (SURVEY.md §5): the reference had
 only host timers (``scalerl/utils/profile.py``) — ported as
 ``utils.timers`` — with no device tracing at all.  Here ``trace()`` wraps
 ``jax.profiler.trace`` (XPlane/perfetto output for TensorBoard's profile
-plugin) and ``annotate()`` names host regions so queue waits and env
-stepping line up against device streams in the trace viewer.
+plugin).  Importing this module also gives ``runtime/tracing.py`` (which
+stays jax-free) its profiler half: every ``tracing.span`` then opens a
+``jax.profiler.TraceAnnotation`` named ``scalerl.<span>``, so the
+program's own phases line up against the device streams in any captured
+trace.  The annotation is a no-op object while no profiler runs.
 """
 
 from __future__ import annotations
@@ -14,6 +17,10 @@ import contextlib
 from typing import Iterator, Optional
 
 import jax
+
+from scalerl_tpu.runtime import tracing
+
+tracing.set_annotator(jax.profiler.TraceAnnotation)
 
 
 @contextlib.contextmanager
@@ -28,15 +35,6 @@ def trace(log_dir: str, create_perfetto_link: bool = False) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def annotate(name: str) -> "jax.profiler.TraceAnnotation":
-    """Name a host-side region so it shows up in the captured trace:
-
-        with annotate("drain_rollout_queue"):
-            batch, idxs = queue.get_batch(...)
-    """
-    return jax.profiler.TraceAnnotation(name)
 
 
 def step_marker(step: int) -> "jax.profiler.StepTraceAnnotation":

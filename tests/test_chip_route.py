@@ -220,7 +220,12 @@ def _tracked_files():
             p for p in REPO.rglob("*")
             if p.is_file() and not skip.intersection(p.relative_to(REPO).parts)
         ]
-    return [p for p in files if p.is_file() and p.name != "ISSUE.md"]
+    # the driver writes both: the issue text, and a ledger that quotes PR
+    # titles; no session can change either
+    return [
+        p for p in files
+        if p.is_file() and p.name not in ("ISSUE.md", "PERF_LEDGER.jsonl")
+    ]
 
 
 @pytest.mark.parametrize(

@@ -441,3 +441,125 @@ def test_disagg_untraced_path_stays_wire_clean(monkeypatch):
         assert tracing.TRACE_KEY not in s
         assert "_t_q" not in s
     assert tracing.get_tracer().finished() == []
+
+
+# ---------------------------------------------------------------------------
+# live spans: one call site, the profiler's annotation and the ring
+
+
+class _FakeAnnotation:
+    made = []
+
+    def __init__(self, name):
+        self.name, self.entered, self.exited = name, 0, 0
+        _FakeAnnotation.made.append(self)
+
+    def __enter__(self):
+        self.entered += 1
+        return self
+
+    def __exit__(self, *exc):
+        self.exited += 1
+
+
+@pytest.fixture
+def fake_annotator(monkeypatch):
+    _FakeAnnotation.made = []
+    monkeypatch.setattr(tracing, "_ANNOTATOR", _FakeAnnotation)
+    return _FakeAnnotation.made
+
+
+def test_span_with_sampling_off_is_one_annotation_and_nothing_else(monkeypatch, fake_annotator):
+    monkeypatch.delenv(tracing.ENV_SAMPLE, raising=False)
+    tracing.reset()
+    made_spans = []
+    monkeypatch.setattr(
+        tracing.Tracer, "start_span", lambda self, *a, **k: made_spans.append(a) or tracing.NOOP_SPAN
+    )
+    for _ in range(3):
+        with tracing.span("genrl.read", kind="genrl", lanes=4) as live:
+            assert tracing.get_tracer().current_span() is None
+            live.set(completed=1)  # attributes for a span nobody records: dropped
+    assert made_spans == []  # no Span, no id
+    assert tracing.get_tracer().finished() == []
+    snap = telemetry.get_registry().snapshot()
+    assert not any(k.startswith("trace.") for k in snap), snap  # no registry counter
+    # the factory ran exactly once per span, under the program's prefix
+    assert [(a.name, a.entered, a.exited) for a in fake_annotator] == [("scalerl.genrl.read", 1, 1)] * 3
+
+
+def test_span_without_an_annotator_is_a_plain_no_op(monkeypatch):
+    monkeypatch.delenv(tracing.ENV_SAMPLE, raising=False)
+    monkeypatch.setattr(tracing, "_ANNOTATOR", None)
+    tracing.reset()
+    with tracing.span("learn.step"):
+        pass
+    assert tracing.get_tracer().finished() == []
+    assert tracing.get_annotator() is None
+
+
+def test_sampled_spans_nest_under_the_active_span_and_carry_late_attrs(monkeypatch, fake_annotator):
+    _armed(monkeypatch)
+    with tracing.span("genrl.macro_step", kind="genrl") as step:
+        with tracing.span("genrl.admit", kind="genrl"):
+            assert tracing.current_trace_id() is not None
+        with tracing.span("genrl.read", kind="genrl"):
+            pass
+        step.set(completed=2)
+    recs = {r["name"]: r for r in tracing.get_tracer().finished()}
+    root = recs["genrl.macro_step"]
+    assert root["parent"] is None and root["attrs"] == {"completed": 2}
+    assert recs["genrl.admit"]["parent"] == recs["genrl.read"]["parent"] == root["span"]
+    assert {r["trace"] for r in recs.values()} == {root["trace"]}
+    assert tracing.get_tracer().current_span() is None
+    assert [a.name for a in fake_annotator] == [
+        "scalerl.genrl.macro_step", "scalerl.genrl.admit", "scalerl.genrl.read",
+    ]
+
+
+def test_children_of_an_unsampled_root_stay_unsampled(monkeypatch):
+    _armed(monkeypatch, rate="0.5")
+    tracer = tracing.get_tracer()
+    draws = iter([0.9, 0.1, 0.1])  # the first root loses the draw, the second wins it
+    monkeypatch.setattr(tracer._rng, "random", lambda: next(draws))
+    for _ in range(2):
+        with tracing.span("genrl.round"):
+            with tracing.span("round.learn"):
+                pass
+    names = [r["name"] for r in tracer.finished()]
+    assert names == ["round.learn", "genrl.round"]  # one sampled trace, whole
+    assert tracer.current_span() is None
+
+
+def test_a_span_closes_when_its_body_raises(monkeypatch, fake_annotator):
+    _armed(monkeypatch)
+    with pytest.raises(RuntimeError):
+        with tracing.span("learn.step", kind="learn"):
+            with tracing.span("learn.dispatch", kind="learn"):
+                raise RuntimeError("boom")
+    assert [r["name"] for r in tracing.get_tracer().finished()] == ["learn.dispatch", "learn.step"]
+    assert tracing.get_tracer().current_span() is None
+    assert [(a.entered, a.exited) for a in fake_annotator] == [(1, 1), (1, 1)]
+    # and with sampling off the annotation still closes
+    monkeypatch.delenv(tracing.ENV_SAMPLE)
+    tracing.reset()
+    with pytest.raises(RuntimeError):
+        with tracing.span("learn.step"):
+            raise RuntimeError("boom")
+    assert fake_annotator[-1].exited == 1
+
+
+def test_trace_report_charges_every_new_span_name():
+    """``tools/trace_report.py`` classifies every span the hot paths open:
+    the two blocking reads are waits, the rest compute."""
+    sys.path.insert(0, str(REPO_ROOT / "tools"))
+    import trace_report
+
+    waits = {"genrl.read", "dispatch.read"}
+    compute = {
+        "genrl.macro_step", "genrl.admit", "genrl.dispatch", "genrl.harvest", "genrl.push_params",
+        "seq.draft", "seq.verify", "learn.step", "learn.dispatch", "loop.dispatch",
+        "round.generate", "round.score", "round.seq_add", "round.sample", "round.learn", "round.push",
+    }
+    assert {trace_report.classify(n) for n in waits} == {"wait"}
+    assert {trace_report.classify(n) for n in compute} == {"compute"}

@@ -86,18 +86,31 @@ def test_target_updates():
 
 @pytest.mark.slow  # ~15 s profiler e2e; annotation plumbing has no tier-1-critical
 # correctness surface (ISSUE 19 tier-1 budget buy-back)
-def test_profiling_trace_and_annotate(tmp_path):
+def test_profiling_trace_and_span_hook(tmp_path):
+    """Importing ``utils.profiling`` installs the profiler half of
+    ``tracing.span``: a span opened under a capture lands in the trace
+    file as a host annotation ``scalerl.<name>``."""
+    import jax
     import jax.numpy as jnp
 
-    from scalerl_tpu.utils.profiling import annotate, maybe_trace, step_marker
+    from scalerl_tpu.runtime import tracing
+    from scalerl_tpu.utils.profiling import maybe_trace, step_marker
 
+    assert tracing.get_annotator() is jax.profiler.TraceAnnotation
     with maybe_trace(str(tmp_path / "prof")):
-        with annotate("host_region"):
+        with tracing.span("host_region"):
             x = jnp.ones((8, 8)) @ jnp.ones((8, 8))
         with step_marker(0):
             x = (x * 2).sum()
     assert float(x) == 1024.0
-    assert any((tmp_path / "prof").rglob("*"))  # trace files written
+    (xplane,) = (tmp_path / "prof").rglob("*.xplane.pb")  # trace file written
+    names = {
+        ev.name
+        for plane in jax.profiler.ProfileData.from_file(str(xplane)).planes
+        for line in plane.lines
+        for ev in line.events
+    }
+    assert "scalerl.host_region" in names
     with maybe_trace(None):  # disabled path is a clean no-op
         pass
 

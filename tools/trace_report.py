@@ -71,9 +71,24 @@ EDGE_CLASSES = {
     "task.episode": "compute",
     "genrl.macro_step": "compute",
     "genrl.generate_round": "compute",
+    "genrl.admit": "compute",
+    "genrl.dispatch": "compute",
+    "genrl.harvest": "compute",
+    "genrl.push_params": "compute",
+    "seq.draft": "compute",
+    "seq.verify": "compute",
     "round.generate": "compute",
+    "round.score": "compute",
     "round.seq_add": "compute",
+    "round.sample": "compute",
     "round.learn": "compute",
+    "round.push": "compute",
+    "learn.step": "compute",
+    "learn.dispatch": "compute",
+    "loop.dispatch": "compute",
+    # the host blocked on the device: one batched read each
+    "genrl.read": "wait",
+    "dispatch.read": "wait",
     "seq.upload": "wire",
     "snapshot.fetch": "wire",
     "snapshot_publish": "wire",
