@@ -23,7 +23,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from scalerl_tpu.ops.pallas_attention import flash_attention
-from scalerl_tpu.ops.pallas_paged_attention import paged_attention_reference
+from scalerl_tpu.ops.pallas_paged_attention import (
+    gather_pages,
+    paged_attention_reference,
+)
 from scalerl_tpu.ops.ring_attention import full_attention
 
 # (q, k, v) -> attention output, all [B, T, H, D]
@@ -70,8 +73,14 @@ def init_kv_cache(
 class PagedKVCache(NamedTuple):
     """Block-paged key/value cache: a fixed pool shared by every lane.
 
-    ``k``/``v``: one ``[num_pages, page_size, H, D]`` pool per transformer
-    block.  Lanes own *pages*, not contiguous rows: a host-side allocator
+    ``k``/``v``: one lane-dense ``[num_pages, page_size, H*D]`` pool per
+    transformer block: a token's heads lie side by side on the minor axis,
+    so the TPU runtime stores the pool row-major and the paged-decode
+    kernel reads it in place (``ops/pallas_paged_attention.py``; a
+    ``[.., H, D]`` pool with ``D < 128`` was stored page-index-minor and
+    copied whole, twice, by every decode program).  Consumers split the
+    heads out of the rows they gathered, never out of the pool.  Lanes own
+    *pages*, not contiguous rows: a host-side allocator
     (``genrl/paging.py``) hands each lane an ordered page list, and the
     decode path writes token ``p`` of a lane into page
     ``table[p // page_size]`` at slot ``p % page_size`` — so KV memory
@@ -93,8 +102,8 @@ def init_paged_kv_cache(
     head_dim: int,
     dtype=jnp.float32,
 ) -> PagedKVCache:
-    """Zeroed page pools (page 0 = the never-read null page)."""
-    shape = (num_pages, page_size, num_heads, head_dim)
+    """Zeroed lane-dense page pools (page 0 = the never-read null page)."""
+    shape = (num_pages, page_size, num_heads * head_dim)
     return PagedKVCache(
         k=tuple(jnp.zeros(shape, dtype) for _ in range(num_layers)),
         v=tuple(jnp.zeros(shape, dtype) for _ in range(num_layers)),
@@ -277,21 +286,22 @@ class _Block(nn.Module):
         new_cache = None
         if paged_cache is not None:
             kp, vp = paged_cache
-            # flat single-axis scatter (page_id * page_size + offset): the
-            # reshape is a bitcast and XLA:CPU lowers 1-level row scatters
-            # measurably faster than the 2-level fancy-index form
-            N, ps = kp.shape[0], kp.shape[1]
+            # flat single-axis scatter (page_id * page_size + offset) of
+            # H*D rows into the lane-dense pool: the reshape is a bitcast
+            # and XLA:CPU lowers 1-level row scatters measurably faster
+            # than the 2-level fancy-index form
+            N, ps, width = kp.shape
             flat_idx = (page_ids * ps + page_offsets).reshape(B * T)
             kp = (
-                kp.reshape(N * ps, *kp.shape[2:])
+                kp.reshape(N * ps, width)
                 .at[flat_idx]
-                .set(k.astype(kp.dtype).reshape(B * T, *k.shape[2:]))
+                .set(k.astype(kp.dtype).reshape(B * T, width))
                 .reshape(kp.shape)
             )
             vp = (
-                vp.reshape(N * ps, *vp.shape[2:])
+                vp.reshape(N * ps, width)
                 .at[flat_idx]
-                .set(v.astype(vp.dtype).reshape(B * T, *v.shape[2:]))
+                .set(v.astype(vp.dtype).reshape(B * T, width))
                 .reshape(vp.shape)
             )
             if page_table is not None and prefix_starts is not None:
@@ -303,15 +313,12 @@ class _Block(nn.Module):
                 # this exact path with T = draft bucket + 1: slot j is
                 # position prefix_starts + j, the pos <= qpos mask keeps
                 # rejected slots' K/V (garbage past the cursor) out of
-                # every query, so draft rollback never touches the device
-                M = page_table.shape[1]
-                gidx = (
-                    page_table[:, :, None] * ps
-                    + jnp.arange(ps)[None, None, :]
-                ).reshape(B, M * ps)
-                kg = kp.reshape(N * ps, *kp.shape[2:])[gidx]
-                vg = vp.reshape(N * ps, *vp.shape[2:])[gidx]
-                pos = jnp.arange(M * ps)[None, None, :]
+                # every query, so draft rollback never touches the device.
+                # The heads are split out of the gathered rows: reshaping
+                # the pool itself would bring its relayout copy back
+                kg = gather_pages(kp, page_table, self.num_heads)
+                vg = gather_pages(vp, page_table, self.num_heads)
+                pos = jnp.arange(kg.shape[1])[None, None, :]
                 qpos = (
                     prefix_starts[:, None] + jnp.arange(T)[None, :]
                 )[:, :, None]

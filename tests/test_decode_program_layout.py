@@ -1,0 +1,167 @@
+"""The engine's five programs compiled for a described ``v5e:2x2`` must read
+the KV pools in place (ISSUE 24).
+
+A pool whose minor dimension is under 128 lanes is stored page-index-minor
+by the TPU runtime, while Mosaic takes its operands row-major: every decode
+macro-step then transposed each whole pool into a padded temporary and back
+(96 copies, 41% of the device's time and 6.4 GB of temporaries at
+gpt2-medium; PERF.md, PR 24).  Nothing about that shows on the CPU, where
+these tests otherwise run, so this file compiles a small engine's programs
+with the chip's own compiler and reads the compiled text: no chip is
+needed, nothing runs.
+
+The topology is described inside a module-scoped fixture, never at import;
+the benchmark's ``tests/benchmark/test_benchmark_real_shape_compiles.py``
+does the same in another xdist worker, which the tier-1 command allows
+with ``ALLOW_MULTIPLE_LIBTPU_LOAD=1``.  Where no topology can be described
+the file skips.
+"""
+
+import functools
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from scalerl_tpu.genrl.continuous import ContinuousConfig, ContinuousEngine
+from scalerl_tpu.models.transformer import TransformerPolicy
+from scalerl_tpu.ops.pallas_paged_attention import paged_decode_attention
+
+LAYERS, HEADS, HEAD_DIM, LANES, PAGE, PAGES = 2, 16, 64, 4, 8, 301
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever stops the description
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip: keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def engine():
+    """2 layers of gpt2-medium's attention geometry (16 heads of 64) over
+    301 pages of 8; the compiled kernel is pinned behind the attention seam
+    because ``auto`` resolves to the XLA gather on this CPU backend."""
+    vocab = 128
+    model = TransformerPolicy(
+        num_actions=vocab, vocab_size=vocab, d_model=HEADS * HEAD_DIM,
+        num_heads=HEADS, num_layers=LAYERS, mlp_ratio=1, max_len=256,
+        paged_attn_fn=functools.partial(paged_decode_attention, interpret=False),
+    )
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
+    return ContinuousEngine(
+        model, params,
+        ContinuousConfig(
+            vocab_size=vocab, max_prompt_len=64, max_new_tokens=64,
+            lanes=LANES, page_size=PAGE, num_pages=PAGES, steps_per_macro=2,
+            spec_k=2,
+        ),
+        iter_mode="scan",
+    )
+
+
+def _described(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.result_type(x), sharding=sharding),
+        tree,
+    )
+
+
+def _compiled_text(eng, one_chip, fn, *, params, extra):
+    """``fn`` lowered on the engine's own state, described on the chip."""
+    state = _described(
+        (eng._pools, eng._logits_st, eng._value_st, eng._cl, eng._done, eng._resp), one_chip
+    )
+    head = (_described(eng._snapshot_params()[0], one_chip),) if params else ()
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    tail = [
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip) if s == "key" else i32(*s)
+        for s in extra
+    ]
+    return fn.lower(*head, *state, *tail).compile().as_text()
+
+
+def _elements(dims):
+    return int(np.prod([int(d) for d in dims.split(",")]))
+
+
+def _assert_pools_read_in_place(text):
+    """No ``copy`` or ``transpose`` the size of a pool, and every pool the
+    program names is row-major on whole ``(8, 128)`` tiles.  Pools this
+    small the compiler may prefetch into another memory space and write
+    back (``copy-start``, the layout unchanged but for ``S(n)``): that is
+    its own business and does not happen at a real pool's size."""
+    pool = PAGES * PAGE * HEADS * HEAD_DIM
+    moved = [
+        line.strip()[:160]
+        for line in text.splitlines()
+        for m in [re.search(r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(", line)]
+        if m and _elements(m.group(1)) >= pool
+    ]
+    assert not moved, f"{len(moved)} whole-pool relayouts, the first: {moved[0]}"
+    layouts = set(re.findall(rf"f32\[{PAGES},[\d,]+\]\{{[^}}]*\}}", text))
+    assert layouts, "the program names no pool"
+    wrong = {l for l in layouts if not re.search(r"\{2,1,0(:T\(8,128\)(S\(\d\))?)?\}$", l)}
+    assert not wrong, f"pools not row-major in place: {wrong}"
+
+
+def test_decode_macro_step_reads_the_pools_in_place(engine, one_chip):
+    M = engine._table.shape[1]
+    text = _compiled_text(
+        engine, one_chip, engine._decode_fn, params=True, extra=[(LANES, M), "key"]
+    )
+    assert text.count("tpu_custom_call") >= LAYERS
+    _assert_pools_read_in_place(text)
+    # the donated pools come back as themselves: output i aliases the
+    # parameter that follows the model's own leaves
+    leaves = len(jax.tree_util.tree_leaves(engine._snapshot_params()[0]))
+    header = text[text.index("input_output_alias={"):].split("\n", 1)[0]
+    aliased = {
+        int(out): int(param)
+        for out, param in re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
+    }
+    for i in range(2 * LAYERS):
+        assert aliased.get(i) == leaves + i, (i, aliased)
+
+
+@pytest.mark.parametrize("program", ["local_prefill", "tail_prefill", "fork", "verify"])
+def test_other_programs_leave_the_pools_in_place(engine, one_chip, program):
+    M = engine._table.shape[1]
+    A, P = 2, 64
+    if program == "local_prefill":
+        fn = engine._prefill_fn(("local", P, A))
+        extra = [(A, P), (A,), (A,), (A, P), (A, P)]
+    elif program == "tail_prefill":
+        fn = engine._prefill_fn(("prefix", P, A))
+        extra = [(A, P), (A,), (A,), (A, P), (A, P), (A, M), (A,)]
+    elif program == "fork":
+        fn, extra = engine._fork_fn(A), [(A,)] * 4
+    else:
+        k = 2
+        fn = engine._build_verify(k)
+        extra = [(LANES, k), (LANES,), (LANES, k + 1), (LANES, k + 1), (LANES, M), (LANES,), "key"]
+    text = _compiled_text(engine, one_chip, fn, params=program != "fork", extra=extra)
+    _assert_pools_read_in_place(text)

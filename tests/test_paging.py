@@ -4,6 +4,8 @@ transformer's paged prefill/decode paths against the dense oracle, and the
 quantized snapshot format.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -400,6 +402,85 @@ def test_paged_kernel_grad_free_by_construction():
         jax.grad(loss)(q)
 
 
+# the lane-dense pool (ISSUE 24): [N, ps, H*D], the engine's stored form.
+# H*D a multiple of 128 (two heads of 64: one whole vreg row) and not (two
+# heads of 8: the block spans the axis), page sizes 8 and 16
+
+_DENSE_GEOMETRIES = [(2, 64), (2, 8)]
+_DENSE_LAYOUTS = {
+    # lane tables over a 9-page pool, and the lanes' lengths in pages:
+    # the test scales them by the page size (minus a ragged tail)
+    "contiguous": ([[1, 2, 3], [4, 5, 6]], [3.0, 2.0]),
+    "fragmented": ([[7, 1, 5], [3, 8, 2]], [3.0, 3.0]),
+    # a CoW-forked group: prefix pages (1, 2) in every lane's table
+    "cow_shared": ([[1, 2, 4], [1, 2, 5], [1, 2, 6]], [2.5, 2.75, 2.25]),
+    # partially-filled last page + junk tail entries (null page 0)
+    "partial_last_page": ([[5, 3, 0], [6, 0, 0]], [1.75, 0.25]),
+}
+
+
+def _dense_pools(rng, ps, H, D, N=9):
+    k = jnp.asarray(rng.normal(size=(N, ps, H * D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(N, ps, H * D)), jnp.float32)
+    return k, v
+
+
+def _dense_case(layout, H, D, ps, seed=24):
+    rng = np.random.default_rng(seed)
+    kp, vp = _dense_pools(rng, ps, H, D)
+    table, pages = _DENSE_LAYOUTS[layout]
+    q = jnp.asarray(rng.normal(size=(len(table), 1, H, D)), jnp.float32)
+    t = jnp.asarray(table, jnp.int32)
+    ln = jnp.asarray([max(1, int(n * ps)) for n in pages], jnp.int32)
+    return q, kp, vp, t, ln
+
+
+@pytest.mark.parametrize("ps", [8, 16])
+@pytest.mark.parametrize("H,D", _DENSE_GEOMETRIES)
+@pytest.mark.parametrize("layout", sorted(_DENSE_LAYOUTS))
+def test_dense_pool_kernel_matches_reference(layout, H, D, ps):
+    """The kernel on one dense ``[ps, H*D]`` page a step (its per-head
+    segment reductions, its per-row softmax streams merged at the last
+    page) against the gather reference, and the reference against a plain
+    per-lane softmax over the lane's own tokens."""
+    q, kp, vp, t, ln = _dense_case(layout, H, D, ps)
+    ref = paged_attention_reference(q, kp, vp, t, ln)
+    ker = paged_decode_attention(q, kp, vp, t, ln, interpret=True)
+    assert ker.shape == q.shape
+    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=1e-5)
+    for b in range(q.shape[0]):
+        n = int(ln[b])
+        rows = np.concatenate([np.asarray(kp)[i] for i in np.asarray(t)[b]])
+        vals = np.concatenate([np.asarray(vp)[i] for i in np.asarray(t)[b]])
+        k_b = rows[:n].reshape(n, H, D)
+        v_b = vals[:n].reshape(n, H, D)
+        sc = np.einsum("hd,shd->hs", np.asarray(q)[b, 0], k_b) / np.sqrt(D)
+        pr = np.exp(sc - sc.max(-1, keepdims=True))
+        pr /= pr.sum(-1, keepdims=True)
+        np.testing.assert_allclose(
+            np.asarray(ref)[b, 0], np.einsum("hs,shd->hd", pr, v_b), atol=1e-5
+        )
+
+
+@pytest.mark.parametrize("ps", [8, 16])
+@pytest.mark.parametrize("H,D", _DENSE_GEOMETRIES)
+def test_four_d_entry_is_the_dense_one(H, D, ps):
+    """``[N, ps, H, D]`` pools (what tests and the benchmark's kernel
+    compile hold) enter through a reshape onto the same dense kernel and
+    reference: the same bits."""
+    q, kp, vp, t, ln = _dense_case("fragmented", H, D, ps)
+    kp4 = kp.reshape(kp.shape[0], ps, H, D)
+    vp4 = vp.reshape(vp.shape[0], ps, H, D)
+    np.testing.assert_array_equal(
+        np.asarray(paged_decode_attention(q, kp4, vp4, t, ln, interpret=True)),
+        np.asarray(paged_decode_attention(q, kp, vp, t, ln, interpret=True)),
+    )
+    np.testing.assert_array_equal(
+        np.asarray(paged_attention_reference(q, kp4, vp4, t, ln)),
+        np.asarray(paged_attention_reference(q, kp, vp, t, ln)),
+    )
+
+
 def test_resolve_paged_attn(monkeypatch):
     assert resolve_paged_attn("xla") == "xla"
     assert resolve_paged_attn("pallas") == "pallas"
@@ -490,6 +571,97 @@ def test_paged_prefill_and_decode_match_dense_forward():
             page_offsets=off,
             page_table=jnp.asarray(table),
             attn_lengths=jnp.asarray(cl + 1, jnp.int32),
+        )
+        np.testing.assert_allclose(
+            np.asarray(out.policy_logits)[:, 0],
+            np.asarray(full.policy_logits)[rows, P + t],
+            atol=1e-5,
+        )
+        cl += 1
+
+
+@pytest.mark.parametrize("ps", [8, 16])
+@pytest.mark.parametrize("attn", ["reference", "kernel"])
+def test_dense_pool_prefill_and_decode_match_dense_forward(attn, ps):
+    """Paged prefill then single-token decode through the lane-dense pool
+    (``[N, ps, H*D]``, rows of ``H*D`` scattered and gathered) against the
+    dense masked forward, with the XLA reference and with the kernel
+    behind the ``paged_attn_fn`` seam; lane 0's prompt fills a page, so
+    its decode crosses onto the next page of a fragmented table."""
+    from scalerl_tpu.models.transformer import sequence_positions
+
+    V, P, R = 11, ps, 3
+    fn = (
+        functools.partial(paged_decode_attention, interpret=True)
+        if attn == "kernel"
+        else paged_attention_reference
+    )
+    m = TransformerPolicy(
+        num_actions=V, vocab_size=V, d_model=16, num_heads=2,
+        num_layers=2, max_len=P + R, paged_attn_fn=fn,
+    )
+    B = 2
+    # lane 0 fills its prompt bucket; lane 1's context ends mid-page
+    lengths = np.array([P, P - 3], np.int32)
+    rng = np.random.default_rng(0)
+    toks = jnp.asarray(rng.integers(0, V, size=(B, P + R)), jnp.int32)
+    params = m.init(jax.random.PRNGKey(0), toks[:, :2])
+    S = P + R
+    left = np.zeros((B, S), np.int32)
+    for b in range(B):
+        n = lengths[b]
+        left[b, P - n : P] = np.asarray(toks)[b, :n]
+        left[b, P:] = np.asarray(toks)[b, P:]
+    lens_j = jnp.asarray(lengths)
+    full = m.apply(
+        params, jnp.asarray(left),
+        positions=sequence_positions(lens_j, P, S),
+        attn_mask=sequence_attention_mask(lens_j, P, S),
+    )
+
+    pools = init_paged_kv_cache(7, ps, 2, 2, 8)
+    assert pools.k[0].shape == (7, ps, 16) and len(pools.k) == 2
+    per_lane = -(-S // ps)
+    table = np.zeros((B, per_lane), np.int32)
+    table[0] = [5, 2, 6][:per_lane]
+    table[1] = [3, 1, 4][:per_lane]
+    pos = np.arange(P)
+    page_ids = np.zeros((B, P), np.int32)
+    offsets = np.zeros((B, P), np.int32)
+    for b in range(B):
+        n = lengths[b]
+        page_ids[b, :n] = table[b][pos[:n] // ps]
+        offsets[b, :n] = pos[:n] % ps
+    out, pools = m.apply(
+        params, toks[:, :P],
+        positions=jnp.broadcast_to(jnp.arange(P), (B, P)),
+        attn_mask=prompt_attention_mask(lens_j, P),
+        paged_cache=pools,
+        page_ids=jnp.asarray(page_ids),
+        page_offsets=jnp.asarray(offsets),
+    )
+    assert pools.k[0].shape == (7, ps, 16)
+    rows = np.arange(B)
+    np.testing.assert_allclose(
+        np.asarray(out.policy_logits)[rows, lengths - 1],
+        np.asarray(full.policy_logits)[rows, P - 1],
+        atol=1e-5,
+    )
+
+    @jax.jit
+    def step(pools, tok, cl, pid, off):
+        return m.apply(
+            params, tok, positions=cl[:, None], paged_cache=pools,
+            page_ids=pid, page_offsets=off, page_table=jnp.asarray(table),
+            attn_lengths=cl + 1,
+        )
+
+    cl = lengths.copy()
+    for t in range(R):
+        pid = np.asarray([table[b][cl[b] // ps] for b in range(B)], np.int32)
+        out, pools = step(
+            pools, toks[:, P + t][:, None], jnp.asarray(cl, jnp.int32),
+            jnp.asarray(pid)[:, None], jnp.asarray(cl % ps, jnp.int32)[:, None],
         )
         np.testing.assert_allclose(
             np.asarray(out.policy_logits)[:, 0],

@@ -460,13 +460,17 @@ def test_genrl_generation_round_on_tpu():
         # the chip_smoke shape: 64 lanes, 8 heads of 32, 8-token pages
         (64, 8, 32, 8, 32, jnp.float32),
         (64, 8, 32, 8, 32, jnp.bfloat16),
+        # the benchmark's rollout cell: gpt2-medium, 16 lanes, 2049 pages
+        (16, 16, 64, 8, 128, jnp.float32),
     ],
 )
 def test_paged_decode_attention_compiled(B, H, D, ps, M, dtype, precision, tol):
     """The continuous-batching decode kernel (ISSUE 11) compiled on the
-    chip: scalar-prefetch page-table indexing + online softmax over whole
-    ``[page, H, D]`` blocks, pinned to the XLA gather reference on-device
-    across a fragmented table with a partially-filled last page."""
+    chip through its dense entry, the engine's own: scalar-prefetch
+    page-table indexing + online softmax over whole lane-dense
+    ``[page, H*D]`` blocks, pinned to the XLA gather reference on-device
+    across a fragmented table with a partially-filled last page.  The 4-D
+    entry (tests hold such pools) must give the same bits."""
     from scalerl_tpu.ops.pallas_paged_attention import (
         paged_attention_reference,
         paged_decode_attention,
@@ -475,8 +479,8 @@ def test_paged_decode_attention_compiled(B, H, D, ps, M, dtype, precision, tol):
     N = B * M + 1
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
     q = _rand(k1, B, 1, H, D, dtype=dtype)
-    k_pages = _rand(k2, N, ps, H, D, dtype=dtype)
-    v_pages = _rand(k3, N, ps, H, D, dtype=dtype)
+    k_pages = _rand(k2, N, ps, H * D, dtype=dtype)
+    v_pages = _rand(k3, N, ps, H * D, dtype=dtype)
     rng = np.random.default_rng(7)
     # fragmented layout: every lane owns a random disjoint page set
     perm = rng.permutation(np.arange(1, N))[: B * M].reshape(B, M)
@@ -488,12 +492,17 @@ def test_paged_decode_attention_compiled(B, H, D, ps, M, dtype, precision, tol):
             q, k_pages, v_pages, table, lengths, interpret=False
         )
         ref = paged_attention_reference(q, k_pages, v_pages, table, lengths)
+        four_d = paged_decode_attention(
+            q, k_pages.reshape(N, ps, H, D), v_pages.reshape(N, ps, H, D),
+            table, lengths, interpret=False,
+        )
     if dtype == jnp.bfloat16:
         tol = 3e-2  # one bfloat16 rounding of the output
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=tol, rtol=tol,
     )
+    np.testing.assert_array_equal(np.asarray(four_d), np.asarray(out))
 
 
 def test_continuous_engine_macro_step_on_tpu():
