@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import optax
 from flax import struct
 
+from scalerl_tpu.models.routed_ffn import router_balance
 from scalerl_tpu.models.transformer import (
     TransformerPolicy,
     sequence_attention_mask,
@@ -63,6 +64,28 @@ def masked_mean(x: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(x * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
+def _forward_with_balance(model, params, tokens, real_tokens, **call):
+    """The learner's forward; for a routed-experts model also what its
+    routers did with the real tokens (``models/routed_ffn.py``
+    :func:`router_balance`), else None."""
+    if model.block.ffn != "experts":
+        return model.apply(params, tokens, **call), None
+    out, sown = model.apply(
+        params, tokens, mutable=["intermediates"], **call
+    )
+    return out, router_balance(sown["intermediates"], real_tokens)
+
+
+def _add_router_aux(total, metrics, balance, coef: float):
+    """``coef x E x sum_e f_e P_e`` joins the total; the metric dict
+    gains the term and the largest expert's load."""
+    if balance is None:
+        return total
+    metrics["moe_aux_loss"] = balance.aux_loss
+    metrics["moe_max_load"] = balance.max_load
+    return total + coef * balance.aux_loss
+
+
 def token_ppo_loss(
     params,
     ref_params,
@@ -73,6 +96,7 @@ def token_ppo_loss(
     entropy_cost: float,
     kl_cost: float,
     adv_norm: bool,
+    router_aux_coef: float = 0.0,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """PPO-clip over one ``[B, S]`` packed-sequence batch.
 
@@ -97,8 +121,12 @@ def token_ppo_loss(
 
     positions = sequence_positions(prompt_len, P, S)
     attn_mask = sequence_attention_mask(prompt_len, P, S)
-    out = model.apply(
-        params, tokens, positions=positions, attn_mask=attn_mask
+    # real tokens: the prompt behind its left pad, the response under its mask
+    cols = jnp.arange(S)[None, :]
+    real = (cols >= P - prompt_len[:, None]) & (cols < P)
+    real = real.at[:, P:].set(mask > 0)
+    out, balance = _forward_with_balance(
+        model, params, tokens, real, positions=positions, attn_mask=attn_mask
     )
     # token at absolute position p is predicted by the output at p-1:
     # response tokens occupy [P, S) -> predicting slice [P-1, S-1)
@@ -159,6 +187,7 @@ def token_ppo_loss(
         kl_term = kl_cost * masked_mean(kl, w_mask)
         total = total + kl_term
         metrics["kl_ref"] = masked_mean(kl, mask)
+    total = _add_router_aux(total, metrics, balance, router_aux_coef)
     metrics["total_loss"] = total
     metrics = {
         k: v if k == "total_loss" else jax.lax.stop_gradient(v)
@@ -177,6 +206,7 @@ def token_ppo_packed_loss(
     entropy_cost: float,
     kl_cost: float,
     adv_norm: bool,
+    router_aux_coef: float = 0.0,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """PPO-clip over PACKED learner rows — the pad-free twin of
     :func:`token_ppo_loss`.
@@ -203,8 +233,8 @@ def token_ppo_packed_loss(
         batch["mask"] if seq_w is None else batch["mask"] * seq_w[:, None]
     )
 
-    out = model.apply(
-        params, tokens, positions=positions, segment_ids=seg
+    out, balance = _forward_with_balance(
+        model, params, tokens, seg > 0, positions=positions, segment_ids=seg
     )
     # output at row offset t-1 predicts the token at offset t
     pred_logits = out.policy_logits[:, :-1]  # [N, S-1, V]
@@ -274,6 +304,7 @@ def token_ppo_packed_loss(
         kl_term = kl_cost * masked_mean(kl, w_mask)
         total = total + kl_term
         metrics["kl_ref"] = masked_mean(kl, mask)
+    total = _add_router_aux(total, metrics, balance, router_aux_coef)
     metrics["total_loss"] = total
     metrics = {
         k: v if k == "total_loss" else jax.lax.stop_gradient(v)
@@ -313,6 +344,7 @@ def make_token_ppo_learn_fn(
             entropy_cost=args.entropy_cost,
             kl_cost=args.kl_cost,
             adv_norm=args.adv_norm,
+            router_aux_coef=getattr(args, "router_aux_loss_coef", 0.0),
         )
         updates, opt_state = optimizer.update(
             grads, state.opt_state, state.params

@@ -743,6 +743,23 @@ class GenRLArguments(RLArguments):
     max_new_tokens: int = 4
     eos_token: int = -1  # < 0: fixed-length responses (no early stop)
 
+    # Block family of the token model (models/transformer.py BlockSpec):
+    # "gpt2" = LayerNorm, learned positions, GELU MLP (the default);
+    # "olmoe" = RMSNorm, rotary positions, RMSNorm on q and k, and in
+    # place of the MLP a router over ``moe_experts`` SwiGLU experts of
+    # width ``moe_hidden`` with the ``moe_experts_per_token`` most
+    # probable kept, dropless.  The sizes below are read by the families
+    # that have them; ``head_dim`` 0 means d_model // n_heads.
+    block_family: str = "gpt2"
+    head_dim: int = 0
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    moe_experts_per_token: int = 2
+    moe_norm_topk_prob: bool = False
+    # weight of the router's load-balancing loss in the learner's total
+    # (agents/token_ppo.py); only a routed family has the term
+    router_aux_loss_coef: float = 0.01
+
     # Sampling (the behavior distribution — stored logprobs are under
     # EXACTLY this distribution, temperature and top-k included).
     temperature: float = 1.0
@@ -861,6 +878,15 @@ class GenRLArguments(RLArguments):
             raise ValueError(
                 f"temperature must be >= 0 (0 = greedy), got "
                 f"{self.temperature}"
+            )
+        if self.block_family not in ("gpt2", "olmoe"):
+            raise ValueError(
+                f"block_family must be gpt2 | olmoe, got {self.block_family!r}"
+            )
+        if self.head_dim < 0 or self.router_aux_loss_coef < 0:
+            raise ValueError(
+                "head_dim and router_aux_loss_coef must be >= 0, got "
+                f"{self.head_dim}/{self.router_aux_loss_coef}"
             )
         if not 0.0 < self.clip_range < 1.0:
             raise ValueError(

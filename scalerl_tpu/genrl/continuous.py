@@ -106,6 +106,7 @@ from scalerl_tpu.genrl.engine import (
 from scalerl_tpu.genrl.drafter import NgramDrafter
 from scalerl_tpu.genrl.paging import PageAllocator, rewind_pages
 from scalerl_tpu.genrl.prefix_cache import PrefixCache
+from scalerl_tpu.models.routed_ffn import router_balance
 from scalerl_tpu.models.transformer import (
     PagedKVCache,
     TransformerPolicy,
@@ -334,7 +335,15 @@ class ContinuousEngine(ParamSnapshotPlane):
             )
         )
         self._admit_buckets = default_buckets(L)
-        head_dim = model.d_model // model.num_heads
+        head_dim = model.head_dim
+        # a routed-experts model: the decode program also returns each
+        # substep's per-expert token counts (live lanes, every layer)
+        self._routed = model.block.ffn == "experts"
+        self._expert_tokens = np.zeros(
+            (model.num_layers, model.block.num_experts), np.int64
+        )
+        self._expert_hits = 0  # experts that received a token, summed
+        self._expert_substeps = 0  # over this many (substep, layer) pairs
         # device state: pools + per-lane decode carry (donated through
         # every program; the host rebinds after each dispatch)
         self._pools = init_paged_kv_cache(
@@ -932,6 +941,7 @@ class ContinuousEngine(ParamSnapshotPlane):
         steps = cfg.steps_per_macro
         budget = self._response_budget
         use_scan = self.iter_mode == "scan"
+        routed = self._routed
 
         def substep(params, table, carry, _t):
             pools, logits, value, cl, done, resp, key = carry
@@ -961,9 +971,7 @@ class ContinuousEngine(ParamSnapshotPlane):
             page_idx = jnp.where(alive, page_idx, 0)
             offs = jnp.where(alive, cl % ps, 0)
             att_len = jnp.where(alive, cl + 1, 1)
-            out, pools = model.apply(
-                params,
-                token[:, None].astype(jnp.int32),
+            call = dict(
                 positions=cl[:, None],
                 paged_cache=pools,
                 page_ids=page_idx[:, None],
@@ -971,6 +979,21 @@ class ContinuousEngine(ParamSnapshotPlane):
                 page_table=table,
                 attn_lengths=att_len,
             )
+            feed = token[:, None].astype(jnp.int32)
+            if routed:
+                # the live lanes' per-expert token counts of every layer
+                # ride out with the substep's tokens: one more row of the
+                # macro-step's one batched read
+                (out, pools), sown = model.apply(
+                    params, feed, mutable=["intermediates"], **call
+                )
+                out_t += (
+                    router_balance(
+                        sown["intermediates"], alive[:, None]
+                    ).counts,
+                )
+            else:
+                out, pools = model.apply(params, feed, **call)
             cl2 = cl + alive.astype(jnp.int32)
             new_carry = (
                 pools,
@@ -994,17 +1017,19 @@ class ContinuousEngine(ParamSnapshotPlane):
                     jnp.arange(steps),
                 )
                 toks, logps, values, alive = (
-                    jnp.swapaxes(o, 0, 1) for o in outs
+                    jnp.swapaxes(o, 0, 1) for o in outs[:4]
                 )
             else:
                 cols = []
                 for t in range(steps):
                     carry, out_t = substep(params, table, carry, t)
                     cols.append(out_t)
-                toks = jnp.stack([c[0] for c in cols], axis=1)
-                logps = jnp.stack([c[1] for c in cols], axis=1)
-                values = jnp.stack([c[2] for c in cols], axis=1)
-                alive = jnp.stack([c[3] for c in cols], axis=1)
+                outs = tuple(
+                    jnp.stack([c[i] for c in cols]) for i in range(len(cols[0]))
+                )
+                toks, logps, values, alive = (
+                    jnp.swapaxes(o, 0, 1) for o in outs[:4]
+                )
             pools, logits_st, value_st, cl, done, resp, _key = carry
             outputs = {
                 "tokens": toks.astype(jnp.int32),
@@ -1015,6 +1040,8 @@ class ContinuousEngine(ParamSnapshotPlane):
                 "done": done,
                 "resp": resp,
             }
+            if routed:
+                outputs["expert_counts"] = outs[4]  # [steps, layers, E]
             return pools, logits_st, value_st, cl, done, resp, outputs
 
         return jax.jit(decode, donate_argnums=(1, 2, 3, 4, 5, 6))
@@ -1365,6 +1392,8 @@ class ContinuousEngine(ParamSnapshotPlane):
                 # ... and ONE explicit batched device->host read
                 host = _device_get(outputs)
             completions.extend(self._harvest(host, macro_idx))
+            if self._routed:
+                self._note_expert_counts(host["expert_counts"])
             if dispatched:
                 break  # steady state: exactly one read per step
         step_span.set(
@@ -1576,6 +1605,16 @@ class ContinuousEngine(ParamSnapshotPlane):
             return None
         return (self._spec_draft_s, self._spec_verify_s)
 
+    def _note_expert_counts(self, counts: np.ndarray) -> None:
+        """Fold one macro-step's ``[substeps, layers, E]`` expert token
+        counts into the lifetime counters ``stats()`` exposes.  A substep
+        in which no lane was alive routed nothing and is not counted."""
+        counts = np.asarray(counts, np.int64)
+        live = counts.sum(axis=(1, 2)) > 0
+        self._expert_tokens += counts.sum(axis=0)
+        self._expert_hits += int((counts[live] > 0).sum())
+        self._expert_substeps += int(live.sum()) * counts.shape[1]
+
     def stats(self) -> Dict[str, Any]:
         """Engine-lifetime counters, batched from host state that already
         crossed the device boundary — reading this never adds a
@@ -1594,6 +1633,13 @@ class ContinuousEngine(ParamSnapshotPlane):
             "spec_acceptance_rate": self.spec_acceptance_rate,
             "spec_draft_s": self._spec_draft_s,
             "spec_verify_s": self._spec_verify_s,
+            # routed-experts models only (zeros otherwise): tokens each
+            # expert of each layer received from live decode lanes, the
+            # number of (substep, layer, expert) cells that received one,
+            # and the number of (substep, layer) pairs that could have
+            "expert_tokens": self._expert_tokens.copy(),
+            "expert_hits": self._expert_hits,
+            "expert_substeps": self._expert_substeps,
         }
 
     def _harvest(

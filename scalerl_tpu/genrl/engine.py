@@ -228,7 +228,7 @@ class GenerationEngine(ParamSnapshotPlane):
         model = self.model
         cfg = self.config
         S = P + R
-        head_dim = model.d_model // model.num_heads
+        head_dim = model.head_dim
         use_scan = self.iter_mode == "scan"
 
         def step(params, lengths, carry, t):
@@ -333,7 +333,7 @@ class GenerationEngine(ParamSnapshotPlane):
         into one program, so the split timing needs this twin)."""
         model = self.model
         S = P + R
-        head_dim = model.d_model // model.num_heads
+        head_dim = model.head_dim
 
         def prefill(params, tokens, lengths):
             B = tokens.shape[0]

@@ -1,0 +1,174 @@
+"""Dropless token-choice routed SwiGLU experts for the token model.
+
+The FFN of the ``olmoe`` block family (``models/transformer.py``
+:class:`BlockSpec` ``ffn="experts"``): a linear router scores every token
+against ``E`` experts, a float32 softmax over all ``E`` turns the scores
+into probabilities, the ``k`` largest are kept (with their probabilities
+as they are, or renormalised under ``norm_topk_prob``), and the token's
+output is the probability-weighted sum of those ``k`` experts' gated MLPs
+``(silu(h Wg) * (h Wu)) Wd``.  Exact at every token count: no capacity,
+no dropped token, no ``[tokens, experts, capacity]`` tensor
+(``models/moe.py`` is the capacity-dropping top-1 Switch layer of the
+classic policies; nothing here builds on it).
+
+One module serves prefill, decode, verify and the learner, in one of two
+forms chosen by the token count the trace sees, because the two regimes
+have opposite bounds:
+
+- **streamed** (up to ``STREAMED_MAX_TOKENS``: a decode substep, one
+  prompt's prefill): every expert's bank is multiplied against every token
+  and the combine weights zero what was not picked.  ``lanes x k`` picks
+  hit most experts anyway, so the step is bound by reading the banks once;
+  the dense form reads them once, in order, with no sort, gather or
+  scatter in the substep loop.
+- **sorted** (more tokens: batched prefill, the learner): the ``tokens x k``
+  assignments are sorted by expert and three ``lax.ragged_dot``s (which
+  XLA:TPU compiles to a grouped matmul) do ``k / E`` of the dense form's
+  arithmetic.
+
+Per call the module also ``sow``s the router's probabilities and picks
+into the ``intermediates`` collection, from which :func:`router_balance`
+computes the per-expert token counts, the load-balancing loss and the
+largest expert's load over the tokens a caller's mask names: a caller
+that wants them applies the model with ``mutable=["intermediates"]``,
+every other call pays nothing.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Mapping, NamedTuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+# Token count at or below which the streamed form runs.  Measured on a
+# v5e at OLMoE's widths (64 experts of 2048 x 1024, 8 a token, bf16;
+# PERF.md, PR 25): the streamed form takes 1.15-1.24 ms a layer from 8 to
+# 256 tokens (reading the banks once is 0.98 ms) and 2.2 ms at 512, the
+# sorted form 1.6 ms at 32 tokens, 2.6-3.1 ms from 64 to 512; at 2,048 it
+# is 8.8 against 5.6 ms, and forward with backward 24.1 against 20.7.
+STREAMED_MAX_TOKENS = 512
+
+
+def _bank_init():
+    # lecun-normal over a [E, in, out] bank: the fan-in is the middle axis
+    return nn.initializers.variance_scaling(
+        1.0, "fan_in", "truncated_normal", in_axis=1, out_axis=2, batch_axis=0
+    )
+
+
+def _streamed(x, top_p, top_i, w_gate, w_up, w_down):
+    E = w_gate.shape[0]
+    f32 = jnp.float32
+    # [N, E] combine weights: a pick's probability at its expert, else 0
+    combine = jnp.sum(
+        jax.nn.one_hot(top_i, E, dtype=f32) * top_p[..., None], axis=1
+    )
+    g = jnp.einsum("nd,edf->enf", x, w_gate, preferred_element_type=f32)
+    u = jnp.einsum("nd,edf->enf", x, w_up, preferred_element_type=f32)
+    a = jax.nn.silu(g) * u * combine.T[:, :, None]
+    # one contraction over (expert, width): the experts' outputs are
+    # summed in the matmul's float32 accumulator
+    return jnp.einsum(
+        "enf,efd->nd", a.astype(x.dtype), w_down, preferred_element_type=f32
+    )
+
+
+def _sorted(x, top_p, top_i, w_gate, w_up, w_down):
+    N, k = top_i.shape
+    E = w_gate.shape[0]
+    f32 = jnp.float32
+    expert = top_i.reshape(N * k)
+    order = jnp.argsort(expert)  # stable: assignments grouped by expert
+    sizes = jnp.zeros((E,), jnp.int32).at[expert].add(1)
+    xs = x[order // k]
+    g = lax.ragged_dot(xs, w_gate, sizes, preferred_element_type=f32)
+    u = lax.ragged_dot(xs, w_up, sizes, preferred_element_type=f32)
+    a = jax.nn.silu(g) * u * top_p.reshape(N * k)[order][:, None]
+    ys = lax.ragged_dot(
+        a.astype(x.dtype), w_down, sizes, preferred_element_type=f32
+    )
+    # back to token order by a gather, then the k picks summed
+    return jnp.sum(ys[jnp.argsort(order)].reshape(N, k, -1), axis=1)
+
+
+class RoutedExperts(nn.Module):
+    """``[B, T, d] -> [B, T, d]``: router, exact top-k, SwiGLU experts."""
+
+    num_experts: int
+    experts_per_token: int
+    width: int
+    norm_topk_prob: bool = False
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
+        B, T, d = h.shape
+        E, k, f = self.num_experts, self.experts_per_token, self.width
+        router = self.param(
+            "router", nn.initializers.lecun_normal(), (d, E), self.param_dtype
+        )
+        w_gate = self.param("w_gate", _bank_init(), (E, d, f), self.param_dtype)
+        w_up = self.param("w_up", _bank_init(), (E, d, f), self.param_dtype)
+        w_down = self.param("w_down", _bank_init(), (E, f, d), self.param_dtype)
+        x = h.reshape(B * T, d).astype(self.dtype)
+        logits = jnp.dot(
+            x, router.astype(self.dtype), preferred_element_type=jnp.float32
+        )
+        probs = jax.nn.softmax(logits, axis=-1)  # float32, over all E
+        top_p, top_i = lax.top_k(probs, k)
+        if self.norm_topk_prob:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        self.sow("intermediates", "router_probs", probs.reshape(B, T, E))
+        self.sow("intermediates", "expert_ids", top_i.reshape(B, T, k))
+        banks = tuple(w.astype(self.dtype) for w in (w_gate, w_up, w_down))
+        form = _streamed if B * T <= STREAMED_MAX_TOKENS else _sorted
+        y = form(x, top_p, top_i, *banks)
+        return y.reshape(B, T, d).astype(self.dtype)
+
+
+class RouterBalance(NamedTuple):
+    counts: jnp.ndarray  # [layers, E] int32: tokens each expert received
+    aux_loss: jnp.ndarray  # E x sum_e f_e P_e over the masked tokens of all layers
+    max_load: jnp.ndarray  # largest expert's share of assignments x E
+
+
+def router_balance(
+    intermediates: Mapping[str, Any], token_mask: jnp.ndarray
+) -> RouterBalance:
+    """What the router did with the tokens ``token_mask [B, T]`` names.
+
+    ``intermediates`` is the collection a forward through routed blocks
+    sowed.  ``f_e`` is the share of the masked tokens' ``k`` assignments,
+    all layers together, that went to expert ``e``; ``P_e`` the mean
+    router probability of ``e`` over the same tokens and layers.  The loss
+    is ``E x sum_e f_e P_e``: 1 when both are uniform.  The gradient
+    reaches the router through ``P_e`` alone (the counts are integers).
+    """
+    layers = sorted(
+        (name for name in intermediates if name.startswith("block_")),
+        key=lambda name: int(name.split("_")[1]),
+    )
+    mask = token_mask.astype(jnp.float32)
+    counts, prob_sums = [], []
+    for name in layers:
+        sown = intermediates[name]["experts"]
+        probs, ids = sown["router_probs"][0], sown["expert_ids"][0]
+        E = probs.shape[-1]
+        picked = jnp.sum(jax.nn.one_hot(ids, E, dtype=jnp.float32), axis=2)
+        counts.append(jnp.sum(picked * mask[..., None], axis=(0, 1)))
+        prob_sums.append(jnp.sum(probs * mask[..., None], axis=(0, 1)))
+    counts = jnp.stack(counts)  # [layers, E]
+    assignments = jnp.maximum(jnp.sum(counts), 1.0)
+    tokens = jnp.maximum(jnp.sum(mask) * len(layers), 1.0)
+    share = jnp.sum(counts, axis=0) / assignments
+    mean_prob = jnp.sum(jnp.stack(prob_sums), axis=0) / tokens
+    E = counts.shape[-1]
+    return RouterBalance(
+        counts=counts.astype(jnp.int32),
+        aux_loss=E * jnp.sum(lax.stop_gradient(share) * mean_prob),
+        max_load=E * jnp.max(share),
+    )

@@ -15,6 +15,7 @@ axis is sharded across the ``sp`` mesh axis — callers pass ``positions``
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
@@ -22,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from scalerl_tpu.models.routed_ffn import RoutedExperts
 from scalerl_tpu.ops.pallas_attention import flash_attention
 from scalerl_tpu.ops.pallas_paged_attention import (
     gather_pages,
@@ -31,6 +33,64 @@ from scalerl_tpu.ops.ring_attention import full_attention
 
 # (q, k, v) -> attention output, all [B, T, H, D]
 AttentionFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray], jnp.ndarray]
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockSpec:
+    """What kind of block the token model stacks, as data.
+
+    The defaults are the GPT-2 block (LayerNorm, a learned position
+    table, one fused-qkv MHA at head size ``d_model / heads``, a GELU
+    MLP): same parameter names, same tree.  Every other kind sits at one
+    of two points that all attention paths share: after the q/k/v
+    projections and before the cache write (q/k norm, rotary positions:
+    K enters a cache normed and rotated, so cached, paged and packed
+    paths read it as it is), and where the MLP sits (the routed experts).
+    """
+
+    norm: str = "layernorm"  # layernorm | rmsnorm
+    norm_eps: float = 1e-6
+    positions: str = "learned"  # learned (a table added to the embedding) | rope
+    rope_theta: float = 10000.0
+    qk_norm: str = "none"  # none | rmsnorm (over the projection's whole width)
+    head_dim: Optional[int] = None  # None: d_model // num_heads
+    ffn: str = "mlp"  # mlp (GELU, mlp_ratio x d_model) | experts (routed SwiGLU)
+    num_experts: int = 0
+    experts_per_token: int = 0
+    expert_width: int = 0
+    norm_topk_prob: bool = False
+
+
+def block_spec(
+    family: str,
+    *,
+    head_dim: Optional[int] = None,
+    norm_eps: float = 1e-5,
+    rope_theta: float = 10000.0,
+    num_experts: int = 0,
+    experts_per_token: int = 0,
+    expert_width: int = 0,
+    norm_topk_prob: bool = False,
+) -> BlockSpec:
+    """The block a named family stacks; the sizes only the family reads
+    are ignored by the others (``gpt2`` keeps its own epsilon)."""
+    if family == "gpt2":
+        return BlockSpec(head_dim=head_dim)
+    if family == "olmoe":
+        if not 1 <= experts_per_token <= num_experts or expert_width < 1:
+            raise ValueError(
+                "the olmoe block needs 1 <= experts_per_token <= num_experts "
+                f"and an expert width, got {experts_per_token}/{num_experts}/"
+                f"{expert_width}"
+            )
+        return BlockSpec(
+            norm="rmsnorm", norm_eps=norm_eps, positions="rope",
+            rope_theta=rope_theta, qk_norm="rmsnorm", head_dim=head_dim,
+            ffn="experts", num_experts=num_experts,
+            experts_per_token=experts_per_token, expert_width=expert_width,
+            norm_topk_prob=norm_topk_prob,
+        )
+    raise ValueError(f"block family must be gpt2 | olmoe, got {family!r}")
 
 
 class TransformerOutput(NamedTuple):
@@ -220,6 +280,48 @@ def _masked_attention(
     return out.astype(out_dtype)
 
 
+class RMSNorm(nn.Module):
+    """``x / rms(x) * scale`` over the last axis, computed in float32
+    (scale included) and rounded once to ``dtype``."""
+
+    epsilon: float
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        scale = self.param(
+            "scale", nn.initializers.ones, (x.shape[-1],), jnp.float32
+        )
+        x = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return (x * lax.rsqrt(ms + self.epsilon) * scale).astype(self.dtype)
+
+
+def _norm(spec: BlockSpec, dtype, name: Optional[str] = None) -> nn.Module:
+    if spec.norm == "rmsnorm":
+        return RMSNorm(spec.norm_eps, dtype=dtype, name=name)
+    return nn.LayerNorm(use_bias=False, dtype=dtype, name=name)
+
+
+def rotary_fn(positions: jnp.ndarray, head_dim: int, theta: float) -> Callable:
+    """``x [B, T, H, D] -> x`` rotated to ``positions [B, T]``: the
+    rotate-half pairing (feature ``i`` with ``i + D/2``), ``inv_freq_i =
+    theta^(-2i/D)``, angle ``position x inv_freq``; computed in float32
+    and rounded once.  The angles are made once a forward and shared by
+    every block."""
+    half = head_dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / head_dim)
+    angle = positions.astype(jnp.float32)[:, :, None, None] * inv_freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+
+    def rotate(x):
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return out.astype(x.dtype)
+
+    return rotate
+
+
 class _Block(nn.Module):
     d_model: int
     num_heads: int
@@ -229,6 +331,11 @@ class _Block(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
     paged_attn_fn: Optional[Callable] = None
     segment_attn_fn: Optional[Callable] = None
+    spec: BlockSpec = BlockSpec()
+    # rotary positions of this forward's tokens (:func:`rotary_fn`), made
+    # by the model from the ``positions`` every caller passes; None under
+    # a learned position table
+    rotary: Optional[Callable] = None
 
     @nn.compact
     def __call__(
@@ -276,13 +383,23 @@ class _Block(nn.Module):
         sharing stays purely a page-table fact.
         """
         B, T, _ = x.shape
-        head_dim = self.d_model // self.num_heads
+        spec = self.spec
+        rms = spec.norm == "rmsnorm"
+        head_dim = spec.head_dim or self.d_model // self.num_heads
+        width = self.num_heads * head_dim
         dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
-        h = nn.LayerNorm(use_bias=False, dtype=self.dtype)(x)
-        qkv = nn.Dense(3 * self.d_model, use_bias=False, name="qkv", **dt)(h)
+        h = _norm(spec, self.dtype, "attn_norm" if rms else None)(x)
+        qkv = nn.Dense(3 * width, use_bias=False, name="qkv", **dt)(h)
         q, k, v = jnp.split(qkv, 3, axis=-1)
+        if spec.qk_norm == "rmsnorm":
+            # over all heads' features at once, before the head split
+            q = RMSNorm(spec.norm_eps, dtype=self.dtype, name="q_norm")(q)
+            k = RMSNorm(spec.norm_eps, dtype=self.dtype, name="k_norm")(k)
         shape = (B, T, self.num_heads, head_dim)
         q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
+        if self.rotary is not None:
+            # before every cache write: K is stored normed and rotated
+            q, k = self.rotary(q), self.rotary(k)
         new_cache = None
         if paged_cache is not None:
             kp, vp = paged_cache
@@ -355,13 +472,19 @@ class _Block(nn.Module):
         else:
             out = self.attn_fn(q, k, v)
         out = nn.Dense(self.d_model, use_bias=False, name="proj", **dt)(
-            out.reshape(B, T, self.d_model)
+            out.reshape(B, T, width)
         )
         x = x + out
-        h = nn.LayerNorm(use_bias=False, dtype=self.dtype)(x)
-        h = nn.Dense(self.mlp_ratio * self.d_model, name="mlp_in", **dt)(h)
-        h = nn.gelu(h)
-        h = nn.Dense(self.d_model, name="mlp_out", **dt)(h)
+        h = _norm(spec, self.dtype, "ffn_norm" if rms else None)(x)
+        if spec.ffn == "experts":
+            h = RoutedExperts(
+                spec.num_experts, spec.experts_per_token, spec.expert_width,
+                spec.norm_topk_prob, name="experts", **dt,
+            )(h)
+        else:
+            h = nn.Dense(self.mlp_ratio * self.d_model, name="mlp_in", **dt)(h)
+            h = nn.gelu(h)
+            h = nn.Dense(self.d_model, name="mlp_out", **dt)(h)
         x = x + h
         if new_cache is not None:
             return x, new_cache
@@ -419,6 +542,14 @@ class TransformerPolicy(nn.Module):
     # Pallas-flash-on-TPU / None-elsewhere; None builds the dense
     # :func:`packed_attention_mask` and rides ``_masked_attention``.
     segment_attn_fn: Optional[Callable] = None
+    # The block kind (norm, positions, q/k norm, head size, FFN) as data;
+    # the default is the GPT-2 block.  ``block_spec(family, ...)`` names
+    # the families the program's arguments can choose.
+    block: BlockSpec = BlockSpec()
+
+    @property
+    def head_dim(self) -> int:
+        return self.block.head_dim or self.d_model // self.num_heads
 
     @nn.compact
     def __call__(
@@ -473,7 +604,8 @@ class TransformerPolicy(nn.Module):
           Same params as every other path.
         """
         B, T = obs.shape[:2]
-        if T > self.max_len:
+        spec = self.block
+        if T > self.max_len and spec.positions == "learned":
             # out-of-range gathers clamp silently under jit, which would
             # alias every late position onto one embedding
             raise ValueError(
@@ -501,13 +633,18 @@ class TransformerPolicy(nn.Module):
                 self.d_model, name="obs_embed",
                 dtype=self.dtype, param_dtype=self.param_dtype,
             )(obs.reshape(B, T, -1).astype(self.dtype))
-        pos_tab = self.param(
-            "pos_embed",
-            nn.initializers.normal(0.02),
-            (self.max_len, self.d_model),
-            self.param_dtype,
-        )
-        x = c(x + pos_tab[positions].astype(self.dtype))
+        rotary = None
+        if spec.positions == "rope":
+            rotary = rotary_fn(positions, self.head_dim, spec.rope_theta)
+        else:
+            pos_tab = self.param(
+                "pos_embed",
+                nn.initializers.normal(0.02),
+                (self.max_len, self.d_model),
+                self.param_dtype,
+            )
+            x = x + pos_tab[positions].astype(self.dtype)
+        x = c(x)
         new_k = []
         new_v = []
         for i in range(self.num_layers):
@@ -520,6 +657,8 @@ class TransformerPolicy(nn.Module):
                 param_dtype=self.param_dtype,
                 paged_attn_fn=self.paged_attn_fn,
                 segment_attn_fn=self.segment_attn_fn,
+                spec=spec,
+                rotary=rotary,
                 name=f"block_{i}",
             )
             if paged_cache is not None:
@@ -549,9 +688,7 @@ class TransformerPolicy(nn.Module):
             else:
                 x = block(x, attn_mask=attn_mask)
             x = c(x)
-        x = nn.LayerNorm(use_bias=False, name="final_norm", dtype=jnp.float32)(
-            x.astype(jnp.float32)
-        )
+        x = _norm(spec, jnp.float32, "final_norm")(x.astype(jnp.float32))
         policy_logits = nn.Dense(self.num_actions, name="policy_head")(x)
         baseline = nn.Dense(1, name="value_head")(x).squeeze(-1)
         out = TransformerOutput(policy_logits, baseline)

@@ -68,6 +68,16 @@ PARAM_LOGICAL_AXES: Dict[Tuple[str, ...], Tuple[Optional[str], ...]] = {
     # sharding buys nothing until experts outgrow a chip).
     ("w_in",): ("experts", "embed", None),
     ("w_out",): ("experts", None, "embed"),
+    # The token model's routed SwiGLU experts (models/routed_ffn.py): the
+    # three banks lead with the expert dim like the ones above; the router
+    # is replicated (every shard scores every token against all experts).
+    # The q/k norm scales of the olmoe block match no rule and replicate:
+    # the norm reduces over the projection's whole width, which GSPMD
+    # completes across ``mp`` with an all-reduce of the sum of squares.
+    ("w_gate",): ("experts", "embed", None),
+    ("w_up",): ("experts", "embed", None),
+    ("w_down",): ("experts", None, "embed"),
+    ("experts", "router"): ("embed", None),
 }
 
 

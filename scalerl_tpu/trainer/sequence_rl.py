@@ -53,7 +53,7 @@ from scalerl_tpu.genrl.rollout import (
     sequence_field_shapes,
 )
 from scalerl_tpu.genrl.task import TokenRecallTask
-from scalerl_tpu.models.transformer import TransformerPolicy
+from scalerl_tpu.models.transformer import TransformerPolicy, block_spec
 from scalerl_tpu.ops.pallas_per import resolve_sample_method
 from scalerl_tpu.parallel.train_step import maybe_enable_mesh_from_args
 from scalerl_tpu.runtime import telemetry, tracing
@@ -91,6 +91,16 @@ def build_genrl_model(args: GenRLArguments) -> TransformerPolicy:
         dtype=jnp.bfloat16 if bf16 else jnp.float32,
         param_dtype=jnp.bfloat16 if bf16 else jnp.float32,
         segment_attn_fn=seg_fn,
+        block=block_spec(
+            args.block_family,
+            head_dim=args.head_dim or None,
+            norm_eps=args.rms_norm_eps,
+            rope_theta=args.rope_theta,
+            num_experts=args.moe_experts,
+            experts_per_token=args.moe_experts_per_token,
+            expert_width=args.moe_hidden,
+            norm_topk_prob=args.moe_norm_topk_prob,
+        ),
     )
 
 

@@ -28,10 +28,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from scalerl_tpu.genrl.continuous import ContinuousConfig, ContinuousEngine
-from scalerl_tpu.models.transformer import TransformerPolicy
+from scalerl_tpu.models.transformer import TransformerPolicy, block_spec
 from scalerl_tpu.ops.pallas_paged_attention import paged_decode_attention
 
-LAYERS, HEADS, HEAD_DIM, LANES, PAGE, PAGES = 2, 16, 64, 4, 8, 301
+LAYERS, HEADS, LANES, PAGE, PAGES = 2, 16, 4, 8, 301
+# the attention geometry of each block family the benchmark runs: a pool
+# row is ``heads x head size`` wide, 1024 (gpt2-medium) and 2048 (OLMoE)
+HEAD_DIM = {"gpt2": 64, "olmoe": 128}
 
 
 @pytest.fixture(scope="module")
@@ -60,16 +63,23 @@ def _no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.fixture(scope="module")
-def engine():
-    """2 layers of gpt2-medium's attention geometry (16 heads of 64) over
-    301 pages of 8; the compiled kernel is pinned behind the attention seam
-    because ``auto`` resolves to the XLA gather on this CPU backend."""
+@pytest.fixture(scope="module", params=sorted(HEAD_DIM))
+def engine(request):
+    """2 layers of gpt2-medium's attention geometry (16 heads of 64), and
+    2 of OLMoE's (16 heads of 128, q/k norm and rotation before the cache
+    write, a small routed FFN: pools ``[pages, 8, 2048]``), over 301 pages
+    of 8; the compiled kernel is pinned behind the attention seam because
+    ``auto`` resolves to the XLA gather on this CPU backend."""
     vocab = 128
+    family = request.param
+    head_dim = HEAD_DIM[family]
     model = TransformerPolicy(
-        num_actions=vocab, vocab_size=vocab, d_model=HEADS * HEAD_DIM,
+        num_actions=vocab, vocab_size=vocab, d_model=HEADS * head_dim,
         num_heads=HEADS, num_layers=LAYERS, mlp_ratio=1, max_len=256,
         paged_attn_fn=functools.partial(paged_decode_attention, interpret=False),
+        block=block_spec(
+            family, head_dim=head_dim, num_experts=4, experts_per_token=2, expert_width=128
+        ),
     )
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
     return ContinuousEngine(
@@ -108,13 +118,13 @@ def _elements(dims):
     return int(np.prod([int(d) for d in dims.split(",")]))
 
 
-def _assert_pools_read_in_place(text):
+def _assert_pools_read_in_place(text, eng):
     """No ``copy`` or ``transpose`` the size of a pool, and every pool the
     program names is row-major on whole ``(8, 128)`` tiles.  Pools this
     small the compiler may prefetch into another memory space and write
     back (``copy-start``, the layout unchanged but for ``S(n)``): that is
     its own business and does not happen at a real pool's size."""
-    pool = PAGES * PAGE * HEADS * HEAD_DIM
+    pool = PAGES * PAGE * HEADS * eng.model.head_dim
     moved = [
         line.strip()[:160]
         for line in text.splitlines()
@@ -134,7 +144,7 @@ def test_decode_macro_step_reads_the_pools_in_place(engine, one_chip):
         engine, one_chip, engine._decode_fn, params=True, extra=[(LANES, M), "key"]
     )
     assert text.count("tpu_custom_call") >= LAYERS
-    _assert_pools_read_in_place(text)
+    _assert_pools_read_in_place(text, engine)
     # the donated pools come back as themselves: output i aliases the
     # parameter that follows the model's own leaves
     leaves = len(jax.tree_util.tree_leaves(engine._snapshot_params()[0]))
@@ -164,4 +174,4 @@ def test_other_programs_leave_the_pools_in_place(engine, one_chip, program):
         fn = engine._build_verify(k)
         extra = [(LANES, k), (LANES,), (LANES, k + 1), (LANES, k + 1), (LANES, M), (LANES,), "key"]
     text = _compiled_text(engine, one_chip, fn, params=program != "fork", extra=extra)
-    _assert_pools_read_in_place(text)
+    _assert_pools_read_in_place(text, engine)

@@ -462,6 +462,9 @@ def test_genrl_generation_round_on_tpu():
         (64, 8, 32, 8, 32, jnp.bfloat16),
         # the benchmark's rollout cell: gpt2-medium, 16 lanes, 2049 pages
         (16, 16, 64, 8, 128, jnp.float32),
+        # the OLMoE rollout cell: 32 lanes, 16 heads of 128, 4097 pages
+        # (page rows 2048 wide: twice gpt2-medium's block)
+        (32, 16, 128, 8, 128, jnp.float32),
     ],
 )
 def test_paged_decode_attention_compiled(B, H, D, ps, M, dtype, precision, tol):
@@ -563,7 +566,10 @@ def test_continuous_engine_macro_step_on_tpu():
 @pytest.mark.parametrize(
     "precision,fwd_tol,bwd_tol", [("highest", 2e-3, 5e-3), (None, 3e-2, 1e-1)]
 )
-@pytest.mark.parametrize("B,T,H,D", [(2, 384, 2, 128), (2, 384, 8, 32)])
+# the third is OLMoE's: 16 heads of 128 over a 1024-token packed row
+@pytest.mark.parametrize(
+    "B,T,H,D", [(2, 384, 2, 128), (2, 384, 8, 32), (2, 1024, 16, 128)]
+)
 def test_segment_flash_forward_backward_compiled(
     B, T, H, D, precision, fwd_tol, bwd_tol
 ):
