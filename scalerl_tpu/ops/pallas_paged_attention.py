@@ -24,17 +24,23 @@ Two implementations behind one contract:
   explicit f32 softmax.  The parity oracle and the CPU-backend default
   (Pallas interpret mode would re-interpret the kernel per decode
   sub-step).
-- :func:`paged_decode_attention` — the Pallas kernel: grid
-  ``(B, num_pages_per_lane)``, all heads per step, with the page table and
-  lengths as *scalar-prefetch* operands, so each kv step's ``BlockSpec``
-  index map reads ``page_table[b, j]`` and DMAs exactly that dense
-  ``[page_size, H*D]`` page from the pool into VMEM.  The kernel's own HBM
-  reads are O(live tokens); whether the *program around it* stays off the
-  rest of the pool is a matter of the pool's stored layout (above), which
-  ``tests/test_decode_program_layout.py`` guards.  Online softmax with
-  float32 accumulators in VMEM scratch persisting across the (innermost,
-  sequential) page dimension; pages past a lane's length are skipped
-  entirely via ``pl.when``.  Interpret mode off-TPU; Mosaic on TPU.
+- :func:`paged_decode_attention` — the Pallas kernel: grid ``(B,)``, one
+  lane a step with all its heads, the page table and lengths as
+  *scalar-prefetch* operands.  The pools stay in HBM; inside a lane the
+  kernel walks the table in blocks of ``P`` pages
+  (:func:`pages_per_block`: 128 tokens, from the pool's shape alone) to
+  ``cdiv(length, P * page_size)`` and no further, issuing one async copy a
+  *live* page into a double-buffered VMEM block while the previous block
+  is attended to, the next lane's first block included.  So both the HBM
+  reads and the steps are O(live tokens): a table slot past a lane's
+  length is never read, a dead lane (length 1) costs one page and one
+  block.  Whether the *program around it* stays off the rest of the pool
+  is a matter of the pool's stored layout (above), which
+  ``tests/test_decode_program_layout.py`` guards.  Scores for all heads
+  come from one MXU product of the block against the query arranged
+  block-diagonally; online softmax with float32 scores, state and
+  accumulator in VMEM scratch; float32 operands reach the MXU as exact
+  bfloat16 terms, never rounded.  Interpret mode off-TPU; Mosaic on TPU.
 
 Grad-free by construction: decode is inference-only, no ``custom_vjp`` is
 defined, and differentiating through ``pallas_call`` raises — the learner
@@ -51,7 +57,6 @@ least to the token it just wrote; dead lanes are masked downstream).
 from __future__ import annotations
 
 import functools
-import math
 import os
 from typing import Optional
 
@@ -140,87 +145,176 @@ def paged_attention_reference(
     return out[:, None].astype(q.dtype)
 
 
-def _lane_chunk(num_heads: int, head_dim: int) -> int:
-    """Width of the lane slices the kernel works in: the fewest whole
-    128-lane rows that hold whole heads, or the whole ``H*D`` axis where
-    that does not divide it (the tests' tiny models)."""
-    chunk = math.lcm(head_dim, 128)
-    return chunk if (num_heads * head_dim) % chunk == 0 else num_heads * head_dim
+# tokens in one block of the walk: one whole 128-lane row of scores a head.
+# Measured on the chip (PERF.md, PR 26): 64 pays the block's fixed cost
+# twice as often, 256 computes more masked positions in dead lanes and tails
+_BLOCK_TOKENS = 128
+# bytes the K and V blocks may hold in VMEM, two buffers each: half of the
+# 16 MiB a v5e kernel gets by default
+_VMEM_BUDGET = 8 * 2**20
+
+
+def pages_per_block(page_size: int, width: int, itemsize: int) -> int:
+    """Pages the kernel fetches and attends to at a step, from the pool's
+    shape alone: ``_BLOCK_TOKENS`` tokens, fewer where ``width = H*D`` is so
+    large that four such blocks would pass ``_VMEM_BUDGET``, never under one
+    page."""
+    tokens = min(_BLOCK_TOKENS, _VMEM_BUDGET // (4 * width * itemsize))
+    return max(1, tokens // page_size)
+
+
+def _bf16_terms(x):
+    """``x`` as bfloat16 terms that sum to it exactly: a float32's 24-bit
+    significand in three 8-bit pieces (high, middle, low), or ``x`` itself
+    where it is bfloat16 already."""
+    if x.dtype == jnp.bfloat16:
+        return (x,)
+    x = x.astype(jnp.float32)
+    high = x.astype(jnp.bfloat16)
+    rest = x - high.astype(jnp.float32)
+    middle = rest.astype(jnp.bfloat16)
+    low = (rest - middle.astype(jnp.float32)).astype(jnp.bfloat16)
+    return high, middle, low
+
+
+def _dot_f32(a_terms, b, contract):
+    """``a @ b`` with float32 operands on an MXU that multiplies bfloat16:
+    what ``precision=HIGHEST`` computes (the six products of a 3 x 3 split
+    that reach float32's last bits, accumulated in float32), arranged so
+    that ``b``, the K or V block, passes the MXU three times and not six.
+    ``a_terms`` is ``a``'s three terms stacked on rows, ``[3 * rows, K]``:
+    all of them meet ``b``'s high term in one product, the first two its
+    middle term, the first its low term.  Measured on the chip against
+    ``HIGHEST`` (PERF.md, PR 26): the same error, 13% less kernel time at
+    gpt2-medium's width."""
+    rows = a_terms.shape[0] // 3
+    total = None
+    # from ``b``'s low term up, and within a product from ``a``'s lowest
+    # term up: the small products enter the sum before the leading one
+    for i, term in reversed(list(enumerate(_bf16_terms(b)))):
+        r = jax.lax.dot_general(
+            a_terms[: (3 - i) * rows], term, (contract, ((), ())),
+            # bfloat16 terms multiply exactly in one pass, whatever
+            # ``jax.default_matmul_precision`` the caller runs under
+            precision=jax.lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32,
+        )
+        for g in reversed(range(3 - i)):
+            piece = r[g * rows:(g + 1) * rows]
+            total = piece if total is None else total + piece
+    return total
 
 
 def _decode_kernel(
-    pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, acc_sc, m_sc, l_sc,
-    *, scale, page_size, num_pages_per_lane, head_dim, chunk,
+    pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+    k_buf, v_buf, sems, q_sc, acc_sc, m_sc, l_sc, walked_sc,
+    *, scale, head_dim,
 ):
-    """One (lane, page) grid step over ALL heads: ``q_ref``/``o_ref`` are
-    ``[1, H*D]``, ``k_ref``/``v_ref`` one dense ``[ps, H*D]`` page (tokens
-    on sublanes, the heads' ``D``-wide segments side by side on lanes).  A
-    single query row cannot feed the MXU, so a head's score is a VPU
-    product and a masked lane reduction over its segment, broadcast back
-    over the segment: scores, softmax state and accumulator all stay in
-    the page's own ``[ps, H*D]`` shape.  Each sublane row runs its own
-    online softmax over the positions ``r, r + ps, ...`` it sees, so a
-    step is elementwise but for that reduction; the ``ps`` rows are merged
-    once, at the last page."""
+    """One lane a grid step, ALL heads, walking the lane's live pages a
+    block of ``P`` at a time.  The pools stay in HBM; ``k_buf``/``v_buf``
+    are ``[2, P, page, H*D]`` VMEM buffers that the kernel fills itself,
+    one async copy a live page, the next block (this lane's, or the next
+    lane's first) in flight while this one is attended to.  ``walked_sc``
+    counts the blocks walked so far over all lanes: its parity is the
+    buffer the current block sits in.
+
+    A block is ``[T, H*D]``: tokens on sublanes, the heads' ``D``-wide
+    segments side by side on lanes.  All heads' scores come from one MXU
+    product against ``q_sc``, the query arranged block-diagonally (row
+    ``h`` holds head ``h``'s segment of the scaled query, zeros
+    elsewhere), so scores and softmax state are ``[heads, T]`` and
+    ``[heads, 1]``; ``acc_sc`` is ``[heads, H*D]``, of which head ``h``'s
+    output is row ``h``'s own segment.  Both products keep float32
+    operands (:func:`_dot_f32`).  Pages past the lane's length are neither
+    read from the table nor fetched: the positions they would fill are
+    masked in the scores and zeroed in the V block, so nothing stale in
+    VMEM reaches the result."""
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    width = q_ref.shape[-1]
+    lanes = pl.num_programs(0)
+    _, P, ps, width = k_buf.shape
+    T = P * ps
+    rows = acc_sc.shape[0]
+    slots = pt_ref.shape[1]
 
-    @pl.when(j == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, _NEG_BIG)
-        l_sc[:] = jnp.zeros_like(l_sc)
+    def live_pages(lane, i):
+        # never a slot past the table's width, whatever the length says (an
+        # index read from beyond it could send a copy anywhere), and never
+        # no page at all: every lane's first block is some lane's prefetch
+        return jnp.clip(pl.cdiv(len_ref[lane], ps), 1, slots) - i * P
 
+    def each_live_page(lane, i, buf, act):
+        def page(j, carry):
+            at = pt_ref[lane, i * P + j]
+            act(pltpu.make_async_copy(k_hbm.at[at], k_buf.at[buf, j], sems.at[0, buf]))
+            act(pltpu.make_async_copy(v_hbm.at[at], v_buf.at[buf, j], sems.at[1, buf]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(live_pages(lane, i), P), page, 0)
+
+    @pl.when(b == 0)
+    def _first_block():
+        walked_sc[0] = 0
+        each_live_page(0, 0, 0, lambda copy: copy.start())
+
+    # read after the reset above: what a scratch holds at entry is anyone's
+    first = walked_sc[0]
     length = len_ref[b]
-    live = j * page_size < length
+    blocks = pl.cdiv(live_pages(b, 0), P)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    own = (col >= row * head_dim) & (col < (row + 1) * head_dim)
+    q = jnp.where(own, q_ref[...].astype(jnp.float32) * scale, 0.0)
+    q_sc[...] = jnp.concatenate(_bf16_terms(q), axis=0)
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+    m_sc[...] = jnp.full_like(m_sc, _NEG_BIG)
+    l_sc[...] = jnp.zeros_like(l_sc)
 
-    @pl.when(live)
-    def _attend():
-        shape = (page_size, chunk)
-        pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-        valid = pos < length
-        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        heads = [
-            (lane >= g * head_dim) & (lane < (g + 1) * head_dim)
-            for g in range(chunk // head_dim)
-        ]
-        for c in range(width // chunk):
-            sl = slice(c * chunk, (c + 1) * chunk)
-            q = q_ref[:, sl].astype(jnp.float32) * scale  # [1, chunk]
-            prod = q * k_ref[:, sl].astype(jnp.float32)  # [ps, chunk]
-            if len(heads) == 1:
-                s = jnp.broadcast_to(
-                    jnp.sum(prod, axis=-1, keepdims=True), shape
-                )
-            else:
-                s = jnp.zeros(shape, jnp.float32)
-                for in_head in heads:
-                    s_h = jnp.sum(
-                        jnp.where(in_head, prod, 0.0), axis=-1, keepdims=True
-                    )
-                    s = jnp.where(in_head, s_h, s)
-            s = jnp.where(valid, s, jnp.float32(_NEG_BIG))
-            m = m_sc[:, sl]
-            m_new = jnp.maximum(m, s)
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m - m_new)
-            l_sc[:, sl] = l_sc[:, sl] * corr + p
-            m_sc[:, sl] = m_new
-            acc_sc[:, sl] = acc_sc[:, sl] * corr + p * v_ref[:, sl].astype(
-                jnp.float32
+    def tokens_on_sublanes(pages):
+        # merged as float32, whose sublane tile a page of 8 fills
+        merged = pages.astype(jnp.float32).reshape(T, width)
+        return merged.astype(pages.dtype)
+
+    def attend(i, carry):
+        buf = (first + i) % 2
+        last = i + 1 == blocks
+        next_lane = jnp.where(last, b + 1, b)
+
+        @pl.when(next_lane < lanes)
+        def _prefetch():
+            each_live_page(
+                next_lane, jnp.where(last, 0, i + 1), 1 - buf,
+                lambda copy: copy.start(),
             )
 
-    @pl.when(j == num_pages_per_lane - 1)
-    def _finish():
-        # merge the rows' softmax streams; a row that saw no valid position
-        # still holds -1e30 and weighs exp(-1e30 - m) = 0 (lengths >= 1
-        # makes row 0's maximum a real score)
-        m = m_sc[:]
-        w = jnp.exp(m - jnp.max(m, axis=0, keepdims=True))
-        l = jnp.sum(l_sc[:] * w, axis=0, keepdims=True)
-        acc = jnp.sum(acc_sc[:] * w, axis=0, keepdims=True)
-        o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        each_live_page(b, i, buf, lambda copy: copy.wait())
+
+        def no_page(j, carry):
+            v_buf[buf, j] = jnp.zeros((ps, width), v_buf.dtype)
+            return carry
+
+        jax.lax.fori_loop(live_pages(b, i), P, no_page, 0)  # only the last block
+
+        k = tokens_on_sublanes(k_buf[buf])
+        s = _dot_f32(q_sc[...], k, ((1,), (1,)))  # [rows, T]
+        pos = i * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < length, s, jnp.float32(_NEG_BIG))
+        m = m_sc[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_sc[...] = m_new
+        v = tokens_on_sublanes(v_buf[buf])
+        p_terms = jnp.concatenate(_bf16_terms(p), axis=0)
+        acc_sc[...] = acc_sc[...] * corr + _dot_f32(p_terms, v, ((1,), (0,)))
+        return carry
+
+    jax.lax.fori_loop(0, blocks, attend, 0)
+    walked_sc[0] = first + blocks
+    # lengths >= 1 makes every head's sum positive; rows past the last head
+    # (padding to whole sublane tiles) own no segment
+    out = jnp.where(own, acc_sc[...] / jnp.maximum(l_sc[...], 1e-30), 0.0)
+    o_ref[...] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -236,8 +330,9 @@ def paged_decode_attention(
 
     The page table and lengths ride as scalar-prefetch operands
     (``pltpu.PrefetchScalarGridSpec``): they land in SMEM before the
-    kernel body runs, so the K/V ``BlockSpec`` index maps dereference
-    ``page_table[b, j]`` to choose which pool page each grid step DMAs.
+    kernel body runs, which reads ``page_table[b, slot]`` to choose the
+    pool page each of its copies fetches.  The pools are handed over where
+    they are (``pltpu.ANY``): no ``BlockSpec`` pipeline touches them.
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
@@ -248,31 +343,36 @@ def paged_decode_attention(
         raise ValueError(f"decode attention takes one query token, got T={T}")
     k_pages, v_pages = _dense_pool(k_pages), _dense_pool(v_pages)
     ps = k_pages.shape[1]
-    M = page_table.shape[1]
+    P = pages_per_block(ps, H * D, k_pages.dtype.itemsize)
+    rows = -(-H // 16) * 16  # whole bfloat16 sublane tiles of heads
 
-    kernel = functools.partial(
-        _decode_kernel, scale=scale, page_size=ps, num_pages_per_lane=M,
-        head_dim=D, chunk=_lane_chunk(H, D),
-    )
-    # Mosaic tiles the last two block dims, which must be (8, 128)-divisible
-    # or span their axis: a K/V block is one whole dense page, [ps, H*D]
-    # (H*D spans its axis whatever the head geometry), q and o one row
-    row = pl.BlockSpec((None, 1, H * D), lambda b, j, pt, ln: (b, 0, 0))
-    page = pl.BlockSpec(
-        (None, ps, H * D), lambda b, j, pt, ln: (pt[b, j], 0, 0)
-    )
+    # q and o go a lane's row at a time through the pipeline; Mosaic tiles
+    # the last two block dims, and [1, H*D] spans both axes
+    row = pl.BlockSpec((None, 1, H * D), lambda b, pt, ln: (b, 0, 0))
+    pool = pl.BlockSpec(memory_space=pltpu.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, M),
-        in_specs=[row, page, page],
+        grid=(B,),
+        in_specs=[row, pool, pool],
         out_specs=row,
-        scratch_shapes=[pltpu.VMEM((ps, H * D), jnp.float32)] * 3,
+        scratch_shapes=[
+            pltpu.VMEM((2, P, ps, H * D), k_pages.dtype),
+            pltpu.VMEM((2, P, ps, H * D), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # [K or V, buffer]
+            pltpu.VMEM((3 * rows, H * D), jnp.bfloat16),  # block-diagonal query
+            pltpu.VMEM((rows, H * D), jnp.float32),  # accumulator
+            pltpu.VMEM((rows, 1), jnp.float32),  # running maximum
+            pltpu.VMEM((rows, 1), jnp.float32),  # running sum
+            pltpu.SMEM((1,), jnp.int32),  # blocks walked
+        ],
     )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_decode_kernel, scale=scale, head_dim=D),
         name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, H * D), q.dtype),
+        # the buffers and the block count carry from lane to lane
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(
         page_table.astype(jnp.int32),

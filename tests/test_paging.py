@@ -20,6 +20,8 @@ from scalerl_tpu.models.transformer import (
     sequence_attention_mask,
 )
 from scalerl_tpu.ops.pallas_paged_attention import (
+    _VMEM_BUDGET,
+    pages_per_block,
     paged_attention_reference,
     paged_decode_attention,
     resolve_paged_attn,
@@ -439,10 +441,10 @@ def _dense_case(layout, H, D, ps, seed=24):
 @pytest.mark.parametrize("H,D", _DENSE_GEOMETRIES)
 @pytest.mark.parametrize("layout", sorted(_DENSE_LAYOUTS))
 def test_dense_pool_kernel_matches_reference(layout, H, D, ps):
-    """The kernel on one dense ``[ps, H*D]`` page a step (its per-head
-    segment reductions, its per-row softmax streams merged at the last
-    page) against the gather reference, and the reference against a plain
-    per-lane softmax over the lane's own tokens."""
+    """The kernel on dense ``[ps, H*D]`` pages (all heads' scores from one
+    product against the block-diagonal query, each head's output read from
+    its own segment) against the gather reference, and the reference
+    against a plain per-lane softmax over the lane's own tokens."""
     q, kp, vp, t, ln = _dense_case(layout, H, D, ps)
     ref = paged_attention_reference(q, kp, vp, t, ln)
     ker = paged_decode_attention(q, kp, vp, t, ln, interpret=True)
@@ -479,6 +481,67 @@ def test_four_d_entry_is_the_dense_one(H, D, ps):
         np.asarray(paged_attention_reference(q, kp4, vp4, t, ln)),
         np.asarray(paged_attention_reference(q, kp, vp, t, ln)),
     )
+
+
+# the walk over a lane's live pages, a block of P pages at a step (ISSUE
+# 26).  A block is 128 tokens: 16 pages of 8, 8 pages of 16.
+
+_WALK_CASES = {
+    # name: (page size, table slots M, the lanes' lengths)
+    # k*P*ps - 1, k*P*ps, k*P*ps + 1 for k = 1, 2; M = 40 is no multiple of P
+    "around_block_boundaries": (8, 40, [127, 128, 129, 255, 256, 257]),
+    "one_token_and_the_full_width": (8, 40, [1, 320, 1, 319, 313]),
+    "table_narrower_than_a_block": (8, 5, [1, 40, 17, 33]),  # M < P
+    "one_long_lane_among_dead_ones": (8, 40, [1, 1, 300, 1, 1]),
+    "pages_of_16": (16, 20, [127, 128, 129, 320, 1, 16, 17]),  # P = 8
+}
+
+
+@pytest.mark.parametrize("H,D", _DENSE_GEOMETRIES)
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_kernel_walks_only_the_live_pages(case, H, D):
+    """Lengths on and around the block's boundaries, tables that are no
+    multiple of a block or narrower than one, dead lanes beside a long one:
+    the kernel against the gather reference, and against itself on a table
+    whose slots past each lane's length hold other pages' ids where the
+    first holds the null page.  Neither may show in the result."""
+    ps, M, lengths = _WALK_CASES[case]
+    B = len(lengths)
+    N = B * M + 1
+    rng = np.random.default_rng(26)
+    kp, vp = _dense_pools(rng, ps, H, D, N=N)
+    q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
+    ln = jnp.asarray(lengths, jnp.int32)
+    junk = rng.permutation(np.arange(1, N)).reshape(B, M)
+    live = np.arange(M)[None, :] * ps < np.asarray(lengths)[:, None]
+    tables = [jnp.asarray(np.where(live, junk, fill), jnp.int32) for fill in (0, junk)]
+    ref = paged_attention_reference(q, kp, vp, tables[0], ln)
+    ker = [paged_decode_attention(q, kp, vp, t, ln, interpret=True) for t in tables]
+    np.testing.assert_allclose(np.asarray(ker[0]), np.asarray(ref), atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(ker[1]), np.asarray(ker[0]))
+
+
+@pytest.mark.parametrize(
+    "ps,width,itemsize,pages",
+    [
+        (8, 16 * 64, 4, 16),  # gpt2m_group_rollout: float32 pools, 512 KB of K+V a block
+        (8, 16 * 128, 4, 16),  # olmoe_group_rollout: 1 MB a block
+        (16, 4 * 128, 4, 8),
+        (8, 8 * 32, 2, 16),  # the chip_smoke shape on bfloat16 pools
+        (4, 2 * 8, 4, 32),  # this file's tiny pools: more pages than a table has
+        (8, 128 * 128, 4, 4),  # a row so wide that the budget cuts the block
+        (256, 1024, 4, 1),  # never under one page
+    ],
+)
+def test_pages_per_block_from_shapes(ps, width, itemsize, pages):
+    """The block is a function of the pool's shape alone: 128 tokens where
+    the K and V blocks, two buffers each, fit the VMEM budget."""
+    P = pages_per_block(ps, width, itemsize)
+    assert P == pages
+    scratch = 4 * P * ps * width * itemsize
+    assert scratch <= _VMEM_BUDGET or P == 1
+    if scratch < _VMEM_BUDGET // 2:
+        assert P * ps >= 64
 
 
 def test_resolve_paged_attn(monkeypatch):

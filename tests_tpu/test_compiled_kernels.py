@@ -470,8 +470,8 @@ def test_genrl_generation_round_on_tpu():
 def test_paged_decode_attention_compiled(B, H, D, ps, M, dtype, precision, tol):
     """The continuous-batching decode kernel (ISSUE 11) compiled on the
     chip through its dense entry, the engine's own: scalar-prefetch
-    page-table indexing + online softmax over whole lane-dense
-    ``[page, H*D]`` blocks, pinned to the XLA gather reference on-device
+    page-table indexing + online softmax over blocks of whole lane-dense
+    ``[page, H*D]`` pages, pinned to the XLA gather reference on-device
     across a fragmented table with a partially-filled last page.  The 4-D
     entry (tests hold such pools) must give the same bits."""
     from scalerl_tpu.ops.pallas_paged_attention import (
@@ -506,6 +506,44 @@ def test_paged_decode_attention_compiled(B, H, D, ps, M, dtype, precision, tol):
         atol=tol, rtol=tol,
     )
     np.testing.assert_array_equal(np.asarray(four_d), np.asarray(out))
+
+
+@pytest.mark.parametrize(
+    "B,H,D",
+    [(16, 16, 64), (32, 16, 128)],
+    ids=["gpt2m_group_rollout", "olmoe_group_rollout"],
+)
+def test_paged_decode_attention_ragged_at_the_cells_geometry(B, H, D):
+    """The walk over live pages (ISSUE 26) at each rollout cell's geometry:
+    2,049 / 4,097 float32 pages of 8, tables of 128 slots, and lanes whose
+    lengths sit on and around the 128-token block's boundaries, dead lanes
+    (length 1) between long ones.  Every slot past a lane's length holds
+    another lane's page id, which must not reach the result.  The kernel
+    runs at the engine's ambient precision and keeps float32 operands on
+    its own; the gather reference needs ``highest`` to be an oracle."""
+    from scalerl_tpu.ops.pallas_paged_attention import (
+        paged_attention_reference,
+        paged_decode_attention,
+    )
+
+    ps, M = 8, 128
+    N = B * M + 1
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(26), 3)
+    q = _rand(k1, B, 1, H, D)
+    k_pages = _rand(k2, N, ps, H * D)
+    v_pages = _rand(k3, N, ps, H * D)
+    rng = np.random.default_rng(26)
+    table = jnp.asarray(
+        rng.permutation(np.arange(1, N))[: B * M].reshape(B, M), jnp.int32
+    )
+    mix = [1, 7, 64, 65, 511, 1023, 1024, 1, 127, 128, 129, 1, 256, 257, 640, 1]
+    lengths = jnp.asarray([mix[b % len(mix)] for b in range(B)], jnp.int32)
+    out = paged_decode_attention(
+        q, k_pages, v_pages, table, lengths, interpret=False
+    )
+    with jax.default_matmul_precision("highest"):
+        ref = paged_attention_reference(q, k_pages, v_pages, table, lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
 def test_continuous_engine_macro_step_on_tpu():
