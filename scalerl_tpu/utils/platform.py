@@ -78,7 +78,7 @@ def setup_platform(platform: str = "auto") -> str:
 
     ``auto`` keeps JAX's default (TPU when present, else CPU); an explicit
     platform that cannot initialize is JAX's hard error — measurement paths
-    (``bench.py``, ``chip_smoke.py``, ``tests_tpu``) pass ``"tpu"`` for
+    (``benchmark/run.py``, ``chip_smoke.py``, ``tests_tpu``) pass ``"tpu"`` for
     exactly that reason.  Returns the backend actually in use.
 
     On accelerator backends this also places JAX's persistent compilation

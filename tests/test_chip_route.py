@@ -1,10 +1,10 @@
 """The two routes the program runs by, and nothing that blurs them.
 
 On the CPU for tests, on the chip for everything that states a speed: the
-measurement entry points (``bench.py``, ``chip_smoke.py``, ``tests_tpu/``)
-fail without a chip instead of falling back; the compile cache can be
-placed from outside; a CPU line says it is one; a peak is never guessed;
-one process drives a chip.  These replace the tests of the probe/bank
+measurement entry points (``chip_smoke.py``, ``tests_tpu/``; the benchmark's
+own tests hold ``benchmark/run.py`` to the same) fail without a chip instead
+of falling back; the compile cache can be placed from outside; one process
+drives a chip.  These replace the tests of the probe/bank
 orchestrator and the watcher's perf gate, which this repo no longer has.
 """
 
@@ -96,7 +96,7 @@ def _json_lines(text):
     return out
 
 
-@pytest.mark.parametrize("script", ["bench.py", "chip_smoke.py"])
+@pytest.mark.parametrize("script", ["chip_smoke.py"])
 def test_entry_point_without_a_chip_exits_nonzero_and_prints_no_metric(script):
     t0 = time.monotonic()
     out = subprocess.run(
@@ -129,50 +129,6 @@ def test_tests_tpu_fails_rather_than_skips_off_tpu():
     )
     assert out.returncode == 1, out.stdout[-1500:]
     assert "skipped" not in out.stdout.splitlines()[-1]
-
-
-# -- a CPU line says so; a peak is never guessed -----------------------------
-def _load_bench():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location("bench_mod", REPO / "bench.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_cpu_run_line_carries_platform_cpu(monkeypatch, capsys):
-    """``bench.py --run --cpu``: the explicit CPU request goes through
-    ``main`` and the line it prints says which device it came from.  (The
-    measurement itself is stubbed; the schema tests run the real ones
-    in-process and assert the same stamp.)"""
-    bench = _load_bench()
-    monkeypatch.setattr(
-        bench, "_run_learn_measurement",
-        lambda: bench._emit(
-            {"metric": "impala_learn_step_frames_per_sec", "value": 1.0}
-        ),
-    )
-    bench.main(["--run", "--cpu", "--learn"])
-    (result,) = _json_lines(capsys.readouterr().out)
-    assert result["platform"] == "cpu" and result["device_count"] >= 1
-    assert result["device_kind"]
-
-
-def test_unknown_device_kind_raises_where_a_peak_is_looked_up():
-    bench = _load_bench()
-    assert bench._peak_flops("TPU v5 lite") == 197e12
-    with pytest.raises(ValueError, match="v5-something"):
-        bench._peak_flops("TPU v5-something")
-    with pytest.raises(ValueError):
-        bench._stamp_mfu({}, 1e12, "tpu", "mystery chip")
-    result = {}
-    bench._stamp_mfu(result, 1e12, "tpu", "TPU v5 lite", n_dev=2)
-    assert result == {"achieved_tflops_per_s": 1.0, "mfu": round(1 / 394, 4)}
-    # a CPU run carries neither field, whatever the kind
-    cpu_result = {}
-    bench._stamp_mfu(cpu_result, 1e12, "cpu", "cpu")
-    assert cpu_result == {}
 
 
 # -- one process for each chip ----------------------------------------------

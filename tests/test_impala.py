@@ -348,9 +348,9 @@ def test_impala_bfloat16_compute_dtype():
 @pytest.mark.slow  # ~11 s; dtype plumbing tier-1-covered by test_bf16_params_with_fp32_opt_state
 # + the fp32 fused loop in test_parallel (ISSUE 19 buy-back)
 def test_impala_bfloat16_fused_device_loop():
-    """The bench's accelerator config — bf16 torso inside the fused
-    env+inference+V-trace loop (bench.py sets compute_dtype='bfloat16'
-    on TPU/GPU) — compiles and produces finite losses."""
+    """The accelerator config — bf16 torso inside the fused
+    env+inference+V-trace loop (the ``impala_fused`` cell sets
+    compute_dtype='bfloat16') — compiles and produces finite losses."""
     import jax
 
     from scalerl_tpu.envs.jax_envs.base import JaxVecEnv

@@ -133,8 +133,8 @@ def test_pallas_per_sample_compiled():
 
 
 def test_fused_loop_one_chunk_on_tpu():
-    """The bench-shaped fused actor-learner program compiles and executes
-    end to end on the chip (the headline path of ``bench.py``) — at a
+    """The fused actor-learner program compiles and executes end to end
+    on the chip (the ``impala_fused`` cell's path) — at a
     reduced batch so this stays a quick smoke, not a benchmark."""
     from scalerl_tpu.agents.impala import ImpalaAgent
     from scalerl_tpu.config import ImpalaArguments
