@@ -66,10 +66,10 @@ the host swaps *sequences* through them —
   ``m``'s emitted tokens), so it runs at ``steps_in_flight = 1``
   semantics regardless of the configured depth.
 
-Sampling math is shared with the fixed-cohort engine (``engine.py``'s
-``adjust_logits``/``sample_tokens``), so at temperature 0 the two engines
-are token-identical on the same params — the parity the acceptance tests
-pin, with the prefix cache on or off, speculation on or off.  A sequence is tagged with the param
+At temperature 0 the engine's tokens are those of a greedy full forward
+(``model.apply`` on the whole sequence) on the same params — the parity
+the acceptance tests pin, with the prefix cache on or off, speculation on
+or off.  A sequence is tagged with the param
 generation that admitted it; a ``push_params`` mid-flight rotates the
 policy under lanes already decoding (inherent to continuous batching; the
 token-PPO ratios absorb it exactly like actor lag) and FLUSHES the prefix
@@ -97,12 +97,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from scalerl_tpu.genrl.engine import (
-    GenerationConfig,
-    ParamSnapshotPlane,
-    adjust_logits,
-    sample_tokens,
-)
 from scalerl_tpu.genrl.drafter import NgramDrafter
 from scalerl_tpu.genrl.paging import PageAllocator, rewind_pages
 from scalerl_tpu.genrl.prefix_cache import PrefixCache
@@ -117,6 +111,7 @@ from scalerl_tpu.ops.pallas_paged_attention import make_paged_attn_fn
 from scalerl_tpu.runtime import telemetry, tracing
 from scalerl_tpu.runtime.device_loop import resolve_iter_mode
 from scalerl_tpu.runtime.dispatch import steady_state_guard
+from scalerl_tpu.runtime.param_server import ParamSnapshotPlane
 from scalerl_tpu.serving.batcher import (
     DynamicBatcher,
     ServingConfig,
@@ -131,9 +126,37 @@ _device_put = jax.device_put
 _device_get = jax.device_get
 
 
+def adjust_logits(
+    logits: jnp.ndarray, temperature: float, top_k: int, vocab_size: int
+) -> jnp.ndarray:
+    """Sampling adjustments (top-k mask then temperature) — the behavior
+    logprob is computed from THESE logits, so the stored logp is the true
+    log-density of the sampling distribution.  ``temperature == 0`` (greedy)
+    skips the scale: sampling argmaxes and the logp reads the unscaled
+    log-softmax."""
+    if top_k > 0 and top_k < vocab_size:
+        kth = jnp.sort(logits, axis=-1)[:, -top_k][:, None]
+        logits = jnp.where(logits >= kth, logits, jnp.float32(-1e30))
+    if temperature > 0:
+        logits = logits / jnp.float32(temperature)
+    return logits
+
+
+def sample_tokens(key, adj_logits: jnp.ndarray, temperature: float):
+    """Categorical sample from adjusted logits; argmax at temperature 0."""
+    if temperature == 0:
+        return jnp.argmax(adj_logits, axis=-1)
+    return jax.random.categorical(key, adj_logits, axis=-1)
+
+
 @dataclass
-class ContinuousConfig(GenerationConfig):
-    """Fixed-cohort knobs plus the continuous-batching geometry.
+class ContinuousConfig:
+    """Sampling knobs plus the continuous-batching geometry.
+
+    ``eos_token < 0`` disables early stopping (fixed-length responses, the
+    synthetic-task default); with an EOS id, a lane latches done on
+    sampling it.  ``temperature == 0`` selects greedy (argmax) decoding —
+    the setting the parity tests pin token-identical outputs at.
 
     ``num_pages = 0`` sizes the pool for every lane's worst case (null
     page included) — no admission backpressure by default; smaller pools
@@ -142,6 +165,16 @@ class ContinuousConfig(GenerationConfig):
     admission flush predicate (0 = admit the moment lanes are free).
     """
 
+    vocab_size: int
+    max_prompt_len: int = 64
+    max_new_tokens: int = 64
+    temperature: float = 1.0
+    top_k: int = 0  # 0 = full distribution
+    eos_token: int = -1
+    pad_token: int = 0
+    prompt_buckets: Tuple[int, ...] = ()  # () -> pow2 ladder
+    response_buckets: Tuple[int, ...] = ()
+    seed: int = 0
     lanes: int = 64
     page_size: int = 16
     num_pages: int = 0
@@ -177,8 +210,33 @@ class ContinuousConfig(GenerationConfig):
     # n-gram width the self-drafter matches against the context tail.
     spec_ngram: int = 3
 
+    def resolved_prompt_buckets(self) -> Tuple[int, ...]:
+        return tuple(self.prompt_buckets) or default_buckets(self.max_prompt_len)
+
+    def resolved_response_buckets(self) -> Tuple[int, ...]:
+        return tuple(self.response_buckets) or default_buckets(self.max_new_tokens)
+
     def validate(self) -> None:
-        super().validate()
+        if self.vocab_size < 2:
+            raise ValueError(f"vocab_size must be >= 2, got {self.vocab_size}")
+        if self.max_prompt_len < 1 or self.max_new_tokens < 1:
+            raise ValueError(
+                "max_prompt_len and max_new_tokens must be >= 1, got "
+                f"{self.max_prompt_len}/{self.max_new_tokens}"
+            )
+        if self.temperature < 0:
+            raise ValueError(
+                f"temperature must be >= 0 (0 = greedy), got "
+                f"{self.temperature}"
+            )
+        if self.top_k < 0 or self.top_k > self.vocab_size:
+            raise ValueError(
+                f"top_k must be in [0, vocab_size], got {self.top_k}"
+            )
+        if self.eos_token >= self.vocab_size:
+            raise ValueError(
+                f"eos_token {self.eos_token} outside vocab {self.vocab_size}"
+            )
         if self.lanes < 1:
             raise ValueError(f"lanes must be >= 1, got {self.lanes}")
         if self.min_free_lanes < 1 or self.min_free_lanes > self.lanes:
@@ -303,8 +361,7 @@ class ContinuousEngine(ParamSnapshotPlane):
         self._max_prompt_bucket = bucket_for(
             config.max_prompt_len, config.resolved_prompt_buckets()
         )
-        # the response budget is the response BUCKET, mirroring the fixed
-        # cohort engine (its scan runs bucket_for(max_new_tokens) steps)
+        # the response budget is the response BUCKET
         self._response_budget = bucket_for(
             config.max_new_tokens, config.resolved_response_buckets()
         )
