@@ -2,13 +2,13 @@
 
 The scenario-diversity tier ROADMAP names after MindSpeed RL's distributed
 dataflow (arxiv 2507.19017): autoregressive generation from the transformer
-policy (KV-cached, bucketed static shapes, one jitted decode loop),
+policy (paged KV cache, continuous batching, one jitted macro-step),
 sequence packing into the prioritized sequence replay, and a token-level
 PPO learner with per-token importance ratios against the stored behavior
 logprobs.  ``genrl`` is a graftlint HOT package: the decode loop performs
-exactly ONE batched host read per generation round.
+exactly ONE batched host read per macro-step.
 
-Exports resolve lazily (PEP 562): the engines pull in jax at import time,
+Exports resolve lazily (PEP 562): the engine pulls in jax at import time,
 but the disaggregated-dataflow shells (``genrl/disagg.py``) are jax-free by
 design and run in fleet children that must not pay the jax import — so the
 package itself stays import-light and ``scalerl_tpu.genrl.disagg`` can be
@@ -21,13 +21,9 @@ _EXPORTS = {
     "CompletedSequence": "scalerl_tpu.genrl.continuous",
     "ContinuousConfig": "scalerl_tpu.genrl.continuous",
     "ContinuousEngine": "scalerl_tpu.genrl.continuous",
-    "GenerationConfig": "scalerl_tpu.genrl.engine",
-    "GenerationEngine": "scalerl_tpu.genrl.engine",
-    "GenerationResult": "scalerl_tpu.genrl.engine",
     "PageAllocator": "scalerl_tpu.genrl.paging",
     "PrefixCache": "scalerl_tpu.genrl.prefix_cache",
     "pack_completions": "scalerl_tpu.genrl.rollout",
-    "pack_sequences": "scalerl_tpu.genrl.rollout",
     "sequence_field_shapes": "scalerl_tpu.genrl.rollout",
     # pad-free packed learner layout (ISSUE 15)
     "PackedLearnerBatch": "scalerl_tpu.genrl.rollout",
@@ -35,10 +31,8 @@ _EXPORTS = {
     "pack_learner_batch": "scalerl_tpu.genrl.rollout",
     "packed_field_shapes": "scalerl_tpu.genrl.rollout",
     "packed_rows_from_completions": "scalerl_tpu.genrl.rollout",
-    "packed_rows_from_result": "scalerl_tpu.genrl.rollout",
     "TokenRecallTask": "scalerl_tpu.genrl.task",
     # the disaggregated dataflow (jax-free shells)
-    "CohortEngineShell": "scalerl_tpu.genrl.disagg",
     "ContinuousEngineShell": "scalerl_tpu.genrl.disagg",
     "DisaggConfig": "scalerl_tpu.genrl.disagg",
     "GenerationHost": "scalerl_tpu.genrl.disagg",

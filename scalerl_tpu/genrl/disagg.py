@@ -5,8 +5,8 @@ generation and training want different hardware shapes and must scale as
 separate tiers; SEED RL showed the learner is just one client of a serving
 plane.  This module composes the ingredients the repo already has — the
 elastic fleet's drain/exactly-once machinery (``fleet/cluster.py``), the
-KV-cached generation engines (``genrl/engine.py`` / ``continuous.py``), and
-the dp×mp learner — into that topology: N generation hosts each running an
+continuous-batching generation engine (``genrl/continuous.py``), and the
+dp×mp learner — into that topology: N generation hosts each running an
 engine behind a jax-free :class:`GenerationHost` shell, streaming completed
 generation-tagged sequences over the codec-v2 fleet wire into the learner's
 sequence replay, with param snapshots flowing back as quantized
@@ -520,107 +520,6 @@ class ScriptedEngineFactory:
         )
         eng.push_params(params, generation)
         return eng
-
-
-class CohortEngineShell:
-    """Drive a fixed-cohort :class:`~scalerl_tpu.genrl.engine.
-    GenerationEngine` as a disagg shell: buffered leases flush as one
-    bucket-pair round per :meth:`step` (the engine's whole-round program),
-    and each lease's true-length slice becomes its wire payload.
-
-    The engine's internal generation counter is mapped to the WIRE
-    generation the learner published (``push_params`` records the pair),
-    so payload tags speak the learner's id space.
-    """
-
-    def __init__(
-        self, engine: Any, round_batch: int, initial_generation: int = 0
-    ) -> None:
-        self.engine = engine
-        self.round_batch = max(int(round_batch), 1)
-        self.generation = int(initial_generation)
-        self._pending: List[Dict[str, Any]] = []
-        # the engine's internal counter at construction maps to the WIRE
-        # generation its construction params carried
-        self._gen_map: Dict[int, int] = {
-            int(engine.generation): int(initial_generation)
-        }
-
-    def push_params(self, params: Any, generation: int) -> None:
-        self._gen_map[
-            self.engine.push_params(_device_ready(params))
-        ] = int(generation)
-        while len(self._gen_map) > 64:
-            self._gen_map.pop(min(self._gen_map))
-        self.generation = int(generation)
-
-    def capacity(self) -> int:
-        return self.round_batch - len(self._pending)
-
-    def live(self) -> int:
-        return len(self._pending)
-
-    def submit(self, lease: Dict[str, Any]) -> None:
-        # a fanned-out lease occupies one cohort lane per sample (the
-        # GRPO tiled layout; the prefix-CoW savings live on the
-        # continuous engine — here fan-out is a data-layout feature)
-        samples = int(lease.get("samples", 1)) if isinstance(
-            lease, dict
-        ) else 1
-        for k in range(samples):
-            self._pending.append((lease, k, samples))
-
-    def abandon(self) -> List[Dict[str, Any]]:
-        leases: List[Dict[str, Any]] = []
-        seen = set()
-        for lease, _k, _n in self._pending:
-            if id(lease) not in seen:
-                seen.add(id(lease))
-                leases.append(lease)
-        self._pending = []
-        return leases
-
-    def step(self) -> List[Dict[str, Any]]:
-        if not self._pending:
-            return []
-        # flush at most one fixed round's worth of lanes; a group whose
-        # tail overflows the round rides the next one
-        batch = self._pending[: self.round_batch]
-        self._pending = self._pending[self.round_batch :]
-        lengths = np.ones((self.round_batch,), np.int32)
-        for i, (t, _k, _n) in enumerate(batch):
-            lengths[i] = int(t["length"])
-        L = int(lengths.max())
-        # partial rounds pad with inert lanes up to the FIXED round batch
-        # (batch size is a jit shape: a ragged round would retrace), and
-        # the pad lanes' outputs are simply dropped below
-        prompts = np.full((self.round_batch, L), 2, np.int32)
-        for i, (t, _k, _n) in enumerate(batch):
-            prompts[i, : lengths[i]] = np.asarray(
-                t["prompt"], np.int32
-            )[: lengths[i]]
-        result = self.engine.generate(prompts, lengths)
-        wire_gen = self._gen_map.get(result.generation, result.generation)
-        out = []
-        for i, (t, k, n) in enumerate(batch):
-            r = max(int(result.response_len[i]), 1)
-            payload = {
-                "prompt": prompts[i, : lengths[i]].copy(),
-                "prompt_len": int(lengths[i]),
-                "response_tokens": result.response_tokens[i, :r].copy(),
-                "behavior_logp": result.behavior_logp[i, :r].copy(),
-                "values": result.values[i, :r].copy(),
-                "generation": int(wire_gen),
-            }
-            tid = t.get("_task_id")
-            if tid is not None:
-                payload["_task_id"] = tid
-            if n > 1:
-                payload["_sample_idx"] = k
-                payload["_samples_total"] = n
-            _inherit_trace(payload, t)
-            out.append(payload)
-        return out
 
 
 class ContinuousEngineShell:

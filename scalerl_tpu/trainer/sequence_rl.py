@@ -3,9 +3,10 @@
 The orchestration glue of the ``genrl/`` plane (MindSpeed RL's dataflow at
 single-host scale, Podracer's fused-program discipline inside each stage):
 
-1. **generate** — the KV-cached engine runs one jitted round (prefill +
-   whole decode loop) and returns host numpy with ONE batched read, under
-   the steady-state transfer guard once the bucket pair is warm;
+1. **generate** — the continuous-batching engine (``genrl/continuous.py``)
+   keeps its lane pool fed and advances it one jitted macro-step at a
+   time, ONE upload and ONE batched read a macro-step, under the
+   steady-state transfer guard once warm;
 2. **score** — the task's rule-based reward runs on host numpy (the
    verifier stays off-device by design);
 3. **pack + replay** — sequences become prioritized sequence-replay
@@ -43,13 +44,10 @@ from scalerl_tpu.data.sequence_replay import (
     seq_sample,
 )
 from scalerl_tpu.genrl.continuous import ContinuousConfig, ContinuousEngine
-from scalerl_tpu.genrl.engine import GenerationConfig, GenerationEngine
 from scalerl_tpu.genrl.rollout import (
     pack_completions,
-    pack_sequences,
     packed_field_shapes,
     packed_rows_from_completions,
-    packed_rows_from_result,
     sequence_field_shapes,
 )
 from scalerl_tpu.genrl.task import TokenRecallTask
@@ -104,6 +102,33 @@ def build_genrl_model(args: GenRLArguments) -> TransformerPolicy:
     )
 
 
+def _engine_config(
+    args: GenRLArguments, lanes: int, max_prompt_len: int
+) -> ContinuousConfig:
+    """The generation engine's config off the run args (one definition
+    for the in-process trainer and the generation hosts' factory)."""
+    return ContinuousConfig(
+        vocab_size=args.vocab_size,
+        max_prompt_len=max_prompt_len,
+        max_new_tokens=args.max_new_tokens,
+        temperature=args.temperature,
+        top_k=args.top_k,
+        eos_token=args.eos_token,
+        seed=args.seed,
+        lanes=lanes,
+        page_size=args.genrl_page_size,
+        num_pages=args.genrl_num_pages,
+        steps_per_macro=args.genrl_macro_steps,
+        admit_max_wait_s=args.genrl_admit_wait_ms / 1e3,
+        max_pending=args.genrl_max_pending,
+        paged_attn=args.genrl_paged_attn,
+        steps_in_flight=args.genrl_steps_in_flight,
+        prefix_cache=args.genrl_prefix_cache,
+        spec_k=args.spec_k if args.spec_enable else 0,
+        spec_ngram=args.spec_ngram,
+    )
+
+
 def _bucketed_rows(pk, row_buckets, pad_gauge):
     """Bucket a :class:`PackedLearnerBatch`'s row count up the pow2
     ladder (shape-stable ``seq_add``), publish the batch pad ratio, and
@@ -139,54 +164,25 @@ class SequenceRLTrainer:
         self.agent = agent or TokenPPOAgent(args, build_genrl_model(args))
         maybe_enable_mesh_from_args(self.agent, args)
         self._mesh_lock = threading.Lock()
-        base_cfg = dict(
-            vocab_size=args.vocab_size,
-            max_prompt_len=max(
-                getattr(self.task, "max_prompt_len", args.prompt_len),
-                args.prompt_len,
-            ),
-            max_new_tokens=args.max_new_tokens,
-            temperature=args.temperature,
-            top_k=args.top_k,
-            eos_token=args.eos_token,
-            seed=args.seed,
-        )
-        self.continuous = args.genrl_engine == "continuous"
-        if self.continuous:
-            self.engine = ContinuousEngine(
-                self.agent.model,
-                self.agent.get_weights(),
-                ContinuousConfig(
-                    lanes=args.genrl_lanes or args.genrl_batch,
-                    page_size=args.genrl_page_size,
-                    num_pages=args.genrl_num_pages,
-                    steps_per_macro=args.genrl_macro_steps,
-                    admit_max_wait_s=args.genrl_admit_wait_ms / 1e3,
-                    max_pending=args.genrl_max_pending,
-                    paged_attn=args.genrl_paged_attn,
-                    steps_in_flight=args.genrl_steps_in_flight,
-                    prefix_cache=args.genrl_prefix_cache,
-                    spec_k=args.spec_k if args.spec_enable else 0,
-                    spec_ngram=args.spec_ngram,
-                    **base_cfg,
+        self.engine = ContinuousEngine(
+            self.agent.model,
+            self.agent.get_weights(),
+            _engine_config(
+                args,
+                args.genrl_lanes or args.genrl_batch,
+                max(
+                    getattr(self.task, "max_prompt_len", args.prompt_len),
+                    args.prompt_len,
                 ),
-                iter_mode=args.genrl_iter_mode,
-            )
-            # a macro-step can finish more lanes than one learn batch
-            # consumes; extras carry into the next round so insert batches
-            # stay shape-stable (seq_add compiles once per batch size)
-            self._completion_backlog = []
-        else:
-            self.engine = GenerationEngine(
-                self.agent.model,
-                self.agent.get_weights(),
-                GenerationConfig(**base_cfg),
-                iter_mode=args.genrl_iter_mode,
-            )
+            ),
+            iter_mode=args.genrl_iter_mode,
+        )
+        # a macro-step can finish more lanes than one learn batch
+        # consumes; extras carry into the next round so insert batches
+        # stay shape-stable (seq_add compiles once per batch size)
+        self._completion_backlog = []
         # replay geometry is pinned to the engine's LARGEST bucket pair so
-        # one buffer covers every round (smaller rounds still land in the
-        # max buckets: generate() buckets by the batch's true max length,
-        # and the fixed task geometry keeps that constant per run)
+        # one buffer covers every round
         self._prompt_pad = bucket_for(
             self.engine.config.max_prompt_len,
             self.engine.config.resolved_prompt_buckets(),
@@ -235,54 +231,8 @@ class SequenceRLTrainer:
             return self._mesh_lock
         return nullcontext()
 
-    def _generate_round(self):
-        B = self.args.genrl_batch
-        spp = self.args.samples_per_prompt
-        if spp > 1:
-            # group sampling on the cohort engine: tile each distinct
-            # prompt spp times — the GRPO data layout (groups contiguous);
-            # the cohort path pays full prefill per lane, the prefix-CoW
-            # savings live on the continuous engine
-            prompts, lengths = self.task.sample_prompts(B // spp, self._rng)
-            prompts = np.repeat(prompts, spp, axis=0)
-            lengths = np.repeat(lengths, spp, axis=0)
-        else:
-            prompts, lengths = self.task.sample_prompts(B, self._rng)
-        with tracing.span("round.generate", kind="genrl") as gen_span:
-            result = self.engine.generate(prompts, lengths)
-            gen_span.set(decode_tokens=float(result.decode_tokens))
-        with tracing.span("round.score", kind="genrl"):
-            rewards = self.task.score(
-                prompts, lengths, result.response_tokens, result.response_len
-            )
-        return result, rewards
-
-    def _round_cohort(self):
-        result, rewards = self._generate_round()
-        if result.prompt_pad != self._prompt_pad or (
-            result.response_pad != self._response_pad
-        ):
-            raise ValueError(
-                "generation round landed outside the replay bucket pair "
-                f"({result.prompt_pad}x{result.response_pad} vs "
-                f"{self._prompt_pad}x{self._response_pad})"
-            )
-        if self.packing:
-            pk = packed_rows_from_result(result, rewards, self._pack_len)
-            fields, priorities, decode = _bucketed_rows(
-                pk, self._row_buckets, self._pad_gauge
-            )
-            return fields, priorities, rewards, decode
-        self._pad_gauge.set(
-            1.0
-            - (result.prompt_tokens + result.decode_tokens)
-            / max(result.sequences.size, 1)
-        )
-        fields, priorities = pack_sequences(result, rewards)
-        return fields, priorities, rewards, result.decode_tokens
-
-    def _round_continuous(self):
-        """One continuous round: keep the lane pool fed, then pack exactly
+    def _round(self):
+        """One round's generation: keep the lane pool fed, then pack exactly
         ``genrl_batch`` finished sequences (macro-steps that overshoot bank
         their extras in the backlog — insert batches stay shape-stable)."""
         B = self.args.genrl_batch
@@ -341,11 +291,7 @@ class SequenceRLTrainer:
         # annotation, and a recorded span when the round was head-sampled
         # (SCALERL_TRACE_SAMPLE); none forces a device value (JG001)
         with tracing.span("genrl.round", kind="genrl") as root:
-            fields, priorities, rewards, decode_tokens = (
-                self._round_continuous()
-                if self.continuous
-                else self._round_cohort()
-            )
+            fields, priorities, rewards, decode_tokens = self._round()
             with self._dispatch_guard():
                 with tracing.span("round.seq_add", kind="genrl"):
                     self.replay = seq_add(self.replay, fields, (), priorities)
@@ -391,7 +337,7 @@ class SequenceRLTrainer:
 
     def lowered_programs(self) -> Dict[str, Any]:
         """The round's three kernel-bearing programs, lowered against the
-        trainer's live state: ``decode`` (continuous engine only), the
+        trainer's live state: the engine's ``decode`` macro-step, the
         replay ``sample`` and the ``learn`` step.  Nothing is trained or
         donated; the sample runs once to give the learn step its batch."""
         key = jax.random.PRNGKey(0)
@@ -408,8 +354,7 @@ class SequenceRLTrainer:
             programs["learn"] = self.agent.lower_learn(
                 dict(batch, is_weight=weights)
             )
-        if self.continuous:
-            programs["decode"] = self.engine.lower_decode()
+        programs["decode"] = self.engine.lower_decode()
         return programs
 
     def train(self, rounds: Optional[int] = None) -> Dict[str, float]:
@@ -458,36 +403,29 @@ class _WireCompletion:
         self.generation = int(payload["generation"])
 
 
-class _CohortShellFactory:
+class _EngineShellFactory:
     """Picklable engine factory for the generation hosts: builds the
-    token-mode model + fixed-cohort engine from the run args INSIDE the
+    token-mode model + generation engine from the run args INSIDE the
     host process — the only seam of the disagg shell that touches jax."""
 
-    def __init__(self, args: GenRLArguments, round_batch: int) -> None:
+    def __init__(self, args: GenRLArguments, lanes: int) -> None:
         self.args = args
-        self.round_batch = round_batch
+        self.lanes = lanes
 
     def __call__(self, params: Any, generation: int):
-        from scalerl_tpu.genrl.disagg import CohortEngineShell, _device_ready
+        from scalerl_tpu.genrl.disagg import (
+            ContinuousEngineShell,
+            _device_ready,
+        )
 
         args = self.args
-        engine = GenerationEngine(
+        engine = ContinuousEngine(
             build_genrl_model(args),
             _device_ready(params),
-            GenerationConfig(
-                vocab_size=args.vocab_size,
-                max_prompt_len=args.prompt_len,
-                max_new_tokens=args.max_new_tokens,
-                temperature=args.temperature,
-                top_k=args.top_k,
-                eos_token=args.eos_token,
-                seed=args.seed,
-            ),
+            _engine_config(args, self.lanes, args.prompt_len),
             iter_mode=args.genrl_iter_mode,
         )
-        return CohortEngineShell(
-            engine, self.round_batch, initial_generation=generation
-        )
+        return ContinuousEngineShell(engine, initial_generation=generation)
 
 
 class DisaggSequenceRLTrainer:
@@ -609,7 +547,7 @@ class DisaggSequenceRLTrainer:
         self.fleet = LocalGenerationFleet(
             self.learner,
             self.config,
-            engine_factory or _CohortShellFactory(args, lanes),
+            engine_factory or _EngineShellFactory(args, lanes),
             use_threads=use_threads,
         )
         self.fleet.start()
@@ -688,9 +626,9 @@ class DisaggSequenceRLTrainer:
         spp = self.args.samples_per_prompt
         if spp > 1:
             # group sampling: this lease fans out into spp completions on
-            # the generation host (submit_group on the continuous engine,
-            # tiled lanes on the cohort engine) — the learner counts the
-            # lease complete when all spp samples arrived
+            # the generation host (submit_group: one shared prompt prefix)
+            # — the learner counts the lease complete when all spp
+            # samples arrived
             lease["samples"] = spp
         return lease
 
