@@ -1,7 +1,7 @@
 """Pack generation rounds into prioritized sequence-replay chunks.
 
 The bridge between the generation engine (host numpy
-:class:`~scalerl_tpu.genrl.engine.GenerationResult`) and
+:class:`~scalerl_tpu.genrl.continuous.CompletedSequence` records) and
 ``data/sequence_replay.py``'s static-shape HBM buffer: every completed
 sequence becomes one replay unit carrying everything the token-PPO learner
 needs to recompute its loss off-policy —
@@ -44,7 +44,6 @@ from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
-from scalerl_tpu.genrl.engine import GenerationResult
 from scalerl_tpu.runtime import telemetry
 
 
@@ -65,40 +64,6 @@ def sequence_field_shapes(
         "prompt_len": ((), jnp.int32),
         "generation": ((), jnp.int32),
     }
-
-
-def pack_sequences(
-    result: GenerationResult,
-    rewards: np.ndarray,
-    priorities: Optional[np.ndarray] = None,
-) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
-    """``(fields [B, ...], priorities [B])`` ready for ``seq_add``.
-
-    Host-side numpy only — the single host->device hop happens when
-    ``seq_add``'s jit consumes the batch, alongside the learner dispatch.
-    """
-    B = result.sequences.shape[0]
-    rewards = np.asarray(rewards, np.float32)
-    if rewards.shape != (B,):
-        raise ValueError(
-            f"rewards must be [B={B}], got shape {rewards.shape}"
-        )
-    fields = {
-        "tokens": result.sequences.astype(np.int32),
-        "behavior_logp": result.behavior_logp.astype(np.float32),
-        "value": result.values.astype(np.float32),
-        "mask": result.mask.astype(np.float32),
-        "reward": rewards,
-        "prompt_len": result.prompt_len.astype(np.int32),
-        "generation": np.full(B, result.generation, np.int32),
-    }
-    if priorities is None:
-        priorities = np.ones(B, np.float32)
-    else:
-        priorities = np.maximum(
-            np.asarray(priorities, np.float32), 1e-6
-        )
-    return fields, priorities
 
 
 class PackedCompletions(NamedTuple):
@@ -131,8 +96,8 @@ class PackedCompletions(NamedTuple):
     def fields(
         self, rewards: np.ndarray, priorities: Optional[np.ndarray] = None
     ) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
-        """``seq_add``-ready fields — same schema as :func:`pack_sequences`
-        (one replay, either engine)."""
+        """``seq_add``-ready fields (the :func:`sequence_field_shapes`
+        schema)."""
         B = self.sequences.shape[0]
         rewards = np.asarray(rewards, np.float32)
         if rewards.shape != (B,):
@@ -466,39 +431,6 @@ def pack_learner_batch(
         priorities=prio,
         sequences_packed=B - len(shed),
         sequences_shed=len(shed),
-    )
-
-
-def packed_rows_from_result(
-    result: GenerationResult,
-    rewards: np.ndarray,
-    pack_len: int,
-    pad_token: int = 0,
-    priorities: Optional[np.ndarray] = None,
-) -> PackedLearnerBatch:
-    """Cohort-engine bridge: unpad a :class:`GenerationResult` back to
-    true-length sequences and bin-pack them (the packed twin of
-    :func:`pack_sequences`)."""
-    B = result.sequences.shape[0]
-    P = result.prompt_pad
-    prompts, responses, logps, vals = [], [], [], []
-    for i in range(B):
-        n = int(result.prompt_len[i])
-        r = int(result.response_len[i])
-        prompts.append(result.sequences[i, P - n : P].astype(np.int32))
-        responses.append(result.response_tokens[i, :r].astype(np.int32))
-        logps.append(result.behavior_logp[i, :r])
-        vals.append(result.values[i, :r])
-    return pack_learner_batch(
-        prompts,
-        responses,
-        logps,
-        vals,
-        rewards,
-        np.full(B, result.generation, np.int32),
-        pack_len,
-        pad_token=pad_token,
-        priorities=priorities,
     )
 
 

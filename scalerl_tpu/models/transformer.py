@@ -98,38 +98,6 @@ class TransformerOutput(NamedTuple):
     baseline: jnp.ndarray  # [B, T]
 
 
-class KVCache(NamedTuple):
-    """Static-shape per-layer key/value cache for incremental decoding.
-
-    ``k``/``v``: one ``[B, S, H, D]`` array per transformer block, where
-    ``S`` is the *total* (prompt bucket + response bucket) sequence length.
-    The cache is allocated once per bucket shape (:func:`init_kv_cache`),
-    written with ``lax.dynamic_update_slice`` at a scalar write cursor, and
-    carried through the jitted decode loop — so XLA compiles one program
-    per bucket and never retraces on ragged prompt lengths (the
-    ``serving/batcher.py`` bucket-ladder idea applied to the time axis).
-    """
-
-    k: Tuple[jnp.ndarray, ...]
-    v: Tuple[jnp.ndarray, ...]
-
-
-def init_kv_cache(
-    batch: int,
-    total_len: int,
-    num_layers: int,
-    num_heads: int,
-    head_dim: int,
-    dtype=jnp.float32,
-) -> KVCache:
-    """Zeroed cache sized for ``total_len`` (prompt + response buckets)."""
-    shape = (batch, total_len, num_heads, head_dim)
-    return KVCache(
-        k=tuple(jnp.zeros(shape, dtype) for _ in range(num_layers)),
-        v=tuple(jnp.zeros(shape, dtype) for _ in range(num_layers)),
-    )
-
-
 class PagedKVCache(NamedTuple):
     """Block-paged key/value cache: a fixed pool shared by every lane.
 
@@ -171,9 +139,8 @@ def init_paged_kv_cache(
 
 
 def prompt_attention_mask(lengths: jnp.ndarray, total_len: int) -> jnp.ndarray:
-    """``[B, T, T]`` causal mask over RIGHT-padded (compact) prompts — the
-    paged-prefill twin of :func:`prefill_attention_mask`: lane ``b``'s real
-    tokens occupy columns ``[0, lengths[b])``, so position ``i`` attends
+    """``[B, T, T]`` causal mask over RIGHT-padded (compact) prompts, for
+    the paged prefill: lane ``b``'s real tokens occupy columns ``[0, lengths[b])``, so position ``i`` attends
     causally within the real prefix and pad-tail rows degrade to uniform
     (finite, outputs unused)."""
     cols = jnp.arange(total_len)[None, None, :]
@@ -181,43 +148,13 @@ def prompt_attention_mask(lengths: jnp.ndarray, total_len: int) -> jnp.ndarray:
     return (cols <= rows) & (cols < lengths[:, None, None])
 
 
-def prefill_attention_mask(
-    lengths: jnp.ndarray, prompt_pad: int, total_len: int
-) -> jnp.ndarray:
-    """``[B, P, S]`` bool mask for the prefill pass over LEFT-padded prompts.
-
-    Prompts are right-aligned inside their ``prompt_pad`` bucket (lane
-    ``b``'s real tokens occupy columns ``[prompt_pad - lengths[b],
-    prompt_pad)``), so every lane's *last* prompt token sits at the same
-    static index and the decode steps share one scalar write cursor.  Row
-    ``r`` attends causally within the prompt, never into the pad prefix and
-    never into the (still empty) response region.  Fully-masked pad rows
-    are harmless: softmax degrades to uniform and their outputs are unused.
-    """
-    cols = jnp.arange(total_len)[None, None, :]
-    rows = jnp.arange(prompt_pad)[None, :, None]
-    pad = (prompt_pad - lengths)[:, None, None]
-    return (cols >= pad) & (cols <= rows)
-
-
-def decode_attention_mask(
-    lengths: jnp.ndarray, prompt_pad: int, step, total_len: int
-) -> jnp.ndarray:
-    """``[B, 1, S]`` mask for decode step ``step`` (0-indexed): attend to
-    the real prompt plus every response token written so far, including the
-    one just written at ``prompt_pad + step``."""
-    cols = jnp.arange(total_len)[None, None, :]
-    pad = (prompt_pad - lengths)[:, None, None]
-    return (cols >= pad) & (cols <= prompt_pad + step)
-
-
 def sequence_attention_mask(
     lengths: jnp.ndarray, prompt_pad: int, total_len: int
 ) -> jnp.ndarray:
-    """``[B, S, S]`` causal mask over a full left-padded sequence — the
-    learner-side twin of the prefill/decode masks, so the training forward
-    recomputes exactly the distribution the generation engine sampled
-    from (pad-prefix columns excluded)."""
+    """``[B, S, S]`` causal mask over a full left-padded sequence (the
+    padded learner layout, ``genrl/rollout.py``'s ``pack_completions``), so
+    the training forward recomputes exactly the distribution the
+    generation engine sampled from (pad-prefix columns excluded)."""
     cols = jnp.arange(total_len)[None, None, :]
     rows = jnp.arange(total_len)[None, :, None]
     pad = (prompt_pad - lengths)[:, None, None]
@@ -341,8 +278,6 @@ class _Block(nn.Module):
     def __call__(
         self,
         x: jnp.ndarray,
-        layer_cache: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
-        cache_index=None,
         attn_mask: Optional[jnp.ndarray] = None,
         paged_cache: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
         page_ids: Optional[jnp.ndarray] = None,
@@ -352,15 +287,10 @@ class _Block(nn.Module):
         prefix_starts: Optional[jnp.ndarray] = None,
         segment_ids: Optional[jnp.ndarray] = None,
     ):
-        """Full forward (``layer_cache=None``) or KV-cached incremental step.
+        """Full forward (no cache) or paged incremental step.
 
-        With ``layer_cache=(k, v)`` the block writes this call's keys/values
-        at ``cache_index`` (a scalar — prompts are left-padded so every lane
-        shares one cursor) and attends ``x``'s ``T`` positions against the
-        whole cache under ``attn_mask`` ``[B, T, S]``; returns
-        ``(out, (new_k, new_v))``.  With a mask but no cache it runs
-        explicit masked attention against its own k/v (the learner-side
-        forward over left-padded sequences).
+        With a mask but no cache it runs explicit masked attention against
+        its own k/v (the learner-side forward over left-padded sequences).
 
         With ``paged_cache=(k_pages, v_pages)`` the block scatters this
         call's keys/values into pool pages — lane ``b``'s token ``t`` lands
@@ -449,18 +379,6 @@ class _Block(nn.Module):
             else:
                 out = _masked_attention(q, k, v, attn_mask, self.dtype)
             new_cache = (kp, vp)
-        elif layer_cache is not None:
-            ck, cv = layer_cache
-            idx = jnp.asarray(cache_index, jnp.int32)
-            zero = jnp.zeros((), jnp.int32)
-            ck = lax.dynamic_update_slice(
-                ck, k.astype(ck.dtype), (zero, idx, zero, zero)
-            )
-            cv = lax.dynamic_update_slice(
-                cv, v.astype(cv.dtype), (zero, idx, zero, zero)
-            )
-            out = _masked_attention(q, ck, cv, attn_mask, self.dtype)
-            new_cache = (ck, cv)
         elif segment_ids is not None and self.segment_attn_fn is not None:
             # packed-row training attention through the flash seam: the
             # kernel enforces the segment-blocked causal rule and skips
@@ -556,8 +474,6 @@ class TransformerPolicy(nn.Module):
         self,
         obs: jnp.ndarray,
         positions: Optional[jnp.ndarray] = None,
-        kv_cache: Optional[KVCache] = None,
-        cache_index=None,
         attn_mask: Optional[jnp.ndarray] = None,
         paged_cache: Optional[PagedKVCache] = None,
         page_ids: Optional[jnp.ndarray] = None,
@@ -567,19 +483,13 @@ class TransformerPolicy(nn.Module):
         prefix_starts: Optional[jnp.ndarray] = None,
         segment_ids: Optional[jnp.ndarray] = None,
     ):
-        """Full forward, masked full forward, or KV-cached incremental step.
+        """Full forward, masked full forward, or paged incremental step.
 
-        - ``kv_cache=None, attn_mask=None``: the original whole-trajectory
-          forward (causal ``attn_fn``) returning :class:`TransformerOutput`.
-        - ``kv_cache=None, attn_mask=[B, T, T]``: full forward under an
-          explicit mask (:func:`sequence_attention_mask`) — the learner
-          pass over left-padded generated sequences.
-        - ``kv_cache=KVCache, cache_index=i, attn_mask=[B, T, S]``: write
-          this call's k/v at ``i`` and attend against the cache — prefill
-          (``T = prompt bucket``, ``i = 0``) and single-token decode
-          (``T = 1``, ``i = prompt_pad + step``) both go through here,
-          sharing every parameter with the training forward.  Returns
-          ``(TransformerOutput, new_cache)``.
+        - ``attn_mask=None``: the original whole-trajectory forward (causal
+          ``attn_fn``) returning :class:`TransformerOutput`.
+        - ``attn_mask=[B, T, T]``: full forward under an explicit mask
+          (:func:`sequence_attention_mask`) — the learner pass over
+          left-padded generated sequences.
         - ``paged_cache=PagedKVCache`` (the continuous-batching plane):
           scatter this call's k/v into pool pages at ``(page_ids[b, t],
           page_offsets[b, t])``.  With ``attn_mask=[B, T, T]`` and no
@@ -674,15 +584,6 @@ class TransformerPolicy(nn.Module):
                 )
                 new_k.append(bk)
                 new_v.append(bv)
-            elif kv_cache is not None:
-                x, (bk, bv) = block(
-                    x,
-                    layer_cache=(kv_cache.k[i], kv_cache.v[i]),
-                    cache_index=cache_index,
-                    attn_mask=attn_mask,
-                )
-                new_k.append(bk)
-                new_v.append(bv)
             elif segment_ids is not None:
                 x = block(x, segment_ids=segment_ids)
             else:
@@ -694,6 +595,4 @@ class TransformerPolicy(nn.Module):
         out = TransformerOutput(policy_logits, baseline)
         if paged_cache is not None:
             return out, PagedKVCache(k=tuple(new_k), v=tuple(new_v))
-        if kv_cache is not None:
-            return out, KVCache(k=tuple(new_k), v=tuple(new_v))
         return out

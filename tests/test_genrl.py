@@ -1,7 +1,7 @@
-"""Token-level sequence-RL plane (ISSUE 10): KV-cached decode parity, the
-generation engine's one-batched-read round discipline, token-PPO learning,
-and the hermetic generate -> score -> learn e2e on the synthetic recall
-task.
+"""Token-level sequence-RL plane (ISSUE 10): the generation engine against
+the full forward, its one-upload-one-read macro-step discipline, token-PPO
+learning, and the hermetic generate -> score -> learn e2e on the synthetic
+recall task.
 """
 
 import numpy as np
@@ -10,22 +10,13 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from genrl_reference import full_forward, greedy_full_forward, left_padded
 from scalerl_tpu.agents.token_ppo import TokenPPOAgent, token_ppo_loss
 from scalerl_tpu.config import GenRLArguments
-from scalerl_tpu.genrl.engine import (
-    GenerationConfig,
-    GenerationEngine,
-)
-from scalerl_tpu.genrl.rollout import pack_sequences, sequence_field_shapes
+from scalerl_tpu.genrl.continuous import ContinuousConfig, ContinuousEngine
+from scalerl_tpu.genrl.rollout import pack_completions, sequence_field_shapes
 from scalerl_tpu.genrl.task import TokenRecallTask
-from scalerl_tpu.models.transformer import (
-    TransformerPolicy,
-    decode_attention_mask,
-    init_kv_cache,
-    prefill_attention_mask,
-    sequence_attention_mask,
-    sequence_positions,
-)
+from scalerl_tpu.models.transformer import TransformerPolicy
 from scalerl_tpu.trainer.sequence_rl import SequenceRLTrainer
 
 
@@ -45,63 +36,6 @@ def _genrl_args(**kw):
     )
     base.update(kw)
     return GenRLArguments(**base)
-
-
-# ---------------------------------------------------------------------------
-# KV cache: prefill + single-token decode == the full masked forward
-
-
-def test_kv_cache_decode_matches_full_forward():
-    """The incremental path must reproduce the training forward exactly:
-    per-position logits/baselines from prefill + R decode steps match the
-    one-shot masked forward over the same left-padded sequence."""
-    V, P, R = 11, 6, 4
-    S = P + R
-    m = _token_model(vocab=V, max_len=S)
-    B = 3
-    lengths = jnp.array([6, 3, 1], jnp.int32)
-    rng = np.random.default_rng(0)
-    toks = jnp.asarray(rng.integers(0, V, size=(B, S)), jnp.int32)
-    params = m.init(jax.random.PRNGKey(0), toks[:, :2])
-
-    full = m.apply(
-        params, toks,
-        positions=sequence_positions(lengths, P, S),
-        attn_mask=sequence_attention_mask(lengths, P, S),
-    )
-
-    cache = init_kv_cache(B, S, m.num_layers, m.num_heads,
-                          m.d_model // m.num_heads)
-    out, cache = m.apply(
-        params, toks[:, :P],
-        positions=sequence_positions(lengths, P, S)[:, :P],
-        kv_cache=cache, cache_index=0,
-        attn_mask=prefill_attention_mask(lengths, P, S),
-    )
-    np.testing.assert_allclose(
-        out.policy_logits[:, -1], full.policy_logits[:, P - 1], atol=1e-5
-    )
-    np.testing.assert_allclose(
-        out.baseline[:, -1], full.baseline[:, P - 1], atol=1e-5
-    )
-
-    # one jitted decode step reused across t: same program, traced cursor
-    @jax.jit
-    def decode(cache, tok, pos, mask, idx):
-        return m.apply(
-            params, tok, positions=pos, kv_cache=cache,
-            cache_index=idx, attn_mask=mask,
-        )
-
-    for t in range(R):
-        out, cache = decode(
-            cache, toks[:, P + t][:, None], (lengths + t)[:, None],
-            decode_attention_mask(lengths, P, t, S),
-            jnp.int32(P + t),
-        )
-        np.testing.assert_allclose(
-            out.policy_logits[:, 0], full.policy_logits[:, P + t], atol=1e-5
-        )
 
 
 def test_token_and_feature_modes_share_param_structure():
@@ -125,115 +59,159 @@ def test_token_and_feature_modes_share_param_structure():
 # ---------------------------------------------------------------------------
 # generation engine
 
+P_MAX, R_MAX = 6, 4
 
-def _engine(iter_mode="auto", **cfg_kw):
+
+def _engine(iter_mode="auto", layers=1, **cfg_kw):
     V = 11
-    cfg = dict(vocab_size=V, max_prompt_len=6, max_new_tokens=4, seed=7)
+    cfg = dict(
+        vocab_size=V, max_prompt_len=P_MAX, max_new_tokens=R_MAX, seed=7,
+        lanes=4, page_size=2, steps_per_macro=2,
+    )
     cfg.update(cfg_kw)
-    config = GenerationConfig(**cfg)
+    config = ContinuousConfig(**cfg)
     max_p = config.resolved_prompt_buckets()[-1]
     max_r = config.resolved_response_buckets()[-1]
-    # 1 layer: engine-behavior tests exercise the round machinery, not
-    # layer stacking (the 2-layer cache path is covered by the kv parity
-    # test above) — halves the per-test compile on the tier-1 clock
-    m = _token_model(vocab=config.vocab_size, layers=1,
+    # 1 layer unless a test is about layer stacking: engine-behavior tests
+    # exercise the macro-step machinery — halves the per-test compile
+    m = _token_model(vocab=config.vocab_size, layers=layers,
                      max_len=max_p + max_r)
     params = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
-    return GenerationEngine(m, params, config, iter_mode=iter_mode)
+    return ContinuousEngine(m, params, config, iter_mode=iter_mode)
 
 
-def test_engine_scan_unroll_parity():
-    """The decode loop is the same math whether fused as lax.scan or a
+def _generate(eng, prompts, lengths):
+    """Every prompt through the engine; completions in prompt order."""
+    for i in range(len(lengths)):
+        eng.submit(prompts[i], int(lengths[i]), tag=i)
+    done = eng.run_until(len(lengths), max_macro_steps=200)
+    return sorted(done, key=lambda c: c.tag)
+
+
+@pytest.mark.parametrize("layers", [1, 2])
+def test_paged_decode_matches_full_forward(layers):
+    """The incremental path must reproduce the training forward exactly:
+    the engine's greedy tokens, log-probabilities and baselines (paged
+    prefill over compact prompts, then one token a substep through the
+    page table) are those of the one-shot masked forward over the same
+    left-padded sequence, token after token."""
+    eng = _engine(temperature=0.0, layers=layers)
+    rng = np.random.default_rng(0)
+    prompts = rng.integers(2, 11, size=(3, P_MAX)).astype(np.int32)
+    lengths = np.array([6, 3, 1], np.int32)
+    ref = greedy_full_forward(
+        eng.model, eng._params, prompts, lengths, P_MAX, R_MAX
+    )
+    for i, c in enumerate(_generate(eng, prompts, lengths)):
+        np.testing.assert_array_equal(c.response_tokens, ref.response_tokens[i])
+        np.testing.assert_allclose(c.behavior_logp, ref.behavior_logp[i], atol=1e-5)
+        np.testing.assert_allclose(c.values, ref.values[i], atol=1e-5)
+
+
+@pytest.mark.parametrize("steps_in_flight", [1, 2])
+def test_engine_scan_unroll_parity(steps_in_flight):
+    """The substep loop is the same math whether fused as lax.scan or a
     Python-unrolled body (the PR 6 iter_mode contract): same params + same
     key schedule -> identical tokens and behavior logprobs."""
     rng = np.random.default_rng(1)
-    prompts = rng.integers(2, 11, size=(5, 6)).astype(np.int32)
+    prompts = rng.integers(2, 11, size=(5, P_MAX)).astype(np.int32)
     lengths = np.array([6, 4, 3, 2, 1], np.int32)
-    r_scan = _engine("scan").generate(prompts, lengths)
-    r_unroll = _engine("unroll").generate(prompts, lengths)
-    np.testing.assert_array_equal(
-        r_scan.response_tokens, r_unroll.response_tokens
+    r_scan = _generate(
+        _engine("scan", steps_in_flight=steps_in_flight), prompts, lengths
     )
-    np.testing.assert_allclose(
-        r_scan.behavior_logp, r_unroll.behavior_logp, atol=1e-5
+    r_unroll = _generate(
+        _engine("unroll", steps_in_flight=steps_in_flight), prompts, lengths
     )
-    np.testing.assert_allclose(r_scan.values, r_unroll.values, atol=1e-5)
+    for a, b in zip(r_scan, r_unroll):
+        np.testing.assert_array_equal(a.response_tokens, b.response_tokens)
+        np.testing.assert_allclose(a.behavior_logp, b.behavior_logp, atol=1e-5)
+        np.testing.assert_allclose(a.values, b.values, atol=1e-5)
 
 
-def test_engine_one_batched_transfer_per_round(monkeypatch):
-    """The round discipline graftlint JG001 pins statically, enforced
-    dynamically: one _device_put up, one _device_get down, per round —
-    and the warm (second) round runs under the armed transfer guard."""
-    import scalerl_tpu.genrl.engine as engine_mod
+def test_engine_one_upload_one_read_per_macro_step(monkeypatch):
+    """The discipline graftlint JG001 pins statically, enforced
+    dynamically on a cold engine: an admitting step uploads its prefill
+    group(s) and the table and reads once; every steady step after it is
+    one _device_put up and one _device_get down, under the armed transfer
+    guard once the first macro-step has compiled."""
+    import scalerl_tpu.genrl.continuous as cont_mod
 
-    eng = _engine()
+    eng = _engine(steps_in_flight=1)
     puts, gets = [], []
-    real_put, real_get = engine_mod._device_put, engine_mod._device_get
+    real_put, real_get = cont_mod._device_put, cont_mod._device_get
     monkeypatch.setattr(
-        engine_mod, "_device_put", lambda x: (puts.append(1), real_put(x))[1]
+        cont_mod, "_device_put", lambda x: (puts.append(1), real_put(x))[1]
     )
     monkeypatch.setattr(
-        engine_mod, "_device_get", lambda x: (gets.append(1), real_get(x))[1]
+        cont_mod, "_device_get", lambda x: (gets.append(1), real_get(x))[1]
     )
     rng = np.random.default_rng(2)
-    prompts = rng.integers(2, 11, size=(4, 6)).astype(np.int32)
-    lengths = np.full(4, 6, np.int32)
-    eng.generate(prompts, lengths)  # cold: compiles
-    assert (len(puts), len(gets)) == (1, 1)
-    # warm round: steady_state_guard armed — zero violations means the
-    # whole decode loop ran without a single implicit host transfer
-    eng.generate(prompts, lengths)
-    assert (len(puts), len(gets)) == (2, 2)
-    assert len(eng._warm) == 1
+    prompts = rng.integers(2, 11, size=(4, P_MAX)).astype(np.int32)
+    for p in prompts:
+        eng.submit(p, P_MAX)
+    eng.step()  # cold: compiles; one prefill group (one bucket) + the table
+    assert (len(puts), len(gets)) == (2, 1)
+    assert eng._warm
+    steady = 0
+    while eng.live_lanes:
+        puts.clear()
+        gets.clear()
+        eng.step()  # nothing to admit: zero implicit transfers, or it raises
+        assert (len(puts), len(gets)) == (1, 1)
+        steady += 1
+    assert steady >= 1
 
 
 def test_engine_generation_tags_and_push_params():
     eng = _engine()
     rng = np.random.default_rng(3)
     prompts = rng.integers(2, 11, size=(2, 4)).astype(np.int32)
-    r0 = eng.generate(prompts)
-    assert r0.generation == 0
+    lengths = np.full(2, 4, np.int32)
+    assert [c.generation for c in _generate(eng, prompts, lengths)] == [0, 0]
     gen = eng.push_params(
         jax.tree_util.tree_map(lambda x: x * 0.5, eng._params)
     )
     assert gen == 1
-    r1 = eng.generate(prompts)
-    assert r1.generation == 1
+    assert [c.generation for c in _generate(eng, prompts, lengths)] == [1, 1]
 
 
 def test_engine_buckets_ragged_prompts_without_retrace():
-    """Prompt lengths inside one bucket reuse one compiled program; the
-    bucket is chosen by the batch's true max length."""
-    eng = _engine()
+    """Prompt lengths inside one bucket reuse one compiled prefill
+    program; a new bucket compiles once; the decode macro-step is traced
+    once whatever the prompts."""
+    eng = _engine(prefix_cache=False)
     rng = np.random.default_rng(4)
     short = rng.integers(2, 11, size=(3, 3)).astype(np.int32)
-    r = eng.generate(short, np.array([3, 2, 1], np.int32))
-    assert r.prompt_pad == 4  # 3 buckets up to 4 in the pow2 ladder
-    assert len(eng._programs) == 1
-    r2 = eng.generate(short[:, :2], np.array([2, 2, 1], np.int32))
-    assert r2.prompt_pad == 2
-    assert len(eng._programs) == 2  # a new bucket pair compiles once
-    r3 = eng.generate(short, np.array([3, 3, 3], np.int32))
-    assert r3.prompt_pad == 4
-    assert len(eng._programs) == 2  # back inside a warm bucket: no retrace
+    _generate(eng, short, np.array([3, 3, 3], np.int32))  # bucket 4
+    programs = len(eng._prefill_fns)
+    assert eng._prefill_traces == programs
+    _generate(eng, short, np.array([2, 2, 2], np.int32))  # bucket 2: new
+    assert len(eng._prefill_fns) == programs + 1
+    _generate(eng, short, np.array([3, 3, 3], np.int32))  # warm bucket
+    assert len(eng._prefill_fns) == programs + 1
+    assert eng._prefill_traces == len(eng._prefill_fns)
+    assert eng._decode_traces == 1
 
 
 def test_engine_eos_early_stop_masks_and_lengths():
-    """With an EOS id, lanes latch done on sampling it: later steps emit
-    EOS with a zero mask and response_len counts real tokens only."""
+    """With an EOS id, a lane latches done on sampling it: the harvested
+    response holds real tokens only, ends in EOS when it stopped short of
+    the budget, and packs into the learner layout with a mask that is 1
+    exactly on those tokens."""
     eng = _engine(eos_token=1)
     rng = np.random.default_rng(5)
-    prompts = rng.integers(2, 11, size=(8, 6)).astype(np.int32)
-    r = eng.generate(prompts, np.full(8, 6, np.int32))
-    for b in range(8):
-        n = int(r.response_len[b])
-        assert 0 < n <= r.response_pad
-        np.testing.assert_array_equal(r.mask[b, n:], 0.0)
-        if n < r.response_pad:
-            # the latch step sampled EOS (real, counted); everything after
-            # is forced EOS with mask 0
-            assert r.response_tokens[b, n - 1] == 1
-            np.testing.assert_array_equal(r.response_tokens[b, n:], 1)
+    prompts = rng.integers(2, 11, size=(8, P_MAX)).astype(np.int32)
+    done = _generate(eng, prompts, np.full(8, P_MAX, np.int32))
+    packed = pack_completions(done, 8, R_MAX)
+    for b, c in enumerate(done):
+        n = len(c.response_tokens)
+        assert 0 < n <= R_MAX and packed.response_len[b] == n
+        np.testing.assert_array_equal(packed.mask[b, :n], 1.0)
+        np.testing.assert_array_equal(packed.mask[b, n:], 0.0)
+        assert 1 not in c.response_tokens[:-1]
+        if n < R_MAX:
+            assert c.response_tokens[-1] == 1  # the latch step is real
+    assert any(len(c.response_tokens) < R_MAX for c in done)
 
 
 def test_engine_behavior_logp_matches_sampling_distribution():
@@ -243,34 +221,32 @@ def test_engine_behavior_logp_matches_sampling_distribution():
     token — recomputed here from the full forward."""
     eng = _engine()
     rng = np.random.default_rng(6)
-    prompts = rng.integers(2, 11, size=(3, 6)).astype(np.int32)
-    lengths = np.full(3, 6, np.int32)
-    r = eng.generate(prompts, lengths)
-    P, S = r.prompt_pad, r.prompt_pad + r.response_pad
-    m, params = eng.model, eng._params
-    lens = jnp.asarray(r.prompt_len)
-    full = m.apply(
-        params, jnp.asarray(r.sequences),
-        positions=sequence_positions(lens, P, S),
-        attn_mask=sequence_attention_mask(lens, P, S),
-    )
-    logp_all = jax.nn.log_softmax(full.policy_logits[:, P - 1:S - 1], -1)
-    expect = np.take_along_axis(
-        np.asarray(logp_all), r.response_tokens[..., None], axis=-1
-    )[..., 0]
-    np.testing.assert_allclose(r.behavior_logp, expect, atol=1e-4)
+    prompts = rng.integers(2, 11, size=(3, P_MAX)).astype(np.int32)
+    lengths = np.array([6, 4, 2], np.int32)
+    done = _generate(eng, prompts, lengths)
+    S = P_MAX + R_MAX
+    seq = left_padded(prompts, lengths, P_MAX, S)
+    for b, c in enumerate(done):
+        seq[b, P_MAX:] = c.response_tokens
+    full = full_forward(eng.model, eng._params, seq, lengths, P_MAX)
+    logp_all = jax.nn.log_softmax(full.policy_logits[:, P_MAX - 1:S - 1], -1)
+    for b, c in enumerate(done):
+        expect = np.asarray(logp_all)[b, np.arange(R_MAX), c.response_tokens]
+        np.testing.assert_allclose(c.behavior_logp, expect, atol=1e-4)
 
 
 def test_generation_config_validation():
     with pytest.raises(ValueError):
-        GenerationConfig(vocab_size=1).validate()
+        ContinuousConfig(vocab_size=1).validate()
     with pytest.raises(ValueError):
-        GenerationConfig(vocab_size=8, temperature=-0.5).validate()
-    GenerationConfig(vocab_size=8, temperature=0.0).validate()  # greedy
+        ContinuousConfig(vocab_size=8, temperature=-0.5).validate()
+    ContinuousConfig(vocab_size=8, temperature=0.0).validate()  # greedy
     with pytest.raises(ValueError):
-        GenerationConfig(vocab_size=8, top_k=9).validate()
+        ContinuousConfig(vocab_size=8, top_k=9).validate()
     with pytest.raises(ValueError):
-        GenerationConfig(vocab_size=8, eos_token=8).validate()
+        ContinuousConfig(vocab_size=8, eos_token=8).validate()
+    with pytest.raises(ValueError):
+        ContinuousConfig(vocab_size=8, max_new_tokens=0).validate()
 
 
 # ---------------------------------------------------------------------------
@@ -300,24 +276,23 @@ def test_token_copy_task_scoring():
     np.testing.assert_allclose(rew, [2 / 3])
 
 
-def test_pack_sequences_fields_and_priorities():
+def test_packed_completions_fields_and_priorities():
     eng = _engine()
     rng = np.random.default_rng(7)
-    prompts = rng.integers(2, 11, size=(4, 6)).astype(np.int32)
-    r = eng.generate(prompts, np.full(4, 6, np.int32))
+    prompts = rng.integers(2, 11, size=(4, P_MAX)).astype(np.int32)
+    done = _generate(eng, prompts, np.full(4, P_MAX, np.int32))
+    packed = pack_completions(done, 8, R_MAX)
     rewards = np.array([0.0, 0.25, 0.5, 1.0], np.float32)
-    fields, prios = pack_sequences(r, rewards)
-    S = r.prompt_pad + r.response_pad
-    assert fields["tokens"].shape == (4, S)
-    assert fields["behavior_logp"].shape == (4, r.response_pad)
+    fields, prios = packed.fields(rewards)
+    assert fields["tokens"].shape == (4, 8 + R_MAX)
+    assert fields["behavior_logp"].shape == (4, R_MAX)
     np.testing.assert_array_equal(fields["reward"], rewards)
     np.testing.assert_array_equal(fields["generation"], 0)
     np.testing.assert_array_equal(prios, 1.0)
     # explicit priorities are floored away from the empty-slot sentinel
-    _f, prios = pack_sequences(r, rewards, priorities=np.zeros(4))
+    _f, prios = packed.fields(rewards, priorities=np.zeros(4))
     assert (prios >= 1e-6).all()
-    shapes = sequence_field_shapes(r.prompt_pad, r.response_pad)
-    assert set(shapes) == set(fields)
+    assert set(sequence_field_shapes(8, R_MAX)) == set(fields)
 
 
 # ---------------------------------------------------------------------------
