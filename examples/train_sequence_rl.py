@@ -1,9 +1,9 @@
 """Sequence-RL training entry point: token-PPO on the generation engine.
 
 The token-level generate -> score -> learn plane (docs/SEQUENCE_RL.md):
-the KV-cached GenerationEngine decodes whole response batches in one
-jitted program per bucket pair, the hermetic recall/copy verifier scores
-them on the host, and the token-PPO learner trains off the prioritized
+the continuous-batching engine decodes a persistent lane pool over a paged
+KV cache one jitted macro-step at a time, the hermetic recall/copy verifier
+scores the completed sequences on the host, and the token-PPO learner trains off the prioritized
 sequence replay with per-token importance ratios.  The dp×mp mesh
 resolves from the args alone, exactly like the other trainer families.
 
@@ -18,10 +18,10 @@ Sharded learner (8 virtual devices, dp=4 × mp=2)::
     python examples/train_sequence_rl.py --dp-size 4 --mp-size 2 \
         --d-model 256 --n-layers 4 --genrl-rounds 200
 
-Continuous-batching generation (paged KV lane pool; ISSUE 11,
-docs/SEQUENCE_RL.md "Continuous batching")::
+The engine's geometry (paged KV lane pool; docs/SEQUENCE_RL.md
+"Continuous batching")::
 
-    python examples/train_sequence_rl.py --genrl-engine continuous \
+    python examples/train_sequence_rl.py \
         --genrl-lanes 32 --genrl-page-size 8 --genrl-macro-steps 4
 
 GRPO-shaped group sampling over the shared-prefix CoW cache (ISSUE 14,
@@ -30,7 +30,7 @@ samples genrl_batch / samples_per_prompt distinct prompts and decodes
 samples_per_prompt completions per prompt, the group forking off ONE
 prompt prefill; steps-in-flight pipelines admission under decode::
 
-    python examples/train_sequence_rl.py --genrl-engine continuous \
+    python examples/train_sequence_rl.py \
         --genrl-lanes 32 --samples-per-prompt 8 \
         --genrl-steps-in-flight 2
 
@@ -40,7 +40,7 @@ segment ids, the learn step runs segment-blocked causal attention (the
 Pallas flash kernel on TPU), and no learn FLOP is spent on pad::
 
     python examples/train_sequence_rl.py --learner-packing \
-        --genrl-engine continuous --genrl-lanes 32
+        --genrl-lanes 32
 """
 
 import os

@@ -787,11 +787,12 @@ class GenRLArguments(RLArguments):
     # iter_mode verdict — unroll on XLA:CPU, scan on TPU/GPU).
     genrl_iter_mode: str = "auto"
 
-    # Engine selection (ISSUE 11): "cohort" = the fixed-cohort bucket-pair
-    # engine (one jitted round, every lane runs the full response bucket);
-    # "continuous" = the persistent continuous-batching engine (paged KV,
-    # macro-steps, admission into freed lanes).  The trainer rides either.
-    genrl_engine: str = "cohort"
+    # The one generation engine: the persistent continuous-batching
+    # engine (paged KV, macro-steps, admission into freed lanes).  The
+    # field accepts only "continuous"; it is kept because the benchmark's
+    # rollout drivers and chip_smoke.py still pass --genrl-engine
+    # (ROADMAP.md D13 drops it).
+    genrl_engine: str = "continuous"
     genrl_lanes: int = 0  # continuous decode lanes; 0 -> genrl_batch
     genrl_page_size: int = 8  # KV pool page size (tokens per page)
     genrl_num_pages: int = 0  # KV pool pages; 0 -> all-lane worst case
@@ -804,9 +805,8 @@ class GenRLArguments(RLArguments):
     genrl_paged_attn: str = "auto"  # pallas | xla | auto (backend)
     # Group sampling (ISSUE 14): generate this many completions per
     # prompt — the GRPO data layout.  Rounds sample genrl_batch /
-    # samples_per_prompt distinct prompts; on the continuous engine each
-    # group admits via submit_group (shared-prefix CoW fork, ~1/n of the
-    # prefill), on the cohort engine prompts are tiled (layout only).
+    # samples_per_prompt distinct prompts; each group admits via
+    # submit_group (shared-prefix CoW fork, ~1/n of the prefill).
     samples_per_prompt: int = 1
     # Macro-step pipelining: K macro dispatches in flight, host read
     # lagging by K-1 so harvest/admission/prefill overlap device decode
@@ -916,10 +916,10 @@ class GenRLArguments(RLArguments):
                 "genrl_iter_mode must be auto | scan | unroll, got "
                 f"{self.genrl_iter_mode!r}"
             )
-        if self.genrl_engine not in ("cohort", "continuous"):
+        if self.genrl_engine != "continuous":
             raise ValueError(
-                "genrl_engine must be cohort | continuous, got "
-                f"{self.genrl_engine!r}"
+                "genrl_engine must be 'continuous': the cohort engine is "
+                f"gone (PR 28), got {self.genrl_engine!r}"
             )
         if self.genrl_lanes < 0 or self.genrl_page_size < 1:
             raise ValueError(
@@ -950,12 +950,6 @@ class GenRLArguments(RLArguments):
             raise ValueError(
                 f"genrl_steps_in_flight must be >= 1, got "
                 f"{self.genrl_steps_in_flight}"
-            )
-        if self.spec_enable and self.genrl_engine != "continuous":
-            raise ValueError(
-                "spec_enable requires genrl_engine='continuous' (the "
-                "cohort engine's fused round has no verify pass), got "
-                f"{self.genrl_engine!r}"
             )
         if self.spec_enable and self.spec_k < 1:
             raise ValueError(

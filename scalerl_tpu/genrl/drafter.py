@@ -3,7 +3,7 @@ table per lane (ISSUE 16).
 
 The continuous engine's speculation loop needs k-token proposals between
 macro-steps, and it needs them WITHOUT a second model — a draft model
-would have to ride the :class:`~scalerl_tpu.genrl.engine
+would have to ride the :class:`~scalerl_tpu.runtime.param_server
 .ParamSnapshotPlane` through every ``push_params``, doubling the snapshot
 wire and adding a whole second forward to the hot loop.  Instead each lane
 drafts from its OWN context (prompt + tokens generated so far), the

@@ -5,9 +5,9 @@ ladder so its jitted programs compile once per bucket and never retrace on
 arrival patterns (graftlint JG003 designed out rather than linted out):
 
 - the serving plane buckets *batch lanes* (``serving/batcher.py``);
-- the generation engines bucket *prompt/response lengths* on the time axis
-  (``genrl/engine.py``, ``genrl/continuous.py``) and the continuous
-  engine additionally buckets *admitted-prefill batch sizes*;
+- the generation engine buckets *prompt/response lengths* on the time axis
+  (``genrl/continuous.py``) and additionally buckets *admitted-prefill
+  batch sizes*;
 - the page allocator sizes page tables off the largest bucket pair.
 
 Extracted here (ISSUE 11) so the ladder has ONE definition and direct unit
