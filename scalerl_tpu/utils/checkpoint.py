@@ -28,7 +28,6 @@ from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
-import orbax.checkpoint as ocp
 
 from scalerl_tpu.utils.logging import get_logger
 
@@ -132,6 +131,13 @@ def save_checkpoint(path: str, state: Any, keep_last: int = 1) -> str:
     new checkpoint has landed (still no unprotected window — the delete
     happens strictly after the rename).
     """
+    # orbax is imported where a checkpoint is written or read, not with
+    # this module: its import pulls google.cloud.logging, whose packages
+    # each walk every installed distribution's file list, 9 s of every
+    # process start on the chip's host and 10-25 s more when the walk falls
+    # on a fragmented heap (PERF.md, PR 28)
+    import orbax.checkpoint as ocp
+
     path = os.path.abspath(path)
     tmp = path + ".tmp"
     checkpointer = ocp.StandardCheckpointer()
@@ -207,6 +213,8 @@ def load_checkpoint(
 
 
 def _restore(path: str, target: Optional[Any]) -> Any:
+    import orbax.checkpoint as ocp
+
     checkpointer = ocp.StandardCheckpointer()
     if target is not None:
         abstract = jax.tree_util.tree_map(ocp.utils.to_shape_dtype_struct, target)

@@ -1,8 +1,8 @@
-"""Continuous-batching decode plane (ISSUE 11): fixed-cohort parity at
-temperature 0, the one-batched-transfer-per-macro-step discipline, zero
-retraces after warmup, EOS/variable-length harvesting, page exhaustion
-backpressure, fragmentation independence, quantized snapshot pushes, and
-the trainer riding either engine.
+"""Continuous-batching decode plane (ISSUE 11): parity with the greedy full
+forward at temperature 0, the one-batched-transfer-per-macro-step
+discipline, zero retraces after warmup, EOS/variable-length harvesting,
+page exhaustion backpressure, fragmentation independence, quantized
+snapshot pushes, and the trainer riding the engine.
 """
 
 import numpy as np
@@ -11,13 +11,13 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from genrl_reference import greedy_full_forward
 from scalerl_tpu.config import GenRLArguments
 from scalerl_tpu.genrl.continuous import (
     CompletedSequence,
     ContinuousConfig,
     ContinuousEngine,
 )
-from scalerl_tpu.genrl.engine import GenerationConfig, GenerationEngine
 from scalerl_tpu.genrl.rollout import pack_completions, sequence_field_shapes
 from scalerl_tpu.models.transformer import TransformerPolicy
 from scalerl_tpu.runtime import telemetry
@@ -36,23 +36,16 @@ def _model():
 
 @pytest.fixture(scope="module")
 def setup():
-    """One model + one fixed engine + one continuous engine, both greedy
-    (temperature 0), plus the fixed engine's reference round — shared by
-    the parity / transfer / retrace / fragmentation tests to keep compiles
-    off the tier-1 clock."""
+    """One model + one greedy (temperature 0) engine, plus the reference
+    round: greedy decoding by the full forward (``model.apply`` on the
+    whole sequence) — shared by the parity / transfer / retrace /
+    fragmentation tests to keep compiles off the tier-1 clock."""
     m = _model()
     params = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
     rng = np.random.default_rng(1)
     prompts = rng.integers(2, V, size=(5, P_MAX)).astype(np.int32)
     lengths = np.array([6, 4, 3, 2, 1], np.int32)
-    fixed = GenerationEngine(
-        m, params,
-        GenerationConfig(
-            vocab_size=V, max_prompt_len=P_MAX, max_new_tokens=R_MAX,
-            temperature=0.0, seed=7,
-        ),
-    )
-    ref = fixed.generate(prompts, lengths)
+    ref = greedy_full_forward(m, params, prompts, lengths, P_MAX, R_MAX)
     cont = ContinuousEngine(
         m, params,
         ContinuousConfig(
@@ -63,7 +56,7 @@ def setup():
     )
     return dict(
         model=m, params=params, prompts=prompts, lengths=lengths,
-        fixed=fixed, ref=ref, cont=cont,
+        ref=ref, cont=cont,
     )
 
 
@@ -105,12 +98,12 @@ def test_engine_is_single_device_under_a_meshed_learner(setup):
     assert devices_of(engine._pools) == {engine._device}
 
 
-def test_greedy_parity_fixed_vs_continuous(setup):
-    """The acceptance pin: at temperature 0 the continuous engine's
-    token-level outputs for any single sequence are IDENTICAL to the
-    fixed-cohort path (exact tokens, 1e-5 behavior logprobs) — through a
-    completely different cache layout (paged vs dense, right- vs
-    left-padded prompts)."""
+def test_greedy_parity_with_the_full_forward(setup):
+    """The acceptance pin: at temperature 0 the engine's token-level
+    outputs for any single sequence are IDENTICAL to greedy decoding by
+    the full forward (exact tokens, 1e-5 behavior logprobs) — through a
+    completely different layout (paged cache and compact prompts against
+    no cache and left-padded rows)."""
     cont, ref = setup["cont"], setup["ref"]
     prompts, lengths = setup["prompts"], setup["lengths"]
     for i in range(5):
@@ -188,7 +181,7 @@ def test_zero_retraces_after_warmup(setup):
 
 def test_fragmentation_independence_of_results(setup):
     """After admit/finish churn has fragmented the page pool, the same
-    prompt still decodes to the same greedy tokens as the fixed-cohort
+    prompt still decodes to the same greedy tokens as the full-forward
     reference — results never depend on the physical page layout."""
     cont, ref = setup["cont"], setup["ref"]
     prompts, lengths = setup["prompts"], setup["lengths"]
@@ -214,24 +207,34 @@ def test_quantized_push_params_logits_parity(setup):
     m, params = setup["model"], setup["params"]
     prompts, lengths = setup["prompts"], setup["lengths"]
     ref = setup["ref"]
-    eng = setup["fixed"]
+    eng = ContinuousEngine(m, params, setup["cont"].config)
+
+    def generate():
+        for i in range(5):
+            eng.submit(prompts[i], lengths[i], tag=i)
+        return sorted(
+            eng.run_until(5, max_macro_steps=60), key=lambda c: c.tag
+        )
+
     gen = eng.push_params(params, quantize="int8")
     assert gen == 1
     snap1, _ = eng._snapshot_params()
     snap2, _ = eng._snapshot_params()
     assert snap1 is snap2  # dequant-on-read cached until the next push
-    r = eng.generate(prompts, lengths)
-    assert r.generation == 1
-    np.testing.assert_array_equal(r.response_tokens, ref.response_tokens)
-    np.testing.assert_allclose(
-        r.behavior_logp, ref.behavior_logp, atol=5e-2
-    )
+    for i, c in enumerate(generate()):
+        assert c.generation == 1
+        np.testing.assert_array_equal(
+            c.response_tokens, ref.response_tokens[i]
+        )
+        np.testing.assert_allclose(
+            c.behavior_logp, ref.behavior_logp[i], atol=5e-2
+        )
     # bf16 mode is tighter
     eng.push_params(params, quantize="bf16")
-    r = eng.generate(prompts, lengths)
-    np.testing.assert_allclose(
-        r.behavior_logp, ref.behavior_logp, atol=5e-2
-    )
+    for i, c in enumerate(generate()):
+        np.testing.assert_allclose(
+            c.behavior_logp, ref.behavior_logp[i], atol=5e-2
+        )
     # the serving plane exposes the same knob (non-learner replicas)
     import inspect
 
@@ -451,8 +454,7 @@ def test_submit_tag_rides_to_completion():
 
 
 def test_trainer_rides_continuous_engine():
-    """genrl_engine="continuous" swaps the engine under the SAME trainer
-    loop: rounds train, insert batches stay shape-stable via the
+    """The trainer loop over the engine: rounds train, insert batches stay shape-stable via the
     completion backlog, and staleness/decode metrics flow."""
     args = GenRLArguments(
         seed=3, vocab_size=8, prompt_len=4, max_new_tokens=4,
@@ -478,7 +480,7 @@ def test_trainer_rides_continuous_engine():
 def test_submit_group_cow_parity_and_prefill_savings(setup):
     """The acceptance pin for group sampling: submit_group(prompt, 8) at
     temperature 0 produces 8 completions TOKEN-IDENTICAL to the
-    fixed-cohort reference — 7 of them riding the leader's prompt pages
+    full-forward reference — 7 of them riding the leader's prompt pages
     copy-on-write — and the prefill-savings ratio hits the bench
     acceptance bar ((n-1)/n of full-page prefix tokens >= 0.8)."""
     m, params = setup["model"], setup["params"]
@@ -559,7 +561,7 @@ def test_pipelined_steps_in_flight_parity_and_lagged_reads(setup, monkeypatch):
     """K=3 macro-steps in flight: reads lag dispatch by K-1 (the first
     K-1 steps dispatch without reading), steady steps still do exactly
     ONE upload + ONE batched read under the armed guard, and the
-    completions stay token-identical to the fixed-cohort reference."""
+    completions stay token-identical to the full-forward reference."""
     import scalerl_tpu.genrl.continuous as cont_mod
 
     m, params = setup["model"], setup["params"]
@@ -766,10 +768,9 @@ def test_churn_grouped_admits_evictions_no_aliasing_token_identity():
     assert stats["evictions"] > 0  # flush/LRU reclaim genuinely fired
 
 
-def test_trainer_group_sampling_continuous_and_cohort():
-    """samples_per_prompt on both trainers: the continuous engine admits
-    via submit_group (prefill savings accrue), the cohort engine tiles
-    prompts (GRPO layout only) — both train a finite round."""
+def test_trainer_group_sampling():
+    """samples_per_prompt on the trainer: the engine admits via
+    submit_group (prefill savings accrue) and the round trains."""
     base = dict(
         seed=3, vocab_size=8, prompt_len=4, max_new_tokens=4,
         d_model=32, n_layers=1, n_heads=2,
@@ -788,15 +789,6 @@ def test_trainer_group_sampling_continuous_and_cohort():
     # prompt pages
     assert trainer.engine.prefix_tokens_saved > 0
     assert trainer.engine.prefix_saved_ratio >= 0.5
-    cohort = SequenceRLTrainer(GenRLArguments(**base))
-    result, rewards = cohort._generate_round()
-    assert len(rewards) == 8
-    # tiled layout: prompts within each group of 4 are identical
-    pl = result.prompt_len
-    for g in range(2):
-        rows = result.sequences[4 * g : 4 * (g + 1), : result.prompt_pad]
-        assert (rows == rows[0]).all()
-        assert (pl[4 * g : 4 * (g + 1)] == pl[4 * g]).all()
 
 
 def test_continuous_config_and_args_validation():
@@ -820,6 +812,9 @@ def test_continuous_config_and_args_validation():
     )
     with pytest.raises(ValueError):
         GenRLArguments(genrl_engine="paged", **argbase).validate()
+    with pytest.raises(ValueError, match="cohort engine is gone"):
+        GenRLArguments(genrl_engine="cohort", **argbase).validate()
+    assert GenRLArguments(**argbase).genrl_engine == "continuous"
     with pytest.raises(ValueError):
         GenRLArguments(genrl_page_size=0, **argbase).validate()
     with pytest.raises(ValueError):
@@ -853,8 +848,6 @@ def test_continuous_config_and_args_validation():
     with pytest.raises(ValueError):
         ContinuousConfig(spec_ngram=0, **base).validate()
     ContinuousConfig(spec_k=0, **base).validate()  # 0 = compiled out
-    with pytest.raises(ValueError):
-        GenRLArguments(spec_enable=True, **argbase).validate()  # fixed eng
     with pytest.raises(ValueError):
         GenRLArguments(
             genrl_engine="continuous", spec_enable=True, spec_k=0, **argbase

@@ -97,15 +97,13 @@ def test_wire_quantize_int8_roundtrip_and_passthrough():
 def test_parameter_server_shares_snapshot_plane_idiom():
     """Satellite: ParameterServer rides the ParamSnapshotPlane mixin —
     monotonic generation ids + device-side copy, the same idiom as the
-    InferenceServer and the generation engines."""
-    from scalerl_tpu.genrl.engine import (
-        ParamSnapshotPlane as engine_plane,
-    )
+    InferenceServer and the generation engine."""
+    from scalerl_tpu.genrl.continuous import ContinuousEngine
     from scalerl_tpu.serving.server import InferenceServer
 
     ps = ParameterServer()
     assert isinstance(ps, ParamSnapshotPlane)
-    assert engine_plane is ParamSnapshotPlane  # one class, re-exported
+    assert issubclass(ContinuousEngine, ParamSnapshotPlane)
     assert issubclass(InferenceServer, ParamSnapshotPlane)
     w = _weights()
     assert ps.push(w) == 1
@@ -394,12 +392,238 @@ def test_disagg_signal_source_and_staleness_rule():
 
 
 # ---------------------------------------------------------------------------
-# real engines over the wire (the jax path, thread hosts)
+# real engines over the wire (the jax path, thread hosts): the trainer's
+# default factory, a ContinuousEngine behind a ContinuousEngineShell
+
+
+def _shell_args(**kw):
+    from scalerl_tpu.config import GenRLArguments
+
+    base = dict(
+        vocab_size=12, prompt_len=4, max_new_tokens=4, d_model=32,
+        n_layers=1, n_heads=2, genrl_batch=4, genrl_sample_batch=4,
+        genrl_buffer_sequences=8, disagg_hosts=1, genrl_page_size=2,
+        genrl_macro_steps=2, eos_token=1, seed=5,
+        telemetry_interval_s=0.0, logger_backend="none",
+        disagg_round_timeout_s=120.0,
+    )
+    base.update(kw)
+    return GenRLArguments(**base)
+
+
+def _host_params(args, scale=1.0):
+    """A wire snapshot (host numpy) of the token model's seeded weights."""
+    import jax
+    import jax.numpy as jnp
+
+    from scalerl_tpu.runtime.param_server import _to_host
+    from scalerl_tpu.trainer.sequence_rl import build_genrl_model
+
+    params = build_genrl_model(args).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32)
+    )
+    return _to_host(jax.tree_util.tree_map(lambda x: x * scale, params))
+
+
+def _shell(args, lanes, generation=0):
+    """What a generation host builds from its first snapshot."""
+    from scalerl_tpu.trainer.sequence_rl import _EngineShellFactory
+
+    return _EngineShellFactory(args, lanes)(_host_params(args), generation)
+
+
+def _prompt_leases(n, samples=1, seed=0):
+    rng = np.random.default_rng(seed)
+    leases = []
+    for i in range(n):
+        m = int(rng.integers(1, 5))
+        lease = {
+            "seed": i + 1, "_task_id": 100 + i, "length": m,
+            "prompt": rng.integers(2, 12, size=m).astype(np.int32),
+        }
+        if samples > 1:
+            lease["samples"] = samples
+        leases.append(lease)
+    return leases
+
+
+def _run_shell(shell, leases, max_steps=400):
+    """The host loop's engine half: submit while there is capacity, step
+    while anything is live."""
+    queued, out = list(leases), []
+    for _ in range(max_steps):
+        while queued and shell.capacity() > 0:
+            shell.submit(queued.pop(0))
+        if shell.live() == 0 and not queued:
+            return out
+        out.extend(shell.step())
+    raise AssertionError(f"{shell.live()} leases still live")
+
+
+def test_continuous_shell_out_of_order_completions_close_their_lease():
+    """Lanes finish at ragged lengths (EOS at temperature 1), so payloads
+    come back in another order than their leases went in; each carries the
+    task id, prompt and length of the lease that admitted it."""
+    shell = _shell(_shell_args(), lanes=2)
+    leases = _prompt_leases(8)
+    payloads = _run_shell(shell, leases)
+    order = [p["_task_id"] for p in payloads]
+    assert sorted(order) == [lease["_task_id"] for lease in leases]
+    assert order != sorted(order)  # out of order, and still routed right
+    by_id = {lease["_task_id"]: lease for lease in leases}
+    for p in payloads:
+        lease = by_id[p["_task_id"]]
+        np.testing.assert_array_equal(p["prompt"], lease["prompt"])
+        assert p["prompt_len"] == lease["length"]
+        assert 1 <= len(p["response_tokens"]) <= 4
+        assert len(p["behavior_logp"]) == len(p["response_tokens"])
+        assert "_sample_idx" not in p
+    assert shell.live() == 0 and shell.capacity() == 2
+
+
+def test_continuous_shell_lease_larger_than_free_lanes_queues_and_completes():
+    """A fanned-out lease that finds fewer free lanes than samples waits in
+    the engine's admission queue (capacity counts its lanes as taken) and
+    is admitted whole when the lanes free; every sample arrives once."""
+    shell = _shell(_shell_args(), lanes=4)
+    first, second = _prompt_leases(2, samples=3)
+    shell.submit(first)
+    assert shell.capacity() == 1
+    shell.submit(second)  # 1 free lane, 3 wanted: queued, not dropped
+    assert shell.capacity() == -2 and shell.live() == 2
+    shell.step()
+    assert shell.engine.live_lanes == 3 and shell.engine.pending == 3
+    payloads = _run_shell(shell, [])
+    samples = {}
+    for p in payloads:
+        assert p["_samples_total"] == 3
+        samples.setdefault(p["_task_id"], []).append(p["_sample_idx"])
+    assert {k: sorted(v) for k, v in samples.items()} == {
+        first["_task_id"]: [0, 1, 2], second["_task_id"]: [0, 1, 2],
+    }
+    assert shell.live() == 0 and shell.capacity() == 4
+
+
+def test_continuous_shell_push_params_mid_lease_tags_later_sequences():
+    """The shell speaks the learner's generation ids: a sequence admitted
+    before a push carries the old wire generation even when it completes
+    after it, and every sequence admitted later carries the new one."""
+    args = _shell_args(eos_token=-1)  # every response runs its 4 tokens
+    shell = _shell(args, lanes=2, generation=7)
+    before, after = _prompt_leases(2)
+    shell.submit(before)
+    assert shell.step() == []  # admitted under generation 7, mid-decode
+    shell.push_params(_host_params(args, scale=0.5), 9)
+    assert shell.generation == 9
+    shell.submit(after)
+    payloads = {p["_task_id"]: p for p in _run_shell(shell, [])}
+    assert payloads[before["_task_id"]]["generation"] == 7
+    assert payloads[after["_task_id"]]["generation"] == 9
+    snapshot = _host_params(args)
+    for _ in range(70):  # the generation map stays bounded
+        shell.push_params(snapshot, 10)
+    assert len(shell._gen_map) <= 64
+
+
+def test_continuous_shell_host_killed_mid_decode_exactly_once():
+    """Two thread hosts built by the trainer's default factory; one loses
+    its link for good while its lanes are decoding.  Its leases requeue to
+    the survivor: every lease is accepted exactly once, none lost, none
+    surfaced twice."""
+    import multiprocessing as mp
+
+    from scalerl_tpu.fleet.transport import PipeConnection
+    from scalerl_tpu.genrl.disagg import generation_host_main
+    from scalerl_tpu.trainer.sequence_rl import _EngineShellFactory
+
+    n = 14
+    args = _shell_args()
+    leases = _prompt_leases(n)
+    prompts = sorted(tuple(lease["prompt"].tolist()) for lease in leases)
+    lock = threading.Lock()
+
+    def source():
+        with lock:
+            if not leases:
+                return None
+            lease = leases.pop(0)
+            lease.pop("_task_id")  # the learner assigns its own
+            return lease
+
+    # the default heartbeat: a host that is compiling its engine answers
+    # no ping for seconds and must not be taken for dead
+    cfg = DisaggConfig(num_hosts=2, lanes_per_host=2, upload_batch=1)
+    learner = SequenceLearner(cfg, source)
+    learner.start()
+    learner.publish(_host_params(args), learner_step=0)
+    links, threads = [], []
+    for host_id in range(2):
+        parent, child = mp.Pipe(duplex=True)
+        links.append(PipeConnection(parent))
+        learner.add_host_connection(links[-1])
+        threads.append(threading.Thread(
+            target=generation_host_main,  # no reconnect seam: a lost link
+            args=(PipeConnection(child), cfg,  # ends the host
+                  _EngineShellFactory(args, 2), host_id),
+            daemon=True,
+        ))
+        threads[-1].start()
+    try:
+        warm = _collect(learner, 2, deadline_s=120.0)
+        assert len(warm) == 2, "the hosts never warmed up"
+        # the victim is whichever host holds leases right now (between an
+        # upload and its next lease request a host holds none)
+        deadline = time.monotonic() + 30.0
+        victim = None
+        while victim is None and time.monotonic() < deadline:
+            held = [conn for conn, _lease in list(learner._outstanding.values())]
+            victim = next((i for i in (0, 1) if links[i] in held), None)
+        assert victim is not None, "no host ever held a lease"
+        learner.hub.disconnect(links[victim])
+        assert learner.requeued_leases >= 1  # it held leases, mid-decode
+        seqs = warm + _collect(learner, n - 2, deadline_s=120.0)
+        assert len(seqs) == n
+        assert len({s["lease_id"] for s in seqs}) == n
+        assert sorted(tuple(s["prompt"].tolist()) for s in seqs) == prompts
+        assert learner.duplicate_sequences == 0
+        threads[victim].join(timeout=20.0)
+        assert not threads[victim].is_alive()
+    finally:
+        learner.stop()
+        for t in threads:
+            t.join(timeout=10.0)
+
+
+def test_disagg_trainer_default_factory_one_round():
+    """The tier-1 twin of the e2e below at its sizes, one host, one round:
+    DisaggSequenceRLTrainer with no engine_factory builds a
+    ContinuousEngine behind a ContinuousEngineShell in the host, and the
+    round is exactly-once."""
+    from scalerl_tpu.genrl.disagg import ContinuousEngineShell
+    from scalerl_tpu.trainer.sequence_rl import (
+        DisaggSequenceRLTrainer,
+        _EngineShellFactory,
+    )
+
+    args = _shell_args(eos_token=-1, genrl_page_size=8, genrl_macro_steps=4)
+    trainer = DisaggSequenceRLTrainer(args)
+    factory = trainer.fleet.engine_factory
+    assert isinstance(factory, _EngineShellFactory)
+    summary = trainer.train(1)
+    assert summary["rounds"] == 1.0
+    assert summary["wire_sequences"] >= args.genrl_batch
+    assert summary["staleness"] >= 0.0
+    assert trainer.learner.duplicate_sequences == 0
+    assert trainer.learner.duplicate_leases == 0
+    assert np.isfinite(summary["total_loss"])
+    shell = factory(_host_params(args), 3)
+    assert isinstance(shell, ContinuousEngineShell)
+    assert shell.capacity() == args.genrl_batch // args.disagg_hosts
 
 
 @pytest.mark.slow
 def test_disagg_trainer_e2e_real_engines():
-    """DisaggSequenceRLTrainer: real GenerationEngines behind the shells
+    """DisaggSequenceRLTrainer: real ContinuousEngines behind the shells
     stream wire sequences into the real replay + token-PPO learner; the
     unified staleness gauge reports learner steps."""
     from scalerl_tpu.config import GenRLArguments
@@ -478,7 +702,7 @@ def test_chaos_mass_kill_wave_mid_decode_exact_sequences(monkeypatch):
         )
         # exact unique accounting: no lost, no duplicate
         assert len({s["lease_id"] for s in seqs}) == n
-        assert {s["seed"] for s in seqs} == set(range(1, n + 1))
+        assert sorted(tuple(s["prompt"].tolist()) for s in seqs) == prompts
         # bit-exact payloads, wherever (and however often) they decoded
         for s in seqs:
             expect = scripted_sequence_payload(s["seed"], 8, 32, 1)

@@ -25,7 +25,8 @@ from scalerl_tpu.genrl.rollout import (
     greedy_pack,
     pack_learner_batch,
     packed_field_shapes,
-    packed_rows_from_result,
+    pack_completions,
+    packed_rows_from_completions,
 )
 from scalerl_tpu.models.transformer import (
     TransformerPolicy,
@@ -511,10 +512,11 @@ def test_packed_args_validation():
     _args(learner_pack_len=16).validate()
 
 
-def test_packed_rows_from_result_roundtrip():
-    """Cohort bridge: unpadding a GenerationResult and bin-packing keeps
-    every token/logp/value at its sequence's offsets."""
-    from scalerl_tpu.genrl.engine import GenerationConfig, GenerationEngine
+def test_packed_rows_from_completions_roundtrip():
+    """The engine's bridge: re-batching completed sequences and
+    bin-packing them keeps every token/logp/value at its sequence's
+    offsets."""
+    from scalerl_tpu.genrl.continuous import ContinuousConfig, ContinuousEngine
 
     V = 16
     model = TransformerPolicy(
@@ -522,22 +524,31 @@ def test_packed_rows_from_result_roundtrip():
         num_layers=1, max_len=16,
     )
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
-    eng = GenerationEngine(
+    eng = ContinuousEngine(
         model, params,
-        GenerationConfig(vocab_size=V, max_prompt_len=4, max_new_tokens=4),
+        ContinuousConfig(
+            vocab_size=V, max_prompt_len=4, max_new_tokens=4, eos_token=1,
+            lanes=4, page_size=2, steps_per_macro=2,
+        ),
     )
     rng = np.random.default_rng(0)
-    prompts = rng.integers(1, V, (4, 4)).astype(np.int32)
+    prompts = rng.integers(2, V, (4, 4)).astype(np.int32)
     lengths = np.array([2, 4, 3, 1], np.int32)
-    r = eng.generate(prompts, lengths)
+    for i in range(4):
+        eng.submit(prompts[i], int(lengths[i]))
+    done = eng.run_until(4, max_macro_steps=50)
+    decode_tokens = sum(len(c.response_tokens) for c in done)
     rewards = np.arange(4, dtype=np.float32)
-    pk = packed_rows_from_result(r, rewards, pack_len=8)
+    pk = packed_rows_from_completions(
+        pack_completions(done, 4, 4), rewards, pack_len=8
+    )
     assert isinstance(pk, PackedLearnerBatch)
     assert pk.sequences_packed == 4
-    assert pk.decode_tokens == r.decode_tokens
-    assert pk.real_tokens == int(lengths.sum()) + r.decode_tokens
+    assert pk.decode_tokens == decode_tokens
+    assert pk.real_tokens == int(lengths.sum()) + decode_tokens
     # every sequence's response logps survive packing, wherever it landed
     packed_logps = np.sort(pk.behavior_logp[pk.mask > 0])
     np.testing.assert_allclose(
-        packed_logps, np.sort(r.behavior_logp[r.mask > 0]), atol=0
+        packed_logps,
+        np.sort(np.concatenate([c.behavior_logp for c in done])), atol=0,
     )

@@ -404,52 +404,68 @@ def test_dp_mp_sharded_transformer_step_on_tpu(dp, mp):
     assert int(agent.state.step) == 2
 
 
+def _genrl_reference():
+    """``tests/genrl_reference.py``: greedy decoding by the full forward."""
+    import sys
+    from pathlib import Path
+
+    tests = str(Path(__file__).resolve().parent.parent / "tests")
+    if tests not in sys.path:
+        sys.path.insert(0, tests)
+    import genrl_reference
+
+    return genrl_reference
+
+
 @pytest.mark.usefixtures("f32_matmuls")
 def test_genrl_generation_round_on_tpu():
-    """One KV-cached generation round compiled on the chip (ISSUE 10): the
-    scan-fused decode loop at a TPU-shaped bucket pair, one dispatch + one
-    batched read, and the decode logprobs must match the full masked
-    forward recomputation on-device (the cache-vs-full parity proof under
-    real tiling/bf16-free f32 attention)."""
-    from scalerl_tpu.genrl.engine import GenerationConfig, GenerationEngine
-    from scalerl_tpu.models.transformer import (
-        TransformerPolicy,
-        sequence_attention_mask,
-        sequence_positions,
+    """Sampled generation compiled on the chip (ISSUE 10): the scan-fused
+    macro-step at a TPU-shaped bucket pair, every lane busy, and the
+    behaviour logprobs must match the full masked forward recomputation
+    on-device (the cache-vs-full parity proof under real tiling/bf16-free
+    f32 attention)."""
+    from scalerl_tpu.genrl.continuous import (
+        ContinuousConfig,
+        ContinuousEngine,
     )
+    from scalerl_tpu.models.transformer import TransformerPolicy
 
+    ref = _genrl_reference()
     V, P, R, B = 256, 64, 64, 16
     model = TransformerPolicy(
         num_actions=V, vocab_size=V, d_model=128, num_heads=4,
         num_layers=2, max_len=P + R,
     )
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
-    engine = GenerationEngine(
+    engine = ContinuousEngine(
         model, params,
-        GenerationConfig(vocab_size=V, max_prompt_len=P, max_new_tokens=R),
+        ContinuousConfig(
+            vocab_size=V, max_prompt_len=P, max_new_tokens=R, lanes=B,
+            page_size=16, steps_per_macro=8,
+        ),
         iter_mode="scan",
     )
     rng = np.random.default_rng(0)
     prompts = rng.integers(2, V, size=(B, P)).astype(np.int32)
     lengths = rng.integers(P // 2, P + 1, size=B).astype(np.int32)
-    result = engine.generate(prompts, lengths)
-    result = engine.generate(prompts, lengths)  # warm round under the guard
-    assert result.response_tokens.shape == (B, R)
-    assert np.isfinite(result.behavior_logp).all()
+    for i in range(B):
+        engine.submit(prompts[i], int(lengths[i]), tag=i)
+    done = sorted(engine.run_until(B, max_macro_steps=40), key=lambda c: c.tag)
+    assert [len(c.response_tokens) for c in done] == [R] * B
     # on-device parity: recompute the sampling distribution from the full
-    # masked forward over the packed sequences
-    lens = jnp.asarray(result.prompt_len)
+    # masked forward over the left-padded sequences
     S = P + R
-    full = model.apply(
-        params, jnp.asarray(result.sequences),
-        positions=sequence_positions(lens, P, S),
-        attn_mask=sequence_attention_mask(lens, P, S),
+    seq = ref.left_padded(prompts, lengths, P, S)
+    for b, c in enumerate(done):
+        seq[b, P:] = c.response_tokens
+    full = ref.full_forward(engine.model, params, seq, lengths, P)
+    logp_all = np.asarray(
+        jax.nn.log_softmax(full.policy_logits[:, P - 1:S - 1], -1)
     )
-    logp_all = jax.nn.log_softmax(full.policy_logits[:, P - 1:S - 1], -1)
-    expect = np.take_along_axis(
-        np.asarray(logp_all), result.response_tokens[..., None], axis=-1
-    )[..., 0]
-    np.testing.assert_allclose(result.behavior_logp, expect, atol=1e-3)
+    for b, c in enumerate(done):
+        assert np.isfinite(c.behavior_logp).all()
+        expect = logp_all[b, np.arange(R), c.response_tokens]
+        np.testing.assert_allclose(c.behavior_logp, expect, atol=1e-3)
 
 
 @pytest.mark.parametrize("precision,tol", [("highest", 2e-3), (None, 3e-2)])
@@ -546,16 +562,16 @@ def test_paged_decode_attention_ragged_at_the_cells_geometry(B, H, D):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
+@pytest.mark.usefixtures("f32_matmuls")
 def test_continuous_engine_macro_step_on_tpu():
     """One continuous-batching macro-step compiled on the chip: paged
     prefill into allocated pages, the fused multi-substep decode with the
     Pallas paged-attention kernel behind the attn seam, one batched read
-    — and greedy parity against the fixed-cohort engine on-device."""
+    — and greedy parity against the full forward on-device."""
     from scalerl_tpu.genrl.continuous import (
         ContinuousConfig,
         ContinuousEngine,
     )
-    from scalerl_tpu.genrl.engine import GenerationConfig, GenerationEngine
     from scalerl_tpu.models.transformer import TransformerPolicy
 
     V, P, R = 256, 64, 32
@@ -567,15 +583,9 @@ def test_continuous_engine_macro_step_on_tpu():
     rng = np.random.default_rng(1)
     prompts = rng.integers(2, V, size=(4, P)).astype(np.int32)
     lengths = rng.integers(P // 2, P + 1, size=4).astype(np.int32)
-    fixed = GenerationEngine(
-        model, params,
-        GenerationConfig(
-            vocab_size=V, max_prompt_len=P, max_new_tokens=R,
-            temperature=0.0,
-        ),
-        iter_mode="scan",
+    ref = _genrl_reference().greedy_full_forward(
+        model, params, prompts, lengths, P, R
     )
-    ref = fixed.generate(prompts, lengths)
     engine = ContinuousEngine(
         model, params,
         ContinuousConfig(
@@ -593,9 +603,8 @@ def test_continuous_engine_macro_step_on_tpu():
     }
     for i in range(4):
         c = done[tuple(prompts[i][: lengths[i]].tolist())]
-        n = int(ref.response_len[i])
         np.testing.assert_array_equal(
-            c.response_tokens, ref.response_tokens[i, :n]
+            c.response_tokens, ref.response_tokens[i]
         )
     assert engine._decode_traces == 1
 

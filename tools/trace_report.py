@@ -70,7 +70,6 @@ EDGE_CLASSES = {
     "serve.flush": "compute",
     "task.episode": "compute",
     "genrl.macro_step": "compute",
-    "genrl.generate_round": "compute",
     "genrl.admit": "compute",
     "genrl.dispatch": "compute",
     "genrl.harvest": "compute",
