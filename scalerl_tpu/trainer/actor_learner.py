@@ -41,7 +41,6 @@ from scalerl_tpu.runtime.param_server import ParameterServer
 from scalerl_tpu.runtime.rollout_queue import RolloutQueue
 from scalerl_tpu.runtime.supervisor import (
     CheckpointCadence,
-    PreemptionGuard,
     StallWatchdog,
 )
 from scalerl_tpu.trainer.base import BaseTrainer
@@ -431,7 +430,7 @@ class HostActorLearnerTrainer(HostPlaneMixin, BaseTrainer):
         # neither env frames nor learn steps advance for the deadline.
         # Installed after env construction so a failing factory cannot leak
         # signal handlers (the finally below owns the teardown).
-        guard = PreemptionGuard().install() if args.handle_preemption else None
+        guard = self.install_preemption_guard()
         watchdog: Optional[StallWatchdog] = None
         learn_progress = None
         if args.watchdog_timeout_s > 0:
@@ -759,7 +758,7 @@ class DeviceActorLearnerTrainer(BaseTrainer):
         # supervision: a preemption signal stops dispatch at the next chunk
         # boundary (in-flight chunks drain and count); the watchdog's
         # progress counter is bumped by the loop per dispatched chunk
-        guard = PreemptionGuard().install() if args.handle_preemption else None
+        guard = self.install_preemption_guard()
         watchdog: Optional[StallWatchdog] = None
         progress = None
         if args.watchdog_timeout_s > 0:

@@ -45,7 +45,6 @@ from scalerl_tpu.runtime.dispatch import get_metrics
 from scalerl_tpu.runtime.param_server import ParameterServer
 from scalerl_tpu.runtime.supervisor import (
     CheckpointCadence,
-    PreemptionGuard,
     StallWatchdog,
 )
 from scalerl_tpu.trainer.base import BaseTrainer
@@ -444,7 +443,7 @@ class ApexTrainer(BaseTrainer):
         # preemption (SIGTERM/SIGINT) -> save_resume at the next loop
         # boundary; stall watchdog dumps all-thread stacks + queue depths
         # when neither env steps nor learn steps advance for the deadline
-        guard = PreemptionGuard().install() if args.handle_preemption else None
+        guard = self.install_preemption_guard()
         watchdog: Optional[StallWatchdog] = None
         if args.watchdog_timeout_s > 0:
             watchdog = StallWatchdog(args.watchdog_timeout_s, name="apex")

@@ -87,6 +87,21 @@ class BaseTrainer:
                 {"seed": float(args.seed)}, prefix="run."
             )
 
+    # -- preemption ----------------------------------------------------
+    def install_preemption_guard(self):
+        """The loop's SIGTERM/SIGINT guard, or None with
+        ``handle_preemption`` off.  Where the guard's safe point writes a
+        checkpoint, orbax is imported here, at set-up: its import takes
+        9-29 s on the chip's host (PERF.md, PR 28) and must not fall inside
+        the preemption grace window."""
+        if not self.args.handle_preemption:
+            return None
+        if self.args.save_model and not self.args.disable_checkpoint:
+            import orbax.checkpoint  # noqa: F401
+        from scalerl_tpu.runtime.supervisor import PreemptionGuard
+
+        return PreemptionGuard().install()
+
     # -- resume checkpointing ------------------------------------------
     @property
     def resume_ckpt_path(self) -> str:

@@ -42,7 +42,6 @@ from scalerl_tpu.runtime.param_server import ParameterServer
 from scalerl_tpu.runtime.shm_ring import ShmRolloutRing, SlotSpec
 from scalerl_tpu.runtime.supervisor import (
     CheckpointCadence,
-    PreemptionGuard,
     StallWatchdog,
 )
 from scalerl_tpu.trainer.base import BaseTrainer
@@ -501,7 +500,7 @@ class ProcessActorLearnerTrainer(BaseTrainer):
         # supervision: preemption saves at the next slot boundary; watchdog
         # dumps stacks + ring occupancy when frames stop advancing (a wedged
         # actor fleet or a dead weight service both freeze this counter)
-        guard = PreemptionGuard().install() if args.handle_preemption else None
+        guard = self.install_preemption_guard()
         watchdog: Optional[StallWatchdog] = None
         if args.watchdog_timeout_s > 0:
             watchdog = StallWatchdog(

@@ -134,8 +134,9 @@ def save_checkpoint(path: str, state: Any, keep_last: int = 1) -> str:
     # orbax is imported where a checkpoint is written or read, not with
     # this module: its import pulls google.cloud.logging, whose packages
     # each walk every installed distribution's file list, 9 s of every
-    # process start on the chip's host and 10-25 s more when the walk falls
-    # on a fragmented heap (PERF.md, PR 28)
+    # process start on the chip's host and 10-25 s more after some import
+    # orders (PERF.md, PR 28).  A loop that saves at a preemption imports it
+    # at set-up (BaseTrainer.install_preemption_guard)
     import orbax.checkpoint as ocp
 
     path = os.path.abspath(path)

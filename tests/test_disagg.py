@@ -702,7 +702,7 @@ def test_chaos_mass_kill_wave_mid_decode_exact_sequences(monkeypatch):
         )
         # exact unique accounting: no lost, no duplicate
         assert len({s["lease_id"] for s in seqs}) == n
-        assert sorted(tuple(s["prompt"].tolist()) for s in seqs) == prompts
+        assert {s["seed"] for s in seqs} == set(range(1, n + 1))
         # bit-exact payloads, wherever (and however often) they decoded
         for s in seqs:
             expect = scripted_sequence_payload(s["seed"], 8, 32, 1)

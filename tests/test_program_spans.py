@@ -134,7 +134,8 @@ def import_checks():
         "import importlib, sys; importlib.import_module(sys.argv[1]); "
         "from scalerl_tpu.runtime import tracing; import jax; "
         "assert tracing.get_annotator() is jax.profiler.TraceAnnotation, tracing.get_annotator(); "
-        "print('loaded device_loop:', 'scalerl_tpu.runtime.device_loop' in sys.modules)"
+        "print('loaded device_loop:', 'scalerl_tpu.runtime.device_loop' in sys.modules); "
+        "print('loaded orbax:', any(m == 'orbax' or m.startswith(('orbax.', 'google.cloud.')) for m in sys.modules))"
     )
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(ROOT)}
     procs = {
@@ -151,3 +152,14 @@ def import_checks():
 def test_importing_a_hot_path_module_installs_the_annotator(import_checks, module):
     output, returncode = import_checks[module]
     assert returncode == 0, output[-2000:]
+
+
+@pytest.mark.parametrize("module", ["scalerl_tpu.genrl.continuous", "scalerl_tpu.agents.token_ppo"])
+def test_importing_a_hot_path_module_leaves_orbax_out(import_checks, module):
+    """No cell writes a checkpoint, and orbax's import (through
+    google.cloud.logging, two walks of every installed distribution) cost
+    the learn cells 9 to 29 s of set-up on the chip's host (PERF.md, PR 28):
+    `utils/checkpoint.py` imports it where a checkpoint is written."""
+    output, returncode = import_checks[module]
+    assert returncode == 0, output[-2000:]
+    assert "loaded orbax: False" in output, output[-2000:]

@@ -2,9 +2,12 @@
 checkpointing (SIGTERM -> resume round-trip), checkpoint retention/fallback,
 and the cadence/backoff primitives."""
 
+import json
 import os
 import shutil
 import signal
+import subprocess
+import sys
 import threading
 import time
 
@@ -220,6 +223,55 @@ def test_preemption_guard_flags_sigterm_without_dying():
         assert guard.received == signal.SIGTERM
     # handlers restored on exit
     assert signal.getsignal(signal.SIGTERM) != guard._handler
+
+
+@pytest.fixture(scope="module")
+def guard_installs(tmp_path_factory):
+    """``BaseTrainer.install_preemption_guard`` under three settings, in a
+    process of its own and in the one order that can tell them apart (an
+    imported orbax stays imported): was a guard installed, is orbax loaded."""
+    code = (
+        "import json, sys\n"
+        "from scalerl_tpu.config import RLArguments\n"
+        "from scalerl_tpu.trainer.base import BaseTrainer\n"
+        "out = {}\n"
+        "for name, kw in json.loads(sys.argv[2]):\n"
+        "    trainer = BaseTrainer(RLArguments(work_dir=sys.argv[1], logger_backend='none', **kw))\n"
+        "    guard = trainer.install_preemption_guard()\n"
+        "    out[name] = [guard is not None and guard._installed, 'orbax.checkpoint' in sys.modules]\n"
+        "    if guard is not None:\n"
+        "        guard.restore()\n"
+        "print(json.dumps(out))\n"
+    )
+    cases = [
+        ["no_guard", {"handle_preemption": False}],
+        ["nothing_to_write", {"save_model": False}],
+        ["checkpoints_disabled", {"disable_checkpoint": True}],
+        ["saves_at_preemption", {}],
+    ]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", code, str(tmp_path_factory.mktemp("guard")), json.dumps(cases)],
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": root}, cwd=root,
+        capture_output=True, text=True, timeout=180,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    return json.loads(done.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize(
+    "case, installed, orbax_loaded",
+    [
+        ("no_guard", False, False),
+        ("nothing_to_write", True, False),
+        ("checkpoints_disabled", True, False),
+        # the import (9-29 s on the chip's host, PERF.md PR 28) is paid at
+        # set-up, not inside the grace window after SIGTERM
+        ("saves_at_preemption", True, True),
+    ],
+)
+def test_guard_that_saves_imports_orbax_at_set_up(guard_installs, case, installed, orbax_loaded):
+    assert guard_installs[case] == [installed, orbax_loaded]
 
 
 def test_sigterm_mid_training_checkpoints_and_resumes(tmp_path):
