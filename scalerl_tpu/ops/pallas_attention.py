@@ -32,8 +32,9 @@ of input dtype (bf16 inputs feed the MXU directly).
 from __future__ import annotations
 
 import functools
+import math
 import os
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -389,234 +390,408 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 # ``genrl/rollout.py`` bin-packer's layout): ``segment_ids [B, T]`` give
 # every token its sequence id within the row (0 = pad), and a token
 # attends only causally WITHIN its own segment.  The kernel is the
-# training-grade twin of :func:`flash_attention` — same tiling, same
-# online-softmax accumulators, same FlashAttention-2 backward split —
-# plus segment-id block masking: each (q block, k block) grid step first
-# reduces the two id vectors to their live ranges (segments are
-# contiguous and ascending inside a row, pad is a zero tail, so the
-# nonzero ids in any block form one integer interval) and SKIPS the
-# matmuls entirely when the intervals cannot intersect — cross-segment
-# and pad-only blocks cost two [block] reductions, never a [bq, bk]
-# score tile.  That block skip is where the packed learner's FLOPs go
-# from O(rows * T^2) to O(sum of per-segment len^2).
+# training-grade twin of :func:`flash_attention` — same online-softmax
+# accumulators, same FlashAttention-2 backward split — with one
+# difference in how the work is cut up.  A grid step costs about a third
+# of a microsecond whatever it does, so a step here is not one score tile
+# but one tile of the stationary operand (q rows in the forward and dq
+# kernels, k rows in the dk/dv kernel) against a ``major`` block of the
+# streamed operand that usually is the whole row; the score tiles
+# ``[block_q, block_k]`` are walked by a loop INSIDE the step (a tile of
+# 512 rows; several heads a step measured within 7% either way and were
+# left out).  The loop's bounds are the segment-id block skip:
+# segments are contiguous and ascending inside a row and pad is a zero
+# tail, so the blocks a tile can pair with form one interval, computed
+# once a call from the ids (``_live_blocks``) and handed to the kernels as
+# prefetched scalars.  Cross-segment, above-diagonal and pad-only blocks
+# cost neither a grid step nor a loop iteration, and a major block outside
+# the interval is not fetched.  That skip is where the packed learner's
+# FLOPs go from O(rows * T^2) to O(sum of per-segment len^2).
 # ======================================================================
 
+# what one grid step may hold of v5e's 128 MiB of VMEM by the estimate
+# below, and the scoped limit handed to Mosaic (its default is 16 MiB)
+_SEG_VMEM_BUDGET = 24 * 2**20
+_SEG_VMEM_LIMIT = 32 * 2**20
+_SEG_BLOCK = 512  # score tile edge chosen when the caller names none
 
-def _seg_ranges(seg_vec):
-    """(min nonzero id, max id) of one block's id vector (pad = 0)."""
-    hi = jnp.max(seg_vec)
-    lo = jnp.min(jnp.where(seg_vec > 0, seg_vec, jnp.int32(_SEG_BIG)))
-    return lo, hi
+
+class SegmentTiling(NamedTuple):
+    """How one ``segment_flash_attention`` call is cut up."""
+
+    block_q: int  # rows of a score tile
+    block_k: int  # columns of a score tile
+    major: int  # rows of the streamed operand a grid step holds
+    t_pad: int  # T rounded up to whole major blocks
+
+    def grid(self, B: int, H: int, q_stationary: bool) -> Tuple[int, int, int, int]:
+        """``(B, H, tiles, majors)``: q tiles stationary in the forward and
+        dq calls, k tiles in the dk/dv call."""
+        rows = self.block_q if q_stationary else self.block_k
+        return (B, H, self.t_pad // rows, self.t_pad // self.major)
+
+    def grid_steps(self, B: int, H: int) -> Tuple[int, int]:
+        """Grid steps of (the forward and dq calls, the dk/dv call)."""
+        return math.prod(self.grid(B, H, True)), math.prod(self.grid(B, H, False))
 
 
-def _seg_block_live(i, j, q_seg, k_seg, block_q: int, block_k: int):
-    """Whether any (q, k) pair in tile (i, j) shares a live segment."""
-    q_lo, q_hi = _seg_ranges(q_seg)
-    k_lo, k_hi = _seg_ranges(k_seg)
-    return (
-        _causal_live(i, j, block_q, block_k)
-        & (q_hi > 0)
-        & (k_hi > 0)
-        & (q_lo <= k_hi)
-        & (k_lo <= q_hi)
+def _seg_vmem_bytes(bq: int, bk: int, major: int, D: int, itemsize: int) -> int:
+    """Upper estimate of the widest of the three kernels' VMEM: operands
+    double-buffered, a ``[rows, D]`` block padded to 128 lanes, a
+    ``[rows, 1]`` column (lse, delta, q-side ids) to a lane tile a row."""
+    row = _round_up(D, 128) * itemsize
+    col = 128 * 4
+    tile = max(bq, bk)
+    operands = 2 * (tile * (4 * row + 3 * col) + major * (2 * row + 3 * col))
+    scratch = tile * (2 * _round_up(D, 128) * 4 + 2 * col)
+    scores = 6 * bq * _round_up(bk, 128) * 4
+    return operands + scratch + scores
+
+
+def segment_flash_tiling(
+    T: int,
+    D: int,
+    dtype,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
+) -> SegmentTiling:
+    """The tiling ``segment_flash_attention`` runs at these shapes.
+
+    A block the caller names is honoured; one left ``None`` is
+    ``_SEG_BLOCK`` rows, or the whole row where that is shorter.  The
+    streamed operand's major block is the largest whole fraction of the
+    row that the VMEM budget holds: the whole of a 1,024-token row at any
+    head size in use, a quarter of an 8k-token one."""
+
+    def edge(block):
+        if block is None:  # a lane multiple, so that any T tiles legally
+            return min(_SEG_BLOCK, _round_up(T, 128 if T >= 128 else 8))
+        return min(block, _round_up(T, 8))
+
+    bq, bk = edge(block_q), edge(block_k)
+    unit = math.lcm(bq, bk)
+    itemsize = jnp.dtype(dtype).itemsize
+    total = -(-T // unit)
+    units = max(
+        u for u in range(1, total + 1)
+        if total % u == 0
+        and (u == 1 or _seg_vmem_bytes(bq, bk, unit * u, D, itemsize) <= _SEG_VMEM_BUDGET)
+    )
+    return SegmentTiling(bq, bk, unit * units, _round_up(T, unit * units))
+
+
+@functools.lru_cache(maxsize=None)
+def _note_tiling(shape: Tuple[int, ...], dtype: str, tl: SegmentTiling) -> None:
+    """The mechanism always engages, so its counter is its geometry: one
+    zero-length program span a traced shape (the cache is the "once"),
+    never one a step, so that a trace says which tiling ran."""
+    from scalerl_tpu.runtime import tracing
+
+    steps, steps_dkv = tl.grid_steps(shape[0], shape[2])
+    with tracing.span(
+        "segment_flash.tiling", kind="kernel", shape=list(shape), dtype=dtype,
+        grid_steps=steps, grid_steps_dkv=steps_dkv, **tl._asdict(),
+    ):
+        pass
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _live_blocks(seg: jnp.ndarray, tile: int, block: int, tiles_are_q: bool):
+    """``[lo, hi)`` per ``tile``-row tile of one operand: the ``block``-row
+    blocks of the other operand that hold a position some row of the tile
+    attends to (q tiles) or is attended from (k tiles).  Exact for the
+    packer's layout and a superset for any other, so the in-kernel masks
+    alone decide values.  Flattened ``[B * T/tile]`` int32, for SMEM.
+    Jitted so that a step of 24 layers traces it once, not 72 times."""
+    B, T = seg.shape
+    tiles = seg.reshape(B, T // tile, tile)
+    t_hi = tiles.max(-1)[..., None]
+    t_lo = jnp.where(tiles > 0, tiles, _SEG_BIG).min(-1)[..., None]
+    other = seg[:, None, :]
+    pos = jnp.arange(T, dtype=jnp.int32)
+    start = jnp.arange(T // tile, dtype=jnp.int32)[:, None] * tile
+    causal = pos < start + tile if tiles_are_q else pos >= start
+    live = (other > 0) & (other >= t_lo) & (other <= t_hi) & causal
+    first = jnp.min(jnp.where(live, pos, T), axis=-1)
+    last = jnp.max(jnp.where(live, pos, -1), axis=-1)
+    return (first // block).reshape(-1), ((last + block) // block).reshape(-1)
+
+
+def _seg_operands(seg: jnp.ndarray, tl: SegmentTiling):
+    """Segment ids as a q-side ``[B, T_p, 1]`` column and k-side
+    ``[B, T_p/bk, 1, bk]`` rows, so the kernels compare them by
+    broadcast and pick a k block by its leading index."""
+    seg = seg.astype(jnp.int32)
+    B, T = seg.shape
+    if T != tl.t_pad:
+        # pad tail rides segment id 0 -> masked everywhere by construction
+        seg = jnp.pad(seg, ((0, 0), (0, tl.t_pad - T)))
+    return seg, seg[:, :, None], seg.reshape(B, -1, 1, tl.block_k)
+
+
+def _seg_specs(tl: SegmentTiling, q_stationary: bool):
+    """BlockSpec makers for a grid ``(B, H, tiles, majors)``: tiles of
+    the stationary operand, major blocks of the streamed one; index maps
+    also receive the two prefetched ``_live_blocks`` arrays."""
+    rows = tl.block_q if q_stationary else tl.block_k
+    block = tl.block_k if q_stationary else tl.block_q
+    n_tiles, n_major = tl.t_pad // rows, tl.t_pad // tl.major
+    per_major = tl.major // block
+
+    def major(b, t, m, lo_ref, hi_ref):
+        """Major block ``m``, held inside the tile's live interval so that
+        a step with nothing to do re-names the resident block: no copy."""
+        if n_major == 1:
+            return 0
+        first = jnp.minimum(lo_ref[b * n_tiles + t] // per_major, n_major - 1)
+        last = jnp.clip((hi_ref[b * n_tiles + t] - 1) // per_major, first, n_major - 1)
+        return jnp.clip(m, first, last)
+
+    def tile(width):
+        return pl.BlockSpec(
+            (None, None, rows, width), lambda b, h, t, m, lo, hi: (b, h, t, 0)
+        )
+
+    def stream(width):
+        return pl.BlockSpec(
+            (None, None, tl.major, width),
+            lambda b, h, t, m, lo, hi: (b, h, major(b, t, m, lo, hi), 0),
+        )
+
+    if q_stationary:
+        qseg = pl.BlockSpec((None, rows, 1), lambda b, h, t, m, lo, hi: (b, t, 0))
+        kseg = pl.BlockSpec(
+            (None, per_major, 1, block),
+            lambda b, h, t, m, lo, hi: (b, major(b, t, m, lo, hi), 0, 0),
+        )
+    else:
+        qseg = pl.BlockSpec(
+            (None, tl.major, 1),
+            lambda b, h, t, m, lo, hi: (b, major(b, t, m, lo, hi), 0),
+        )
+        kseg = pl.BlockSpec((None, 1, 1, rows), lambda b, h, t, m, lo, hi: (b, t, 0, 0))
+    return tile, stream, qseg, kseg
+
+
+def _seg_pallas_call(
+    kernel, name, grid, in_specs, out_specs, out_shape, scratch_shapes, interpret
+):
+    return pl.pallas_call(
+        kernel,
+        name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # the two _live_blocks arrays
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes,
+        ),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_SEG_VMEM_LIMIT,
+        ),
+        interpret=interpret,
     )
 
 
-def _seg_mask_block(
-    i, j, q_seg, k_seg, q_len: int, block_q: int, block_k: int
-):
-    """[bq, bk] validity: in-bounds, causal, same nonzero segment."""
-    mask = _mask_block(i, j, q_len, q_len, block_q, block_k, causal=True)
-    return mask & (q_seg == k_seg) & (q_seg > 0)
+def _seg_loop_bounds(lo_ref, hi_ref, tile, m, per_major: int):
+    """The tile's live blocks that lie in major block ``m``, as indices
+    into that major block (an empty range when there are none)."""
+    lo = jnp.maximum(lo_ref[tile] - m * per_major, 0)
+    hi = jnp.minimum(hi_ref[tile] - m * per_major, per_major)
+    return lo, hi
+
+
+def _seg_mask(rel, gap, q_seg, k_seg):
+    """[bq, bk] validity: causal, same nonzero segment.  ``rel`` is column
+    minus row inside the tile, ``gap`` the tile's first q position minus
+    its first k position; pad (id 0) never passes, so neither does the
+    tail that rounds T up."""
+    return (rel <= gap) & (q_seg == k_seg) & (q_seg > 0)
+
+
+def _rel(bq: int, bk: int):
+    return jax.lax.broadcasted_iota(
+        jnp.int32, (bq, bk), 1
+    ) - jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
 
 
 def _seg_fwd_kernel(
-    q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref,
+    lo_ref, hi_ref, q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref,
     acc_sc, m_sc, l_sc,
-    *, scale, q_len, block_q, block_k, nk,
+    *, scale, tl: SegmentTiling,
 ):
-    i = pl.program_id(2)
-    j = pl.program_id(3)
+    b, i, km = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    bq, bk = tl.block_q, tl.block_k
 
-    @pl.when(j == 0)
+    @pl.when(km == 0)
     def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
 
+    lo, hi = _seg_loop_bounds(
+        lo_ref, hi_ref, b * pl.num_programs(2) + i, km, tl.major // bk
+    )
+    q = q_ref[...].astype(jnp.float32) * scale
     q_seg = qseg_ref[...]  # [bq, 1]
-    k_seg = kseg_ref[...]  # [1, bk]
-    live = _seg_block_live(i, j, q_seg, k_seg, block_q, block_k)
+    rel = _rel(bq, bk)
+    gap0 = i * bq - km * tl.major
 
-    @pl.when(live)
-    def _attend():
-        q = q_ref[...].astype(jnp.float32) * scale
-        k_blk = k_ref[...].astype(jnp.float32)
-        v_blk = v_ref[...].astype(jnp.float32)
+    def k_block(j, carry):
+        cols = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        k_blk = k_ref[cols, :].astype(jnp.float32)
+        v_blk = v_ref[cols, :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        mask = _seg_mask_block(i, j, q_seg, k_seg, q_len, block_q, block_k)
+        mask = _seg_mask(rel, gap0 - j * bk, q_seg, kseg_ref[j])
         s = jnp.where(mask, s, _NEG_INF)
-        m = m_sc[:]
-        l = l_sc[:]
+        m = m_sc[...]
+        l = l_sc[...]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         safe_m = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
         p = jnp.exp(s - safe_m)
         corr = jnp.exp(jnp.where(jnp.isneginf(m), _NEG_INF, m) - safe_m)
-        l_sc[:] = l * corr + p.sum(axis=-1, keepdims=True)
-        m_sc[:] = m_new
-        acc_sc[:] = acc_sc[:] * corr + jax.lax.dot_general(
+        l_sc[...] = l * corr + p.sum(axis=-1, keepdims=True)
+        m_sc[...] = m_new
+        acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
             p, v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        return carry
 
-    @pl.when(j == nk - 1)
+    jax.lax.fori_loop(lo, hi, k_block, 0)
+
+    @pl.when(km == pl.num_programs(3) - 1)
     def _finish():
-        l = l_sc[:]
-        m = m_sc[:]
+        l = l_sc[...]
+        m = m_sc[...]
         # fully-masked rows (pad queries) emit exact zeros, matching the
         # reference — their outputs are unused but must stay finite
-        o_ref[...] = (
-            acc_sc[:] / jnp.maximum(l, 1e-30)
-        ).astype(o_ref.dtype)
+        o_ref[...] = (acc_sc[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         lse_ref[...] = jnp.where(
             l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)), _NEG_INF
         )
 
 
-def _seg_operands(seg: jnp.ndarray, t_pad: int):
-    """Segment ids as a q-side ``[B, t_pad, 1]`` column and a k-side
-    ``[B, 1, t_pad]`` row, so the kernels compare them by broadcast."""
-    seg = seg.astype(jnp.int32)
-    T = seg.shape[1]
-    if T != t_pad:
-        # pad tail rides segment id 0 -> masked everywhere by construction
-        seg = jnp.pad(seg, ((0, 0), (0, t_pad - T)))
-    return seg[:, :, None], seg[:, None, :]
-
-
-def _seg_specs(bq: int, bk: int, q_axis: int, k_axis: int):
-    return [
-        pl.BlockSpec((None, bq, 1), lambda *g: (g[0], g[q_axis], 0)),
-        pl.BlockSpec((None, 1, bk), lambda *g: (g[0], 0, g[k_axis])),
-    ]
-
-
 def _seg_fwd(q, k, v, seg, scale, block_q, block_k, interpret):
     B, T, H, D = q.shape
-    bq, bk, T_p, _ = _blocks(T, T, block_q, block_k)
-    nq, nk = T_p // bq, T_p // bk
-    qh, kh, vh = (_heads_first(x, T_p) for x in (q, k, v))
-    qseg, kseg = _seg_operands(seg, T_p)
+    tl = segment_flash_tiling(T, D, q.dtype, block_q, block_k)
+    _note_tiling(q.shape, jnp.dtype(q.dtype).name, tl)
+    qh, kh, vh = (_heads_first(x, tl.t_pad) for x in (q, k, v))
+    seg_p, qseg, kseg = _seg_operands(seg, tl)
+    tile, stream, qseg_spec, kseg_spec = _seg_specs(tl, q_stationary=True)
 
-    kernel = functools.partial(
-        _seg_fwd_kernel, scale=scale, q_len=T,
-        block_q=bq, block_k=bk, nk=nk,
-    )
-    o, lse = pl.pallas_call(
-        kernel,
-        name="segment_flash_fwd",
-        grid=(B, H, nq, nk),
-        in_specs=[
-            _tile(bq, D, 2), _tile(bk, D, 3), _tile(bk, D, 3),
-            *_seg_specs(bq, bk, 2, 3),
-        ],
-        out_specs=[_tile(bq, D, 2), _tile(bq, 1, 2)],
+    o, lse = _seg_pallas_call(
+        functools.partial(_seg_fwd_kernel, scale=scale, tl=tl),
+        "segment_flash_fwd", tl.grid(B, H, True),
+        in_specs=[tile(D), stream(D), stream(D), qseg_spec, kseg_spec],
+        out_specs=[tile(D), tile(1)],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, T_p, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, T_p, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, tl.t_pad, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, tl.t_pad, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((tl.block_q, D), jnp.float32),
+            pltpu.VMEM((tl.block_q, 1), jnp.float32),
+            pltpu.VMEM((tl.block_q, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(qh, kh, vh, qseg, kseg)
+    )(*_live_blocks(seg_p, tl.block_q, tl.block_k, True), qh, kh, vh, qseg, kseg)
     return _heads_last(o, T), lse
 
 
 def _seg_bwd_dq_kernel(
-    q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref,
-    dq_ref, dq_sc,
-    *, scale, q_len, block_q, block_k, nk,
+    lo_ref, hi_ref, q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref, lse_ref,
+    delta_ref, dq_ref, dq_sc,
+    *, scale, tl: SegmentTiling,
 ):
-    i = pl.program_id(2)
-    j = pl.program_id(3)
+    b, i, km = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    bq, bk = tl.block_q, tl.block_k
 
-    @pl.when(j == 0)
+    @pl.when(km == 0)
     def _init():
-        dq_sc[:] = jnp.zeros_like(dq_sc)
+        dq_sc[...] = jnp.zeros_like(dq_sc)
 
+    lo, hi = _seg_loop_bounds(
+        lo_ref, hi_ref, b * pl.num_programs(2) + i, km, tl.major // bk
+    )
+    q = q_ref[...].astype(jnp.float32) * scale
+    do = do_ref[...].astype(jnp.float32)
+    lse = lse_ref[...]
+    delta = delta_ref[...]
+    safe_lse = jnp.where(jnp.isneginf(lse), 0.0, lse)
     q_seg = qseg_ref[...]  # [bq, 1]
-    k_seg = kseg_ref[...]  # [1, bk]
-    live = _seg_block_live(i, j, q_seg, k_seg, block_q, block_k)
+    rel = _rel(bq, bk)
+    gap0 = i * bq - km * tl.major
 
-    @pl.when(live)
-    def _accumulate():
-        q = q_ref[...].astype(jnp.float32) * scale
-        do = do_ref[...].astype(jnp.float32)
-        lse = lse_ref[...]
-        delta = delta_ref[...]
-        safe_lse = jnp.where(jnp.isneginf(lse), 0.0, lse)
-        k_blk = k_ref[...].astype(jnp.float32)
-        v_blk = v_ref[...].astype(jnp.float32)
+    def k_block(j, carry):
+        cols = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        k_blk = k_ref[cols, :].astype(jnp.float32)
+        v_blk = v_ref[cols, :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        mask = _seg_mask_block(i, j, q_seg, k_seg, q_len, block_q, block_k)
+        mask = _seg_mask(rel, gap0 - j * bk, q_seg, kseg_ref[j])
         p = jnp.where(mask, jnp.exp(s - safe_lse), 0.0)
         dp = jax.lax.dot_general(
             do, v_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         ds = p * (dp - delta)
-        dq_sc[:] = dq_sc[:] + jax.lax.dot_general(
+        dq_sc[...] = dq_sc[...] + jax.lax.dot_general(
             ds, k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        return carry
 
-    @pl.when(j == nk - 1)
+    jax.lax.fori_loop(lo, hi, k_block, 0)
+
+    @pl.when(km == pl.num_programs(3) - 1)
     def _finish():
-        dq_ref[...] = (dq_sc[:] * scale).astype(dq_ref.dtype)
+        dq_ref[...] = (dq_sc[...] * scale).astype(dq_ref.dtype)
 
 
 def _seg_bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref, dk_sc, dv_sc,
-    *, scale, q_len, block_q, block_k, nq,
+    lo_ref, hi_ref, q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref, lse_ref,
+    delta_ref, dk_ref, dv_ref, dk_sc, dv_sc,
+    *, scale, tl: SegmentTiling,
 ):
-    j = pl.program_id(2)
-    i = pl.program_id(3)
+    b, j, qm = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    bq, bk = tl.block_q, tl.block_k
 
-    @pl.when(i == 0)
+    @pl.when(qm == 0)
     def _init():
-        dk_sc[:] = jnp.zeros_like(dk_sc)
-        dv_sc[:] = jnp.zeros_like(dv_sc)
+        dk_sc[...] = jnp.zeros_like(dk_sc)
+        dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    q_seg = qseg_ref[...]  # [bq, 1]
-    k_seg = kseg_ref[...]  # [1, bk]
-    live = _seg_block_live(i, j, q_seg, k_seg, block_q, block_k)
+    lo, hi = _seg_loop_bounds(
+        lo_ref, hi_ref, b * pl.num_programs(2) + j, qm, tl.major // bq
+    )
+    k_blk = k_ref[...].astype(jnp.float32)
+    v_blk = v_ref[...].astype(jnp.float32)
+    k_seg = kseg_ref[0]  # [1, bk]
+    rel = _rel(bq, bk)
+    gap0 = qm * tl.major - j * bk
 
-    @pl.when(live)
-    def _accumulate():
-        k_blk = k_ref[...].astype(jnp.float32)
-        v_blk = v_ref[...].astype(jnp.float32)
-        q = q_ref[...].astype(jnp.float32) * scale
-        do = do_ref[...].astype(jnp.float32)
-        lse = lse_ref[...]
-        delta = delta_ref[...]
+    def q_block(i, carry):
+        rows = pl.ds(pl.multiple_of(i * bq, bq), bq)
+        q = q_ref[rows, :].astype(jnp.float32) * scale
+        do = do_ref[rows, :].astype(jnp.float32)
+        lse = lse_ref[rows, :]
+        delta = delta_ref[rows, :]
         safe_lse = jnp.where(jnp.isneginf(lse), 0.0, lse)
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        mask = _seg_mask_block(i, j, q_seg, k_seg, q_len, block_q, block_k)
+        mask = _seg_mask(rel, gap0 + i * bq, qseg_ref[rows, :], k_seg)
         p = jnp.where(mask, jnp.exp(s - safe_lse), 0.0)
-        dv_sc[:] = dv_sc[:] + jax.lax.dot_general(
+        dv_sc[...] = dv_sc[...] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -627,72 +802,65 @@ def _seg_bwd_dkv_kernel(
         ds = p * (dp - delta)
         # q carries one factor of `scale` already (same split as the
         # causal kernel): the remaining factor belongs to dq only
-        dk_sc[:] = dk_sc[:] + jax.lax.dot_general(
+        dk_sc[...] = dk_sc[...] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        return carry
 
-    @pl.when(i == nq - 1)
+    jax.lax.fori_loop(lo, hi, q_block, 0)
+
+    @pl.when(qm == pl.num_programs(3) - 1)
     def _finish():
-        dk_ref[...] = dk_sc[:].astype(dk_ref.dtype)
-        dv_ref[...] = dv_sc[:].astype(dv_ref.dtype)
+        dk_ref[...] = dk_sc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
 
 
 def _seg_bwd(scale, block_q, block_k, interpret, residuals, g):
     q, k, v, seg, o, lse = residuals  # lse: [B, H, T_p, 1]
     B, T, H, D = q.shape
-    bq, bk, T_p, _ = _blocks(T, T, block_q, block_k)
-    nq, nk = T_p // bq, T_p // bk
-    qh, kh, vh, doh, oh = (_heads_first(x, T_p) for x in (q, k, v, g, o))
-    qseg, kseg = _seg_operands(seg, T_p)
+    tl = segment_flash_tiling(T, D, q.dtype, block_q, block_k)
+    qh, kh, vh, doh, oh = (_heads_first(x, tl.t_pad) for x in (q, k, v, g, o))
+    seg_p, qseg, kseg = _seg_operands(seg, tl)
     delta = jnp.sum(
         doh.astype(jnp.float32) * oh.astype(jnp.float32), axis=-1, keepdims=True
     )
+    operands = (qh, kh, vh, qseg, kseg, doh, lse, delta)
 
-    dq_kernel = functools.partial(
-        _seg_bwd_dq_kernel, scale=scale, q_len=T,
-        block_q=bq, block_k=bk, nk=nk,
-    )
-    dq = pl.pallas_call(
-        dq_kernel,
-        name="segment_flash_bwd_dq",
-        grid=(B, H, nq, nk),
+    tile, stream, qseg_spec, kseg_spec = _seg_specs(tl, q_stationary=True)
+    dq = _seg_pallas_call(
+        functools.partial(_seg_bwd_dq_kernel, scale=scale, tl=tl),
+        "segment_flash_bwd_dq", tl.grid(B, H, True),
         in_specs=[
-            _tile(bq, D, 2), _tile(bk, D, 3), _tile(bk, D, 3),
-            *_seg_specs(bq, bk, 2, 3),
-            _tile(bq, D, 2), _tile(bq, 1, 2), _tile(bq, 1, 2),
+            tile(D), stream(D), stream(D), qseg_spec, kseg_spec,
+            tile(D), tile(1), tile(1),
         ],
-        out_specs=_tile(bq, D, 2),
-        out_shape=jax.ShapeDtypeStruct((B, H, T_p, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        out_specs=tile(D),
+        out_shape=jax.ShapeDtypeStruct((B, H, tl.t_pad, D), q.dtype),
+        scratch_shapes=[pltpu.VMEM((tl.block_q, D), jnp.float32)],
         interpret=interpret,
-    )(qh, kh, vh, qseg, kseg, doh, lse, delta)
+    )(*_live_blocks(seg_p, tl.block_q, tl.block_k, True), *operands)
 
-    dkv_kernel = functools.partial(
-        _seg_bwd_dkv_kernel, scale=scale, q_len=T,
-        block_q=bq, block_k=bk, nq=nq,
-    )
-    # k blocks outermost here: grid axis 2 walks k, axis 3 walks q
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        name="segment_flash_bwd_dkv",
-        grid=(B, H, nk, nq),
+    # k tiles stationary here: grid axis 2 walks k, the loop walks q
+    tile, stream, qseg_spec, kseg_spec = _seg_specs(tl, q_stationary=False)
+    dk, dv = _seg_pallas_call(
+        functools.partial(_seg_bwd_dkv_kernel, scale=scale, tl=tl),
+        "segment_flash_bwd_dkv", tl.grid(B, H, False),
         in_specs=[
-            _tile(bq, D, 3), _tile(bk, D, 2), _tile(bk, D, 2),
-            *_seg_specs(bq, bk, 3, 2),
-            _tile(bq, D, 3), _tile(bq, 1, 3), _tile(bq, 1, 3),
+            stream(D), tile(D), tile(D), qseg_spec, kseg_spec,
+            stream(D), stream(1), stream(1),
         ],
-        out_specs=[_tile(bk, D, 2), _tile(bk, D, 2)],
+        out_specs=[tile(D), tile(D)],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, T_p, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, T_p, D), v.dtype),
+            jax.ShapeDtypeStruct((B, H, tl.t_pad, D), k.dtype),
+            jax.ShapeDtypeStruct((B, H, tl.t_pad, D), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((tl.block_k, D), jnp.float32),
+            pltpu.VMEM((tl.block_k, D), jnp.float32),
         ],
         interpret=interpret,
-    )(qh, kh, vh, qseg, kseg, doh, lse, delta)
+    )(*_live_blocks(seg_p, tl.block_k, tl.block_q, False), *operands)
     return _heads_last(dq, T), _heads_last(dk, T), _heads_last(dv, T)
 
 
@@ -703,8 +871,8 @@ def segment_flash_attention(
     v: jnp.ndarray,
     segment_ids: jnp.ndarray,
     scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Segment-packed causal self-attention, forward AND backward.
@@ -714,8 +882,10 @@ def segment_flash_attention(
     starting at 1 with a zero pad tail (the ``genrl/rollout.py`` packer's
     contract).  Token ``i`` attends to ``j <= i`` iff
     ``segment_ids[i] == segment_ids[j] != 0``.  Fully-masked rows (pad
-    queries) emit exact zeros.  ``interpret=None`` auto-selects Pallas
-    interpret mode off-TPU.
+    queries) emit exact zeros.  ``block_q`` / ``block_k`` name the score
+    tile; left ``None`` it is chosen from the shape
+    (:func:`segment_flash_tiling`).  ``interpret=None`` auto-selects
+    Pallas interpret mode off-TPU.
     """
     out, _ = _segment_flash_fwd(
         q, k, v, segment_ids, scale, block_q, block_k, interpret

@@ -130,6 +130,11 @@ def _seg_rand(seed, B, T, H, D):
     )
 
 
+# explicit score tiles (square, and wider or taller than their partner, so a
+# major block holds several of one kind), and the tile chosen from the shape
+_SEG_BLOCKS = [(8, 8), (8, 16), (16, 8), (None, None)]
+
+
 @pytest.mark.parametrize(
     "spans",
     [
@@ -141,7 +146,8 @@ def _seg_rand(seed, B, T, H, D):
         [[(0, 7, 1), (7, 9, 2), (9, 24, 3)], [(0, 8, 1), (8, 16, 2)]],
     ],
 )
-def test_segment_flash_matches_reference(spans):
+@pytest.mark.parametrize("blocks", _SEG_BLOCKS, ids=str)
+def test_segment_flash_matches_reference(spans, blocks):
     from scalerl_tpu.ops.pallas_attention import (
         segment_attention_reference,
         segment_flash_attention,
@@ -150,14 +156,15 @@ def test_segment_flash_matches_reference(spans):
     B, T, H, D = 2, 24, 2, 8
     q, k, v = _seg_rand(0, B, T, H, D)
     seg = _seg_layout(B, T, spans)
-    out = segment_flash_attention(q, k, v, seg, None, 8, 8, None)
+    out = segment_flash_attention(q, k, v, seg, None, *blocks, None)
     ref = segment_attention_reference(q, k, v, seg)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
     )
 
 
-def test_segment_flash_gradients_match_reference():
+@pytest.mark.parametrize("blocks", _SEG_BLOCKS, ids=str)
+def test_segment_flash_gradients_match_reference(blocks):
     """custom_vjp backward vs XLA autodiff through the dense oracle —
     the training-grade contract (values AND grads at 1e-5), with pad
     rows and cross-segment blocks in the layout."""
@@ -173,7 +180,7 @@ def test_segment_flash_gradients_match_reference():
     )
 
     def loss_kernel(q, k, v):
-        o = segment_flash_attention(q, k, v, seg, None, 8, 8, None)
+        o = segment_flash_attention(q, k, v, seg, None, *blocks, None)
         return jnp.sum(jnp.sin(o))
 
     def loss_ref(q, k, v):
@@ -234,6 +241,221 @@ def test_segment_flash_under_jit_and_grad_of_ints():
 
     g = jax.grad(f)(q, k, v)
     assert np.isfinite(np.asarray(g)).all()
+
+
+def _seg_check(q, k, v, seg, blocks, out_tol, grad_tol):
+    """Outputs and all three gradients against the dense oracle."""
+    from scalerl_tpu.ops.pallas_attention import (
+        segment_attention_reference,
+        segment_flash_attention,
+    )
+
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))  # noqa: E731
+    out = segment_flash_attention(q, k, v, seg, None, *blocks, None)
+    assert out.dtype == q.dtype
+    np.testing.assert_allclose(
+        f32(out), f32(segment_attention_reference(q, k, v, seg)),
+        atol=out_tol, rtol=out_tol,
+    )
+    np.testing.assert_array_equal(f32(out)[np.asarray(seg) == 0], 0.0)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(jnp.sin(attn(q, k, v).astype(jnp.float32)))
+
+    gk = jax.grad(
+        loss(lambda q, k, v: segment_flash_attention(q, k, v, seg, None, *blocks, None)),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    gr = jax.grad(
+        loss(lambda q, k, v: segment_attention_reference(q, k, v, seg)),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    for a, b, name in zip(gk, gr, "qkv"):
+        np.testing.assert_allclose(
+            f32(a), f32(b), atol=grad_tol, rtol=grad_tol, err_msg=f"d{name}"
+        )
+
+
+@pytest.mark.parametrize(
+    "H,D,dtype,out_tol,grad_tol",
+    [
+        (2, 8, jnp.float32, 2e-5, 1e-5),
+        (10, 8, jnp.float32, 2e-5, 1e-5),  # a head count 4 and 8 do not divide
+        (2, 128, jnp.float32, 2e-5, 1e-5),  # OLMoE's head size
+        (2, 16, jnp.bfloat16, 5e-2, 5e-2),
+    ],
+    ids=["2x8-f32", "10-heads", "head-128", "bf16"],
+)
+def test_segment_flash_chosen_tile_edges(H, D, dtype, out_tol, grad_tol):
+    """The tile chosen from the shape (512 rows), at a T it does not
+    divide: row 0 has a segment boundary inside the first tile, a segment
+    that crosses into the second, and a third tile that is all padding;
+    row 1 is one full-length segment."""
+    from scalerl_tpu.ops.pallas_attention import segment_flash_tiling
+
+    B, T = 2, 1100
+    tl = segment_flash_tiling(T, D, dtype)
+    assert (tl.block_q, tl.block_k, tl.t_pad) == (512, 512, 1536)
+    q, k, v = (x.astype(dtype) for x in _seg_rand(5, B, T, H, D))
+    seg = _seg_layout(
+        B, T, [[(0, 300, 1), (300, 700, 2), (700, 1000, 3)], [(0, T, 1)]]
+    )
+    _seg_check(q, k, v, seg, (None, None), out_tol, grad_tol)
+
+
+@pytest.mark.parametrize("blocks", [(8, 8), (8, 16), (16, 8)], ids=str)
+def test_segment_flash_several_major_blocks(monkeypatch, blocks):
+    """Rows longer than the VMEM budget holds (the planned 8k-token rows):
+    the streamed operand then arrives in several major blocks, the
+    accumulators live across them, and a major block outside a tile's
+    live interval is neither fetched nor read.  A tiny budget stands in
+    for a long row."""
+    import scalerl_tpu.ops.pallas_attention as pa
+
+    B, T, H, D = 2, 64, 2, 8
+    monkeypatch.setattr(pa, "_SEG_VMEM_BUDGET", 150_000)
+    tl = pa.segment_flash_tiling(T, D, jnp.float32, *blocks)
+    assert tl.t_pad // tl.major > 1
+    q, k, v = _seg_rand(6, B, T, H, D)
+    seg = _seg_layout(
+        B, T, [[(0, 20, 1), (20, 50, 2), (50, 58, 3)], [(0, T, 1)]]
+    )
+    _seg_check(q, k, v, seg, blocks, 2e-5, 1e-5)
+
+
+@pytest.mark.parametrize(
+    "T,D,dtype",
+    [
+        (1024, 64, jnp.float32),  # both learn cells
+        (1024, 128, jnp.bfloat16),  # the OLMoE learner
+        (8192, 128, jnp.bfloat16),  # its planned 8k-token rows
+        (8192, 128, jnp.float32),
+        (384, 32, jnp.float32),
+        (24, 8, jnp.float32),
+        (19, 8, jnp.float32),
+    ],
+)
+def test_segment_flash_tiling_is_legal(T, D, dtype):
+    """The tile is chosen from T, D, the dtype and a VMEM budget (any head
+    count is legal: a grid step holds one head): whole blocks, Mosaic's
+    (8, 128) rule, and never more than the budget."""
+    import scalerl_tpu.ops.pallas_attention as pa
+
+    tl = pa.segment_flash_tiling(T, D, dtype)
+    assert tl.major % tl.block_q == 0 and tl.major % tl.block_k == 0
+    assert tl.t_pad % tl.major == 0 and T <= tl.t_pad < T + tl.major
+    # a score tile's edge is a lane multiple unless it spans the padded row
+    for block in (tl.block_q, tl.block_k):
+        assert block % 128 == 0 or (block == tl.t_pad and block % 8 == 0)
+    need = pa._seg_vmem_bytes(
+        tl.block_q, tl.block_k, tl.major, D, jnp.dtype(dtype).itemsize
+    )
+    assert need <= pa._SEG_VMEM_BUDGET < pa._SEG_VMEM_LIMIT <= 96 * 2**20
+    if T >= 8192:  # no whole row in VMEM
+        assert tl.major < tl.t_pad
+    # blocks the caller names are honoured
+    named = pa.segment_flash_tiling(max(T, 256), D, dtype, 128, 128)
+    assert (named.block_q, named.block_k) == (128, 128)
+
+
+def _pallas_grids(jaxpr):
+    """``{kernel name: grid}`` of every pallas_call under ``jaxpr``."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = tuple(eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.update(_pallas_grids(sub))
+    return found
+
+
+@pytest.mark.parametrize("H,most", [(16, 64), (10, 64)], ids=["gpt2m", "gpt2l-shard"])
+def test_segment_flash_grid_at_the_learn_cells_shape(H, most):
+    """The guard against sliding back: ISSUE 29 found 2,048 (1,280) grid
+    steps of one 128x128 tile each in all three calls at these shapes."""
+    from scalerl_tpu.ops.pallas_attention import segment_flash_attention
+
+    B, T, D = 2, 1024, 64
+    qkv = jax.ShapeDtypeStruct((B, T, H, D), jnp.float32)
+    seg = jax.ShapeDtypeStruct((B, T), jnp.int32)
+
+    def loss(q, k, v, seg):
+        return jnp.sum(segment_flash_attention(q, k, v, seg, interpret=False))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(qkv, qkv, qkv, seg)
+    grids = _pallas_grids(jaxpr.jaxpr)
+    assert set(grids) == {
+        "segment_flash_fwd", "segment_flash_bwd_dq", "segment_flash_bwd_dkv"
+    }
+    for name, grid in grids.items():
+        assert int(np.prod(grid)) <= most, (name, grid)
+
+
+@pytest.mark.parametrize("tiles_are_q", [True, False])
+@pytest.mark.parametrize("tile,block", [(8, 8), (16, 8), (8, 16)])
+def test_segment_live_blocks_cover_exactly_the_live_pairs(tile, block, tiles_are_q):
+    """The prefetched loop bounds against brute force: for the packer's
+    layout a block lies inside a tile's interval exactly when some pair in
+    it is unmasked; for ids in any order the interval still covers every
+    live block (the masks alone decide values)."""
+    from scalerl_tpu.ops.pallas_attention import _live_blocks
+
+    rng = np.random.default_rng(0)
+    T = 64
+    packed = np.zeros((3, T), np.int32)
+    packed[0, :20], packed[0, 20:50], packed[0, 50:58] = 1, 2, 3
+    packed[1, :] = 1
+    shuffled = rng.integers(0, 4, size=(3, T)).astype(np.int32)
+    for seg, exact in ((packed, True), (shuffled, False)):
+        lo, hi = (
+            np.asarray(x).reshape(seg.shape[0], -1)
+            for x in _live_blocks(jnp.asarray(seg), tile, block, tiles_are_q)
+        )
+        pos = np.arange(T)
+        mask = (
+            (seg[:, :, None] == seg[:, None, :])
+            & (seg[:, :, None] > 0)
+            & (pos[None, :, None] >= pos[None, None, :])
+        )  # [B, q, k]
+        if not tiles_are_q:
+            mask = mask.transpose(0, 2, 1)  # [B, k, q]
+        live = mask.reshape(seg.shape[0], T // tile, tile, T // block, block)
+        live = live.any(axis=(2, 4))  # [B, tiles, blocks]
+        inside = (np.arange(T // block) >= lo[..., None]) & (
+            np.arange(T // block) < hi[..., None]
+        )
+        assert not (live & ~inside).any()
+        if exact:
+            np.testing.assert_array_equal(inside, live)
+
+
+def test_segment_flash_tiling_is_recorded_once_a_shape(monkeypatch):
+    """The mechanism always engages, so its counter is its geometry: one
+    program span a traced shape, through ``runtime/tracing``."""
+    from scalerl_tpu.ops.pallas_attention import segment_flash_attention
+    from scalerl_tpu.runtime import tracing
+
+    monkeypatch.setenv(tracing.ENV_SAMPLE, "1.0")
+    tracing.reset()
+    try:
+        B, T, H, D = 1, 40, 3, 8  # a shape no other test traces
+        q, k, v = _seg_rand(7, B, T, H, D)
+        seg = jnp.ones((B, T), jnp.int32)
+        f = jax.jit(lambda q, k, v: jnp.sum(segment_flash_attention(q, k, v, seg)))
+        for _ in range(2):
+            jax.grad(f)(q, k, v)
+        spans = [
+            s for s in tracing.get_tracer().finished()
+            if s["name"] == "segment_flash.tiling"
+        ]
+        assert len(spans) == 1, spans
+        attrs = spans[0]["attrs"]
+        assert attrs["shape"] == [B, T, H, D] and attrs["dtype"] == "float32"
+        assert (attrs["block_q"], attrs["block_k"], attrs["major"]) == (40, 40, 40)
+        assert attrs["grid_steps"] == attrs["grid_steps_dkv"] == B * H
+    finally:
+        monkeypatch.delenv(tracing.ENV_SAMPLE)
+        tracing.reset()
 
 
 def test_resolve_segment_attn(monkeypatch):

@@ -661,3 +661,58 @@ def test_segment_flash_forward_backward_compiled(
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=bwd_tol, rtol=bwd_tol
         )
+
+
+
+# the two learn cells' shapes (a shard of gpt2-large holds 10 heads) and the
+# OLMoE learner's, with the tile left to segment_flash_tiling
+@pytest.mark.parametrize(
+    "B,T,H,D,dtype,fwd_tol,bwd_tol",
+    [
+        (2, 1024, 16, 64, jnp.float32, 2e-3, 5e-3),
+        (2, 1024, 10, 64, jnp.float32, 2e-3, 5e-3),
+        (1, 1024, 16, 128, jnp.bfloat16, 3e-2, 1e-1),
+    ],
+)
+def test_segment_flash_default_tiling_compiled(
+    B, T, H, D, dtype, fwd_tol, bwd_tol, f32_matmuls
+):
+    """ISSUE 29: a grid step covers a 512-row tile of one head and loops
+    over its live blocks of the other operand; the tile comes from the shape.  Rows of
+    two and three segments with a pad tail, so that a 512-row tile holds a
+    segment boundary, a cross-segment block and padding."""
+    from scalerl_tpu.ops.pallas_attention import (
+        segment_attention_reference,
+        segment_flash_attention,
+        segment_flash_tiling,
+    )
+
+    tl = segment_flash_tiling(T, D, dtype)
+    assert max(tl.grid_steps(B, H)) <= 64, tl
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(11), 3)
+    q, k, v = (_rand(kk, B, T, H, D).astype(dtype) for kk in (k1, k2, k3))
+    seg = np.zeros((B, T), np.int32)
+    seg[0, :400], seg[0, 400:780], seg[0, 780:980] = 1, 2, 3
+    if B > 1:
+        seg[1, :520], seg[1, 520:990] = 1, 2
+    seg = jnp.asarray(seg)
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))  # noqa: E731
+
+    out = segment_flash_attention(q, k, v, seg, interpret=False)
+    ref = segment_attention_reference(q, k, v, seg)
+    np.testing.assert_allclose(f32(out), f32(ref), atol=fwd_tol, rtol=fwd_tol)
+    np.testing.assert_array_equal(f32(out)[0, 980:], 0.0)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2)
+
+    gk = jax.jit(jax.grad(
+        loss(lambda q, k, v: segment_flash_attention(q, k, v, seg, interpret=False)),
+        argnums=(0, 1, 2),
+    ))(q, k, v)
+    gr = jax.grad(
+        loss(lambda q, k, v: segment_attention_reference(q, k, v, seg)),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    for a, b in zip(gk, gr):
+        np.testing.assert_allclose(f32(a), f32(b), atol=bwd_tol, rtol=bwd_tol)
