@@ -750,12 +750,30 @@ class GenRLArguments(RLArguments):
     # width ``moe_hidden`` with the ``moe_experts_per_token`` most
     # probable kept, dropless.  The sizes below are read by the families
     # that have them; ``head_dim`` 0 means d_model // n_heads.
+    # "longcat" = the shortcut-connected double layer: two latent (MLA)
+    # attentions of the five ``mla_*`` sizes and two dense SwiGLU FFNs of
+    # width ``ffn_hidden`` in a row, with one router over ``moe_experts``
+    # computed experts and ``moe_zero_experts`` identity ones beside them;
+    # its picks weigh ``moe_routed_scaling`` x their probability.  Of the
+    # computed experts this program holds ``moe_experts_held`` (0: all)
+    # from ``moe_first_expert`` on, one rank's share of an expert-parallel
+    # deployment: picks of the others add nothing here.
     block_family: str = "gpt2"
     head_dim: int = 0
     rms_norm_eps: float = 1e-5
     rope_theta: float = 10000.0
     moe_experts_per_token: int = 2
     moe_norm_topk_prob: bool = False
+    mla_q_lora_rank: int = 0
+    mla_kv_lora_rank: int = 0
+    mla_qk_nope_head_dim: int = 0
+    mla_qk_rope_head_dim: int = 0
+    mla_v_head_dim: int = 0
+    ffn_hidden: int = 0
+    moe_zero_experts: int = 0
+    moe_routed_scaling: float = 1.0
+    moe_experts_held: int = 0
+    moe_first_expert: int = 0
     # weight of the router's load-balancing loss in the learner's total
     # (agents/token_ppo.py); only a routed family has the term
     router_aux_loss_coef: float = 0.01
@@ -879,9 +897,10 @@ class GenRLArguments(RLArguments):
                 f"temperature must be >= 0 (0 = greedy), got "
                 f"{self.temperature}"
             )
-        if self.block_family not in ("gpt2", "olmoe"):
+        if self.block_family not in ("gpt2", "olmoe", "longcat"):
             raise ValueError(
-                f"block_family must be gpt2 | olmoe, got {self.block_family!r}"
+                "block_family must be gpt2 | olmoe | longcat, got "
+                f"{self.block_family!r}"
             )
         if self.head_dim < 0 or self.router_aux_loss_coef < 0:
             raise ValueError(

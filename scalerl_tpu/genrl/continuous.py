@@ -38,8 +38,9 @@ the host swaps *sequences* through them —
   ``n-1`` lanes map the SAME full prompt pages copy-on-write and only the
   last partial page is physically copied per lane by a small jitted
   page-copy program — so a GRPO-shaped round pays ~1/n of its prefill;
-- **paged KV** — ``models/transformer.py``'s ``PagedKVCache`` pools plus
-  the jax-free refcounting :class:`~scalerl_tpu.genrl.paging
+- **paged KV** — the pools the model describes
+  (``TransformerPolicy.init_paged_cache``: a K and a V pool a block, or
+  the one latent pool an ``mla`` attention caches into) plus the jax-free refcounting :class:`~scalerl_tpu.genrl.paging
   .PageAllocator`: admission reserves a sequence's worst-case pages
   (exhaustion backpressures, never corrupts; shared pages count against
   EVERY holder's reservation, so sharing never loosens the guarantee)
@@ -102,9 +103,7 @@ from scalerl_tpu.genrl.paging import PageAllocator, rewind_pages
 from scalerl_tpu.genrl.prefix_cache import PrefixCache
 from scalerl_tpu.models.routed_ffn import router_balance
 from scalerl_tpu.models.transformer import (
-    PagedKVCache,
     TransformerPolicy,
-    init_paged_kv_cache,
     prompt_attention_mask,
 )
 from scalerl_tpu.ops.pallas_paged_attention import make_paged_attn_fn
@@ -347,7 +346,9 @@ class ContinuousEngine(ParamSnapshotPlane):
         # snapshots all live on this one device, whatever mesh the learner
         # shards its own copy over (_place re-places every push)
         self._device = jax.devices()[0]
-        self._paged_attn = make_paged_attn_fn(config.paged_attn)
+        self._paged_attn = make_paged_attn_fn(
+            config.paged_attn, model.block.attention
+        )
         if model.paged_attn_fn is None:
             # route the model's paged decode reads through the resolved impl
             # (clone shares the param structure: same names, same shapes)
@@ -392,20 +393,27 @@ class ContinuousEngine(ParamSnapshotPlane):
             )
         )
         self._admit_buckets = default_buckets(L)
-        head_dim = model.head_dim
         # a routed-experts model: the decode program also returns each
-        # substep's per-expert token counts (live lanes, every layer)
-        self._routed = model.block.ffn == "experts"
-        self._expert_tokens = np.zeros(
-            (model.num_layers, model.block.num_experts), np.int64
+        # substep's token counts of every router output (live lanes, every
+        # layer).  The outputs are the computed experts, of which this
+        # program holds ``held`` from ``first`` on, then the zero-compute
+        # ones; OLMoE holds all it routes over and has none of the latter
+        spec = model.block
+        self._routed = spec.ffn == "experts"
+        self._experts = spec.num_experts
+        self._held = slice(
+            spec.first_expert,
+            spec.first_expert + (spec.experts_held or spec.num_experts),
         )
-        self._expert_hits = 0  # experts that received a token, summed
+        self._expert_tokens = np.zeros(
+            (model.num_layers, spec.num_experts + spec.zero_experts), np.int64
+        )
+        self._expert_hits = 0  # held experts that received a token, summed
         self._expert_substeps = 0  # over this many (substep, layer) pairs
         # device state: pools + per-lane decode carry (donated through
-        # every program; the host rebinds after each dispatch)
-        self._pools = init_paged_kv_cache(
-            num_pages, ps, model.num_layers, model.num_heads, head_dim
-        )
+        # every program; the host rebinds after each dispatch).  The
+        # model describes its cache; here it is one pytree of pools
+        self._pools = model.init_paged_cache(num_pages, ps)
         self._logits_st = jnp.zeros((L, config.vocab_size), jnp.float32)
         self._value_st = jnp.zeros((L,), jnp.float32)
         self._cl = jnp.zeros((L,), jnp.int32)
@@ -969,13 +977,9 @@ class ContinuousEngine(ParamSnapshotPlane):
             src_lane, dst_lane, src_page, dst_page,
         ):
             self._fork_traces += 1
-            new_k = tuple(
-                kp.at[dst_page].set(kp[src_page]) for kp in pools.k
+            pools = jax.tree_util.tree_map(
+                lambda pool: pool.at[dst_page].set(pool[src_page]), pools
             )
-            new_v = tuple(
-                vp.at[dst_page].set(vp[src_page]) for vp in pools.v
-            )
-            pools = PagedKVCache(k=new_k, v=new_v)
             logits_st = logits_st.at[dst_lane].set(
                 logits_st[src_lane], mode="drop"
             )
@@ -1669,13 +1673,15 @@ class ContinuousEngine(ParamSnapshotPlane):
         counts = np.asarray(counts, np.int64)
         live = counts.sum(axis=(1, 2)) > 0
         self._expert_tokens += counts.sum(axis=0)
-        self._expert_hits += int((counts[live] > 0).sum())
+        self._expert_hits += int((counts[live][:, :, self._held] > 0).sum())
         self._expert_substeps += int(live.sum()) * counts.shape[1]
 
     def stats(self) -> Dict[str, Any]:
         """Engine-lifetime counters, batched from host state that already
         crossed the device boundary — reading this never adds a
         transfer."""
+        held_picks = int(self._expert_tokens[:, self._held].sum())
+        routed_picks = int(self._expert_tokens[:, : self._experts].sum())
         return {
             "macro_steps": self.macro_steps,
             "completed": self.completed_total,
@@ -1691,12 +1697,18 @@ class ContinuousEngine(ParamSnapshotPlane):
             "spec_draft_s": self._spec_draft_s,
             "spec_verify_s": self._spec_verify_s,
             # routed-experts models only (zeros otherwise): tokens each
-            # expert of each layer received from live decode lanes, the
-            # number of (substep, layer, expert) cells that received one,
-            # and the number of (substep, layer) pairs that could have
+            # router output of each layer received from live decode lanes,
+            # the number of (substep, layer, held expert) cells that
+            # received one, and the number of (substep, layer) pairs that
+            # could have; then the same picks split three ways, by whether
+            # the output is an expert held here, one another chip holds,
+            # or a zero-compute one
             "expert_tokens": self._expert_tokens.copy(),
             "expert_hits": self._expert_hits,
             "expert_substeps": self._expert_substeps,
+            "held_expert_tokens": held_picks,
+            "absent_expert_tokens": routed_picks - held_picks,
+            "zero_expert_tokens": int(self._expert_tokens.sum()) - routed_picks,
         }
 
     def _harvest(

@@ -26,6 +26,20 @@ have opposite bounds:
   XLA:TPU compiles to a grouped matmul) do ``k / E`` of the dense form's
   arithmetic.
 
+**A share of a wider layer** (the ``longcat`` family, ``held`` <
+``num_experts``): the router keeps its published width, ``num_experts``
+computed experts and ``zero_experts`` identity ones behind them, and this
+module holds the banks of experts ``first_expert .. first_expert + held``
+alone, as one rank of an expert-parallel deployment does.  A pick inside
+the held range is computed here, an identity pick adds ``w h`` here (it
+costs nothing, so it is computed where the token lives), and a pick of an
+absent expert contributes nothing: the part of the sum another chip owns.
+Both forms serve; the sorted form's group sizes count held picks only.
+The picks are the ``k`` largest of ``p + b`` (``b`` the ``router_bias``
+parameter, which only chooses) and weigh ``routed_scaling x p``.  OLMoE
+is the case ``zero_experts = 0``, ``held = num_experts``, no bias,
+scaling 1, and traces to the program it always did.
+
 Per call the module also ``sow``s the router's probabilities and picks
 into the ``intermediates`` collection, from which :func:`router_balance`
 computes the per-expert token counts, the load-balancing loss and the
@@ -59,15 +73,30 @@ def _bank_init():
     )
 
 
-def _streamed(x, top_p, top_i, w_gate, w_up, w_down):
+def _streamed(x, top_p, top_i, w_gate, w_up, w_down, expert_axis=False):
+    """``expert_axis``: the tokens are given an explicit expert axis
+    (``[E, N, d]``, a broadcast), which makes the two up products batched
+    matmuls that read the banks as they are stored.  Without it XLA:TPU,
+    inside the decode loop at 128 tokens, wants ``w_gate`` and ``w_up``
+    with ``d`` minor and copies both whole banks of every layer once a
+    macro-step (8 copies of 0.4 GB and 3.2 GB of temporaries at the
+    longcat cell's sizes: AOT, PR 30).  The share path takes it; OLMoE's
+    32-token program meets no such copy and stays the text it was."""
     E = w_gate.shape[0]
     f32 = jnp.float32
     # [N, E] combine weights: a pick's probability at its expert, else 0
+    # (a pick outside the held banks, ``top_i`` not in [0, E), is a row of
+    # zeros: ``one_hot`` of an index out of range)
     combine = jnp.sum(
         jax.nn.one_hot(top_i, E, dtype=f32) * top_p[..., None], axis=1
     )
-    g = jnp.einsum("nd,edf->enf", x, w_gate, preferred_element_type=f32)
-    u = jnp.einsum("nd,edf->enf", x, w_up, preferred_element_type=f32)
+    if expert_axis:
+        xe = jnp.broadcast_to(x, (E,) + x.shape)
+        g = jnp.einsum("end,edf->enf", xe, w_gate, preferred_element_type=f32)
+        u = jnp.einsum("end,edf->enf", xe, w_up, preferred_element_type=f32)
+    else:
+        g = jnp.einsum("nd,edf->enf", x, w_gate, preferred_element_type=f32)
+        u = jnp.einsum("nd,edf->enf", x, w_up, preferred_element_type=f32)
     a = jax.nn.silu(g) * u * combine.T[:, :, None]
     # one contraction over (expert, width): the experts' outputs are
     # summed in the matmul's float32 accumulator
@@ -76,31 +105,52 @@ def _streamed(x, top_p, top_i, w_gate, w_up, w_down):
     )
 
 
-def _sorted(x, top_p, top_i, w_gate, w_up, w_down):
+def _sorted(x, top_p, top_i, w_gate, w_up, w_down, held_rows=None):
+    """``held_rows [N * k]`` (a share of a wider layer): which assignments
+    fell on a bank held here; the others carry the index ``E``, sort
+    behind every group, count in no group's size and add nothing."""
     N, k = top_i.shape
     E = w_gate.shape[0]
     f32 = jnp.float32
     expert = top_i.reshape(N * k)
     order = jnp.argsort(expert)  # stable: assignments grouped by expert
+    # an index of ``E`` (not held here) is out of range and dropped
     sizes = jnp.zeros((E,), jnp.int32).at[expert].add(1)
     xs = x[order // k]
     g = lax.ragged_dot(xs, w_gate, sizes, preferred_element_type=f32)
     u = lax.ragged_dot(xs, w_up, sizes, preferred_element_type=f32)
     a = jax.nn.silu(g) * u * top_p.reshape(N * k)[order][:, None]
+    if held_rows is not None:
+        # rows past the last group belong to no expert here: what a
+        # grouped matmul leaves in them is not defined
+        in_a_group = held_rows[order][:, None]
+        a = jnp.where(in_a_group, a, 0.0)
     ys = lax.ragged_dot(
         a.astype(x.dtype), w_down, sizes, preferred_element_type=f32
     )
+    if held_rows is not None:
+        ys = jnp.where(in_a_group, ys, 0.0)
     # back to token order by a gather, then the k picks summed
     return jnp.sum(ys[jnp.argsort(order)].reshape(N, k, -1), axis=1)
 
 
 class RoutedExperts(nn.Module):
-    """``[B, T, d] -> [B, T, d]``: router, exact top-k, SwiGLU experts."""
+    """``[B, T, d] -> [B, T, d]``: router, exact top-k, SwiGLU experts.
+
+    ``num_experts`` computed experts and ``zero_experts`` identity ones
+    share one router of ``num_experts + zero_experts`` outputs; of the
+    computed ones this module holds ``held`` (0: all), from
+    ``first_expert`` on (see the module docstring)."""
 
     num_experts: int
     experts_per_token: int
     width: int
     norm_topk_prob: bool = False
+    zero_experts: int = 0
+    held: int = 0
+    first_expert: int = 0
+    choice_bias: bool = False
+    routed_scaling: float = 1.0
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -108,25 +158,49 @@ class RoutedExperts(nn.Module):
     def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
         B, T, d = h.shape
         E, k, f = self.num_experts, self.experts_per_token, self.width
+        R = E + self.zero_experts  # the router's outputs
+        held, first = self.held or E, self.first_expert
         router = self.param(
-            "router", nn.initializers.lecun_normal(), (d, E), self.param_dtype
+            "router", nn.initializers.lecun_normal(), (d, R), self.param_dtype
         )
-        w_gate = self.param("w_gate", _bank_init(), (E, d, f), self.param_dtype)
-        w_up = self.param("w_up", _bank_init(), (E, d, f), self.param_dtype)
-        w_down = self.param("w_down", _bank_init(), (E, f, d), self.param_dtype)
+        w_gate = self.param("w_gate", _bank_init(), (held, d, f), self.param_dtype)
+        w_up = self.param("w_up", _bank_init(), (held, d, f), self.param_dtype)
+        w_down = self.param("w_down", _bank_init(), (held, f, d), self.param_dtype)
         x = h.reshape(B * T, d).astype(self.dtype)
         logits = jnp.dot(
             x, router.astype(self.dtype), preferred_element_type=jnp.float32
         )
-        probs = jax.nn.softmax(logits, axis=-1)  # float32, over all E
-        top_p, top_i = lax.top_k(probs, k)
+        probs = jax.nn.softmax(logits, axis=-1)  # float32, over all R
+        if self.choice_bias:
+            # the bias chooses and does not weigh
+            bias = self.param(
+                "router_bias", nn.initializers.zeros, (R,), jnp.float32
+            )
+            _, top_i = lax.top_k(probs + bias, k)
+            top_p = jnp.take_along_axis(probs, top_i, axis=-1)
+        else:
+            top_p, top_i = lax.top_k(probs, k)
         if self.norm_topk_prob:
             top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-        self.sow("intermediates", "router_probs", probs.reshape(B, T, E))
+        if self.routed_scaling != 1.0:
+            top_p = top_p * self.routed_scaling
+        self.sow("intermediates", "router_probs", probs.reshape(B, T, R))
         self.sow("intermediates", "expert_ids", top_i.reshape(B, T, k))
         banks = tuple(w.astype(self.dtype) for w in (w_gate, w_up, w_down))
-        form = _streamed if B * T <= STREAMED_MAX_TOKENS else _sorted
-        y = form(x, top_p, top_i, *banks)
+        streamed = B * T <= STREAMED_MAX_TOKENS
+        if held == R:  # every output is a bank held here
+            y = (_streamed if streamed else _sorted)(x, top_p, top_i, *banks)
+            return y.reshape(B, T, d).astype(self.dtype)
+        here = (top_i >= first) & (top_i < first + held)
+        local = jnp.where(here, top_i - first, held)  # ``held``: no bank here
+        if streamed:
+            y = _streamed(x, top_p, local, *banks, expert_axis=True)
+        else:
+            y = _sorted(x, top_p, local, *banks, held_rows=here.reshape(-1))
+        if self.zero_experts:
+            # identity experts: ``w h``, computed where the token lives
+            w_zero = jnp.sum(jnp.where(top_i >= E, top_p, 0.0), axis=-1)
+            y = y + w_zero[:, None] * x.astype(jnp.float32)
         return y.reshape(B, T, d).astype(self.dtype)
 
 

@@ -27,7 +27,10 @@ from scalerl_tpu.models.routed_ffn import RoutedExperts
 from scalerl_tpu.ops.pallas_attention import flash_attention
 from scalerl_tpu.ops.pallas_paged_attention import (
     gather_pages,
+    latent_attention,
+    latent_pool_width,
     paged_attention_reference,
+    paged_latent_attention_reference,
 )
 from scalerl_tpu.ops.ring_attention import full_attention
 
@@ -46,6 +49,18 @@ class BlockSpec:
     projections and before the cache write (q/k norm, rotary positions:
     K enters a cache normed and rotated, so cached, paged and packed
     paths read it as it is), and where the MLP sits (the routed experts).
+
+    Two further kinds change what a layer *is* and so have modules of
+    their own beside :class:`_Block`.  ``attention="mla"`` is multi-head
+    latent attention (:class:`_LatentAttention`): low-rank q and kv
+    projections, a rotated key part all heads share, and a cache of one
+    ``kv_lora_rank + qk_rope_head_dim`` row a token.  ``layer="scmoe"``
+    is the shortcut-connected double layer (:class:`_ShortcutBlock`): two
+    attentions and two dense SwiGLU FFNs of width ``ffn_hidden`` in a
+    row, with one routed-experts branch leaving after the first attention
+    and joining after the second FFN.  The router there scores
+    ``num_experts + zero_experts`` outputs, of which this program holds
+    the banks of ``experts_held`` (``models/routed_ffn.py``).
     """
 
     norm: str = "layernorm"  # layernorm | rmsnorm
@@ -59,6 +74,20 @@ class BlockSpec:
     experts_per_token: int = 0
     expert_width: int = 0
     norm_topk_prob: bool = False
+    attention: str = "mha"  # mha | mla
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_pairing: str = "half"  # half (i with i + D/2) | interleaved (2i with 2i + 1)
+    layer: str = "plain"  # plain (attention, FFN) | scmoe
+    ffn_hidden: int = 0
+    zero_experts: int = 0
+    experts_held: int = 0  # 0: every routed expert
+    first_expert: int = 0
+    router_bias: bool = False
+    routed_scaling: float = 1.0
 
 
 def block_spec(
@@ -71,6 +100,16 @@ def block_spec(
     experts_per_token: int = 0,
     expert_width: int = 0,
     norm_topk_prob: bool = False,
+    q_lora_rank: int = 0,
+    kv_lora_rank: int = 0,
+    qk_nope_head_dim: int = 0,
+    qk_rope_head_dim: int = 0,
+    v_head_dim: int = 0,
+    ffn_hidden: int = 0,
+    zero_experts: int = 0,
+    experts_held: int = 0,
+    first_expert: int = 0,
+    routed_scaling: float = 1.0,
 ) -> BlockSpec:
     """The block a named family stacks; the sizes only the family reads
     are ignored by the others (``gpt2`` keeps its own epsilon)."""
@@ -90,7 +129,48 @@ def block_spec(
             experts_per_token=experts_per_token, expert_width=expert_width,
             norm_topk_prob=norm_topk_prob,
         )
-    raise ValueError(f"block family must be gpt2 | olmoe, got {family!r}")
+    if family == "longcat":
+        held = experts_held or num_experts
+        sizes = (
+            q_lora_rank, kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
+            v_head_dim, ffn_hidden, expert_width,
+        )
+        if min(sizes) < 1 or qk_rope_head_dim % 2:
+            raise ValueError(
+                "the longcat block needs its five latent-attention sizes (an "
+                "even rotary part), a dense FFN width and an expert width, "
+                f"got {sizes}"
+            )
+        if not (
+            1 <= experts_per_token <= num_experts + zero_experts
+            and zero_experts >= 0
+            and 0 <= first_expert
+            and 1 <= held
+            and first_expert + held <= num_experts
+        ):
+            raise ValueError(
+                "the longcat router picks experts_per_token of num_experts + "
+                "zero_experts outputs and holds experts first_expert .. "
+                f"first_expert + experts_held of the first num_experts, got "
+                f"{experts_per_token}/{num_experts}/{zero_experts}/"
+                f"{first_expert}/{held}"
+            )
+        return BlockSpec(
+            norm="rmsnorm", norm_eps=norm_eps, positions="rope",
+            rope_theta=rope_theta, ffn="experts", num_experts=num_experts,
+            experts_per_token=experts_per_token, expert_width=expert_width,
+            norm_topk_prob=norm_topk_prob, attention="mla",
+            q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+            qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rope_pairing="interleaved", layer="scmoe", ffn_hidden=ffn_hidden,
+            zero_experts=zero_experts, experts_held=held,
+            first_expert=first_expert, router_bias=True,
+            routed_scaling=routed_scaling,
+        )
+    raise ValueError(
+        f"block family must be gpt2 | olmoe | longcat, got {family!r}"
+    )
 
 
 class TransformerOutput(NamedTuple):
@@ -136,6 +216,20 @@ def init_paged_kv_cache(
         k=tuple(jnp.zeros(shape, dtype) for _ in range(num_layers)),
         v=tuple(jnp.zeros(shape, dtype) for _ in range(num_layers)),
     )
+
+
+class LatentKVCache(NamedTuple):
+    """The paged cache of a latent-attention (``mla``) model: per
+    attention ONE lane-dense ``[num_pages, page_size, W]`` pool of
+    ``[c | rotated k_pe | zeros]`` rows (``W`` =
+    ``latent_pool_width(kv_lora_rank + qk_rope_head_dim)``, 640 for the
+    published 576), which every head shares and which holds the values
+    too: no V pool.  A ``scmoe`` layer has two attentions, so layer ``i``
+    owns ``rows[2i]`` and ``rows[2i + 1]``.  Pages, tables, the null page
+    and every rule of :class:`PagedKVCache` are the same: sharing, forks
+    and the prefix cache are page-index facts and do not see the kind."""
+
+    rows: Tuple[jnp.ndarray, ...]
 
 
 def prompt_attention_mask(lengths: jnp.ndarray, total_len: int) -> jnp.ndarray:
@@ -240,23 +334,46 @@ def _norm(spec: BlockSpec, dtype, name: Optional[str] = None) -> nn.Module:
     return nn.LayerNorm(use_bias=False, dtype=dtype, name=name)
 
 
-def rotary_fn(positions: jnp.ndarray, head_dim: int, theta: float) -> Callable:
+def rotary_fn(
+    positions: jnp.ndarray, head_dim: int, theta: float, pairing: str = "half"
+) -> Callable:
     """``x [B, T, H, D] -> x`` rotated to ``positions [B, T]``: the
     rotate-half pairing (feature ``i`` with ``i + D/2``), ``inv_freq_i =
     theta^(-2i/D)``, angle ``position x inv_freq``; computed in float32
     and rounded once.  The angles are made once a forward and shared by
-    every block."""
+    every block.  Under ``pairing="interleaved"`` pair ``i`` is features
+    ``(2i, 2i + 1)``; the result is laid out half-wise (all first members,
+    then all second: the DeepSeek family's own arrangement), which q and k
+    share, so every score is that of the interleaved rotation."""
     half = head_dim // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / head_dim)
     angle = positions.astype(jnp.float32)[:, :, None, None] * inv_freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
 
     def rotate(x):
-        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        if pairing == "interleaved":
+            xf = x.astype(jnp.float32)
+            x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        else:
+            x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
         out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
         return out.astype(x.dtype)
 
     return rotate
+
+
+def _scatter_rows(pool: jnp.ndarray, flat_idx: jnp.ndarray, rows: jnp.ndarray):
+    """This call's rows into pool pages: a flat single-axis scatter (page
+    id x page size + offset) into the lane-dense pool.  The reshape is a
+    bitcast, and XLA:CPU lowers 1-level row scatters measurably faster
+    than the 2-level fancy-index form."""
+    N, ps, width = pool.shape
+    return (
+        pool.reshape(N * ps, width)
+        .at[flat_idx]
+        .set(rows.astype(pool.dtype).reshape(-1, width))
+        .reshape(pool.shape)
+    )
 
 
 class _Block(nn.Module):
@@ -333,24 +450,9 @@ class _Block(nn.Module):
         new_cache = None
         if paged_cache is not None:
             kp, vp = paged_cache
-            # flat single-axis scatter (page_id * page_size + offset) of
-            # H*D rows into the lane-dense pool: the reshape is a bitcast
-            # and XLA:CPU lowers 1-level row scatters measurably faster
-            # than the 2-level fancy-index form
-            N, ps, width = kp.shape
-            flat_idx = (page_ids * ps + page_offsets).reshape(B * T)
-            kp = (
-                kp.reshape(N * ps, width)
-                .at[flat_idx]
-                .set(k.astype(kp.dtype).reshape(B * T, width))
-                .reshape(kp.shape)
-            )
-            vp = (
-                vp.reshape(N * ps, width)
-                .at[flat_idx]
-                .set(v.astype(vp.dtype).reshape(B * T, width))
-                .reshape(vp.shape)
-            )
+            flat_idx = (page_ids * kp.shape[1] + page_offsets).reshape(B * T)
+            kp = _scatter_rows(kp, flat_idx, k)
+            vp = _scatter_rows(vp, flat_idx, v)
             if page_table is not None and prefix_starts is not None:
                 # shared-table tail prefill: gather the whole context
                 # (cached prefix pages + the tail just scattered above)
@@ -406,6 +508,220 @@ class _Block(nn.Module):
         x = x + h
         if new_cache is not None:
             return x, new_cache
+        return x
+
+
+class _LatentAttention(nn.Module):
+    """Multi-head latent attention (MLA) on a normed input ``h [B, T, d]``.
+
+    ``c_q = RMSNorm(h W_qa)``; ``q = s_q (c_q W_qb)``, a head ``[q_nope |
+    q_pe]``; ``[c | k_pe] = h W_kva``; ``c = s_kv RMSNorm(c)``; ``[k_nope |
+    v]`` a head ``= c W_kvb``; rotary on ``q_pe`` and on the one ``k_pe``
+    all heads share; scores ``(q_nope . k_nope + q_pe . k_pe) /
+    sqrt(nope + rope)``, softmax in float32; ``o = concat(p v) W_o``.
+    ``s_q = sqrt(d / q_lora_rank)``, ``s_kv = sqrt(d / kv_lora_rank)``.
+
+    One set of parameters, two forms of the same product:
+
+    - **un-absorbed** wherever the keys are this call's own (the full
+      and masked forwards, packed rows, the local prefill): ``k_nope`` and
+      ``v`` are made from ``c`` and the call sites of :class:`_Block`
+      attend (``v`` is padded to the q/k head size for the kernels that
+      take one head size, and the pad sliced off).
+    - **absorbed** wherever the keys come through a page table (decode,
+      the tail prefill over a cached prefix, the speculative verify): the
+      cache holds ``[c | rotated k_pe]`` a token and never a head's K or
+      V, so ``W_kvb`` moves to the query side, ``q_abs = [W_uk^T q_nope |
+      q_pe]``, scores are ``q_abs . row``, and a head's output is
+      ``W_uv (sum p row[:kv_lora_rank])``.  Decode goes through
+      ``paged_attn_fn`` (``ops.pallas_paged_attention.paged_decode_latent``
+      or its XLA twin), the other two gather rows and run
+      :func:`latent_attention`.
+
+    Returns ``(out [B, T, d], pool)``; ``pool`` is None without a cache.
+    """
+
+    d_model: int
+    num_heads: int
+    spec: BlockSpec
+    attn_fn: AttentionFn
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    paged_attn_fn: Optional[Callable] = None
+    segment_attn_fn: Optional[Callable] = None
+    rotary: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(
+        self, h, attn_mask=None, pool=None, page_ids=None, page_offsets=None,
+        page_table=None, attn_lengths=None, prefix_starts=None,
+        segment_ids=None,
+    ):
+        B, T, _ = h.shape
+        s, H = self.spec, self.num_heads
+        nope, rope, vd = s.qk_nope_head_dim, s.qk_rope_head_dim, s.v_head_dim
+        r_kv = s.kv_lora_rank
+        f32 = jnp.float32
+        dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+
+        def dense(width, name, **kw):
+            return nn.Dense(width, use_bias=False, name=name, **dt, **kw)
+
+        # the two projections OUT of a rank start at the variance a
+        # ``d_model``-wide input would give them (std ``d_model ** -0.5``),
+        # which is what the two scales below bring back to one: q, k and v
+        # of unit variance and attention scores of order one at the start.
+        # Plain fan-in over the rank would leave the scores ``s_q x s_kv``
+        # (7 at the published sizes) too large and the softmax near one-hot
+        aligned = nn.initializers.normal(self.d_model ** -0.5)
+        c_q = RMSNorm(s.norm_eps, dtype=self.dtype, name="q_a_norm")(
+            dense(s.q_lora_rank, "q_a")(h)
+        )
+        q = dense(H * (nope + rope), "q_b", kernel_init=aligned)(c_q)
+        q = (q * (self.d_model / s.q_lora_rank) ** 0.5).reshape(B, T, H, nope + rope)
+        q_nope, q_pe = q[..., :nope], q[..., nope:]
+        kv = dense(r_kv + rope, "kv_a")(h)
+        # the scale is applied in the norm's float32, rounded once
+        c = RMSNorm(s.norm_eps, dtype=f32, name="kv_a_norm")(kv[..., :r_kv])
+        c = (c * (self.d_model / r_kv) ** 0.5).astype(self.dtype)
+        k_pe = kv[..., None, r_kv:]  # [B, T, 1, rope]: one for all heads
+        q_pe, k_pe = self.rotary(q_pe), self.rotary(k_pe)
+        w_kvb = self.param(
+            "kv_b", aligned, (r_kv, H * (nope + vd)), self.param_dtype
+        ).astype(self.dtype)
+        scale = 1.0 / (nope + rope) ** 0.5
+        if pool is not None:
+            # the cached row, normed, scaled and rotated, zero to the
+            # pool's whole tiles
+            row = jnp.concatenate([c, k_pe[:, :, 0]], axis=-1)
+            row = jnp.pad(row, ((0, 0), (0, 0), (0, pool.shape[2] - row.shape[-1])))
+            flat_idx = (page_ids * pool.shape[1] + page_offsets).reshape(B * T)
+            pool = _scatter_rows(pool, flat_idx, row)
+        if page_table is not None:
+            w = w_kvb.reshape(r_kv, H, nope + vd)
+            # as wide as the pool's row, zeros against its pad columns: the
+            # kernel then takes the query as it is
+            q_abs = jnp.concatenate(
+                [
+                    jnp.einsum(
+                        "bthn,chn->bthc", q_nope, w[..., :nope],
+                        preferred_element_type=f32,
+                    ),
+                    q_pe.astype(f32),
+                    jnp.zeros((B, T, H, pool.shape[2] - r_kv - rope), f32),
+                ],
+                axis=-1,
+            )
+            if prefix_starts is not None:
+                rows = gather_pages(pool, page_table, 1)[:, :, 0]
+                pos = jnp.arange(rows.shape[1])[None, None, :]
+                qpos = (
+                    prefix_starts[:, None] + jnp.arange(T)[None, :]
+                )[:, :, None]
+                lat = latent_attention(q_abs, rows, pos <= qpos, r_kv, scale)
+            else:
+                paged = self.paged_attn_fn or paged_latent_attention_reference
+                lat = paged(q_abs, pool, page_table, attn_lengths, r_kv, scale)
+            out = jnp.einsum(
+                "bthc,chv->bthv", lat.astype(self.dtype), w[..., nope:],
+                preferred_element_type=f32,
+            ).astype(self.dtype)
+        else:
+            kvb = jnp.dot(c, w_kvb).reshape(B, T, H, nope + vd)
+            k = jnp.concatenate(
+                [kvb[..., :nope], jnp.broadcast_to(k_pe, (B, T, H, rope))],
+                axis=-1,
+            )
+            qf = jnp.concatenate([q_nope, q_pe], axis=-1)
+            v = kvb[..., nope:]
+            packed = segment_ids is not None and self.segment_attn_fn is not None
+            if pool is not None or (attn_mask is not None and not packed):
+                out = _masked_attention(qf, k, v, attn_mask, self.dtype)
+            else:
+                # kernels that take one head size: v padded to q and k's
+                v = jnp.pad(v, ((0, 0),) * 3 + ((0, nope + rope - vd),))
+                if packed:
+                    out = self.segment_attn_fn(qf, k, v, segment_ids)
+                else:
+                    out = self.attn_fn(qf, k, v)
+                out = out[..., :vd].astype(self.dtype)
+        out = dense(self.d_model, "proj")(out.reshape(B, T, H * vd))
+        return out, pool
+
+
+class _GatedMLP(nn.Module):
+    """Dense SwiGLU FFN, no bias: ``(silu(h Wg) * (h Wu)) Wd``."""
+
+    d_model: int
+    hidden: int
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        dt = dict(use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype)
+        a = nn.silu(nn.Dense(self.hidden, name="gate", **dt)(h))
+        a = a * nn.Dense(self.hidden, name="up", **dt)(h)
+        return nn.Dense(self.d_model, name="down", **dt)(a)
+
+
+class _ShortcutBlock(nn.Module):
+    """The shortcut-connected double layer (``layer="scmoe"``), ``N`` an
+    RMSNorm of its own at each use::
+
+        x1 = x + MLA_0(N(x));  h = N(x1);  m = MoE(h)
+        x2 = x1 + FFN_0(h)
+        x3 = x2 + MLA_1(N(x2))
+        out = x3 + FFN_1(N(x3)) + m
+
+    The routed experts read what the first dense FFN reads and their sum
+    joins after the second, so the expert branch can run beside the
+    layer's second half.  Two attentions: a layer owns two cache pools.
+    The call arguments are :class:`_Block`'s, ``paged_cache`` the pair of
+    latent pools."""
+
+    d_model: int
+    num_heads: int
+    attn_fn: AttentionFn
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    paged_attn_fn: Optional[Callable] = None
+    segment_attn_fn: Optional[Callable] = None
+    spec: BlockSpec = BlockSpec()
+    rotary: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x, paged_cache=None, **call):
+        spec = self.spec
+        dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        pools = paged_cache if paged_cache is not None else (None, None)
+
+        def attention(i, x):
+            out, pool = _LatentAttention(
+                self.d_model, self.num_heads, spec, self.attn_fn,
+                paged_attn_fn=self.paged_attn_fn,
+                segment_attn_fn=self.segment_attn_fn, rotary=self.rotary,
+                name=f"attn_{i}", **dt,
+            )(_norm(spec, self.dtype, f"attn_norm_{i}")(x), pool=pools[i], **call)
+            return x + out, pool
+
+        def ffn(i, h):
+            return _GatedMLP(self.d_model, spec.ffn_hidden, name=f"ffn_{i}", **dt)(h)
+
+        x, pool_0 = attention(0, x)
+        h = _norm(spec, self.dtype, "ffn_norm_0")(x)
+        m = RoutedExperts(
+            spec.num_experts, spec.experts_per_token, spec.expert_width,
+            spec.norm_topk_prob, zero_experts=spec.zero_experts,
+            held=spec.experts_held, first_expert=spec.first_expert,
+            choice_bias=spec.router_bias, routed_scaling=spec.routed_scaling,
+            name="experts", **dt,
+        )(h)
+        x = x + ffn(0, h)
+        x, pool_1 = attention(1, x)
+        x = x + ffn(1, _norm(spec, self.dtype, "ffn_norm_1")(x)) + m
+        if paged_cache is not None:
+            return x, (pool_0, pool_1)
         return x
 
 
@@ -467,7 +783,31 @@ class TransformerPolicy(nn.Module):
 
     @property
     def head_dim(self) -> int:
+        """The head size of q and k (of an ``mla`` block: both parts)."""
+        if self.block.attention == "mla":
+            return self.block.qk_nope_head_dim + self.block.qk_rope_head_dim
         return self.block.head_dim or self.d_model // self.num_heads
+
+    def init_paged_cache(self, num_pages: int, page_size: int, dtype=jnp.float32):
+        """Zeroed page pools of the kind and number this model's blocks
+        cache into (page 0 = the never-read null page): the cache is
+        described by the model, and everything that holds it (the engine,
+        its fork and its programs) treats it as one pytree of ``[num_pages,
+        page_size, width]`` pools."""
+        spec = self.block
+        if spec.attention == "mla":
+            width = latent_pool_width(spec.kv_lora_rank + spec.qk_rope_head_dim)
+            per_layer = 2 if spec.layer == "scmoe" else 1
+            return LatentKVCache(
+                rows=tuple(
+                    jnp.zeros((num_pages, page_size, width), dtype)
+                    for _ in range(per_layer * self.num_layers)
+                )
+            )
+        return init_paged_kv_cache(
+            num_pages, page_size, self.num_layers, self.num_heads,
+            self.head_dim, dtype,
+        )
 
     @nn.compact
     def __call__(
@@ -544,7 +884,12 @@ class TransformerPolicy(nn.Module):
                 dtype=self.dtype, param_dtype=self.param_dtype,
             )(obs.reshape(B, T, -1).astype(self.dtype))
         rotary = None
-        if spec.positions == "rope":
+        if spec.attention == "mla":
+            rotary = rotary_fn(
+                positions, spec.qk_rope_head_dim, spec.rope_theta,
+                spec.rope_pairing,
+            )
+        elif spec.positions == "rope":
             rotary = rotary_fn(positions, self.head_dim, spec.rope_theta)
         else:
             pos_tab = self.param(
@@ -555,14 +900,10 @@ class TransformerPolicy(nn.Module):
             )
             x = x + pos_tab[positions].astype(self.dtype)
         x = c(x)
-        new_k = []
-        new_v = []
+        scmoe = spec.layer == "scmoe"
+        pools = []  # mha: (k, v) a block; scmoe: its two latent pools
         for i in range(self.num_layers):
-            block = _Block(
-                self.d_model,
-                self.num_heads,
-                self.mlp_ratio,
-                attn,
+            common = dict(
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
                 paged_attn_fn=self.paged_attn_fn,
@@ -571,19 +912,27 @@ class TransformerPolicy(nn.Module):
                 rotary=rotary,
                 name=f"block_{i}",
             )
+            if scmoe:
+                block = _ShortcutBlock(self.d_model, self.num_heads, attn, **common)
+            else:
+                block = _Block(
+                    self.d_model, self.num_heads, self.mlp_ratio, attn, **common
+                )
             if paged_cache is not None:
-                x, (bk, bv) = block(
+                x, written = block(
                     x,
                     attn_mask=attn_mask,
-                    paged_cache=(paged_cache.k[i], paged_cache.v[i]),
+                    paged_cache=(
+                        paged_cache.rows[2 * i : 2 * i + 2] if scmoe
+                        else (paged_cache.k[i], paged_cache.v[i])
+                    ),
                     page_ids=page_ids,
                     page_offsets=page_offsets,
                     page_table=page_table,
                     attn_lengths=attn_lengths,
                     prefix_starts=prefix_starts,
                 )
-                new_k.append(bk)
-                new_v.append(bv)
+                pools.append(written)
             elif segment_ids is not None:
                 x = block(x, segment_ids=segment_ids)
             else:
@@ -593,6 +942,10 @@ class TransformerPolicy(nn.Module):
         policy_logits = nn.Dense(self.num_actions, name="policy_head")(x)
         baseline = nn.Dense(1, name="value_head")(x).squeeze(-1)
         out = TransformerOutput(policy_logits, baseline)
-        if paged_cache is not None:
-            return out, PagedKVCache(k=tuple(new_k), v=tuple(new_v))
-        return out
+        if paged_cache is None:
+            return out
+        if scmoe:
+            return out, LatentKVCache(rows=tuple(p for pair in pools for p in pair))
+        return out, PagedKVCache(
+            k=tuple(k for k, _v in pools), v=tuple(v for _k, v in pools)
+        )

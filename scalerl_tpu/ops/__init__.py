@@ -14,6 +14,8 @@ from scalerl_tpu.ops.pallas_paged_attention import (  # noqa: F401
     make_paged_attn_fn,
     paged_attention_reference,
     paged_decode_attention,
+    paged_decode_latent,
+    paged_latent_attention_reference,
     resolve_paged_attn,
 )
 from scalerl_tpu.ops.ring_attention import (  # noqa: F401

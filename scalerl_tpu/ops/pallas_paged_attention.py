@@ -42,6 +42,10 @@ Two implementations behind one contract:
   accumulator in VMEM scratch; float32 operands reach the MXU as exact
   bfloat16 terms, never rounded.  Interpret mode off-TPU; Mosaic on TPU.
 
+A latent-attention (MLA) model caches ONE row a token that every head
+shares; its decode kernel, :func:`paged_decode_latent`, and its XLA twin
+are the second half of this file, on the same walk.
+
 Grad-free by construction: decode is inference-only, no ``custom_vjp`` is
 defined, and differentiating through ``pallas_call`` raises — the learner
 recomputes logits with the dense training forward, never through this op.
@@ -187,11 +191,17 @@ def _dot_f32(a_terms, b, contract):
     middle term, the first its low term.  Measured on the chip against
     ``HIGHEST`` (PERF.md, PR 26): the same error, 13% less kernel time at
     gpt2-medium's width."""
+    return _dot_terms(a_terms, _bf16_terms(b), contract)
+
+
+def _dot_terms(a_terms, b_terms, contract):
+    """:func:`_dot_f32` on ``b``'s terms as :func:`_bf16_terms` made them
+    (a caller that multiplies one block twice makes them once)."""
     rows = a_terms.shape[0] // 3
     total = None
     # from ``b``'s low term up, and within a product from ``a``'s lowest
     # term up: the small products enter the sum before the leading one
-    for i, term in reversed(list(enumerate(_bf16_terms(b)))):
+    for i, term in reversed(list(enumerate(b_terms))):
         r = jax.lax.dot_general(
             a_terms[: (3 - i) * rows], term, (contract, ((), ())),
             # bfloat16 terms multiply exactly in one pass, whatever
@@ -384,10 +394,238 @@ def paged_decode_attention(
     return out.reshape(B, 1, H, D)
 
 
-def make_paged_attn_fn(impl: str = "auto"):
+# ======================================================================
+# The latent (MLA) cache: one row a token that every head shares
+# ======================================================================
+# A latent-attention layer caches ``[c | rotated k_pe]`` a token, ``W`` =
+# ``kv_lora_rank + qk_rope_head_dim`` wide (576 at the published sizes),
+# in ONE lane-dense pool with no V pool: the values are the row's first
+# ``value_width`` columns.  The pool is ``[num_pages, page_size,
+# latent_pool_width(W)]``: ``W`` rounded up to whole 128-lane tiles (640),
+# the pad columns zero.  The TPU stores a 576-wide row in 640 lanes
+# whatever the shape says, and a page can be copied out of HBM only in
+# whole tiles, so the pool says what it is.  The decode query arrives
+# *absorbed*, ``q_abs = [W_uk^T q_nope | q_pe]`` (``[H, W]`` a lane), so
+# all heads' scores are one ``[H, W] x [W, T]`` product against rows that
+# are read once.
+
+
+def latent_pool_width(row_width: int) -> int:
+    """Lanes a latent pool gives a ``row_width``-wide row: whole tiles."""
+    return -(-row_width // 128) * 128
+
+
+def latent_attention(
+    q: jnp.ndarray,
+    rows: jnp.ndarray,
+    mask: jnp.ndarray,
+    value_width: int,
+    scale: float,
+) -> jnp.ndarray:
+    """Absorbed attention in plain XLA: ``q [B, T, H, W]`` against latent
+    ``rows [B, S, W]`` under ``mask [B, T, S]`` (True = attend), float32
+    scores and softmax; returns ``[B, T, H, value_width]`` float32, the
+    probability-weighted sum of the rows' first ``value_width`` columns.
+    Fully masked query rows degrade to uniform, finite, like
+    ``models/transformer._masked_attention``."""
+    rows = rows.astype(jnp.float32)
+    scores = jnp.einsum("bthw,bsw->bhts", q.astype(jnp.float32), rows) * scale
+    scores = jnp.where(mask[:, None, :, :], scores, jnp.float32(_NEG_BIG))
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhts,bsc->bthc", probs, rows[..., :value_width])
+
+
+def paged_latent_attention_reference(
+    q: jnp.ndarray,
+    pool: jnp.ndarray,
+    page_table: jnp.ndarray,
+    lengths: jnp.ndarray,
+    value_width: int,
+    scale: float,
+) -> jnp.ndarray:
+    """XLA gather twin of :func:`paged_decode_latent`, the oracle it is
+    pinned to: ``q [B, 1, H, W]`` absorbed queries, ``pool [N, page_size,
+    latent_pool_width(W)]``, ``page_table [B, M]``, ``lengths [B]`` (>= 1); returns ``[B, 1,
+    H, value_width]`` float32."""
+    rows = gather_pages(pool, page_table, 1)[:, :, 0, : q.shape[-1]]  # [B, S, W]
+    valid = jnp.arange(rows.shape[1])[None, :] < lengths[:, None]
+    return latent_attention(q, rows, valid[:, None, :], value_width, scale)
+
+
+@functools.lru_cache(maxsize=None)
+def _note_latent_tiling(q_shape, pool_shape, dtype: str, pages: int) -> None:
+    """One zero-length program span a traced shape (the cache is the
+    "once"), so that a trace says which walk ran: lanes, heads, the row's
+    width, tokens a block."""
+    from scalerl_tpu.runtime import tracing
+
+    with tracing.span(
+        "latent_decode.tiling", kind="kernel", shape=list(q_shape),
+        pool=list(pool_shape), dtype=dtype, pages_per_block=pages,
+        block_tokens=pages * pool_shape[1],
+    ):
+        pass
+
+
+def _latent_kernel(
+    pt_ref, len_ref, q_ref, pool_hbm, o_ref,
+    buf, sems, q_sc, acc_sc, m_sc, l_sc, walked_sc,
+    *, scale, value_width,
+):
+    """:func:`_decode_kernel`'s walk over ONE pool: a lane a grid step,
+    its live pages a block of ``P`` at a time, one async copy a live page
+    into the double-buffered ``buf [2, P, page, W]``, the next block (this
+    lane's, or the next lane's first) in flight while this one is attended
+    to.  The one copied block serves scores AND values: its bfloat16 terms
+    are made once, the score product contracts all ``W`` columns against
+    the absorbed query ``q_sc [3 * heads, W]``, and the value product
+    takes the same terms' first ``value_width`` columns.  Pages past the
+    lane's length are not fetched; the positions they would fill are
+    masked in the scores and zeroed in the block, so nothing stale in
+    VMEM reaches the result."""
+    b = pl.program_id(0)
+    lanes = pl.num_programs(0)
+    _, P, ps, width = buf.shape
+    T = P * ps
+    slots = pt_ref.shape[1]
+
+    def live_pages(lane, i):
+        return jnp.clip(pl.cdiv(len_ref[lane], ps), 1, slots) - i * P
+
+    def each_live_page(lane, i, at_buf, act):
+        def page(j, carry):
+            at = pt_ref[lane, i * P + j]
+            act(pltpu.make_async_copy(pool_hbm.at[at], buf.at[at_buf, j], sems.at[at_buf]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(live_pages(lane, i), P), page, 0)
+
+    @pl.when(b == 0)
+    def _first_block():
+        walked_sc[0] = 0
+        each_live_page(0, 0, 0, lambda copy: copy.start())
+
+    first = walked_sc[0]
+    length = len_ref[b]
+    blocks = pl.cdiv(live_pages(b, 0), P)
+    q_sc[...] = jnp.concatenate(
+        _bf16_terms(q_ref[...].astype(jnp.float32) * scale), axis=0
+    )
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+    m_sc[...] = jnp.full_like(m_sc, _NEG_BIG)
+    l_sc[...] = jnp.zeros_like(l_sc)
+
+    def attend(i, carry):
+        at_buf = (first + i) % 2
+        last = i + 1 == blocks
+        next_lane = jnp.where(last, b + 1, b)
+
+        @pl.when(next_lane < lanes)
+        def _prefetch():
+            each_live_page(
+                next_lane, jnp.where(last, 0, i + 1), 1 - at_buf,
+                lambda copy: copy.start(),
+            )
+
+        each_live_page(b, i, at_buf, lambda copy: copy.wait())
+
+        def no_page(j, carry):
+            buf[at_buf, j] = jnp.zeros((ps, width), buf.dtype)
+            return carry
+
+        jax.lax.fori_loop(live_pages(b, i), P, no_page, 0)  # only the last block
+
+        # merged as float32, whose sublane tile a page of 8 fills
+        rows = buf[at_buf].astype(jnp.float32).reshape(T, width).astype(buf.dtype)
+        terms = _bf16_terms(rows)
+        s = _dot_terms(q_sc[...], terms, ((1,), (1,)))  # [heads, T]
+        pos = i * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < length, s, jnp.float32(_NEG_BIG))
+        m = m_sc[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_sc[...] = m_new
+        p_terms = jnp.concatenate(_bf16_terms(p), axis=0)
+        values = tuple(t[:, :value_width] for t in terms)
+        acc_sc[...] = acc_sc[...] * corr + _dot_terms(p_terms, values, ((1,), (0,)))
+        return carry
+
+    jax.lax.fori_loop(0, blocks, attend, 0)
+    walked_sc[0] = first + blocks
+    o_ref[...] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
+
+
+def paged_decode_latent(
+    q: jnp.ndarray,
+    pool: jnp.ndarray,
+    page_table: jnp.ndarray,
+    lengths: jnp.ndarray,
+    value_width: int,
+    scale: float,
+    interpret: Optional[bool] = None,
+) -> jnp.ndarray:
+    """Pallas absorbed decode attention over a latent pool; same contract
+    as :func:`paged_latent_attention_reference`.  Grid ``(lanes,)``, the
+    pool left in HBM (``pltpu.ANY``), table and lengths scalar-prefetched,
+    float32 scores, softmax state and accumulator."""
+    if interpret is None:
+        interpret = _interpret_default()
+    B, T, H, W = q.shape
+    if T != 1:
+        raise ValueError(f"decode attention takes one query token, got T={T}")
+    if pool.shape[2] != latent_pool_width(W) or not 0 < value_width <= W:
+        raise ValueError(
+            f"a latent pool row is the absorbed query's width in whole "
+            f"tiles (the query may come padded to them): got pool "
+            f"{pool.shape}, q {q.shape}, value_width {value_width}"
+        )
+    ps = pool.shape[1]
+    P = pages_per_block(ps, pool.shape[2], pool.dtype.itemsize)
+    rows = -(-H // 16) * 16  # whole bfloat16 sublane tiles of heads
+    _note_latent_tiling(tuple(q.shape), tuple(pool.shape), str(pool.dtype), P)
+    qp = q.reshape(B, H, W)
+    if (rows, pool.shape[2]) != (H, W):
+        # zeros meet the pool's pad columns, and the rows past the last head
+        qp = jnp.pad(qp, ((0, 0), (0, rows - H), (0, pool.shape[2] - W)))
+        W = pool.shape[2]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((None, rows, W), lambda b, pt, ln: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.ANY),
+        ],
+        out_specs=pl.BlockSpec((None, rows, value_width), lambda b, pt, ln: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, P, ps, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),  # one a buffer
+            pltpu.VMEM((3 * rows, W), jnp.bfloat16),  # the absorbed query's terms
+            pltpu.VMEM((rows, value_width), jnp.float32),  # accumulator
+            pltpu.VMEM((rows, 1), jnp.float32),  # running maximum
+            pltpu.VMEM((rows, 1), jnp.float32),  # running sum
+            pltpu.SMEM((1,), jnp.int32),  # blocks walked
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, scale=scale, value_width=value_width),
+        name="paged_decode_latent",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, rows, value_width), jnp.float32),
+        # the buffers and the block count carry from lane to lane
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), qp, pool)
+    return out[:, None, :H]
+
+
+def make_paged_attn_fn(impl: str = "auto", attention: str = "mha"):
     """The ``TransformerPolicy.paged_attn_fn`` seam: resolve once, close
-    over the choice, keep the jitted decode program shape-stable."""
-    resolved = resolve_paged_attn(impl)
-    if resolved == "pallas":
-        return paged_decode_attention
-    return paged_attention_reference
+    over the choice, keep the jitted decode program shape-stable.  The
+    model's cache kind (``BlockSpec.attention``) picks the pair: K and V
+    pools of ``H*D`` rows (``mha``) or the one latent pool (``mla``)."""
+    pallas = resolve_paged_attn(impl) == "pallas"
+    if attention == "mla":
+        return paged_decode_latent if pallas else paged_latent_attention_reference
+    return paged_decode_attention if pallas else paged_attention_reference

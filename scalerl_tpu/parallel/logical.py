@@ -78,6 +78,19 @@ PARAM_LOGICAL_AXES: Dict[Tuple[str, ...], Tuple[Optional[str], ...]] = {
     ("w_up",): ("experts", "embed", None),
     ("w_down",): ("experts", None, "embed"),
     ("experts", "router"): ("embed", None),
+    # The longcat block (models/transformer.py): the latent attention's
+    # up-projections lead out to the heads and shard there, its two
+    # down-projections (``q_a``, ``kv_a``: ranks, and a rotary part every
+    # head shares) replicate, as do the norms over those ranks and the
+    # router's choice bias; the dense SwiGLU FFNs shard their hidden width
+    # like the GPT-2 MLP.  ``proj`` is the rule above.
+    ("q_a", "kernel"): ("embed", None),
+    ("q_b", "kernel"): (None, "heads"),
+    ("kv_a", "kernel"): ("embed", None),
+    ("kv_b",): (None, "heads"),
+    ("gate", "kernel"): ("embed", "mlp"),
+    ("up", "kernel"): ("embed", "mlp"),
+    ("down", "kernel"): ("mlp", "embed"),
 }
 
 

@@ -98,6 +98,16 @@ def build_genrl_model(args: GenRLArguments) -> TransformerPolicy:
             experts_per_token=args.moe_experts_per_token,
             expert_width=args.moe_hidden,
             norm_topk_prob=args.moe_norm_topk_prob,
+            q_lora_rank=args.mla_q_lora_rank,
+            kv_lora_rank=args.mla_kv_lora_rank,
+            qk_nope_head_dim=args.mla_qk_nope_head_dim,
+            qk_rope_head_dim=args.mla_qk_rope_head_dim,
+            v_head_dim=args.mla_v_head_dim,
+            ffn_hidden=args.ffn_hidden,
+            zero_experts=args.moe_zero_experts,
+            experts_held=args.moe_experts_held,
+            first_expert=args.moe_first_expert,
+            routed_scaling=args.moe_routed_scaling,
         ),
     )
 

@@ -29,12 +29,20 @@ from jax.sharding import SingleDeviceSharding
 
 from scalerl_tpu.genrl.continuous import ContinuousConfig, ContinuousEngine
 from scalerl_tpu.models.transformer import TransformerPolicy, block_spec
-from scalerl_tpu.ops.pallas_paged_attention import paged_decode_attention
+from scalerl_tpu.ops.pallas_paged_attention import (
+    paged_decode_attention,
+    paged_decode_latent,
+)
 
 LAYERS, HEADS, LANES, PAGE, PAGES = 2, 16, 4, 8, 301
 # the attention geometry of each block family the benchmark runs: a pool
-# row is ``heads x head size`` wide, 1024 (gpt2-medium) and 2048 (OLMoE)
-HEAD_DIM = {"gpt2": 64, "olmoe": 128}
+# row is ``heads x head size`` wide, 1024 (gpt2-medium) and 2048 (OLMoE);
+# longcat's is the latent row every head shares, 576 stored in 640 lanes
+HEAD_DIM = {"gpt2": 64, "olmoe": 128, "longcat": 192}
+LATENT = dict(
+    q_lora_rank=256, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, ffn_hidden=256, zero_experts=4, experts_held=2,
+)
 
 
 @pytest.fixture(scope="module")
@@ -67,18 +75,26 @@ def _no_persistent_cache():
 def engine(request):
     """2 layers of gpt2-medium's attention geometry (16 heads of 64), and
     2 of OLMoE's (16 heads of 128, q/k norm and rotation before the cache
-    write, a small routed FFN: pools ``[pages, 8, 2048]``), over 301 pages
-    of 8; the compiled kernel is pinned behind the attention seam because
+    write, a small routed FFN: pools ``[pages, 8, 2048]``), and 2 double
+    layers of LongCat's latent attention at its published sizes (8 heads
+    of 128 + 64, rows of 512 + 64: four pools ``[pages, 8, 640]``, no V),
+    over 301 pages of 8; the compiled kernel is pinned behind the attention seam because
     ``auto`` resolves to the XLA gather on this CPU backend."""
     vocab = 128
     family = request.param
     head_dim = HEAD_DIM[family]
+    kernel = paged_decode_latent if family == "longcat" else paged_decode_attention
     model = TransformerPolicy(
-        num_actions=vocab, vocab_size=vocab, d_model=HEADS * head_dim,
-        num_heads=HEADS, num_layers=LAYERS, mlp_ratio=1, max_len=256,
-        paged_attn_fn=functools.partial(paged_decode_attention, interpret=False),
+        num_actions=vocab, vocab_size=vocab,
+        d_model=512 if family == "longcat" else HEADS * head_dim,
+        # 8 heads keep every longcat weight smaller than its 1.5 M-value pool,
+        # so that "a copy the size of a pool" can only be a pool
+        num_heads=8 if family == "longcat" else HEADS, num_layers=LAYERS,
+        mlp_ratio=1, max_len=256,
+        paged_attn_fn=functools.partial(kernel, interpret=False),
         block=block_spec(
-            family, head_dim=head_dim, num_experts=4, experts_per_token=2, expert_width=128
+            family, head_dim=head_dim, num_experts=4, experts_per_token=2, expert_width=128,
+            **(LATENT if family == "longcat" else {}),
         ),
     )
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
@@ -124,7 +140,7 @@ def _assert_pools_read_in_place(text, eng):
     small the compiler may prefetch into another memory space and write
     back (``copy-start``, the layout unchanged but for ``S(n)``): that is
     its own business and does not happen at a real pool's size."""
-    pool = PAGES * PAGE * HEADS * eng.model.head_dim
+    pool = int(np.prod(jax.tree_util.tree_leaves(eng._pools)[0].shape))
     moved = [
         line.strip()[:160]
         for line in text.splitlines()
@@ -153,7 +169,9 @@ def test_decode_macro_step_reads_the_pools_in_place(engine, one_chip):
         int(out): int(param)
         for out, param in re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
     }
-    for i in range(2 * LAYERS):
+    pools = len(jax.tree_util.tree_leaves(engine._pools))
+    assert pools == 2 * LAYERS  # K and V a block, or a double layer's two latent pools
+    for i in range(pools):
         assert aliased.get(i) == leaves + i, (i, aliased)
 
 

@@ -366,6 +366,47 @@ def test_paged_kernel_matches_reference_across_layouts(table, lengths):
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=1e-5)
 
 
+@pytest.mark.parametrize("scratch", ["plain", "nan"])
+@pytest.mark.parametrize(
+    "lengths",
+    [
+        [1, 7, 8, 130, 160],  # ends inside a page, on a page, inside the second block
+        [128, 129, 1, 256, 255],  # on and around the 128-token block's boundary
+    ],
+)
+def test_latent_kernel_matches_reference(lengths, scratch):
+    """``paged_decode_latent`` (the absorbed MLA decode over ONE pool of
+    shared rows, ISSUE 30) in interpret mode against its XLA gather twin:
+    fragmented tables whose slots past the length name other lanes' pages,
+    lengths that end inside a block, and, under ``nan``, the TPU
+    interpreter with every scratch buffer filled with NaN, so that a
+    position the kernel neither fetched nor zeroed would poison the
+    result.  The pool's pad columns (48 -> 128 lanes) hold junk: the
+    query is zero there."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from scalerl_tpu.ops.pallas_paged_attention import (
+        latent_pool_width,
+        paged_decode_latent,
+        paged_latent_attention_reference,
+    )
+
+    rng = np.random.default_rng(8)
+    B, H, W, VW, ps, N, M = len(lengths), 4, 48, 32, 8, 120, 32
+    q = jnp.asarray(rng.normal(size=(B, 1, H, W)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(N, ps, latent_pool_width(W))), jnp.float32)
+    table = jnp.asarray(rng.permutation(np.arange(1, N))[: B * M // 2].reshape(B, M // 2))
+    table = jnp.concatenate([table, table[::-1]], axis=1).astype(jnp.int32)
+    ln = jnp.asarray(lengths, jnp.int32)
+    ref = paged_latent_attention_reference(q, pool, table, ln, VW, 0.2)
+    interpret = pltpu.InterpretParams() if scratch == "nan" else True
+    out = paged_decode_latent(q, pool, table, ln, VW, 0.2, interpret=interpret)
+    assert out.shape == (B, 1, H, VW) and bool(jnp.all(jnp.isfinite(out)))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    with pytest.raises(ValueError, match=r"whole\s+tiles"):
+        paged_decode_latent(q, pool[:, :, :W], table, ln, VW, 0.2, interpret=True)
+
+
 def test_paged_reference_fragmentation_independence():
     """The same logical context through two different physical page
     layouts produces identical attention output — content addressing is

@@ -562,6 +562,41 @@ def test_paged_decode_attention_ragged_at_the_cells_geometry(B, H, D):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
+def test_paged_decode_latent_ragged_at_the_cells_geometry():
+    """The absorbed latent decode kernel (ISSUE 30) compiled at
+    ``longcat_group_rollout``'s geometry: 128 lanes of 64 heads over rows
+    of 576 in a 16,385-page pool 640 lanes wide, lanes whose lengths sit
+    on and around the 128-token block's boundaries with dead lanes between
+    them, twice over so that a block's buffer parity carries from a lane
+    to the next in both states (interpret mode starts every scratch alike
+    and cannot show a stale parity: PR 26).  Every slot past a lane's
+    length holds another lane's page id, which must not reach the result;
+    the pool's pad columns hold junk that the zero-padded query must not
+    see either."""
+    from scalerl_tpu.ops.pallas_paged_attention import (
+        latent_pool_width,
+        paged_decode_latent,
+        paged_latent_attention_reference,
+    )
+
+    B, H, W, VW, ps, M = 128, 64, 576, 512, 8, 128
+    N = B * M + 1
+    k1, k2 = jax.random.split(jax.random.PRNGKey(30))
+    q = _rand(k1, B, 1, H, W)
+    pool = _rand(k2, N, ps, latent_pool_width(W))
+    rng = np.random.default_rng(30)
+    table = jnp.asarray(
+        rng.permutation(np.arange(1, N))[: B * M].reshape(B, M), jnp.int32
+    )
+    mix = [1, 7, 64, 65, 511, 1023, 1024, 1, 127, 128, 129, 1, 256, 257, 640, 1, 385]
+    lengths = jnp.asarray([mix[b % len(mix)] for b in range(B)], jnp.int32)
+    scale = 192 ** -0.5
+    out = paged_decode_latent(q, pool, table, lengths, VW, scale, interpret=False)
+    with jax.default_matmul_precision("highest"):
+        ref = paged_latent_attention_reference(q, pool, table, lengths, VW, scale)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
 @pytest.mark.usefixtures("f32_matmuls")
 def test_continuous_engine_macro_step_on_tpu():
     """One continuous-batching macro-step compiled on the chip: paged
