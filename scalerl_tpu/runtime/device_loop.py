@@ -20,7 +20,7 @@ the on-policy special case); the *host* actor plane
 from __future__ import annotations
 
 from contextlib import nullcontext
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -37,13 +37,20 @@ from scalerl_tpu.utils.profiling import step_marker
 class ActorCarry(NamedTuple):
     """Per-env actor state threaded across rollout chunks.
 
-    Every leaf keeps the env/batch axis leading (the accumulators are
-    per-env vectors, not scalars), so the whole carry shards uniformly
-    over a ``dp`` mesh axis in the multi-device fused loop.
+    Every leaf has an env/batch axis (the accumulators are per-env vectors,
+    not scalars), so the whole carry shards over a ``dp`` mesh axis in the
+    multi-device fused loop.  It leads everywhere but in ``obs``, which is
+    stored env-axis-LAST (:func:`carry_env_axes`): a loop carry takes the
+    default major-to-minor layout, and on the TPU the minor axis lands in
+    the 128 lanes of a tile.  ``[B, 84, 84, 4]`` uint8 puts 4 channels
+    there and pads each frame batch 32 times (1.94 GB for 57.8 MB at 2048
+    envs); ``[84, 84, 4, B]`` is dense, and is the physical order the
+    convolution and the trajectory buffer want anyway
+    (docs/PERFORMANCE.md, "Reading a tiled layout").
     """
 
     env_state: Any
-    obs: jnp.ndarray  # [B, ...]
+    obs: jnp.ndarray  # [*obs_shape, B]
     last_action: jnp.ndarray  # [B]
     reward: jnp.ndarray  # [B]
     done: jnp.ndarray  # [B]
@@ -51,6 +58,37 @@ class ActorCarry(NamedTuple):
     episode_return: jnp.ndarray  # [B] running return accumulator
     return_sum: jnp.ndarray  # [B] per-env sum of completed-episode returns
     episode_count: jnp.ndarray  # [B] per-env completed-episode count
+
+
+def _store_obs(obs: jnp.ndarray) -> jnp.ndarray:
+    """``[B, *obs_shape]`` as the env hands it out -> ``[*obs_shape, B]``."""
+    return jnp.moveaxis(obs, 0, -1)
+
+
+def _load_obs(stored: jnp.ndarray, env_axis: int = 0) -> jnp.ndarray:
+    """Stored observations with the env axis moved (logically: the compiler
+    resolves it to a bitcast on the TPU) to where the consumer wants it."""
+    return jnp.moveaxis(stored, -1, env_axis)
+
+
+def carry_env_axes(carry: ActorCarry) -> ActorCarry:
+    """The position of the env axis in every leaf of ``carry`` (a pytree of
+    ints shaped like it): last in the stored observation, leading in the
+    rest.  The one place that says so; the mesh path shards by it."""
+    axes = jax.tree_util.tree_map(lambda x: 0, carry)
+    return axes._replace(obs=jnp.ndim(carry.obs) - 1)
+
+
+@lru_cache(maxsize=None)
+def _note_obs_storage(stored_shape: Tuple[int, ...], dtype: str) -> None:
+    """The storage is a layout and engages on every step, so it has no hit
+    rate: one zero-length program span a traced shape (the cache is the
+    "once"), so that a trace says which storage ran."""
+    with tracing.span(
+        "fused.obs_storage", kind="loop", stored_shape=list(stored_shape),
+        dtype=dtype, env_axis=len(stored_shape) - 1,
+    ):
+        pass
 
 
 def resolve_iter_mode(iter_mode: str = "auto") -> str:
@@ -146,13 +184,16 @@ class DeviceActorLearnerLoop:
         if self._sharded_fn is None:
             axis = self.axis_name
 
-            def leaf_spec(x):
-                if getattr(x, "ndim", 0) >= 1:
-                    return P(axis, *([None] * (x.ndim - 1)))
+            def leaf_spec(x, env_axis):
+                ndim = getattr(x, "ndim", 0)
+                if ndim >= 1:
+                    return P(*(axis if i == env_axis else None for i in range(ndim)))
                 return P()
 
             state_spec = jax.tree_util.tree_map(lambda x: P(), state)
-            carry_spec = jax.tree_util.tree_map(leaf_spec, carry)
+            carry_spec = jax.tree_util.tree_map(
+                leaf_spec, carry, carry_env_axes(carry)
+            )
 
             def inner(state, carry, key):
                 # distinct randomness per shard: fold the device's ring index
@@ -247,7 +288,7 @@ class DeviceActorLearnerLoop:
         env_state, obs = self.venv.reset(key)
         return ActorCarry(
             env_state=env_state,
-            obs=obs,
+            obs=_store_obs(obs),
             last_action=jnp.zeros(B, jnp.int32),
             reward=jnp.zeros(B, jnp.float32),
             done=jnp.ones(B, jnp.bool_),
@@ -262,11 +303,12 @@ class DeviceActorLearnerLoop:
         """Collect one [T+1, B] trajectory chunk; row T's logits are unused
         by the learner (behavior_logits[:-1]) and left zero."""
         core0 = carry.core_state
+        _note_obs_storage(tuple(carry.obs.shape), jnp.dtype(carry.obs.dtype).name)
 
         def step(c: ActorCarry, k):
             out, new_core = self.model.apply(
-                params, c.obs[None], c.last_action[None], c.reward[None],
-                c.done[None], c.core_state,
+                params, _load_obs(c.obs)[None], c.last_action[None],
+                c.reward[None], c.done[None], c.core_state,
             )
             logits = out.policy_logits[0]
             k_act, k_env = jax.random.split(k)
@@ -278,7 +320,7 @@ class DeviceActorLearnerLoop:
             ep_ret = c.episode_return + reward
             new_c = ActorCarry(
                 env_state=env_state,
-                obs=next_obs,
+                obs=_store_obs(next_obs),
                 last_action=action,
                 reward=reward,
                 done=done,
@@ -293,9 +335,12 @@ class DeviceActorLearnerLoop:
         carry, rows = jax.lax.scan(step, carry, keys)
         obs_rows, la_rows, rew_rows, done_rows, logit_rows = rows
 
-        # final row T from the post-scan carry (logits zero: unused)
+        # final row T from the post-scan carry (logits zero: unused); the
+        # rows were stacked as stored, [T, *obs_shape, B]
         traj = Trajectory(
-            obs=jnp.concatenate([obs_rows, carry.obs[None]], axis=0),
+            obs=_load_obs(
+                jnp.concatenate([obs_rows, carry.obs[None]], axis=0), env_axis=1
+            ),
             action=jnp.concatenate([la_rows, carry.last_action[None]], axis=0),
             reward=jnp.concatenate([rew_rows, carry.reward[None]], axis=0),
             done=jnp.concatenate([done_rows, carry.done[None]], axis=0),

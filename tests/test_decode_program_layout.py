@@ -14,7 +14,9 @@ The topology is described inside a module-scoped fixture, never at import;
 the benchmark's ``tests/benchmark/test_benchmark_real_shape_compiles.py``
 does the same in another xdist worker, which the tier-1 command allows
 with ``ALLOW_MULTIPLE_LIBTPU_LOAD=1``.  Where no topology can be described
-the file skips.
+the file skips.  The fused classic loop's program is held to the same
+rule at the end of the file (ISSUE 31): this is the one tier-1 file that
+loads the TPU's compiler outside ``tests/benchmark``.
 """
 
 import functools
@@ -193,3 +195,46 @@ def test_other_programs_leave_the_pools_in_place(engine, one_chip, program):
         extra = [(LANES, k), (LANES,), (LANES, k + 1), (LANES, k + 1), (LANES, M), (LANES,), "key"]
     text = _compiled_text(engine, one_chip, fn, params=program != "fork", extra=extra)
     _assert_pools_read_in_place(text, engine)
+
+
+# -- the fused classic loop (ISSUE 31) ---------------------------------------
+
+
+@pytest.mark.parametrize("num_envs", [512, 2048])
+def test_fused_loop_stores_frames_lane_dense(one_chip, num_envs):
+    """The ``impala_fused`` cell's program (unroll 20, 5 iterations a
+    dispatch, bf16 torso) holds no lane-padded uint8 frame array and
+    relayouts no frame batch inside a loop.  With ``obs`` carried as
+    ``[B, 84, 84, 4]`` the renderer wrote ``u8[B,84,84,4]{3,2,1,0:T(8,128)
+    (4,1)}``, 33.5 times padded, and a ``copy`` read it back on every
+    environment step: 65% of the cell's device time (PERF.md, PR 31)."""
+    from scalerl_tpu.agents.impala import ImpalaAgent
+    from scalerl_tpu.config import ImpalaArguments
+    from scalerl_tpu.envs import make_jax_vec_env
+    from scalerl_tpu.runtime.device_loop import DeviceActorLearnerLoop
+    from scalerl_tpu.utils import tiled_layout
+
+    T = 20
+    args = ImpalaArguments(
+        env_id="SyntheticPixel-v0", use_lstm=False, hidden_size=512,
+        rollout_length=T, batch_size=num_envs, max_timesteps=0,
+        compute_dtype="bfloat16", logger_backend="none",
+    )
+    venv = make_jax_vec_env(args.env_id, num_envs=num_envs)
+    agent = ImpalaAgent(
+        args, obs_shape=venv.observation_shape, num_actions=venv.num_actions,
+        obs_dtype=venv.env.observation_dtype,
+    )
+    loop = DeviceActorLearnerLoop(
+        agent.model, venv, agent.make_learn_fn(), T, iters_per_call=5,
+        iter_mode="scan",  # what "auto" resolves to on the chip
+    )
+    key = jax.random.PRNGKey(0)
+    carry = jax.eval_shape(loop.init_carry, key)
+    text = (
+        loop._train_many.lower(*_described((agent.state, carry, key), one_chip))
+        .compile().as_text()
+    )
+    frame_batch = num_envs * int(np.prod(venv.observation_shape))
+    faults = tiled_layout.lane_dense_faults(text, "u8", frame_batch, lane_dim=num_envs)
+    assert not faults, faults

@@ -159,6 +159,44 @@ def test_fused_loop_one_chunk_on_tpu():
     assert np.isfinite(float(m["total_loss"]))
 
 
+def test_fused_program_stores_frames_lane_dense_at_the_cells_geometry():
+    """The ``impala_fused`` cell's program as the chip's own compiler
+    builds it (2048 envs, unroll 20, 5 iterations a dispatch): no uint8
+    frame array with anything but the env axis in the lanes, and no frame
+    batch relaid inside a loop.  ``ActorCarry.obs`` carried as
+    ``[B, 84, 84, 4]`` was stored ``{3,2,1,0:T(8,128)(4,1)}``, 1.94 GB a
+    57.8 MB batch, written and copied back on every environment step
+    (PERF.md, PR 31); a later change to the carry must not bring that back
+    unseen.  Compiled only: nothing of this size runs here."""
+    from scalerl_tpu.agents.impala import ImpalaAgent
+    from scalerl_tpu.config import ImpalaArguments
+    from scalerl_tpu.envs import make_jax_vec_env
+    from scalerl_tpu.runtime.device_loop import DeviceActorLearnerLoop
+    from scalerl_tpu.utils import tiled_layout
+
+    B, T = 2048, 20
+    args = ImpalaArguments(
+        env_id="SyntheticPixel-v0", use_lstm=False, hidden_size=512,
+        rollout_length=T, batch_size=B, max_timesteps=0,
+        compute_dtype="bfloat16", logger_backend="none",
+    )
+    venv = make_jax_vec_env(args.env_id, num_envs=B)
+    agent = ImpalaAgent(
+        args, obs_shape=venv.observation_shape, num_actions=venv.num_actions,
+        obs_dtype=venv.env.observation_dtype,
+    )
+    loop = DeviceActorLearnerLoop(
+        agent.model, venv, agent.make_learn_fn(), T, iters_per_call=5
+    )
+    assert loop.iter_mode == "scan"
+    key = jax.random.PRNGKey(0)
+    carry = jax.eval_shape(loop.init_carry, key)
+    text = loop._train_many.lower(agent.state, carry, key).compile().as_text()
+    frame_batch = B * int(np.prod(venv.observation_shape))
+    faults = tiled_layout.lane_dense_faults(text, "u8", frame_batch, lane_dim=B)
+    assert not faults, faults
+
+
 def test_breakout_fused_chunk_on_tpu():
     """The flagship Breakout game + fused IMPALA iteration compiles and
     executes on the chip (the wall-clock-to-score path of
