@@ -32,6 +32,7 @@ token is one action —
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -76,14 +77,54 @@ def _forward_with_balance(model, params, tokens, real_tokens, **call):
     return out, router_balance(sown["intermediates"], real_tokens)
 
 
-def _add_router_aux(total, metrics, balance, coef: float):
+def _add_router_aux(total, metrics, balance, coef: float, spec):
     """``coef x E x sum_e f_e P_e`` joins the total; the metric dict
-    gains the term and the largest expert's load."""
+    gains the term, the largest expert's load, and the real tokens' picks
+    of the routed experts split into those whose banks are held here and
+    those another rank holds (sums over the routed layers)."""
     if balance is None:
         return total
     metrics["moe_aux_loss"] = balance.aux_loss
     metrics["moe_max_load"] = balance.max_load
+    held = spec.experts_held or spec.num_experts
+    routed = balance.counts[:, : spec.num_experts]
+    here = routed[:, spec.first_expert : spec.first_expert + held]
+    metrics["moe_held_picks"] = jnp.sum(here).astype(jnp.float32)
+    metrics["moe_absent_picks"] = (jnp.sum(routed) - jnp.sum(here)).astype(
+        jnp.float32
+    )
     return total + coef * balance.aux_loss
+
+
+def _add_mtp(total, metrics, mtp_logits, batch, w_full, coef: float):
+    """The multi-token-prediction term of a packed batch: position ``i``'s
+    module output predicts token ``i + 2``.  A position counts when tokens
+    ``i + 1`` and ``i + 2`` lie in ``i``'s own segment and token ``i + 2``
+    is a response token the loss mask counts; ``L_mtp`` is the mean of
+    ``-log p_i(t_{i+2})`` over those (weighted like every loss term), and
+    ``coef x L_mtp`` joins the total.  ``mtp_top1_match`` is the share of
+    counted positions whose largest logit is ``t_{i+2}``."""
+    seg = batch["segment_ids"]
+    S = seg.shape[1]
+
+    def ahead(x, n):  # x at offset i + n, in place (the row keeps its length)
+        return jnp.roll(x, -n, axis=1)
+
+    tgt = ahead(batch["tokens"], 2)
+    own = (
+        (seg > 0) & (ahead(seg, 1) == seg) & (ahead(seg, 2) == seg)
+        & (jnp.arange(S)[None, :] < S - 2)
+    )
+    mask = ahead(batch["mask"], 2) * own
+    w_mask = ahead(w_full, 2) * own
+    logp = jax.nn.log_softmax(mtp_logits, axis=-1)  # [N, S, V]
+    nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+    mtp_loss = masked_mean(nll, w_mask)
+    metrics["mtp_loss"] = mtp_loss
+    metrics["mtp_top1_match"] = masked_mean(
+        (jnp.argmax(mtp_logits, axis=-1) == tgt).astype(jnp.float32), mask
+    )
+    return total + coef * mtp_loss
 
 
 def token_ppo_loss(
@@ -187,7 +228,7 @@ def token_ppo_loss(
         kl_term = kl_cost * masked_mean(kl, w_mask)
         total = total + kl_term
         metrics["kl_ref"] = masked_mean(kl, mask)
-    total = _add_router_aux(total, metrics, balance, router_aux_coef)
+    total = _add_router_aux(total, metrics, balance, router_aux_coef, model.block)
     metrics["total_loss"] = total
     metrics = {
         k: v if k == "total_loss" else jax.lax.stop_gradient(v)
@@ -207,6 +248,7 @@ def token_ppo_packed_loss(
     kl_cost: float,
     adv_norm: bool,
     router_aux_coef: float = 0.0,
+    mtp_coef: float = 0.0,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """PPO-clip over PACKED learner rows — the pad-free twin of
     :func:`token_ppo_loss`.
@@ -224,6 +266,11 @@ def token_ppo_packed_loss(
     contract the tests pin at 1e-5).  An optional ``is_weight [N]`` (PER
     weights, per ROW — the replay unit) scales the loss mask exactly like
     the padded path's per-sequence weight.
+
+    A model that carries a multi-token-prediction module (``mtp_layers``)
+    runs it in this forward, and ``mtp_coef x L_mtp`` joins the total
+    (:func:`_add_mtp`); its gradient reaches the trunk, the embedding and
+    the head.  The padded loss has no such term.
     """
     tokens = batch["tokens"]
     seg = batch["segment_ids"]
@@ -233,8 +280,10 @@ def token_ppo_packed_loss(
         batch["mask"] if seq_w is None else batch["mask"] * seq_w[:, None]
     )
 
+    mtp = dict(mtp=True) if model.mtp_layers else {}
     out, balance = _forward_with_balance(
-        model, params, tokens, seg > 0, positions=positions, segment_ids=seg
+        model, params, tokens, seg > 0, positions=positions, segment_ids=seg,
+        **mtp,
     )
     # output at row offset t-1 predicts the token at offset t
     pred_logits = out.policy_logits[:, :-1]  # [N, S-1, V]
@@ -304,7 +353,9 @@ def token_ppo_packed_loss(
         kl_term = kl_cost * masked_mean(kl, w_mask)
         total = total + kl_term
         metrics["kl_ref"] = masked_mean(kl, mask)
-    total = _add_router_aux(total, metrics, balance, router_aux_coef)
+    total = _add_router_aux(total, metrics, balance, router_aux_coef, model.block)
+    if model.mtp_layers:
+        total = _add_mtp(total, metrics, out.mtp_logits, batch, w_full, mtp_coef)
     metrics["total_loss"] = total
     metrics = {
         k: v if k == "total_loss" else jax.lax.stop_gradient(v)
@@ -327,11 +378,12 @@ def make_token_ppo_learn_fn(
     """
 
     def learn(state: TokenPPOTrainState, batch: Dict[str, jnp.ndarray]):
-        loss_fn = (
-            token_ppo_packed_loss
-            if "segment_ids" in batch
-            else token_ppo_loss
-        )
+        loss_fn = token_ppo_loss
+        if "segment_ids" in batch:
+            loss_fn = functools.partial(
+                token_ppo_packed_loss,
+                mtp_coef=getattr(args, "mtp_loss_coef", 0.0),
+            )
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(

@@ -758,6 +758,16 @@ class GenRLArguments(RLArguments):
     # computed experts this program holds ``moe_experts_held`` (0: all)
     # from ``moe_first_expert`` on, one rank's share of an expert-parallel
     # deployment: picks of the others add nothing here.
+    # "joyai" = a stack of more than one kind of layer: ``dense_layers``
+    # leading plain layers of one latent (MLA) attention and a dense SwiGLU
+    # FFN of width ``ffn_hidden``, then plain layers of the same attention
+    # and a router over ``moe_experts`` experts (``moe_scoring``: each
+    # output's sigmoid or a softmax; the picks' scores renormalised under
+    # ``moe_norm_topk_prob`` and scaled by ``moe_routed_scaling``) of which
+    # ``moe_experts_held`` are held, beside ``moe_shared_experts``
+    # always-on ones; and ``mtp_layers`` (0 | 1) multi-token-prediction
+    # modules, which the packed learner runs and trains with weight
+    # ``mtp_loss_coef`` and generation never builds.
     block_family: str = "gpt2"
     head_dim: int = 0
     rms_norm_eps: float = 1e-5
@@ -774,6 +784,11 @@ class GenRLArguments(RLArguments):
     moe_routed_scaling: float = 1.0
     moe_experts_held: int = 0
     moe_first_expert: int = 0
+    dense_layers: int = 0
+    moe_shared_experts: int = 0
+    moe_scoring: str = "softmax"
+    mtp_layers: int = 0
+    mtp_loss_coef: float = 0.1
     # weight of the router's load-balancing loss in the learner's total
     # (agents/token_ppo.py); only a routed family has the term
     router_aux_loss_coef: float = 0.01
@@ -897,10 +912,31 @@ class GenRLArguments(RLArguments):
                 f"temperature must be >= 0 (0 = greedy), got "
                 f"{self.temperature}"
             )
-        if self.block_family not in ("gpt2", "olmoe", "longcat"):
+        if self.block_family not in ("gpt2", "olmoe", "longcat", "joyai"):
             raise ValueError(
-                "block_family must be gpt2 | olmoe | longcat, got "
+                "block_family must be gpt2 | olmoe | longcat | joyai, got "
                 f"{self.block_family!r}"
+            )
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_scoring must be softmax | sigmoid, got {self.moe_scoring!r}"
+            )
+        if self.mtp_layers not in (0, 1) or self.mtp_loss_coef < 0:
+            raise ValueError(
+                "mtp_layers must be 0 | 1 and mtp_loss_coef >= 0, got "
+                f"{self.mtp_layers}/{self.mtp_loss_coef}"
+            )
+        if self.mtp_layers and not self.learner_packing:
+            raise ValueError(
+                "mtp_layers needs learner_packing: the multi-token-prediction "
+                "term lives in the packed loss"
+            )
+        if self.block_family != "joyai" and (
+            self.dense_layers or self.moe_shared_experts or self.mtp_layers
+        ):
+            raise ValueError(
+                "dense_layers, moe_shared_experts and mtp_layers are the joyai "
+                f"family's, got them with {self.block_family!r}"
             )
         if self.head_dim < 0 or self.router_aux_loss_coef < 0:
             raise ValueError(

@@ -406,7 +406,7 @@ class ContinuousEngine(ParamSnapshotPlane):
             spec.first_expert + (spec.experts_held or spec.num_experts),
         )
         self._expert_tokens = np.zeros(
-            (model.num_layers, spec.num_experts + spec.zero_experts), np.int64
+            (model.routed_layers, spec.num_experts + spec.zero_experts), np.int64
         )
         self._expert_hits = 0  # held experts that received a token, summed
         self._expert_substeps = 0  # over this many (substep, layer) pairs

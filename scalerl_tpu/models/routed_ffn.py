@@ -1,11 +1,13 @@
 """Dropless token-choice routed SwiGLU experts for the token model.
 
-The FFN of the ``olmoe`` block family (``models/transformer.py``
+The FFN of the routed block families (``models/transformer.py``
 :class:`BlockSpec` ``ffn="experts"``): a linear router scores every token
-against ``E`` experts, a float32 softmax over all ``E`` turns the scores
-into probabilities, the ``k`` largest are kept (with their probabilities
-as they are, or renormalised under ``norm_topk_prob``), and the token's
-output is the probability-weighted sum of those ``k`` experts' gated MLPs
+against ``E`` experts, a float32 softmax over all ``E`` (or, under
+``scoring="sigmoid"``, each output's own sigmoid) turns the scores into
+probabilities, the ``k`` largest are kept (with their probabilities as
+they are, or renormalised over the ``k`` under ``norm_topk_prob``), and
+the token's output is the probability-weighted sum of those ``k`` experts'
+gated MLPs
 ``(silu(h Wg) * (h Wu)) Wd``.  Exact at every token count: no capacity,
 no dropped token, no ``[tokens, experts, capacity]`` tensor
 (``models/moe.py`` is the capacity-dropping top-1 Switch layer of the
@@ -26,18 +28,27 @@ have opposite bounds:
   XLA:TPU compiles to a grouped matmul) do ``k / E`` of the dense form's
   arithmetic.
 
-**A share of a wider layer** (the ``longcat`` family, ``held`` <
-``num_experts``): the router keeps its published width, ``num_experts``
+**A share of a wider layer** (the ``longcat`` and ``joyai`` families,
+``held`` < ``num_experts``): the router keeps its published width, ``num_experts``
 computed experts and ``zero_experts`` identity ones behind them, and this
 module holds the banks of experts ``first_expert .. first_expert + held``
 alone, as one rank of an expert-parallel deployment does.  A pick inside
 the held range is computed here, an identity pick adds ``w h`` here (it
 costs nothing, so it is computed where the token lives), and a pick of an
 absent expert contributes nothing: the part of the sum another chip owns.
-Both forms serve; the sorted form's group sizes count held picks only.
+Both forms serve, and the sorted form's group sizes count held picks only.
+But ``lax.ragged_dot`` on a v5e does not skip the sorted rows past its last
+group (4,096 tokens x 8 picks with one in thirty-two held took two thirds
+of what all held takes; PERF.md, PR 32), so on a share the sorted form pays
+for all ``N x k`` rows; where ``held <= k`` the streamed form's ``N x held``
+rows are never more, its time does not follow the router, and it runs at
+every token count.
 The picks are the ``k`` largest of ``p + b`` (``b`` the ``router_bias``
-parameter, which only chooses) and weigh ``routed_scaling x p``.  OLMoE
-is the case ``zero_experts = 0``, ``held = num_experts``, no bias,
+parameter, which only chooses) and weigh ``routed_scaling x p``; a
+renormalising sum runs over all ``k`` picks, wherever their experts live,
+so that the shares' parts add up.  An always-on shared expert is not
+here: it is a dense SwiGLU beside this module (``_Block``).  OLMoE is the
+case ``zero_experts = 0``, ``held = num_experts``, no bias,
 scaling 1, and traces to the program it always did.
 
 Per call the module also ``sow``s the router's probabilities and picks
@@ -108,7 +119,14 @@ def _streamed(x, top_p, top_i, w_gate, w_up, w_down, expert_axis=False):
 def _sorted(x, top_p, top_i, w_gate, w_up, w_down, held_rows=None):
     """``held_rows [N * k]`` (a share of a wider layer): which assignments
     fell on a bank held here; the others carry the index ``E``, sort
-    behind every group, count in no group's size and add nothing."""
+    behind every group, count in no group's size and add nothing.  What a
+    grouped matmul leaves in a row of no group is not defined, and so is
+    what its transposes leave there: the gathered tokens, the two products
+    that enter the gate, the gated activations and the outputs of such rows
+    are selected to zero.  Both sides of the gate: going back, a zero cotangent times the
+    gate's derivative at a non-finite leftover is a NaN, which the
+    weights' transposes then sum into a bank's gradient and into the
+    absent picks' scores (one seed in five on the chip; PERF.md, PR 32)."""
     N, k = top_i.shape
     E = w_gate.shape[0]
     f32 = jnp.float32
@@ -117,13 +135,15 @@ def _sorted(x, top_p, top_i, w_gate, w_up, w_down, held_rows=None):
     # an index of ``E`` (not held here) is out of range and dropped
     sizes = jnp.zeros((E,), jnp.int32).at[expert].add(1)
     xs = x[order // k]
+    if held_rows is not None:
+        in_a_group = held_rows[order][:, None]
+        xs = jnp.where(in_a_group, xs, 0)  # nothing of them goes back to ``x``
     g = lax.ragged_dot(xs, w_gate, sizes, preferred_element_type=f32)
     u = lax.ragged_dot(xs, w_up, sizes, preferred_element_type=f32)
+    if held_rows is not None:
+        g, u = jnp.where(in_a_group, g, 0.0), jnp.where(in_a_group, u, 0.0)
     a = jax.nn.silu(g) * u * top_p.reshape(N * k)[order][:, None]
     if held_rows is not None:
-        # rows past the last group belong to no expert here: what a
-        # grouped matmul leaves in them is not defined
-        in_a_group = held_rows[order][:, None]
         a = jnp.where(in_a_group, a, 0.0)
     ys = lax.ragged_dot(
         a.astype(x.dtype), w_down, sizes, preferred_element_type=f32
@@ -151,6 +171,7 @@ class RoutedExperts(nn.Module):
     first_expert: int = 0
     choice_bias: bool = False
     routed_scaling: float = 1.0
+    scoring: str = "softmax"  # softmax | sigmoid
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -170,7 +191,11 @@ class RoutedExperts(nn.Module):
         logits = jnp.dot(
             x, router.astype(self.dtype), preferred_element_type=jnp.float32
         )
-        probs = jax.nn.softmax(logits, axis=-1)  # float32, over all R
+        # float32, over all R: a softmax, or each output's own sigmoid
+        if self.scoring == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
         if self.choice_bias:
             # the bias chooses and does not weigh
             bias = self.param(
@@ -181,7 +206,8 @@ class RoutedExperts(nn.Module):
         else:
             top_p, top_i = lax.top_k(probs, k)
         if self.norm_topk_prob:
-            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            # over all k picks, wherever their experts live
+            top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
         if self.routed_scaling != 1.0:
             top_p = top_p * self.routed_scaling
         self.sow("intermediates", "router_probs", probs.reshape(B, T, R))
@@ -193,7 +219,10 @@ class RoutedExperts(nn.Module):
             return y.reshape(B, T, d).astype(self.dtype)
         here = (top_i >= first) & (top_i < first + held)
         local = jnp.where(here, top_i - first, held)  # ``held``: no bank here
-        if streamed:
+        # the sorted form multiplies all ``N x k`` sorted rows however few
+        # are held (see the module docstring), the streamed form ``N x
+        # held``: never more where ``held <= k``, whatever the router does
+        if streamed or held <= k:
             y = _streamed(x, top_p, local, *banks, expert_axis=True)
         else:
             y = _sorted(x, top_p, local, *banks, held_rows=here.reshape(-1))
@@ -216,20 +245,27 @@ def router_balance(
     """What the router did with the tokens ``token_mask [B, T]`` names.
 
     ``intermediates`` is the collection a forward through routed blocks
-    sowed.  ``f_e`` is the share of the masked tokens' ``k`` assignments,
+    sowed (the scores are a sigmoid router's where it is one: ``P_e`` is
+    then a mean score).  ``f_e`` is the share of the masked tokens' ``k`` assignments,
     all layers together, that went to expert ``e``; ``P_e`` the mean
     router probability of ``e`` over the same tokens and layers.  The loss
     is ``E x sum_e f_e P_e``: 1 when both are uniform.  The gradient
     reaches the router through ``P_e`` alone (the counts are integers).
     """
-    layers = sorted(
-        (name for name in intermediates if name.startswith("block_")),
-        key=lambda name: int(name.split("_")[1]),
-    )
+    # the stack's routed layers in order (a dense layer sows nothing),
+    # then the multi-token-prediction module's layer where a forward ran it
+    layers = [
+        intermediates[name]["experts"]
+        for name in sorted(
+            (name for name in intermediates if name.startswith("block_")),
+            key=lambda name: int(name.split("_")[1]),
+        )
+    ]
+    if "mtp" in intermediates:
+        layers.append(intermediates["mtp"]["block"]["experts"])
     mask = token_mask.astype(jnp.float32)
     counts, prob_sums = [], []
-    for name in layers:
-        sown = intermediates[name]["experts"]
+    for sown in layers:
         probs, ids = sown["router_probs"][0], sown["expert_ids"][0]
         E = probs.shape[-1]
         picked = jnp.sum(jax.nn.one_hot(ids, E, dtype=jnp.float32), axis=2)

@@ -16,6 +16,7 @@ axis is sharded across the ``sp`` mesh axis — callers pass ``positions``
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
@@ -50,17 +51,24 @@ class BlockSpec:
     K enters a cache normed and rotated, so cached, paged and packed
     paths read it as it is), and where the MLP sits (the routed experts).
 
-    Two further kinds change what a layer *is* and so have modules of
-    their own beside :class:`_Block`.  ``attention="mla"`` is multi-head
-    latent attention (:class:`_LatentAttention`): low-rank q and kv
-    projections, a rotated key part all heads share, and a cache of one
-    ``kv_lora_rank + qk_rope_head_dim`` row a token.  ``layer="scmoe"``
+    The attention kind and the layer kind are apart.  ``attention="mla"``
+    is multi-head latent attention (:class:`_LatentAttention`): low-rank
+    q and kv projections, a rotated key part all heads share, and a cache
+    of one ``kv_lora_rank + qk_rope_head_dim`` row a token; it runs in a
+    plain layer (:class:`_Block`: one attention, one FFN) or in the
+    double one.  ``mla_scale`` multiplies q by ``sqrt(d / q_lora_rank)``
+    and the normed latent by ``sqrt(d / kv_lora_rank)`` (LongCat's two
+    factors; a model without those keys has neither).  ``layer="scmoe"``
     is the shortcut-connected double layer (:class:`_ShortcutBlock`): two
     attentions and two dense SwiGLU FFNs of width ``ffn_hidden`` in a
     row, with one routed-experts branch leaving after the first attention
-    and joining after the second FFN.  The router there scores
-    ``num_experts + zero_experts`` outputs, of which this program holds
-    the banks of ``experts_held`` (``models/routed_ffn.py``).
+    and joining after the second FFN.  A router scores ``num_experts +
+    zero_experts`` outputs by ``scoring``, of which this program holds the
+    banks of ``experts_held`` (``models/routed_ffn.py``);
+    ``shared_experts`` always-on experts of the routed width sit beside
+    them as one dense SwiGLU.  ``ffn="swiglu"`` is a dense SwiGLU of width
+    ``ffn_hidden`` in a plain layer: a stack's leading dense layers
+    (:func:`layer_specs`).
     """
 
     norm: str = "layernorm"  # layernorm | rmsnorm
@@ -69,7 +77,7 @@ class BlockSpec:
     rope_theta: float = 10000.0
     qk_norm: str = "none"  # none | rmsnorm (over the projection's whole width)
     head_dim: Optional[int] = None  # None: d_model // num_heads
-    ffn: str = "mlp"  # mlp (GELU, mlp_ratio x d_model) | experts (routed SwiGLU)
+    ffn: str = "mlp"  # mlp (GELU, mlp_ratio x d_model) | experts (routed SwiGLU) | swiglu
     num_experts: int = 0
     experts_per_token: int = 0
     expert_width: int = 0
@@ -88,6 +96,9 @@ class BlockSpec:
     first_expert: int = 0
     router_bias: bool = False
     routed_scaling: float = 1.0
+    mla_scale: bool = False
+    scoring: str = "softmax"  # softmax | sigmoid (the router's, over all outputs)
+    shared_experts: int = 0
 
 
 def block_spec(
@@ -110,9 +121,14 @@ def block_spec(
     experts_held: int = 0,
     first_expert: int = 0,
     routed_scaling: float = 1.0,
+    scoring: str = "softmax",
+    shared_experts: int = 0,
 ) -> BlockSpec:
     """The block a named family stacks; the sizes only the family reads
-    are ignored by the others (``gpt2`` keeps its own epsilon)."""
+    are ignored by the others (``gpt2`` keeps its own epsilon).  For a
+    family whose stack has more than one kind of layer this is the kind
+    that repeats (``joyai``: the routed layer) and :func:`layer_specs`
+    gives the stack."""
     if family == "gpt2":
         return BlockSpec(head_dim=head_dim)
     if family == "olmoe":
@@ -166,16 +182,78 @@ def block_spec(
             rope_pairing="interleaved", layer="scmoe", ffn_hidden=ffn_hidden,
             zero_experts=zero_experts, experts_held=held,
             first_expert=first_expert, router_bias=True,
-            routed_scaling=routed_scaling,
+            routed_scaling=routed_scaling, mla_scale=True,
+        )
+    if family == "joyai":
+        held = experts_held or num_experts
+        sizes = (
+            q_lora_rank, kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
+            v_head_dim, expert_width,
+        )
+        if min(sizes) < 1 or qk_rope_head_dim % 2:
+            raise ValueError(
+                "the joyai block needs its five latent-attention sizes (an "
+                f"even rotary part) and an expert width, got {sizes}"
+            )
+        if not (
+            1 <= experts_per_token <= num_experts
+            and 0 <= first_expert
+            and 1 <= held
+            and first_expert + held <= num_experts
+            and shared_experts >= 0
+            and scoring in ("softmax", "sigmoid")
+        ):
+            raise ValueError(
+                "the joyai router picks experts_per_token of num_experts by "
+                "a softmax or sigmoid score and holds experts first_expert "
+                ".. first_expert + experts_held beside shared_experts "
+                f"always-on ones, got {experts_per_token}/{num_experts}/"
+                f"{scoring}/{first_expert}/{held}/{shared_experts}"
+            )
+        return BlockSpec(
+            norm="rmsnorm", norm_eps=norm_eps, positions="rope",
+            rope_theta=rope_theta, ffn="experts", num_experts=num_experts,
+            experts_per_token=experts_per_token, expert_width=expert_width,
+            norm_topk_prob=norm_topk_prob, attention="mla",
+            q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+            qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rope_pairing="interleaved", ffn_hidden=ffn_hidden,
+            experts_held=held, first_expert=first_expert, router_bias=True,
+            routed_scaling=routed_scaling, scoring=scoring,
+            shared_experts=shared_experts,
         )
     raise ValueError(
-        f"block family must be gpt2 | olmoe | longcat, got {family!r}"
+        f"block family must be gpt2 | olmoe | longcat | joyai, got {family!r}"
     )
+
+
+def layer_specs(
+    spec: BlockSpec, num_layers: int, dense_layers: int = 0
+) -> Tuple[BlockSpec, ...]:
+    """The stack as a per-layer list: ``dense_layers`` leading layers of
+    ``spec``'s attention around a dense SwiGLU of width ``ffn_hidden``,
+    then ``spec`` itself to ``num_layers``."""
+    if not 0 <= dense_layers <= num_layers or (dense_layers and spec.ffn_hidden < 1):
+        raise ValueError(
+            "dense_layers must lie in 0..num_layers and needs ffn_hidden, got "
+            f"{dense_layers}/{num_layers}/{spec.ffn_hidden}"
+        )
+    dense = dataclasses.replace(
+        spec, ffn="swiglu", num_experts=0, experts_per_token=0, expert_width=0,
+        experts_held=0, first_expert=0, router_bias=False, routed_scaling=1.0,
+        norm_topk_prob=False, scoring="softmax", shared_experts=0,
+    )
+    return (dense,) * dense_layers + (spec,) * (num_layers - dense_layers)
 
 
 class TransformerOutput(NamedTuple):
     policy_logits: jnp.ndarray  # [B, T, num_actions]
     baseline: jnp.ndarray  # [B, T]
+    # [B, T, num_actions] of the multi-token-prediction module: position
+    # i's distribution over token i + 2; only a forward called with
+    # ``mtp=True`` on a model that carries a module has it
+    mtp_logits: Optional[jnp.ndarray] = None
 
 
 class PagedKVCache(NamedTuple):
@@ -224,8 +302,8 @@ class LatentKVCache(NamedTuple):
     ``[c | rotated k_pe | zeros]`` rows (``W`` =
     ``latent_pool_width(kv_lora_rank + qk_rope_head_dim)``, 640 for the
     published 576), which every head shares and which holds the values
-    too: no V pool.  A ``scmoe`` layer has two attentions, so layer ``i``
-    owns ``rows[2i]`` and ``rows[2i + 1]``.  Pages, tables, the null page
+    too: no V pool.  The pools lie in layer order, one a plain layer and
+    two a ``scmoe`` layer (its two attentions).  Pages, tables, the null page
     and every rule of :class:`PagedKVCache` are the same: sharing, forks
     and the prefix cache are page-index facts and do not see the kind."""
 
@@ -376,6 +454,18 @@ def _scatter_rows(pool: jnp.ndarray, flat_idx: jnp.ndarray, rows: jnp.ndarray):
     )
 
 
+def _routed_experts(spec: BlockSpec, dt) -> RoutedExperts:
+    """The routed FFN a spec describes, under the name every layer kind
+    gives it."""
+    return RoutedExperts(
+        spec.num_experts, spec.experts_per_token, spec.expert_width,
+        spec.norm_topk_prob, zero_experts=spec.zero_experts,
+        held=spec.experts_held, first_expert=spec.first_expert,
+        choice_bias=spec.router_bias, routed_scaling=spec.routed_scaling,
+        scoring=spec.scoring, name="experts", **dt,
+    )
+
+
 class _Block(nn.Module):
     d_model: int
     num_heads: int
@@ -436,71 +526,91 @@ class _Block(nn.Module):
         width = self.num_heads * head_dim
         dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         h = _norm(spec, self.dtype, "attn_norm" if rms else None)(x)
-        qkv = nn.Dense(3 * width, use_bias=False, name="qkv", **dt)(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        if spec.qk_norm == "rmsnorm":
-            # over all heads' features at once, before the head split
-            q = RMSNorm(spec.norm_eps, dtype=self.dtype, name="q_norm")(q)
-            k = RMSNorm(spec.norm_eps, dtype=self.dtype, name="k_norm")(k)
-        shape = (B, T, self.num_heads, head_dim)
-        q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
-        if self.rotary is not None:
-            # before every cache write: K is stored normed and rotated
-            q, k = self.rotary(q), self.rotary(k)
         new_cache = None
-        if paged_cache is not None:
-            kp, vp = paged_cache
-            flat_idx = (page_ids * kp.shape[1] + page_offsets).reshape(B * T)
-            kp = _scatter_rows(kp, flat_idx, k)
-            vp = _scatter_rows(vp, flat_idx, v)
-            if page_table is not None and prefix_starts is not None:
-                # shared-table tail prefill: gather the whole context
-                # (cached prefix pages + the tail just scattered above)
-                # through the table, attend causal-from-start — the
-                # compute twin of the decode seam at T > 1, kernel-free.
-                # The speculative verify pass (genrl/continuous.py) rides
-                # this exact path with T = draft bucket + 1: slot j is
-                # position prefix_starts + j, the pos <= qpos mask keeps
-                # rejected slots' K/V (garbage past the cursor) out of
-                # every query, so draft rollback never touches the device.
-                # The heads are split out of the gathered rows: reshaping
-                # the pool itself would bring its relayout copy back
-                kg = gather_pages(kp, page_table, self.num_heads)
-                vg = gather_pages(vp, page_table, self.num_heads)
-                pos = jnp.arange(kg.shape[1])[None, None, :]
-                qpos = (
-                    prefix_starts[:, None] + jnp.arange(T)[None, :]
-                )[:, :, None]
-                out = _masked_attention(
-                    q, kg, vg, pos <= qpos, self.dtype
-                )
-            elif page_table is not None:
-                paged_attn = self.paged_attn_fn or paged_attention_reference
-                out = paged_attn(q, kp, vp, page_table, attn_lengths)
-                out = out.astype(self.dtype)
-            else:
-                out = _masked_attention(q, k, v, attn_mask, self.dtype)
-            new_cache = (kp, vp)
-        elif segment_ids is not None and self.segment_attn_fn is not None:
-            # packed-row training attention through the flash seam: the
-            # kernel enforces the segment-blocked causal rule and skips
-            # fully-masked (cross-segment / pad) blocks entirely
-            out = self.segment_attn_fn(q, k, v, segment_ids)
-            out = out.astype(self.dtype)
-        elif attn_mask is not None:
-            out = _masked_attention(q, k, v, attn_mask, self.dtype)
+        if spec.attention == "mla":
+            # one latent attention: ``paged_cache`` is its one pool
+            out, new_cache = _LatentAttention(
+                self.d_model, self.num_heads, spec, self.attn_fn,
+                paged_attn_fn=self.paged_attn_fn,
+                segment_attn_fn=self.segment_attn_fn, rotary=self.rotary,
+                name="attn", **dt,
+            )(
+                h, attn_mask=attn_mask, pool=paged_cache, page_ids=page_ids,
+                page_offsets=page_offsets, page_table=page_table,
+                attn_lengths=attn_lengths, prefix_starts=prefix_starts,
+                segment_ids=segment_ids,
+            )
         else:
-            out = self.attn_fn(q, k, v)
-        out = nn.Dense(self.d_model, use_bias=False, name="proj", **dt)(
-            out.reshape(B, T, width)
-        )
+            qkv = nn.Dense(3 * width, use_bias=False, name="qkv", **dt)(h)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            if spec.qk_norm == "rmsnorm":
+                # over all heads' features at once, before the head split
+                q = RMSNorm(spec.norm_eps, dtype=self.dtype, name="q_norm")(q)
+                k = RMSNorm(spec.norm_eps, dtype=self.dtype, name="k_norm")(k)
+            shape = (B, T, self.num_heads, head_dim)
+            q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
+            if self.rotary is not None:
+                # before every cache write: K is stored normed and rotated
+                q, k = self.rotary(q), self.rotary(k)
+            if paged_cache is not None:
+                kp, vp = paged_cache
+                flat_idx = (page_ids * kp.shape[1] + page_offsets).reshape(B * T)
+                kp = _scatter_rows(kp, flat_idx, k)
+                vp = _scatter_rows(vp, flat_idx, v)
+                if page_table is not None and prefix_starts is not None:
+                    # shared-table tail prefill: gather the whole context
+                    # (cached prefix pages + the tail just scattered above)
+                    # through the table, attend causal-from-start — the
+                    # compute twin of the decode seam at T > 1, kernel-free.
+                    # The speculative verify pass (genrl/continuous.py) rides
+                    # this exact path with T = draft bucket + 1: slot j is
+                    # position prefix_starts + j, the pos <= qpos mask keeps
+                    # rejected slots' K/V (garbage past the cursor) out of
+                    # every query, so draft rollback never touches the device.
+                    # The heads are split out of the gathered rows: reshaping
+                    # the pool itself would bring its relayout copy back
+                    kg = gather_pages(kp, page_table, self.num_heads)
+                    vg = gather_pages(vp, page_table, self.num_heads)
+                    pos = jnp.arange(kg.shape[1])[None, None, :]
+                    qpos = (
+                        prefix_starts[:, None] + jnp.arange(T)[None, :]
+                    )[:, :, None]
+                    out = _masked_attention(
+                        q, kg, vg, pos <= qpos, self.dtype
+                    )
+                elif page_table is not None:
+                    paged_attn = self.paged_attn_fn or paged_attention_reference
+                    out = paged_attn(q, kp, vp, page_table, attn_lengths)
+                    out = out.astype(self.dtype)
+                else:
+                    out = _masked_attention(q, k, v, attn_mask, self.dtype)
+                new_cache = (kp, vp)
+            elif segment_ids is not None and self.segment_attn_fn is not None:
+                # packed-row training attention through the flash seam: the
+                # kernel enforces the segment-blocked causal rule and skips
+                # fully-masked (cross-segment / pad) blocks entirely
+                out = self.segment_attn_fn(q, k, v, segment_ids)
+                out = out.astype(self.dtype)
+            elif attn_mask is not None:
+                out = _masked_attention(q, k, v, attn_mask, self.dtype)
+            else:
+                out = self.attn_fn(q, k, v)
+            out = nn.Dense(self.d_model, use_bias=False, name="proj", **dt)(
+                out.reshape(B, T, width)
+            )
         x = x + out
         h = _norm(spec, self.dtype, "ffn_norm" if rms else None)(x)
         if spec.ffn == "experts":
-            h = RoutedExperts(
-                spec.num_experts, spec.experts_per_token, spec.expert_width,
-                spec.norm_topk_prob, name="experts", **dt,
-            )(h)
+            y = _routed_experts(spec, dt)(h)
+            if spec.shared_experts:
+                # always on, computed where the token lives
+                y = y + _GatedMLP(
+                    self.d_model, spec.shared_experts * spec.expert_width,
+                    name="shared", **dt,
+                )(h)
+            h = y
+        elif spec.ffn == "swiglu":
+            h = _GatedMLP(self.d_model, spec.ffn_hidden, name="ffn", **dt)(h)
         else:
             h = nn.Dense(self.mlp_ratio * self.d_model, name="mlp_in", **dt)(h)
             h = nn.gelu(h)
@@ -519,7 +629,8 @@ class _LatentAttention(nn.Module):
     v]`` a head ``= c W_kvb``; rotary on ``q_pe`` and on the one ``k_pe``
     all heads share; scores ``(q_nope . k_nope + q_pe . k_pe) /
     sqrt(nope + rope)``, softmax in float32; ``o = concat(p v) W_o``.
-    ``s_q = sqrt(d / q_lora_rank)``, ``s_kv = sqrt(d / kv_lora_rank)``.
+    Under ``spec.mla_scale`` ``s_q = sqrt(d / q_lora_rank)`` and ``s_kv =
+    sqrt(d / kv_lora_rank)``; else both are 1.
 
     One set of parameters, two forms of the same product:
 
@@ -567,27 +678,38 @@ class _LatentAttention(nn.Module):
         def dense(width, name, **kw):
             return nn.Dense(width, use_bias=False, name=name, **dt, **kw)
 
-        # the two projections OUT of a rank start at the variance a
-        # ``d_model``-wide input would give them (std ``d_model ** -0.5``),
-        # which is what the two scales below bring back to one: q, k and v
-        # of unit variance and attention scores of order one at the start.
-        # Plain fan-in over the rank would leave the scores ``s_q x s_kv``
-        # (7 at the published sizes) too large and the softmax near one-hot
-        aligned = nn.initializers.normal(self.d_model ** -0.5)
+        # under ``mla_scale`` the two projections OUT of a rank start at
+        # the variance a ``d_model``-wide input would give them (std
+        # ``d_model ** -0.5``), which is what the two scales below bring
+        # back to one: q, k and v of unit variance and attention scores of
+        # order one at the start.  Plain fan-in over the rank would leave
+        # the scores ``s_q x s_kv`` (7 at LongCat's sizes) too large and
+        # the softmax near one-hot.  Without the factors plain fan-in over
+        # the rank IS unit variance: the normed ranks go in at variance one
+        if s.mla_scale:
+            up_init = nn.initializers.normal(self.d_model ** -0.5)
+            s_q = (self.d_model / s.q_lora_rank) ** 0.5
+            s_kv = (self.d_model / r_kv) ** 0.5
+        else:
+            up_init, s_q, s_kv = nn.initializers.lecun_normal(), None, None
         c_q = RMSNorm(s.norm_eps, dtype=self.dtype, name="q_a_norm")(
             dense(s.q_lora_rank, "q_a")(h)
         )
-        q = dense(H * (nope + rope), "q_b", kernel_init=aligned)(c_q)
-        q = (q * (self.d_model / s.q_lora_rank) ** 0.5).reshape(B, T, H, nope + rope)
+        q = dense(H * (nope + rope), "q_b", kernel_init=up_init)(c_q)
+        if s_q is not None:
+            q = q * s_q
+        q = q.reshape(B, T, H, nope + rope)
         q_nope, q_pe = q[..., :nope], q[..., nope:]
         kv = dense(r_kv + rope, "kv_a")(h)
         # the scale is applied in the norm's float32, rounded once
         c = RMSNorm(s.norm_eps, dtype=f32, name="kv_a_norm")(kv[..., :r_kv])
-        c = (c * (self.d_model / r_kv) ** 0.5).astype(self.dtype)
+        if s_kv is not None:
+            c = c * s_kv
+        c = c.astype(self.dtype)
         k_pe = kv[..., None, r_kv:]  # [B, T, 1, rope]: one for all heads
         q_pe, k_pe = self.rotary(q_pe), self.rotary(k_pe)
         w_kvb = self.param(
-            "kv_b", aligned, (r_kv, H * (nope + vd)), self.param_dtype
+            "kv_b", up_init, (r_kv, H * (nope + vd)), self.param_dtype
         ).astype(self.dtype)
         scale = 1.0 / (nope + rope) ** 0.5
         if pool is not None:
@@ -637,14 +759,14 @@ class _LatentAttention(nn.Module):
             packed = segment_ids is not None and self.segment_attn_fn is not None
             if pool is not None or (attn_mask is not None and not packed):
                 out = _masked_attention(qf, k, v, attn_mask, self.dtype)
+            elif packed:
+                # the segment kernels take a v narrower than q and k
+                out = self.segment_attn_fn(qf, k, v, segment_ids).astype(self.dtype)
             else:
-                # kernels that take one head size: v padded to q and k's
+                # a causal ``attn_fn`` takes one head size: v padded to q
+                # and k's, the pad sliced off
                 v = jnp.pad(v, ((0, 0),) * 3 + ((0, nope + rope - vd),))
-                if packed:
-                    out = self.segment_attn_fn(qf, k, v, segment_ids)
-                else:
-                    out = self.attn_fn(qf, k, v)
-                out = out[..., :vd].astype(self.dtype)
+                out = self.attn_fn(qf, k, v)[..., :vd].astype(self.dtype)
         out = dense(self.d_model, "proj")(out.reshape(B, T, H * vd))
         return out, pool
 
@@ -710,19 +832,72 @@ class _ShortcutBlock(nn.Module):
 
         x, pool_0 = attention(0, x)
         h = _norm(spec, self.dtype, "ffn_norm_0")(x)
-        m = RoutedExperts(
-            spec.num_experts, spec.experts_per_token, spec.expert_width,
-            spec.norm_topk_prob, zero_experts=spec.zero_experts,
-            held=spec.experts_held, first_expert=spec.first_expert,
-            choice_bias=spec.router_bias, routed_scaling=spec.routed_scaling,
-            name="experts", **dt,
-        )(h)
+        m = _routed_experts(spec, dt)(h)
         x = x + ffn(0, h)
         x, pool_1 = attention(1, x)
         x = x + ffn(1, _norm(spec, self.dtype, "ffn_norm_1")(x)) + m
         if paged_cache is not None:
             return x, (pool_0, pool_1)
         return x
+
+
+class _MTPModule(nn.Module):
+    """One multi-token-prediction module (the DeepSeek-V3 report's section
+    2.2): ``h'_i = W_eh [RMSNorm_h(h_i) ; RMSNorm_e(Emb(t_{i+1}))]``, then
+    one layer of the stack's repeating kind with weights of its own, at
+    position ``i``'s rotary angle and under the caller's attention rule.
+    ``x`` is the trunk's last layer output (before the final norm),
+    ``next_emb`` the shared embedding of each position's next token and
+    ``has_next [B, T]`` whether that token is in the position's own
+    sequence: where it is not, the embedding's half is zero, so nothing of
+    a neighbouring segment enters.  The caller norms the result and scores
+    it with the shared head; it predicts ``t_{i+2}``."""
+
+    d_model: int
+    num_heads: int
+    mlp_ratio: int
+    attn_fn: AttentionFn
+    spec: BlockSpec
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    segment_attn_fn: Optional[Callable] = None
+    rotary: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x, next_emb, has_next, **call):
+        eps = self.spec.norm_eps
+        h = RMSNorm(eps, dtype=self.dtype, name="h_norm")(x)
+        e = RMSNorm(eps, dtype=self.dtype, name="e_norm")(next_emb)
+        e = e * has_next[..., None].astype(self.dtype)
+        y = nn.Dense(
+            self.d_model, use_bias=False, name="eh_proj", dtype=self.dtype,
+            param_dtype=self.param_dtype,
+        )(jnp.concatenate([h, e], axis=-1))
+        return _Block(
+            self.d_model, self.num_heads, self.mlp_ratio, self.attn_fn,
+            dtype=self.dtype, param_dtype=self.param_dtype,
+            segment_attn_fn=self.segment_attn_fn, spec=self.spec,
+            rotary=self.rotary, name="block",
+        )(y, **call)
+
+
+def _latent_pools(spec: BlockSpec) -> int:
+    return 2 if spec.layer == "scmoe" else 1
+
+
+@functools.lru_cache(maxsize=None)
+def _note_layers(shape, kinds, attention, held, num_experts, mtp_layers) -> None:
+    """A stack always runs the layers it was built from, so its counter is
+    its make-up: one zero-length program span a traced shape (the cache is
+    the "once"), so that a trace says which stack ran."""
+    from scalerl_tpu.runtime import tracing
+
+    with tracing.span(
+        "model.layers", kind="model", shape=list(shape), layers=list(kinds),
+        attention=attention, held=held, num_experts=num_experts,
+        mtp_layers=mtp_layers,
+    ):
+        pass
 
 
 class TransformerPolicy(nn.Module):
@@ -778,8 +953,16 @@ class TransformerPolicy(nn.Module):
     segment_attn_fn: Optional[Callable] = None
     # The block kind (norm, positions, q/k norm, head size, FFN) as data;
     # the default is the GPT-2 block.  ``block_spec(family, ...)`` names
-    # the families the program's arguments can choose.
+    # the families the program's arguments can choose.  What the whole
+    # model shares is read from here: norm, positions, attention kind.
     block: BlockSpec = BlockSpec()
+    # The stack as a per-layer list (``layer_specs``); empty: ``block``,
+    # ``num_layers`` times.  The layers share ``block``'s attention kind.
+    layers: Tuple[BlockSpec, ...] = ()
+    # Multi-token-prediction modules (0 | 1): a layer of ``block``'s kind
+    # with weights of its own under ``mtp/``, which a forward called with
+    # ``mtp=True`` (the packed learner's) runs and no other does.
+    mtp_layers: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -787,6 +970,24 @@ class TransformerPolicy(nn.Module):
         if self.block.attention == "mla":
             return self.block.qk_nope_head_dim + self.block.qk_rope_head_dim
         return self.block.head_dim or self.d_model // self.num_heads
+
+    @property
+    def layer_specs(self) -> Tuple[BlockSpec, ...]:
+        specs = self.layers or (self.block,) * self.num_layers
+        if len(specs) != self.num_layers or any(
+            s.attention != self.block.attention for s in specs
+        ):
+            raise ValueError(
+                f"{len(specs)} layer specs for num_layers={self.num_layers}, "
+                "or a layer whose attention kind is not the model's"
+            )
+        return specs
+
+    @property
+    def routed_layers(self) -> int:
+        """Layers of the stack with a router (the MTP module's not among
+        them: generation never runs it)."""
+        return sum(s.ffn == "experts" for s in self.layer_specs)
 
     def init_paged_cache(self, num_pages: int, page_size: int, dtype=jnp.float32):
         """Zeroed page pools of the kind and number this model's blocks
@@ -796,12 +997,14 @@ class TransformerPolicy(nn.Module):
         page_size, width]`` pools."""
         spec = self.block
         if spec.attention == "mla":
+            # one latent pool an attention: one a plain layer, two a
+            # shortcut-connected double layer
             width = latent_pool_width(spec.kv_lora_rank + spec.qk_rope_head_dim)
-            per_layer = 2 if spec.layer == "scmoe" else 1
+            pools = sum(_latent_pools(s) for s in self.layer_specs)
             return LatentKVCache(
                 rows=tuple(
                     jnp.zeros((num_pages, page_size, width), dtype)
-                    for _ in range(per_layer * self.num_layers)
+                    for _ in range(pools)
                 )
             )
         return init_paged_kv_cache(
@@ -822,6 +1025,7 @@ class TransformerPolicy(nn.Module):
         attn_lengths: Optional[jnp.ndarray] = None,
         prefix_starts: Optional[jnp.ndarray] = None,
         segment_ids: Optional[jnp.ndarray] = None,
+        mtp: bool = False,
     ):
         """Full forward, masked full forward, or paged incremental step.
 
@@ -852,19 +1056,37 @@ class TransformerPolicy(nn.Module):
           flash kernel; otherwise the dense
           :func:`packed_attention_mask` feeds the existing masked path.
           Same params as every other path.
+        - ``mtp=True`` (a model with ``mtp_layers``, no cache): also run
+          the multi-token-prediction module over the same rows and return
+          its logits as ``mtp_logits`` (:class:`_MTPModule`).
         """
         B, T = obs.shape[:2]
         spec = self.block
+        specs = self.layer_specs
         if T > self.max_len and spec.positions == "learned":
             # out-of-range gathers clamp silently under jit, which would
             # alias every late position onto one embedding
             raise ValueError(
                 f"sequence length {T} exceeds max_len={self.max_len}"
             )
+        if not self.is_initializing():  # a program's trace, not the weights' making
+            _note_layers(
+                tuple(obs.shape), tuple(f"{s.layer}/{s.ffn}" for s in specs),
+                spec.attention, spec.experts_held or spec.num_experts,
+                spec.num_experts, self.mtp_layers,
+            )
         attn = self.attn_fn
         if attn is None:
             base = flash_attention if self.use_flash else full_attention
             attn = lambda q, k, v: base(q, k, v, causal=True)  # noqa: E731
+        run_mtp = bool(self.mtp_layers) and (mtp or self.is_initializing())
+        if run_mtp:
+            # whether position i's next token is of i's own sequence
+            has_next = jnp.arange(T)[None, :] < T - 1
+            if segment_ids is not None:
+                seg = segment_ids.astype(jnp.int32)
+                has_next = has_next & (seg > 0) & (jnp.roll(seg, -1, axis=1) == seg)
+            has_next = jnp.broadcast_to(has_next, (B, T))
         if segment_ids is not None and self.segment_attn_fn is None:
             # dense packed fallback: ONE [B, S, S] mask shared by every
             # block — the XLA reference path and the off-TPU shape
@@ -874,10 +1096,11 @@ class TransformerPolicy(nn.Module):
             positions = jnp.broadcast_to(jnp.arange(T), (B, T))
         c = self.constrain if self.constrain is not None else (lambda x: x)
         if self.vocab_size is not None:
-            x = nn.Embed(
+            embed = nn.Embed(
                 self.vocab_size, self.d_model, name="token_embed",
                 dtype=self.dtype, param_dtype=self.param_dtype,
-            )(obs.astype(jnp.int32))
+            )
+            x = embed(obs.astype(jnp.int32))
         else:
             x = nn.Dense(
                 self.d_model, name="obs_embed",
@@ -900,32 +1123,37 @@ class TransformerPolicy(nn.Module):
             )
             x = x + pos_tab[positions].astype(self.dtype)
         x = c(x)
-        scmoe = spec.layer == "scmoe"
-        pools = []  # mha: (k, v) a block; scmoe: its two latent pools
-        for i in range(self.num_layers):
+        latent = spec.attention == "mla"
+        pools = []  # what each layer wrote: (k, v), one latent pool, or two
+        at = 0  # the layer's first pool among the latent cache's rows
+        for i, layer in enumerate(specs):
             common = dict(
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
                 paged_attn_fn=self.paged_attn_fn,
                 segment_attn_fn=self.segment_attn_fn,
-                spec=spec,
+                spec=layer,
                 rotary=rotary,
                 name=f"block_{i}",
             )
-            if scmoe:
+            if layer.layer == "scmoe":
                 block = _ShortcutBlock(self.d_model, self.num_heads, attn, **common)
             else:
                 block = _Block(
                     self.d_model, self.num_heads, self.mlp_ratio, attn, **common
                 )
             if paged_cache is not None:
+                if not latent:
+                    cache = (paged_cache.k[i], paged_cache.v[i])
+                elif layer.layer == "scmoe":
+                    cache = paged_cache.rows[at : at + 2]
+                else:
+                    cache = paged_cache.rows[at]
+                at += _latent_pools(layer)
                 x, written = block(
                     x,
                     attn_mask=attn_mask,
-                    paged_cache=(
-                        paged_cache.rows[2 * i : 2 * i + 2] if scmoe
-                        else (paged_cache.k[i], paged_cache.v[i])
-                    ),
+                    paged_cache=cache,
                     page_ids=page_ids,
                     page_offsets=page_offsets,
                     page_table=page_table,
@@ -938,14 +1166,38 @@ class TransformerPolicy(nn.Module):
             else:
                 x = block(x, attn_mask=attn_mask)
             x = c(x)
-        x = _norm(spec, jnp.float32, "final_norm")(x.astype(jnp.float32))
-        policy_logits = nn.Dense(self.num_actions, name="policy_head")(x)
+        final_norm = functools.partial(_norm, spec, jnp.float32)
+        policy_head = nn.Dense(self.num_actions, name="policy_head")
+        mtp_logits = None
+        if run_mtp:
+            # the module reads the trunk's last layer output and the shared
+            # embedding of each position's next token, and is scored by the
+            # shared head behind a norm of its own
+            call = (
+                dict(segment_ids=segment_ids) if segment_ids is not None
+                else dict(attn_mask=attn_mask)
+            )
+            y = _MTPModule(
+                self.d_model, self.num_heads, self.mlp_ratio, attn, spec,
+                dtype=self.dtype, param_dtype=self.param_dtype,
+                segment_attn_fn=self.segment_attn_fn, rotary=rotary, name="mtp",
+            )(x, embed(jnp.roll(obs.astype(jnp.int32), -1, axis=1)), has_next, **call)
+            mtp_logits = policy_head(
+                final_norm("mtp_final_norm")(c(y).astype(jnp.float32))
+            )
+        x = final_norm("final_norm")(x.astype(jnp.float32))
+        policy_logits = policy_head(x)
         baseline = nn.Dense(1, name="value_head")(x).squeeze(-1)
-        out = TransformerOutput(policy_logits, baseline)
+        out = TransformerOutput(policy_logits, baseline, mtp_logits)
         if paged_cache is None:
             return out
-        if scmoe:
-            return out, LatentKVCache(rows=tuple(p for pair in pools for p in pair))
+        if latent:
+            return out, LatentKVCache(
+                rows=tuple(
+                    p for layer, w in zip(specs, pools)
+                    for p in (w if layer.layer == "scmoe" else (w,))
+                )
+            )
         return out, PagedKVCache(
             k=tuple(k for k, _v in pools), v=tuple(v for _k, v in pools)
         )

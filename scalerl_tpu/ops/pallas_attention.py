@@ -481,16 +481,19 @@ def segment_flash_tiling(
 
 
 @functools.lru_cache(maxsize=None)
-def _note_tiling(shape: Tuple[int, ...], dtype: str, tl: SegmentTiling) -> None:
+def _note_tiling(
+    shape: Tuple[int, ...], v_head: int, dtype: str, tl: SegmentTiling
+) -> None:
     """The mechanism always engages, so its counter is its geometry: one
     zero-length program span a traced shape (the cache is the "once"),
-    never one a step, so that a trace says which tiling ran."""
+    never one a step, so that a trace says which tiling ran.  ``shape`` is
+    q's and k's, ``v_head`` the head size of v and of the output."""
     from scalerl_tpu.runtime import tracing
 
     steps, steps_dkv = tl.grid_steps(shape[0], shape[2])
     with tracing.span(
-        "segment_flash.tiling", kind="kernel", shape=list(shape), dtype=dtype,
-        grid_steps=steps, grid_steps_dkv=steps_dkv, **tl._asdict(),
+        "segment_flash.tiling", kind="kernel", shape=list(shape), v_head=v_head,
+        dtype=dtype, grid_steps=steps, grid_steps_dkv=steps_dkv, **tl._asdict(),
     ):
         pass
 
@@ -679,8 +682,9 @@ def _seg_fwd_kernel(
 
 def _seg_fwd(q, k, v, seg, scale, block_q, block_k, interpret):
     B, T, H, D = q.shape
+    Dv = v.shape[-1]  # v, and so the output, may be narrower than q and k
     tl = segment_flash_tiling(T, D, q.dtype, block_q, block_k)
-    _note_tiling(q.shape, jnp.dtype(q.dtype).name, tl)
+    _note_tiling(q.shape, Dv, jnp.dtype(q.dtype).name, tl)
     qh, kh, vh = (_heads_first(x, tl.t_pad) for x in (q, k, v))
     seg_p, qseg, kseg = _seg_operands(seg, tl)
     tile, stream, qseg_spec, kseg_spec = _seg_specs(tl, q_stationary=True)
@@ -688,14 +692,14 @@ def _seg_fwd(q, k, v, seg, scale, block_q, block_k, interpret):
     o, lse = _seg_pallas_call(
         functools.partial(_seg_fwd_kernel, scale=scale, tl=tl),
         "segment_flash_fwd", tl.grid(B, H, True),
-        in_specs=[tile(D), stream(D), stream(D), qseg_spec, kseg_spec],
-        out_specs=[tile(D), tile(1)],
+        in_specs=[tile(D), stream(D), stream(Dv), qseg_spec, kseg_spec],
+        out_specs=[tile(Dv), tile(1)],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, tl.t_pad, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, tl.t_pad, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, tl.t_pad, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tl.block_q, D), jnp.float32),
+            pltpu.VMEM((tl.block_q, Dv), jnp.float32),
             pltpu.VMEM((tl.block_q, 1), jnp.float32),
             pltpu.VMEM((tl.block_q, 1), jnp.float32),
         ],
@@ -819,6 +823,7 @@ def _seg_bwd_dkv_kernel(
 def _seg_bwd(scale, block_q, block_k, interpret, residuals, g):
     q, k, v, seg, o, lse = residuals  # lse: [B, H, T_p, 1]
     B, T, H, D = q.shape
+    Dv = v.shape[-1]
     tl = segment_flash_tiling(T, D, q.dtype, block_q, block_k)
     qh, kh, vh, doh, oh = (_heads_first(x, tl.t_pad) for x in (q, k, v, g, o))
     seg_p, qseg, kseg = _seg_operands(seg, tl)
@@ -832,8 +837,8 @@ def _seg_bwd(scale, block_q, block_k, interpret, residuals, g):
         functools.partial(_seg_bwd_dq_kernel, scale=scale, tl=tl),
         "segment_flash_bwd_dq", tl.grid(B, H, True),
         in_specs=[
-            tile(D), stream(D), stream(D), qseg_spec, kseg_spec,
-            tile(D), tile(1), tile(1),
+            tile(D), stream(D), stream(Dv), qseg_spec, kseg_spec,
+            tile(Dv), tile(1), tile(1),
         ],
         out_specs=tile(D),
         out_shape=jax.ShapeDtypeStruct((B, H, tl.t_pad, D), q.dtype),
@@ -847,17 +852,17 @@ def _seg_bwd(scale, block_q, block_k, interpret, residuals, g):
         functools.partial(_seg_bwd_dkv_kernel, scale=scale, tl=tl),
         "segment_flash_bwd_dkv", tl.grid(B, H, False),
         in_specs=[
-            stream(D), tile(D), tile(D), qseg_spec, kseg_spec,
-            stream(D), stream(1), stream(1),
+            stream(D), tile(D), tile(Dv), qseg_spec, kseg_spec,
+            stream(Dv), stream(1), stream(1),
         ],
-        out_specs=[tile(D), tile(D)],
+        out_specs=[tile(D), tile(Dv)],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, tl.t_pad, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, tl.t_pad, D), v.dtype),
+            jax.ShapeDtypeStruct((B, H, tl.t_pad, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((tl.block_k, D), jnp.float32),
-            pltpu.VMEM((tl.block_k, D), jnp.float32),
+            pltpu.VMEM((tl.block_k, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(*_live_blocks(seg_p, tl.block_k, tl.block_q, False), *operands)
@@ -878,7 +883,8 @@ def segment_flash_attention(
     """Segment-packed causal self-attention, forward AND backward.
 
     ``q/k/v``: ``[B, T, H, D]`` with T shared (self-attention over packed
-    rows).  ``segment_ids``: ``[B, T]`` int32, contiguous ascending ids
+    rows); ``v`` may have a head size of its own (latent attention: q and
+    k 192, v 128), which is then the output's.  ``segment_ids``: ``[B, T]`` int32, contiguous ascending ids
     starting at 1 with a zero pad tail (the ``genrl/rollout.py`` packer's
     contract).  Token ``i`` attends to ``j <= i`` iff
     ``segment_ids[i] == segment_ids[j] != 0``.  Fully-masked rows (pad

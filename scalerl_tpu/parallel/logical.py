@@ -91,6 +91,15 @@ PARAM_LOGICAL_AXES: Dict[Tuple[str, ...], Tuple[Optional[str], ...]] = {
     ("gate", "kernel"): ("embed", "mlp"),
     ("up", "kernel"): ("embed", "mlp"),
     ("down", "kernel"): ("mlp", "embed"),
+    # The joyai stack: its leading dense FFN (``ffn``) and each routed
+    # layer's always-on shared expert (``shared``) are SwiGLUs whose three
+    # kernels the rules above name (they match by the last two path
+    # names), and the plain layer's latent attention (``attn``) is the
+    # longcat one.  The multi-token-prediction module (``mtp/``) holds a
+    # layer of the same kinds under ``mtp/block`` and one matrix of its
+    # own, which takes the concatenated ``[trunk ; embedding]`` (2 x embed)
+    # back to the residual stream and replicates like the stream.
+    ("eh_proj", "kernel"): (None, "embed"),
 }
 
 

@@ -51,7 +51,11 @@ from scalerl_tpu.genrl.rollout import (
     sequence_field_shapes,
 )
 from scalerl_tpu.genrl.task import TokenRecallTask
-from scalerl_tpu.models.transformer import TransformerPolicy, block_spec
+from scalerl_tpu.models.transformer import (
+    TransformerPolicy,
+    block_spec,
+    layer_specs,
+)
 from scalerl_tpu.ops.pallas_per import resolve_sample_method
 from scalerl_tpu.parallel.train_step import maybe_enable_mesh_from_args
 from scalerl_tpu.runtime import telemetry, tracing
@@ -79,6 +83,28 @@ def build_genrl_model(args: GenRLArguments) -> TransformerPolicy:
     bf16 = bool(getattr(args, "bf16_params", False))
     import jax.numpy as jnp
 
+    spec = block_spec(
+        args.block_family,
+        head_dim=args.head_dim or None,
+        norm_eps=args.rms_norm_eps,
+        rope_theta=args.rope_theta,
+        num_experts=args.moe_experts,
+        experts_per_token=args.moe_experts_per_token,
+        expert_width=args.moe_hidden,
+        norm_topk_prob=args.moe_norm_topk_prob,
+        q_lora_rank=args.mla_q_lora_rank,
+        kv_lora_rank=args.mla_kv_lora_rank,
+        qk_nope_head_dim=args.mla_qk_nope_head_dim,
+        qk_rope_head_dim=args.mla_qk_rope_head_dim,
+        v_head_dim=args.mla_v_head_dim,
+        ffn_hidden=args.ffn_hidden,
+        zero_experts=args.moe_zero_experts,
+        experts_held=args.moe_experts_held,
+        first_expert=args.moe_first_expert,
+        routed_scaling=args.moe_routed_scaling,
+        scoring=args.moe_scoring,
+        shared_experts=args.moe_shared_experts,
+    )
     return TransformerPolicy(
         num_actions=args.vocab_size,
         vocab_size=args.vocab_size,
@@ -89,26 +115,13 @@ def build_genrl_model(args: GenRLArguments) -> TransformerPolicy:
         dtype=jnp.bfloat16 if bf16 else jnp.float32,
         param_dtype=jnp.bfloat16 if bf16 else jnp.float32,
         segment_attn_fn=seg_fn,
-        block=block_spec(
-            args.block_family,
-            head_dim=args.head_dim or None,
-            norm_eps=args.rms_norm_eps,
-            rope_theta=args.rope_theta,
-            num_experts=args.moe_experts,
-            experts_per_token=args.moe_experts_per_token,
-            expert_width=args.moe_hidden,
-            norm_topk_prob=args.moe_norm_topk_prob,
-            q_lora_rank=args.mla_q_lora_rank,
-            kv_lora_rank=args.mla_kv_lora_rank,
-            qk_nope_head_dim=args.mla_qk_nope_head_dim,
-            qk_rope_head_dim=args.mla_qk_rope_head_dim,
-            v_head_dim=args.mla_v_head_dim,
-            ffn_hidden=args.ffn_hidden,
-            zero_experts=args.moe_zero_experts,
-            experts_held=args.moe_experts_held,
-            first_expert=args.moe_first_expert,
-            routed_scaling=args.moe_routed_scaling,
+        block=spec,
+        # a stack of one kind of layer is its block, ``n_layers`` times
+        layers=(
+            layer_specs(spec, args.n_layers, args.dense_layers)
+            if args.dense_layers else ()
         ),
+        mtp_layers=args.mtp_layers,
     )
 
 

@@ -15,8 +15,10 @@ the benchmark's ``tests/benchmark/test_benchmark_real_shape_compiles.py``
 does the same in another xdist worker, which the tier-1 command allows
 with ``ALLOW_MULTIPLE_LIBTPU_LOAD=1``.  Where no topology can be described
 the file skips.  The fused classic loop's program is held to the same
-rule at the end of the file (ISSUE 31): this is the one tier-1 file that
-loads the TPU's compiler outside ``tests/benchmark``.
+rule at the end of the file (ISSUE 31), and the segment kernels at latent
+attention's head shape are compiled there at the learn cell's own sizes
+(ISSUE 32): this is the one tier-1 file that loads the TPU's compiler
+outside ``tests/benchmark``.
 """
 
 import functools
@@ -238,3 +240,37 @@ def test_fused_loop_stores_frames_lane_dense(one_chip, num_envs):
     frame_batch = num_envs * int(np.prod(venv.observation_shape))
     faults = tiled_layout.lane_dense_faults(text, "u8", frame_batch, lane_dim=num_envs)
     assert not faults, faults
+
+
+def test_segment_kernels_compile_at_latent_attentions_head_shape(one_chip):
+    """``joyai_packed_learn``'s attention: 4 packed rows of 1,024, 32 heads,
+    q and k 192 wide (one and a half lane tiles) and v 128, bfloat16,
+    forward and both backward kernels.  Mosaic takes the 192-wide head and
+    the narrower v as they are (ISSUE 32; it had refused a 576-wide page
+    out of a 640-lane pool, PR 30): three custom calls and no pad of v in
+    the program."""
+    import json
+    from pathlib import Path
+
+    from scalerl_tpu.ops.pallas_attention import segment_flash_attention
+
+    bench = Path(__file__).resolve().parents[1] / "benchmark"
+    p = json.loads((bench / "workloads" / "joyai_packed_learn.json").read_text())["params"]
+    cfg = json.loads((bench / "configs" / "joyai-llm-flash.json").read_text())
+    rows, T, H = p["rows_per_step"], p["pack_len"], cfg["num_attention_heads"]
+    qk = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
+    sds = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def loss(q, k, v, seg):
+        return jnp.sum(segment_flash_attention(q, k, v, seg, interpret=False).astype(jnp.float32))
+
+    compiled = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        .trace(sds(rows, T, H, qk), sds(rows, T, H, qk), sds(rows, T, H, cfg["v_head_dim"]),
+               sds(rows, T, dt=jnp.int32))
+        .lower(lowering_platforms=("tpu",)).compile()
+    )
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("segment_flash_fwd", "segment_flash_bwd_dq", "segment_flash_bwd_dkv"):
+        assert name in text

@@ -303,6 +303,38 @@ def test_segment_flash_chosen_tile_edges(H, D, dtype, out_tol, grad_tol):
     _seg_check(q, k, v, seg, (None, None), out_tol, grad_tol)
 
 
+@pytest.mark.parametrize(
+    "D,Dv,dtype,blocks,out_tol,grad_tol",
+    [
+        (12, 8, jnp.float32, (8, 8), 2e-5, 1e-5),
+        (12, 8, jnp.float32, (8, 16), 2e-5, 1e-5),
+        (192, 128, jnp.float32, (None, None), 2e-5, 1e-5),  # latent attention's heads
+        # bfloat16 operands and outputs: a rounding of 2^-8 on values of
+        # order one, summed over a head of 24
+        (24, 16, jnp.bfloat16, (None, None), 5e-2, 5e-2),
+    ],
+    ids=["12-8", "12-8-wide-k", "192-128", "bf16"],
+)
+def test_segment_flash_takes_a_v_narrower_than_q_and_k(D, Dv, dtype, blocks, out_tol, grad_tol):
+    """Latent attention's head shape (ISSUE 32): q and k of one head size,
+    v and the output of a smaller one, with no pad.  Outputs and all three
+    gradients against the dense oracle (dv has v's width), and bit for bit
+    what the same call gives with v padded to q's width and the pad
+    sliced off: a column of ``p v`` never sees another."""
+    from scalerl_tpu.ops.pallas_attention import segment_flash_attention
+
+    B, T, H = 2, 40, 2
+    q, k, _ = (x.astype(dtype) for x in _seg_rand(7, B, T, H, D))
+    v = _seg_rand(8, B, T, H, Dv)[2].astype(dtype)
+    seg = _seg_layout(B, T, [[(0, 9, 1), (9, 30, 2), (30, 36, 3)], [(0, T, 1)]])
+    _seg_check(q, k, v, seg, blocks, out_tol, grad_tol)
+    out = segment_flash_attention(q, k, v, seg, None, *blocks, None)
+    assert out.shape == (B, T, H, Dv)
+    padded = jnp.pad(v, ((0, 0),) * 3 + ((0, D - Dv),))
+    wide = segment_flash_attention(q, k, padded, seg, None, *blocks, None)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(wide[..., :Dv]))
+
+
 @pytest.mark.parametrize("blocks", [(8, 8), (8, 16), (16, 8)], ids=str)
 def test_segment_flash_several_major_blocks(monkeypatch, blocks):
     """Rows longer than the VMEM budget holds (the planned 8k-token rows):
@@ -328,6 +360,7 @@ def test_segment_flash_several_major_blocks(monkeypatch, blocks):
     [
         (1024, 64, jnp.float32),  # both learn cells
         (1024, 128, jnp.bfloat16),  # the OLMoE learner
+        (1024, 192, jnp.bfloat16),  # joyai_packed_learn: latent attention's q and k
         (8192, 128, jnp.bfloat16),  # its planned 8k-token rows
         (8192, 128, jnp.float32),
         (384, 32, jnp.float32),

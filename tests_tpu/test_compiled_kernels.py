@@ -789,3 +789,52 @@ def test_segment_flash_default_tiling_compiled(
     )(q, k, v)
     for a, b in zip(gk, gr):
         np.testing.assert_allclose(f32(a), f32(b), atol=bwd_tol, rtol=bwd_tol)
+
+
+@pytest.mark.parametrize(
+    "Dv,grad_tol", [(128, 1e-1), (192, 1.5e-1)], ids=["v128", "v192-padded"]
+)
+def test_segment_flash_latent_head_shape_compiled(Dv, grad_tol):
+    """ISSUE 32: ``joyai_packed_learn``'s attention on the chip: q and k
+    192 wide, v 128 (and, beside it, v padded to 192 as the model did
+    before), bfloat16, 32 heads over 1,024-token packed rows, the tile left
+    to ``segment_flash_tiling``; outputs and all three gradients against
+    the dense oracle.  bfloat16 operands and one bf16 pass a product: the
+    tolerances are the OLMoE learner's case above; with v 192 wide ``dp``
+    sums one and a half times the columns, and the gradients' tolerance is
+    one and a half times as wide (one element of 12.6 M read 0.133 off on
+    the chip, PR 32)."""
+    from scalerl_tpu.ops.pallas_attention import (
+        segment_attention_reference,
+        segment_flash_attention,
+    )
+
+    B, T, H, D = 2, 1024, 32, 192
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(13), 3)
+    q, k = (_rand(kk, B, T, H, D).astype(jnp.bfloat16) for kk in (k1, k2))
+    v = _rand(k3, B, T, H, Dv).astype(jnp.bfloat16)
+    seg = np.zeros((B, T), np.int32)
+    seg[0, :400], seg[0, 400:780], seg[0, 780:980] = 1, 2, 3
+    seg[1, :520], seg[1, 520:990] = 1, 2
+    seg = jnp.asarray(seg)
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))  # noqa: E731
+
+    out = segment_flash_attention(q, k, v, seg, interpret=False)
+    assert out.shape == (B, T, H, Dv)
+    ref = segment_attention_reference(q, k, v, seg)
+    np.testing.assert_allclose(f32(out), f32(ref), atol=3e-2, rtol=3e-2)
+    np.testing.assert_array_equal(f32(out)[0, 980:], 0.0)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2)
+
+    gk = jax.jit(jax.grad(
+        loss(lambda q, k, v: segment_flash_attention(q, k, v, seg, interpret=False)),
+        argnums=(0, 1, 2),
+    ))(q, k, v)
+    gr = jax.grad(
+        loss(lambda q, k, v: segment_attention_reference(q, k, v, seg)),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    for a, b in zip(gk, gr):
+        np.testing.assert_allclose(f32(a), f32(b), atol=grad_tol, rtol=grad_tol)
