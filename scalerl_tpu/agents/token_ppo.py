@@ -24,7 +24,8 @@ token is one action —
   trace time, so the padded path stays the packed path's parity twin;
 - the whole update is ONE pure jitted ``(state, batch) -> (state,
   metrics)`` function riding the existing machinery: the nonfinite guard
-  (``maybe_guard_nonfinite``), the dp×mp sharded learn step
+  (decided from the loss and the gradient norm before the update,
+  :func:`make_token_ppo_learn_fn`), the dp×mp sharded learn step
   (``enable_mesh`` -> ``make_parallel_learn_fn`` with the logical mp rule
   table), and the one-batched-transfer metric discipline
   (``learn_device`` + ``get_metrics``).
@@ -364,18 +365,86 @@ def token_ppo_packed_loss(
     return total, metrics
 
 
+@functools.lru_cache(maxsize=None)
+def _note_guard(leaves: int, state_bytes: int) -> None:
+    """The guard engages on every step, so it has no hit rate: one
+    zero-length program span a traced state (the cache is the "once"), so
+    that a trace says which form ran and over how much state.
+    ``nonfinite_grads`` is the counter of how often it refuses."""
+    with tracing.span(
+        "learn.guard", kind="learn", verdict="isfinite(loss, grad_norm)",
+        leaves=leaves, state_bytes=state_bytes,
+    ):
+        pass
+
+
 def make_token_ppo_learn_fn(
     model: TransformerPolicy, optimizer: optax.GradientTransformation, args
 ) -> Callable:
-    """Build the pure ``(state, batch) -> (state, metrics)`` update,
-    wrapped in the all-finite guard like every other learn-fn factory.
+    """Build the pure ``(state, batch) -> (state, metrics)`` update, with
+    the all-finite guard folded into it.
 
     Dispatches per batch LAYOUT at trace time: a batch carrying
     ``segment_ids`` takes the packed-row loss, anything else the padded
     bucket-pair loss — dict structure is static under jit, so one learn
     fn serves both paths (the padded path stays the packed path's parity
     twin) and each layout compiles exactly once.
+
+    **The guard** (ISSUE 33).  Every other learn-fn factory wraps its update
+    in ``parallel/train_step.guard_nonfinite_updates``, which builds the
+    candidate state, reads all of it to judge it, and lets a ``lax.cond``
+    choose between candidate and input.  This state is gigabytes and
+    donated: the candidate then lives beside the old state and the chosen
+    branch copies it over (598 copies, 4.9 GB a step at gpt2-medium).  So
+    here the verdict is taken BEFORE the update, from two scalars the step
+    computes anyway::
+
+        ok = isfinite(loss) & isfinite(grad_norm)
+
+    and params, both moments, ``step`` and ``tokens_seen`` are
+    ``where(ok, candidate, old)`` inside the update's own elementwise pass.
+    A refused step returns its input state bit for bit and counts
+    ``nonfinite_grads = skipped_steps = 1``; ``ref_params`` passes through
+    and is judged by nobody, for it cannot change.  ``grad_norm`` is the
+    float32 norm ``optax.clip_by_global_norm`` asks for: it is written once
+    here, the clip inside ``optimizer`` traces the same expression and XLA
+    keeps one (on the chip, fused into the weight-gradient matmuls), so
+    clip, metric and guard share it.
+
+    *Why ``ok`` implies an all-finite new state*, for the chain
+    ``TokenPPOAgent._make_optimizer`` builds (``clip_by_global_norm(G)`` then
+    ``adam``, under ``fp32_optimizer_state`` or not), given a finite input
+    state: (1) the norm is the root of a sum of float32 squares; a NaN or an
+    infinity in any gradient leaf makes the sum NaN or infinite, so a finite
+    norm means every gradient is finite.  (2) The clip leaves ``g`` alone
+    when ``norm < G`` and else scales it by ``G / norm``: either way
+    ``|g| <= G`` up to a rounding.  (3) ``mu`` and ``nu`` become convex
+    combinations of finite numbers (``b * old + (1 - b) * g``, the same of
+    ``g * g <= G * G``), and their bias corrections divide by ``1 - b**t``,
+    which is at least ``1 - b > 0``.  (4) Adam's step is ``lr * mu_hat /
+    (sqrt(nu_hat) + eps)``: a finite number over at least ``eps``, at most
+    ``lr * G / eps`` in size, and a parameter that moves by so little a step
+    cannot leave float32's range in any run (1e30 steps).  bfloat16
+    parameters share float32's exponent range, so the cast down keeps finite
+    numbers finite.  By induction from a finite initial or restored state,
+    the committed state is finite on every step.  The converse fails in one
+    place, on the conservative side: gradients whose squares overflow
+    float32 while each is finite.  The post-hoc form applied the zero
+    gradient the clip made of them (``g / inf``); this form refuses the
+    step.  A caller who passes another ``optimizer`` keeps the refusal of
+    non-finite gradients and owes its own step (2)-(4).
+
+    ``nonfinite_guard=False`` and ``SCALERL_NONFINITE_GUARD=0`` compile the
+    guard out (``train_step.nonfinite_guard_enabled``);
+    ``nonfinite_check_every`` is not read here: a select inside the
+    update's own pass leaves nothing to amortise.
     """
+    from scalerl_tpu.parallel.train_step import (
+        nonfinite_guard_enabled,
+        tree_all_finite,
+    )
+
+    guarded = nonfinite_guard_enabled(args)
 
     def learn(state: TokenPPOTrainState, batch: Dict[str, jnp.ndarray]):
         loss_fn = token_ppo_loss
@@ -398,24 +467,33 @@ def make_token_ppo_learn_fn(
             adv_norm=args.adv_norm,
             router_aux_coef=getattr(args, "router_aux_loss_coef", 0.0),
         )
+        metrics["grad_norm"] = optax.global_norm(
+            jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
+        )
         updates, opt_state = optimizer.update(
             grads, state.opt_state, state.params
         )
-        params = optax.apply_updates(state.params, updates)
-        new_state = TokenPPOTrainState(
-            params=params,
-            ref_params=state.ref_params,
+        new = dict(
+            params=optax.apply_updates(state.params, updates),
             opt_state=opt_state,
             step=state.step + 1,
             tokens_seen=state.tokens_seen
             + jnp.sum(batch["mask"]).astype(state.tokens_seen.dtype),
         )
-        metrics["grad_norm"] = optax.global_norm(grads)
-        return new_state, metrics
+        if guarded:
+            ok = tree_all_finite((loss, metrics["grad_norm"]))
+            old = {name: getattr(state, name) for name in new}
+            new = jax.tree_util.tree_map(
+                lambda n, o: jnp.where(ok, n, o), new, old
+            )
+            leaves = jax.tree_util.tree_leaves(old)
+            _note_guard(len(leaves), sum(x.size * x.dtype.itemsize for x in leaves))
+            bad = 1.0 - ok.astype(jnp.float32)
+            metrics["nonfinite_grads"] = bad
+            metrics["skipped_steps"] = bad
+        return state.replace(**new), metrics
 
-    from scalerl_tpu.parallel.train_step import maybe_guard_nonfinite
-
-    return maybe_guard_nonfinite(learn, args)
+    return learn
 
 
 class TokenPPOAgent:

@@ -144,7 +144,9 @@ class RLArguments:
     # Numerical fault tolerance (parallel/train_step.py, runtime/chaos.py)
     # All-finite update guard: a learn step whose result contains NaN/Inf is
     # skipped (lax.cond inside the jitted step — no extra dispatch) and
-    # counted in the batched metrics as skipped_steps/nonfinite_grads.
+    # counted in the batched metrics as skipped_steps/nonfinite_grads.  The
+    # token learner refuses a step whose loss or gradient norm is not finite,
+    # before the update (no candidate state, no cond), under the same names.
     nonfinite_guard: bool = True
     # Guard amortization: run the (single fused-reduction) all-finite check
     # only on learn steps where state.step % K == 0.  K=1 (default)
@@ -152,6 +154,9 @@ class RLArguments:
     # ~1/K per step — a divergence is still caught within K-1 steps, which
     # the tripwire's consecutive-skip window tolerates.  The env fast-off
     # SCALERL_NONFINITE_GUARD=0 compiles the guard out entirely instead.
+    # The token learner (agents/token_ppo.py) does not read it: its guard is
+    # a select inside the update, decided from the loss and the gradient
+    # norm, and judges every step.
     nonfinite_check_every: int = 1
     # Divergence tripwire: after this many CONSECUTIVE skipped learn steps
     # the trainer restores agent state from the last good resume checkpoint

@@ -140,23 +140,37 @@ def guard_nonfinite_updates(
     return guarded
 
 
-def maybe_guard_nonfinite(learn_fn: Callable, args: Any) -> Callable:
-    """Apply :func:`guard_nonfinite_updates` unless the config disabled it.
-
-    Two off switches, different costs: ``RLArguments.nonfinite_guard=False``
-    and the environment fast-off ``SCALERL_NONFINITE_GUARD=0`` both return
-    ``learn_fn`` untouched — the guard is *compiled out entirely* (no cond,
-    no reduction, no counters in the metrics dict), not skipped at runtime.
-    The env var exists so a bench/bisect run can toggle the guard without
-    plumbing a config change through every trainer (the r05 regression
-    bisect protocol, docs/PERFORMANCE.md).  ``nonfinite_check_every``
-    amortizes the enabled guard instead of removing it.
-    """
+def nonfinite_guard_enabled(args: Any) -> bool:
+    """The guard's two off switches, read once while a learn fn is built:
+    ``RLArguments.nonfinite_guard=False`` and the environment fast-off
+    ``SCALERL_NONFINITE_GUARD=0``.  Either one *compiles the guard out
+    entirely* (no select, no reduction, no counters in the metrics dict),
+    it is not skipped at runtime.  The env var exists so a bench/bisect run
+    can toggle the guard without plumbing a config change through every
+    trainer (the r05 regression bisect protocol, docs/PERFORMANCE.md)."""
     import os
 
     if os.environ.get("SCALERL_NONFINITE_GUARD") == "0":
-        return learn_fn
-    if getattr(args, "nonfinite_guard", True):
+        return False
+    return bool(getattr(args, "nonfinite_guard", True))
+
+
+def maybe_guard_nonfinite(learn_fn: Callable, args: Any) -> Callable:
+    """Apply :func:`guard_nonfinite_updates` unless the config disabled it
+    (:func:`nonfinite_guard_enabled`: ``learn_fn`` comes back untouched).
+    ``nonfinite_check_every`` amortizes the enabled guard instead of
+    removing it.
+
+    This is the post-hoc form, for a learn fn taken as a black box: nine
+    agent families use it, with train states of megabytes.  The token
+    learner does not (``agents/token_ppo.make_token_ppo_learn_fn``): its
+    state is gigabytes and donated, so a candidate built beside the old
+    state and chosen by a ``lax.cond`` is a copy of the whole state on every
+    step.  It takes its verdict from the loss and the gradient norm, before
+    the update, and shares with this form :func:`tree_all_finite`, the off
+    switches and the two metric names, and nothing else.
+    """
+    if nonfinite_guard_enabled(args):
         return guard_nonfinite_updates(
             learn_fn, check_every=getattr(args, "nonfinite_check_every", 1)
         )

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import math
 import re
-from typing import Iterator, List, NamedTuple, Tuple
+from typing import Iterable, Iterator, List, NamedTuple, Tuple
 
 _ITEMSIZE = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1, "s16": 2,
@@ -137,3 +137,28 @@ def lane_dense_faults(text: str, dtype: str, min_elements: int, lane_dim: int) -
         f"relayout a loop trip: {line}"
         for line in loop_body_copies(text, dtype, min_elements)
     ]
+
+
+def candidate_state_faults(
+    text: str, leaf_shapes: Iterable[Tuple[int, ...]], min_elements: int = 128
+) -> List[str]:
+    """What a learn step must not hold if it builds no candidate train
+    state beside its donated one: a ``conditional`` (a guard choosing
+    between two states), and a ``copy`` whose shape is one of
+    ``leaf_shapes``, the train state's leaves (the chosen state written
+    over the donated buffers, one copy a leaf).  Leaves under
+    ``min_elements`` are left out: a counter or a one-element bias the
+    compiler may stage in scalar memory, which is no pass over the state."""
+    shapes = {tuple(s) for s in leaf_shapes if math.prod(s) >= min_elements}
+    faults = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) (conditional|copy)\(", line)
+        if m is None:
+            continue
+        if m.group(2) == "conditional":
+            faults.append(f"conditional: {line.strip()[:120]}")
+            continue
+        array = _ARRAY.match(m.group(1))  # none for a tuple's type
+        if array and _from_match(array).dims in shapes:
+            faults.append(f"copy of a leaf: {line.strip()[:120]}")
+    return faults

@@ -17,8 +17,9 @@ with ``ALLOW_MULTIPLE_LIBTPU_LOAD=1``.  Where no topology can be described
 the file skips.  The fused classic loop's program is held to the same
 rule at the end of the file (ISSUE 31), and the segment kernels at latent
 attention's head shape are compiled there at the learn cell's own sizes
-(ISSUE 32): this is the one tier-1 file that loads the TPU's compiler
-outside ``tests/benchmark``.
+(ISSUE 32), and the token learner's learn step is read for the copy of
+its train state that a post-hoc guard costs (ISSUE 33): this is the one
+tier-1 file that loads the TPU's compiler outside ``tests/benchmark``.
 """
 
 import functools
@@ -274,3 +275,65 @@ def test_segment_kernels_compile_at_latent_attentions_head_shape(one_chip):
     assert text.count("tpu_custom_call") == 3
     for name in ("segment_flash_fwd", "segment_flash_bwd_dq", "segment_flash_bwd_dkv"):
         assert name in text
+
+
+# -- the token learner's guard (ISSUE 33) ------------------------------------
+
+
+def small_token_learner():
+    """Two layers 256 wide, no kernel (the guard is the optimiser's tail,
+    whatever the attention); sizes at which no activation has a parameter
+    leaf's shape: 2 rows x 96 positions, vocabulary 384."""
+    from scalerl_tpu.agents.token_ppo import TokenPPOAgent
+    from scalerl_tpu.config import GenRLArguments
+    from scalerl_tpu.genrl.rollout import packed_field_shapes
+
+    rows, S = 2, 96
+    args = GenRLArguments(
+        vocab_size=384, d_model=256, n_layers=2, n_heads=4, prompt_len=32,
+        max_new_tokens=64, telemetry_interval_s=0.0, logger_backend="none",
+    )
+    model = TransformerPolicy(
+        num_actions=384, vocab_size=384, d_model=256, num_heads=4, num_layers=2, max_len=S,
+    )
+    batch = {
+        name: jnp.zeros((rows,) + shape, dtype)
+        for name, (shape, dtype) in packed_field_shapes(S).items()
+    }
+    return TokenPPOAgent(args, model), batch
+
+
+def test_token_learn_step_builds_no_candidate_beside_its_state(one_chip):
+    """With the state donated, the post-hoc guard's chosen branch was one
+    ``copy`` a leaf of parameters and both moments into the donated
+    buffers: 598 copies, 4.9 GB a step at gpt2-medium, the three largest
+    ``copy`` rows of ``gpt2m_packed_learn`` (PERF.md, PR 33).  The token
+    learner's own form has no ``conditional`` and copies no leaf; the
+    post-hoc form around the same update, which this learner had before,
+    must show both, or this test reads nothing."""
+    import dataclasses
+
+    from scalerl_tpu.agents.token_ppo import make_token_ppo_learn_fn
+    from scalerl_tpu.parallel.train_step import guard_nonfinite_updates
+    from scalerl_tpu.utils import tiled_layout
+
+    agent, batch = small_token_learner()
+    leaves = jax.tree_util.tree_leaves(agent.state.params)
+
+    def faults(learn_fn):
+        text = (
+            jax.jit(learn_fn, donate_argnums=(0,))
+            .lower(*_described((agent.state, batch), one_chip))
+            .compile().as_text()
+        )
+        return tiled_layout.candidate_state_faults(text, [x.shape for x in leaves])
+
+    found = faults(agent.make_learn_fn())
+    assert not found, (len(found), found[:2])
+
+    unguarded = make_token_ppo_learn_fn(
+        agent.model, agent.optimizer, dataclasses.replace(agent.args, nonfinite_guard=False)
+    )
+    found = faults(guard_nonfinite_updates(unguarded))
+    assert sum(f.startswith("conditional") for f in found) == 1, found[:2]
+    assert sum(f.startswith("copy") for f in found) >= 2 * len(leaves), len(found)

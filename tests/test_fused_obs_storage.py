@@ -293,3 +293,27 @@ ENTRY %main (a: u8[64,8,8,4]) -> u8[64,8,8,4] {
     assert faults[-1].startswith("relayout a loop trip: %copy.3")
     with pytest.raises(ValueError):
         tiled_layout.lane_dense_faults(text, "s8", 1, lane_dim=64)
+
+
+def test_candidate_state_faults_reads_a_guards_branch():
+    """A ``conditional`` and the copies of train-state leaves in its chosen
+    branch (the post-hoc guard under donation, PERF.md, PR 33); activations
+    and leaves a compiler stages in scalar memory are nobody's fault."""
+    from scalerl_tpu.utils import tiled_layout
+
+    text = """\
+%region_1.2 (p: (f32[1024,4096], f32[4096])) -> (f32[1024,4096], f32[4096]) {
+  %copy.1 = f32[1024,4096]{1,0:T(8,128)} copy(%get-tuple-element.1), backend_config={}
+  ROOT %copy.2 = f32[4096]{0:T(1024)} copy(%get-tuple-element.2)
+}
+
+ENTRY %main (a: f32[1024,4096]) -> f32[1024,4096] {
+  %copy.7 = f32[2,1024,1024]{2,1,0:T(8,128)} copy(%act)
+  %copy.8 = f32[1]{0:T(128)S(6)} copy(%state_params_value_head_bias)
+  %cond.5 = (f32[1024,4096]{1,0:T(8,128)}, f32[4096]{0:T(1024)}) conditional(%ok, %t, %t), branch_computations={%region_1.2, %region_3.4}
+}
+"""
+    leaves = [(1024, 4096), (4096,), (1,)]
+    faults = tiled_layout.candidate_state_faults(text, leaves)
+    assert [f.split(":")[0] for f in faults] == ["copy of a leaf", "copy of a leaf", "conditional"]
+    assert tiled_layout.candidate_state_faults(text, [(8, 8)]) == [faults[-1]]

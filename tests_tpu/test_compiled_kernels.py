@@ -838,3 +838,59 @@ def test_segment_flash_latent_head_shape_compiled(Dv, grad_tol):
     )(q, k, v)
     for a, b in zip(gk, gr):
         np.testing.assert_allclose(f32(a), f32(b), atol=grad_tol, rtol=grad_tol)
+
+
+@pytest.mark.parametrize("bf16", [False, True], ids=["f32", "bf16_params"])
+def test_token_learner_guard_decides_before_the_update_on_tpu(bf16):
+    """ISSUE 33: the packed token learner as the learn cells run it (one
+    chip's mesh, the state donated, the segment kernels), compiled by the
+    chip's own compiler: no ``conditional`` and no ``copy`` of a train-state
+    leaf (the post-hoc guard wrote its chosen candidate over the donated
+    buffers, 4.9 GB a step at gpt2-medium; PERF.md, PR 33).  Then on the
+    device: a stored logprob of -200 makes every gradient NaN under a
+    finite loss, the step is refused and the donated buffers hold the input
+    state bit for bit; the next step trains and leaves a finite state."""
+    from scalerl_tpu.agents.token_ppo import TokenPPOAgent
+    from scalerl_tpu.config import GenRLArguments
+    from scalerl_tpu.genrl.rollout import pack_learner_batch
+    from scalerl_tpu.parallel.train_step import tree_all_finite
+    from scalerl_tpu.trainer.sequence_rl import build_genrl_model
+    from scalerl_tpu.utils import tiled_layout
+
+    V, S, n = 640, 256, 8
+    args = GenRLArguments(
+        vocab_size=V, d_model=384, n_layers=2, n_heads=6, prompt_len=32,
+        max_new_tokens=32, learner_packing=True, learner_pack_len=S,
+        bf16_params=bf16, adv_norm=False, telemetry_interval_s=0.0,
+        logger_backend="none",
+    )
+    agent = TokenPPOAgent(args, build_genrl_model(args))
+    agent.enable_mesh(_mesh("dp=1", 1))
+    rng = np.random.default_rng(33)
+    plens, rlens = rng.integers(8, 33, n), rng.integers(8, 33, n)
+    pk = pack_learner_batch(
+        [rng.integers(1, V, k).astype(np.int32) for k in plens],
+        [rng.integers(1, V, k).astype(np.int32) for k in rlens],
+        [np.log(rng.uniform(0.05, 0.5, k)).astype(np.float32) for k in rlens],
+        [rng.normal(0, 0.1, k).astype(np.float32) for k in rlens],
+        rng.uniform(0, 1, n).astype(np.float32), np.zeros(n, np.int32), pack_len=S,
+    )
+    batch = {k: jnp.asarray(v) for k, v in pk.fields()[0].items()}
+
+    text = agent.lower_learn(batch).compile().as_text()
+    assert "tpu_custom_call" in text  # the segment kernels, as in the cells
+    leaves = jax.tree_util.tree_leaves(agent.state.params)
+    faults = tiled_layout.candidate_state_faults(text, [x.shape for x in leaves])
+    assert not faults, (len(faults), faults[:2])
+
+    bits = lambda tree: [np.asarray(x).tobytes() for x in jax.tree_util.tree_leaves(tree)]  # noqa: E731
+    before = bits(agent.state)
+    at = tuple(np.argwhere(np.asarray(batch["mask"]) > 0)[1])
+    m = agent.learn({**batch, "behavior_logp": batch["behavior_logp"].at[at].set(-200.0)})
+    assert np.isfinite(m["total_loss"]) and not np.isfinite(m["grad_norm"])
+    assert m["skipped_steps"] == 1.0 and m["nonfinite_grads"] == 1.0
+    assert bits(agent.state) == before
+    m = agent.learn(batch)
+    assert m["skipped_steps"] == 0.0 and int(agent.state.step) == 1
+    assert bits(agent.state.params) != before[: len(leaves)]
+    assert bool(tree_all_finite(agent.state))
