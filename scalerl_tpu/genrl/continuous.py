@@ -1332,10 +1332,11 @@ class ContinuousEngine(ParamSnapshotPlane):
         the temperature-0 token-identity contract.  Live lanes keep their
         shared pages (their own refs) until harvest — only the cache's
         index drops."""
-        with tracing.span("genrl.push_params", kind="genrl"):
+        with tracing.span("genrl.push_params", kind="genrl") as span:
             gen = super().push_params(params, learner_step, quantize)
             if self._prefix_cache is not None:
                 self._prefix_cache.flush()
+            span.set(**self.last_push)
         return gen
 
     # -- the macro-step --------------------------------------------------
@@ -1402,14 +1403,16 @@ class ContinuousEngine(ParamSnapshotPlane):
         occ = 0.0
         if self.live_lanes > 0:
             self._ensure_pages()
-            params, _gen = self._snapshot_params()
+            params, gen = self._snapshot_params()
             occ = self.live_lanes / self.config.lanes
             self._occupancy_gauge.set(occ)
             self._occupancy_sum += occ
             guard = steady_state_guard() if self._warm else nullcontext()
             with guard:
+                # ``generation``: the snapshot this macro-step decodes with,
+                # so a trace shows the first one on freshly pushed weights
                 with self._dispatch_guard(), tracing.span(
-                    "genrl.dispatch", kind="genrl"
+                    "genrl.dispatch", kind="genrl", generation=gen
                 ):
                     self._key, sub = jax.random.split(self._key)
                     # ONE explicit batched host->device upload per macro

@@ -80,6 +80,14 @@ def _tree_map(fn, tree):
     return fn(tree)
 
 
+def tree_size(tree) -> Tuple[int, int]:
+    """``(leaves, bytes)`` of a weight pytree, from shapes and dtypes alone
+    (host values: nothing is read from a device)."""
+    sizes: list = []
+    _tree_map(lambda leaf: sizes.append(int(getattr(leaf, "nbytes", 0))), tree)
+    return len(sizes), sum(sizes)
+
+
 class ParamSnapshotPlane:
     """Generation-tagged parameter snapshots, optionally quantized.
 
@@ -122,6 +130,8 @@ class ParamSnapshotPlane:
         self.generation = 0
         self._gen_steps: Dict[int, int] = {0: 0}
         self._latest_learner_step = 0
+        # what the newest push carried, for the spans around it
+        self.last_push: Dict[str, int] = {}
 
     def _place(self, snapshot: Any) -> Any:
         """Placement hook: identity here; sharded consumers re-place the
@@ -145,12 +155,14 @@ class ParamSnapshotPlane:
             from scalerl_tpu.runtime.quantize import quantize_tree
 
             snapshot, qsnap = None, quantize_tree(params, quantize)
+        leaves, size = tree_size(params)
         with self._param_lock:
             self.generation += 1
             gen = self.generation
             self._params = snapshot
             self._quantized = qsnap
             self._record_step(gen, learner_step)
+            self.last_push = {"bytes": size, "leaves": leaves, "generation": gen}
             return gen
 
     def _record_step(self, gen: int, learner_step: Optional[int]) -> None:

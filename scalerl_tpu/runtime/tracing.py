@@ -38,8 +38,15 @@ substrate, in the telemetry idiom:
   ``utils/profiling.py`` installs ``jax.profiler.TraceAnnotation``), so a
   device trace carries the program's own phases on the profiler's clock,
   and records the :class:`Span` too when the trace's root was sampled.
-  With the profiler stopped and sampling at 0 it is one annotation object
-  made and dropped.
+  Whatever the profiler and the sampler do, a live span **always knows
+  how long it took**: enter and exit read the monotonic clock, and the
+  registry keeps per span name a counter pair ``span.<name>.count`` /
+  ``span.<name>.seconds`` (:func:`span_totals`; two snapshots subtract).
+  A span that took more than ``SLOW_FACTOR`` times its name's running
+  level and at least ``SLOW_MIN_S`` records ONE ``slow_span`` flight
+  event (:func:`slow_spans`): the names open above it, its direct
+  children's shares, the thread's CPU seconds and the garbage
+  collector's pauses inside it.
 - **Per-host JSONL export** — when ``SCALERL_TRACE_DIR`` is set every
   finished span is appended (line-buffered) to
   ``spans_<host>.jsonl``, so a SIGTERM'd generation host loses at most the
@@ -57,6 +64,7 @@ forensics link both ways.
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import random
@@ -229,6 +237,14 @@ def _context_of(parent: Any) -> Optional[SpanContext]:
     return SpanContext.from_wire(parent)
 
 
+class _ActiveStack(threading.local):
+    """Per-thread stack of active recorded spans; ``None`` on a thread
+    that never activated one (a class default: reading it costs no
+    raised-and-caught AttributeError, which every live span would pay)."""
+
+    stack: Optional[List[Any]] = None
+
+
 class Tracer:
     """Head-sampling span factory with a bounded finished-span ring and an
     optional per-host JSONL sink (``SCALERL_TRACE_DIR``)."""
@@ -253,7 +269,10 @@ class Tracer:
         self.dropped = 0
         self._sink = None
         self._sink_path: Optional[str] = None
-        self._tls = threading.local()
+        self._tls = _ActiveStack()
+        # has any thread ever made a span or a context active?  Until then
+        # no live span has a recorded parent to look for
+        self.activated = False
         self._rng = random.Random(os.urandom(8))
         self._listeners: List[Callable[[Dict[str, Any]], None]] = []
 
@@ -349,18 +368,19 @@ class Tracer:
 
     # -- active-span stack (FlightRecorder linkage) ---------------------
     def _push_active(self, span: Span) -> None:
-        stack = getattr(self._tls, "stack", None)
+        self.activated = True
+        stack = self._tls.stack
         if stack is None:
             stack = self._tls.stack = []
         stack.append(span)
 
     def _pop_active(self, span: Span) -> None:
-        stack = getattr(self._tls, "stack", None)
+        stack = self._tls.stack
         if stack and stack[-1] is span:
             stack.pop()
 
     def current_span(self):
-        stack = getattr(self._tls, "stack", None)
+        stack = self._tls.stack
         return stack[-1] if stack else None
 
     def activate(self, parent: Any):
@@ -637,56 +657,121 @@ def get_annotator() -> Optional[Callable[[str], Any]]:
     return _ANNOTATOR
 
 
+# ---------------------------------------------------------------------------
+# always-on span accounting: totals per name and the slow-span event
+
+SPAN_TOTALS_PREFIX = "span."
+# a span is slow when it took more than SLOW_FACTOR times its name's
+# running level and at least SLOW_MIN_S: constants, not knobs
+SLOW_FACTOR = 4.0
+SLOW_MIN_S = 0.25
+SLOW_EVENT = "slow_span"
+# a name's first occurrences build programs (a compile is not a stall):
+# they are neither judged nor let into the level
+_LEVEL_SKIP = 2
+# the spans that block on the device: these and the roots carry a stamp of
+# the thread's CPU clock, so that a slow one says whether the host worked
+# or waited.  That clock is a real system call (5 us on the v5e's host,
+# PERF.md PR 34), so a thread reads it at most once in _CPU_STAMP_EVERY_S
+# and a span takes the thread's newest stamp: the CPU seconds it reports
+# are since a moment ``cpu_slack_s`` before it began
+_BLOCKING = frozenset({"genrl.read", "dispatch.read"})
+_CPU_STAMP_EVERY_S = 0.02
+
+_monotonic = time.monotonic
+_thread_time = time.thread_time
+
+
+class _LiveStack(threading.local):
+    stack: Optional[List[Any]] = None  # the live spans open on this thread
+    cpu_s = 0.0  # the thread's CPU clock when it was last read,
+    cpu_at = float("-inf")  # and when that was, on the monotonic clock
+
+
+_LIVE = _LiveStack()
+
+
+class _GcClock:
+    """The garbage collector's recent collections as ``(start, seconds)``
+    on the monotonic clock, from ``gc.callbacks``: nothing runs between
+    collections and a span stamps nothing; a slow span counts the
+    collections that began inside it (of the last 4,096)."""
+
+    __slots__ = ("recent", "_t0")
+
+    def __init__(self) -> None:
+        self.recent: Deque[Any] = deque(maxlen=4096)
+        self._t0 = 0.0
+
+    def __call__(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._t0 = _monotonic()
+        else:
+            self.recent.append((self._t0, _monotonic() - self._t0))
+
+    def inside(self, t0: float, t1: float):
+        """``(collections, pause seconds)`` that began in ``[t0, t1]``."""
+        pauses = [took for began, took in list(self.recent) if t0 <= began <= t1]
+        return len(pauses), sum(pauses)
+
+
+_GC = _GcClock()
+gc.callbacks.append(_GC)
+
+
+class _NameStat:
+    """One span name: the registry's counter pair and the running level
+    its next occurrence is compared with (``_LiveSpan.__exit__`` keeps
+    both, inline: a call is a fifth of a span's budget)."""
+
+    __slots__ = ("registry", "count", "seconds", "add_one", "add_seconds", "seen", "level")
+
+    def __init__(self, name: str) -> None:
+        reg = telemetry.get_registry()
+        self.registry = reg
+        self.count = reg.counter(f"{SPAN_TOTALS_PREFIX}{name}.count")
+        self.seconds = reg.counter(f"{SPAN_TOTALS_PREFIX}{name}.seconds")
+        self.add_one, self.add_seconds = self.count.inc, self.seconds.inc
+        self.seen = 0
+        self.level: Optional[float] = None
+
+
+_STATS: Dict[str, _NameStat] = {}
+
+
+def _stat_for(name: str) -> _NameStat:
+    # a fresh registry (telemetry.reset, tests) starts every name anew
+    stat = _STATS[name] = _NameStat(name)
+    return stat
+
+
+def span_totals() -> Dict[str, Dict[str, float]]:
+    """``{name: {"count": n, "seconds": s}}`` of every live span name that
+    has ended in this process, from the registry's counter pairs.  Two
+    snapshots subtract: the spans that ended between them."""
+    reg = telemetry._REGISTRY
+    return {
+        name: {"count": stat.count.value, "seconds": stat.seconds.value}
+        for name, stat in list(_STATS.items())
+        if stat.registry is reg
+    }
+
+
+def slow_spans(
+    since: Optional[float] = None, until: Optional[float] = None
+) -> List[Dict[str, Any]]:
+    """The ``slow_span`` events the flight recorder still holds, oldest
+    first; with ``since``/``until`` (``time.monotonic()`` stamps) those
+    that BEGAN inside that interval."""
+    return [
+        e for e in telemetry.get_recorder().events(SLOW_EVENT)
+        if (since is None or e["t_start"] >= since)
+        and (until is None or e["t_start"] <= until)
+    ]
+
+
 class _LiveSpan:
-    """What :func:`span` returns: one ``with`` block, two sinks."""
-
-    __slots__ = ("_name", "_kind", "_attrs", "_annotation", "_span")
-
-    def __init__(self, name: str, kind: str, attrs: Dict[str, Any]) -> None:
-        self._name = name
-        self._kind = kind
-        self._attrs = attrs
-        self._annotation = None
-        self._span = None
-
-    def __enter__(self) -> "_LiveSpan":
-        if _ANNOTATOR is not None:
-            self._annotation = _ANNOTATOR(ANNOTATION_PREFIX + self._name)
-            self._annotation.__enter__()
-        tracer = get_tracer()
-        parent = tracer.current_span()
-        if parent is None and tracer.sample_rate <= 0.0:
-            return self  # the hot loops' case: nothing else happens
-        # the rule start_span has: head decision at the root, children
-        # follow the span active on this thread (an unsampled root is
-        # active too, so that its children stay unsampled)
-        span = (
-            NOOP_SPAN
-            if parent is NOOP_SPAN
-            else tracer.start_span(
-                self._name, parent=parent, kind=self._kind, **self._attrs
-            )
-        )
-        tracer._push_active(span)
-        self._span = span
-        return self
-
-    def set(self, **attrs: Any) -> None:
-        """Attributes known only at the block's end (host values only)."""
-        if self._span is not None and self._span.sampled:
-            self._span.attrs.update(attrs)
-
-    def __exit__(self, *exc: Any) -> None:
-        span = self._span
-        if span is not None:
-            get_tracer()._pop_active(span)
-            span.end()
-        if self._annotation is not None:
-            self._annotation.__exit__(*exc)
-
-
-def span(name: str, kind: str = "", **attrs: Any) -> _LiveSpan:
-    """A live span around work as it happens::
+    """``tracing.span``: a live span around work as it happens::
 
         with tracing.span("genrl.read", kind="genrl"):
             host = _device_get(outputs)
@@ -694,9 +779,142 @@ def span(name: str, kind: str = "", **attrs: Any) -> _LiveSpan:
     Entering it opens the profiler annotation ``"scalerl." + name`` (a
     no-op object unless the profiler runs) and, when this thread's trace
     was sampled at its root, records the :class:`Span` as a child of
-    :func:`current_span`.  Host-side stamps only: never force a device
-    value to open, annotate or close a span (graftlint JG001)."""
-    return _LiveSpan(name, kind, attrs)
+    :func:`current_span`.  Whatever those two do, its duration lands in
+    :func:`span_totals`, and a slow one in :func:`slow_spans`.  Host-side
+    stamps only: never force a device value to open, annotate or close a
+    span (graftlint JG001)."""
+
+    __slots__ = (
+        "_name", "_kind", "_attrs", "_late", "_annotation", "_span", "_t0",
+        "_cpu0", "_cpu_at", "_kids",
+    )
+
+    def __init__(self, name: str, kind: str = "", **attrs: Any) -> None:
+        self._name = name
+        self._kind = kind
+        self._attrs = attrs
+        self._late: Optional[Dict[str, Any]] = None  # what set() was handed
+        self._annotation = None
+        self._span = None
+        self._kids: Optional[List[Any]] = None  # (name, seconds) per direct child
+
+    def __enter__(self) -> "_LiveSpan":
+        if _ANNOTATOR is not None:
+            self._annotation = _ANNOTATOR(ANNOTATION_PREFIX + self._name)
+            self._annotation.__enter__()
+        live = _LIVE
+        stack = live.stack
+        if stack is None:
+            stack = live.stack = []
+        t0 = self._t0 = _monotonic()
+        if not stack or self._name in _BLOCKING:
+            if t0 - live.cpu_at > _CPU_STAMP_EVERY_S:
+                live.cpu_s, live.cpu_at = _thread_time(), t0
+            self._cpu0, self._cpu_at = live.cpu_s, live.cpu_at
+        else:
+            self._cpu0 = None
+        stack.append(self)
+        tracer = _TRACER or get_tracer()
+        if tracer.sample_rate > 0.0 or tracer.activated:
+            # the recorded half.  The rule start_span has: head decision at
+            # the root, children follow the span active on this thread (an
+            # unsampled root is active too, so that its children stay
+            # unsampled)
+            parent = tracer.current_span()
+            if parent is not None or tracer.sample_rate > 0.0:
+                span = (
+                    NOOP_SPAN
+                    if parent is NOOP_SPAN
+                    else tracer.start_span(
+                        self._name, parent=parent, kind=self._kind, **self._attrs
+                    )
+                )
+                tracer._push_active(span)
+                self._span = span
+        return self
+
+    def set(self, **attrs: Any) -> None:
+        """Attributes known only at the block's end (host values only).
+        Kept as handed in (no copy: four of a macro-step's five spans are
+        children that call this once); a slow span's event merges them."""
+        if self._late is None:
+            self._late = attrs
+        else:
+            self._late.update(attrs)
+        if self._span is not None and self._span.sampled:
+            self._span.attrs.update(attrs)
+
+    def __exit__(self, exc_type: Any = None, exc: Any = None, tb: Any = None) -> None:
+        t1 = _monotonic()
+        dur = t1 - self._t0
+        name = self._name
+        stack = _LIVE.stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        stat = _STATS.get(name)
+        if stat is None or stat.registry is not telemetry._REGISTRY:
+            stat = _stat_for(name)
+        stat.add_one()
+        stat.add_seconds(dur)
+        if stack:
+            above = stack[-1]
+            if above._kids is None:
+                above._kids = [(name, dur)]
+            else:
+                above._kids.append((name, dur))
+        # the name's running level: its first occurrences build programs
+        # and are neither judged nor let in; after them the level falls
+        # quickly and rises slowly, by a twentieth of itself at most, so
+        # that one stall does not hide the next
+        seen = stat.seen
+        stat.seen = seen + 1
+        if seen >= _LEVEL_SKIP:
+            level = stat.level
+            if level is None:
+                stat.level = dur
+            elif dur < level:
+                stat.level = level + 0.25 * (dur - level)
+            else:
+                stat.level = level + 0.05 * ((dur if dur < 2.0 * level else 2.0 * level) - level)
+                if dur >= SLOW_MIN_S and dur > SLOW_FACTOR * level:
+                    self._record_slow(stack, t1, dur, level)
+        span = self._span
+        if span is not None:
+            get_tracer()._pop_active(span)
+            span.end()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+
+    def _record_slow(
+        self, stack: List["_LiveSpan"], t1: float, dur: float, level: float
+    ) -> None:
+        """ONE flight event; nothing is dumped, nothing interrupts."""
+        held: Dict[str, float] = {}
+        for child, took in self._kids or ():
+            held[child] = held.get(child, 0.0) + took
+        collections, pause_s = _GC.inside(self._t0, t1)
+        event = {
+            "name": self._name,
+            "above": [s._name for s in stack],
+            "t_start": self._t0,
+            "t_end": t1,
+            "dur_s": dur,
+            "level_s": level,
+            "children": {c: took / dur for c, took in held.items()},
+            # None: neither a root nor a blocking read, so not stamped
+            "cpu_s": None if self._cpu0 is None else _thread_time() - self._cpu0,
+            "cpu_slack_s": None if self._cpu0 is None else self._t0 - self._cpu_at,
+            "gc_collections": collections,
+            "gc_pause_s": pause_s,
+            "attrs": {**self._attrs, **(self._late or {})},
+        }
+        telemetry.record_event(SLOW_EVENT, **event)
+        logger.warning("slow span: %s", json.dumps(event, default=str))
+
+
+# ``tracing.span(name, kind="", **attrs)``: the class itself, so that
+# opening a span is one call
+span = _LiveSpan
 
 
 def current_trace_id() -> Optional[str]:

@@ -254,13 +254,15 @@ class SequenceRLTrainer:
             return self._mesh_lock
         return nullcontext()
 
-    def _round(self):
-        """One round's generation: keep the lane pool fed, then pack exactly
-        ``genrl_batch`` finished sequences (macro-steps that overshoot bank
-        their extras in the backlog — insert batches stay shape-stable)."""
+    def _generate(self) -> List[Any]:
+        """One round's generation: keep the lane pool fed until exactly
+        ``genrl_batch`` sequences have finished (macro-steps that overshoot
+        bank their extras in the backlog: insert batches stay
+        shape-stable).  Engine cycles only; the packing is ``round.pack``."""
         B = self.args.genrl_batch
         spp = self.args.samples_per_prompt
         with tracing.span("round.generate", kind="genrl") as gen_span:
+            macro_steps, groups = self.engine.macro_steps, 0
             while len(self._completion_backlog) < B:
                 deficit = (
                     B
@@ -278,13 +280,27 @@ class SequenceRLTrainer:
                     )
                     for i in range(n_groups):
                         self.engine.submit_group(prompts[i], spp, lengths[i])
+                    groups += n_groups
                 self._completion_backlog.extend(self.engine.step())
             batch = self._completion_backlog[:B]
             self._completion_backlog = self._completion_backlog[B:]
+            gen_span.set(
+                macro_steps=self.engine.macro_steps - macro_steps,
+                groups_submitted=groups,
+                decode_tokens=float(
+                    sum(len(c.response_tokens) for c in batch)
+                ),
+            )
+        return batch
+
+    def _round(self):
+        """Generate, then pack and score on the host: the insert triple,
+        the rewards and the round's decode tokens."""
+        batch = self._generate()
+        with tracing.span("round.pack", kind="genrl"):
             packed = pack_completions(
                 batch, self._prompt_pad, self._response_pad
             )
-            gen_span.set(decode_tokens=float(packed.decode_tokens))
         with tracing.span("round.score", kind="genrl"):
             rewards = self.task.score(
                 packed.prompts,
@@ -292,27 +308,31 @@ class SequenceRLTrainer:
                 packed.response_tokens,
                 packed.response_len,
             )
-        if self.packing:
-            pk = packed_rows_from_completions(
-                packed, rewards, self._pack_len
+        with tracing.span("round.pack", kind="genrl"):
+            if self.packing:
+                pk = packed_rows_from_completions(
+                    packed, rewards, self._pack_len
+                )
+                fields, priorities, decode = _bucketed_rows(
+                    pk, self._row_buckets, self._pad_gauge
+                )
+                return fields, priorities, rewards, decode
+            self._pad_gauge.set(
+                1.0
+                - (packed.prompt_len.sum() + packed.mask.sum())
+                / max(packed.sequences.size, 1)
             )
-            fields, priorities, decode = _bucketed_rows(
-                pk, self._row_buckets, self._pad_gauge
-            )
-            return fields, priorities, rewards, decode
-        self._pad_gauge.set(
-            1.0
-            - (packed.prompt_len.sum() + packed.mask.sum())
-            / max(packed.sequences.size, 1)
-        )
-        fields, priorities = packed.fields(rewards)
-        return fields, priorities, rewards, packed.decode_tokens
+            fields, priorities = packed.fields(rewards)
+            return fields, priorities, rewards, packed.decode_tokens
 
     def train_round(self) -> Dict[str, float]:
         """One generate -> score -> insert -> sample -> learn round."""
         # one live span per phase (runtime/tracing.span): each is a profiler
-        # annotation, and a recorded span when the round was head-sampled
-        # (SCALERL_TRACE_SAMPLE); none forces a device value (JG001)
+        # annotation, a recorded span when the round was head-sampled
+        # (SCALERL_TRACE_SAMPLE) and always a count and a total in the
+        # registry (tracing.span_totals); none forces a device value
+        # (JG001).  The phases tile the round: what the root holds beside
+        # them is the bookkeeping at its end
         with tracing.span("genrl.round", kind="genrl") as root:
             fields, priorities, rewards, decode_tokens = self._round()
             with self._dispatch_guard():
@@ -336,26 +356,27 @@ class SequenceRLTrainer:
                 # learner_step feeds the plane's gen -> step map, so
                 # staleness below reports the UNIFIED definition (learner
                 # steps behind the newest generation, docs/OBSERVABILITY.md)
-                with tracing.span("round.push", kind="genrl"):
+                with tracing.span("round.push", kind="genrl") as push:
                     self.engine.push_params(
                         self.agent.get_weights(), learner_step=self.learn_steps
                     )
-            root.set(step=self.learn_steps)
-        # staleness off the metric that already crossed the host boundary
-        # inside the batched read — no extra transfer
-        staleness = self.engine.staleness_steps(
-            int(round(metrics["mean_generation"]))
-        )
-        self._stale_gauge.set(staleness)
-        telemetry.observe_staleness(staleness, plane="genrl")
-        mean_reward = float(np.mean(rewards))
-        self._reward_gauge.set(mean_reward)
-        if "kl_ref" in metrics:
-            self._kl_gauge.set(metrics["kl_ref"])
-        metrics["round_reward"] = mean_reward
-        metrics["staleness"] = staleness
-        metrics["decode_tokens"] = float(decode_tokens)
-        self.reward_history.append(mean_reward)
+                    push.set(**self.engine.last_push)
+            # staleness off the metric that already crossed the host
+            # boundary inside the batched read: no extra transfer
+            staleness = self.engine.staleness_steps(
+                int(round(metrics["mean_generation"]))
+            )
+            self._stale_gauge.set(staleness)
+            telemetry.observe_staleness(staleness, plane="genrl")
+            mean_reward = float(np.mean(rewards))
+            self._reward_gauge.set(mean_reward)
+            if "kl_ref" in metrics:
+                self._kl_gauge.set(metrics["kl_ref"])
+            metrics["round_reward"] = mean_reward
+            metrics["staleness"] = staleness
+            metrics["decode_tokens"] = float(decode_tokens)
+            self.reward_history.append(mean_reward)
+            root.set(step=self.learn_steps, staleness=staleness)
         return metrics
 
     def lowered_programs(self) -> Dict[str, Any]:

@@ -19,9 +19,9 @@ from scalerl_tpu.runtime import telemetry, tracing
 from scalerl_tpu.trainer.sequence_rl import SequenceRLTrainer
 
 ROOT = Path(__file__).resolve().parent.parent
-PHASES = [
-    "round.generate", "round.score", "round.seq_add", "round.sample",
-    "round.learn", "round.push",
+PHASES = [  # host packing before and after the score: two ``round.pack`` a round
+    "round.generate", "round.pack", "round.score", "round.pack", "round.seq_add",
+    "round.sample", "round.learn", "round.push",
 ]
 
 
@@ -44,8 +44,9 @@ def recorded():
             genrl_macro_steps=2,
         ))
         tracer = tracing.get_tracer()
+        before = tracing.span_totals()
         trainer.train_round()
-        out = {"round": tracer.finished()}
+        out = {"round": tracer.finished(), "totals": (before, tracing.span_totals())}
         tracer.clear()
         prompts, lengths = trainer.task.sample_prompts(2, np.random.default_rng(0))
         for i in range(2):
@@ -104,10 +105,11 @@ def test_a_learn_step_records_its_dispatch_and_its_one_read(recorded):
     assert dispatch["t0"] + dispatch["dur"] <= read["t0"] + 1e-6
 
 
-def test_a_round_records_six_phases_that_do_not_overlap_and_cover_the_push(recorded):
+def test_a_round_records_its_phases_that_do_not_overlap_and_cover_the_push(recorded):
     records = recorded["round"]
     (root,) = _named(records, "genrl.round")
     assert root["parent"] is None and root["attrs"]["step"] == 1
+    assert root["attrs"]["staleness"] == 1.0  # the bookkeeping is inside the root
     phases = _children(records, root)
     assert [p["name"] for p in phases] == PHASES  # in the order they ran
     end = root["t0"]
@@ -119,11 +121,41 @@ def test_a_round_records_six_phases_that_do_not_overlap_and_cover_the_push(recor
     # the engine's cycles hang under the generate phase, the learner's step
     # under the learn phase, the snapshot placement under the push
     by_name = {p["name"]: p for p in phases}
+    generate, push = by_name["round.generate"]["attrs"], by_name["round.push"]["attrs"]
+    assert generate["groups_submitted"] == 2 and generate["macro_steps"] >= 2
+    assert push["generation"] == 1 and push["leaves"] > 0 and push["bytes"] > 4 * push["leaves"]
+    (placed,) = _named(records, "genrl.push_params")
+    assert placed["attrs"] == push
     parents = lambda name: {r["parent"] for r in _named(records, name)}  # noqa: E731
     assert parents("genrl.macro_step") == {by_name["round.generate"]["span"]}
     assert parents("learn.step") == {by_name["round.learn"]["span"]}
     assert parents("genrl.push_params") == {by_name["round.push"]["span"]}
     assert by_name["round.generate"]["attrs"]["decode_tokens"] > 0
+
+
+def test_the_round_s_children_cover_it_and_the_totals_say_so(recorded):
+    """The phases tile the round: the root's self time (its duration less
+    its children) is the bookkeeping at its end.  The same from the
+    always-on totals, which need neither the sampler nor the profiler."""
+    records = recorded["round"]
+    (root,) = _named(records, "genrl.round")
+    covered = sum(p["dur"] for p in _children(records, root))
+    assert 0.0 <= root["dur"] - covered < 0.01 * root["dur"] + 2e-3
+    before, after = recorded["totals"]
+    took = lambda name: after[name]["seconds"] - before.get(name, {"seconds": 0.0})["seconds"]  # noqa: E731
+    count = lambda name: after[name]["count"] - before.get(name, {"count": 0.0})["count"]  # noqa: E731
+    assert count("genrl.round") == 1 and count("round.pack") == 2 and count("round.push") == 1
+    assert took("genrl.round") == pytest.approx(root["dur"], abs=1e-3)
+    phases = sum(took(name) for name in set(PHASES))
+    assert 0.0 <= took("genrl.round") - phases < 0.01 * took("genrl.round") + 2e-3
+    assert count("genrl.macro_step") == _named(records, "round.generate")[0]["attrs"]["macro_steps"]
+
+
+def test_a_dispatch_carries_the_generation_of_the_snapshot_it_ran_with(recorded):
+    """The round pushed generation 1 at its end: its own macro-steps ran on
+    generation 0, the engine cycles after it on 1."""
+    assert {d["attrs"]["generation"] for d in _named(recorded["round"], "genrl.dispatch")} == {0}
+    assert {d["attrs"]["generation"] for d in _named(recorded["engine"], "genrl.dispatch")} == {1}
 
 
 @pytest.fixture(scope="module")

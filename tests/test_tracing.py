@@ -559,7 +559,162 @@ def test_trace_report_charges_every_new_span_name():
     compute = {
         "genrl.macro_step", "genrl.admit", "genrl.dispatch", "genrl.harvest", "genrl.push_params",
         "seq.draft", "seq.verify", "learn.step", "learn.dispatch", "loop.dispatch",
-        "round.generate", "round.score", "round.seq_add", "round.sample", "round.learn", "round.push",
+        "round.generate", "round.pack", "round.score", "round.seq_add", "round.sample",
+        "round.learn", "round.push",
     }
     assert {trace_report.classify(n) for n in waits} == {"wait"}
     assert {trace_report.classify(n) for n in compute} == {"compute"}
+
+
+# ---------------------------------------------------------------------------
+# always on: a live span's own duration, whatever the profiler and the
+# sampler do (ISSUE 34)
+
+
+@pytest.fixture
+def span_clock(monkeypatch):
+    """The monotonic clock the live spans read, moved by hand."""
+    monkeypatch.delenv(tracing.ENV_SAMPLE, raising=False)
+    tracing.reset()
+    now = [100.0]
+    monkeypatch.setattr(tracing, "_monotonic", lambda: now[0])
+
+    def run(name, took, children=(), **late):
+        with tracing.span(name, kind="test", lanes=4) as live:
+            for child, child_took in children:
+                run(child, child_took)
+            now[0] += took
+            if late:
+                live.set(**late)
+
+    return run
+
+
+def test_the_tracer_s_clock_is_the_one_the_benchmark_reads():
+    """``harness.Context.t_open`` is ``time.perf_counter()``; a span's
+    ``t_start`` is ``time.monotonic()``: one clock on Linux, so a reader
+    can place a span in the measured window."""
+    assert (
+        time.get_clock_info("monotonic").implementation
+        == time.get_clock_info("perf_counter").implementation
+    )
+    assert abs(time.monotonic() - time.perf_counter()) < 1e-3
+
+
+def test_count_and_total_with_the_profiler_stopped_and_sampling_at_zero(span_clock):
+    span_clock("genrl.macro_step", 0.015, children=[("genrl.read", 0.010)])
+    first = tracing.span_totals()
+    assert first["genrl.macro_step"] == {"count": 1.0, "seconds": pytest.approx(0.025)}
+    for _ in range(3):
+        span_clock("genrl.macro_step", 0.002, children=[("genrl.read", 0.010), ("genrl.read", 0.001)])
+    second = tracing.span_totals()
+    # two snapshots subtract: the spans that ended between them
+    assert second["genrl.macro_step"]["count"] - first["genrl.macro_step"]["count"] == 3
+    assert second["genrl.macro_step"]["seconds"] - first["genrl.macro_step"]["seconds"] == pytest.approx(0.039)
+    assert second["genrl.read"] == {"count": 7.0, "seconds": pytest.approx(0.043)}
+    # they are the registry's own counters: an operator's snapshot has them
+    tree = telemetry.get_registry().snapshot()["span"]["genrl"]
+    assert tree["read"] == {"count": 7.0, "seconds": pytest.approx(0.043)}
+    assert tracing.get_tracer().finished() == [] and tracing.slow_spans() == []
+    telemetry.reset()  # a fresh registry starts every name anew
+    assert tracing.span_totals() == {}
+    span_clock("genrl.read", 0.5)
+    assert tracing.span_totals() == {"genrl.read": {"count": 1.0, "seconds": pytest.approx(0.5)}}
+
+
+@pytest.mark.parametrize("took,slow", [(0.27, False), (0.37, True), (2.6, True)])
+def test_a_span_is_slow_past_four_times_its_level_and_a_quarter_second(span_clock, took, slow):
+    span_clock("loop.chunk", 30.0)  # the first occurrences compile: not judged,
+    span_clock("loop.chunk", 12.0)  # and kept out of the level
+    for _ in range(6):
+        span_clock("loop.chunk", 0.09)
+    assert tracing.slow_spans() == []
+    span_clock("loop.chunk", took)  # 3 x the level is no stall; 4.1 x is
+    events = tracing.slow_spans()
+    assert len(events) == (1 if slow else 0)
+    if slow:
+        assert events[0]["level_s"] == pytest.approx(0.09) and events[0]["dur_s"] == pytest.approx(took)
+        # one stall does not hide the next: it raised the level by a twentieth
+        assert tracing._STATS["loop.chunk"].level == pytest.approx(0.09 * 1.05)
+        span_clock("loop.chunk", 1.06 * took)
+        assert len(tracing.slow_spans()) == 2
+
+
+def test_a_fast_span_is_never_slow_however_far_above_its_level(span_clock):
+    for _ in range(8):
+        span_clock("genrl.admit", 0.0002)
+    span_clock("genrl.admit", 0.2)  # a thousand times its level, under the quarter second
+    assert tracing.slow_spans() == []
+
+
+def test_a_slow_span_says_what_held_it(span_clock):
+    for _ in range(5):
+        span_clock("genrl.macro_step", 0.002, children=[("genrl.admit", 0.001), ("genrl.read", 0.012)])
+    with tracing.span("round.generate", kind="genrl"):
+        span_clock(
+            "genrl.macro_step", 0.1, in_flight=2,
+            children=[("genrl.admit", 0.001), ("genrl.read", 1.0), ("genrl.read", 0.899)],
+        )
+    events = tracing.slow_spans()  # in the order they ended: both reads, then the step
+    assert [e["name"] for e in events] == ["genrl.read", "genrl.read", "genrl.macro_step"]
+    by_name = {e["name"]: e for e in events}
+    step = by_name["genrl.macro_step"]
+    assert step["above"] == ["round.generate"] and by_name["genrl.read"]["above"] == [
+        "round.generate", "genrl.macro_step",
+    ]
+    assert step["dur_s"] == pytest.approx(2.0) and step["level_s"] == pytest.approx(0.015)
+    assert step["children"] == {"genrl.admit": pytest.approx(0.0005), "genrl.read": pytest.approx(0.9495)}
+    assert step["attrs"] == {"lanes": 4, "in_flight": 2}
+    assert step["t_end"] - step["t_start"] == pytest.approx(2.0)
+    assert step["cpu_s"] is None  # neither a root nor a blocking read: not stamped
+    assert by_name["genrl.read"]["cpu_s"] is not None
+    assert step["kind"] == tracing.SLOW_EVENT and step in telemetry.get_recorder().events()
+    # the query by when a span began
+    assert [e["name"] for e in tracing.slow_spans(since=step["t_start"] + 1e-6)] == ["genrl.read"] * 2
+    assert [e["name"] for e in tracing.slow_spans(until=step["t_start"])] == ["genrl.macro_step"]
+    assert tracing.slow_spans(since=step["t_end"] + 1.0) == []
+
+
+def _slow_root(body):
+    """A root span made slow by ``body`` after five quick ones; its event."""
+    import gc
+
+    for _ in range(5):
+        with tracing.span("learn.step", kind="learn"):
+            with tracing.span("dispatch.read", kind="dispatch"):
+                time.sleep(0.002)
+    gc.collect()
+    with tracing.span("learn.step", kind="learn"):
+        with tracing.span("dispatch.read", kind="dispatch"):
+            body()
+    events = {e["name"]: e for e in tracing.slow_spans()}
+    assert set(events) == {"learn.step", "dispatch.read"}
+    return events
+
+
+def test_a_span_blocked_in_a_wait_burns_no_cpu(monkeypatch):
+    monkeypatch.delenv(tracing.ENV_SAMPLE, raising=False)
+    tracing.reset()
+    events = _slow_root(lambda: time.sleep(0.3))
+    for event in events.values():
+        assert event["dur_s"] >= 0.3 and event["cpu_s"] < 0.05, event
+    assert events["learn.step"]["children"]["dispatch.read"] > 0.95
+    assert events["learn.step"]["gc_collections"] == 0
+
+
+def test_a_span_held_by_the_host_burns_its_duration_in_cpu_and_counts_collections(monkeypatch):
+    import gc
+
+    monkeypatch.delenv(tracing.ENV_SAMPLE, raising=False)
+    tracing.reset()
+
+    def busy():
+        gc.collect()
+        until = time.perf_counter() + 0.3
+        while time.perf_counter() < until:
+            sum(range(100))
+
+    events = _slow_root(busy)
+    for event in events.values():
+        assert event["cpu_s"] > 0.6 * event["dur_s"] > 0.18, event
+        assert event["gc_collections"] >= 1 and 0.0 < event["gc_pause_s"] < event["dur_s"]

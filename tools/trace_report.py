@@ -77,6 +77,7 @@ EDGE_CLASSES = {
     "seq.draft": "compute",
     "seq.verify": "compute",
     "round.generate": "compute",
+    "round.pack": "compute",
     "round.score": "compute",
     "round.seq_add": "compute",
     "round.sample": "compute",
