@@ -289,6 +289,13 @@ class CompletedSequence(NamedTuple):
     tag: Any = None
 
 
+def _snapshot_overwrite(new, old):
+    """A copy of ``new``; jitted with ``old`` donated, its outputs take
+    ``old``'s buffers (the name is the device program's in a trace)."""
+    del old
+    return jax.tree_util.tree_map(jnp.copy, new)
+
+
 @dataclass
 class _Lane:
     """Host-side record of one lane's current occupancy."""
@@ -346,6 +353,15 @@ class ContinuousEngine(ParamSnapshotPlane):
         # snapshots all live on this one device, whatever mesh the learner
         # shards its own copy over (_place re-places every push)
         self._device = jax.devices()[0]
+        # a push's copy written over the retired snapshot (_copy_snapshot):
+        # ``old`` is donated, and kept though unread; a donated argument
+        # the function does not use is otherwise pruned before donation
+        self._copy_over = jax.jit(
+            _snapshot_overwrite,
+            donate_argnums=1,
+            keep_unused=True,
+            out_shardings=jax.sharding.SingleDeviceSharding(self._device),
+        )
         self._paged_attn = make_paged_attn_fn(
             config.paged_attn, model.block.attention
         )
@@ -1304,6 +1320,45 @@ class ContinuousEngine(ParamSnapshotPlane):
         paged-attention kernel cannot be partitioned."""
         return jax.device_put(snapshot, self._device)
 
+    def _copy_snapshot(self, params: Any) -> Tuple[Any, int, bool]:
+        """A push's snapshot copy, written OVER the snapshot it retires
+        when the incoming tree already lives whole on the engine's device
+        and matches the current snapshot leaf for leaf (structure, shapes,
+        dtypes): one program takes the old snapshot as a donated operand,
+        so its outputs land in those buffers.  No second copy of the
+        weights exists at any moment and ``_place`` has nothing to move.
+        Anything else (the first snapshot, a mesh learner's tree that
+        ``device_put`` must gather, numpy leaves, a changed tree, the
+        engine's own snapshot pushed back) takes the plane's route.
+
+        Safe because ONE thread both steps and pushes an engine (the
+        trainer's round, a disagg host's loop): macro-steps already
+        enqueued keep their operands until they have run (the runtime
+        orders a donated buffer's reuse behind its readers), and
+        ``push_params`` rebinds ``_params`` before it returns.  The
+        servers, read by other threads while a push arrives, never take
+        this path (``ParamSnapshotPlane._copy_snapshot``)."""
+        old = self._params
+        if old is not None and self._lives_here_like(params, old):
+            return self._copy_over(params, old), 1, True
+        return super()._copy_snapshot(params)
+
+    def _lives_here_like(self, params: Any, old: Any) -> bool:
+        """Device arrays whole on the engine's device, ``old``'s structure,
+        shapes and dtypes, and none of them ``old``'s own (a buffer cannot
+        be both read and donated by one call)."""
+        new_leaves, new_def = jax.tree_util.tree_flatten(params)
+        old_leaves, old_def = jax.tree_util.tree_flatten(old)
+        here = {self._device}
+        return new_def == old_def and all(
+            isinstance(n, jax.Array)
+            and n is not o
+            and n.shape == o.shape
+            and n.dtype == o.dtype
+            and n.sharding.device_set == here
+            for n, o in zip(new_leaves, old_leaves)
+        )
+
     def lower_decode(self):
         """Lower the decode macro-step against the engine's live state —
         nothing runs and nothing is donated.  The returned
@@ -1331,7 +1386,13 @@ class ContinuousEngine(ParamSnapshotPlane):
         computed under the previous generation, and reusing it would break
         the temperature-0 token-identity contract.  Live lanes keep their
         shared pages (their own refs) until harvest — only the cache's
-        index drops."""
+        index drops.
+
+        A full-precision push from the engine's own device overwrites the
+        retired snapshot's buffers (:meth:`_copy_snapshot`;
+        ``last_push["in_place"]``), so a tree obtained from
+        ``_snapshot_params()`` does NOT outlive the next push: its arrays
+        are deleted.  Call it from the thread that steps the engine."""
         with tracing.span("genrl.push_params", kind="genrl") as span:
             gen = super().push_params(params, learner_step, quantize)
             if self._prefix_cache is not None:

@@ -22,6 +22,7 @@ generation -> learner-step map backing the unified staleness definition
 
 from __future__ import annotations
 
+import functools
 import sys
 import threading
 from typing import Any, Dict, Optional, Tuple
@@ -80,6 +81,43 @@ def _tree_map(fn, tree):
     return fn(tree)
 
 
+@functools.lru_cache(maxsize=None)
+def _copy_program():
+    """The one-program snapshot copy.  jit's own cache keys it by tree
+    structure, shapes and shardings: a job's second push traces nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    def snapshot_copy_program(tree):  # the device program's name in a trace
+        return jax.tree_util.tree_map(jnp.copy, tree)
+
+    return jax.jit(snapshot_copy_program)
+
+
+def snapshot_copy(tree) -> Tuple[Any, int]:
+    """THE place a full-precision snapshot copy is made: fresh buffers that
+    survive the donation of ``tree``'s, and the count of device programs
+    the copy enqueued.
+
+    A tree of ``jax.Array`` leaves that share one set of devices is copied
+    by ONE compiled program (one host dispatch for the whole tree; a mesh
+    learner's tree comes back on its mesh, for ``_place`` to move).
+    Anything else keeps the leaf-by-leaf walk: one program a device-array
+    leaf, none for numpy leaves, and the only route of a process that
+    never imported jax.
+    """
+    jax = sys.modules.get("jax")
+    arrays = 0
+    if jax is not None:
+        leaves = jax.tree_util.tree_leaves(tree)
+        arrays = sum(isinstance(x, jax.Array) for x in leaves)
+        if arrays and arrays == len(leaves):
+            devices = leaves[0].sharding.device_set
+            if all(x.sharding.device_set == devices for x in leaves):
+                return _copy_program()(tree), 1
+    return _tree_map(jnp_copy, tree), arrays
+
+
 def tree_size(tree) -> Tuple[int, int]:
     """``(leaves, bytes)`` of a weight pytree, from shapes and dtypes alone
     (host values: nothing is read from a device)."""
@@ -94,8 +132,13 @@ class ParamSnapshotPlane:
     The shared distribution idiom (``ParameterServer``, ``InferenceServer``,
     the generation engines, the disagg learner): :meth:`push_params`
     publishes a snapshot copy with a monotonic generation bump — the copy
-    detaches the snapshot from the learner's donated buffers — and
+    (:func:`snapshot_copy`: one compiled program over a tree of device
+    arrays) detaches the snapshot from the learner's donated buffers — and
     ``_snapshot_params`` hands consumers the serve-ready tree.
+    ``last_push`` says what the newest push carried: ``bytes``, ``leaves``,
+    ``generation``, ``programs`` (device programs the copy enqueued) and
+    ``in_place`` (whether it was written over the retired snapshot, which
+    only :class:`~scalerl_tpu.genrl.continuous.ContinuousEngine` does).
 
     ``quantize="int8" | "bf16"`` stores the ROADMAP's compressed broadcast
     format instead (``runtime/quantize.py``: per-leaf symmetric int8 with
@@ -113,30 +156,36 @@ class ParamSnapshotPlane:
     generation delta equals it for entries that aged out of the map.
 
     jax-optional by design: full-precision pushes of numpy trees work in
-    processes that never imported jax (``_tree_map``/``jnp_copy`` fall back
-    to stdlib walks); only ``quantize=`` requires jax.
+    processes that never imported jax (:func:`snapshot_copy` falls back to
+    the stdlib walk); only ``quantize=`` requires jax.
     """
 
     _GEN_STEPS_CAP = 64
 
     def _init_param_plane(self, params: Any) -> None:
         self._param_lock = threading.Lock()
-        self._params = (
-            self._place(_tree_map(jnp_copy, params))
-            if params is not None
-            else None
-        )
+        self._params = None
+        if params is not None:
+            self._params = self._copy_snapshot(params)[0]
         self._quantized = None
         self.generation = 0
         self._gen_steps: Dict[int, int] = {0: 0}
         self._latest_learner_step = 0
         # what the newest push carried, for the spans around it
-        self.last_push: Dict[str, int] = {}
+        self.last_push: Dict[str, Any] = {}
 
     def _place(self, snapshot: Any) -> Any:
         """Placement hook: identity here; sharded consumers re-place the
         snapshot into their live layout (device-side reshard at worst)."""
         return snapshot
+
+    def _copy_snapshot(self, params: Any) -> Tuple[Any, int, bool]:
+        """``(placed snapshot, programs, in_place)`` of a full-precision
+        push.  The plane always builds the new snapshot BESIDE the old one
+        (``in_place`` False): a server's reader threads may still hold the
+        tree they fetched before the push."""
+        snapshot, programs = snapshot_copy(params)
+        return self._place(snapshot), programs, False
 
     def push_params(
         self,
@@ -148,13 +197,15 @@ class ParamSnapshotPlane:
         monotonic generation bump; no host transfer).  Returns the new
         generation."""
         if quantize is None:
-            snapshot, qsnap = self._place(_tree_map(jnp_copy, params)), None
+            snapshot, programs, in_place = self._copy_snapshot(params)
+            qsnap = None
         else:
             # round/clip/cast produce fresh buffers, so the quantized tree
             # is already detached from the learner's donated params
             from scalerl_tpu.runtime.quantize import quantize_tree
 
             snapshot, qsnap = None, quantize_tree(params, quantize)
+            programs, in_place = 0, False  # no copy: its ops are not counted
         leaves, size = tree_size(params)
         with self._param_lock:
             self.generation += 1
@@ -162,7 +213,10 @@ class ParamSnapshotPlane:
             self._params = snapshot
             self._quantized = qsnap
             self._record_step(gen, learner_step)
-            self.last_push = {"bytes": size, "leaves": leaves, "generation": gen}
+            self.last_push = {
+                "bytes": size, "leaves": leaves, "generation": gen,
+                "programs": programs, "in_place": in_place,
+            }
             return gen
 
     def _record_step(self, gen: int, learner_step: Optional[int]) -> None:
@@ -234,7 +288,7 @@ class ParameterServer(ParamSnapshotPlane):
         if to_host:
             snapshot = _to_host(weights)
         else:
-            snapshot = _tree_map(jnp_copy, weights)
+            snapshot = snapshot_copy(weights)[0]
         with self._param_lock:
             self.generation += 1
             self._params = snapshot

@@ -894,3 +894,73 @@ def test_token_learner_guard_decides_before_the_update_on_tpu(bf16):
     assert m["skipped_steps"] == 0.0 and int(agent.state.step) == 1
     assert bits(agent.state.params) != before[: len(leaves)]
     assert bool(tree_all_finite(agent.state))
+
+
+def test_engine_push_overwrites_the_snapshot_behind_a_macro_step_in_flight():
+    """ISSUE 38: the engine's push is one program whose outputs take the
+    retired snapshot's buffers (a donated operand).  What only the chip's
+    asynchronous queue shows: a macro-step enqueued BEFORE the push and read
+    AFTER it returns the old generation's tokens, though its operands'
+    buffers were donated meanwhile (the runtime orders the overwrite behind
+    the reader); and after the second push every snapshot leaf sits at the
+    first snapshot's address."""
+    from scalerl_tpu.genrl.continuous import (
+        ContinuousConfig,
+        ContinuousEngine,
+    )
+    from scalerl_tpu.models.transformer import TransformerPolicy
+
+    V, P, R, n = 4096, 64, 32, 8
+    model = TransformerPolicy(
+        num_actions=V, vocab_size=V, d_model=512, num_heads=8,
+        num_layers=4, max_len=2 * (P + R),
+    )
+    old = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
+    new = model.init(jax.random.PRNGKey(1), jnp.zeros((1, 2), jnp.int32))
+    rng = np.random.default_rng(38)
+    prompts = rng.integers(2, V, size=(n, P)).astype(np.int32)
+    lengths = rng.integers(P // 2, P + 1, size=n).astype(np.int32)
+
+    def engine(params):
+        return ContinuousEngine(
+            model, params,
+            ContinuousConfig(
+                vocab_size=V, max_prompt_len=P, max_new_tokens=R,
+                temperature=0.0, lanes=n, page_size=16, steps_per_macro=R,
+                steps_in_flight=2, paged_attn="pallas",
+            ),
+            iter_mode="scan",
+        )
+
+    def decode(eng, before_read=lambda: None):
+        for i in range(n):
+            eng.submit(prompts[i], lengths[i], tag=i)
+        assert eng.step() == []  # every response decoded whole, enqueued, unread
+        before_read()
+        done = sorted(eng.run_until(n, max_macro_steps=10), key=lambda c: c.tag)
+        return [c.response_tokens for c in done], {c.generation for c in done}
+
+    leaves = jax.tree_util.tree_leaves
+    where = lambda tree: [x.unsafe_buffer_pointer() for x in leaves(tree)]  # noqa: E731
+    expect_old, _ = decode(engine(old))  # never pushed
+    expect_new, _ = decode(engine(new))
+    assert any(not np.array_equal(a, b) for a, b in zip(expect_old, expect_new))
+
+    eng = engine(old)
+    first, _ = eng._snapshot_params()
+    at = where(first)
+    eng.push_params(jax.tree_util.tree_map(jnp.copy, old))  # builds the program
+    assert eng.last_push["programs"] == 1 and eng.last_push["in_place"] is True
+    assert all(x.is_deleted() for x in leaves(first))
+    tokens, gens = decode(eng, before_read=lambda: eng.push_params(new))
+    assert eng.last_push["in_place"] is True and gens == {1}
+    for got, want in zip(tokens, expect_old):
+        np.testing.assert_array_equal(got, want)
+    snapshot, gen = eng._snapshot_params()
+    assert gen == 2 and where(snapshot) == at
+    assert not set(at) & set(where(new))
+    tokens, gens = decode(eng)
+    assert gens == {2}
+    for got, want in zip(tokens, expect_new):
+        np.testing.assert_array_equal(got, want)
+    assert eng._copy_over._cache_size() == 1 and eng._decode_traces == 1
