@@ -773,6 +773,17 @@ class GenRLArguments(RLArguments):
     # always-on ones; and ``mtp_layers`` (0 | 1) multi-token-prediction
     # modules, which the packed learner runs and trains with weight
     # ``mtp_loss_coef`` and generation never builds.
+    # "nemotron_h" = a stack of single-mixer layers (``x + Mixer(N(x))``)
+    # laid out by ``layer_pattern``, a character a layer: ``M`` a Mamba-2
+    # mixer of the ``ssm_*`` sizes, ``E`` the routed experts beside a
+    # shared expert of ``moe_shared_width``, ``*`` attention with
+    # ``kv_heads`` key/value heads under ``n_heads`` query heads and no
+    # position signal, ``-`` a dense FFN of ``ffn_hidden``;
+    # ``moe_expert_act`` relu2 makes every expert ``relu(h W_up)^2 W_down``
+    # (no gate).  ``n_layers`` is the pattern's length.  The generation
+    # engine keeps a Mamba layer's recurrent state by lane beside the KV
+    # pages, so such a model is admitted by local prefill and group fork
+    # alone: no prefix-cache hit is served and speculation is refused.
     block_family: str = "gpt2"
     head_dim: int = 0
     rms_norm_eps: float = 1e-5
@@ -792,6 +803,16 @@ class GenRLArguments(RLArguments):
     dense_layers: int = 0
     moe_shared_experts: int = 0
     moe_scoring: str = "softmax"
+    layer_pattern: str = ""
+    kv_heads: int = 0
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    moe_expert_act: str = "swiglu"
+    moe_shared_width: int = 0
     mtp_layers: int = 0
     mtp_loss_coef: float = 0.1
     # weight of the router's load-balancing loss in the learner's total
@@ -917,10 +938,47 @@ class GenRLArguments(RLArguments):
                 f"temperature must be >= 0 (0 = greedy), got "
                 f"{self.temperature}"
             )
-        if self.block_family not in ("gpt2", "olmoe", "longcat", "joyai"):
+        if self.block_family not in (
+            "gpt2", "olmoe", "longcat", "joyai", "nemotron_h"
+        ):
             raise ValueError(
-                "block_family must be gpt2 | olmoe | longcat | joyai, got "
+                "block_family must be gpt2 | olmoe | longcat | joyai | "
+                f"nemotron_h, got {self.block_family!r}"
+            )
+        hybrid = self.block_family == "nemotron_h"
+        if hybrid and (
+            not self.layer_pattern
+            or set(self.layer_pattern) - set("ME*-")
+            or len(self.layer_pattern) != self.n_layers
+        ):
+            raise ValueError(
+                "the nemotron_h family needs layer_pattern, a string of "
+                "M | E | * | - with one character for each of n_layers "
+                f"({self.n_layers}), got {self.layer_pattern!r}"
+            )
+        if not hybrid and (
+            self.layer_pattern or self.kv_heads or self.ssm_heads
+            or self.moe_expert_act != "swiglu" or self.moe_shared_width
+        ):
+            raise ValueError(
+                "layer_pattern, kv_heads, the ssm sizes, moe_expert_act and "
+                "moe_shared_width are the nemotron_h family's, got them with "
                 f"{self.block_family!r}"
+            )
+        if self.moe_expert_act not in ("swiglu", "relu2"):
+            raise ValueError(
+                f"moe_expert_act must be swiglu | relu2, got {self.moe_expert_act!r}"
+            )
+        if self.kv_heads < 0 or (self.kv_heads and self.n_heads % self.kv_heads):
+            raise ValueError(
+                "kv_heads must divide n_heads (0: one each), got "
+                f"{self.kv_heads}/{self.n_heads}"
+            )
+        if hybrid and "M" in self.layer_pattern and self.spec_enable:
+            raise ValueError(
+                "spec_enable cannot serve a model with a recurrent (Mamba) "
+                "layer: a rejected draft is undone by moving a page cursor "
+                "back, and a recurrent state has no cursor to rewind"
             )
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(
@@ -937,11 +995,13 @@ class GenRLArguments(RLArguments):
                 "term lives in the packed loss"
             )
         if self.block_family != "joyai" and (
-            self.dense_layers or self.moe_shared_experts or self.mtp_layers
+            self.dense_layers or self.mtp_layers
+            or (self.moe_shared_experts and not hybrid)
         ):
             raise ValueError(
-                "dense_layers, moe_shared_experts and mtp_layers are the joyai "
-                f"family's, got them with {self.block_family!r}"
+                "dense_layers and mtp_layers are the joyai family's and "
+                "moe_shared_experts joyai's and nemotron_h's, got them with "
+                f"{self.block_family!r}"
             )
         if self.head_dim < 0 or self.router_aux_loss_coef < 0:
             raise ValueError(

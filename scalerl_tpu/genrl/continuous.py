@@ -45,6 +45,19 @@ the host swaps *sequences* through them —
   (exhaustion backpressures, never corrupts; shared pages count against
   EVERY holder's reservation, so sharing never loosens the guarantee)
   while physical pages are drawn lazily as contexts grow.
+- **recurrent state beside the pages** -- a stack with Mamba layers
+  (``model.recurrent``) caches into a :class:`~scalerl_tpu.models
+  .transformer.HybridCache`: page pools for its attention layers and, for
+  each Mamba layer, a float32 state indexed by LANE whose size does not
+  depend on a lane's length.  It rides in the same pytree as the pools
+  (donated through every program, never copied whole): the local prefill
+  writes a lane's rows at the prompt's true length, every decode substep
+  updates them in place, the group fork copies the leader's rows to the
+  members, and a dead lane's rows are whatever they are until the next
+  prefill writes them.  No page table describes that state, so such a
+  model is admitted **by local prefill and group fork only**: prefix-cache
+  lookups and inserts are skipped (``genrl.prefix_skipped_recurrent``)
+  and speculation is refused;
 
 - **speculative decoding** (ISSUE 16, ``spec_k > 0``) — the sequential-
   depth lever: each pass, every live lane proposes up to ``spec_k``
@@ -104,6 +117,7 @@ from scalerl_tpu.genrl.prefix_cache import PrefixCache
 from scalerl_tpu.models.routed_ffn import router_balance
 from scalerl_tpu.models.transformer import (
     TransformerPolicy,
+    fork_cache,
     prompt_attention_mask,
 )
 from scalerl_tpu.ops.pallas_paged_attention import make_paged_attn_fn
@@ -345,6 +359,17 @@ class ContinuousEngine(ParamSnapshotPlane):
                 "ContinuousEngine needs a token-mode TransformerPolicy "
                 "(vocab_size set); got a feature-embedding model"
             )
+        # a recurrent layer's state is no page-table fact: nothing can
+        # enter it at a page boundary (a prefix hit) or rewind it by a page
+        # cursor (a rejected draft)
+        self._recurrent = model.recurrent
+        if self._recurrent and config.spec_k:
+            raise ValueError(
+                "speculation (spec_k > 0) cannot serve a model with a "
+                "recurrent (Mamba) layer: a rejected draft is undone by "
+                "moving a page cursor back, and a recurrent state has no "
+                "cursor to rewind"
+            )
         self.config = config
         self.model = model
         self.iter_mode = resolve_iter_mode(iter_mode)
@@ -429,7 +454,22 @@ class ContinuousEngine(ParamSnapshotPlane):
         # device state: pools + per-lane decode carry (donated through
         # every program; the host rebinds after each dispatch).  The
         # model describes its cache; here it is one pytree of pools
-        self._pools = model.init_paged_cache(num_pages, ps)
+        # (a recurrent model's: pools and a lane-indexed state)
+        self._pools = model.init_paged_cache(num_pages, ps, lanes=L)
+        # bytes of recurrent state a lane carries, all layers together
+        self._state_bytes_per_lane = (
+            sum(a.nbytes for a in self._pools.ssm + self._pools.conv) // L
+            if self._recurrent
+            else 0
+        )
+        # what the decode program carries in place beside the pools
+        self._dispatch_attrs = (
+            {"state_bytes": self._state_bytes_per_lane * L}
+            if self._recurrent
+            else {}
+        )
+        self.state_forks = 0  # members whose state rows a fork wrote
+        self.prefix_skipped_recurrent = 0  # admissions that skipped the cache
         self._logits_st = jnp.zeros((L, config.vocab_size), jnp.float32)
         self._value_st = jnp.zeros((L,), jnp.float32)
         self._cl = jnp.zeros((L,), jnp.int32)
@@ -503,6 +543,9 @@ class ContinuousEngine(ParamSnapshotPlane):
         self._admitted_counter = reg.counter("genrl.admitted")
         self._completed_counter = reg.counter("genrl.completed")
         self._shared_counter = reg.counter("genrl.pages_shared")
+        self._prefix_skipped_counter = reg.counter(
+            "genrl.prefix_skipped_recurrent"
+        )
         self._admit_hist = reg.histogram("genrl.admission_latency_s")
         self._spec_proposed_counter = reg.counter("genrl.spec_proposed")
         self._spec_accepted_counter = reg.counter("genrl.spec_accepted")
@@ -647,8 +690,12 @@ class ContinuousEngine(ParamSnapshotPlane):
             # the uncached tail always holds the token whose forward
             # produces the lane's first decode logits
             cached: List[int] = []
-            if self._prefix_cache is not None:
+            use_cache = self._prefix_cache is not None and not self._recurrent
+            if use_cache:
                 cached = self._prefix_cache.lookup(prompt, m - 1)
+            elif self._prefix_cache is not None:
+                self.prefix_skipped_recurrent += 1
+                self._prefix_skipped_counter.inc()
             ck = len(cached) * ps
             worst = self.allocator.pages_for_tokens(
                 m + self._response_budget
@@ -703,7 +750,7 @@ class ContinuousEngine(ParamSnapshotPlane):
                 self.prefix_tokens_saved += full_tokens
             admitted += n
             self._admit_hist.observe(now - req.t_enqueue)
-            if self._prefix_cache is not None and n_full:
+            if use_cache and n_full:
                 inserts.append((prompt, m, pages[:n_full]))
         self._admitted_counter.inc(admitted)
         for P, rows in local.items():
@@ -864,6 +911,8 @@ class ContinuousEngine(ParamSnapshotPlane):
             src_page[i] = sp
             dst_page[i] = dp
         fn = self._fork_fn(F)
+        if self._recurrent:
+            self.state_forks += len(forks)
         with self._dispatch_guard():
             up = _device_put((src_lane, dst_lane, src_page, dst_page))
             (
@@ -909,6 +958,7 @@ class ContinuousEngine(ParamSnapshotPlane):
         the newly-allocated pages, last-position logits/value + cursor +
         flags scattered into the lane state — all device-side, no read."""
         model = self.model
+        recurrent = self._recurrent
 
         def prefill(
             params, pools, logits_st, value_st, cl, done, resp,
@@ -917,6 +967,10 @@ class ContinuousEngine(ParamSnapshotPlane):
             self._prefill_traces += 1
             positions = jnp.broadcast_to(jnp.arange(P), (A, P))
             mask = prompt_attention_mask(lengths, P)
+            # a recurrent layer's state leaves the prompt at its TRUE
+            # length (the mask's diagonal says which tokens are real) and
+            # is written to the admitted lanes' rows; pad rows drop
+            state = dict(state_lanes=lane_ids) if recurrent else {}
             out, pools = model.apply(
                 params,
                 tokens,
@@ -925,6 +979,7 @@ class ContinuousEngine(ParamSnapshotPlane):
                 paged_cache=pools,
                 page_ids=page_ids,
                 page_offsets=page_offsets,
+                **state,
             )
             rows = jnp.arange(A)
             last = lengths - 1
@@ -986,16 +1041,16 @@ class ContinuousEngine(ParamSnapshotPlane):
         """The CoW fork program at admit bucket ``F``: batched pool-page
         copy (``pools[dst] = pools[src]`` per layer — only partial prompt
         pages ever ride here) plus leader -> member lane-state
-        replication.  Pad rows copy null -> null and scatter-drop."""
+        replication, a recurrent model's state rows among it
+        (:func:`fork_cache`).  Pad rows copy null -> null and
+        scatter-drop."""
 
         def fork(
             pools, logits_st, value_st, cl, done, resp,
             src_lane, dst_lane, src_page, dst_page,
         ):
             self._fork_traces += 1
-            pools = jax.tree_util.tree_map(
-                lambda pool: pool.at[dst_page].set(pool[src_page]), pools
-            )
+            pools = fork_cache(pools, src_page, dst_page, src_lane, dst_lane)
             logits_st = logits_st.at[dst_lane].set(
                 logits_st[src_lane], mode="drop"
             )
@@ -1473,7 +1528,8 @@ class ContinuousEngine(ParamSnapshotPlane):
                 # ``generation``: the snapshot this macro-step decodes with,
                 # so a trace shows the first one on freshly pushed weights
                 with self._dispatch_guard(), tracing.span(
-                    "genrl.dispatch", kind="genrl", generation=gen
+                    "genrl.dispatch", kind="genrl", generation=gen,
+                    **self._dispatch_attrs,
                 ):
                     self._key, sub = jax.random.split(self._key)
                     # ONE explicit batched host->device upload per macro
@@ -1773,6 +1829,12 @@ class ContinuousEngine(ParamSnapshotPlane):
             "held_expert_tokens": held_picks,
             "absent_expert_tokens": routed_picks - held_picks,
             "zero_expert_tokens": int(self._expert_tokens.sum()) - routed_picks,
+            # a model with recurrent layers only (zeros otherwise): bytes
+            # of state a lane carries, member lanes whose state a fork
+            # wrote, admissions that skipped the prefix cache
+            "state_bytes_per_lane": self._state_bytes_per_lane,
+            "state_forks": self.state_forks,
+            "prefix_skipped_recurrent": self.prefix_skipped_recurrent,
         }
 
     def _harvest(

@@ -84,8 +84,17 @@ def _bank_init():
     )
 
 
+def _activate(g, u):
+    """An expert's hidden activation in float32: ``silu(g) * u`` of a
+    SwiGLU expert, ``relu(u)^2`` of one without a gate (``g`` None)."""
+    if g is None:
+        return jnp.square(jax.nn.relu(u))
+    return jax.nn.silu(g) * u
+
+
 def _streamed(x, top_p, top_i, w_gate, w_up, w_down, expert_axis=False):
-    """``expert_axis``: the tokens are given an explicit expert axis
+    """``w_gate`` None: the experts have no gate (``relu2``).
+    ``expert_axis``: the tokens are given an explicit expert axis
     (``[E, N, d]``, a broadcast), which makes the two up products batched
     matmuls that read the banks as they are stored.  Without it XLA:TPU,
     inside the decode loop at 128 tokens, wants ``w_gate`` and ``w_up``
@@ -93,7 +102,7 @@ def _streamed(x, top_p, top_i, w_gate, w_up, w_down, expert_axis=False):
     macro-step (8 copies of 0.4 GB and 3.2 GB of temporaries at the
     longcat cell's sizes: AOT, PR 30).  The share path takes it; OLMoE's
     32-token program meets no such copy and stays the text it was."""
-    E = w_gate.shape[0]
+    E = w_up.shape[0]
     f32 = jnp.float32
     # [N, E] combine weights: a pick's probability at its expert, else 0
     # (a pick outside the held banks, ``top_i`` not in [0, E), is a row of
@@ -101,14 +110,14 @@ def _streamed(x, top_p, top_i, w_gate, w_up, w_down, expert_axis=False):
     combine = jnp.sum(
         jax.nn.one_hot(top_i, E, dtype=f32) * top_p[..., None], axis=1
     )
+    tokens, xe = "nd", x
     if expert_axis:
-        xe = jnp.broadcast_to(x, (E,) + x.shape)
-        g = jnp.einsum("end,edf->enf", xe, w_gate, preferred_element_type=f32)
-        u = jnp.einsum("end,edf->enf", xe, w_up, preferred_element_type=f32)
-    else:
-        g = jnp.einsum("nd,edf->enf", x, w_gate, preferred_element_type=f32)
-        u = jnp.einsum("nd,edf->enf", x, w_up, preferred_element_type=f32)
-    a = jax.nn.silu(g) * u * combine.T[:, :, None]
+        tokens, xe = "end", jnp.broadcast_to(x, (E,) + x.shape)
+    up = lambda w: jnp.einsum(  # noqa: E731
+        f"{tokens},edf->enf", xe, w, preferred_element_type=f32
+    )
+    g = None if w_gate is None else up(w_gate)
+    a = _activate(g, up(w_up)) * combine.T[:, :, None]
     # one contraction over (expert, width): the experts' outputs are
     # summed in the matmul's float32 accumulator
     return jnp.einsum(
@@ -128,7 +137,7 @@ def _sorted(x, top_p, top_i, w_gate, w_up, w_down, held_rows=None):
     weights' transposes then sum into a bank's gradient and into the
     absent picks' scores (one seed in five on the chip; PERF.md, PR 32)."""
     N, k = top_i.shape
-    E = w_gate.shape[0]
+    E = w_up.shape[0]
     f32 = jnp.float32
     expert = top_i.reshape(N * k)
     order = jnp.argsort(expert)  # stable: assignments grouped by expert
@@ -138,11 +147,15 @@ def _sorted(x, top_p, top_i, w_gate, w_up, w_down, held_rows=None):
     if held_rows is not None:
         in_a_group = held_rows[order][:, None]
         xs = jnp.where(in_a_group, xs, 0)  # nothing of them goes back to ``x``
-    g = lax.ragged_dot(xs, w_gate, sizes, preferred_element_type=f32)
+    g = None
+    if w_gate is not None:
+        g = lax.ragged_dot(xs, w_gate, sizes, preferred_element_type=f32)
     u = lax.ragged_dot(xs, w_up, sizes, preferred_element_type=f32)
     if held_rows is not None:
-        g, u = jnp.where(in_a_group, g, 0.0), jnp.where(in_a_group, u, 0.0)
-    a = jax.nn.silu(g) * u * top_p.reshape(N * k)[order][:, None]
+        u = jnp.where(in_a_group, u, 0.0)
+        if g is not None:
+            g = jnp.where(in_a_group, g, 0.0)
+    a = _activate(g, u) * top_p.reshape(N * k)[order][:, None]
     if held_rows is not None:
         a = jnp.where(in_a_group, a, 0.0)
     ys = lax.ragged_dot(
@@ -172,6 +185,7 @@ class RoutedExperts(nn.Module):
     choice_bias: bool = False
     routed_scaling: float = 1.0
     scoring: str = "softmax"  # softmax | sigmoid
+    act: str = "swiglu"  # swiglu (gate, up, down) | relu2 (up, down: no gate bank)
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -184,7 +198,9 @@ class RoutedExperts(nn.Module):
         router = self.param(
             "router", nn.initializers.lecun_normal(), (d, R), self.param_dtype
         )
-        w_gate = self.param("w_gate", _bank_init(), (held, d, f), self.param_dtype)
+        w_gate = None
+        if self.act == "swiglu":
+            w_gate = self.param("w_gate", _bank_init(), (held, d, f), self.param_dtype)
         w_up = self.param("w_up", _bank_init(), (held, d, f), self.param_dtype)
         w_down = self.param("w_down", _bank_init(), (held, f, d), self.param_dtype)
         x = h.reshape(B * T, d).astype(self.dtype)
@@ -212,7 +228,10 @@ class RoutedExperts(nn.Module):
             top_p = top_p * self.routed_scaling
         self.sow("intermediates", "router_probs", probs.reshape(B, T, R))
         self.sow("intermediates", "expert_ids", top_i.reshape(B, T, k))
-        banks = tuple(w.astype(self.dtype) for w in (w_gate, w_up, w_down))
+        banks = tuple(
+            None if w is None else w.astype(self.dtype)
+            for w in (w_gate, w_up, w_down)
+        )
         streamed = B * T <= STREAMED_MAX_TOKENS
         if held == R:  # every output is a bank held here
             y = (_streamed if streamed else _sorted)(x, top_p, top_i, *banks)

@@ -69,11 +69,25 @@ class BlockSpec:
     them as one dense SwiGLU.  ``ffn="swiglu"`` is a dense SwiGLU of width
     ``ffn_hidden`` in a plain layer: a stack's leading dense layers
     (:func:`layer_specs`).
+
+    ``layer="mixer"`` is a layer of ONE mixer (:class:`_MixerBlock`: one
+    norm, one residual add), ``mixer`` saying which: ``mamba`` (a Mamba-2
+    state-space mixer of the ``ssm_*`` sizes, :class:`_Mamba2Mixer`),
+    ``attention`` (this spec's attention alone), ``experts`` (the routed
+    experts and their shared expert alone) or ``ffn`` (a dense FFN of
+    ``ffn_hidden`` alone); :func:`pattern_specs` lays a stack of them out
+    by a pattern string.  ``kv_heads`` key/value heads serve ``num_heads``
+    query heads (query head ``i`` reads head ``i // (heads / kv_heads)``;
+    0: one each), projected apart from ``q``; ``positions="none"`` gives
+    the attention no position signal at all (a stack whose state-space
+    layers carry the order).  ``expert_act="relu2"`` makes every expert
+    and dense FFN of the spec ``relu(h W_up)^2 W_down``, two matrices and
+    no gate; ``shared_width`` is the always-on expert's own width.
     """
 
     norm: str = "layernorm"  # layernorm | rmsnorm
     norm_eps: float = 1e-6
-    positions: str = "learned"  # learned (a table added to the embedding) | rope
+    positions: str = "learned"  # learned (a table added to the embedding) | rope | none
     rope_theta: float = 10000.0
     qk_norm: str = "none"  # none | rmsnorm (over the projection's whole width)
     head_dim: Optional[int] = None  # None: d_model // num_heads
@@ -89,7 +103,7 @@ class BlockSpec:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_pairing: str = "half"  # half (i with i + D/2) | interleaved (2i with 2i + 1)
-    layer: str = "plain"  # plain (attention, FFN) | scmoe
+    layer: str = "plain"  # plain (attention, FFN) | scmoe | mixer (one mixer alone)
     ffn_hidden: int = 0
     zero_experts: int = 0
     experts_held: int = 0  # 0: every routed expert
@@ -99,6 +113,16 @@ class BlockSpec:
     mla_scale: bool = False
     scoring: str = "softmax"  # softmax | sigmoid (the router's, over all outputs)
     shared_experts: int = 0
+    kv_heads: int = 0  # 0: as many as query heads
+    expert_act: str = "swiglu"  # swiglu | relu2 (experts, shared expert, dense FFN)
+    shared_width: int = 0  # 0: shared_experts x expert_width
+    mixer: str = ""  # of a mixer layer: mamba | attention | experts | ffn
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
 
 
 def block_spec(
@@ -123,12 +147,22 @@ def block_spec(
     routed_scaling: float = 1.0,
     scoring: str = "softmax",
     shared_experts: int = 0,
+    kv_heads: int = 0,
+    expert_act: str = "swiglu",
+    shared_width: int = 0,
+    ssm_heads: int = 0,
+    ssm_head_dim: int = 0,
+    ssm_state: int = 0,
+    ssm_groups: int = 1,
+    ssm_conv: int = 4,
+    ssm_chunk: int = 128,
 ) -> BlockSpec:
     """The block a named family stacks; the sizes only the family reads
     are ignored by the others (``gpt2`` keeps its own epsilon).  For a
     family whose stack has more than one kind of layer this is the kind
     that repeats (``joyai``: the routed layer) and :func:`layer_specs`
-    gives the stack."""
+    gives the stack; for ``nemotron_h`` it is the expert layer, and
+    :func:`pattern_specs` gives the stack."""
     if family == "gpt2":
         return BlockSpec(head_dim=head_dim)
     if family == "olmoe":
@@ -223,8 +257,52 @@ def block_spec(
             routed_scaling=routed_scaling, scoring=scoring,
             shared_experts=shared_experts,
         )
+    if family == "nemotron_h":
+        held = experts_held or num_experts
+        sizes = (
+            head_dim or 0, expert_width, ssm_heads, ssm_head_dim, ssm_state,
+            ssm_groups, ssm_chunk,
+        )
+        if min(sizes) < 1 or ssm_conv < 2 or ssm_heads % ssm_groups:
+            raise ValueError(
+                "the nemotron_h stack needs a head size, an expert width and "
+                "its state-space sizes (heads a multiple of the groups, a "
+                f"convolution of 2 taps or more), got {sizes}/{ssm_conv}"
+            )
+        if not (
+            1 <= experts_per_token <= num_experts
+            and 0 <= first_expert
+            and 1 <= held
+            and first_expert + held <= num_experts
+            and shared_experts >= 0
+            and kv_heads >= 0
+            and scoring in ("softmax", "sigmoid")
+            and expert_act in ("swiglu", "relu2")
+        ):
+            raise ValueError(
+                "the nemotron_h router picks experts_per_token of num_experts "
+                "by a softmax or sigmoid score and holds experts first_expert "
+                ".. first_expert + experts_held beside shared_experts "
+                f"always-on ones, got {experts_per_token}/{num_experts}/"
+                f"{scoring}/{first_expert}/{held}/{shared_experts}/{expert_act}"
+            )
+        return BlockSpec(
+            norm="rmsnorm", norm_eps=norm_eps, positions="none",
+            head_dim=head_dim, ffn="experts", num_experts=num_experts,
+            experts_per_token=experts_per_token, expert_width=expert_width,
+            norm_topk_prob=norm_topk_prob, layer="mixer", mixer="experts",
+            ffn_hidden=ffn_hidden, experts_held=held,
+            first_expert=first_expert, router_bias=True,
+            routed_scaling=routed_scaling, scoring=scoring,
+            shared_experts=shared_experts, kv_heads=kv_heads,
+            expert_act=expert_act, shared_width=shared_width,
+            ssm_heads=ssm_heads, ssm_head_dim=ssm_head_dim,
+            ssm_state=ssm_state, ssm_groups=ssm_groups, ssm_conv=ssm_conv,
+            ssm_chunk=ssm_chunk,
+        )
     raise ValueError(
-        f"block family must be gpt2 | olmoe | longcat | joyai, got {family!r}"
+        "block family must be gpt2 | olmoe | longcat | joyai | nemotron_h, "
+        f"got {family!r}"
     )
 
 
@@ -245,6 +323,38 @@ def layer_specs(
         norm_topk_prob=False, scoring="softmax", shared_experts=0,
     )
     return (dense,) * dense_layers + (spec,) * (num_layers - dense_layers)
+
+
+_PATTERN_MIXERS = {"M": "mamba", "E": "experts", "*": "attention", "-": "ffn"}
+
+
+def pattern_specs(spec: BlockSpec, pattern: str) -> Tuple[BlockSpec, ...]:
+    """A stack of single-mixer layers laid out by the ``nemotron_h``
+    family's pattern string, a character a layer: ``M`` a Mamba-2 mixer,
+    ``E`` the routed experts, ``*`` attention, ``-`` a dense FFN of
+    ``ffn_hidden``.  ``spec`` is the family's expert layer."""
+    unknown = sorted(set(pattern) - set(_PATTERN_MIXERS))
+    if not pattern or unknown or spec.layer != "mixer":
+        raise ValueError(
+            "a layer pattern is a string of M | E | * | - over a mixer-layer "
+            f"spec, got {pattern!r} (unknown {unknown}) over {spec.layer!r}"
+        )
+    if "-" in pattern and spec.ffn_hidden < 1:
+        raise ValueError("a '-' layer needs ffn_hidden")
+    bare = dataclasses.replace(
+        spec, ffn="none", num_experts=0, experts_per_token=0, expert_width=0,
+        experts_held=0, first_expert=0, router_bias=False, routed_scaling=1.0,
+        norm_topk_prob=False, scoring="softmax", shared_experts=0,
+        shared_width=0,
+    )
+    kinds = {
+        "E": spec,
+        **{
+            ch: dataclasses.replace(bare, mixer=_PATTERN_MIXERS[ch])
+            for ch in "M*-"
+        },
+    }
+    return tuple(kinds[ch] for ch in pattern)
 
 
 class TransformerOutput(NamedTuple):
@@ -308,6 +418,41 @@ class LatentKVCache(NamedTuple):
     and the prefix cache are page-index facts and do not see the kind."""
 
     rows: Tuple[jnp.ndarray, ...]
+
+
+class HybridCache(NamedTuple):
+    """The cache of a stack with recurrent layers (``nemotron_h``): the K
+    and V page pools of its attention layers, in layer order (``[num_pages,
+    page_size, kv_heads x head_dim]``, every rule of :class:`PagedKVCache`),
+    **and** the recurrent state of its Mamba layers, in layer order:
+    ``ssm [lanes, heads, head_dim, state]`` (what
+    :func:`ssm_decode_update` updates in place) and ``conv [lanes, taps - 1,
+    channels]``, float32, indexed by LANE and of a size that does not
+    depend on a lane's length.  The state is written by the prefill at the
+    prompt's true length, updated in place by every decode substep and
+    copied leader to member by the group fork (:func:`fork_cache`); a page
+    table says nothing about it, so a prefix-cache hit and a page-cursor
+    rollback cannot serve a model that has one."""
+
+    k: Tuple[jnp.ndarray, ...]
+    v: Tuple[jnp.ndarray, ...]
+    ssm: Tuple[jnp.ndarray, ...]
+    conv: Tuple[jnp.ndarray, ...]
+
+
+def fork_cache(cache, src_page, dst_page, src_lane, dst_lane):
+    """A group fork on a model's cache: pool pages ``src_page`` copied to
+    ``dst_page`` (partial prompt pages; pad rows copy null to null) and,
+    where the cache has lane-indexed state, lanes ``src_lane``'s rows to
+    ``dst_lane``'s (pad rows carry an out-of-range lane and drop)."""
+    pages = lambda pool: pool.at[dst_page].set(pool[src_page])  # noqa: E731
+    if not isinstance(cache, HybridCache):
+        return jax.tree_util.tree_map(pages, cache)
+    lanes = lambda st: st.at[dst_lane].set(st[src_lane], mode="drop")  # noqa: E731
+    return HybridCache(
+        k=tuple(map(pages, cache.k)), v=tuple(map(pages, cache.v)),
+        ssm=tuple(map(lanes, cache.ssm)), conv=tuple(map(lanes, cache.conv)),
+    )
 
 
 def prompt_attention_mask(lengths: jnp.ndarray, total_len: int) -> jnp.ndarray:
@@ -462,8 +607,108 @@ def _routed_experts(spec: BlockSpec, dt) -> RoutedExperts:
         spec.norm_topk_prob, zero_experts=spec.zero_experts,
         held=spec.experts_held, first_expert=spec.first_expert,
         choice_bias=spec.router_bias, routed_scaling=spec.routed_scaling,
-        scoring=spec.scoring, name="experts", **dt,
+        scoring=spec.scoring, act=spec.expert_act, name="experts", **dt,
     )
+
+
+def _repeat_kv(x: jnp.ndarray, num_heads: int) -> jnp.ndarray:
+    """``[B, S, KV, D] -> [B, S, H, D]``: each key/value head under the
+    ``H / KV`` query heads that read it (the identity at ``KV == H``)."""
+    kv = x.shape[2]
+    return x if kv == num_heads else jnp.repeat(x, num_heads // kv, axis=2)
+
+
+def _mha(
+    mod, h, attn_mask=None, paged_cache=None, page_ids=None, page_offsets=None,
+    page_table=None, attn_lengths=None, prefix_starts=None, segment_ids=None,
+):
+    """Multi-head attention on a normed input ``h [B, T, d]``, inside the
+    compact ``__call__`` of ``mod`` (a :class:`_Block` or a
+    :class:`_MixerBlock`: the projections are ``mod``'s own children, so a
+    plain layer's tree is what it always was).  Returns ``(out [B, T, d],
+    (k_pages, v_pages) or None)``; the paths are those of
+    :class:`_Block`'s docstring.
+
+    With ``spec.kv_heads`` key/value heads under more query heads the
+    projections are ``q`` and ``kv`` apart, the pools hold ``kv_heads x D``
+    a token, the paged decode reads them as they are (the kernel's query
+    is block-diagonal by group) and every other path repeats each
+    key/value head under its query heads after the cache write."""
+    B, T, _ = h.shape
+    spec, H = mod.spec, mod.num_heads
+    head_dim = spec.head_dim or mod.d_model // H
+    width = H * head_dim
+    KV = spec.kv_heads or H
+    dt = dict(dtype=mod.dtype, param_dtype=mod.param_dtype)
+    if KV == H:
+        qkv = nn.Dense(3 * width, use_bias=False, name="qkv", **dt)(h)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+    else:
+        q = nn.Dense(width, use_bias=False, name="q", **dt)(h)
+        kv = nn.Dense(2 * KV * head_dim, use_bias=False, name="kv", **dt)(h)
+        k, v = jnp.split(kv, 2, axis=-1)
+    if spec.qk_norm == "rmsnorm":
+        # over all heads' features at once, before the head split
+        q = RMSNorm(spec.norm_eps, dtype=mod.dtype, name="q_norm")(q)
+        k = RMSNorm(spec.norm_eps, dtype=mod.dtype, name="k_norm")(k)
+    shape = (B, T, KV, head_dim)
+    q = q.reshape(B, T, H, head_dim)
+    k, v = k.reshape(shape), v.reshape(shape)
+    if mod.rotary is not None:
+        # before every cache write: K is stored normed and rotated
+        q, k = mod.rotary(q), mod.rotary(k)
+    new_cache = None
+    if paged_cache is not None:
+        kp, vp = paged_cache
+        flat_idx = (page_ids * kp.shape[1] + page_offsets).reshape(B * T)
+        kp = _scatter_rows(kp, flat_idx, k)
+        vp = _scatter_rows(vp, flat_idx, v)
+        if page_table is not None and prefix_starts is not None:
+            # shared-table tail prefill: gather the whole context
+            # (cached prefix pages + the tail just scattered above)
+            # through the table, attend causal-from-start — the
+            # compute twin of the decode seam at T > 1, kernel-free.
+            # The speculative verify pass (genrl/continuous.py) rides
+            # this exact path with T = draft bucket + 1: slot j is
+            # position prefix_starts + j, the pos <= qpos mask keeps
+            # rejected slots' K/V (garbage past the cursor) out of
+            # every query, so draft rollback never touches the device.
+            # The heads are split out of the gathered rows: reshaping
+            # the pool itself would bring its relayout copy back
+            kg = _repeat_kv(gather_pages(kp, page_table, KV), H)
+            vg = _repeat_kv(gather_pages(vp, page_table, KV), H)
+            pos = jnp.arange(kg.shape[1])[None, None, :]
+            qpos = (
+                prefix_starts[:, None] + jnp.arange(T)[None, :]
+            )[:, :, None]
+            out = _masked_attention(
+                q, kg, vg, pos <= qpos, mod.dtype
+            )
+        elif page_table is not None:
+            paged_attn = mod.paged_attn_fn or paged_attention_reference
+            out = paged_attn(q, kp, vp, page_table, attn_lengths)
+            out = out.astype(mod.dtype)
+        else:
+            out = _masked_attention(
+                q, _repeat_kv(k, H), _repeat_kv(v, H), attn_mask, mod.dtype
+            )
+        new_cache = (kp, vp)
+    elif segment_ids is not None and mod.segment_attn_fn is not None:
+        # packed-row training attention through the flash seam: the
+        # kernel enforces the segment-blocked causal rule and skips
+        # fully-masked (cross-segment / pad) blocks entirely
+        out = mod.segment_attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H), segment_ids)
+        out = out.astype(mod.dtype)
+    elif attn_mask is not None:
+        out = _masked_attention(
+            q, _repeat_kv(k, H), _repeat_kv(v, H), attn_mask, mod.dtype
+        )
+    else:
+        out = mod.attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H))
+    out = nn.Dense(mod.d_model, use_bias=False, name="proj", **dt)(
+        out.reshape(B, T, width)
+    )
+    return out, new_cache
 
 
 class _Block(nn.Module):
@@ -522,8 +767,6 @@ class _Block(nn.Module):
         B, T, _ = x.shape
         spec = self.spec
         rms = spec.norm == "rmsnorm"
-        head_dim = spec.head_dim or self.d_model // self.num_heads
-        width = self.num_heads * head_dim
         dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         h = _norm(spec, self.dtype, "attn_norm" if rms else None)(x)
         new_cache = None
@@ -541,62 +784,11 @@ class _Block(nn.Module):
                 segment_ids=segment_ids,
             )
         else:
-            qkv = nn.Dense(3 * width, use_bias=False, name="qkv", **dt)(h)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            if spec.qk_norm == "rmsnorm":
-                # over all heads' features at once, before the head split
-                q = RMSNorm(spec.norm_eps, dtype=self.dtype, name="q_norm")(q)
-                k = RMSNorm(spec.norm_eps, dtype=self.dtype, name="k_norm")(k)
-            shape = (B, T, self.num_heads, head_dim)
-            q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
-            if self.rotary is not None:
-                # before every cache write: K is stored normed and rotated
-                q, k = self.rotary(q), self.rotary(k)
-            if paged_cache is not None:
-                kp, vp = paged_cache
-                flat_idx = (page_ids * kp.shape[1] + page_offsets).reshape(B * T)
-                kp = _scatter_rows(kp, flat_idx, k)
-                vp = _scatter_rows(vp, flat_idx, v)
-                if page_table is not None and prefix_starts is not None:
-                    # shared-table tail prefill: gather the whole context
-                    # (cached prefix pages + the tail just scattered above)
-                    # through the table, attend causal-from-start — the
-                    # compute twin of the decode seam at T > 1, kernel-free.
-                    # The speculative verify pass (genrl/continuous.py) rides
-                    # this exact path with T = draft bucket + 1: slot j is
-                    # position prefix_starts + j, the pos <= qpos mask keeps
-                    # rejected slots' K/V (garbage past the cursor) out of
-                    # every query, so draft rollback never touches the device.
-                    # The heads are split out of the gathered rows: reshaping
-                    # the pool itself would bring its relayout copy back
-                    kg = gather_pages(kp, page_table, self.num_heads)
-                    vg = gather_pages(vp, page_table, self.num_heads)
-                    pos = jnp.arange(kg.shape[1])[None, None, :]
-                    qpos = (
-                        prefix_starts[:, None] + jnp.arange(T)[None, :]
-                    )[:, :, None]
-                    out = _masked_attention(
-                        q, kg, vg, pos <= qpos, self.dtype
-                    )
-                elif page_table is not None:
-                    paged_attn = self.paged_attn_fn or paged_attention_reference
-                    out = paged_attn(q, kp, vp, page_table, attn_lengths)
-                    out = out.astype(self.dtype)
-                else:
-                    out = _masked_attention(q, k, v, attn_mask, self.dtype)
-                new_cache = (kp, vp)
-            elif segment_ids is not None and self.segment_attn_fn is not None:
-                # packed-row training attention through the flash seam: the
-                # kernel enforces the segment-blocked causal rule and skips
-                # fully-masked (cross-segment / pad) blocks entirely
-                out = self.segment_attn_fn(q, k, v, segment_ids)
-                out = out.astype(self.dtype)
-            elif attn_mask is not None:
-                out = _masked_attention(q, k, v, attn_mask, self.dtype)
-            else:
-                out = self.attn_fn(q, k, v)
-            out = nn.Dense(self.d_model, use_bias=False, name="proj", **dt)(
-                out.reshape(B, T, width)
+            out, new_cache = _mha(
+                self, h, attn_mask=attn_mask, paged_cache=paged_cache,
+                page_ids=page_ids, page_offsets=page_offsets,
+                page_table=page_table, attn_lengths=attn_lengths,
+                prefix_starts=prefix_starts, segment_ids=segment_ids,
             )
         x = x + out
         h = _norm(spec, self.dtype, "ffn_norm" if rms else None)(x)
@@ -785,6 +977,331 @@ class _GatedMLP(nn.Module):
         a = nn.silu(nn.Dense(self.hidden, name="gate", **dt)(h))
         a = a * nn.Dense(self.hidden, name="up", **dt)(h)
         return nn.Dense(self.d_model, name="down", **dt)(a)
+
+
+class _SquaredReluMLP(nn.Module):
+    """Dense FFN without a gate, no bias: ``relu(h Wu)^2 Wd``."""
+
+    d_model: int
+    hidden: int
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        dt = dict(use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype)
+        a = jnp.square(nn.relu(nn.Dense(self.hidden, name="up", **dt)(h)))
+        return nn.Dense(self.d_model, name="down", **dt)(a)
+
+
+def _dense_ffn(spec: BlockSpec, d_model: int, hidden: int, name: str, dt):
+    """A dense FFN of the spec's expert form."""
+    kind = _SquaredReluMLP if spec.expert_act == "relu2" else _GatedMLP
+    return kind(d_model, hidden, name=name, **dt)
+
+
+def run_ids(segment_ids: jnp.ndarray) -> jnp.ndarray:
+    """``[B, T]`` ids of the run each token's recurrence belongs to: a
+    real token's own segment id, a pad token's (id 0) the id of the last
+    real token before it, 0 before the first.  So a right-padded prompt's
+    tail and a packed row's tail ride on the last sequence (with no input
+    and no decay: the state passes through them), and a new id is a
+    reset."""
+    seg = segment_ids.astype(jnp.int32)
+    at = lax.cummax(jnp.where(seg > 0, jnp.arange(seg.shape[1])[None, :], 0), axis=1)
+    return jnp.take_along_axis(seg, at, axis=1)
+
+
+def _masked_exp(keep, v):
+    """``exp(v)`` where ``keep``, 0 elsewhere; what is masked out never
+    reaches the exponential (no overflow there, no NaN in its gradient)."""
+    return jnp.where(keep, jnp.exp(jnp.where(keep, v, 0.0)), 0.0)
+
+
+def ssd_chunked(x, dt, A, B, C, runs, chunk: int):
+    """The Mamba-2 recurrence over whole sequences, ``chunk`` tokens at a
+    time (the state-space-duality form)::
+
+        S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T ;  y_t = S_t C_t
+
+    ``x [Bt, T, H, P]``, ``dt [Bt, T, H]`` float32 (0 at a pad token, with
+    ``x`` 0 there: no decay, no input), ``A [H]`` negative, ``B``/``C``
+    ``[Bt, T, G, N]``, ``runs [Bt, T]`` (:func:`run_ids`, never
+    decreasing along a row: the state is zero at the start of every run).  Inside a chunk the outputs are one
+    masked ``C B^T`` product weighted by the decays between the two
+    positions; between chunks the state is carried, ``[H, P, N]`` a row.
+    A position of another run is masked out of every product (never a
+    ``-inf`` in a running sum of log-decays).  Returns ``(y [Bt, T, H, P]
+    float32, S [Bt, H, P, N] float32 after the last token)``.
+    """
+    f32 = jnp.float32
+    Bt, T, H, P = x.shape
+    G, N = B.shape[2], B.shape[3]
+    Q = chunk
+    pad = -T % Q
+    if pad:
+        widen = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))  # noqa: E731
+        x, dt, B, C = widen(x), widen(dt), widen(B), widen(C)
+        runs = jnp.pad(runs, ((0, 0), (0, pad)), mode="edge")
+    nc = (T + pad) // Q
+    per = H // G
+    x = x.astype(f32).reshape(Bt, nc, Q, H, P)
+    dt = dt.reshape(Bt, nc, Q, H)
+    B = B.astype(f32).reshape(Bt, nc, Q, G, N)
+    C = C.astype(f32).reshape(Bt, nc, Q, G, N)
+    runs = runs.reshape(Bt, nc, Q)
+    dtx = dt[..., None] * x
+    causal = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+    same = causal[None, None] & (runs[:, :, :, None] == runs[:, :, None, :])  # [Bt, nc, t, s]
+    # log-decay from the start of the token's run (or of the chunk, if the
+    # run entered it): a masked sum, so that no rounding of another run's
+    # decays reaches this one; the mask is exact at any matmul precision
+    cum = jnp.einsum(
+        "bcts,bcsh->bcth", same.astype(f32), dt * A.astype(f32),
+        precision=lax.Precision.HIGHEST,
+    )
+    # -- inside a chunk: y_t += sum_{s <= t} exp(cum_t - cum_s) (C_t . B_s) dt_s x_s
+    diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # [Bt, nc, t, s, H]
+    weight = _masked_exp(same[..., None], diff)
+    cb = jnp.einsum("bctgn,bcsgn->bctsg", C, B)
+    m = weight * jnp.repeat(cb, per, axis=-1)
+    y = jnp.einsum("bctsh,bcshp->bcthp", m, dtx)
+    # -- a chunk's own contribution to the state at its end, and the decay
+    # of what entered it: both only for what is of the end's run
+    last = runs[:, :, -1]
+    of_last = runs == last[:, :, None]  # [Bt, nc, Q]
+    tail = _masked_exp(of_last[..., None], cum[:, :, -1:] - cum)
+    Bh = jnp.repeat(B, per, axis=3)  # [Bt, nc, Q, H, N]
+    grown = jnp.einsum("bcsh,bcshp,bcshn->bchpn", tail, dtx, Bh)
+    entered = jnp.concatenate([jnp.zeros_like(last[:, :1]), last[:, :-1]], axis=1)  # the carry's run
+    through = jnp.where(
+        (last == entered)[..., None], jnp.exp(cum[:, :, -1]), 0.0
+    )  # [Bt, nc, H]
+
+    def carry(S, c):
+        through_c, grown_c = c
+        return through_c[:, :, None, None] * S + grown_c, S
+
+    S, entering = lax.scan(
+        carry, jnp.zeros((Bt, H, P, N), f32),
+        (jnp.moveaxis(through, 1, 0), jnp.moveaxis(grown, 1, 0)),
+    )
+    entering = jnp.moveaxis(entering, 0, 1)  # [Bt, nc, H, P, N]: the state at each chunk's start
+    # -- what entered the chunk, read by the tokens of its run
+    of_entered = runs == entered[:, :, None]
+    reach = _masked_exp(of_entered[..., None], cum)
+    Ch = jnp.repeat(C, per, axis=3)
+    y = y + reach[..., None] * jnp.einsum("bcthn,bchpn->bcthp", Ch, entering)
+    return y.reshape(Bt, nc * Q, H, P)[:, :T], S
+
+
+def ssm_decode_update(state, x, dt, A, B, C, D):
+    """One token of the same recurrence for every lane, on the carried
+    state::
+
+        S <- exp(dt A) S + dt x B^T ;  y = S C + D x
+
+    ``state [L, H, P, N]`` float32 (how :func:`ssd_chunked` leaves it, the
+    state axis on the lanes); ``x [L, H, P]``; ``dt [L, H]`` (after the
+    softplus); ``A [H]`` negative; ``B``/``C`` ``[L, G, N]`` (head ``h``
+    reads group ``h // (H / G)``); ``D [H]``.  Returns ``(y [L, H, P]
+    float32, the new state)``.  Plain ``jax.numpy`` on every backend: XLA
+    makes one fusion of it that reads the state once and writes it over
+    its input inside a donated ``while`` carry, and a Pallas kernel moved
+    the same bytes no faster (PERF.md, PR 40).  Grad-free: decode is
+    inference-only, the learner differentiates :func:`ssd_chunked`."""
+    f32 = jnp.float32
+    per = dt.shape[1] // B.shape[1]
+    x, dt = x.astype(f32), dt.astype(f32)
+    Bh = jnp.repeat(B.astype(f32), per, axis=1)  # [L, H, N]
+    Ch = jnp.repeat(C.astype(f32), per, axis=1)
+    with jax.named_scope("ssm_decode_update"):
+        state = (
+            jnp.exp(dt * A.astype(f32))[:, :, None, None] * state
+            + (dt[:, :, None] * x)[..., None] * Bh[:, :, None, :]
+        )
+        y = jnp.sum(state * Ch[:, :, None, :], axis=-1)
+    return y + D.astype(f32)[None, :, None] * x, state
+
+
+class _Mamba2Mixer(nn.Module):
+    """The Mamba-2 mixer on a normed input ``h [B, T, d]`` (sizes
+    ``spec.ssm_*``: ``H`` heads of ``P``, ``G`` groups, state ``N``, a
+    causal depthwise convolution of ``K`` taps)::
+
+        [z | xBC | dt] = h W_in                       (d -> H P + (H P + 2 G N) + H)
+        xBC_t = silu(b + sum_j w_j xBC_{t-K+1+j})     (float32; [x | B | C])
+        dt = softplus(dt + dt_bias);  A = -exp(A_log)
+        S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T;   y_t = S_t C_t + D x_t
+        out = RMSNorm_groups(y * silu(z)) W_out       (G groups of H P / G)
+
+    ONE set of parameters, three paths:
+
+    - **whole sequences** (every full, masked or packed forward, and the
+      prefill): :func:`ssd_chunked` over ``runs [B, T]``
+      (:func:`run_ids`): the state and the convolution's taps are cut at
+      every run's start, and a pad token (``real`` False) has no input and
+      no decay, so what leaves a right-padded prompt is the state at its
+      true length.  With ``state`` given (the prefill) the final state and
+      the last ``K - 1`` real convolution inputs of each row are written
+      to rows ``state_lanes`` of it.
+    - **one token a lane** (decode: ``decode=True``, ``T = 1``, row ``b``
+      is lane ``b``): the convolution over the carried taps and
+      :func:`ssm_decode_update` on the carried state, in place.
+
+    ``state`` is ``(ssm [lanes, H, P, N], conv [lanes, K - 1, H P + 2 G
+    N])``, both float32.  Returns ``(out [B, T, d], state or None)``."""
+
+    d_model: int
+    spec: BlockSpec
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h, runs=None, real=None, state=None, state_lanes=None, decode=False):
+        s = self.spec
+        f32 = jnp.float32
+        Bt, T, _ = h.shape
+        H, P, G, N, K = s.ssm_heads, s.ssm_head_dim, s.ssm_groups, s.ssm_state, s.ssm_conv
+        inner, channels = H * P, H * P + 2 * G * N
+        dt_kw = dict(use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype)
+        tap_init = lambda key, shape: jax.random.uniform(  # noqa: E731
+            key, shape, f32, -(K ** -0.5), K ** -0.5
+        )
+        conv_w = self.param("conv_w", tap_init, (K, channels))
+        conv_b = self.param("conv_b", tap_init, (channels,))
+
+        def dt_bias_init(key, shape):
+            # softplus(dt_bias) log-uniform in [0.001, 0.1], floored at 1e-4
+            step = jnp.exp(
+                jax.random.uniform(key, shape, f32) * (jnp.log(0.1) - jnp.log(0.001))
+                + jnp.log(0.001)
+            )
+            step = jnp.maximum(step, 1e-4)
+            return step + jnp.log(-jnp.expm1(-step))
+
+        dt_bias = self.param("dt_bias", dt_bias_init, (H,))
+        A_log = self.param(
+            "A_log", lambda key, shape: jnp.log(jax.random.uniform(key, shape, f32, 1.0, 16.0)), (H,)
+        )
+        D = self.param("D", nn.initializers.ones, (H,), f32)
+        norm_scale = self.param("norm_scale", nn.initializers.ones, (inner,), f32)
+        A = -jnp.exp(A_log)
+
+        zxbcdt = nn.Dense(2 * inner + 2 * G * N + H, name="in_proj", **dt_kw)(h)
+        z = zxbcdt[..., :inner]
+        u = zxbcdt[..., inner : inner + channels].astype(f32)  # the convolution's input
+        step = jax.nn.softplus(zxbcdt[..., inner + channels :].astype(f32) + dt_bias)
+
+        def split(xbc):
+            xbc = jax.nn.silu(xbc).astype(self.dtype)
+            lead = xbc.shape[:-1]
+            return (
+                xbc[..., :inner].reshape(*lead, H, P),
+                xbc[..., inner : inner + G * N].reshape(*lead, G, N),
+                xbc[..., inner + G * N :].reshape(*lead, G, N),
+            )
+
+        new_state = None
+        if decode:
+            ssm, taps = state
+            window = jnp.concatenate([taps, u], axis=1)  # [lanes, K, channels]
+            x, B, C = split(conv_b + jnp.sum(window * conv_w, axis=1))
+            y, ssm = ssm_decode_update(ssm, x, step[:, 0], A, B, C, D)
+            y = y[:, None]  # [lanes, 1, H, P]
+            new_state = (ssm, window[:, 1:])
+        else:
+            if runs is None:
+                runs = jnp.ones((Bt, T), jnp.int32)
+                real = jnp.ones((Bt, T), bool)
+            u = jnp.where(real[..., None], u, 0.0)
+            step = jnp.where(real[..., None], step, 0.0)
+            xbc = conv_b + u * conv_w[K - 1]
+            for back in range(1, K):
+                # the input ``back`` tokens earlier, if it is of this run
+                earlier = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :T]
+                near = jnp.pad(runs, ((0, 0), (back, 0)), constant_values=-1)[:, :T] == runs
+                xbc = xbc + jnp.where(near[..., None], earlier, 0.0) * conv_w[K - 1 - back]
+            x, B, C = split(xbc)
+            x = jnp.where(real[..., None, None], x, 0)
+            y, last = ssd_chunked(x, step, A, B, C, runs, s.ssm_chunk)
+            y = y + D[:, None] * x.astype(f32)
+            if state is not None:
+                ssm, taps = state
+                # the last K - 1 real inputs of each (right-padded) row
+                length = jnp.sum(real, axis=1)
+                at = length[:, None] - (K - 1) + jnp.arange(K - 1)[None, :]
+                tail = jnp.take_along_axis(u, jnp.clip(at, 0, T - 1)[..., None], axis=1)
+                tail = jnp.where((at >= 0)[..., None], tail, 0.0)
+                new_state = (
+                    ssm.at[state_lanes].set(last, mode="drop"),
+                    taps.at[state_lanes].set(tail, mode="drop"),
+                )
+        # the gated norm: within each group's channels, one scale of ``inner``
+        gated = (y.reshape(Bt, T, inner) * jax.nn.silu(z.astype(f32))).reshape(Bt, T, G, inner // G)
+        ms = jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
+        normed = (gated * lax.rsqrt(ms + s.norm_eps)).reshape(Bt, T, inner) * norm_scale
+        out = nn.Dense(self.d_model, name="out_proj", **dt_kw)(normed.astype(self.dtype))
+        return out, new_state
+
+
+class _MixerBlock(nn.Module):
+    """A layer of one mixer (``layer="mixer"``): ``x + Mixer(N(x))``, the
+    mixer ``spec.mixer``'s: a Mamba-2 mixer, this spec's attention alone
+    (:func:`_mha`), the routed experts beside their shared expert, or a
+    dense FFN.  The call arguments are :class:`_Block`'s and ``runs`` /
+    ``real`` / ``state_lanes`` for a Mamba mixer; ``paged_cache`` is what
+    the mixer caches into: ``(k_pages, v_pages)``, a Mamba layer's ``(ssm,
+    conv)`` state, or None.  Returns ``x``, or ``(x, cache)`` when the
+    model runs on a cache."""
+
+    d_model: int
+    num_heads: int
+    mlp_ratio: int
+    attn_fn: AttentionFn
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    paged_attn_fn: Optional[Callable] = None
+    segment_attn_fn: Optional[Callable] = None
+    spec: BlockSpec = BlockSpec()
+    rotary: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(
+        self, x, paged_cache=None, runs=None, real=None, state_lanes=None,
+        on_cache=False, **call,
+    ):
+        spec = self.spec
+        dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        h = _norm(spec, self.dtype, "norm")(x)
+        cache = None
+        if spec.mixer == "mamba":
+            if call.get("prefix_starts") is not None:
+                raise NotImplementedError(
+                    "a recurrent layer has no tail prefill over a cached "
+                    "prefix and no speculative verify: its state cannot be "
+                    "entered at a page boundary or rewound by a page cursor"
+                )
+            out, cache = _Mamba2Mixer(self.d_model, spec, name="mixer", **dt)(
+                h, runs=runs, real=real, state=paged_cache,
+                state_lanes=state_lanes,
+                decode=paged_cache is not None and call.get("page_table") is not None,
+            )
+        elif spec.mixer == "attention":
+            out, cache = _mha(self, h, paged_cache=paged_cache, **call)
+        elif spec.mixer == "experts":
+            out = _routed_experts(spec, dt)(h)
+            if spec.shared_experts:
+                # always on, computed where the token lives
+                out = out + _dense_ffn(
+                    spec, self.d_model,
+                    spec.shared_width or spec.shared_experts * spec.expert_width,
+                    "shared", dt,
+                )(h)
+        else:
+            out = _dense_ffn(spec, self.d_model, spec.ffn_hidden, "ffn", dt)(h)
+        x = x + out
+        return (x, cache) if on_cache else x
 
 
 class _ShortcutBlock(nn.Module):
@@ -989,13 +1506,54 @@ class TransformerPolicy(nn.Module):
         them: generation never runs it)."""
         return sum(s.ffn == "experts" for s in self.layer_specs)
 
-    def init_paged_cache(self, num_pages: int, page_size: int, dtype=jnp.float32):
-        """Zeroed page pools of the kind and number this model's blocks
-        cache into (page 0 = the never-read null page): the cache is
-        described by the model, and everything that holds it (the engine,
-        its fork and its programs) treats it as one pytree of ``[num_pages,
-        page_size, width]`` pools."""
+    @property
+    def recurrent(self) -> bool:
+        """Whether a layer of the stack carries a state that no page table
+        describes (a Mamba mixer)."""
+        return any(s.mixer == "mamba" for s in self.layer_specs)
+
+    def init_paged_cache(
+        self, num_pages: int, page_size: int, dtype=jnp.float32, lanes: int = 0
+    ):
+        """The zeroed cache this model's layers cache into: the cache is
+        described by the model, and everything that holds it (the engine
+        and its programs) treats it as one pytree.  Page pools are
+        ``[num_pages, page_size, width]`` (page 0 = the never-read null
+        page), one K and one V an attention layer or one latent pool an
+        ``mla`` attention; a stack with Mamba layers gets a
+        :class:`HybridCache`: pools for its attention layers alone, a
+        ``lanes``-indexed recurrent state for each Mamba layer, nothing
+        for the others."""
         spec = self.block
+        if spec.layer == "mixer":
+            specs = self.layer_specs
+            attn = sum(s.mixer == "attention" for s in specs)
+            mamba = [s for s in specs if s.mixer == "mamba"]
+            if mamba and lanes < 1:
+                raise ValueError("a recurrent model's cache is sized by its lanes")
+            pools = init_paged_kv_cache(
+                num_pages, page_size, attn, spec.kv_heads or self.num_heads,
+                self.head_dim, dtype,
+            )
+            return HybridCache(
+                k=pools.k, v=pools.v,
+                ssm=tuple(
+                    jnp.zeros(
+                        (lanes, s.ssm_heads, s.ssm_head_dim, s.ssm_state), jnp.float32
+                    )
+                    for s in mamba
+                ),
+                conv=tuple(
+                    jnp.zeros(
+                        (
+                            lanes, s.ssm_conv - 1,
+                            s.ssm_heads * s.ssm_head_dim + 2 * s.ssm_groups * s.ssm_state,
+                        ),
+                        jnp.float32,
+                    )
+                    for s in mamba
+                ),
+            )
         if spec.attention == "mla":
             # one latent pool an attention: one a plain layer, two a
             # shortcut-connected double layer
@@ -1026,6 +1584,7 @@ class TransformerPolicy(nn.Module):
         prefix_starts: Optional[jnp.ndarray] = None,
         segment_ids: Optional[jnp.ndarray] = None,
         mtp: bool = False,
+        state_lanes: Optional[jnp.ndarray] = None,
     ):
         """Full forward, masked full forward, or paged incremental step.
 
@@ -1059,6 +1618,11 @@ class TransformerPolicy(nn.Module):
         - ``mtp=True`` (a model with ``mtp_layers``, no cache): also run
           the multi-token-prediction module over the same rows and return
           its logits as ``mtp_logits`` (:class:`_MTPModule`).
+        - ``state_lanes=[B]`` (a stack with recurrent layers, paged
+          prefill): the lanes whose rows of the cache's recurrent state
+          this call's prompts write, at their true lengths; an id out of
+          range drops.  Decode updates every lane's row in place (row
+          ``b`` is lane ``b``).
         """
         B, T = obs.shape[:2]
         spec = self.block
@@ -1071,7 +1635,11 @@ class TransformerPolicy(nn.Module):
             )
         if not self.is_initializing():  # a program's trace, not the weights' making
             _note_layers(
-                tuple(obs.shape), tuple(f"{s.layer}/{s.ffn}" for s in specs),
+                tuple(obs.shape),
+                tuple(
+                    f"{s.layer}/{s.mixer if s.layer == 'mixer' else s.ffn}"
+                    for s in specs
+                ),
                 spec.attention, spec.experts_held or spec.num_experts,
                 spec.num_experts, self.mtp_layers,
             )
@@ -1087,6 +1655,19 @@ class TransformerPolicy(nn.Module):
                 seg = segment_ids.astype(jnp.int32)
                 has_next = has_next & (seg > 0) & (jnp.roll(seg, -1, axis=1) == seg)
             has_next = jnp.broadcast_to(has_next, (B, T))
+        runs = real = None
+        if self.recurrent:
+            # which tokens are real and where a recurrence starts anew: the
+            # packed rows' segments, or the diagonal of a padded forward's
+            # mask (a token that may attend itself is real)
+            if segment_ids is not None:
+                real = segment_ids > 0
+            elif attn_mask is not None:
+                real = jnp.diagonal(attn_mask, axis1=1, axis2=2)
+            if real is not None:
+                runs = run_ids(
+                    segment_ids if segment_ids is not None else real.astype(jnp.int32)
+                )
         if segment_ids is not None and self.segment_attn_fn is None:
             # dense packed fallback: ONE [B, S, S] mask shared by every
             # block — the XLA reference path and the off-TPU shape
@@ -1114,7 +1695,7 @@ class TransformerPolicy(nn.Module):
             )
         elif spec.positions == "rope":
             rotary = rotary_fn(positions, self.head_dim, spec.rope_theta)
-        else:
+        elif spec.positions == "learned":
             pos_tab = self.param(
                 "pos_embed",
                 nn.initializers.normal(0.02),
@@ -1126,6 +1707,7 @@ class TransformerPolicy(nn.Module):
         latent = spec.attention == "mla"
         pools = []  # what each layer wrote: (k, v), one latent pool, or two
         at = 0  # the layer's first pool among the latent cache's rows
+        n_attn = n_mamba = 0  # a mixer stack's attention and Mamba layers so far
         for i, layer in enumerate(specs):
             common = dict(
                 dtype=self.dtype,
@@ -1136,6 +1718,35 @@ class TransformerPolicy(nn.Module):
                 rotary=rotary,
                 name=f"block_{i}",
             )
+            if layer.layer == "mixer":
+                # one mixer, and the cache of its kind: pools, state, none
+                block = _MixerBlock(
+                    self.d_model, self.num_heads, self.mlp_ratio, attn, **common
+                )
+                cache = None
+                if paged_cache is not None and layer.mixer == "attention":
+                    cache = (paged_cache.k[n_attn], paged_cache.v[n_attn])
+                elif paged_cache is not None and layer.mixer == "mamba":
+                    cache = (paged_cache.ssm[n_mamba], paged_cache.conv[n_mamba])
+                n_attn += layer.mixer == "attention"
+                n_mamba += layer.mixer == "mamba"
+                mixer_call = dict(runs=runs, real=real)
+                if paged_cache is not None:
+                    x, written = block(
+                        x, paged_cache=cache, on_cache=True,
+                        state_lanes=state_lanes, attn_mask=attn_mask,
+                        page_ids=page_ids, page_offsets=page_offsets,
+                        page_table=page_table, attn_lengths=attn_lengths,
+                        prefix_starts=prefix_starts, **mixer_call,
+                    )
+                    if written is not None:
+                        pools.append((layer.mixer, written))
+                elif segment_ids is not None:
+                    x = block(x, segment_ids=segment_ids, **mixer_call)
+                else:
+                    x = block(x, attn_mask=attn_mask, **mixer_call)
+                x = c(x)
+                continue
             if layer.layer == "scmoe":
                 block = _ShortcutBlock(self.d_model, self.num_heads, attn, **common)
             else:
@@ -1191,6 +1802,13 @@ class TransformerPolicy(nn.Module):
         out = TransformerOutput(policy_logits, baseline, mtp_logits)
         if paged_cache is None:
             return out
+        if spec.layer == "mixer":
+            kv = [w for kind, w in pools if kind == "attention"]
+            st = [w for kind, w in pools if kind == "mamba"]
+            return out, HybridCache(
+                k=tuple(k for k, _v in kv), v=tuple(v for _k, v in kv),
+                ssm=tuple(s for s, _c in st), conv=tuple(c for _s, c in st),
+            )
         if latent:
             return out, LatentKVCache(
                 rows=tuple(

@@ -129,17 +129,23 @@ def paged_attention_reference(
     """XLA gather implementation — the oracle the kernel is pinned to.
 
     ``q``: ``[B, 1, H, D]`` (one query token per lane).  ``k_pages`` /
-    ``v_pages``: ``[N, page_size, H*D]`` dense pools (``[N, page_size, H,
-    D]`` is accepted and flattened).  ``page_table``: ``[B, M]`` int32 page
+    ``v_pages``: ``[N, page_size, KV*D]`` dense pools (``[N, page_size, KV,
+    D]`` is accepted and flattened); ``KV`` key/value heads, read off the
+    pool's width, serve the ``H`` query heads, head ``i`` reading
+    ``i // (H / KV)`` (grouped heads; ``KV == H`` is one each).  ``page_table``: ``[B, M]`` int32 page
     ids (junk entries must still be in ``[0, N)`` — the allocator's null
     page 0 — they are masked by ``lengths``).  ``lengths``: ``[B]`` int32
     valid-token counts (>= 1).  Returns ``[B, 1, H, D]``.
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    H = q.shape[2]
-    k = gather_pages(k_pages, page_table, H)  # [B, S, H, D]
-    v = gather_pages(v_pages, page_table, H)
+    H, D = q.shape[2], q.shape[3]
+    kv_heads = _dense_pool(k_pages).shape[2] // D
+    k = gather_pages(k_pages, page_table, kv_heads)  # [B, S, KV, D]
+    v = gather_pages(v_pages, page_table, kv_heads)
+    if kv_heads != H:
+        k = jnp.repeat(k, H // kv_heads, axis=2)
+        v = jnp.repeat(v, H // kv_heads, axis=2)
     qf = q[:, 0].astype(jnp.float32)  # [B, H, D]
     scores = jnp.einsum("bhd,bshd->bhs", qf, k.astype(jnp.float32)) * scale
     valid = jnp.arange(k.shape[1])[None, :] < lengths[:, None]  # [B, S]
@@ -218,7 +224,7 @@ def _dot_terms(a_terms, b_terms, contract):
 def _decode_kernel(
     pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     k_buf, v_buf, sems, q_sc, acc_sc, m_sc, l_sc, walked_sc,
-    *, scale, head_dim,
+    *, scale, head_dim, group=1,
 ):
     """One lane a grid step, ALL heads, walking the lane's live pages a
     block of ``P`` at a time.  The pools stay in HBM; ``k_buf``/``v_buf``
@@ -238,7 +244,14 @@ def _decode_kernel(
     operands (:func:`_dot_f32`).  Pages past the lane's length are neither
     read from the table nor fetched: the positions they would fill are
     masked in the scores and zeroed in the V block, so nothing stale in
-    VMEM reaches the result."""
+    VMEM reaches the result.
+
+    **Grouped heads** (``group`` = query heads a key/value head, > 1): the
+    pools' rows hold the ``KV`` key/value heads, ``width = KV * D``, and
+    the block-diagonal query has ``group`` rows against each key/value
+    head's ``D`` columns: row ``h`` holds query head ``h``'s vector in
+    segment ``h // group``.  ``q_ref`` and ``o_ref`` are then ``[rows,
+    D]``, a head a row."""
     b = pl.program_id(0)
     lanes = pl.num_programs(0)
     _, P, ps, width = k_buf.shape
@@ -272,8 +285,17 @@ def _decode_kernel(
     blocks = pl.cdiv(live_pages(b, 0), P)
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
-    own = (col >= row * head_dim) & (col < (row + 1) * head_dim)
-    q = jnp.where(own, q_ref[...].astype(jnp.float32) * scale, 0.0)
+    if group == 1:
+        own = (col >= row * head_dim) & (col < (row + 1) * head_dim)
+        q = jnp.where(own, q_ref[...].astype(jnp.float32) * scale, 0.0)
+    else:
+        seg = row // group
+        own = (col >= seg * head_dim) & (col < (seg + 1) * head_dim)
+        # every query row beside itself, once a key/value head
+        q_wide = jnp.concatenate(
+            [q_ref[...].astype(jnp.float32) * scale] * (width // head_dim), axis=1
+        )
+        q = jnp.where(own, q_wide, 0.0)
     q_sc[...] = jnp.concatenate(_bf16_terms(q), axis=0)
     acc_sc[...] = jnp.zeros_like(acc_sc)
     m_sc[...] = jnp.full_like(m_sc, _NEG_BIG)
@@ -324,7 +346,13 @@ def _decode_kernel(
     # lengths >= 1 makes every head's sum positive; rows past the last head
     # (padding to whole sublane tiles) own no segment
     out = jnp.where(own, acc_sc[...] / jnp.maximum(l_sc[...], 1e-30), 0.0)
-    o_ref[...] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+    if group == 1:
+        o_ref[...] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+    else:
+        # a row's own segment is the only one that is not zero
+        o_ref[...] = sum(
+            out[:, j * head_dim:(j + 1) * head_dim] for j in range(width // head_dim)
+        ).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -352,13 +380,27 @@ def paged_decode_attention(
     if T != 1:
         raise ValueError(f"decode attention takes one query token, got T={T}")
     k_pages, v_pages = _dense_pool(k_pages), _dense_pool(v_pages)
-    ps = k_pages.shape[1]
-    P = pages_per_block(ps, H * D, k_pages.dtype.itemsize)
+    ps, width = k_pages.shape[1], k_pages.shape[2]
+    group = H * D // width  # query heads a key/value head
+    if width % D or group * width != H * D:
+        raise ValueError(
+            f"a pool row holds whole key/value heads of the query's size, "
+            f"a divisor of its {H} heads: got pools {k_pages.shape}, q {q.shape}"
+        )
+    P = pages_per_block(ps, width, k_pages.dtype.itemsize)
     rows = -(-H // 16) * 16  # whole bfloat16 sublane tiles of heads
 
-    # q and o go a lane's row at a time through the pipeline; Mosaic tiles
-    # the last two block dims, and [1, H*D] spans both axes
-    row = pl.BlockSpec((None, 1, H * D), lambda b, pt, ln: (b, 0, 0))
+    if group == 1:
+        # q and o go a lane's row at a time through the pipeline; Mosaic tiles
+        # the last two block dims, and [1, H*D] spans both axes
+        row = pl.BlockSpec((None, 1, H * D), lambda b, pt, ln: (b, 0, 0))
+        q_in, out_shape = q.reshape(B, 1, H * D), (B, 1, H * D)
+    else:
+        # a head a row (to whole tiles of rows; a zero query row reads
+        # uniformly and is sliced off)
+        row = pl.BlockSpec((None, rows, D), lambda b, pt, ln: (b, 0, 0))
+        q_in = jnp.pad(q.reshape(B, H, D), ((0, 0), (0, rows - H), (0, 0)))
+        out_shape = (B, rows, D)
     pool = pl.BlockSpec(memory_space=pltpu.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -366,31 +408,33 @@ def paged_decode_attention(
         in_specs=[row, pool, pool],
         out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((2, P, ps, H * D), k_pages.dtype),
-            pltpu.VMEM((2, P, ps, H * D), v_pages.dtype),
+            pltpu.VMEM((2, P, ps, width), k_pages.dtype),
+            pltpu.VMEM((2, P, ps, width), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),  # [K or V, buffer]
-            pltpu.VMEM((3 * rows, H * D), jnp.bfloat16),  # block-diagonal query
-            pltpu.VMEM((rows, H * D), jnp.float32),  # accumulator
+            pltpu.VMEM((3 * rows, width), jnp.bfloat16),  # block-diagonal query
+            pltpu.VMEM((rows, width), jnp.float32),  # accumulator
             pltpu.VMEM((rows, 1), jnp.float32),  # running maximum
             pltpu.VMEM((rows, 1), jnp.float32),  # running sum
             pltpu.SMEM((1,), jnp.int32),  # blocks walked
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, head_dim=D),
+        functools.partial(_decode_kernel, scale=scale, head_dim=D, group=group),
         name="paged_decode",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, 1, H * D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         # the buffers and the block count carry from lane to lane
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(
         page_table.astype(jnp.int32),
         lengths.astype(jnp.int32),
-        q.reshape(B, 1, H * D),
+        q_in,
         k_pages,
         v_pages,
     )
+    if group > 1:
+        out = out[:, :H]
     return out.reshape(B, 1, H, D)
 
 

@@ -100,6 +100,20 @@ PARAM_LOGICAL_AXES: Dict[Tuple[str, ...], Tuple[Optional[str], ...]] = {
     # own, which takes the concatenated ``[trunk ; embedding]`` (2 x embed)
     # back to the residual stream and replicates like the stream.
     ("eh_proj", "kernel"): (None, "embed"),
+    # The nemotron_h stack (single-mixer layers): grouped-head attention
+    # projects ``q`` apart from ``kv``; ``q`` leads out to the heads like
+    # ``qkv``, the fused ``kv`` of a few key/value heads replicates (a split
+    # would fall between K and V, not between heads).  A Mamba-2 mixer's
+    # ``in_proj`` output is ``[z | x | B | C | dt]``, segments of different
+    # widths that no even split respects, so it replicates, with the
+    # convolution's taps and the per-head ``A_log`` / ``D`` / ``dt_bias``
+    # (no rule); ``out_proj`` takes the heads' outputs back to the stream
+    # and shards its input like ``proj``.  The relu2 experts and shared
+    # expert have ``up`` / ``down`` / ``w_up`` / ``w_down`` and no gate.
+    ("q", "kernel"): ("embed", "heads"),
+    ("kv", "kernel"): ("embed", None),
+    ("in_proj", "kernel"): ("embed", None),
+    ("out_proj", "kernel"): ("heads", "embed"),
 }
 
 

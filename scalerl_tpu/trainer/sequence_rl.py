@@ -55,6 +55,7 @@ from scalerl_tpu.models.transformer import (
     TransformerPolicy,
     block_spec,
     layer_specs,
+    pattern_specs,
 )
 from scalerl_tpu.ops.pallas_per import resolve_sample_method
 from scalerl_tpu.parallel.train_step import maybe_enable_mesh_from_args
@@ -104,7 +105,22 @@ def build_genrl_model(args: GenRLArguments) -> TransformerPolicy:
         routed_scaling=args.moe_routed_scaling,
         scoring=args.moe_scoring,
         shared_experts=args.moe_shared_experts,
+        kv_heads=args.kv_heads,
+        expert_act=args.moe_expert_act,
+        shared_width=args.moe_shared_width,
+        ssm_heads=args.ssm_heads,
+        ssm_head_dim=args.ssm_head_dim,
+        ssm_state=args.ssm_state,
+        ssm_groups=args.ssm_groups,
+        ssm_conv=args.ssm_conv,
+        ssm_chunk=args.ssm_chunk,
     )
+    if args.layer_pattern:
+        layers = pattern_specs(spec, args.layer_pattern)
+    elif args.dense_layers:
+        layers = layer_specs(spec, args.n_layers, args.dense_layers)
+    else:  # a stack of one kind of layer is its block, ``n_layers`` times
+        layers = ()
     return TransformerPolicy(
         num_actions=args.vocab_size,
         vocab_size=args.vocab_size,
@@ -116,11 +132,7 @@ def build_genrl_model(args: GenRLArguments) -> TransformerPolicy:
         param_dtype=jnp.bfloat16 if bf16 else jnp.float32,
         segment_attn_fn=seg_fn,
         block=spec,
-        # a stack of one kind of layer is its block, ``n_layers`` times
-        layers=(
-            layer_specs(spec, args.n_layers, args.dense_layers)
-            if args.dense_layers else ()
-        ),
+        layers=layers,
         mtp_layers=args.mtp_layers,
     )
 

@@ -18,8 +18,11 @@ the file skips.  The fused classic loop's program is held to the same
 rule at the end of the file (ISSUE 31), and the segment kernels at latent
 attention's head shape are compiled there at the learn cell's own sizes
 (ISSUE 32), and the token learner's learn step is read for the copy of
-its train state that a post-hoc guard costs (ISSUE 33): this is the one
-tier-1 file that loads the TPU's compiler outside ``tests/benchmark``.
+its train state that a post-hoc guard costs (ISSUE 33), and a hybrid
+stack's decode, prefill and fork programs are read for a copy of a Mamba
+layer's recurrent state, which has to be carried in place beside the
+pools (ISSUE 40): this is the one tier-1 file that loads the TPU's
+compiler outside ``tests/benchmark``.
 """
 
 import functools
@@ -198,6 +201,115 @@ def test_other_programs_leave_the_pools_in_place(engine, one_chip, program):
         extra = [(LANES, k), (LANES,), (LANES, k + 1), (LANES, k + 1), (LANES, M), (LANES,), "key"]
     text = _compiled_text(engine, one_chip, fn, params=program != "fork", extra=extra)
     _assert_pools_read_in_place(text, engine)
+
+
+# -- a recurrent state beside the pools (ISSUE 40) ---------------------------
+
+
+@pytest.fixture(scope="module")
+def hybrid_engine():
+    """The pattern ``M*EM`` at Nemotron-3-Nano's mixer sizes (64 Mamba
+    heads of 64, state 128, 8 groups; 32 query heads of 128 over 2
+    key/value heads) on a hidden size of 256, 4 lanes: a layer's state is
+    ``[4, 64, 64, 128]`` float32 (8 MB: larger than any weight here, so
+    that a copy of its size can only be a state) and its pools ``[301, 8,
+    256]``.  The paged kernel is pinned compiled, as in the fixture above."""
+    from scalerl_tpu.models.transformer import pattern_specs
+
+    vocab, pattern = 128, "M*EM"
+    spec = block_spec(
+        "nemotron_h", head_dim=128, num_experts=8, experts_per_token=2, expert_width=128,
+        norm_topk_prob=True, experts_held=4, scoring="sigmoid", shared_experts=1,
+        shared_width=256, kv_heads=2, expert_act="relu2", ssm_heads=64, ssm_head_dim=64,
+        ssm_state=128, ssm_groups=8,
+    )
+    model = TransformerPolicy(
+        num_actions=vocab, vocab_size=vocab, d_model=256, num_heads=32,
+        num_layers=len(pattern), max_len=256, block=spec, layers=pattern_specs(spec, pattern),
+        paged_attn_fn=functools.partial(paged_decode_attention, interpret=False),
+    )
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
+    return ContinuousEngine(
+        model, params,
+        ContinuousConfig(
+            vocab_size=vocab, max_prompt_len=64, max_new_tokens=64,
+            lanes=LANES, page_size=PAGE, num_pages=PAGES, steps_per_macro=2,
+        ),
+        iter_mode="scan",
+    )
+
+
+def _assert_state_in_place(text, eng):
+    """No ``copy`` or ``transpose`` of the shape of a layer's recurrent
+    state or of a pool (a fork's gathered rows are a few lanes' worth, not
+    the array; the convolution's window, 1/28 of the state, is rewritten
+    whole by every token's shift, and one this small the compiler moves
+    through another memory space), and every state array the program
+    names is row-major on whole ``(8, 128)`` tiles: the 128 states on the
+    minor axis (a ``while`` carry takes its logical shape's layout)."""
+    cache = eng._pools
+    whole = {",".join(str(d) for d in x.shape) for x in cache.k + cache.v + cache.ssm}
+    moved = [
+        line.strip()[:160]
+        for line in text.splitlines()
+        for m in [re.search(r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(", line)]
+        if m and m.group(1) in whole
+    ]
+    assert not moved, f"{len(moved)} whole-state or whole-pool copies, the first: {moved[0]}"
+    # nor a pass over a state under another shape: a gather of rows this
+    # wide first split the whole array in two halves (the fork, PR 40)
+    half = _elements(",".join(str(d) for d in cache.ssm[0].shape)) // 2
+    passes = [
+        line.strip()[:160]
+        for line in text.splitlines()
+        for m in [re.search(rf"= \(?f32\[({LANES},[\d,]+)\]", line)]  # lane-indexed, like a state
+        if m and m.group(1) not in whole and _elements(m.group(1)) >= half
+    ]
+    assert not passes, f"{len(passes)} arrays of half a state or more, the first: {passes[0]}"
+    layouts = set(re.findall(rf"f32\[{LANES},64,64,128\]\{{[^}}]*\}}", text))
+    assert layouts, "the program names no state"
+    wrong = {l for l in layouts if not re.search(r"\{3,2,1,0(:T\(8,128\)(S\(\d\))?)?\}$", l)}
+    assert not wrong, f"states not row-major in place: {wrong}"
+
+
+def test_hybrid_decode_carries_the_state_in_place(hybrid_engine, one_chip):
+    """The decode macro-step of a stack with Mamba layers: ONE fusion a
+    Mamba layer a substep that takes the state and gives ``(y, state)``
+    (``ssm_decode_update``: the state read once and written once), one
+    ``paged_decode`` an attention layer, every pool and state array
+    donated and returned as itself, none copied."""
+    eng = hybrid_engine
+    M = eng._table.shape[1]
+    text = _compiled_text(eng, one_chip, eng._decode_fn, params=True, extra=[(LANES, M), "key"])
+    assert "tpu_custom_call" in text and "paged_decode" in text  # one attention layer
+    updates = re.findall(
+        rf"= \(f32\[{LANES},64,64\]\S*, f32\[{LANES},64,64,128\]\S*\) fusion\(.*ssm_decode_update", text
+    )
+    assert len(updates) == 2, f"{len(updates)} state updates for two Mamba layers"
+    _assert_state_in_place(text, eng)
+    leaves = len(jax.tree_util.tree_leaves(eng._snapshot_params()[0]))
+    header = text[text.index("input_output_alias={"):].split("\n", 1)[0]
+    aliased = {
+        int(out): int(param)
+        for out, param in re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
+    }
+    cache = len(jax.tree_util.tree_leaves(eng._pools))
+    assert cache == 2 + 2 + 2  # K and V of one attention, state and window of two Mamba layers
+    for i in range(cache):
+        assert aliased.get(i) == leaves + i, (i, aliased)
+
+
+@pytest.mark.parametrize("program", ["local_prefill", "fork"])
+def test_hybrid_prefill_and_fork_leave_the_state_in_place(hybrid_engine, one_chip, program):
+    eng = hybrid_engine
+    A, P = 2, 64
+    if program == "local_prefill":
+        fn = eng._prefill_fn(("local", P, A))
+        extra = [(A, P), (A,), (A,), (A, P), (A, P)]
+    else:
+        fn, extra = eng._fork_fn(A), [(A,)] * 4
+    text = _compiled_text(eng, one_chip, fn, params=program != "fork", extra=extra)
+    _assert_state_in_place(text, eng)
 
 
 # -- the fused classic loop (ISSUE 31) ---------------------------------------

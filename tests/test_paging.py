@@ -813,3 +813,68 @@ def test_quantize_bf16_mode_and_validation():
     np.testing.assert_allclose(np.asarray(d["w"]), 1.5)
     with pytest.raises(ValueError):
         quantize_tree(tree, "int4")
+
+
+# -- grouped key/value heads (ISSUE 40) --------------------------------------
+
+
+@pytest.mark.parametrize(
+    "H,KV,D,ps",
+    [(4, 2, 8, 4), (8, 2, 16, 8), (32, 2, 128, 8), (6, 3, 8, 4)],
+    ids=["4over2", "8over2", "nemotron_32over2", "6over3"],
+)
+@pytest.mark.parametrize(
+    "table,lengths",
+    [
+        ([[1, 2, 3], [4, 5, 6]], [3, 2]),  # whole pages (x ps below)
+        ([[7, 1, 5], [3, 8, 2]], [3, 3]),  # fragmented
+        ([[5, 3, 0], [6, 0, 0]], [1.75, 0.5]),  # a part-filled last page, junk tail entries
+    ],
+    ids=["contiguous", "fragmented", "partial"],
+)
+def test_grouped_heads_kernel_matches_reference(H, KV, D, ps, table, lengths):
+    """Fewer key/value heads than query heads: the pools' rows hold ``KV x
+    D`` values, the kernel's block-diagonal query has ``H / KV`` rows
+    against each key/value head's columns, and query head ``i`` reads
+    key/value head ``i // (H / KV)``: the kernel against the gather
+    reference, and the reference against a plain per-lane softmax with the
+    key/value heads repeated (1e-5: float32 sums in another order)."""
+    rng = np.random.default_rng(H * KV + ps)
+    N = 9
+    kp = jnp.asarray(rng.normal(size=(N, ps, KV * D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(N, ps, KV * D)), jnp.float32)
+    B = len(table)
+    q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
+    t = jnp.asarray(table, jnp.int32)
+    ln = jnp.asarray([max(1, int(n * ps)) for n in lengths], jnp.int32)
+    ref = paged_attention_reference(q, kp, vp, t, ln)
+    ker = paged_decode_attention(q, kp, vp, t, ln, interpret=True)
+    assert ker.shape == q.shape
+    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=1e-5)
+    for b in range(B):
+        n = int(ln[b])
+        rows = np.concatenate([np.asarray(kp)[i] for i in np.asarray(t)[b]])[:n].reshape(n, KV, D)
+        vals = np.concatenate([np.asarray(vp)[i] for i in np.asarray(t)[b]])[:n].reshape(n, KV, D)
+        k_b, v_b = np.repeat(rows, H // KV, axis=1), np.repeat(vals, H // KV, axis=1)
+        sc = np.einsum("hd,shd->hs", np.asarray(q)[b, 0], k_b) / np.sqrt(D)
+        pr = np.exp(sc - sc.max(-1, keepdims=True))
+        pr /= pr.sum(-1, keepdims=True)
+        np.testing.assert_allclose(
+            np.asarray(ref)[b, 0], np.einsum("hs,shd->hd", pr, v_b), atol=1e-5
+        )
+    # four-dimensional pools enter through the same reshape
+    np.testing.assert_array_equal(
+        np.asarray(paged_decode_attention(
+            q, kp.reshape(N, ps, KV, D), vp.reshape(N, ps, KV, D), t, ln, interpret=True
+        )),
+        np.asarray(ker),
+    )
+
+
+def test_grouped_heads_refuse_a_pool_that_is_no_divisor():
+    q = jnp.zeros((1, 1, 4, 8))
+    pool = jnp.zeros((3, 4, 3 * 8))  # three key/value heads under four query heads
+    with pytest.raises(ValueError, match="whole key/value heads"):
+        paged_decode_attention(
+            q, pool, pool, jnp.zeros((1, 2), jnp.int32), jnp.ones((1,), jnp.int32), interpret=True
+        )

@@ -258,6 +258,9 @@ def test_the_engine_s_pushes_land_in_the_first_snapshot_s_buffers(setup):
     the first snapshot's did, the retired arrays are deleted, the copy was
     traced once, and the values are the source's."""
     eng = _engine(setup)
+    # jit caches by the wrapped function: another engine of this process
+    # (any test file that shared the worker) may have traced it already
+    eng._copy_over.clear_cache()
     first, _ = eng._snapshot_params()
     where = pointers(first)
     retired = first

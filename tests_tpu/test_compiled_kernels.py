@@ -635,6 +635,88 @@ def test_paged_decode_latent_ragged_at_the_cells_geometry():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
+def test_paged_decode_grouped_heads_at_the_cells_geometry():
+    """Grouped key/value heads (ISSUE 40) compiled at
+    ``nemotron_group_rollout``'s geometry: 96 lanes, 32 query heads of 128
+    over 2 key/value heads, pools ``[12289, 8, 256]`` float32, lanes whose
+    lengths sit on and around the block's boundaries with dead lanes
+    between them.  The block-diagonal query has 16 rows a key/value head;
+    the gather reference repeats each key/value head under its query
+    heads and needs ``highest`` to be an oracle."""
+    from scalerl_tpu.ops.pallas_paged_attention import (
+        paged_attention_reference,
+        paged_decode_attention,
+    )
+
+    B, H, KV, D, ps, M = 96, 32, 2, 128, 8, 128
+    N = B * M + 1
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(40), 3)
+    q = _rand(k1, B, 1, H, D)
+    k_pages = _rand(k2, N, ps, KV * D)
+    v_pages = _rand(k3, N, ps, KV * D)
+    rng = np.random.default_rng(40)
+    table = jnp.asarray(
+        rng.permutation(np.arange(1, N))[: B * M].reshape(B, M), jnp.int32
+    )
+    mix = [1, 7, 64, 65, 511, 1023, 1024, 1, 127, 128, 129, 1, 256, 257, 640, 1, 385]
+    lengths = jnp.asarray([mix[b % len(mix)] for b in range(B)], jnp.int32)
+    out = paged_decode_attention(
+        q, k_pages, v_pages, table, lengths, interpret=False
+    )
+    with jax.default_matmul_precision("highest"):
+        ref = paged_attention_reference(q, k_pages, v_pages, table, lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    # a query head reads ITS key/value head: head 15 the first, head 16 the second
+    only_first = paged_decode_attention(
+        q, k_pages.at[:, :, D:].set(0.0), v_pages.at[:, :, D:].set(0.0), table, lengths,
+        interpret=False,
+    )
+    np.testing.assert_allclose(
+        np.asarray(only_first[:, :, :16]), np.asarray(out[:, :, :16]), atol=1e-5
+    )
+    assert float(jnp.max(jnp.abs(only_first[:, :, 16:] - out[:, :, 16:]))) > 1e-2
+
+
+@pytest.mark.parametrize("lanes", [8, 96])
+def test_ssm_decode_update_compiled(lanes):
+    """The Mamba-2 decode update (ISSUE 40; plain ``jax.numpy``, one XLA
+    fusion) compiled on the chip at Nemotron-3-Nano's sizes (64 heads of
+    64, state 128, 8 groups) against the recurrence written out in float64
+    on the host, twice in a row so that the second call reads what the
+    first wrote in place; the state's buffer is donated.  1e-4: float32
+    sums of 128 products, on values of order ten."""
+    from scalerl_tpu.models.transformer import ssm_decode_update
+
+    H, P, N, G = 64, 64, 128, 8
+    k = jax.random.split(jax.random.PRNGKey(lanes), 7)
+    state = _rand(k[0], lanes, H, P, N)
+    x = _rand(k[1], lanes, H, P)
+    dt = jax.nn.softplus(_rand(k[2], lanes, H) - 3.0)
+    A = -jnp.exp(jax.random.uniform(k[3], (H,), minval=0.0, maxval=2.7))
+    Bm, Cm = _rand(k[4], lanes, G, N), _rand(k[5], lanes, G, N)
+    D = jnp.ones((H,))
+
+    def written_out(s):
+        x64, dt64 = np.asarray(x, np.float64), np.asarray(dt, np.float64)
+        Bh = np.repeat(np.asarray(Bm, np.float64), H // G, axis=1)
+        Ch = np.repeat(np.asarray(Cm, np.float64), H // G, axis=1)
+        s = (
+            np.exp(dt64 * np.asarray(A, np.float64))[:, :, None, None] * s
+            + (dt64[:, :, None] * x64)[..., None] * Bh[:, :, None, :]
+        )
+        return np.einsum("lhpn,lhn->lhp", s, Ch) + x64, s
+
+    y_ref, s_ref = written_out(np.asarray(state, np.float64))
+    y_ref2, s_ref2 = written_out(s_ref)
+    step = jax.jit(lambda s: ssm_decode_update(s, x, dt, A, Bm, Cm, D), donate_argnums=0)
+    y1, s1 = step(state + 0.0)
+    np.testing.assert_allclose(np.asarray(y1), y_ref, atol=1e-4, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(s1), s_ref, atol=1e-5, rtol=1e-5)
+    y2, s2 = step(s1)
+    np.testing.assert_allclose(np.asarray(y2), y_ref2, atol=1e-4, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(s2), s_ref2, atol=1e-5, rtol=1e-5)
+
+
 @pytest.mark.usefixtures("f32_matmuls")
 def test_continuous_engine_macro_step_on_tpu():
     """One continuous-batching macro-step compiled on the chip: paged
