@@ -27,6 +27,7 @@ from scalerl_tpu.parallel.sharding import (
     param_sharding,
     replicated,
 )
+from scalerl_tpu.runtime import tracing
 
 
 # ---------------------------------------------------------------------------
@@ -177,6 +178,35 @@ def maybe_guard_nonfinite(learn_fn: Callable, args: Any) -> Callable:
     return learn_fn
 
 
+# XLA:TPU compile options of the sharded learn program, and of no other.
+# Without them every all-reduce of the step stops the chip's instruction
+# stream until the link is done.  AOT for ``v5e:2x2``, gpt2-large at
+# ``dp=2 x mp=2``, 4 rows of 1,024 (PR 41): 162 synchronous ``all-reduce``
+# and no asynchronous one without them; with them 41 of the backward's 72
+# ``mp`` activation reductions run inside 137 ``async_collective_fusion``
+# computations, each around one weight-gradient matmul that does not depend
+# on them.  Either name alone gives the parent's text byte for byte; the
+# two give what ISSUE 41's seven gave, byte for byte (PERF.md, PR 41).
+ASYNC_COLLECTIVE_OPTIONS: Tuple[str, ...] = (
+    # an all-reduce may be split into a start and a done at all
+    "xla_enable_async_all_reduce",
+    # the pass that fuses a collective with independent work takes
+    # all-reduces too (the pass and its several-steps form are on already:
+    # naming them as well changes nothing in the text)
+    "xla_tpu_enable_async_collective_fusion_fuse_all_reduce",
+)
+
+
+def mesh_compile_options(mesh) -> Tuple[str, ...]:
+    """Names of the compile options a learn program on ``mesh`` takes:
+    :data:`ASYNC_COLLECTIVE_OPTIONS` where the mesh is several TPU devices,
+    none anywhere else (one device has no collective to hide, and XLA:CPU
+    refuses the ``xla_tpu_*`` names)."""
+    if mesh.devices.size > 1 and all(d.platform == "tpu" for d in mesh.devices.flat):
+        return ASYNC_COLLECTIVE_OPTIONS
+    return ()
+
+
 def make_parallel_learn_fn(
     learn_fn: Callable[[Any, Any], Tuple[Any, Any]],
     mesh,
@@ -203,7 +233,9 @@ def make_parallel_learn_fn(
       its mesh layout (counters replicated);
     - ``.shard_batch(batch)`` — device_put a host batch pytree with its
       batch dim split over ``dp×fsdp`` (dim 1 for time-major trajectories);
-    - ``.state_sharding`` / ``.batch_sharding`` — the NamedSharding pytrees.
+    - ``.state_sharding`` / ``.batch_sharding`` — the NamedSharding pytrees;
+    - ``.compile_options`` — the names :func:`mesh_compile_options` chose
+      for this mesh and this one program (each passed as ``True``).
     """
     st_sh = param_specs if param_specs is not None else param_sharding(state_example, mesh)
     if batch_example is not None:
@@ -216,11 +248,20 @@ def make_parallel_learn_fn(
         data_sh = None
     rep = replicated(mesh)
 
+    compile_options = mesh_compile_options(mesh)
+    # zero-length, once a program built: which compile this mesh's learn
+    # program is, for a run's trace and span totals
+    with tracing.span(
+        "learn.compile_options", kind="learn", names=list(compile_options),
+        devices=int(mesh.devices.size),
+    ):
+        pass
     jitted = jax.jit(
         learn_fn,
         in_shardings=(st_sh, data_sh),
         out_shardings=(st_sh, rep),
         donate_argnums=(0,) if donate_state else (),
+        compiler_options={name: True for name in compile_options} or None,
     )
 
     def shard_state(state: Any) -> Any:
@@ -272,6 +313,7 @@ def make_parallel_learn_fn(
     jitted.shard_batch = shard_batch  # type: ignore[attr-defined]
     jitted.state_sharding = st_sh  # type: ignore[attr-defined]
     jitted.batch_sharding = data_sh  # type: ignore[attr-defined]
+    jitted.compile_options = compile_options  # type: ignore[attr-defined]
     return jitted
 
 

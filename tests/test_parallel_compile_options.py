@@ -1,0 +1,317 @@
+"""The sharded learn program's compile options (ISSUE 41).
+
+``make_parallel_learn_fn`` compiles its ONE program with asynchronous
+collectives when the mesh it is handed is several TPU devices, and makes
+the parent's call everywhere else.  On the CPU meshes of this suite that
+means: no option reaches ``jax.jit`` and the outputs are the plain call's
+bit for bit.  What the options do to a program shows only in a text
+compiled for the chip, so the last test compiles a small ``dp=2 x mp=2``
+learn step for a described ``v5e:2x2`` (no chip is needed, nothing runs;
+skipped where the topology cannot be described) and reads the text.
+
+The topology is described inside a fixture, never at import
+(``tests/test_decode_program_layout.py`` says why).
+"""
+
+import os
+import re
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from scalerl_tpu.agents.token_ppo import TokenPPOAgent
+from scalerl_tpu.config import GenRLArguments
+from scalerl_tpu.genrl.rollout import packed_field_shapes
+from scalerl_tpu.models.transformer import TransformerPolicy
+from scalerl_tpu.parallel import make_mesh, train_step
+from scalerl_tpu.parallel.sharding import replicated
+from scalerl_tpu.parallel.train_step import (
+    ASYNC_COLLECTIVE_OPTIONS,
+    make_parallel_learn_fn,
+    mesh_compile_options,
+)
+
+ROWS, S, VOCAB, WIDTH = 4, 32, 64, 128
+
+
+def _agent(width=WIDTH, seq=S, vocab=VOCAB):
+    """A 2-layer token learner with 4 heads, no kernel."""
+    args = GenRLArguments(
+        vocab_size=vocab, d_model=width, n_layers=2, n_heads=4, prompt_len=seq // 2,
+        max_new_tokens=seq // 2, telemetry_interval_s=0.0, logger_backend="none",
+    )
+    model = TransformerPolicy(
+        num_actions=vocab, vocab_size=vocab, d_model=width, num_heads=4, num_layers=2,
+        max_len=seq,
+    )
+    return TokenPPOAgent(args, model)
+
+
+def _packed_batch(seq=S, vocab=VOCAB, rows=ROWS):
+    """Rows of two packed sequences each, a response at the end of both."""
+    rng = np.random.default_rng(0)
+    half = seq // 2
+    seg = np.repeat(np.array([[1, 2]], np.int32), half, axis=1).repeat(rows, axis=0)
+    pos = np.tile(np.arange(half, dtype=np.int32), (rows, 2))
+    mask = (pos >= half // 2).astype(np.float32)
+    batch = {
+        "tokens": rng.integers(1, vocab, (rows, seq)).astype(np.int32),
+        "segment_ids": seg,
+        "positions": pos,
+        "behavior_logp": np.log(rng.uniform(0.05, 0.5, (rows, seq))).astype(np.float32) * mask,
+        "value": rng.normal(size=(rows, seq)).astype(np.float32) * mask,
+        "mask": mask,
+        "reward": rng.normal(size=(rows, seq)).astype(np.float32) * mask,
+        "generation": np.zeros((rows, seq), np.int32),
+    }
+    assert set(batch) == set(packed_field_shapes(seq))
+    return {k: jnp.asarray(v) for k, v in batch.items()}
+
+
+def _fake_mesh(*platforms):
+    return SimpleNamespace(
+        devices=np.array([SimpleNamespace(platform=p) for p in platforms], dtype=object)
+    )
+
+
+@pytest.mark.parametrize(
+    "platforms, chosen",
+    [
+        (("tpu",) * 4, ASYNC_COLLECTIVE_OPTIONS),
+        (("tpu",), ()),  # one chip has no collective to hide
+        (("cpu",) * 4, ()),  # XLA:CPU refuses the xla_tpu_* names
+        (("tpu", "tpu", "cpu", "cpu"), ()),
+    ],
+    ids=["tpu4", "tpu1", "cpu4", "mixed"],
+)
+def test_options_are_chosen_from_the_meshs_devices(platforms, chosen):
+    assert mesh_compile_options(_fake_mesh(*platforms)) == chosen
+
+
+def test_the_options_reach_jit_untouched_and_the_program_says_which(monkeypatch):
+    """Whatever names the mesh's devices choose go to ``jax.jit`` as they
+    are, each set to ``True``; the callable says which they were, and so
+    does one zero-length ``learn.compile_options`` span a program built.
+    (The choice is made for a CPU mesh here and ``jit`` is kept from seeing
+    it: XLA:CPU knows none of the names.)"""
+    from scalerl_tpu.runtime import tracing
+
+    seen, notes = {}, []
+    real_jit, real_span = jax.jit, tracing.span
+
+    def spy_jit(fun, **kw):
+        seen.update(kw)
+        kw.pop("compiler_options")
+        return real_jit(fun, **kw)
+
+    def spy_span(name, kind="", **attrs):
+        notes.append((name, attrs))
+        return real_span(name, kind, **attrs)
+
+    monkeypatch.setattr(train_step, "mesh_compile_options", lambda mesh: ASYNC_COLLECTIVE_OPTIONS)
+    monkeypatch.setattr(jax, "jit", spy_jit)
+    monkeypatch.setattr(tracing, "span", spy_span)
+    mesh = make_mesh("dp=4", jax.devices()[:4])
+    state = {"w": jnp.ones((8, 4))}
+    plearn = make_parallel_learn_fn(
+        lambda st, b: (st, jnp.sum(b)), mesh, state, batch_time_major=False
+    )
+    for name in ASYNC_COLLECTIVE_OPTIONS:
+        assert isinstance(name, str) and name == name.strip() and name.startswith("xla_")
+    assert seen["compiler_options"] == {name: True for name in ASYNC_COLLECTIVE_OPTIONS}
+    assert tuple(seen["compiler_options"]) == ASYNC_COLLECTIVE_OPTIONS
+    assert plearn.compile_options is ASYNC_COLLECTIVE_OPTIONS
+    assert notes == [
+        ("learn.compile_options", {"names": list(ASYNC_COLLECTIVE_OPTIONS), "devices": 4})
+    ]
+
+
+@pytest.mark.parametrize("spec", ["dp=4", "dp=2,mp=2"])
+def test_cpu_mesh_is_the_parents_call_bit_for_bit(spec, monkeypatch):
+    """A CPU mesh takes no option at all, and one learn step of a tiny
+    transformer gives what the parent's ``jax.jit`` call gives: the same
+    loss, metrics and updated state, bit for bit."""
+    jit_kwargs = []
+    real_jit = jax.jit
+
+    def spy(fun, **kw):
+        jit_kwargs.append(kw)
+        return real_jit(fun, **kw)
+
+    agent = _agent()
+    mesh = make_mesh(spec, jax.devices()[:4])
+    monkeypatch.setattr(jax, "jit", spy)
+    agent.enable_mesh(mesh)
+    monkeypatch.undo()
+    (kw,) = [k for k in jit_kwargs if "in_shardings" in k]
+    assert not kw.get("compiler_options")
+    assert agent._learn.compile_options == ()
+
+    plearn = make_parallel_learn_fn(
+        agent._learn_fn, mesh, agent.state, batch_time_major=False,
+        param_specs=agent._learn.state_sharding, donate_state=False,
+    )
+    parent = jax.jit(
+        agent._learn_fn,
+        in_shardings=(plearn.state_sharding, None),
+        out_shardings=(plearn.state_sharding, replicated(mesh)),
+    )
+    batch = plearn.shard_batch(_packed_batch())
+    got, want = plearn(agent.state, batch), parent(agent.state, batch)
+    assert np.isfinite(float(got[1]["total_loss"]))
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want), strict=True):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_a_meshed_learner_notes_its_compile_once_and_never_on_a_step():
+    """``enable_mesh`` builds one program and the span totals count one
+    ``learn.compile_options`` for it; learn steps add none."""
+    from scalerl_tpu.runtime import tracing
+
+    count = lambda: tracing.span_totals().get("learn.compile_options", {}).get("count", 0)  # noqa: E731
+    agent = _agent()
+    before = count()
+    agent.enable_mesh(make_mesh("dp=2,mp=2", jax.devices()[:4]))
+    assert count() == before + 1
+    for _ in range(2):
+        agent.learn_device(_packed_batch())
+    assert count() == before + 1
+
+
+# -- the text compiled for the chip -------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def tpu_devices():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever stops the description
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices
+
+
+@pytest.fixture
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _channels(text):
+    """``{channel: opcode}`` of every collective instruction in a compiled
+    text, steps inside asynchronous fusions included."""
+    return {
+        m.group(2): m.group(1)
+        for m in re.finditer(
+            r" (all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+            r"(?:-start)?\(.*?channel_id=(\d+)", text,
+        )
+    }
+
+
+def test_described_tpu_mesh_compiles_with_async_collective_fusions(
+    tpu_devices, _no_persistent_cache, monkeypatch
+):
+    """On a ``dp=2 x mp=2`` mesh of described v5e devices the learn step of
+    a 2-layer, 128-wide token learner is compiled with the options, and its
+    text holds asynchronous collective fusions around the same reductions
+    the parent's call leaves synchronous."""
+    real_put = jax.device_put
+
+    def described_put(x, device=None, **kw):
+        # nothing can be placed on a described device: give the shape
+        def one(leaf, sh):
+            if sh is None or not set(tpu_devices) & set(sh.device_set):
+                return real_put(leaf, sh, **kw)
+            return jax.ShapeDtypeStruct(np.shape(leaf), jnp.result_type(leaf), sharding=sh)
+
+        if device is None or isinstance(device, jax.sharding.Sharding):
+            return jax.tree_util.tree_map(lambda leaf: one(leaf, device), x)
+        return jax.tree_util.tree_map(one, x, device)
+
+    monkeypatch.setattr(jax, "device_put", described_put)
+    agent = _agent(seq=256, vocab=512)
+    mesh = make_mesh("dp=2,mp=2", list(tpu_devices))
+    agent.enable_mesh(mesh)
+    assert agent._learn.compile_options is ASYNC_COLLECTIVE_OPTIONS
+    batch = _packed_batch(seq=256, vocab=512)
+    text = agent.lower_learn(batch).compile().as_text()
+
+    parent = jax.jit(
+        agent._learn_fn,
+        in_shardings=(agent._learn.state_sharding, None),
+        out_shardings=(agent._learn.state_sharding, replicated(mesh)),
+        donate_argnums=(0,),
+    )
+    parent_text = parent.lower(agent.state, agent._shard_batch(batch)).compile().as_text()
+
+    # (the computation's name, not the word: this test's own name is in
+    # both texts' stack frames)
+    assert parent_text.count("calls=%async_collective_fusion") == 0
+    assert text.count("calls=%async_collective_fusion") >= 1
+    # the same collectives, a reduction under several fusions counted once
+    assert _channels(text) == _channels(parent_text)
+    assert {"all-reduce", "all-to-all", "collective-permute"} <= set(_channels(text).values())
+
+
+@pytest.mark.slow  # two more whole XLA:TPU compiles: PERF.md (PR 41) keeps what they showed
+@pytest.mark.parametrize("spec, same_text", [("dp=4", True), ("dp=2,fsdp=2", False)])
+def test_described_tpu_mesh_of_the_classic_family_keeps_its_collectives(
+    tpu_devices, _no_persistent_cache, spec, same_text
+):
+    """Every agent family's ``enable_mesh`` comes through the same function.
+    An IMPALA AtariNet learn step on a pure ``dp`` mesh has one combined
+    gradient all-reduce, which the pass does not take: its text is the
+    parent's.  With ``fsdp`` the text moves (an activation all-reduce goes
+    into asynchronous fusions), and the collectives stay the same ones."""
+    from scalerl_tpu.agents.impala import ImpalaAgent, make_impala_learn_fn
+    from scalerl_tpu.config import ImpalaArguments
+    from scalerl_tpu.data.trajectory import Trajectory
+
+    T, B = 4, 16
+    args = ImpalaArguments(
+        use_lstm=False, hidden_size=64, rollout_length=T, batch_size=B, max_timesteps=0
+    )
+    agent = ImpalaAgent(args, obs_shape=(84, 84, 4), num_actions=6)
+    learn = make_impala_learn_fn(agent.model, agent.optimizer, args)
+    traj = Trajectory(
+        obs=jnp.zeros((T + 1, B, 84, 84, 4), jnp.uint8),
+        action=jnp.zeros((T + 1, B), jnp.int32),
+        reward=jnp.zeros((T + 1, B), jnp.float32),
+        done=jnp.zeros((T + 1, B), jnp.bool_),
+        logits=jnp.zeros((T + 1, B, 6), jnp.float32),
+        core_state=agent.initial_state(B),
+    )
+    mesh = make_mesh(spec, list(tpu_devices))
+    plearn = make_parallel_learn_fn(learn, mesh, agent.state, batch_example=traj)
+    assert plearn.compile_options is ASYNC_COLLECTIVE_OPTIONS
+    parent = jax.jit(
+        learn,
+        in_shardings=(plearn.state_sharding, plearn.batch_sharding),
+        out_shardings=(plearn.state_sharding, replicated(mesh)),
+        donate_argnums=(0,),
+    )
+    described = lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)  # noqa: E731
+    operands = (
+        jax.tree_util.tree_map(described, agent.state, plearn.state_sharding),
+        jax.tree_util.tree_map(described, traj, plearn.batch_sharding),
+    )
+    text, parent_text = (
+        re.sub(r"metadata=\{[^}]*\}", "", fn.lower(*operands).compile().as_text())
+        for fn in (plearn, parent)
+    )
+    assert _channels(text) == _channels(parent_text) and _channels(text)
+    assert (text == parent_text) == same_text

@@ -978,6 +978,75 @@ def test_token_learner_guard_decides_before_the_update_on_tpu(bf16):
     assert bool(tree_all_finite(agent.state))
 
 
+def test_async_collectives_reschedule_the_learn_step_and_keep_its_sums():
+    """ISSUE 41: on the four-chip host ``make_parallel_learn_fn`` compiles
+    the ``dp=2 x mp=2`` learn program with asynchronous collectives.  The
+    reductions are the parent's (operands, float32, groups), run earlier:
+    one step of a small packed token learner with the options and one by
+    the parent's plain ``jax.jit`` call give the same loss, gradient norm
+    and updated ``block_0/qkv/kernel``, and only the first text holds
+    asynchronous collective fusions."""
+    from scalerl_tpu.agents.token_ppo import TokenPPOAgent
+    from scalerl_tpu.config import GenRLArguments
+    from scalerl_tpu.genrl.rollout import pack_learner_batch
+    from scalerl_tpu.parallel.sharding import replicated
+    from scalerl_tpu.parallel.train_step import (
+        ASYNC_COLLECTIVE_OPTIONS,
+        make_parallel_learn_fn,
+    )
+    from scalerl_tpu.trainer.sequence_rl import build_genrl_model
+
+    mesh = _mesh("dp=2,mp=2", 4)
+    V, S, n, lr = 640, 256, 16, 1e-3
+    args = GenRLArguments(
+        vocab_size=V, d_model=128, n_layers=2, n_heads=4, prompt_len=32,
+        max_new_tokens=32, learner_packing=True, learner_pack_len=S,
+        adv_norm=False, learning_rate=lr, telemetry_interval_s=0.0,
+        logger_backend="none",
+    )
+    agent = TokenPPOAgent(args, build_genrl_model(args))
+    agent.enable_mesh(mesh)
+    assert agent._learn.compile_options is ASYNC_COLLECTIVE_OPTIONS
+    rng = np.random.default_rng(41)
+    plens, rlens = rng.integers(8, 33, n), rng.integers(8, 33, n)
+    pk = pack_learner_batch(
+        [rng.integers(1, V, k).astype(np.int32) for k in plens],
+        [rng.integers(1, V, k).astype(np.int32) for k in rlens],
+        [np.log(rng.uniform(0.05, 0.5, k)).astype(np.float32) for k in rlens],
+        [rng.normal(0, 0.1, k).astype(np.float32) for k in rlens],
+        rng.uniform(0, 1, n).astype(np.float32), np.zeros(n, np.int32), pack_len=S,
+    )
+    fields, _ = pk.bucketed(4).fields()  # rows divide by dp
+    batch = agent._shard_batch({k: jnp.asarray(v) for k, v in fields.items()})
+
+    st_sh = agent._learn.state_sharding
+    with_options = make_parallel_learn_fn(
+        agent._learn_fn, mesh, agent.state, batch_time_major=False,
+        param_specs=st_sh, donate_state=False,
+    )
+    parent = jax.jit(
+        agent._learn_fn, in_shardings=(st_sh, None), out_shardings=(st_sh, replicated(mesh))
+    )
+    # each program is compiled once: its text is read, then it runs
+    with_options, parent = (
+        fn.lower(agent.state, batch).compile() for fn in (with_options, parent)
+    )
+    fusions = lambda c: c.as_text().count("calls=%async_collective_fusion")  # noqa: E731
+    assert fusions(parent) == 0 and fusions(with_options) >= 1
+
+    (new_a, m_a), (new_b, m_b) = with_options(agent.state, batch), parent(agent.state, batch)
+    assert m_a["skipped_steps"] == 0.0 and np.isfinite(m_a["total_loss"])
+    for name in ("total_loss", "grad_norm"):
+        np.testing.assert_allclose(float(m_a[name]), float(m_b[name]), rtol=1e-5)
+    kernel = lambda st: np.asarray(st.params["params"]["block_0"]["qkv"]["kernel"])  # noqa: E731
+    old = kernel(agent.state)
+    # Adam's first step moves a weight by lr x g / (|g| + 1e-8): an element
+    # whose gradient is a rounding away from zero may differ by a fraction
+    # of one update, never by the 2 x lr a flipped sign would cost
+    np.testing.assert_allclose(kernel(new_a), kernel(new_b), rtol=0, atol=0.05 * lr)
+    assert np.abs(kernel(new_a) - old).max() > 0.5 * lr
+
+
 def test_engine_push_overwrites_the_snapshot_behind_a_macro_step_in_flight():
     """ISSUE 38: the engine's push is one program whose outputs take the
     retired snapshot's buffers (a donated operand).  What only the chip's
