@@ -784,6 +784,18 @@ class GenRLArguments(RLArguments):
     # engine keeps a Mamba layer's recurrent state by lane beside the KV
     # pages, so such a model is admitted by local prefill and group fork
     # alone: no prefix-cache hit is served and speculation is refused.
+    # "qwen3_next" = plain layers (mixer, then routed FFN) whose mixer is
+    # full attention at every ``full_attention_interval``-th layer and a
+    # Gated DeltaNet at the others: a linear-attention layer whose state,
+    # a ``ssm_state x ssm_head_dim`` matrix a value head, is updated by a
+    # gated delta rule and kept by lane as a Mamba layer's is (the
+    # ``ssm_*`` sizes: ``ssm_heads`` value heads, ``ssm_groups`` key
+    # heads).  Its attention has ``kv_heads`` key/value heads, a sigmoid
+    # output gate carried by the query projection, an RMSNorm over each
+    # head of q and k and a rotary over a head's first ``rotary_dim``
+    # features; its RMSNorm scales are stored zero-centred; its router is
+    # a softmax over ``moe_experts`` with ``moe_experts_held`` held, beside
+    # a shared expert of ``moe_shared_width`` behind a sigmoid scalar gate.
     block_family: str = "gpt2"
     head_dim: int = 0
     rms_norm_eps: float = 1e-5
@@ -813,6 +825,8 @@ class GenRLArguments(RLArguments):
     ssm_chunk: int = 128
     moe_expert_act: str = "swiglu"
     moe_shared_width: int = 0
+    full_attention_interval: int = 0
+    rotary_dim: int = 0
     mtp_layers: int = 0
     mtp_loss_coef: float = 0.1
     # weight of the router's load-balancing loss in the learner's total
@@ -939,13 +953,15 @@ class GenRLArguments(RLArguments):
                 f"{self.temperature}"
             )
         if self.block_family not in (
-            "gpt2", "olmoe", "longcat", "joyai", "nemotron_h"
+            "gpt2", "olmoe", "longcat", "joyai", "nemotron_h", "qwen3_next"
         ):
             raise ValueError(
-                "block_family must be gpt2 | olmoe | longcat | joyai | "
-                f"nemotron_h, got {self.block_family!r}"
+                "block_family must be one of the six families gpt2 | olmoe | "
+                "longcat | joyai | nemotron_h | qwen3_next, got "
+                f"{self.block_family!r}"
             )
         hybrid = self.block_family == "nemotron_h"
+        delta = self.block_family == "qwen3_next"
         if hybrid and (
             not self.layer_pattern
             or set(self.layer_pattern) - set("ME*-")
@@ -957,13 +973,23 @@ class GenRLArguments(RLArguments):
                 f"({self.n_layers}), got {self.layer_pattern!r}"
             )
         if not hybrid and (
-            self.layer_pattern or self.kv_heads or self.ssm_heads
-            or self.moe_expert_act != "swiglu" or self.moe_shared_width
+            self.layer_pattern or self.moe_expert_act != "swiglu"
+            or (not delta and (self.kv_heads or self.ssm_heads or self.moe_shared_width))
         ):
             raise ValueError(
-                "layer_pattern, kv_heads, the ssm sizes, moe_expert_act and "
-                "moe_shared_width are the nemotron_h family's, got them with "
-                f"{self.block_family!r}"
+                "layer_pattern and moe_expert_act are the nemotron_h family's, "
+                "kv_heads, the ssm sizes and moe_shared_width that family's "
+                f"and qwen3_next's, got them with {self.block_family!r}"
+            )
+        if delta and not 1 <= self.full_attention_interval <= self.n_layers:
+            raise ValueError(
+                "the qwen3_next family needs full_attention_interval in "
+                f"1..n_layers ({self.n_layers}), got {self.full_attention_interval}"
+            )
+        if not delta and (self.full_attention_interval or self.rotary_dim):
+            raise ValueError(
+                "full_attention_interval and rotary_dim are the qwen3_next "
+                f"family's, got them with {self.block_family!r}"
             )
         if self.moe_expert_act not in ("swiglu", "relu2"):
             raise ValueError(
@@ -974,11 +1000,15 @@ class GenRLArguments(RLArguments):
                 "kv_heads must divide n_heads (0: one each), got "
                 f"{self.kv_heads}/{self.n_heads}"
             )
-        if hybrid and "M" in self.layer_pattern and self.spec_enable:
+        recurrent = (hybrid and "M" in self.layer_pattern) or (
+            delta and self.full_attention_interval > 1
+        )
+        if recurrent and self.spec_enable:
             raise ValueError(
-                "spec_enable cannot serve a model with a recurrent (Mamba) "
-                "layer: a rejected draft is undone by moving a page cursor "
-                "back, and a recurrent state has no cursor to rewind"
+                "spec_enable cannot serve a model with a recurrent layer (a "
+                "Mamba-2 or a Gated DeltaNet mixer): a rejected draft is "
+                "undone by moving a page cursor back, and a recurrent state "
+                "has no cursor to rewind"
             )
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(
@@ -996,12 +1026,12 @@ class GenRLArguments(RLArguments):
             )
         if self.block_family != "joyai" and (
             self.dense_layers or self.mtp_layers
-            or (self.moe_shared_experts and not hybrid)
+            or (self.moe_shared_experts and not (hybrid or delta))
         ):
             raise ValueError(
                 "dense_layers and mtp_layers are the joyai family's and "
-                "moe_shared_experts joyai's and nemotron_h's, got them with "
-                f"{self.block_family!r}"
+                "moe_shared_experts joyai's, nemotron_h's and qwen3_next's, "
+                f"got them with {self.block_family!r}"
             )
         if self.head_dim < 0 or self.router_aux_loss_coef < 0:
             raise ValueError(

@@ -45,10 +45,11 @@ the host swaps *sequences* through them —
   (exhaustion backpressures, never corrupts; shared pages count against
   EVERY holder's reservation, so sharing never loosens the guarantee)
   while physical pages are drawn lazily as contexts grow.
-- **recurrent state beside the pages** -- a stack with Mamba layers
-  (``model.recurrent``) caches into a :class:`~scalerl_tpu.models
-  .transformer.HybridCache`: page pools for its attention layers and, for
-  each Mamba layer, a float32 state indexed by LANE whose size does not
+- **recurrent state beside the pages** -- a stack with recurrent layers
+  (``model.recurrent``: Mamba-2 or Gated DeltaNet mixers) caches into a
+  :class:`~scalerl_tpu.models.transformer.HybridCache`: page pools for its
+  attention layers and, for each recurrent layer whatever its kind, a
+  float32 state indexed by LANE whose size does not
   depend on a lane's length.  It rides in the same pytree as the pools
   (donated through every program, never copied whole): the local prefill
   writes a lane's rows at the prompt's true length, every decode substep
@@ -366,7 +367,7 @@ class ContinuousEngine(ParamSnapshotPlane):
         if self._recurrent and config.spec_k:
             raise ValueError(
                 "speculation (spec_k > 0) cannot serve a model with a "
-                "recurrent (Mamba) layer: a rejected draft is undone by "
+                "recurrent layer: a rejected draft is undone by "
                 "moving a page cursor back, and a recurrent state has no "
                 "cursor to rewind"
             )

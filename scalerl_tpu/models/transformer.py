@@ -26,6 +26,7 @@ from jax import lax
 
 from scalerl_tpu.models.routed_ffn import RoutedExperts
 from scalerl_tpu.ops.pallas_attention import flash_attention
+from scalerl_tpu.ops.pallas_gdn import gdn_decode_update_pallas, heads_per_block
 from scalerl_tpu.ops.pallas_paged_attention import (
     gather_pages,
     latent_attention,
@@ -83,13 +84,29 @@ class BlockSpec:
     layers carry the order).  ``expert_act="relu2"`` makes every expert
     and dense FFN of the spec ``relu(h W_up)^2 W_down``, two matrices and
     no gate; ``shared_width`` is the always-on expert's own width.
+
+    A PLAIN layer's mixer may be recurrent too: ``mixer="gdn"`` puts a
+    Gated DeltaNet (:class:`_GatedDeltaMixer`) where the attention sits
+    (``"attention"`` or ``""``: attention), and :func:`interval_specs`
+    lays such a stack out.  The rule reuses the ``ssm_*`` sizes, whose
+    meaning is the same: ``ssm_heads`` value heads of ``ssm_head_dim``,
+    ``ssm_groups`` key heads (key head ``j`` serves value heads ``j x
+    heads / groups`` on, as a Mamba group does) of ``ssm_state`` features,
+    so that a head's state is ``[ssm_state, ssm_head_dim]`` (key x value);
+    ``ssm_conv`` taps, ``ssm_chunk`` tokens a chunk.  That family's
+    attention: ``qk_norm="head"`` norms q and k over each head's own
+    features, ``rotary_dim`` rotates a head's first features alone (0:
+    all), ``attn_gate`` has the query projection carry a sigmoid gate of
+    the attention's output, ``shared_gate`` puts the shared expert behind
+    a sigmoid scalar, and ``norm_zero_centered`` stores every RMSNorm
+    scale as ``w`` in ``1 + w``.
     """
 
     norm: str = "layernorm"  # layernorm | rmsnorm
     norm_eps: float = 1e-6
     positions: str = "learned"  # learned (a table added to the embedding) | rope | none
     rope_theta: float = 10000.0
-    qk_norm: str = "none"  # none | rmsnorm (over the projection's whole width)
+    qk_norm: str = "none"  # none | rmsnorm (over the projection's whole width) | head (each head's)
     head_dim: Optional[int] = None  # None: d_model // num_heads
     ffn: str = "mlp"  # mlp (GELU, mlp_ratio x d_model) | experts (routed SwiGLU) | swiglu
     num_experts: int = 0
@@ -116,13 +133,38 @@ class BlockSpec:
     kv_heads: int = 0  # 0: as many as query heads
     expert_act: str = "swiglu"  # swiglu | relu2 (experts, shared expert, dense FFN)
     shared_width: int = 0  # 0: shared_experts x expert_width
-    mixer: str = ""  # of a mixer layer: mamba | attention | experts | ffn
+    # of a mixer layer: mamba | attention | experts | ffn; of a plain
+    # layer: gdn | attention ("": attention)
+    mixer: str = ""
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    rotary_dim: int = 0  # a head's leading features that rotate (0: all)
+    attn_gate: bool = False
+    shared_gate: bool = False
+    norm_zero_centered: bool = False
+
+    @property
+    def recurrent(self) -> bool:
+        """Whether the layer carries a state that no page table describes."""
+        return self.mixer in ("mamba", "gdn")
+
+    @property
+    def state_shape(self) -> Tuple[int, ...]:
+        """A lane's recurrent state in this layer: Mamba-2's ``[heads,
+        head_dim, state]``, the delta rule's ``[heads, key, value]``."""
+        if self.mixer == "gdn":
+            return (self.ssm_heads, self.ssm_state, self.ssm_head_dim)
+        return (self.ssm_heads, self.ssm_head_dim, self.ssm_state)
+
+    @property
+    def conv_channels(self) -> int:
+        """What the layer's causal convolution runs over: Mamba-2's ``[x |
+        B | C]``, the delta rule's ``[q | k | v]``."""
+        return self.ssm_heads * self.ssm_head_dim + 2 * self.ssm_groups * self.ssm_state
 
 
 def block_spec(
@@ -156,13 +198,15 @@ def block_spec(
     ssm_groups: int = 1,
     ssm_conv: int = 4,
     ssm_chunk: int = 128,
+    rotary_dim: int = 0,
 ) -> BlockSpec:
     """The block a named family stacks; the sizes only the family reads
     are ignored by the others (``gpt2`` keeps its own epsilon).  For a
     family whose stack has more than one kind of layer this is the kind
     that repeats (``joyai``: the routed layer) and :func:`layer_specs`
     gives the stack; for ``nemotron_h`` it is the expert layer, and
-    :func:`pattern_specs` gives the stack."""
+    :func:`pattern_specs` gives the stack; for ``qwen3_next`` it is the
+    full-attention layer, and :func:`interval_specs` gives the stack."""
     if family == "gpt2":
         return BlockSpec(head_dim=head_dim)
     if family == "olmoe":
@@ -300,9 +344,53 @@ def block_spec(
             ssm_state=ssm_state, ssm_groups=ssm_groups, ssm_conv=ssm_conv,
             ssm_chunk=ssm_chunk,
         )
+    if family == "qwen3_next":
+        held = experts_held or num_experts
+        sizes = (
+            head_dim or 0, expert_width, ssm_heads, ssm_head_dim, ssm_state,
+            ssm_groups, ssm_chunk,
+        )
+        if min(sizes) < 1 or ssm_conv < 2 or ssm_heads % ssm_groups:
+            raise ValueError(
+                "the qwen3_next stack needs a head size, an expert width and "
+                "its delta-rule sizes (value heads a multiple of the key "
+                f"heads, a convolution of 2 taps or more), got {sizes}/{ssm_conv}"
+            )
+        if not (
+            1 <= experts_per_token <= num_experts
+            and 0 <= first_expert
+            and 1 <= held
+            and first_expert + held <= num_experts
+            and shared_experts >= 0
+            and kv_heads >= 0
+            and rotary_dim >= 0
+            and rotary_dim % 2 == 0
+            and rotary_dim <= (head_dim or 0)
+        ):
+            raise ValueError(
+                "the qwen3_next router picks experts_per_token of num_experts "
+                "and holds experts first_expert .. first_expert + experts_held "
+                "beside shared_experts gated always-on ones, and rotates an "
+                "even rotary_dim of a head's features, got "
+                f"{experts_per_token}/{num_experts}/{first_expert}/{held}/"
+                f"{shared_experts}/{rotary_dim}"
+            )
+        return BlockSpec(
+            norm="rmsnorm", norm_eps=norm_eps, positions="rope",
+            rope_theta=rope_theta, qk_norm="head", head_dim=head_dim,
+            ffn="experts", num_experts=num_experts,
+            experts_per_token=experts_per_token, expert_width=expert_width,
+            norm_topk_prob=norm_topk_prob, experts_held=held,
+            first_expert=first_expert, shared_experts=shared_experts,
+            kv_heads=kv_heads, shared_width=shared_width, mixer="attention",
+            ssm_heads=ssm_heads, ssm_head_dim=ssm_head_dim,
+            ssm_state=ssm_state, ssm_groups=ssm_groups, ssm_conv=ssm_conv,
+            ssm_chunk=ssm_chunk, rotary_dim=rotary_dim, attn_gate=True,
+            shared_gate=bool(shared_experts), norm_zero_centered=True,
+        )
     raise ValueError(
-        "block family must be gpt2 | olmoe | longcat | joyai | nemotron_h, "
-        f"got {family!r}"
+        "block family must be gpt2 | olmoe | longcat | joyai | nemotron_h | "
+        f"qwen3_next, got {family!r}"
     )
 
 
@@ -355,6 +443,26 @@ def pattern_specs(spec: BlockSpec, pattern: str) -> Tuple[BlockSpec, ...]:
         },
     }
     return tuple(kinds[ch] for ch in pattern)
+
+
+def interval_specs(
+    spec: BlockSpec, num_layers: int, full_attention_interval: int
+) -> Tuple[BlockSpec, ...]:
+    """A stack of plain layers whose mixer is ``spec``'s attention at
+    every ``full_attention_interval``-th layer (layer ``i`` from 0 where
+    ``(i + 1) % interval == 0``) and a Gated DeltaNet at the others: the
+    ``qwen3_next`` family's ``L L L F`` at an interval of 4."""
+    if full_attention_interval < 1 or spec.layer != "plain" or spec.ssm_heads < 1:
+        raise ValueError(
+            "full_attention_interval must be >= 1 over a plain-layer spec "
+            f"with the delta rule's sizes, got {full_attention_interval} over "
+            f"{spec.layer!r}/{spec.ssm_heads}"
+        )
+    linear = dataclasses.replace(spec, mixer="gdn")
+    return tuple(
+        spec if (i + 1) % full_attention_interval == 0 else linear
+        for i in range(num_layers)
+    )
 
 
 class TransformerOutput(NamedTuple):
@@ -421,14 +529,16 @@ class LatentKVCache(NamedTuple):
 
 
 class HybridCache(NamedTuple):
-    """The cache of a stack with recurrent layers (``nemotron_h``): the K
-    and V page pools of its attention layers, in layer order (``[num_pages,
-    page_size, kv_heads x head_dim]``, every rule of :class:`PagedKVCache`),
-    **and** the recurrent state of its Mamba layers, in layer order:
-    ``ssm [lanes, heads, head_dim, state]`` (what
-    :func:`ssm_decode_update` updates in place) and ``conv [lanes, taps - 1,
-    channels]``, float32, indexed by LANE and of a size that does not
-    depend on a lane's length.  The state is written by the prefill at the
+    """The cache of a stack with recurrent layers (``nemotron_h``,
+    ``qwen3_next``): the K and V page pools of its attention layers, in
+    layer order (``[num_pages, page_size, kv_heads x head_dim]``, every
+    rule of :class:`PagedKVCache`), **and** the recurrent state of its
+    recurrent layers, whatever their kind, in layer order: ``ssm [lanes,
+    *spec.state_shape]`` (a Mamba-2 layer's ``[heads, head_dim, state]``,
+    which :func:`ssm_decode_update` updates in place; a Gated DeltaNet
+    layer's ``[heads, key, value]``, which :func:`gdn_decode_update` does)
+    and ``conv [lanes, taps - 1, channels]``, float32, indexed by LANE and
+    of a size that does not depend on a lane's length.  The state is written by the prefill at the
     prompt's true length, updated in place by every decode substep and
     copied leader to member by the group fork (:func:`fork_cache`); a page
     table says nothing about it, so a prefix-cache hit and a page-cursor
@@ -536,16 +646,19 @@ def _masked_attention(
 
 class RMSNorm(nn.Module):
     """``x / rms(x) * scale`` over the last axis, computed in float32
-    (scale included) and rounded once to ``dtype``."""
+    (scale included) and rounded once to ``dtype``.  ``zero_centered``:
+    the parameter is ``w`` in ``scale = 1 + w`` and starts at zero."""
 
     epsilon: float
     dtype: jnp.dtype = jnp.float32
+    zero_centered: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        scale = self.param(
-            "scale", nn.initializers.ones, (x.shape[-1],), jnp.float32
-        )
+        init = nn.initializers.zeros if self.zero_centered else nn.initializers.ones
+        scale = self.param("scale", init, (x.shape[-1],), jnp.float32)
+        if self.zero_centered:
+            scale = 1.0 + scale
         x = x.astype(jnp.float32)
         ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
         return (x * lax.rsqrt(ms + self.epsilon) * scale).astype(self.dtype)
@@ -553,6 +666,8 @@ class RMSNorm(nn.Module):
 
 def _norm(spec: BlockSpec, dtype, name: Optional[str] = None) -> nn.Module:
     if spec.norm == "rmsnorm":
+        if spec.norm_zero_centered:
+            return RMSNorm(spec.norm_eps, dtype=dtype, zero_centered=True, name=name)
         return RMSNorm(spec.norm_eps, dtype=dtype, name=name)
     return nn.LayerNorm(use_bias=False, dtype=dtype, name=name)
 
@@ -567,13 +682,19 @@ def rotary_fn(
     every block.  Under ``pairing="interleaved"`` pair ``i`` is features
     ``(2i, 2i + 1)``; the result is laid out half-wise (all first members,
     then all second: the DeepSeek family's own arrangement), which q and k
-    share, so every score is that of the interleaved rotation."""
+    share, so every score is that of the interleaved rotation.  An ``x``
+    wider than ``head_dim`` has its first ``head_dim`` features rotated
+    and the rest passed through (a partial rotary)."""
     half = head_dim // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / head_dim)
     angle = positions.astype(jnp.float32)[:, :, None, None] * inv_freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
 
     def rotate(x):
+        if x.shape[-1] > head_dim:
+            return jnp.concatenate(
+                [rotate(x[..., :head_dim]), x[..., head_dim:]], axis=-1
+            )
         if pairing == "interleaved":
             xf = x.astype(jnp.float32)
             x1, x2 = xf[..., 0::2], xf[..., 1::2]
@@ -629,24 +750,35 @@ def _mha(
     (k_pages, v_pages) or None)``; the paths are those of
     :class:`_Block`'s docstring.
 
-    With ``spec.kv_heads`` key/value heads under more query heads the
-    projections are ``q`` and ``kv`` apart, the pools hold ``kv_heads x D``
+    With ``spec.kv_heads`` key/value heads under more query heads (or a
+    gated query) the projections are ``q`` and ``kv`` apart, the pools hold ``kv_heads x D``
     a token, the paged decode reads them as they are (the kernel's query
     is block-diagonal by group) and every other path repeats each
-    key/value head under its query heads after the cache write."""
+    key/value head under its query heads after the cache write.
+
+    ``spec.attn_gate``: the query projection is twice as wide, a head
+    ``[q | gate]``, and ``sigmoid(gate)`` multiplies the attention's
+    output before ``proj``.  ``spec.qk_norm == "head"``: q and k are
+    normed over each head's own features, after the head split and
+    before the rotation and the cache write."""
     B, T, _ = h.shape
     spec, H = mod.spec, mod.num_heads
     head_dim = spec.head_dim or mod.d_model // H
     width = H * head_dim
     KV = spec.kv_heads or H
     dt = dict(dtype=mod.dtype, param_dtype=mod.param_dtype)
-    if KV == H:
+    if KV == H and not spec.attn_gate:
         qkv = nn.Dense(3 * width, use_bias=False, name="qkv", **dt)(h)
         q, k, v = jnp.split(qkv, 3, axis=-1)
     else:
-        q = nn.Dense(width, use_bias=False, name="q", **dt)(h)
+        q = nn.Dense(
+            (2 if spec.attn_gate else 1) * width, use_bias=False, name="q", **dt
+        )(h)
         kv = nn.Dense(2 * KV * head_dim, use_bias=False, name="kv", **dt)(h)
         k, v = jnp.split(kv, 2, axis=-1)
+    gate = None
+    if spec.attn_gate:
+        q, gate = jnp.split(q.reshape(B, T, H, 2 * head_dim), 2, axis=-1)
     if spec.qk_norm == "rmsnorm":
         # over all heads' features at once, before the head split
         q = RMSNorm(spec.norm_eps, dtype=mod.dtype, name="q_norm")(q)
@@ -654,6 +786,12 @@ def _mha(
     shape = (B, T, KV, head_dim)
     q = q.reshape(B, T, H, head_dim)
     k, v = k.reshape(shape), v.reshape(shape)
+    if spec.qk_norm == "head":
+        head_norm = functools.partial(
+            RMSNorm, spec.norm_eps, dtype=mod.dtype,
+            zero_centered=spec.norm_zero_centered,
+        )
+        q, k = head_norm(name="q_norm")(q), head_norm(name="k_norm")(k)
     if mod.rotary is not None:
         # before every cache write: K is stored normed and rotated
         q, k = mod.rotary(q), mod.rotary(k)
@@ -705,6 +843,8 @@ def _mha(
         )
     else:
         out = mod.attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H))
+    if gate is not None:
+        out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
     out = nn.Dense(mod.d_model, use_bias=False, name="proj", **dt)(
         out.reshape(B, T, width)
     )
@@ -738,6 +878,9 @@ class _Block(nn.Module):
         attn_lengths: Optional[jnp.ndarray] = None,
         prefix_starts: Optional[jnp.ndarray] = None,
         segment_ids: Optional[jnp.ndarray] = None,
+        runs: Optional[jnp.ndarray] = None,
+        real: Optional[jnp.ndarray] = None,
+        state_lanes: Optional[jnp.ndarray] = None,
     ):
         """Full forward (no cache) or paged incremental step.
 
@@ -763,6 +906,10 @@ class _Block(nn.Module):
         through the table under a causal-from-start mask — a plain XLA
         gather + :func:`_masked_attention`, no kernel involvement, so
         sharing stays purely a page-table fact.
+
+        Under ``spec.mixer == "gdn"`` the mixer is a Gated DeltaNet
+        (:class:`_GatedDeltaMixer`) and ``paged_cache`` its ``(ssm, conv)``
+        state; ``runs`` / ``real`` / ``state_lanes`` are that mixer's.
         """
         B, T, _ = x.shape
         spec = self.spec
@@ -770,7 +917,14 @@ class _Block(nn.Module):
         dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         h = _norm(spec, self.dtype, "attn_norm" if rms else None)(x)
         new_cache = None
-        if spec.attention == "mla":
+        if spec.mixer == "gdn":
+            _no_tail_prefill(prefix_starts)
+            out, new_cache = _GatedDeltaMixer(self.d_model, spec, name="mixer", **dt)(
+                h, runs=runs, real=real, state=paged_cache,
+                state_lanes=state_lanes,
+                decode=paged_cache is not None and page_table is not None,
+            )
+        elif spec.attention == "mla":
             # one latent attention: ``paged_cache`` is its one pool
             out, new_cache = _LatentAttention(
                 self.d_model, self.num_heads, spec, self.attn_fn,
@@ -796,10 +950,18 @@ class _Block(nn.Module):
             y = _routed_experts(spec, dt)(h)
             if spec.shared_experts:
                 # always on, computed where the token lives
-                y = y + _GatedMLP(
-                    self.d_model, spec.shared_experts * spec.expert_width,
+                shared = _GatedMLP(
+                    self.d_model,
+                    spec.shared_width or spec.shared_experts * spec.expert_width,
                     name="shared", **dt,
                 )(h)
+                if spec.shared_gate:
+                    # behind a sigmoid scalar a token
+                    shared = shared * jax.nn.sigmoid(
+                        nn.Dense(1, use_bias=False, name="shared_gate", **dt)(h)
+                        .astype(jnp.float32)
+                    ).astype(shared.dtype)
+                y = y + shared
             h = y
         elif spec.ffn == "swiglu":
             h = _GatedMLP(self.d_model, spec.ffn_hidden, name="ffn", **dt)(h)
@@ -1000,6 +1162,15 @@ def _dense_ffn(spec: BlockSpec, d_model: int, hidden: int, name: str, dt):
     return kind(d_model, hidden, name=name, **dt)
 
 
+def _no_tail_prefill(prefix_starts) -> None:
+    if prefix_starts is not None:
+        raise NotImplementedError(
+            "a recurrent layer has no tail prefill over a cached "
+            "prefix and no speculative verify: its state cannot be "
+            "entered at a page boundary or rewound by a page cursor"
+        )
+
+
 def run_ids(segment_ids: jnp.ndarray) -> jnp.ndarray:
     """``[B, T]`` ids of the run each token's recurrence belongs to: a
     real token's own segment id, a pad token's (id 0) the id of the last
@@ -1124,6 +1295,34 @@ def ssm_decode_update(state, x, dt, A, B, C, D):
     return y + D.astype(f32)[None, :, None] * x, state
 
 
+def _tap_init(taps: int):
+    """A depthwise convolution's taps (and bias): uniform in ``+- taps^-0.5``."""
+    return lambda key, shape: jax.random.uniform(
+        key, shape, jnp.float32, -(taps ** -0.5), taps ** -0.5
+    )
+
+
+def _dt_bias_init(key, shape):
+    """``softplus(dt_bias)`` log-uniform in [0.001, 0.1], floored at 1e-4."""
+    step = jnp.exp(
+        jax.random.uniform(key, shape, jnp.float32) * (jnp.log(0.1) - jnp.log(0.001))
+        + jnp.log(0.001)
+    )
+    step = jnp.maximum(step, 1e-4)
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+def _last_taps(u, real, taps: int):
+    """The last ``taps`` real inputs of each RIGHT-padded row of ``u [Bt,
+    T, C]`` (zeros before a row's start): what a prefill hands the
+    decode's convolution window."""
+    T = u.shape[1]
+    length = jnp.sum(real, axis=1)
+    at = length[:, None] - taps + jnp.arange(taps)[None, :]
+    tail = jnp.take_along_axis(u, jnp.clip(at, 0, T - 1)[..., None], axis=1)
+    return jnp.where((at >= 0)[..., None], tail, 0.0)
+
+
 class _Mamba2Mixer(nn.Module):
     """The Mamba-2 mixer on a normed input ``h [B, T, d]`` (sizes
     ``spec.ssm_*``: ``H`` heads of ``P``, ``G`` groups, state ``N``, a
@@ -1165,22 +1364,9 @@ class _Mamba2Mixer(nn.Module):
         H, P, G, N, K = s.ssm_heads, s.ssm_head_dim, s.ssm_groups, s.ssm_state, s.ssm_conv
         inner, channels = H * P, H * P + 2 * G * N
         dt_kw = dict(use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype)
-        tap_init = lambda key, shape: jax.random.uniform(  # noqa: E731
-            key, shape, f32, -(K ** -0.5), K ** -0.5
-        )
-        conv_w = self.param("conv_w", tap_init, (K, channels))
-        conv_b = self.param("conv_b", tap_init, (channels,))
-
-        def dt_bias_init(key, shape):
-            # softplus(dt_bias) log-uniform in [0.001, 0.1], floored at 1e-4
-            step = jnp.exp(
-                jax.random.uniform(key, shape, f32) * (jnp.log(0.1) - jnp.log(0.001))
-                + jnp.log(0.001)
-            )
-            step = jnp.maximum(step, 1e-4)
-            return step + jnp.log(-jnp.expm1(-step))
-
-        dt_bias = self.param("dt_bias", dt_bias_init, (H,))
+        conv_w = self.param("conv_w", _tap_init(K), (K, channels))
+        conv_b = self.param("conv_b", _tap_init(K), (channels,))
+        dt_bias = self.param("dt_bias", _dt_bias_init, (H,))
         A_log = self.param(
             "A_log", lambda key, shape: jnp.log(jax.random.uniform(key, shape, f32, 1.0, 16.0)), (H,)
         )
@@ -1228,11 +1414,7 @@ class _Mamba2Mixer(nn.Module):
             y = y + D[:, None] * x.astype(f32)
             if state is not None:
                 ssm, taps = state
-                # the last K - 1 real inputs of each (right-padded) row
-                length = jnp.sum(real, axis=1)
-                at = length[:, None] - (K - 1) + jnp.arange(K - 1)[None, :]
-                tail = jnp.take_along_axis(u, jnp.clip(at, 0, T - 1)[..., None], axis=1)
-                tail = jnp.where((at >= 0)[..., None], tail, 0.0)
+                tail = _last_taps(u, real, K - 1)
                 new_state = (
                     ssm.at[state_lanes].set(last, mode="drop"),
                     taps.at[state_lanes].set(tail, mode="drop"),
@@ -1242,6 +1424,250 @@ class _Mamba2Mixer(nn.Module):
         ms = jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
         normed = (gated * lax.rsqrt(ms + s.norm_eps)).reshape(Bt, T, inner) * norm_scale
         out = nn.Dense(self.d_model, name="out_proj", **dt_kw)(normed.astype(self.dtype))
+        return out, new_state
+
+
+def _causal_taps(u, conv_w, runs):
+    """The causal depthwise convolution ``sum_j w_j u_{t-K+1+j}`` of ``u
+    [Bt, T, C]`` by taps ``conv_w [K, C]``, no bias; an input of another
+    run (``runs [Bt, T]``) is not read."""
+    K, T = conv_w.shape[0], u.shape[1]
+    out = u * conv_w[K - 1]
+    for back in range(1, K):
+        earlier = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :T]
+        near = jnp.pad(runs, ((0, 0), (back, 0)), constant_values=-1)[:, :T] == runs
+        out = out + jnp.where(near[..., None], earlier, 0.0) * conv_w[K - 1 - back]
+    return out
+
+
+def gated_delta_chunked(q, k, v, g, beta, runs, chunk: int):
+    """The gated delta rule over whole sequences, ``chunk`` tokens at a
+    time; a head's state ``S`` is ``[N, P]`` (key x value)::
+
+        S <- exp(g_t) S;  d_t = beta_t (v_t - S^T k_t);  S <- S + k_t d_t^T;  o_t = S^T q_t
+
+    ``q``/``k`` ``[Bt, T, G, N]`` (L2-normalised, ``q`` scaled; key head
+    ``j`` serves value heads ``j x H / G`` on), ``v [Bt, T, H, P]``, ``g``
+    (a log-decay, <= 0) and ``beta`` ``[Bt, T, H]`` float32, both 0 at a
+    pad token (the state passes it unchanged), ``runs [Bt, T]``
+    (:func:`run_ids`: the state is zero at the start of every run).
+
+    Inside a chunk the writes ``d`` depend on one another through the
+    state: with ``c_t`` the log-decay from the run's (or the chunk's)
+    start and ``S_0`` what entered the chunk, ``d_t = beta_t (v_t -
+    e^{c_t} S_0^T k_t - sum_{s<t} e^{c_t - c_s} (k_s . k_t) d_s)``, a
+    unit-lower-triangular system ``(I + A) D = beta (V - e^c K S_0)``
+    (the WY form), solved once a chunk for both right-hand sides: ``D =
+    U - W S_0``.  Then ``O = e^c Q S_0 + M D`` (``M`` the causal ``q .
+    k`` products weighted by the decays between the two positions) and
+    the state leaves the chunk as ``e^{c_end} S_0 + (e^{c_end - c} K)^T
+    D``; across chunks a ``lax.scan`` carries it.  A position of another
+    run is masked out of every product, as :func:`ssd_chunked` does
+    (never a ``-inf`` in a running sum of log-decays).  Every product is
+    float32 at ``HIGHEST`` precision: the triangular system compounds a
+    rounding of its operands.  Differentiable by autodiff.  Returns ``(o
+    [Bt, T, H, P] float32, S [Bt, H, N, P] float32 after the last
+    token)``.
+    """
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    Bt, T, H, P = v.shape
+    G, N = k.shape[2], k.shape[3]
+    Q = chunk
+    pad = -T % Q
+    if pad:
+        widen = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))  # noqa: E731
+        q, k, v, g, beta = widen(q), widen(k), widen(v), widen(g), widen(beta)
+        runs = jnp.pad(runs, ((0, 0), (0, pad)), mode="edge")
+    nc = (T + pad) // Q
+    per = H // G
+    q = jnp.repeat(q.astype(f32), per, axis=2).reshape(Bt, nc, Q, H, N)
+    k = jnp.repeat(k.astype(f32), per, axis=2).reshape(Bt, nc, Q, H, N)
+    v = v.astype(f32).reshape(Bt, nc, Q, H, P)
+    g = g.reshape(Bt, nc, Q, H)
+    beta = beta.reshape(Bt, nc, Q, H)
+    runs = runs.reshape(Bt, nc, Q)
+    causal = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+    same = causal[None, None] & (runs[:, :, :, None] == runs[:, :, None, :])  # [Bt, nc, t, s]
+    before = same & ~jnp.eye(Q, dtype=bool)
+    # log-decay from the start of the token's run (or of the chunk): a
+    # masked sum, exact at any matmul precision
+    cum = jnp.einsum("bcts,bcsh->bcth", same.astype(f32), g, precision=hi)
+    decay = _masked_exp(same[..., None], cum[:, :, :, None, :] - cum[:, :, None, :, :])  # [Bt, nc, t, s, H]
+    kk = jnp.einsum("bcthn,bcshn->bctsh", k, k, precision=hi)
+    A = jnp.where(before[..., None], beta[:, :, :, None, :] * decay * kk, 0.0)
+    M = jnp.where(same[..., None], decay * jnp.einsum("bcthn,bcshn->bctsh", q, k, precision=hi), 0.0)
+    # what enters a chunk is of the run its predecessor ended in; only the
+    # tokens of that run read it, and only the end's run leaves the chunk
+    last = runs[:, :, -1]
+    entered = jnp.concatenate([jnp.zeros_like(last[:, :1]), last[:, :-1]], axis=1)
+    reach = _masked_exp((runs == entered[:, :, None])[..., None], cum)  # [Bt, nc, Q, H]
+    tail = _masked_exp((runs == last[:, :, None])[..., None], cum[:, :, -1:] - cum)
+    through = jnp.where((last == entered)[..., None], jnp.exp(cum[:, :, -1]), 0.0)  # [Bt, nc, H]
+    # (I + A) [U | W] = [beta V | beta e^c K], a head at a time
+    rhs = jnp.concatenate(
+        [beta[..., None] * v, (beta * reach)[..., None] * k], axis=-1
+    )  # [Bt, nc, Q, H, P + N]
+    solved = jax.scipy.linalg.solve_triangular(
+        jnp.eye(Q, dtype=f32) + jnp.moveaxis(A, -1, 2), jnp.moveaxis(rhs, 3, 2),
+        lower=True, unit_diagonal=True,
+    )  # [Bt, nc, H, Q, P + N]
+    chunks = tuple(
+        jnp.moveaxis(a, 1, 0)
+        for a in (
+            solved[..., :P], solved[..., P:], M, reach[..., None] * q,
+            tail[..., None] * k, through,
+        )
+    )
+
+    def carry(S, c):
+        U, W, M_c, reach_q, tail_k, through_c = c
+        D = U - jnp.einsum("bhqn,bhnp->bhqp", W, S, precision=hi)
+        o = jnp.einsum("bqhn,bhnp->bqhp", reach_q, S, precision=hi) + jnp.einsum(
+            "btsh,bhsp->bthp", M_c, D, precision=hi
+        )
+        S = through_c[:, :, None, None] * S + jnp.einsum(
+            "bshn,bhsp->bhnp", tail_k, D, precision=hi
+        )
+        return S, o
+
+    S, o = lax.scan(carry, jnp.zeros((Bt, H, N, P), f32), chunks)
+    return jnp.moveaxis(o, 0, 1).reshape(Bt, nc * Q, H, P)[:, :T], S
+
+
+def gdn_decode_update(state, q, k, v, g, beta):
+    """One token of the same rule for every lane, on the carried state,
+    which is READ ONCE: with ``S`` the state before the token::
+
+        u = e^g S^T k;  d = beta (v - u);  o = e^g S^T q + (k . q) d;  S' = e^g S + k d^T
+
+    (the literal order, decay, ``S^T k``, rank-one write, ``S^T q``, reads
+    the written state again for ``o``; ``S'^T q`` is the line above).
+    ``state [L, H, N, P]`` float32 (how :func:`gated_delta_chunked` leaves
+    it: key x value); ``q``/``k`` ``[L, G, N]``; ``v [L, H, P]``; ``g``,
+    ``beta`` ``[L, H]``.  Returns ``(o [L, H, P] float32, the new
+    state)``.  The Pallas kernel of ``ops/pallas_gdn.py`` on every backend
+    (interpret mode off the chip): a block of heads of a lane in VMEM, one
+    pass in and one out, in place.  The same lines in plain ``jax.numpy``
+    compile on a v5e to a reduction fusion and a write fusion a layer,
+    which read the state twice, and lost by 18-20% of the cell's rate
+    (PERF.md, PR 42; ``benchmark/tools/gdn_decode_probe.py`` keeps that
+    form to measure against).  Grad-free: decode is inference-only, the
+    learner differentiates the chunked form."""
+    with jax.named_scope("gdn_decode_update"):
+        return gdn_decode_update_pallas(state, q, k, v, g, beta)
+
+
+@functools.lru_cache(maxsize=None)
+def _note_gdn_form(shape, chunk, heads, groups, decode) -> None:
+    """Which form of the delta rule a traced shape took: one zero-length
+    program span a shape (the cache is the "once")."""
+    from scalerl_tpu.runtime import tracing
+
+    attrs = dict(shape=list(shape), chunk=chunk, heads=heads, state_dtype="float32")
+    if decode:
+        # what one step of the kernel holds of the state: a block of heads of a lane
+        per_block = heads_per_block(heads[0], heads[0] // groups, heads[1], heads[2])
+        attrs.update(kernel="pallas", tile=[per_block, heads[1], heads[2]])
+    with tracing.span("gdn.form", kind="model", **attrs):
+        pass
+
+
+class _GatedDeltaMixer(nn.Module):
+    """The Gated DeltaNet mixer on a normed input ``h [B, T, d]`` (sizes
+    ``spec.ssm_*``: ``H`` value heads of ``P``, ``G`` key heads of ``N``, a
+    causal depthwise convolution of ``K`` taps, no bias)::
+
+        [q | k | v | z] = h W_in       (d -> G N + G N + H P + H P);   [b | a] = h W_ba  (d -> H + H)
+        [q | k | v]_t = silu(sum_j w_j [q | k | v]_{t-K+1+j})       (float32)
+        beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)
+        q, k L2-normalised a head (eps 1e-6), q times N^-0.5
+        S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;  o_t = S^T q_t
+        out = (RMSNorm_P(o_t) * w_norm * silu(z_t)) W_out     (the norm a head, THEN the gate)
+
+    ONE set of parameters and :class:`_Mamba2Mixer`'s call and paths:
+    whole sequences (full, masked, packed, the prefill) through
+    :func:`gated_delta_chunked` over ``runs``, with the state and the last
+    ``K - 1`` real convolution inputs written to rows ``state_lanes`` of
+    ``state`` at the TRUE length (a pad token has ``beta = 0``, ``g = 0``
+    and no convolution input); one token a lane (``decode=True``) through
+    :func:`gdn_decode_update` on the carried state.  ``state`` is ``(ssm
+    [lanes, H, N, P], conv [lanes, K - 1, 2 G N + H P])``, both float32.
+    Returns ``(out [B, T, d], state or None)``."""
+
+    d_model: int
+    spec: BlockSpec
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h, runs=None, real=None, state=None, state_lanes=None, decode=False):
+        s = self.spec
+        f32 = jnp.float32
+        Bt, T, _ = h.shape
+        H, P, G, N, K = s.ssm_heads, s.ssm_head_dim, s.ssm_groups, s.ssm_state, s.ssm_conv
+        keys, values, channels = G * N, H * P, s.conv_channels
+        dt_kw = dict(use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype)
+        conv_w = self.param("conv_w", _tap_init(K), (K, channels))
+        dt_bias = self.param("dt_bias", _dt_bias_init, (H,))  # as the Mamba mixer's
+        A_log = self.param(
+            "A_log",
+            lambda key, shape: jnp.log(jnp.maximum(jax.random.uniform(key, shape, f32, 0.0, 16.0), 1e-4)),
+            (H,),
+        )
+        norm_scale = self.param("norm_scale", nn.initializers.ones, (P,), f32)
+        if not self.is_initializing():
+            _note_gdn_form(tuple(h.shape), s.ssm_chunk, (H, N, P), G, bool(decode))
+
+        qkvz = nn.Dense(channels + values, name="in_proj", **dt_kw)(h)
+        ba = nn.Dense(2 * H, name="ba_proj", **dt_kw)(h).astype(f32)
+        u = qkvz[..., :channels].astype(f32)  # the convolution's input
+        z = qkvz[..., channels:]
+        beta = jax.nn.sigmoid(ba[..., :H])
+        g = -jnp.exp(A_log) * jax.nn.softplus(ba[..., H:] + dt_bias)
+
+        def split(qkv):
+            qkv = jax.nn.silu(qkv)
+            lead = qkv.shape[:-1]
+            l2 = lambda a: a * lax.rsqrt(jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)  # noqa: E731
+            return (
+                l2(qkv[..., :keys].reshape(*lead, G, N)) * N ** -0.5,
+                l2(qkv[..., keys : 2 * keys].reshape(*lead, G, N)),
+                qkv[..., 2 * keys :].reshape(*lead, H, P),
+            )
+
+        new_state = None
+        if decode:
+            ssm, taps = state
+            window = jnp.concatenate([taps, u], axis=1)  # [lanes, K, channels]
+            q, k, v = split(jnp.sum(window * conv_w, axis=1))
+            o, ssm = gdn_decode_update(ssm, q, k, v, g[:, 0], beta[:, 0])
+            o = o[:, None]  # [lanes, 1, H, P]
+            new_state = (ssm, window[:, 1:])
+        else:
+            if runs is None:
+                runs = jnp.ones((Bt, T), jnp.int32)
+                real = jnp.ones((Bt, T), bool)
+            u = jnp.where(real[..., None], u, 0.0)
+            g = jnp.where(real[..., None], g, 0.0)
+            beta = jnp.where(real[..., None], beta, 0.0)
+            q, k, v = split(_causal_taps(u, conv_w, runs))
+            o, last = gated_delta_chunked(q, k, v, g, beta, runs, s.ssm_chunk)
+            if state is not None:
+                ssm, taps = state
+                tail = _last_taps(u, real, K - 1)
+                new_state = (
+                    ssm.at[state_lanes].set(last, mode="drop"),
+                    taps.at[state_lanes].set(tail, mode="drop"),
+                )
+        # the norm a head, then the gate
+        ms = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+        gated = (
+            o * lax.rsqrt(ms + s.norm_eps) * norm_scale
+            * jax.nn.silu(z.astype(f32)).reshape(Bt, T, H, P)
+        )
+        out = nn.Dense(self.d_model, name="out_proj", **dt_kw)(
+            gated.reshape(Bt, T, values).astype(self.dtype)
+        )
         return out, new_state
 
 
@@ -1276,12 +1702,7 @@ class _MixerBlock(nn.Module):
         h = _norm(spec, self.dtype, "norm")(x)
         cache = None
         if spec.mixer == "mamba":
-            if call.get("prefix_starts") is not None:
-                raise NotImplementedError(
-                    "a recurrent layer has no tail prefill over a cached "
-                    "prefix and no speculative verify: its state cannot be "
-                    "entered at a page boundary or rewound by a page cursor"
-                )
+            _no_tail_prefill(call.get("prefix_starts"))
             out, cache = _Mamba2Mixer(self.d_model, spec, name="mixer", **dt)(
                 h, runs=runs, real=real, state=paged_cache,
                 state_lanes=state_lanes,
@@ -1398,6 +1819,24 @@ class _MTPModule(nn.Module):
         )(y, **call)
 
 
+def _attends(spec: BlockSpec) -> bool:
+    """Whether the layer writes K and V pools: a mixer layer of
+    attention, or a plain layer whose mixer is not recurrent."""
+    if spec.layer == "mixer":
+        return spec.mixer == "attention"
+    return not spec.recurrent
+
+
+def _layer_kind(spec: BlockSpec) -> str:
+    """A layer as ``model.layers`` notes it: its layer kind and what fills
+    it (a plain layer that names its mixer: the mixer, then the FFN)."""
+    if spec.layer == "mixer":
+        return f"{spec.layer}/{spec.mixer}"
+    if spec.mixer:
+        return f"{spec.layer}/{spec.mixer}/{spec.ffn}"
+    return f"{spec.layer}/{spec.ffn}"
+
+
 def _latent_pools(spec: BlockSpec) -> int:
     return 2 if spec.layer == "scmoe" else 1
 
@@ -1509,8 +1948,13 @@ class TransformerPolicy(nn.Module):
     @property
     def recurrent(self) -> bool:
         """Whether a layer of the stack carries a state that no page table
-        describes (a Mamba mixer)."""
-        return any(s.mixer == "mamba" for s in self.layer_specs)
+        describes (a Mamba-2 or a Gated DeltaNet mixer)."""
+        return any(s.recurrent for s in self.layer_specs)
+
+    @property
+    def _hybrid(self) -> bool:
+        """Whether the model caches into a :class:`HybridCache`."""
+        return self.block.layer == "mixer" or self.recurrent
 
     def init_paged_cache(
         self, num_pages: int, page_size: int, dtype=jnp.float32, lanes: int = 0
@@ -1520,16 +1964,16 @@ class TransformerPolicy(nn.Module):
         and its programs) treats it as one pytree.  Page pools are
         ``[num_pages, page_size, width]`` (page 0 = the never-read null
         page), one K and one V an attention layer or one latent pool an
-        ``mla`` attention; a stack with Mamba layers gets a
-        :class:`HybridCache`: pools for its attention layers alone, a
-        ``lanes``-indexed recurrent state for each Mamba layer, nothing
-        for the others."""
+        ``mla`` attention; a stack with recurrent layers (or of
+        single-mixer layers) gets a :class:`HybridCache`: pools for its
+        attention layers alone, a ``lanes``-indexed recurrent state for
+        each recurrent layer whatever its kind, nothing for the others."""
         spec = self.block
-        if spec.layer == "mixer":
+        if self._hybrid:
             specs = self.layer_specs
-            attn = sum(s.mixer == "attention" for s in specs)
-            mamba = [s for s in specs if s.mixer == "mamba"]
-            if mamba and lanes < 1:
+            attn = sum(_attends(s) for s in specs)
+            stateful = [s for s in specs if s.recurrent]
+            if stateful and lanes < 1:
                 raise ValueError("a recurrent model's cache is sized by its lanes")
             pools = init_paged_kv_cache(
                 num_pages, page_size, attn, spec.kv_heads or self.num_heads,
@@ -1538,20 +1982,11 @@ class TransformerPolicy(nn.Module):
             return HybridCache(
                 k=pools.k, v=pools.v,
                 ssm=tuple(
-                    jnp.zeros(
-                        (lanes, s.ssm_heads, s.ssm_head_dim, s.ssm_state), jnp.float32
-                    )
-                    for s in mamba
+                    jnp.zeros((lanes,) + s.state_shape, jnp.float32) for s in stateful
                 ),
                 conv=tuple(
-                    jnp.zeros(
-                        (
-                            lanes, s.ssm_conv - 1,
-                            s.ssm_heads * s.ssm_head_dim + 2 * s.ssm_groups * s.ssm_state,
-                        ),
-                        jnp.float32,
-                    )
-                    for s in mamba
+                    jnp.zeros((lanes, s.ssm_conv - 1, s.conv_channels), jnp.float32)
+                    for s in stateful
                 ),
             )
         if spec.attention == "mla":
@@ -1566,8 +2001,8 @@ class TransformerPolicy(nn.Module):
                 )
             )
         return init_paged_kv_cache(
-            num_pages, page_size, self.num_layers, self.num_heads,
-            self.head_dim, dtype,
+            num_pages, page_size, self.num_layers,
+            spec.kv_heads or self.num_heads, self.head_dim, dtype,
         )
 
     @nn.compact
@@ -1636,10 +2071,7 @@ class TransformerPolicy(nn.Module):
         if not self.is_initializing():  # a program's trace, not the weights' making
             _note_layers(
                 tuple(obs.shape),
-                tuple(
-                    f"{s.layer}/{s.mixer if s.layer == 'mixer' else s.ffn}"
-                    for s in specs
-                ),
+                tuple(_layer_kind(s) for s in specs),
                 spec.attention, spec.experts_held or spec.num_experts,
                 spec.num_experts, self.mtp_layers,
             )
@@ -1694,7 +2126,9 @@ class TransformerPolicy(nn.Module):
                 spec.rope_pairing,
             )
         elif spec.positions == "rope":
-            rotary = rotary_fn(positions, self.head_dim, spec.rope_theta)
+            rotary = rotary_fn(
+                positions, spec.rotary_dim or self.head_dim, spec.rope_theta
+            )
         elif spec.positions == "learned":
             pos_tab = self.param(
                 "pos_embed",
@@ -1707,7 +2141,8 @@ class TransformerPolicy(nn.Module):
         latent = spec.attention == "mla"
         pools = []  # what each layer wrote: (k, v), one latent pool, or two
         at = 0  # the layer's first pool among the latent cache's rows
-        n_attn = n_mamba = 0  # a mixer stack's attention and Mamba layers so far
+        hybrid = self._hybrid
+        n_attn = n_state = 0  # a hybrid stack's attention and recurrent layers so far
         for i, layer in enumerate(specs):
             common = dict(
                 dtype=self.dtype,
@@ -1718,18 +2153,19 @@ class TransformerPolicy(nn.Module):
                 rotary=rotary,
                 name=f"block_{i}",
             )
+            cache = None
+            if hybrid and paged_cache is not None:
+                # the cache of the layer's kind: its pools, its state, none
+                if _attends(layer):
+                    cache = (paged_cache.k[n_attn], paged_cache.v[n_attn])
+                elif layer.recurrent:
+                    cache = (paged_cache.ssm[n_state], paged_cache.conv[n_state])
+                n_attn += _attends(layer)
+                n_state += layer.recurrent
             if layer.layer == "mixer":
-                # one mixer, and the cache of its kind: pools, state, none
                 block = _MixerBlock(
                     self.d_model, self.num_heads, self.mlp_ratio, attn, **common
                 )
-                cache = None
-                if paged_cache is not None and layer.mixer == "attention":
-                    cache = (paged_cache.k[n_attn], paged_cache.v[n_attn])
-                elif paged_cache is not None and layer.mixer == "mamba":
-                    cache = (paged_cache.ssm[n_mamba], paged_cache.conv[n_mamba])
-                n_attn += layer.mixer == "attention"
-                n_mamba += layer.mixer == "mamba"
                 mixer_call = dict(runs=runs, real=real)
                 if paged_cache is not None:
                     x, written = block(
@@ -1753,7 +2189,18 @@ class TransformerPolicy(nn.Module):
                 block = _Block(
                     self.d_model, self.num_heads, self.mlp_ratio, attn, **common
                 )
-            if paged_cache is not None:
+            # a plain layer of a stack with recurrent ones: what a
+            # recurrent mixer reads beside its state
+            state_call = dict(runs=runs, real=real) if hybrid else {}
+            if paged_cache is not None and hybrid:
+                x, written = block(
+                    x, attn_mask=attn_mask, paged_cache=cache, page_ids=page_ids,
+                    page_offsets=page_offsets, page_table=page_table,
+                    attn_lengths=attn_lengths, prefix_starts=prefix_starts,
+                    state_lanes=state_lanes, **state_call,
+                )
+                pools.append(("attention" if _attends(layer) else layer.mixer, written))
+            elif paged_cache is not None:
                 if not latent:
                     cache = (paged_cache.k[i], paged_cache.v[i])
                 elif layer.layer == "scmoe":
@@ -1773,9 +2220,9 @@ class TransformerPolicy(nn.Module):
                 )
                 pools.append(written)
             elif segment_ids is not None:
-                x = block(x, segment_ids=segment_ids)
+                x = block(x, segment_ids=segment_ids, **state_call)
             else:
-                x = block(x, attn_mask=attn_mask)
+                x = block(x, attn_mask=attn_mask, **state_call)
             x = c(x)
         final_norm = functools.partial(_norm, spec, jnp.float32)
         policy_head = nn.Dense(self.num_actions, name="policy_head")
@@ -1802,9 +2249,9 @@ class TransformerPolicy(nn.Module):
         out = TransformerOutput(policy_logits, baseline, mtp_logits)
         if paged_cache is None:
             return out
-        if spec.layer == "mixer":
+        if hybrid:
             kv = [w for kind, w in pools if kind == "attention"]
-            st = [w for kind, w in pools if kind == "mamba"]
+            st = [w for kind, w in pools if kind != "attention"]
             return out, HybridCache(
                 k=tuple(k for k, _v in kv), v=tuple(v for _k, v in kv),
                 ssm=tuple(s for s, _c in st), conv=tuple(c for _s, c in st),

@@ -114,6 +114,13 @@ PARAM_LOGICAL_AXES: Dict[Tuple[str, ...], Tuple[Optional[str], ...]] = {
     ("kv", "kernel"): ("embed", None),
     ("in_proj", "kernel"): ("embed", None),
     ("out_proj", "kernel"): ("heads", "embed"),
+    # The qwen3_next stack: its attention's ``q`` (a head ``[q | gate]``)
+    # and ``kv`` take the two rules above; a Gated DeltaNet mixer's
+    # ``in_proj`` (``[q | k | v | z]``) and ``out_proj`` take the Mamba
+    # mixer's, and its ``ba_proj`` (``[beta | a]``, two values a head) and
+    # the shared expert's scalar ``shared_gate`` replicate like them.
+    ("ba_proj", "kernel"): ("embed", None),
+    ("shared_gate", "kernel"): ("embed", None),
 }
 
 

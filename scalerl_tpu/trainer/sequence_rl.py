@@ -54,6 +54,7 @@ from scalerl_tpu.genrl.task import TokenRecallTask
 from scalerl_tpu.models.transformer import (
     TransformerPolicy,
     block_spec,
+    interval_specs,
     layer_specs,
     pattern_specs,
 )
@@ -114,9 +115,12 @@ def build_genrl_model(args: GenRLArguments) -> TransformerPolicy:
         ssm_groups=args.ssm_groups,
         ssm_conv=args.ssm_conv,
         ssm_chunk=args.ssm_chunk,
+        rotary_dim=args.rotary_dim,
     )
     if args.layer_pattern:
         layers = pattern_specs(spec, args.layer_pattern)
+    elif args.full_attention_interval:
+        layers = interval_specs(spec, args.n_layers, args.full_attention_interval)
     elif args.dense_layers:
         layers = layer_specs(spec, args.n_layers, args.dense_layers)
     else:  # a stack of one kind of layer is its block, ``n_layers`` times

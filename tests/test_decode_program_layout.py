@@ -21,7 +21,8 @@ attention's head shape are compiled there at the learn cell's own sizes
 its train state that a post-hoc guard costs (ISSUE 33), and a hybrid
 stack's decode, prefill and fork programs are read for a copy of a Mamba
 layer's recurrent state, which has to be carried in place beside the
-pools (ISSUE 40): this is the one tier-1 file that loads the TPU's
+pools (ISSUE 40), and a Gated DeltaNet stack's for a second read or a
+copy of a layer's matrix state (ISSUE 42): this is the one tier-1 file that loads the TPU's
 compiler outside ``tests/benchmark``.
 """
 
@@ -310,6 +311,106 @@ def test_hybrid_prefill_and_fork_leave_the_state_in_place(hybrid_engine, one_chi
         fn, extra = eng._fork_fn(A), [(A,)] * 4
     text = _compiled_text(eng, one_chip, fn, params=program != "fork", extra=extra)
     _assert_state_in_place(text, eng)
+
+
+# -- a matrix state a head beside the pools (ISSUE 42) ------------------------
+
+
+@pytest.fixture(scope="module")
+def delta_engine():
+    """``L L F`` (an interval of 3) at Qwen3-Next's mixer sizes (32 value
+    heads of 128 over 16 key heads of 128; 16 query heads of 256 over 2
+    key/value heads, 64 rotating features) on a hidden size of 256, 4
+    lanes: a layer's state is ``[4, 32, 128, 128]`` float32 (8 MB: larger
+    than any weight here) and its pools ``[301, 8, 512]``.  The paged
+    kernel is pinned compiled; the delta rule's kernel is what the mixer
+    takes on a TPU backend, which the tests below stand in for."""
+    from scalerl_tpu.models.transformer import interval_specs
+
+    vocab = 128
+    spec = block_spec(
+        "qwen3_next", head_dim=256, norm_eps=1e-6, rope_theta=1e7, num_experts=8,
+        experts_per_token=2, expert_width=128, norm_topk_prob=True, experts_held=4,
+        shared_experts=1, shared_width=128, kv_heads=2, ssm_heads=32, ssm_head_dim=128,
+        ssm_state=128, ssm_groups=16, ssm_chunk=64, rotary_dim=64,
+    )
+    model = TransformerPolicy(
+        num_actions=vocab, vocab_size=vocab, d_model=256, num_heads=16, num_layers=3,
+        max_len=256, block=spec, layers=interval_specs(spec, 3, 3),
+        paged_attn_fn=functools.partial(paged_decode_attention, interpret=False),
+    )
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
+    return ContinuousEngine(
+        model, params,
+        ContinuousConfig(
+            vocab_size=vocab, max_prompt_len=64, max_new_tokens=64,
+            lanes=LANES, page_size=PAGE, num_pages=PAGES, steps_per_macro=2,
+        ),
+        iter_mode="scan",
+    )
+
+
+def _assert_delta_state_in_place(text, eng):
+    """No ``copy`` or ``transpose`` of the shape of a layer's matrix state
+    or of a pool, and every state array the program names row-major on
+    whole ``(8, 128)`` tiles (value features on the minor axis)."""
+    cache = eng._pools
+    whole = {",".join(str(d) for d in x.shape) for x in cache.k + cache.v + cache.ssm}
+    moved = [
+        line.strip()[:160]
+        for line in text.splitlines()
+        for m in [re.search(r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(", line)]
+        if m and m.group(1) in whole
+    ]
+    assert not moved, f"{len(moved)} whole-state or whole-pool copies, the first: {moved[0]}"
+    layouts = set(re.findall(rf"f32\[{LANES},32,128,128\]\{{[^}}]*\}}", text))
+    assert layouts, "the program names no state"
+    wrong = {l for l in layouts if not re.search(r"\{3,2,1,0(:T\(8,128\)(S\(\d\))?)?\}$", l)}
+    assert not wrong, f"states not row-major in place: {wrong}"
+
+
+def test_delta_decode_reads_the_state_once_and_in_place(delta_engine, one_chip, monkeypatch):
+    """The decode macro-step of a stack with Gated DeltaNet layers, as a
+    TPU backend traces it: ONE ``gdn_decode_update`` kernel a delta-rule
+    layer a substep and no fusion that takes a state (the plain form's two
+    fusions a layer read it twice: PERF.md, PR 42), one ``paged_decode``
+    at 16 query heads over 2 key/value heads of 256, every pool and state
+    array donated and returned as itself, none copied."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = delta_engine
+    M = eng._table.shape[1]
+    text = _compiled_text(eng, one_chip, eng._build_decode(), params=True, extra=[(LANES, M), "key"])
+    calls = re.findall(r"= \((?:[^()]|\([^()]*\))*\) custom-call\(.*gdn_decode_update", text)
+    assert len(calls) == 2, f"{len(calls)} kernel calls for two delta-rule layers"
+    assert all(f"f32[{LANES},32,128,128]" in call for call in calls)  # the state is a result
+    assert "paged_decode" in text
+    _assert_delta_state_in_place(text, eng)
+    leaves = len(jax.tree_util.tree_leaves(eng._snapshot_params()[0]))
+    header = text[text.index("input_output_alias={"):].split("\n", 1)[0]
+    aliased = {
+        int(out): int(param)
+        for out, param in re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
+    }
+    cache = len(jax.tree_util.tree_leaves(eng._pools))
+    assert cache == 2 + 2 + 2  # K and V of one attention, state and window of two delta layers
+    for i in range(cache):
+        assert aliased.get(i) == leaves + i, (i, aliased)
+
+
+@pytest.mark.parametrize("program", ["local_prefill", "fork"])
+def test_delta_prefill_and_fork_leave_the_state_in_place(delta_engine, one_chip, program):
+    """The chunked prefill (a triangular solve a chunk, the state written
+    at the true length) and the fork compile for the chip and copy no
+    whole state or pool."""
+    eng = delta_engine
+    A, P = 2, 64
+    if program == "local_prefill":
+        fn = eng._prefill_fn(("local", P, A))
+        extra = [(A, P), (A,), (A,), (A, P), (A, P)]
+    else:
+        fn, extra = eng._fork_fn(A), [(A,)] * 4
+    text = _compiled_text(eng, one_chip, fn, params=program != "fork", extra=extra)
+    _assert_delta_state_in_place(text, eng)
 
 
 # -- the fused classic loop (ISSUE 31) ---------------------------------------

@@ -717,6 +717,48 @@ def test_ssm_decode_update_compiled(lanes):
     np.testing.assert_allclose(np.asarray(s2), s_ref2, atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("lanes", [8, 96])
+def test_gdn_decode_update_compiled(lanes):
+    """The Gated DeltaNet decode update (ISSUE 42; the Pallas kernel of
+    ``ops/pallas_gdn.py``: a block of 16 heads of a lane a step, the state
+    read once and written over itself) compiled on the chip at
+    Qwen3-Next's sizes (32 value heads of 128 over 16 key heads of 128)
+    against the rule written out in its LITERAL order in float64 on the
+    host (decay, ``S^T k``, rank-one write, ``S^T q``), twice in a row so
+    that the second call reads what the first wrote in place; the state's
+    buffer is donated.  1e-4: float32 sums of 128 products."""
+    from scalerl_tpu.models.transformer import gdn_decode_update
+
+    H, N, P, G = 32, 128, 128, 16
+    key = jax.random.split(jax.random.PRNGKey(lanes), 6)
+    unit = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)  # noqa: E731
+    state = _rand(key[0], lanes, H, N, P)
+    q = unit(_rand(key[1], lanes, G, N)) * N ** -0.5
+    k = unit(_rand(key[2], lanes, G, N))
+    v = _rand(key[3], lanes, H, P)
+    g = -jax.nn.softplus(_rand(key[4], lanes, H))
+    beta = jax.nn.sigmoid(_rand(key[5], lanes, H))
+
+    def written_out(s):
+        f64 = lambda a: np.asarray(a, np.float64)  # noqa: E731
+        qh, kh = np.repeat(f64(q), H // G, axis=1), np.repeat(f64(k), H // G, axis=1)
+        s = np.exp(f64(g))[:, :, None, None] * s
+        d = f64(beta)[:, :, None] * (f64(v) - np.einsum("lhnp,lhn->lhp", s, kh))
+        s = s + kh[..., None] * d[:, :, None, :]
+        return np.einsum("lhnp,lhn->lhp", s, qh), s
+
+    o_ref, s_ref = written_out(np.asarray(state, np.float64))
+    o_ref2, s_ref2 = written_out(s_ref)
+    step = jax.jit(lambda s: gdn_decode_update(s, q, k, v, g, beta), donate_argnums=0)
+    assert "tpu_custom_call" in step.lower(state).compile().as_text()
+    o1, s1 = step(state + 0.0)
+    np.testing.assert_allclose(np.asarray(o1), o_ref, atol=1e-4, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(s1), s_ref, atol=1e-5, rtol=1e-5)
+    o2, s2 = step(s1)
+    np.testing.assert_allclose(np.asarray(o2), o_ref2, atol=1e-4, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(s2), s_ref2, atol=1e-5, rtol=1e-5)
+
+
 @pytest.mark.usefixtures("f32_matmuls")
 def test_continuous_engine_macro_step_on_tpu():
     """One continuous-batching macro-step compiled on the chip: paged
