@@ -25,6 +25,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from scalerl_tpu.agents.impala import ImpalaTrainState
 from scalerl_tpu.data.trajectory import Trajectory
@@ -47,6 +48,20 @@ class ActorCarry(NamedTuple):
     envs); ``[84, 84, 4, B]`` is dense, and is the physical order the
     convolution and the trajectory buffer want anyway
     (docs/PERFORMANCE.md, "Reading a tiled layout").
+
+    The trajectory's observations (:meth:`DeviceActorLearnerLoop._unroll`)
+    are written, as the actor produces them, into one buffer
+    ``[*obs_shape[:-1], T+1, obs_shape[-1], B]`` (``[84, 84, 21, 4, 2048]``):
+    the time axis directly outside the stored frame's two minor axes.  The
+    learner merges ``[T, B]`` into one batch axis, and a merge of two axes is
+    a bitcast only if the outer one sits directly outside the tile of the
+    inner: under the ``(4, 128)`` tile on ``(C, B)`` that buffer IS
+    ``[84, 84, 4, (T B)]`` byte for byte.  Rows stacked with T leading (a
+    scan's ``ys``) put T major-most, and the learner then copied the whole
+    bf16 trajectory (2.4 GB at 2048 envs) to move it (PERF.md, PR 43).  The
+    buffer's layout is pinned row-major (:func:`_pin_row_major`): left
+    alone, XLA gives a ``while`` carry the layout that makes its
+    ``dynamic-update-slice`` cheapest, T major-most again.
     """
 
     env_state: Any
@@ -65,10 +80,17 @@ def _store_obs(obs: jnp.ndarray) -> jnp.ndarray:
     return jnp.moveaxis(obs, 0, -1)
 
 
-def _load_obs(stored: jnp.ndarray, env_axis: int = 0) -> jnp.ndarray:
-    """Stored observations with the env axis moved (logically: the compiler
-    resolves it to a bitcast on the TPU) to where the consumer wants it."""
-    return jnp.moveaxis(stored, -1, env_axis)
+def _load_obs(stored: jnp.ndarray) -> jnp.ndarray:
+    """Stored observations with the env axis moved back to the front
+    (logically: the compiler resolves it to a bitcast on the TPU)."""
+    return jnp.moveaxis(stored, -1, 0)
+
+
+def _pin_row_major(x: jnp.ndarray) -> jnp.ndarray:
+    """Hold ``x`` to the major-to-minor layout of its logical shape where
+    the compiler would otherwise choose one (a ``while`` carry that lives
+    inside one program).  Lowers on every backend and under ``shard_map``."""
+    return with_layout_constraint(x, Layout(major_to_minor=tuple(range(x.ndim))))
 
 
 def carry_env_axes(carry: ActorCarry) -> ActorCarry:
@@ -87,6 +109,17 @@ def _note_obs_storage(stored_shape: Tuple[int, ...], dtype: str) -> None:
     with tracing.span(
         "fused.obs_storage", kind="loop", stored_shape=list(stored_shape),
         dtype=dtype, env_axis=len(stored_shape) - 1,
+    ):
+        pass
+
+
+@lru_cache(maxsize=None)
+def _note_traj_storage(buffer_shape: Tuple[int, ...], dtype: str, time_axis: int) -> None:
+    """As :func:`_note_obs_storage`, for the trajectory buffer: one
+    zero-length span a traced shape."""
+    with tracing.span(
+        "fused.traj_storage", kind="loop", buffer_shape=list(buffer_shape),
+        dtype=dtype, time_axis=time_axis, pinned=True,
     ):
         pass
 
@@ -301,11 +334,34 @@ class DeviceActorLearnerLoop:
     # ------------------------------------------------------------------
     def _unroll(self, params, carry: ActorCarry, key: jax.Array):
         """Collect one [T+1, B] trajectory chunk; row T's logits are unused
-        by the learner (behavior_logits[:-1]) and left zero."""
-        core0 = carry.core_state
-        _note_obs_storage(tuple(carry.obs.shape), jnp.dtype(carry.obs.dtype).name)
+        by the learner (behavior_logits[:-1]) and left zero.
 
-        def step(c: ActorCarry, k):
+        The observations are not stacked as a scan's ``ys``: row ``t`` is
+        written in place into a buffer carried through the scan, whose time
+        axis sits where the learner's ``[T, B]`` merge wants it
+        (:class:`ActorCarry`), and ``Trajectory.obs`` is the logical
+        ``[T+1, B, *obs_shape]`` view of it.  The other four rows are
+        kilobytes and stay ``ys``."""
+        core0 = carry.core_state
+        T = self.unroll_length
+        stored = carry.obs.shape
+        # the time axis goes directly outside the stored frame's two minor
+        # axes; a stored observation of rank 1 ([B]) has no second one and
+        # takes it leading
+        t_axis = max(len(stored) - 2, 0)
+        buf_shape = (*stored[:t_axis], T + 1, *stored[t_axis:])
+        dtype = jnp.dtype(carry.obs.dtype).name
+        _note_obs_storage(tuple(stored), dtype)
+        _note_traj_storage(buf_shape, dtype, t_axis)
+
+        def write_row(buf, obs, t):
+            return _pin_row_major(
+                jax.lax.dynamic_update_index_in_dim(buf, obs, t, axis=t_axis)
+            )
+
+        def step(cb, kt):
+            c, buf = cb
+            k, t = kt
             out, new_core = self.model.apply(
                 params, _load_obs(c.obs)[None], c.last_action[None],
                 c.reward[None], c.done[None], c.core_state,
@@ -316,7 +372,7 @@ class DeviceActorLearnerLoop:
             env_state, next_obs, reward, done = self.venv.step(
                 c.env_state, action, k_env
             )
-            row = (c.obs, c.last_action, c.reward, c.done, logits)
+            row = (c.last_action, c.reward, c.done, logits)
             ep_ret = c.episode_return + reward
             new_c = ActorCarry(
                 env_state=env_state,
@@ -329,18 +385,23 @@ class DeviceActorLearnerLoop:
                 return_sum=c.return_sum + jnp.where(done, ep_ret, 0.0),
                 episode_count=c.episode_count + done.astype(jnp.float32),
             )
-            return new_c, row
+            return (new_c, write_row(buf, c.obs, t)), row
 
-        keys = jax.random.split(key, self.unroll_length)
-        carry, rows = jax.lax.scan(step, carry, keys)
-        obs_rows, la_rows, rew_rows, done_rows, logit_rows = rows
+        keys = jax.random.split(key, T)
+        # every row is written before it is read, so the buffer starts
+        # uninitialised: a bare allocation on the TPU, zeros on the CPU (a
+        # zero fill took 3.7 ms an iteration at 2048 envs, PERF.md, PR 43)
+        buf = _pin_row_major(jax.lax.empty(buf_shape, carry.obs.dtype))
+        (carry, buf), rows = jax.lax.scan(
+            step, (carry, buf), (keys, jnp.arange(T, dtype=jnp.int32))
+        )
+        la_rows, rew_rows, done_rows, logit_rows = rows
+        # final row T from the post-scan carry (logits zero: unused)
+        buf = write_row(buf, carry.obs, T)
 
-        # final row T from the post-scan carry (logits zero: unused); the
-        # rows were stacked as stored, [T, *obs_shape, B]
         traj = Trajectory(
-            obs=_load_obs(
-                jnp.concatenate([obs_rows, carry.obs[None]], axis=0), env_axis=1
-            ),
+            # [.., T+1, C, B] -> [T+1, B, .., C]
+            obs=jnp.moveaxis(_load_obs(buf), t_axis + 1, 0),
             action=jnp.concatenate([la_rows, carry.last_action[None]], axis=0),
             reward=jnp.concatenate([rew_rows, carry.reward[None]], axis=0),
             done=jnp.concatenate([done_rows, carry.done[None]], axis=0),

@@ -26,6 +26,7 @@ copy of a layer's matrix state (ISSUE 42): this is the one tier-1 file that load
 compiler outside ``tests/benchmark``.
 """
 
+import contextlib
 import functools
 import os
 import re
@@ -66,8 +67,8 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(autouse=True)
-def _no_persistent_cache():
+@contextlib.contextmanager
+def _cache_off():
     """A compile for a described device is written to the persistent cache
     but cannot be read back without a chip: keep these out of it."""
     from jax.experimental.compilation_cache import compilation_cache
@@ -75,9 +76,17 @@ def _no_persistent_cache():
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", before)
-    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    with _cache_off():
+        yield
 
 
 @pytest.fixture(scope="module", params=sorted(HEAD_DIM))
@@ -416,21 +425,17 @@ def test_delta_prefill_and_fork_leave_the_state_in_place(delta_engine, one_chip,
 # -- the fused classic loop (ISSUE 31) ---------------------------------------
 
 
-@pytest.mark.parametrize("num_envs", [512, 2048])
-def test_fused_loop_stores_frames_lane_dense(one_chip, num_envs):
+@pytest.fixture(scope="module", params=[512, 2048])
+def fused_program(request, one_chip):
     """The ``impala_fused`` cell's program (unroll 20, 5 iterations a
-    dispatch, bf16 torso) holds no lane-padded uint8 frame array and
-    relayouts no frame batch inside a loop.  With ``obs`` carried as
-    ``[B, 84, 84, 4]`` the renderer wrote ``u8[B,84,84,4]{3,2,1,0:T(8,128)
-    (4,1)}``, 33.5 times padded, and a ``copy`` read it back on every
-    environment step: 65% of the cell's device time (PERF.md, PR 31)."""
+    dispatch, bf16 torso) compiled at ``num_envs``: ``(num_envs, elements of
+    a frame batch, compiled text)``."""
     from scalerl_tpu.agents.impala import ImpalaAgent
     from scalerl_tpu.config import ImpalaArguments
     from scalerl_tpu.envs import make_jax_vec_env
     from scalerl_tpu.runtime.device_loop import DeviceActorLearnerLoop
-    from scalerl_tpu.utils import tiled_layout
 
-    T = 20
+    num_envs, T = request.param, 20
     args = ImpalaArguments(
         env_id="SyntheticPixel-v0", use_lstm=False, hidden_size=512,
         rollout_length=T, batch_size=num_envs, max_timesteps=0,
@@ -447,13 +452,52 @@ def test_fused_loop_stores_frames_lane_dense(one_chip, num_envs):
     )
     key = jax.random.PRNGKey(0)
     carry = jax.eval_shape(loop.init_carry, key)
-    text = (
-        loop._train_many.lower(*_described((agent.state, carry, key), one_chip))
-        .compile().as_text()
-    )
-    frame_batch = num_envs * int(np.prod(venv.observation_shape))
+    with _cache_off():
+        text = (
+            loop._train_many.lower(*_described((agent.state, carry, key), one_chip))
+            .compile().as_text()
+        )
+    return num_envs, num_envs * int(np.prod(venv.observation_shape)), text
+
+
+def test_fused_loop_stores_frames_lane_dense(fused_program):
+    """The program holds no lane-padded uint8 frame array and relayouts no
+    frame batch inside a loop.  With ``obs`` carried as ``[B, 84, 84, 4]``
+    the renderer wrote ``u8[B,84,84,4]{3,2,1,0:T(8,128) (4,1)}``, 33.5
+    times padded, and a ``copy`` read it back on every environment step:
+    65% of the cell's device time (PERF.md, PR 31)."""
+    from scalerl_tpu.utils import tiled_layout
+
+    num_envs, frame_batch, text = fused_program
     faults = tiled_layout.lane_dense_faults(text, "u8", frame_batch, lane_dim=num_envs)
     assert not faults, faults
+
+
+def test_fused_loop_merges_time_and_envs_without_a_copy(fused_program):
+    """The learner merges ``[T, B]`` into conv1's batch axis.  With the
+    rows stacked T major-most that merge was ``copy bf16[21,84,84,1,4,B]``,
+    the whole scaled trajectory read and written once an iteration (8% of
+    the cell's device time, PERF.md, PR 43); written into a buffer
+    ``[84, 84, T+1, 4, B]`` pinned row-major it is a bitcast of the one
+    convert pass, and no loop body copies an array that large in either
+    dtype."""
+    from scalerl_tpu.utils import tiled_layout
+
+    num_envs, frame_batch, text = fused_program
+    for dtype in ("bf16", "u8"):
+        assert not tiled_layout.loop_body_copies(text, dtype, 10 * frame_batch)
+    buf = f"[84,84,21,4,{num_envs}]{{4,3,2,1,0:"
+    assert f"u8{buf}" in text  # the carry kept the pinned layout
+    merged = re.findall(
+        rf"= bf16\[{21 * num_envs},84,84,4\]\{{0,3,2,1:[^}}]*\}} bitcast\(%([\w.\-]+)\)", text
+    )
+    # conv1's operand comes straight from the convert pass over the buffer
+    # (the same bitcast inside a fusion reads a parameter: not this one)
+    assert any(
+        re.search(rf"%{re.escape(src)} = bf16{re.escape(buf)}[^}}]*\}} fusion\(", text)
+        for src in merged
+    ), merged
+    assert not [src for src in merged if src.startswith("copy")], merged
 
 
 def test_segment_kernels_compile_at_latent_attentions_head_shape(one_chip):

@@ -8,6 +8,12 @@ frame batch 32 times there.  The storage is an internal of
 loop to a plain per-step Python loop over ``venv.step`` and ``model.apply``
 on the same keys, and the mesh and super-chunk paths to the single-device
 chunked one.
+
+The trajectory's observations are written into one buffer whose time axis
+sits directly outside the stored frame's two minor axes, pinned row-major
+(ISSUE 43): the learner's ``[T, B] -> [T*B]`` merge is then a bitcast where
+a scan's stacked rows cost a copy of the whole bf16 trajectory.  The cases
+after the storage's hold that form to the stacked one bit for bit.
 """
 
 import jax
@@ -20,7 +26,10 @@ from scalerl_tpu.config import ImpalaArguments
 from scalerl_tpu.data.trajectory import Trajectory
 from scalerl_tpu.envs import make_jax_vec_env
 from scalerl_tpu.runtime.device_loop import (
+    ActorCarry,
     DeviceActorLearnerLoop,
+    _load_obs,
+    _store_obs,
     carry_env_axes,
 )
 
@@ -237,6 +246,200 @@ def test_obs_storage_is_recorded_once_a_shape(monkeypatch):
         tracing.reset()
 
 
+# -- the trajectory buffer (ISSUE 43) -----------------------------------------
+
+
+class _StackedRowsLoop(DeviceActorLearnerLoop):
+    """The loop as it was before ISSUE 43: the scan stacks ``c.obs`` as a
+    ``ys`` row, row T is concatenated on, the env axis moved to 1."""
+
+    def _unroll(self, params, carry, key):
+        core0 = carry.core_state
+
+        def step(c, k):
+            out, new_core = self.model.apply(
+                params, _load_obs(c.obs)[None], c.last_action[None],
+                c.reward[None], c.done[None], c.core_state,
+            )
+            logits = out.policy_logits[0]
+            k_act, k_env = jax.random.split(k)
+            action = jax.random.categorical(k_act, logits, axis=-1)
+            env_state, next_obs, reward, done = self.venv.step(
+                c.env_state, action, k_env
+            )
+            row = (c.obs, c.last_action, c.reward, c.done, logits)
+            ep_ret = c.episode_return + reward
+            new_c = ActorCarry(
+                env_state=env_state, obs=_store_obs(next_obs), last_action=action,
+                reward=reward, done=done, core_state=new_core,
+                episode_return=jnp.where(done, 0.0, ep_ret),
+                return_sum=c.return_sum + jnp.where(done, ep_ret, 0.0),
+                episode_count=c.episode_count + done.astype(jnp.float32),
+            )
+            return new_c, row
+
+        carry, rows = jax.lax.scan(
+            step, carry, jax.random.split(key, self.unroll_length)
+        )
+        obs_rows, la_rows, rew_rows, done_rows, logit_rows = rows
+        traj = Trajectory(
+            obs=jnp.moveaxis(
+                jnp.concatenate([obs_rows, carry.obs[None]], axis=0), -1, 1
+            ),
+            action=jnp.concatenate([la_rows, carry.last_action[None]], axis=0),
+            reward=jnp.concatenate([rew_rows, carry.reward[None]], axis=0),
+            done=jnp.concatenate([done_rows, carry.done[None]], axis=0),
+            logits=jnp.concatenate(
+                [logit_rows, jnp.zeros_like(logit_rows[:1])], axis=0
+            ),
+            core_state=core0,
+        )
+        return carry, traj
+
+
+def _stacked_twin(loop):
+    return _StackedRowsLoop(
+        loop.model, loop.venv, loop.learn_fn, loop.unroll_length,
+        iters_per_call=loop.iters_per_call,
+    )
+
+
+def _assert_bitwise(got, want, what=""):
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape and a.dtype == b.dtype, what
+        np.testing.assert_array_equal(a, b, err_msg=what)
+
+
+@pytest.mark.parametrize("name", sorted(ENVS))
+def test_buffered_trajectory_is_the_stacked_one_bitwise(name):
+    """``_unroll`` with the rows written into the buffer against the scan
+    that stacks them: the same trajectory and the same carry, bit for bit
+    (two scans over the same body, so floats too)."""
+    loop, agent, _venv = _build(name)
+    old = _stacked_twin(loop)
+    k_init, k_roll = jax.random.split(jax.random.PRNGKey(11))
+    params = agent.state.params
+    carry, traj = jax.jit(loop._unroll)(params, loop.init_carry(k_init), k_roll)
+    carry0, traj0 = jax.jit(old._unroll)(params, old.init_carry(k_init), k_roll)
+    _assert_bitwise(traj, traj0, "trajectory")
+    _assert_bitwise(carry, carry0, "carry")
+
+
+@pytest.mark.parametrize("name", sorted(ENVS))
+def test_two_dispatches_give_the_stacked_loops_metrics_bitwise(name):
+    """Two dispatches of two iterations each, the second from the first's
+    state and carry: every metric, the parameters and the carry equal the
+    stacked-rows loop's, bit for bit."""
+    loop, agent, _venv = _build(name, iters_per_call=2)
+    old = _stacked_twin(loop)
+    fresh = lambda: jax.tree_util.tree_map(jnp.copy, agent.state)  # noqa: E731
+    k_init, key = jax.random.split(jax.random.PRNGKey(7))
+
+    def two_dispatches(lp):
+        state, carry, k, stream = fresh(), lp.init_carry(k_init), key, []
+        for _ in range(2):
+            k, sub = jax.random.split(k)
+            state, carry, m = lp.train_chunk(state, carry, sub)
+            stream.append(m)
+        return state, carry, stream
+
+    state, carry, stream = two_dispatches(loop)
+    state0, carry0, stream0 = two_dispatches(old)
+    for i, (m, m0) in enumerate(zip(stream, stream0)):
+        assert set(m) == set(m0)
+        for k_ in m:
+            np.testing.assert_array_equal(
+                np.asarray(m[k_]), np.asarray(m0[k_]), err_msg=f"dispatch {i}: {k_}"
+            )
+    _assert_bitwise(state.params, state0.params, "params")
+    _assert_bitwise(carry, carry0, "carry")
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for inner in v if isinstance(v, (tuple, list)) else (v,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("name", sorted(ENVS))
+def test_unroll_writes_rows_in_place_and_concatenates_no_observation(name):
+    """The traced ``_unroll``: no ``concatenate`` gives an array the size
+    of the trajectory's observations (the stacked loop's does), the buffer
+    is written by ``dynamic_update_slice`` with the time axis directly
+    outside the stored frame's two minor axes, and every write is pinned."""
+    loop, agent, venv = _build(name)
+    carry = loop.init_carry(jax.random.PRNGKey(0))
+    stored = carry.obs.shape
+    t_axis = max(len(stored) - 2, 0)
+    buf_shape = (*stored[:t_axis], T + 1, *stored[t_axis:])
+    sizes = int(np.prod(buf_shape))
+
+    def concatenated(lp):
+        jaxpr = jax.make_jaxpr(lp._unroll)(agent.state.params, carry, jax.random.PRNGKey(1))
+        eqns = list(_eqns(jaxpr.jaxpr))
+        return eqns, [
+            e for e in eqns
+            if e.primitive.name == "concatenate"
+            and e.outvars[0].aval.dtype == carry.obs.dtype
+            and e.outvars[0].aval.size == sizes
+        ]
+
+    eqns, found = concatenated(loop)
+    assert not found, found
+    assert concatenated(_stacked_twin(loop))[1]  # the reader sees the old form's
+    writes = [
+        e for e in eqns
+        if e.primitive.name == "dynamic_update_slice"
+        and e.outvars[0].aval.shape == buf_shape
+    ]
+    pins = [
+        e for e in eqns
+        if e.primitive.name == "layout_constraint"
+        and e.outvars[0].aval.shape == buf_shape
+    ]
+    assert len(writes) == 2, writes  # the scan body's, and row T's
+    assert len(pins) == 3, pins  # the buffer's creation and both writes
+    assert all(
+        e.params["layout"].major_to_minor == tuple(range(len(buf_shape))) for e in pins
+    )
+
+
+def test_traj_storage_is_recorded_once_a_shape(monkeypatch):
+    """As the storage: one zero-length ``fused.traj_storage`` span a traced
+    shape says which trajectory buffer ran."""
+    from scalerl_tpu.runtime import tracing
+
+    monkeypatch.setenv(tracing.ENV_SAMPLE, "1.0")
+    tracing.reset()
+    try:
+        # geometries no other test traces (the note is cached by shape)
+        for name, num_envs, want in (
+            ("vector", 10, {"buffer_shape": [T + 1, 4, 10], "dtype": "float32", "time_axis": 0}),
+            ("pixel", 2, {"buffer_shape": [84, 84, T + 1, 4, 2], "dtype": "uint8", "time_axis": 2}),
+        ):
+            loop, agent, _venv = _build(name, num_envs=num_envs)
+            unroll = jax.jit(loop._unroll)
+            carry = loop.init_carry(jax.random.PRNGKey(0))
+            for i in range(2):
+                carry, _traj = unroll(agent.state.params, carry, jax.random.PRNGKey(i))
+            spans = [
+                s for s in tracing.get_tracer().finished()
+                if s["name"] == "fused.traj_storage"
+                and s["attrs"]["dtype"] == want["dtype"]
+            ]
+            assert len(spans) == 1, spans
+            assert spans[0]["attrs"] == {**want, "pinned": True}
+    finally:
+        monkeypatch.delenv(tracing.ENV_SAMPLE)
+        tracing.reset()
+
+
 # -- reading a tiled layout (scalerl_tpu/utils/tiled_layout.py) --------------
 
 FRAMES = 2048 * 84 * 84 * 4
@@ -293,6 +496,59 @@ ENTRY %main (a: u8[64,8,8,4]) -> u8[64,8,8,4] {
     assert faults[-1].startswith("relayout a loop trip: %copy.3")
     with pytest.raises(ValueError):
         tiled_layout.lane_dense_faults(text, "s8", 1, lane_dim=64)
+
+
+# the learner's input in the compiled ``impala_fused`` dispatch at 2048 envs
+# (AOT for a described v5e, PR 43): the three lines from the stacked rows
+# to conv1's operand, in the iteration loop's body
+_BODY = """\
+%wide.region_0.145 (p: (s32[], u8[84,84,4,2048])) -> (s32[], u8[84,84,4,2048]) {{
+{lines}
+}}
+
+ENTRY %main.159 (a: u8[84,84,4,2048]) -> u8[84,84,4,2048] {{
+  %while.176 = (s32[], u8[84,84,4,2048]{{3,2,1,0}}) while(%t), condition=%cond.1, body=%wide.region_0.145
+}}
+"""
+TRAJECTORY_TEXTS = {
+    # rows stacked as a scan's ``ys``: T major-most, and a copy moves it
+    "stacked": """\
+  %multiply_bitcast_fusion.3 = bf16[21,84,84,1,4,2048]{5,4,3,2,1,0:T(4,128)(2,1)} fusion(%bitcast.375, %while.175), kind=kLoop, calls=%fused_computation.280
+  %copy.52 = bf16[21,84,84,1,4,2048]{5,4,0,3,2,1:T(4,128)(2,1)} copy(%multiply_bitcast_fusion.3), metadata={op_name="jit(train_chunk)/while/body/closed_call/jvp(AtariNet)/reshape"}
+  %bitcast.354 = bf16[43008,84,84,4]{0,3,2,1:T(4,128)(2,1)} bitcast(%copy.52)""",
+    # the buffer ``[84, 84, T+1, 4, B]`` left to XLA's layout assignment:
+    # the ``while`` carry is given ``{4,3,1,0,2}``, T major-most again, and the
+    # copy stays
+    "unpinned": """\
+  %bitcast_dynamic-update-slice_fusion.9 = u8[84,84,21,4,2048]{4,3,1,0,2:T(4,128)(4,1)} fusion(%while.175, %get-tuple-element.4918), kind=kLoop, calls=%fused_computation.317
+  %multiply_bitcast_fusion.3 = bf16[21,84,84,1,4,2048]{5,4,3,2,1,0:T(4,128)(2,1)} fusion(%bitcast_dynamic-update-slice_fusion.9), kind=kLoop, calls=%fused_computation.280
+  %copy.51 = bf16[21,84,84,1,4,2048]{5,4,0,3,2,1:T(4,128)(2,1)} copy(%multiply_bitcast_fusion.3), metadata={op_name="jit(train_chunk)/while/body/closed_call/jvp(AtariNet)/reshape"}
+  %bitcast.358 = bf16[43008,84,84,4]{0,3,2,1:T(4,128)(2,1)} bitcast(%copy.51)""",
+    # the buffer pinned row-major: the merge is a bitcast
+    "pinned": """\
+  %bitcast_dynamic-update-slice_fusion.9 = u8[84,84,21,4,2048]{4,3,2,1,0:T(4,128)(4,1)} fusion(%while.175, %get-tuple-element.4918), kind=kLoop, calls=%fused_computation.317
+  %convert_multiply_fusion.10 = bf16[84,84,21,4,2048]{4,3,2,1,0:T(4,128)(2,1)} fusion(%bitcast_dynamic-update-slice_fusion.9), kind=kLoop, calls=%fused_computation.280
+  %bitcast.354 = bf16[43008,84,84,4]{0,3,2,1:T(4,128)(2,1)} bitcast(%convert_multiply_fusion.10)""",
+}
+
+
+@pytest.mark.parametrize(
+    "form, copies", [("stacked", ["%copy.52"]), ("unpinned", ["%copy.51"]), ("pinned", [])]
+)
+def test_loop_body_copies_reports_the_trajectory_copy(form, copies):
+    """The reader the layout tests hold the fused program to finds the
+    copy of the whole bf16 trajectory in the stacked-rows text and in the
+    unpinned buffer's, and nothing in the pinned buffer's (nor a uint8
+    relayout in any)."""
+    from scalerl_tpu.utils import tiled_layout
+
+    text = _BODY.format(lines=TRAJECTORY_TEXTS[form])
+    found = tiled_layout.loop_body_copies(text, "bf16", 10_000_000)
+    assert [line.split(" = ")[0] for line in found] == copies
+    assert tiled_layout.loop_body_copies(text, "u8", 10_000_000) == []
+    # the same bytes either way: every form is stored dense, envs in the lanes
+    for a in tiled_layout.arrays(text, "bf16"):
+        assert a.padding == 1.0 and a.minor_dim in (2048, 43008), a
 
 
 def test_candidate_state_faults_reads_a_guards_branch():

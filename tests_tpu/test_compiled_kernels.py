@@ -197,6 +197,47 @@ def test_fused_program_stores_frames_lane_dense_at_the_cells_geometry():
     assert not faults, faults
 
 
+def test_fused_program_copies_no_trajectory_on_tpu():
+    """A fused dispatch as the chip's own compiler builds it, at a small
+    env count: no loop body copies a trajectory-sized bf16 (or uint8)
+    array.  With the scan's rows stacked T major-most the learner's
+    ``[T, B] -> [T*B]`` merge was ``copy bf16[21,84,84,1,4,B]``, the whole
+    scaled trajectory once an iteration (PERF.md, PR 43); the rows are
+    now written into a buffer ``[84, 84, T+1, 4, B]`` pinned row-major.
+    The dispatch then runs once: the pinned layout executes."""
+    from scalerl_tpu.agents.impala import ImpalaAgent
+    from scalerl_tpu.config import ImpalaArguments
+    from scalerl_tpu.envs import make_jax_vec_env
+    from scalerl_tpu.runtime.device_loop import DeviceActorLearnerLoop
+    from scalerl_tpu.utils import tiled_layout
+
+    B, T = 256, 20
+    args = ImpalaArguments(
+        env_id="SyntheticPixel-v0", use_lstm=False, hidden_size=512,
+        rollout_length=T, batch_size=B, max_timesteps=0,
+        compute_dtype="bfloat16", logger_backend="none",
+    )
+    venv = make_jax_vec_env(args.env_id, num_envs=B)
+    agent = ImpalaAgent(
+        args, obs_shape=venv.observation_shape, num_actions=venv.num_actions,
+        obs_dtype=venv.env.observation_dtype,
+    )
+    loop = DeviceActorLearnerLoop(
+        agent.model, venv, agent.make_learn_fn(), T, iters_per_call=2
+    )
+    assert loop.iter_mode == "scan"
+    key = jax.random.PRNGKey(0)
+    carry = loop.init_carry(key)
+    text = loop._train_many.lower(agent.state, carry, key).compile().as_text()
+    trajectory = (T + 1) * B * int(np.prod(venv.observation_shape))
+    assert f"u8[84,84,{T + 1},4,{B}]{{4,3,2,1,0:" in text
+    for dtype in ("bf16", "u8"):
+        found = tiled_layout.loop_body_copies(text, dtype, trajectory)
+        assert not found, found
+    _state, _carry, m = loop.train_chunk(agent.state, carry, jax.random.PRNGKey(1))
+    assert np.isfinite(float(m["total_loss"]))
+
+
 def test_breakout_fused_chunk_on_tpu():
     """The flagship Breakout game + fused IMPALA iteration compiles and
     executes on the chip (the wall-clock-to-score path of
