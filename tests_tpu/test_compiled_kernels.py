@@ -1062,13 +1062,14 @@ def test_token_learner_guard_decides_before_the_update_on_tpu(bf16):
 
 
 def test_async_collectives_reschedule_the_learn_step_and_keep_its_sums():
-    """ISSUE 41: on the four-chip host ``make_parallel_learn_fn`` compiles
-    the ``dp=2 x mp=2`` learn program with asynchronous collectives.  The
-    reductions are the parent's (operands, float32, groups), run earlier:
-    one step of a small packed token learner with the options and one by
-    the parent's plain ``jax.jit`` call give the same loss, gradient norm
-    and updated ``block_0/qkv/kernel``, and only the first text holds
-    asynchronous collective fusions."""
+    """ISSUE 41, ISSUE 44: on the four-chip host ``make_parallel_learn_fn``
+    compiles the ``dp=2 x mp=2`` learn program with the option table
+    (asynchronous collectives, the combiner's threshold).  The reductions
+    are the parent's (operands, float32, groups), run earlier: one step of
+    a small packed token learner with the options and one by the parent's
+    plain ``jax.jit`` call give the same loss, gradient norm and updated
+    ``block_0/qkv/kernel``, and only the first text holds asynchronous
+    collective fusions."""
     from scalerl_tpu.agents.token_ppo import TokenPPOAgent
     from scalerl_tpu.config import GenRLArguments
     from scalerl_tpu.genrl.rollout import pack_learner_batch
