@@ -38,19 +38,22 @@ the host swaps *sequences* through them —
   ``n-1`` lanes map the SAME full prompt pages copy-on-write and only the
   last partial page is physically copied per lane by a small jitted
   page-copy program — so a GRPO-shaped round pays ~1/n of its prefill;
-- **paged KV** — the pools the model describes
-  (``TransformerPolicy.init_paged_cache``: a K and a V pool a block, or
-  the one latent pool an ``mla`` attention caches into) plus the jax-free refcounting :class:`~scalerl_tpu.genrl.paging
-  .PageAllocator`: admission reserves a sequence's worst-case pages
+- **paged KV** — the cache the model describes
+  (``TransformerPolicy.init_paged_cache``, one
+  :class:`~scalerl_tpu.models.transformer.ModelCache` whatever the stack:
+  a K and a V pool an attention layer, or the one latent pool an ``mla``
+  attention caches into) plus the jax-free refcounting
+  :class:`~scalerl_tpu.genrl.paging.PageAllocator`: admission reserves a
+  sequence's worst-case pages
   (exhaustion backpressures, never corrupts; shared pages count against
   EVERY holder's reservation, so sharing never loosens the guarantee)
   while physical pages are drawn lazily as contexts grow.
 - **recurrent state beside the pages** -- a stack with recurrent layers
-  (``model.recurrent``: Mamba-2 or Gated DeltaNet mixers) caches into a
-  :class:`~scalerl_tpu.models.transformer.HybridCache`: page pools for its
-  attention layers and, for each recurrent layer whatever its kind, a
-  float32 state indexed by LANE whose size does not
-  depend on a lane's length.  It rides in the same pytree as the pools
+  (``model.recurrent``: Mamba-2 or Gated DeltaNet mixers) has, in the
+  same cache's ``ssm`` and ``conv`` fields (empty for every other model),
+  a float32 state for each recurrent layer whatever its kind, indexed by
+  LANE, whose size does not depend on a lane's length, beside the page
+  pools of its attention layers.  It rides in the same pytree as the pools
   (donated through every program, never copied whole): the local prefill
   writes a lane's rows at the prompt's true length, every decode substep
   updates them in place, the group fork copies the leader's rows to the
@@ -460,8 +463,6 @@ class ContinuousEngine(ParamSnapshotPlane):
         # bytes of recurrent state a lane carries, all layers together
         self._state_bytes_per_lane = (
             sum(a.nbytes for a in self._pools.ssm + self._pools.conv) // L
-            if self._recurrent
-            else 0
         )
         # what the decode program carries in place beside the pools
         self._dispatch_attrs = (

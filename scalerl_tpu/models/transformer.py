@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, NamedTuple, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -165,6 +165,31 @@ class BlockSpec:
         """What the layer's causal convolution runs over: Mamba-2's ``[x |
         B | C]``, the delta rule's ``[q | k | v]``."""
         return self.ssm_heads * self.ssm_head_dim + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def owns(self) -> Dict[str, int]:
+        """How many arrays of each of :class:`ModelCache`'s fields the
+        layer caches into: a recurrent mixer one state and one window of
+        taps, an attention one K and one V pool (or one latent pool, and
+        the double layer's two attentions two), any other mixer none."""
+        if self.recurrent:
+            return {"ssm": 1, "conv": 1}
+        if self.layer == "mixer" and self.mixer != "attention":
+            return {}
+        if self.attention == "mla":
+            return {"rows": 2 if self.layer == "scmoe" else 1}
+        return {"k": 1, "v": 1}
+
+    @property
+    def kind(self) -> str:
+        """The layer as ``model.layers`` notes it: its layer kind and what
+        fills it (a plain layer that names its mixer: the mixer, then the
+        FFN)."""
+        if self.layer == "mixer":
+            return f"{self.layer}/{self.mixer}"
+        if self.mixer:
+            return f"{self.layer}/{self.mixer}/{self.ffn}"
+        return f"{self.layer}/{self.ffn}"
 
 
 def block_spec(
@@ -474,95 +499,220 @@ class TransformerOutput(NamedTuple):
     mtp_logits: Optional[jnp.ndarray] = None
 
 
-class PagedKVCache(NamedTuple):
-    """Block-paged key/value cache: a fixed pool shared by every lane.
+class ModelCache(NamedTuple):
+    """What a model's layers cache into, one pytree whatever the stack: a
+    tuple of arrays a kind, each in layer order and empty where the model
+    has none of the kind (an empty tuple has no leaves: a program's
+    arguments are the arrays the model has).  ``BlockSpec.owns`` says how
+    many of each a layer owns, :meth:`TransformerPolicy.init_paged_cache`
+    makes them, and a layer is handed, and hands back, a cache of its own
+    arrays alone.
 
-    ``k``/``v``: one lane-dense ``[num_pages, page_size, H*D]`` pool per
-    transformer block: a token's heads lie side by side on the minor axis,
-    so the TPU runtime stores the pool row-major and the paged-decode
-    kernel reads it in place (``ops/pallas_paged_attention.py``; a
-    ``[.., H, D]`` pool with ``D < 128`` was stored page-index-minor and
-    copied whole, twice, by every decode program).  Consumers split the
-    heads out of the rows they gathered, never out of the pool.  Lanes own
-    *pages*, not contiguous rows: a host-side allocator
-    (``genrl/paging.py``) hands each lane an ordered page list, and the
-    decode path writes token ``p`` of a lane into page
-    ``table[p // page_size]`` at slot ``p % page_size`` — so KV memory
-    scales with LIVE tokens across all lanes instead of
-    ``max_bucket x lanes`` (the vLLM shape).  Page 0 is the allocator's
-    null page: dead-lane and pad writes are routed there and it is never
-    read (every read is masked by a lane's true length).
+    **Pages** (``k``, ``v``, ``rows``): lane-dense ``[num_pages,
+    page_size, width]`` pools shared by every lane.  ``k``/``v``: one of
+    each an attention layer, ``width = kv_heads x head_dim``: a token's
+    heads lie side by side on the minor axis, so the TPU runtime stores
+    the pool row-major and the paged-decode kernel reads it in place
+    (``ops/pallas_paged_attention.py``; a ``[.., H, D]`` pool with ``D <
+    128`` was stored page-index-minor and copied whole, twice, by every
+    decode program).  Consumers split the heads out of the rows they
+    gathered, never out of the pool.  ``rows``: ONE pool a latent
+    (``mla``) attention, of ``[c | rotated k_pe | zeros]`` rows (``width =
+    latent_pool_width(kv_lora_rank + qk_rope_head_dim)``, 640 for the
+    published 576), which every head shares and which holds the values
+    too; one a plain layer, two a ``scmoe`` layer.  Lanes own *pages*, not
+    contiguous rows: a host-side allocator (``genrl/paging.py``) hands
+    each lane an ordered page list, and the decode path writes token ``p``
+    of a lane into page ``table[p // page_size]`` at slot ``p %
+    page_size``, so KV memory scales with LIVE tokens across all lanes
+    instead of ``max_bucket x lanes`` (the vLLM shape).  Page 0 is the
+    allocator's null page: dead-lane and pad writes are routed there and
+    it is never read (every read is masked by a lane's true length).
+    Sharing, forks and the prefix cache are page-index facts and do not
+    see the kind.
+
+    **Lanes** (``ssm``, ``conv``): the state of the recurrent layers
+    whatever their kind, float32, indexed by LANE and of a size that does
+    not depend on a lane's length: ``ssm [lanes, *spec.state_shape]`` (a
+    Mamba-2 layer's ``[heads, head_dim, state]``, which
+    :func:`ssm_decode_update` updates in place; a Gated DeltaNet layer's
+    ``[heads, key, value]``, which :func:`gdn_decode_update` does) and
+    ``conv [lanes, taps - 1, channels]``.  Written by the prefill at the
+    prompt's true length, updated in place by every decode substep and
+    copied leader to member by the group fork (:func:`fork_cache`); a
+    page table says nothing about it, so a prefix-cache hit and a
+    page-cursor rollback cannot serve a model that has one.
     """
 
-    k: Tuple[jnp.ndarray, ...]
-    v: Tuple[jnp.ndarray, ...]
+    k: Tuple[jnp.ndarray, ...] = ()
+    v: Tuple[jnp.ndarray, ...] = ()
+    rows: Tuple[jnp.ndarray, ...] = ()
+    ssm: Tuple[jnp.ndarray, ...] = ()
+    conv: Tuple[jnp.ndarray, ...] = ()
 
 
-def init_paged_kv_cache(
-    num_pages: int,
-    page_size: int,
-    num_layers: int,
-    num_heads: int,
-    head_dim: int,
-    dtype=jnp.float32,
-) -> PagedKVCache:
-    """Zeroed lane-dense page pools (page 0 = the never-read null page)."""
-    shape = (num_pages, page_size, num_heads * head_dim)
-    return PagedKVCache(
-        k=tuple(jnp.zeros(shape, dtype) for _ in range(num_layers)),
-        v=tuple(jnp.zeros(shape, dtype) for _ in range(num_layers)),
-    )
+_PAGE_FIELDS = ("k", "v", "rows")  # the others are indexed by lane
 
 
-class LatentKVCache(NamedTuple):
-    """The paged cache of a latent-attention (``mla``) model: per
-    attention ONE lane-dense ``[num_pages, page_size, W]`` pool of
-    ``[c | rotated k_pe | zeros]`` rows (``W`` =
-    ``latent_pool_width(kv_lora_rank + qk_rope_head_dim)``, 640 for the
-    published 576), which every head shares and which holds the values
-    too: no V pool.  The pools lie in layer order, one a plain layer and
-    two a ``scmoe`` layer (its two attentions).  Pages, tables, the null page
-    and every rule of :class:`PagedKVCache` are the same: sharing, forks
-    and the prefix cache are page-index facts and do not see the kind."""
-
-    rows: Tuple[jnp.ndarray, ...]
+def _layer_entries(cache: ModelCache, specs) -> list:
+    """The cache cut into each layer's own arrays (``BlockSpec.owns``), a
+    cache of them alone a layer, in layer order."""
+    entries = []
+    for spec in specs:
+        own = [spec.owns.get(name, 0) for name in ModelCache._fields]
+        entries.append(ModelCache(*(arrays[:n] for arrays, n in zip(cache, own))))
+        cache = ModelCache(*(arrays[n:] for arrays, n in zip(cache, own)))
+    return entries
 
 
-class HybridCache(NamedTuple):
-    """The cache of a stack with recurrent layers (``nemotron_h``,
-    ``qwen3_next``): the K and V page pools of its attention layers, in
-    layer order (``[num_pages, page_size, kv_heads x head_dim]``, every
-    rule of :class:`PagedKVCache`), **and** the recurrent state of its
-    recurrent layers, whatever their kind, in layer order: ``ssm [lanes,
-    *spec.state_shape]`` (a Mamba-2 layer's ``[heads, head_dim, state]``,
-    which :func:`ssm_decode_update` updates in place; a Gated DeltaNet
-    layer's ``[heads, key, value]``, which :func:`gdn_decode_update` does)
-    and ``conv [lanes, taps - 1, channels]``, float32, indexed by LANE and
-    of a size that does not depend on a lane's length.  The state is written by the prefill at the
-    prompt's true length, updated in place by every decode substep and
-    copied leader to member by the group fork (:func:`fork_cache`); a page
-    table says nothing about it, so a prefix-cache hit and a page-cursor
-    rollback cannot serve a model that has one."""
-
-    k: Tuple[jnp.ndarray, ...]
-    v: Tuple[jnp.ndarray, ...]
-    ssm: Tuple[jnp.ndarray, ...]
-    conv: Tuple[jnp.ndarray, ...]
+def _join(entries) -> ModelCache:
+    """The layers' entries, in layer order, as the model's cache again."""
+    return ModelCache(*(sum(parts, ()) for parts in zip(*entries)))
 
 
-def fork_cache(cache, src_page, dst_page, src_lane, dst_lane):
+def fork_cache(cache: ModelCache, src_page, dst_page, src_lane, dst_lane) -> ModelCache:
     """A group fork on a model's cache: pool pages ``src_page`` copied to
-    ``dst_page`` (partial prompt pages; pad rows copy null to null) and,
-    where the cache has lane-indexed state, lanes ``src_lane``'s rows to
-    ``dst_lane``'s (pad rows carry an out-of-range lane and drop)."""
+    ``dst_page`` (partial prompt pages; pad rows copy null to null) and
+    lanes ``src_lane``'s rows of the lane-indexed state to ``dst_lane``'s
+    (pad rows carry an out-of-range lane and drop)."""
     pages = lambda pool: pool.at[dst_page].set(pool[src_page])  # noqa: E731
-    if not isinstance(cache, HybridCache):
-        return jax.tree_util.tree_map(pages, cache)
     lanes = lambda st: st.at[dst_lane].set(st[src_lane], mode="drop")  # noqa: E731
-    return HybridCache(
-        k=tuple(map(pages, cache.k)), v=tuple(map(pages, cache.v)),
-        ssm=tuple(map(lanes, cache.ssm)), conv=tuple(map(lanes, cache.conv)),
-    )
+    return ModelCache(*(
+        tuple(map(pages if name in _PAGE_FIELDS else lanes, arrays))
+        for name, arrays in zip(ModelCache._fields, cache)
+    ))
+
+
+# the arguments that spell each form, beside ``positions`` (and, for a
+# prefill of a model with recurrent layers, ``state_lanes``)
+_FORMS = {
+    "causal": (),
+    "masked": ("attn_mask",),
+    "packed": ("segment_ids",),
+    "prefill": ("attn_mask", "page_ids", "page_offsets"),
+    "tail": ("page_ids", "page_offsets", "page_table", "prefix_starts"),
+    "decode": ("page_ids", "page_offsets", "page_table", "attn_lengths"),
+}
+
+
+@dataclasses.dataclass(frozen=True)
+class Call:
+    """How the model is being called: decided once, by :meth:`Call.of` at
+    the top of :class:`TransformerPolicy`, from the keyword arguments
+    that method takes.  ``mode`` is a Python string, fixed when a program
+    is traced; every layer and mixer switches on it and reads the arrays
+    its mode has.  One set of parameters serves all six:
+
+    - ``causal``: the whole-trajectory forward, no argument but
+      ``positions``; attention is the model's causal ``attn_fn``.
+    - ``masked`` (``attn_mask [B, T, T]``, True = attend): a full forward
+      under an explicit mask: the learner's pass over left-padded
+      generated sequences (:func:`sequence_attention_mask`), explicit
+      masked attention against the call's own k/v.
+    - ``packed`` (``segment_ids [B, S]``, the pad-free learner): a full
+      forward over rows holding several independent sequences; a token
+      attends causally WITHIN its own nonzero segment, through the
+      model's ``segment_attn_fn`` (the Pallas segment flash kernel, which
+      skips cross-segment and pad blocks).  Callers pass per-segment
+      ``positions`` (reset to 0 at every segment start,
+      ``genrl/rollout.py``).  A model without that kernel runs the same
+      rows as ``masked`` under the dense :func:`packed_attention_mask`,
+      made here once for every layer.
+    - the three on a cache (``paged_cache``, the continuous-batching
+      plane): each scatters this call's keys and values (or latent rows)
+      into pool pages first, token ``t`` of row ``b`` into ``(page_ids[b,
+      t], page_offsets[b, t])``, dead-lane and pad writes routed to the
+      null page by the caller, and the model returns ``(output, cache)``:
+
+      - ``prefill`` (``attn_mask``, no ``page_table``): fresh RIGHT-padded
+        prompts (:func:`prompt_attention_mask`); the whole context is in
+        the call, so attention is local and the pool write-only.  On a
+        recurrent model ``state_lanes [B]`` names the lanes whose state
+        rows the prompts write, at their true lengths (an id out of range
+        drops).
+      - ``decode`` (``page_table [B, M]``, ``attn_lengths [B]``, ``T =
+        1``, row ``b`` is lane ``b``): attention gathers through the
+        table by the model's ``paged_attn_fn``; a recurrent layer updates
+        every lane's state in place.
+      - ``tail`` (``page_table``, ``prefix_starts [B]``): the
+        shared-table tail prefill of the prefix-cache path: the ``T``
+        tokens sit at positions ``prefix_starts[b] + t`` on top of a
+        cached prefix whose K/V already lives in pages the table maps;
+        attention gathers the WHOLE context (prefix and this chunk)
+        through the table under a causal-from-start mask
+        (:func:`_tail_mask`), plain XLA and no kernel, so sharing stays a
+        page-table fact.  The speculative verify pass
+        (``genrl/continuous.py``) rides this form with ``T`` = draft
+        bucket + 1: the mask keeps rejected slots' K/V (garbage past the
+        cursor) out of every query, so a draft rollback never touches the
+        device.  A recurrent model has no such form: its state cannot be
+        entered at a page boundary or rewound by a page cursor.
+
+    On a model with recurrent layers ``real [B, T]`` says which tokens
+    are real and ``runs [B, T]`` (:func:`run_ids`) where a recurrence
+    starts anew: the packed rows' segments, or the diagonal of a mask (a
+    token that may attend itself is real); a ``causal`` call's rows are
+    one run of real tokens each, and ``decode`` reads neither.
+    """
+
+    mode: str  # causal | masked | packed | prefill | tail | decode
+    attn_mask: Optional[jnp.ndarray] = None
+    segment_ids: Optional[jnp.ndarray] = None
+    page_ids: Optional[jnp.ndarray] = None
+    page_offsets: Optional[jnp.ndarray] = None
+    page_table: Optional[jnp.ndarray] = None
+    attn_lengths: Optional[jnp.ndarray] = None
+    prefix_starts: Optional[jnp.ndarray] = None
+    runs: Optional[jnp.ndarray] = None
+    real: Optional[jnp.ndarray] = None
+    state_lanes: Optional[jnp.ndarray] = None
+
+    @property
+    def paged(self) -> bool:
+        """Whether the call runs on a cache (and the model returns one)."""
+        return self.mode in ("prefill", "tail", "decode")
+
+    @classmethod
+    def of(
+        cls, *, recurrent: bool, segment_kernel: bool, mtp: bool, paged_cache, **arrays
+    ) -> "Call":
+        """The form the arguments spell (``arrays``: the fields above that
+        a caller passes, None where it did not), for a model that has
+        ``recurrent`` layers or none and a ``segment_kernel`` or none;
+        ``ValueError`` for a set that spells none of the six."""
+        given = {name for name, a in arrays.items() if a is not None}
+        if paged_cache is None:
+            mode = "packed" if "segment_ids" in given else "masked" if "attn_mask" in given else "causal"
+        else:
+            mode = "prefill" if "page_table" not in given else "tail" if "prefix_starts" in given else "decode"
+        takes = set(_FORMS[mode]) | ({"state_lanes"} if recurrent and mode == "prefill" else set())
+        if mtp and paged_cache is not None:
+            given.add("mtp")  # the module runs on no cache
+        if given != takes:
+            raise ValueError(
+                f"a {mode} call {'on' if paged_cache is not None else 'without'} a "
+                f"paged_cache takes {sorted(takes)} beside positions, got {sorted(given)}"
+            )
+        if recurrent and mode == "tail":
+            raise ValueError(
+                "a recurrent layer has no tail prefill over a cached "
+                "prefix and no speculative verify: its state cannot be "
+                "entered at a page boundary or rewound by a page cursor"
+            )
+        attn_mask, segment_ids = arrays["attn_mask"], arrays["segment_ids"]
+        runs = real = None
+        if recurrent and mode == "packed":
+            real = segment_ids > 0
+            runs = run_ids(segment_ids)
+        elif recurrent and mode in ("masked", "prefill"):
+            real = jnp.diagonal(attn_mask, axis1=1, axis2=2)
+            runs = run_ids(real.astype(jnp.int32))
+        if mode == "packed" and not segment_kernel:
+            # ONE dense [B, S, S] mask shared by every layer: the XLA
+            # reference path and the off-TPU shape
+            mode = "masked"
+            arrays.update(attn_mask=packed_attention_mask(segment_ids), segment_ids=None)
+        return cls(mode, runs=runs, real=real, **arrays)
 
 
 def prompt_attention_mask(lengths: jnp.ndarray, total_len: int) -> jnp.ndarray:
@@ -706,18 +856,30 @@ def rotary_fn(
     return rotate
 
 
-def _scatter_rows(pool: jnp.ndarray, flat_idx: jnp.ndarray, rows: jnp.ndarray):
-    """This call's rows into pool pages: a flat single-axis scatter (page
-    id x page size + offset) into the lane-dense pool.  The reshape is a
-    bitcast, and XLA:CPU lowers 1-level row scatters measurably faster
-    than the 2-level fancy-index form."""
-    N, ps, width = pool.shape
-    return (
-        pool.reshape(N * ps, width)
+def _write_pages(call: Call, pools, rows):
+    """This call's ``rows`` (one ``[B, T, ...]`` array a pool) into the
+    pools' pages: a flat single-axis scatter (page id x page size +
+    offset) into each lane-dense pool.  The reshape is a bitcast, and
+    XLA:CPU lowers 1-level row scatters measurably faster than the
+    2-level fancy-index form."""
+    N, ps, _ = pools[0].shape
+    flat_idx = (call.page_ids * ps + call.page_offsets).reshape(-1)
+    return tuple(
+        pool.reshape(N * ps, -1)
         .at[flat_idx]
-        .set(rows.astype(pool.dtype).reshape(-1, width))
+        .set(new.astype(pool.dtype).reshape(-1, pool.shape[2]))
         .reshape(pool.shape)
+        for pool, new in zip(pools, rows)
     )
+
+
+def _tail_mask(call: Call, T: int, context: int) -> jnp.ndarray:
+    """``[B, T, context]``: a tail prefill's query ``t`` of row ``b``,
+    at position ``prefix_starts[b] + t``, attends the gathered context
+    causally from its start."""
+    pos = jnp.arange(context)[None, None, :]
+    qpos = (call.prefix_starts[:, None] + jnp.arange(T)[None, :])[:, :, None]
+    return pos <= qpos
 
 
 def _routed_experts(spec: BlockSpec, dt) -> RoutedExperts:
@@ -739,16 +901,12 @@ def _repeat_kv(x: jnp.ndarray, num_heads: int) -> jnp.ndarray:
     return x if kv == num_heads else jnp.repeat(x, num_heads // kv, axis=2)
 
 
-def _mha(
-    mod, h, attn_mask=None, paged_cache=None, page_ids=None, page_offsets=None,
-    page_table=None, attn_lengths=None, prefix_starts=None, segment_ids=None,
-):
+def _mha(mod, h, call: Call, cache: Optional[ModelCache]):
     """Multi-head attention on a normed input ``h [B, T, d]``, inside the
     compact ``__call__`` of ``mod`` (a :class:`_Block` or a
     :class:`_MixerBlock`: the projections are ``mod``'s own children, so a
     plain layer's tree is what it always was).  Returns ``(out [B, T, d],
-    (k_pages, v_pages) or None)``; the paths are those of
-    :class:`_Block`'s docstring.
+    the layer's K and V pools written, or None)``.
 
     With ``spec.kv_heads`` key/value heads under more query heads (or a
     gated query) the projections are ``q`` and ``kv`` apart, the pools hold ``kv_heads x D``
@@ -795,63 +953,42 @@ def _mha(
     if mod.rotary is not None:
         # before every cache write: K is stored normed and rotated
         q, k = mod.rotary(q), mod.rotary(k)
-    new_cache = None
-    if paged_cache is not None:
-        kp, vp = paged_cache
-        flat_idx = (page_ids * kp.shape[1] + page_offsets).reshape(B * T)
-        kp = _scatter_rows(kp, flat_idx, k)
-        vp = _scatter_rows(vp, flat_idx, v)
-        if page_table is not None and prefix_starts is not None:
-            # shared-table tail prefill: gather the whole context
-            # (cached prefix pages + the tail just scattered above)
-            # through the table, attend causal-from-start — the
-            # compute twin of the decode seam at T > 1, kernel-free.
-            # The speculative verify pass (genrl/continuous.py) rides
-            # this exact path with T = draft bucket + 1: slot j is
-            # position prefix_starts + j, the pos <= qpos mask keeps
-            # rejected slots' K/V (garbage past the cursor) out of
-            # every query, so draft rollback never touches the device.
-            # The heads are split out of the gathered rows: reshaping
-            # the pool itself would bring its relayout copy back
-            kg = _repeat_kv(gather_pages(kp, page_table, KV), H)
-            vg = _repeat_kv(gather_pages(vp, page_table, KV), H)
-            pos = jnp.arange(kg.shape[1])[None, None, :]
-            qpos = (
-                prefix_starts[:, None] + jnp.arange(T)[None, :]
-            )[:, :, None]
-            out = _masked_attention(
-                q, kg, vg, pos <= qpos, mod.dtype
-            )
-        elif page_table is not None:
-            paged_attn = mod.paged_attn_fn or paged_attention_reference
-            out = paged_attn(q, kp, vp, page_table, attn_lengths)
-            out = out.astype(mod.dtype)
-        else:
-            out = _masked_attention(
-                q, _repeat_kv(k, H), _repeat_kv(v, H), attn_mask, mod.dtype
-            )
-        new_cache = (kp, vp)
-    elif segment_ids is not None and mod.segment_attn_fn is not None:
-        # packed-row training attention through the flash seam: the
-        # kernel enforces the segment-blocked causal rule and skips
-        # fully-masked (cross-segment / pad) blocks entirely
-        out = mod.segment_attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H), segment_ids)
+    if call.paged:
+        kp, vp = _write_pages(call, (cache.k[0], cache.v[0]), (k, v))
+        cache = ModelCache(k=(kp,), v=(vp,))
+    if call.mode == "tail":
+        # the heads are split out of the gathered rows: reshaping the
+        # pool itself would bring its relayout copy back
+        kg = _repeat_kv(gather_pages(kp, call.page_table, KV), H)
+        vg = _repeat_kv(gather_pages(vp, call.page_table, KV), H)
+        out = _masked_attention(q, kg, vg, _tail_mask(call, T, kg.shape[1]), mod.dtype)
+    elif call.mode == "decode":
+        paged_attn = mod.paged_attn_fn or paged_attention_reference
+        out = paged_attn(q, kp, vp, call.page_table, call.attn_lengths)
         out = out.astype(mod.dtype)
-    elif attn_mask is not None:
-        out = _masked_attention(
-            q, _repeat_kv(k, H), _repeat_kv(v, H), attn_mask, mod.dtype
-        )
-    else:
+    elif call.mode == "packed":
+        out = mod.segment_attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H), call.segment_ids)
+        out = out.astype(mod.dtype)
+    elif call.mode == "causal":
         out = mod.attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H))
+    else:  # masked, prefill: the call's own keys under its mask
+        out = _masked_attention(
+            q, _repeat_kv(k, H), _repeat_kv(v, H), call.attn_mask, mod.dtype
+        )
     if gate is not None:
         out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
     out = nn.Dense(mod.d_model, use_bias=False, name="proj", **dt)(
         out.reshape(B, T, width)
     )
-    return out, new_cache
+    return out, cache
 
 
-class _Block(nn.Module):
+class _Layer(nn.Module):
+    """What every layer class is built from, and the one contract: ``(x [B,
+    T, d], call, cache) -> (x, cache)``, ``cache`` the layer's own arrays
+    (:func:`_layer_entries`) where ``call.paged`` and None elsewhere, in and
+    out.  A mixer under a layer takes the normed input the same way."""
+
     d_model: int
     num_heads: int
     mlp_ratio: int
@@ -866,84 +1003,25 @@ class _Block(nn.Module):
     # a learned position table
     rotary: Optional[Callable] = None
 
+
+class _Block(_Layer):
+    """A plain layer (``layer="plain"``): ``x + Mixer(N(x))``, then ``x +
+    FFN(N(x))``; the mixer this spec's attention (:func:`_mha`, or
+    :class:`_LatentAttention` under ``attention="mla"``) or, under
+    ``mixer="gdn"``, a Gated DeltaNet (:class:`_GatedDeltaMixer`)."""
+
     @nn.compact
-    def __call__(
-        self,
-        x: jnp.ndarray,
-        attn_mask: Optional[jnp.ndarray] = None,
-        paged_cache: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
-        page_ids: Optional[jnp.ndarray] = None,
-        page_offsets: Optional[jnp.ndarray] = None,
-        page_table: Optional[jnp.ndarray] = None,
-        attn_lengths: Optional[jnp.ndarray] = None,
-        prefix_starts: Optional[jnp.ndarray] = None,
-        segment_ids: Optional[jnp.ndarray] = None,
-        runs: Optional[jnp.ndarray] = None,
-        real: Optional[jnp.ndarray] = None,
-        state_lanes: Optional[jnp.ndarray] = None,
-    ):
-        """Full forward (no cache) or paged incremental step.
-
-        With a mask but no cache it runs explicit masked attention against
-        its own k/v (the learner-side forward over left-padded sequences).
-
-        With ``paged_cache=(k_pages, v_pages)`` the block scatters this
-        call's keys/values into pool pages — lane ``b``'s token ``t`` lands
-        in ``(page_ids[b, t], page_offsets[b, t])``; dead-lane/pad writes
-        are routed to the null page by the caller — then attends either
-        *locally* against its own k/v under ``attn_mask`` (paged prefill: a
-        fresh prompt's whole context is in-program, no pool read needed) or
-        *through the pool* via ``paged_attn_fn(q, k_pages, v_pages,
-        page_table, attn_lengths)`` (paged single-token decode); returns
-        ``(out, (k_pages, v_pages))``.  Same params on every path.
-
-        With ``page_table`` AND ``prefix_starts`` ``[B]`` this is the
-        *shared-table tail prefill* (the prefix-cache path, ISSUE 14):
-        the ``T`` tokens sit at global positions ``prefix_starts[b] + t``
-        on top of a cached prefix whose K/V already lives in pool pages
-        mapped by the table; this call's K/V is scattered first, then
-        attention gathers the WHOLE context (cached prefix + this chunk)
-        through the table under a causal-from-start mask — a plain XLA
-        gather + :func:`_masked_attention`, no kernel involvement, so
-        sharing stays purely a page-table fact.
-
-        Under ``spec.mixer == "gdn"`` the mixer is a Gated DeltaNet
-        (:class:`_GatedDeltaMixer`) and ``paged_cache`` its ``(ssm, conv)``
-        state; ``runs`` / ``real`` / ``state_lanes`` are that mixer's.
-        """
-        B, T, _ = x.shape
+    def __call__(self, x, call: Call, cache: Optional[ModelCache] = None):
         spec = self.spec
         rms = spec.norm == "rmsnorm"
         dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         h = _norm(spec, self.dtype, "attn_norm" if rms else None)(x)
-        new_cache = None
         if spec.mixer == "gdn":
-            _no_tail_prefill(prefix_starts)
-            out, new_cache = _GatedDeltaMixer(self.d_model, spec, name="mixer", **dt)(
-                h, runs=runs, real=real, state=paged_cache,
-                state_lanes=state_lanes,
-                decode=paged_cache is not None and page_table is not None,
-            )
+            out, cache = _GatedDeltaMixer(self.d_model, spec, name="mixer", **dt)(h, call, cache)
         elif spec.attention == "mla":
-            # one latent attention: ``paged_cache`` is its one pool
-            out, new_cache = _LatentAttention(
-                self.d_model, self.num_heads, spec, self.attn_fn,
-                paged_attn_fn=self.paged_attn_fn,
-                segment_attn_fn=self.segment_attn_fn, rotary=self.rotary,
-                name="attn", **dt,
-            )(
-                h, attn_mask=attn_mask, pool=paged_cache, page_ids=page_ids,
-                page_offsets=page_offsets, page_table=page_table,
-                attn_lengths=attn_lengths, prefix_starts=prefix_starts,
-                segment_ids=segment_ids,
-            )
+            out, cache = _latent_attention(self, "attn")(h, call, cache)
         else:
-            out, new_cache = _mha(
-                self, h, attn_mask=attn_mask, paged_cache=paged_cache,
-                page_ids=page_ids, page_offsets=page_offsets,
-                page_table=page_table, attn_lengths=attn_lengths,
-                prefix_starts=prefix_starts, segment_ids=segment_ids,
-            )
+            out, cache = _mha(self, h, call, cache)
         x = x + out
         h = _norm(spec, self.dtype, "ffn_norm" if rms else None)(x)
         if spec.ffn == "experts":
@@ -969,10 +1047,7 @@ class _Block(nn.Module):
             h = nn.Dense(self.mlp_ratio * self.d_model, name="mlp_in", **dt)(h)
             h = nn.gelu(h)
             h = nn.Dense(self.d_model, name="mlp_out", **dt)(h)
-        x = x + h
-        if new_cache is not None:
-            return x, new_cache
-        return x
+        return x + h, cache
 
 
 class _LatentAttention(nn.Module):
@@ -988,22 +1063,22 @@ class _LatentAttention(nn.Module):
 
     One set of parameters, two forms of the same product:
 
-    - **un-absorbed** wherever the keys are this call's own (the full
-      and masked forwards, packed rows, the local prefill): ``k_nope`` and
-      ``v`` are made from ``c`` and the call sites of :class:`_Block`
-      attend (``v`` is padded to the q/k head size for the kernels that
-      take one head size, and the pad sliced off).
-    - **absorbed** wherever the keys come through a page table (decode,
-      the tail prefill over a cached prefix, the speculative verify): the
+    - **un-absorbed** wherever the keys are this call's own (``causal``,
+      ``masked``, ``packed``, ``prefill``): ``k_nope`` and ``v`` are made
+      from ``c`` and :func:`_mha`'s call sites attend (``v`` is padded to
+      the q/k head size for the kernels that take one head size, and the
+      pad sliced off).
+    - **absorbed** wherever the keys come through a page table (``decode``
+      and ``tail``, the speculative verify with it): the
       cache holds ``[c | rotated k_pe]`` a token and never a head's K or
       V, so ``W_kvb`` moves to the query side, ``q_abs = [W_uk^T q_nope |
       q_pe]``, scores are ``q_abs . row``, and a head's output is
       ``W_uv (sum p row[:kv_lora_rank])``.  Decode goes through
       ``paged_attn_fn`` (``ops.pallas_paged_attention.paged_decode_latent``
-      or its XLA twin), the other two gather rows and run
+      or its XLA twin), the tail gathers rows and runs
       :func:`latent_attention`.
 
-    Returns ``(out [B, T, d], pool)``; ``pool`` is None without a cache.
+    Returns ``(out [B, T, d], its one pool written, or None)``.
     """
 
     d_model: int
@@ -1017,11 +1092,7 @@ class _LatentAttention(nn.Module):
     rotary: Optional[Callable] = None
 
     @nn.compact
-    def __call__(
-        self, h, attn_mask=None, pool=None, page_ids=None, page_offsets=None,
-        page_table=None, attn_lengths=None, prefix_starts=None,
-        segment_ids=None,
-    ):
+    def __call__(self, h, call: Call, cache: Optional[ModelCache]):
         B, T, _ = h.shape
         s, H = self.spec, self.num_heads
         nope, rope, vd = s.qk_nope_head_dim, s.qk_rope_head_dim, s.v_head_dim
@@ -1066,14 +1137,15 @@ class _LatentAttention(nn.Module):
             "kv_b", up_init, (r_kv, H * (nope + vd)), self.param_dtype
         ).astype(self.dtype)
         scale = 1.0 / (nope + rope) ** 0.5
-        if pool is not None:
+        if call.paged:
             # the cached row, normed, scaled and rotated, zero to the
             # pool's whole tiles
+            pool = cache.rows[0]
             row = jnp.concatenate([c, k_pe[:, :, 0]], axis=-1)
             row = jnp.pad(row, ((0, 0), (0, 0), (0, pool.shape[2] - row.shape[-1])))
-            flat_idx = (page_ids * pool.shape[1] + page_offsets).reshape(B * T)
-            pool = _scatter_rows(pool, flat_idx, row)
-        if page_table is not None:
+            (pool,) = _write_pages(call, (pool,), (row,))
+            cache = ModelCache(rows=(pool,))
+        if call.mode in ("tail", "decode"):
             w = w_kvb.reshape(r_kv, H, nope + vd)
             # as wide as the pool's row, zeros against its pad columns: the
             # kernel then takes the query as it is
@@ -1088,16 +1160,14 @@ class _LatentAttention(nn.Module):
                 ],
                 axis=-1,
             )
-            if prefix_starts is not None:
-                rows = gather_pages(pool, page_table, 1)[:, :, 0]
-                pos = jnp.arange(rows.shape[1])[None, None, :]
-                qpos = (
-                    prefix_starts[:, None] + jnp.arange(T)[None, :]
-                )[:, :, None]
-                lat = latent_attention(q_abs, rows, pos <= qpos, r_kv, scale)
+            if call.mode == "tail":
+                rows = gather_pages(pool, call.page_table, 1)[:, :, 0]
+                lat = latent_attention(
+                    q_abs, rows, _tail_mask(call, T, rows.shape[1]), r_kv, scale
+                )
             else:
                 paged = self.paged_attn_fn or paged_latent_attention_reference
-                lat = paged(q_abs, pool, page_table, attn_lengths, r_kv, scale)
+                lat = paged(q_abs, pool, call.page_table, call.attn_lengths, r_kv, scale)
             out = jnp.einsum(
                 "bthc,chv->bthv", lat.astype(self.dtype), w[..., nope:],
                 preferred_element_type=f32,
@@ -1110,19 +1180,27 @@ class _LatentAttention(nn.Module):
             )
             qf = jnp.concatenate([q_nope, q_pe], axis=-1)
             v = kvb[..., nope:]
-            packed = segment_ids is not None and self.segment_attn_fn is not None
-            if pool is not None or (attn_mask is not None and not packed):
-                out = _masked_attention(qf, k, v, attn_mask, self.dtype)
-            elif packed:
+            if call.mode == "packed":
                 # the segment kernels take a v narrower than q and k
-                out = self.segment_attn_fn(qf, k, v, segment_ids).astype(self.dtype)
-            else:
+                out = self.segment_attn_fn(qf, k, v, call.segment_ids).astype(self.dtype)
+            elif call.mode == "causal":
                 # a causal ``attn_fn`` takes one head size: v padded to q
                 # and k's, the pad sliced off
                 v = jnp.pad(v, ((0, 0),) * 3 + ((0, nope + rope - vd),))
                 out = self.attn_fn(qf, k, v)[..., :vd].astype(self.dtype)
+            else:  # masked, prefill
+                out = _masked_attention(qf, k, v, call.attn_mask, self.dtype)
         out = dense(self.d_model, "proj")(out.reshape(B, T, H * vd))
-        return out, pool
+        return out, cache
+
+
+def _latent_attention(mod: _Layer, name: str) -> _LatentAttention:
+    """A layer's latent attention, under ``name`` among its children."""
+    return _LatentAttention(
+        mod.d_model, mod.num_heads, mod.spec, mod.attn_fn, dtype=mod.dtype,
+        param_dtype=mod.param_dtype, paged_attn_fn=mod.paged_attn_fn,
+        segment_attn_fn=mod.segment_attn_fn, rotary=mod.rotary, name=name,
+    )
 
 
 class _GatedMLP(nn.Module):
@@ -1160,15 +1238,6 @@ def _dense_ffn(spec: BlockSpec, d_model: int, hidden: int, name: str, dt):
     """A dense FFN of the spec's expert form."""
     kind = _SquaredReluMLP if spec.expert_act == "relu2" else _GatedMLP
     return kind(d_model, hidden, name=name, **dt)
-
-
-def _no_tail_prefill(prefix_starts) -> None:
-    if prefix_starts is not None:
-        raise NotImplementedError(
-            "a recurrent layer has no tail prefill over a cached "
-            "prefix and no speculative verify: its state cannot be "
-            "entered at a page boundary or rewound by a page cursor"
-        )
 
 
 def run_ids(segment_ids: jnp.ndarray) -> jnp.ndarray:
@@ -1323,6 +1392,53 @@ def _last_taps(u, real, taps: int):
     return jnp.where((at >= 0)[..., None], tail, 0.0)
 
 
+def _causal_taps(u, conv_w, runs, bias=None):
+    """The causal depthwise convolution ``bias + sum_j w_j u_{t-K+1+j}`` of
+    ``u [Bt, T, C]`` by taps ``conv_w [K, C]``; an input of another run
+    (``runs [Bt, T]``) is not read."""
+    K, T = conv_w.shape[0], u.shape[1]
+    out = u * conv_w[K - 1]
+    if bias is not None:
+        out = bias + out
+    for back in range(1, K):
+        # the input ``back`` tokens earlier, if it is of this run
+        earlier = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :T]
+        near = jnp.pad(runs, ((0, 0), (back, 0)), constant_values=-1)[:, :T] == runs
+        out = out + jnp.where(near[..., None], earlier, 0.0) * conv_w[K - 1 - back]
+    return out
+
+
+def _runs_and_real(call: Call, rows: int, T: int):
+    """A whole-sequence call's ``(runs, real)`` for a recurrent mixer: a
+    ``causal`` call's rows are one run of real tokens each."""
+    if call.mode == "causal":
+        return jnp.ones((rows, T), jnp.int32), jnp.ones((rows, T), bool)
+    return call.runs, call.real
+
+
+def _decode_window(cache: ModelCache, u, conv_w):
+    """One token a lane: the carried taps and this token's input ``u
+    [lanes, 1, C]`` as the convolution's window ``[lanes, K, C]``, and its
+    sum over the taps (no bias)."""
+    window = jnp.concatenate([cache.conv[0], u], axis=1)
+    return window, jnp.sum(window * conv_w, axis=1)
+
+
+def _prefill_state(call: Call, cache: Optional[ModelCache], last, u, real):
+    """What a whole-sequence call leaves in a recurrent layer's cache: a
+    ``prefill`` writes the state after each row's last real token
+    (``last``) and its last ``K - 1`` real convolution inputs to rows
+    ``call.state_lanes``; the other forms have no cache."""
+    if call.mode != "prefill":
+        return cache
+    ssm, taps = cache.ssm[0], cache.conv[0]
+    tail = _last_taps(u, real, taps.shape[1])
+    return ModelCache(
+        ssm=(ssm.at[call.state_lanes].set(last, mode="drop"),),
+        conv=(taps.at[call.state_lanes].set(tail, mode="drop"),),
+    )
+
+
 class _Mamba2Mixer(nn.Module):
     """The Mamba-2 mixer on a normed input ``h [B, T, d]`` (sizes
     ``spec.ssm_*``: ``H`` heads of ``P``, ``G`` groups, state ``N``, a
@@ -1334,22 +1450,19 @@ class _Mamba2Mixer(nn.Module):
         S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T;   y_t = S_t C_t + D x_t
         out = RMSNorm_groups(y * silu(z)) W_out       (G groups of H P / G)
 
-    ONE set of parameters, three paths:
+    ONE set of parameters, two paths:
 
-    - **whole sequences** (every full, masked or packed forward, and the
-      prefill): :func:`ssd_chunked` over ``runs [B, T]``
-      (:func:`run_ids`): the state and the convolution's taps are cut at
-      every run's start, and a pad token (``real`` False) has no input and
-      no decay, so what leaves a right-padded prompt is the state at its
-      true length.  With ``state`` given (the prefill) the final state and
-      the last ``K - 1`` real convolution inputs of each row are written
-      to rows ``state_lanes`` of it.
-    - **one token a lane** (decode: ``decode=True``, ``T = 1``, row ``b``
-      is lane ``b``): the convolution over the carried taps and
-      :func:`ssm_decode_update` on the carried state, in place.
+    - **whole sequences** (every form but ``decode``):
+      :func:`ssd_chunked` over ``call.runs``: the state and the
+      convolution's taps are cut at every run's start, and a pad token
+      (``call.real`` False) has no input and no decay, so what leaves a
+      right-padded prompt is the state at its true length, which a
+      ``prefill`` writes (:func:`_prefill_state`).
+    - **one token a lane** (``decode``): the convolution over the carried
+      taps and :func:`ssm_decode_update` on the carried state, in place.
 
-    ``state`` is ``(ssm [lanes, H, P, N], conv [lanes, K - 1, H P + 2 G
-    N])``, both float32.  Returns ``(out [B, T, d], state or None)``."""
+    ``cache`` holds ``ssm [lanes, H, P, N]`` and ``conv [lanes, K - 1, H P
+    + 2 G N]``, both float32.  Returns ``(out [B, T, d], cache)``."""
 
     d_model: int
     spec: BlockSpec
@@ -1357,7 +1470,7 @@ class _Mamba2Mixer(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, h, runs=None, real=None, state=None, state_lanes=None, decode=False):
+    def __call__(self, h, call: Call, cache: Optional[ModelCache]):
         s = self.spec
         f32 = jnp.float32
         Bt, T, _ = h.shape
@@ -1388,56 +1501,27 @@ class _Mamba2Mixer(nn.Module):
                 xbc[..., inner + G * N :].reshape(*lead, G, N),
             )
 
-        new_state = None
-        if decode:
-            ssm, taps = state
-            window = jnp.concatenate([taps, u], axis=1)  # [lanes, K, channels]
-            x, B, C = split(conv_b + jnp.sum(window * conv_w, axis=1))
-            y, ssm = ssm_decode_update(ssm, x, step[:, 0], A, B, C, D)
+        if call.mode == "decode":
+            window, mixed = _decode_window(cache, u, conv_w)
+            x, B, C = split(conv_b + mixed)
+            y, ssm = ssm_decode_update(cache.ssm[0], x, step[:, 0], A, B, C, D)
             y = y[:, None]  # [lanes, 1, H, P]
-            new_state = (ssm, window[:, 1:])
+            cache = ModelCache(ssm=(ssm,), conv=(window[:, 1:],))
         else:
-            if runs is None:
-                runs = jnp.ones((Bt, T), jnp.int32)
-                real = jnp.ones((Bt, T), bool)
+            runs, real = _runs_and_real(call, Bt, T)
             u = jnp.where(real[..., None], u, 0.0)
             step = jnp.where(real[..., None], step, 0.0)
-            xbc = conv_b + u * conv_w[K - 1]
-            for back in range(1, K):
-                # the input ``back`` tokens earlier, if it is of this run
-                earlier = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :T]
-                near = jnp.pad(runs, ((0, 0), (back, 0)), constant_values=-1)[:, :T] == runs
-                xbc = xbc + jnp.where(near[..., None], earlier, 0.0) * conv_w[K - 1 - back]
-            x, B, C = split(xbc)
+            x, B, C = split(_causal_taps(u, conv_w, runs, conv_b))
             x = jnp.where(real[..., None, None], x, 0)
             y, last = ssd_chunked(x, step, A, B, C, runs, s.ssm_chunk)
             y = y + D[:, None] * x.astype(f32)
-            if state is not None:
-                ssm, taps = state
-                tail = _last_taps(u, real, K - 1)
-                new_state = (
-                    ssm.at[state_lanes].set(last, mode="drop"),
-                    taps.at[state_lanes].set(tail, mode="drop"),
-                )
+            cache = _prefill_state(call, cache, last, u, real)
         # the gated norm: within each group's channels, one scale of ``inner``
         gated = (y.reshape(Bt, T, inner) * jax.nn.silu(z.astype(f32))).reshape(Bt, T, G, inner // G)
         ms = jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
         normed = (gated * lax.rsqrt(ms + s.norm_eps)).reshape(Bt, T, inner) * norm_scale
         out = nn.Dense(self.d_model, name="out_proj", **dt_kw)(normed.astype(self.dtype))
-        return out, new_state
-
-
-def _causal_taps(u, conv_w, runs):
-    """The causal depthwise convolution ``sum_j w_j u_{t-K+1+j}`` of ``u
-    [Bt, T, C]`` by taps ``conv_w [K, C]``, no bias; an input of another
-    run (``runs [Bt, T]``) is not read."""
-    K, T = conv_w.shape[0], u.shape[1]
-    out = u * conv_w[K - 1]
-    for back in range(1, K):
-        earlier = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :T]
-        near = jnp.pad(runs, ((0, 0), (back, 0)), constant_values=-1)[:, :T] == runs
-        out = out + jnp.where(near[..., None], earlier, 0.0) * conv_w[K - 1 - back]
-    return out
+        return out, cache
 
 
 def gated_delta_chunked(q, k, v, g, beta, runs, chunk: int):
@@ -1584,15 +1668,13 @@ class _GatedDeltaMixer(nn.Module):
         S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;  o_t = S^T q_t
         out = (RMSNorm_P(o_t) * w_norm * silu(z_t)) W_out     (the norm a head, THEN the gate)
 
-    ONE set of parameters and :class:`_Mamba2Mixer`'s call and paths:
-    whole sequences (full, masked, packed, the prefill) through
-    :func:`gated_delta_chunked` over ``runs``, with the state and the last
-    ``K - 1`` real convolution inputs written to rows ``state_lanes`` of
-    ``state`` at the TRUE length (a pad token has ``beta = 0``, ``g = 0``
-    and no convolution input); one token a lane (``decode=True``) through
-    :func:`gdn_decode_update` on the carried state.  ``state`` is ``(ssm
-    [lanes, H, N, P], conv [lanes, K - 1, 2 G N + H P])``, both float32.
-    Returns ``(out [B, T, d], state or None)``."""
+    ONE set of parameters and :class:`_Mamba2Mixer`'s two paths: whole
+    sequences through :func:`gated_delta_chunked` over ``call.runs``, the
+    state leaving a prompt at its TRUE length (a pad token has ``beta =
+    0``, ``g = 0`` and no convolution input); one token a lane
+    (``decode``) through :func:`gdn_decode_update` on the carried state.
+    ``cache`` holds ``ssm [lanes, H, N, P]`` and ``conv [lanes, K - 1, 2 G
+    N + H P]``, both float32.  Returns ``(out [B, T, d], cache)``."""
 
     d_model: int
     spec: BlockSpec
@@ -1600,7 +1682,7 @@ class _GatedDeltaMixer(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, h, runs=None, real=None, state=None, state_lanes=None, decode=False):
+    def __call__(self, h, call: Call, cache: Optional[ModelCache]):
         s = self.spec
         f32 = jnp.float32
         Bt, T, _ = h.shape
@@ -1616,7 +1698,7 @@ class _GatedDeltaMixer(nn.Module):
         )
         norm_scale = self.param("norm_scale", nn.initializers.ones, (P,), f32)
         if not self.is_initializing():
-            _note_gdn_form(tuple(h.shape), s.ssm_chunk, (H, N, P), G, bool(decode))
+            _note_gdn_form(tuple(h.shape), s.ssm_chunk, (H, N, P), G, call.mode == "decode")
 
         qkvz = nn.Dense(channels + values, name="in_proj", **dt_kw)(h)
         ba = nn.Dense(2 * H, name="ba_proj", **dt_kw)(h).astype(f32)
@@ -1635,30 +1717,20 @@ class _GatedDeltaMixer(nn.Module):
                 qkv[..., 2 * keys :].reshape(*lead, H, P),
             )
 
-        new_state = None
-        if decode:
-            ssm, taps = state
-            window = jnp.concatenate([taps, u], axis=1)  # [lanes, K, channels]
-            q, k, v = split(jnp.sum(window * conv_w, axis=1))
-            o, ssm = gdn_decode_update(ssm, q, k, v, g[:, 0], beta[:, 0])
+        if call.mode == "decode":
+            window, mixed = _decode_window(cache, u, conv_w)
+            q, k, v = split(mixed)
+            o, ssm = gdn_decode_update(cache.ssm[0], q, k, v, g[:, 0], beta[:, 0])
             o = o[:, None]  # [lanes, 1, H, P]
-            new_state = (ssm, window[:, 1:])
+            cache = ModelCache(ssm=(ssm,), conv=(window[:, 1:],))
         else:
-            if runs is None:
-                runs = jnp.ones((Bt, T), jnp.int32)
-                real = jnp.ones((Bt, T), bool)
+            runs, real = _runs_and_real(call, Bt, T)
             u = jnp.where(real[..., None], u, 0.0)
             g = jnp.where(real[..., None], g, 0.0)
             beta = jnp.where(real[..., None], beta, 0.0)
             q, k, v = split(_causal_taps(u, conv_w, runs))
             o, last = gated_delta_chunked(q, k, v, g, beta, runs, s.ssm_chunk)
-            if state is not None:
-                ssm, taps = state
-                tail = _last_taps(u, real, K - 1)
-                new_state = (
-                    ssm.at[state_lanes].set(last, mode="drop"),
-                    taps.at[state_lanes].set(tail, mode="drop"),
-                )
+            cache = _prefill_state(call, cache, last, u, real)
         # the norm a head, then the gate
         ms = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
         gated = (
@@ -1668,48 +1740,24 @@ class _GatedDeltaMixer(nn.Module):
         out = nn.Dense(self.d_model, name="out_proj", **dt_kw)(
             gated.reshape(Bt, T, values).astype(self.dtype)
         )
-        return out, new_state
+        return out, cache
 
 
-class _MixerBlock(nn.Module):
+class _MixerBlock(_Layer):
     """A layer of one mixer (``layer="mixer"``): ``x + Mixer(N(x))``, the
     mixer ``spec.mixer``'s: a Mamba-2 mixer, this spec's attention alone
     (:func:`_mha`), the routed experts beside their shared expert, or a
-    dense FFN.  The call arguments are :class:`_Block`'s and ``runs`` /
-    ``real`` / ``state_lanes`` for a Mamba mixer; ``paged_cache`` is what
-    the mixer caches into: ``(k_pages, v_pages)``, a Mamba layer's ``(ssm,
-    conv)`` state, or None.  Returns ``x``, or ``(x, cache)`` when the
-    model runs on a cache."""
-
-    d_model: int
-    num_heads: int
-    mlp_ratio: int
-    attn_fn: AttentionFn
-    dtype: jnp.dtype = jnp.float32
-    param_dtype: jnp.dtype = jnp.float32
-    paged_attn_fn: Optional[Callable] = None
-    segment_attn_fn: Optional[Callable] = None
-    spec: BlockSpec = BlockSpec()
-    rotary: Optional[Callable] = None
+    dense FFN (the last two cache nothing: their entry is empty)."""
 
     @nn.compact
-    def __call__(
-        self, x, paged_cache=None, runs=None, real=None, state_lanes=None,
-        on_cache=False, **call,
-    ):
+    def __call__(self, x, call: Call, cache: Optional[ModelCache] = None):
         spec = self.spec
         dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         h = _norm(spec, self.dtype, "norm")(x)
-        cache = None
         if spec.mixer == "mamba":
-            _no_tail_prefill(call.get("prefix_starts"))
-            out, cache = _Mamba2Mixer(self.d_model, spec, name="mixer", **dt)(
-                h, runs=runs, real=real, state=paged_cache,
-                state_lanes=state_lanes,
-                decode=paged_cache is not None and call.get("page_table") is not None,
-            )
+            out, cache = _Mamba2Mixer(self.d_model, spec, name="mixer", **dt)(h, call, cache)
         elif spec.mixer == "attention":
-            out, cache = _mha(self, h, paged_cache=paged_cache, **call)
+            out, cache = _mha(self, h, call, cache)
         elif spec.mixer == "experts":
             out = _routed_experts(spec, dt)(h)
             if spec.shared_experts:
@@ -1721,11 +1769,10 @@ class _MixerBlock(nn.Module):
                 )(h)
         else:
             out = _dense_ffn(spec, self.d_model, spec.ffn_hidden, "ffn", dt)(h)
-        x = x + out
-        return (x, cache) if on_cache else x
+        return x + out, cache
 
 
-class _ShortcutBlock(nn.Module):
+class _ShortcutBlock(_Layer):
     """The shortcut-connected double layer (``layer="scmoe"``), ``N`` an
     RMSNorm of its own at each use::
 
@@ -1736,50 +1783,39 @@ class _ShortcutBlock(nn.Module):
 
     The routed experts read what the first dense FFN reads and their sum
     joins after the second, so the expert branch can run beside the
-    layer's second half.  Two attentions: a layer owns two cache pools.
-    The call arguments are :class:`_Block`'s, ``paged_cache`` the pair of
-    latent pools."""
-
-    d_model: int
-    num_heads: int
-    attn_fn: AttentionFn
-    dtype: jnp.dtype = jnp.float32
-    param_dtype: jnp.dtype = jnp.float32
-    paged_attn_fn: Optional[Callable] = None
-    segment_attn_fn: Optional[Callable] = None
-    spec: BlockSpec = BlockSpec()
-    rotary: Optional[Callable] = None
+    layer's second half.  Two attentions: the layer owns two latent
+    pools, one each.  (``mlp_ratio`` is not read: both FFNs are
+    ``spec.ffn_hidden`` wide.)"""
 
     @nn.compact
-    def __call__(self, x, paged_cache=None, **call):
+    def __call__(self, x, call: Call, cache: Optional[ModelCache] = None):
         spec = self.spec
         dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
-        pools = paged_cache if paged_cache is not None else (None, None)
 
         def attention(i, x):
-            out, pool = _LatentAttention(
-                self.d_model, self.num_heads, spec, self.attn_fn,
-                paged_attn_fn=self.paged_attn_fn,
-                segment_attn_fn=self.segment_attn_fn, rotary=self.rotary,
-                name=f"attn_{i}", **dt,
-            )(_norm(spec, self.dtype, f"attn_norm_{i}")(x), pool=pools[i], **call)
-            return x + out, pool
+            own = ModelCache(rows=cache.rows[i : i + 1]) if call.paged else None
+            out, own = _latent_attention(self, f"attn_{i}")(
+                _norm(spec, self.dtype, f"attn_norm_{i}")(x), call, own
+            )
+            return x + out, own
 
         def ffn(i, h):
             return _GatedMLP(self.d_model, spec.ffn_hidden, name=f"ffn_{i}", **dt)(h)
 
-        x, pool_0 = attention(0, x)
+        x, first = attention(0, x)
         h = _norm(spec, self.dtype, "ffn_norm_0")(x)
         m = _routed_experts(spec, dt)(h)
         x = x + ffn(0, h)
-        x, pool_1 = attention(1, x)
+        x, second = attention(1, x)
         x = x + ffn(1, _norm(spec, self.dtype, "ffn_norm_1")(x)) + m
-        if paged_cache is not None:
-            return x, (pool_0, pool_1)
-        return x
+        return x, _join((first, second)) if call.paged else None
 
 
-class _MTPModule(nn.Module):
+# the class of each ``BlockSpec.layer``
+_LAYERS = {"plain": _Block, "mixer": _MixerBlock, "scmoe": _ShortcutBlock}
+
+
+class _MTPModule(_Layer):
     """One multi-token-prediction module (the DeepSeek-V3 report's section
     2.2): ``h'_i = W_eh [RMSNorm_h(h_i) ; RMSNorm_e(Emb(t_{i+1}))]``, then
     one layer of the stack's repeating kind with weights of its own, at
@@ -1789,20 +1825,11 @@ class _MTPModule(nn.Module):
     ``has_next [B, T]`` whether that token is in the position's own
     sequence: where it is not, the embedding's half is zero, so nothing of
     a neighbouring segment enters.  The caller norms the result and scores
-    it with the shared head; it predicts ``t_{i+2}``."""
-
-    d_model: int
-    num_heads: int
-    mlp_ratio: int
-    attn_fn: AttentionFn
-    spec: BlockSpec
-    dtype: jnp.dtype = jnp.float32
-    param_dtype: jnp.dtype = jnp.float32
-    segment_attn_fn: Optional[Callable] = None
-    rotary: Optional[Callable] = None
+    it with the shared head; it predicts ``t_{i+2}``.  It runs in the
+    forms without a cache alone."""
 
     @nn.compact
-    def __call__(self, x, next_emb, has_next, **call):
+    def __call__(self, x, next_emb, has_next, call: Call):
         eps = self.spec.norm_eps
         h = RMSNorm(eps, dtype=self.dtype, name="h_norm")(x)
         e = RMSNorm(eps, dtype=self.dtype, name="e_norm")(next_emb)
@@ -1811,34 +1838,13 @@ class _MTPModule(nn.Module):
             self.d_model, use_bias=False, name="eh_proj", dtype=self.dtype,
             param_dtype=self.param_dtype,
         )(jnp.concatenate([h, e], axis=-1))
-        return _Block(
+        y, _none = _Block(
             self.d_model, self.num_heads, self.mlp_ratio, self.attn_fn,
             dtype=self.dtype, param_dtype=self.param_dtype,
             segment_attn_fn=self.segment_attn_fn, spec=self.spec,
             rotary=self.rotary, name="block",
-        )(y, **call)
-
-
-def _attends(spec: BlockSpec) -> bool:
-    """Whether the layer writes K and V pools: a mixer layer of
-    attention, or a plain layer whose mixer is not recurrent."""
-    if spec.layer == "mixer":
-        return spec.mixer == "attention"
-    return not spec.recurrent
-
-
-def _layer_kind(spec: BlockSpec) -> str:
-    """A layer as ``model.layers`` notes it: its layer kind and what fills
-    it (a plain layer that names its mixer: the mixer, then the FFN)."""
-    if spec.layer == "mixer":
-        return f"{spec.layer}/{spec.mixer}"
-    if spec.mixer:
-        return f"{spec.layer}/{spec.mixer}/{spec.ffn}"
-    return f"{spec.layer}/{spec.ffn}"
-
-
-def _latent_pools(spec: BlockSpec) -> int:
-    return 2 if spec.layer == "scmoe" else 1
+        )(y, call)
+        return y
 
 
 @functools.lru_cache(maxsize=None)
@@ -1951,59 +1957,36 @@ class TransformerPolicy(nn.Module):
         describes (a Mamba-2 or a Gated DeltaNet mixer)."""
         return any(s.recurrent for s in self.layer_specs)
 
-    @property
-    def _hybrid(self) -> bool:
-        """Whether the model caches into a :class:`HybridCache`."""
-        return self.block.layer == "mixer" or self.recurrent
-
     def init_paged_cache(
         self, num_pages: int, page_size: int, dtype=jnp.float32, lanes: int = 0
-    ):
-        """The zeroed cache this model's layers cache into: the cache is
-        described by the model, and everything that holds it (the engine
-        and its programs) treats it as one pytree.  Page pools are
-        ``[num_pages, page_size, width]`` (page 0 = the never-read null
-        page), one K and one V an attention layer or one latent pool an
-        ``mla`` attention; a stack with recurrent layers (or of
-        single-mixer layers) gets a :class:`HybridCache`: pools for its
-        attention layers alone, a ``lanes``-indexed recurrent state for
-        each recurrent layer whatever its kind, nothing for the others."""
-        spec = self.block
-        if self._hybrid:
-            specs = self.layer_specs
-            attn = sum(_attends(s) for s in specs)
-            stateful = [s for s in specs if s.recurrent]
-            if stateful and lanes < 1:
-                raise ValueError("a recurrent model's cache is sized by its lanes")
-            pools = init_paged_kv_cache(
-                num_pages, page_size, attn, spec.kv_heads or self.num_heads,
-                self.head_dim, dtype,
+    ) -> ModelCache:
+        """The zeroed cache this model's layers cache into
+        (:class:`ModelCache`): the cache is described by the model, layer
+        by layer (``BlockSpec.owns``), and everything that holds it (the
+        engine and its programs) treats it as one pytree.  Page pools are
+        ``[num_pages, page_size, width]`` of ``dtype`` (page 0 = the
+        never-read null page); a recurrent layer's state is float32 and
+        indexed by ``lanes``."""
+        if self.recurrent and lanes < 1:
+            raise ValueError("a recurrent model's cache is sized by its lanes")
+
+        def shape(s: BlockSpec, name: str):
+            if name == "ssm":
+                return (lanes,) + s.state_shape
+            if name == "conv":
+                return (lanes, s.ssm_conv - 1, s.conv_channels)
+            if name == "rows":
+                return (num_pages, page_size, latent_pool_width(s.kv_lora_rank + s.qk_rope_head_dim))
+            return (num_pages, page_size, (s.kv_heads or self.num_heads) * self.head_dim)
+
+        return ModelCache(*(
+            tuple(
+                jnp.zeros(shape(s, name), dtype if name in _PAGE_FIELDS else jnp.float32)
+                for s in self.layer_specs
+                for _ in range(s.owns.get(name, 0))
             )
-            return HybridCache(
-                k=pools.k, v=pools.v,
-                ssm=tuple(
-                    jnp.zeros((lanes,) + s.state_shape, jnp.float32) for s in stateful
-                ),
-                conv=tuple(
-                    jnp.zeros((lanes, s.ssm_conv - 1, s.conv_channels), jnp.float32)
-                    for s in stateful
-                ),
-            )
-        if spec.attention == "mla":
-            # one latent pool an attention: one a plain layer, two a
-            # shortcut-connected double layer
-            width = latent_pool_width(spec.kv_lora_rank + spec.qk_rope_head_dim)
-            pools = sum(_latent_pools(s) for s in self.layer_specs)
-            return LatentKVCache(
-                rows=tuple(
-                    jnp.zeros((num_pages, page_size, width), dtype)
-                    for _ in range(pools)
-                )
-            )
-        return init_paged_kv_cache(
-            num_pages, page_size, self.num_layers,
-            spec.kv_heads or self.num_heads, self.head_dim, dtype,
-        )
+            for name in ModelCache._fields
+        ))
 
     @nn.compact
     def __call__(
@@ -2011,7 +1994,7 @@ class TransformerPolicy(nn.Module):
         obs: jnp.ndarray,
         positions: Optional[jnp.ndarray] = None,
         attn_mask: Optional[jnp.ndarray] = None,
-        paged_cache: Optional[PagedKVCache] = None,
+        paged_cache: Optional[ModelCache] = None,
         page_ids: Optional[jnp.ndarray] = None,
         page_offsets: Optional[jnp.ndarray] = None,
         page_table: Optional[jnp.ndarray] = None,
@@ -2021,44 +2004,14 @@ class TransformerPolicy(nn.Module):
         mtp: bool = False,
         state_lanes: Optional[jnp.ndarray] = None,
     ):
-        """Full forward, masked full forward, or paged incremental step.
-
-        - ``attn_mask=None``: the original whole-trajectory forward (causal
-          ``attn_fn``) returning :class:`TransformerOutput`.
-        - ``attn_mask=[B, T, T]``: full forward under an explicit mask
-          (:func:`sequence_attention_mask`) — the learner pass over
-          left-padded generated sequences.
-        - ``paged_cache=PagedKVCache`` (the continuous-batching plane):
-          scatter this call's k/v into pool pages at ``(page_ids[b, t],
-          page_offsets[b, t])``.  With ``attn_mask=[B, T, T]`` and no
-          ``page_table`` this is paged *prefill* over RIGHT-padded compact
-          prompts (:func:`prompt_attention_mask` — attention is local, the
-          pool is write-only); with ``page_table=[B, M]`` +
-          ``attn_lengths=[B]`` and ``T = 1`` it is paged *decode*
-          (attention gathers through the table); with ``page_table`` +
-          ``prefix_starts=[B]`` it is the shared-table *tail prefill*
-          over a cached prefix (the prefix-cache path — see
-          :class:`_Block`).  Returns
-          ``(TransformerOutput, new_paged_cache)``.  Same params as every
-          other path.
-        - ``segment_ids=[B, S]`` (the pad-free packed learner, ISSUE 15):
-          full forward over PACKED rows holding several independent
-          sequences — tokens attend causally WITHIN their own nonzero
-          segment only.  Callers pass per-segment ``positions`` (reset to
-          0 at every segment start, ``genrl/rollout.py``).  With
-          ``segment_attn_fn`` set the blocks ride the Pallas segment
-          flash kernel; otherwise the dense
-          :func:`packed_attention_mask` feeds the existing masked path.
-          Same params as every other path.
-        - ``mtp=True`` (a model with ``mtp_layers``, no cache): also run
-          the multi-token-prediction module over the same rows and return
-          its logits as ``mtp_logits`` (:class:`_MTPModule`).
-        - ``state_lanes=[B]`` (a stack with recurrent layers, paged
-          prefill): the lanes whose rows of the cache's recurrent state
-          this call's prompts write, at their true lengths; an id out of
-          range drops.  Decode updates every lane's row in place (row
-          ``b`` is lane ``b``).
-        """
+        """One forward in the form the arguments spell (:class:`Call`:
+        ``causal``, ``masked``, ``packed``, or ``prefill`` / ``tail`` /
+        ``decode`` on a ``paged_cache``); an argument set that spells none
+        raises ``ValueError``.  Returns :class:`TransformerOutput`, and
+        ``(TransformerOutput, the cache written)`` on a cache.
+        ``mtp=True`` (a model with ``mtp_layers``, no cache): also run the
+        multi-token-prediction module over the same rows and return its
+        logits as ``mtp_logits`` (:class:`_MTPModule`)."""
         B, T = obs.shape[:2]
         spec = self.block
         specs = self.layer_specs
@@ -2071,7 +2024,7 @@ class TransformerPolicy(nn.Module):
         if not self.is_initializing():  # a program's trace, not the weights' making
             _note_layers(
                 tuple(obs.shape),
-                tuple(_layer_kind(s) for s in specs),
+                tuple(s.kind for s in specs),
                 spec.attention, spec.experts_held or spec.num_experts,
                 spec.num_experts, self.mtp_layers,
             )
@@ -2087,24 +2040,13 @@ class TransformerPolicy(nn.Module):
                 seg = segment_ids.astype(jnp.int32)
                 has_next = has_next & (seg > 0) & (jnp.roll(seg, -1, axis=1) == seg)
             has_next = jnp.broadcast_to(has_next, (B, T))
-        runs = real = None
-        if self.recurrent:
-            # which tokens are real and where a recurrence starts anew: the
-            # packed rows' segments, or the diagonal of a padded forward's
-            # mask (a token that may attend itself is real)
-            if segment_ids is not None:
-                real = segment_ids > 0
-            elif attn_mask is not None:
-                real = jnp.diagonal(attn_mask, axis1=1, axis2=2)
-            if real is not None:
-                runs = run_ids(
-                    segment_ids if segment_ids is not None else real.astype(jnp.int32)
-                )
-        if segment_ids is not None and self.segment_attn_fn is None:
-            # dense packed fallback: ONE [B, S, S] mask shared by every
-            # block — the XLA reference path and the off-TPU shape
-            attn_mask = packed_attention_mask(segment_ids)
-            segment_ids = None
+        call = Call.of(
+            recurrent=self.recurrent, segment_kernel=self.segment_attn_fn is not None,
+            mtp=mtp, paged_cache=paged_cache, attn_mask=attn_mask,
+            segment_ids=segment_ids, page_ids=page_ids, page_offsets=page_offsets,
+            page_table=page_table, attn_lengths=attn_lengths,
+            prefix_starts=prefix_starts, state_lanes=state_lanes,
+        )
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(T), (B, T))
         c = self.constrain if self.constrain is not None else (lambda x: x)
@@ -2138,91 +2080,16 @@ class TransformerPolicy(nn.Module):
             )
             x = x + pos_tab[positions].astype(self.dtype)
         x = c(x)
-        latent = spec.attention == "mla"
-        pools = []  # what each layer wrote: (k, v), one latent pool, or two
-        at = 0  # the layer's first pool among the latent cache's rows
-        hybrid = self._hybrid
-        n_attn = n_state = 0  # a hybrid stack's attention and recurrent layers so far
+        entries = _layer_entries(paged_cache, specs) if call.paged else [None] * len(specs)
         for i, layer in enumerate(specs):
-            common = dict(
-                dtype=self.dtype,
-                param_dtype=self.param_dtype,
+            block = _LAYERS[layer.layer](
+                self.d_model, self.num_heads, self.mlp_ratio, attn,
+                dtype=self.dtype, param_dtype=self.param_dtype,
                 paged_attn_fn=self.paged_attn_fn,
-                segment_attn_fn=self.segment_attn_fn,
-                spec=layer,
-                rotary=rotary,
+                segment_attn_fn=self.segment_attn_fn, spec=layer, rotary=rotary,
                 name=f"block_{i}",
             )
-            cache = None
-            if hybrid and paged_cache is not None:
-                # the cache of the layer's kind: its pools, its state, none
-                if _attends(layer):
-                    cache = (paged_cache.k[n_attn], paged_cache.v[n_attn])
-                elif layer.recurrent:
-                    cache = (paged_cache.ssm[n_state], paged_cache.conv[n_state])
-                n_attn += _attends(layer)
-                n_state += layer.recurrent
-            if layer.layer == "mixer":
-                block = _MixerBlock(
-                    self.d_model, self.num_heads, self.mlp_ratio, attn, **common
-                )
-                mixer_call = dict(runs=runs, real=real)
-                if paged_cache is not None:
-                    x, written = block(
-                        x, paged_cache=cache, on_cache=True,
-                        state_lanes=state_lanes, attn_mask=attn_mask,
-                        page_ids=page_ids, page_offsets=page_offsets,
-                        page_table=page_table, attn_lengths=attn_lengths,
-                        prefix_starts=prefix_starts, **mixer_call,
-                    )
-                    if written is not None:
-                        pools.append((layer.mixer, written))
-                elif segment_ids is not None:
-                    x = block(x, segment_ids=segment_ids, **mixer_call)
-                else:
-                    x = block(x, attn_mask=attn_mask, **mixer_call)
-                x = c(x)
-                continue
-            if layer.layer == "scmoe":
-                block = _ShortcutBlock(self.d_model, self.num_heads, attn, **common)
-            else:
-                block = _Block(
-                    self.d_model, self.num_heads, self.mlp_ratio, attn, **common
-                )
-            # a plain layer of a stack with recurrent ones: what a
-            # recurrent mixer reads beside its state
-            state_call = dict(runs=runs, real=real) if hybrid else {}
-            if paged_cache is not None and hybrid:
-                x, written = block(
-                    x, attn_mask=attn_mask, paged_cache=cache, page_ids=page_ids,
-                    page_offsets=page_offsets, page_table=page_table,
-                    attn_lengths=attn_lengths, prefix_starts=prefix_starts,
-                    state_lanes=state_lanes, **state_call,
-                )
-                pools.append(("attention" if _attends(layer) else layer.mixer, written))
-            elif paged_cache is not None:
-                if not latent:
-                    cache = (paged_cache.k[i], paged_cache.v[i])
-                elif layer.layer == "scmoe":
-                    cache = paged_cache.rows[at : at + 2]
-                else:
-                    cache = paged_cache.rows[at]
-                at += _latent_pools(layer)
-                x, written = block(
-                    x,
-                    attn_mask=attn_mask,
-                    paged_cache=cache,
-                    page_ids=page_ids,
-                    page_offsets=page_offsets,
-                    page_table=page_table,
-                    attn_lengths=attn_lengths,
-                    prefix_starts=prefix_starts,
-                )
-                pools.append(written)
-            elif segment_ids is not None:
-                x = block(x, segment_ids=segment_ids, **state_call)
-            else:
-                x = block(x, attn_mask=attn_mask, **state_call)
+            x, entries[i] = block(x, call, entries[i])
             x = c(x)
         final_norm = functools.partial(_norm, spec, jnp.float32)
         policy_head = nn.Dense(self.num_actions, name="policy_head")
@@ -2231,15 +2098,12 @@ class TransformerPolicy(nn.Module):
             # the module reads the trunk's last layer output and the shared
             # embedding of each position's next token, and is scored by the
             # shared head behind a norm of its own
-            call = (
-                dict(segment_ids=segment_ids) if segment_ids is not None
-                else dict(attn_mask=attn_mask)
-            )
             y = _MTPModule(
-                self.d_model, self.num_heads, self.mlp_ratio, attn, spec,
+                self.d_model, self.num_heads, self.mlp_ratio, attn,
                 dtype=self.dtype, param_dtype=self.param_dtype,
-                segment_attn_fn=self.segment_attn_fn, rotary=rotary, name="mtp",
-            )(x, embed(jnp.roll(obs.astype(jnp.int32), -1, axis=1)), has_next, **call)
+                segment_attn_fn=self.segment_attn_fn, spec=spec, rotary=rotary,
+                name="mtp",
+            )(x, embed(jnp.roll(obs.astype(jnp.int32), -1, axis=1)), has_next, call)
             mtp_logits = policy_head(
                 final_norm("mtp_final_norm")(c(y).astype(jnp.float32))
             )
@@ -2247,22 +2111,4 @@ class TransformerPolicy(nn.Module):
         policy_logits = policy_head(x)
         baseline = nn.Dense(1, name="value_head")(x).squeeze(-1)
         out = TransformerOutput(policy_logits, baseline, mtp_logits)
-        if paged_cache is None:
-            return out
-        if hybrid:
-            kv = [w for kind, w in pools if kind == "attention"]
-            st = [w for kind, w in pools if kind != "attention"]
-            return out, HybridCache(
-                k=tuple(k for k, _v in kv), v=tuple(v for _k, v in kv),
-                ssm=tuple(s for s, _c in st), conv=tuple(c for _s, c in st),
-            )
-        if latent:
-            return out, LatentKVCache(
-                rows=tuple(
-                    p for layer, w in zip(specs, pools)
-                    for p in (w if layer.layer == "scmoe" else (w,))
-                )
-            )
-        return out, PagedKVCache(
-            k=tuple(k for k, _v in pools), v=tuple(v for _k, v in pools)
-        )
+        return (out, _join(entries)) if call.paged else out

