@@ -796,6 +796,19 @@ class GenRLArguments(RLArguments):
     # features; its RMSNorm scales are stored zero-centred; its router is
     # a softmax over ``moe_experts`` with ``moe_experts_held`` held, beside
     # a shared expert of ``moe_shared_width`` behind a sigmoid scalar gate.
+    # "zaya" = plain layers of compressed convolutional attention (CCA)
+    # and routed experts: ``n_heads`` query heads over ``kv_heads`` of
+    # ``head_dim`` whose queries and keys are convolved over the tokens
+    # before them (a depthwise convolution of ``cca_time0`` taps, then one
+    # of ``cca_time1`` that mixes a head's channels), L2-normalised and
+    # rotated over a head's first ``rotary_dim`` features, and half of
+    # whose values are the previous token's; a router that is an MLP of
+    # width ``router_hidden`` on a state handed up from the layer before,
+    # which picks ``moe_experts_per_token`` of ``moe_experts`` experts of
+    # ``moe_hidden``; a learned scale and bias on both sides of every
+    # residual add.  The engine keeps the convolutions' window by lane
+    # beside the layer's own KV pages, so such a model too is admitted by
+    # local prefill and group fork alone.
     block_family: str = "gpt2"
     head_dim: int = 0
     rms_norm_eps: float = 1e-5
@@ -827,6 +840,9 @@ class GenRLArguments(RLArguments):
     moe_shared_width: int = 0
     full_attention_interval: int = 0
     rotary_dim: int = 0
+    cca_time0: int = 0
+    cca_time1: int = 0
+    router_hidden: int = 0
     mtp_layers: int = 0
     mtp_loss_coef: float = 0.1
     # weight of the router's load-balancing loss in the learner's total
@@ -953,15 +969,16 @@ class GenRLArguments(RLArguments):
                 f"{self.temperature}"
             )
         if self.block_family not in (
-            "gpt2", "olmoe", "longcat", "joyai", "nemotron_h", "qwen3_next"
+            "gpt2", "olmoe", "longcat", "joyai", "nemotron_h", "qwen3_next", "zaya"
         ):
             raise ValueError(
-                "block_family must be one of the six families gpt2 | olmoe | "
-                "longcat | joyai | nemotron_h | qwen3_next, got "
+                "block_family must be one of the seven families gpt2 | olmoe | "
+                "longcat | joyai | nemotron_h | qwen3_next | zaya, got "
                 f"{self.block_family!r}"
             )
         hybrid = self.block_family == "nemotron_h"
         delta = self.block_family == "qwen3_next"
+        cca = self.block_family == "zaya"
         if hybrid and (
             not self.layer_pattern
             or set(self.layer_pattern) - set("ME*-")
@@ -974,22 +991,32 @@ class GenRLArguments(RLArguments):
             )
         if not hybrid and (
             self.layer_pattern or self.moe_expert_act != "swiglu"
-            or (not delta and (self.kv_heads or self.ssm_heads or self.moe_shared_width))
+            or (not delta and (self.ssm_heads or self.moe_shared_width))
+            or (not (delta or cca) and self.kv_heads)
         ):
             raise ValueError(
                 "layer_pattern and moe_expert_act are the nemotron_h family's, "
-                "kv_heads, the ssm sizes and moe_shared_width that family's "
-                f"and qwen3_next's, got them with {self.block_family!r}"
+                "the ssm sizes and moe_shared_width that family's and "
+                "qwen3_next's, kv_heads theirs and zaya's, got them with "
+                f"{self.block_family!r}"
             )
         if delta and not 1 <= self.full_attention_interval <= self.n_layers:
             raise ValueError(
                 "the qwen3_next family needs full_attention_interval in "
                 f"1..n_layers ({self.n_layers}), got {self.full_attention_interval}"
             )
-        if not delta and (self.full_attention_interval or self.rotary_dim):
+        if (not delta and self.full_attention_interval) or (
+            not (delta or cca) and self.rotary_dim
+        ):
             raise ValueError(
-                "full_attention_interval and rotary_dim are the qwen3_next "
-                f"family's, got them with {self.block_family!r}"
+                "full_attention_interval is the qwen3_next family's and "
+                "rotary_dim that family's and zaya's, got them with "
+                f"{self.block_family!r}"
+            )
+        if not cca and (self.cca_time0 or self.cca_time1 or self.router_hidden):
+            raise ValueError(
+                "cca_time0, cca_time1 and router_hidden are the zaya family's, "
+                f"got them with {self.block_family!r}"
             )
         if self.moe_expert_act not in ("swiglu", "relu2"):
             raise ValueError(
@@ -1000,15 +1027,18 @@ class GenRLArguments(RLArguments):
                 "kv_heads must divide n_heads (0: one each), got "
                 f"{self.kv_heads}/{self.n_heads}"
             )
-        recurrent = (hybrid and "M" in self.layer_pattern) or (
-            delta and self.full_attention_interval > 1
+        lane_state = (
+            (hybrid and "M" in self.layer_pattern)
+            or (delta and self.full_attention_interval > 1)
+            or cca
         )
-        if recurrent and self.spec_enable:
+        if lane_state and self.spec_enable:
             raise ValueError(
-                "spec_enable cannot serve a model with a recurrent layer (a "
-                "Mamba-2 or a Gated DeltaNet mixer): a rejected draft is "
-                "undone by moving a page cursor back, and a recurrent state "
-                "has no cursor to rewind"
+                "spec_enable cannot serve a model with a layer that carries "
+                "lane state (a Mamba-2 or a Gated DeltaNet mixer's state, a "
+                "convolutional attention's window): a rejected draft is undone "
+                "by moving a page cursor back, and what a lane carries has no "
+                "cursor to rewind"
             )
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(

@@ -48,11 +48,12 @@ the host swaps *sequences* through them —
   (exhaustion backpressures, never corrupts; shared pages count against
   EVERY holder's reservation, so sharing never loosens the guarantee)
   while physical pages are drawn lazily as contexts grow.
-- **recurrent state beside the pages** -- a stack with recurrent layers
-  (``model.recurrent``: Mamba-2 or Gated DeltaNet mixers) has, in the
-  same cache's ``ssm`` and ``conv`` fields (empty for every other model),
-  a float32 state for each recurrent layer whatever its kind, indexed by
-  LANE, whose size does not depend on a lane's length, beside the page
+- **lane state beside the pages** -- a stack with a layer that carries
+  lane state (``model.lane_state``: a Mamba-2 or a Gated DeltaNet
+  mixer's state and window, a compressed convolutional attention's
+  window beside its OWN pools) has, in the same cache's ``ssm`` and
+  ``conv`` fields (empty for every other model), float32 arrays indexed
+  by LANE, whose size does not depend on a lane's length, beside the page
   pools of its attention layers.  It rides in the same pytree as the pools
   (donated through every program, never copied whole): the local prefill
   writes a lane's rows at the prompt's true length, every decode substep
@@ -363,16 +364,17 @@ class ContinuousEngine(ParamSnapshotPlane):
                 "ContinuousEngine needs a token-mode TransformerPolicy "
                 "(vocab_size set); got a feature-embedding model"
             )
-        # a recurrent layer's state is no page-table fact: nothing can
-        # enter it at a page boundary (a prefix hit) or rewind it by a page
-        # cursor (a rejected draft)
-        self._recurrent = model.recurrent
-        if self._recurrent and config.spec_k:
+        # what a lane carries of a layer (a recurrent state, a window of
+        # convolution inputs) is no page-table fact: nothing can enter it
+        # at a page boundary (a prefix hit) or rewind it by a page cursor
+        # (a rejected draft)
+        self._lane_state = model.lane_state
+        if self._lane_state and config.spec_k:
             raise ValueError(
                 "speculation (spec_k > 0) cannot serve a model with a "
-                "recurrent layer: a rejected draft is undone by "
-                "moving a page cursor back, and a recurrent state has no "
-                "cursor to rewind"
+                "layer that carries lane state: a rejected draft is undone "
+                "by moving a page cursor back, and what a lane carries has "
+                "no cursor to rewind"
             )
         self.config = config
         self.model = model
@@ -458,16 +460,16 @@ class ContinuousEngine(ParamSnapshotPlane):
         # device state: pools + per-lane decode carry (donated through
         # every program; the host rebinds after each dispatch).  The
         # model describes its cache; here it is one pytree of pools
-        # (a recurrent model's: pools and a lane-indexed state)
+        # (with lane state: pools and the lane-indexed arrays)
         self._pools = model.init_paged_cache(num_pages, ps, lanes=L)
-        # bytes of recurrent state a lane carries, all layers together
+        # bytes a lane carries beside pages, all layers together
         self._state_bytes_per_lane = (
             sum(a.nbytes for a in self._pools.ssm + self._pools.conv) // L
         )
         # what the decode program carries in place beside the pools
         self._dispatch_attrs = (
             {"state_bytes": self._state_bytes_per_lane * L}
-            if self._recurrent
+            if self._lane_state
             else {}
         )
         self.state_forks = 0  # members whose state rows a fork wrote
@@ -692,7 +694,7 @@ class ContinuousEngine(ParamSnapshotPlane):
             # the uncached tail always holds the token whose forward
             # produces the lane's first decode logits
             cached: List[int] = []
-            use_cache = self._prefix_cache is not None and not self._recurrent
+            use_cache = self._prefix_cache is not None and not self._lane_state
             if use_cache:
                 cached = self._prefix_cache.lookup(prompt, m - 1)
             elif self._prefix_cache is not None:
@@ -913,7 +915,7 @@ class ContinuousEngine(ParamSnapshotPlane):
             src_page[i] = sp
             dst_page[i] = dp
         fn = self._fork_fn(F)
-        if self._recurrent:
+        if self._lane_state:
             self.state_forks += len(forks)
         with self._dispatch_guard():
             up = _device_put((src_lane, dst_lane, src_page, dst_page))
@@ -960,7 +962,7 @@ class ContinuousEngine(ParamSnapshotPlane):
         the newly-allocated pages, last-position logits/value + cursor +
         flags scattered into the lane state — all device-side, no read."""
         model = self.model
-        recurrent = self._recurrent
+        lane_state = self._lane_state
 
         def prefill(
             params, pools, logits_st, value_st, cl, done, resp,
@@ -969,10 +971,10 @@ class ContinuousEngine(ParamSnapshotPlane):
             self._prefill_traces += 1
             positions = jnp.broadcast_to(jnp.arange(P), (A, P))
             mask = prompt_attention_mask(lengths, P)
-            # a recurrent layer's state leaves the prompt at its TRUE
-            # length (the mask's diagonal says which tokens are real) and
-            # is written to the admitted lanes' rows; pad rows drop
-            state = dict(state_lanes=lane_ids) if recurrent else {}
+            # what a lane carries leaves the prompt at its TRUE length
+            # (the mask's diagonal says which tokens are real) and is
+            # written to the admitted lanes' rows; pad rows drop
+            state = dict(state_lanes=lane_ids) if lane_state else {}
             out, pools = model.apply(
                 params,
                 tokens,
@@ -1043,7 +1045,7 @@ class ContinuousEngine(ParamSnapshotPlane):
         """The CoW fork program at admit bucket ``F``: batched pool-page
         copy (``pools[dst] = pools[src]`` per layer — only partial prompt
         pages ever ride here) plus leader -> member lane-state
-        replication, a recurrent model's state rows among it
+        replication, the rows a lane carries of a layer among it
         (:func:`fork_cache`).  Pad rows copy null -> null and
         scatter-drop."""
 
@@ -1831,9 +1833,10 @@ class ContinuousEngine(ParamSnapshotPlane):
             "held_expert_tokens": held_picks,
             "absent_expert_tokens": routed_picks - held_picks,
             "zero_expert_tokens": int(self._expert_tokens.sum()) - routed_picks,
-            # a model with recurrent layers only (zeros otherwise): bytes
-            # of state a lane carries, member lanes whose state a fork
-            # wrote, admissions that skipped the prefix cache
+            # a model with lane state only (zeros otherwise): bytes a
+            # lane carries beside pages (recurrent states, windows), member
+            # lanes whose rows a fork wrote, admissions that skipped the
+            # prefix cache
             "state_bytes_per_lane": self._state_bytes_per_lane,
             "state_forks": self.state_forks,
             "prefix_skipped_recurrent": self.prefix_skipped_recurrent,

@@ -57,11 +57,20 @@ computes the per-expert token counts, the load-balancing loss and the
 largest expert's load over the tokens a caller's mask names: a caller
 that wants them applies the model with ``mutable=["intermediates"]``,
 every other call pays nothing.
+
+**A scorer in front** (the ``zaya`` family): the router need not be this
+module's one matrix.  :class:`MLPRouter` scores a token through a narrow
+MLP on a state that the previous layer's router handed up, and hands this
+module its logits (``__call__``'s ``logits``); the softmax, the pick by
+``p + b``, the weights and both forms are the same code.  Top-1 without
+renormalisation is ``experts_per_token = 1`` with ``norm_topk_prob``
+false: the pick weighs its own probability.
 """
 
 from __future__ import annotations
 
-from typing import Any, Mapping, NamedTuple
+import functools
+from typing import Any, Mapping, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -167,13 +176,74 @@ def _sorted(x, top_p, top_i, w_gate, w_up, w_down, held_rows=None):
     return jnp.sum(ys[jnp.argsort(order)].reshape(N, k, -1), axis=1)
 
 
+@functools.lru_cache(maxsize=None)
+def _note_router_form(shape, kind, width, outputs, top, carries) -> None:
+    """Which scorer a traced shape took: one zero-length program span a
+    shape (the cache is the "once").  ``router`` is the scorer's kind (a
+    span's own ``kind`` is its plane), ``carries`` whether a layer's
+    state came in."""
+    from scalerl_tpu.runtime import tracing
+
+    with tracing.span(
+        "router.form", kind="model", shape=list(shape), router=kind,
+        width=width, outputs=outputs, top=top, carries=carries,
+    ):
+        pass
+
+
+class MLPRouter(nn.Module):
+    """The ``zaya`` family's router on a normed input ``g [B, T, d]`` and
+    the state ``r [B, T, width]`` the previous layer's router handed up
+    (None: no layer stands before this one)::
+
+        r'     = g W_dn + b_dn  (+ gamma * r)                  gamma: learned [width]
+        z      = RMSNorm(r');   logits = gelu(gelu(z W_1 + b_1) W_2 + b_2) W_3
+
+    ``(logits [B, T, outputs] float32, r')``: the logits go to
+    :class:`RoutedExperts` (its ``logits``), ``r'`` BEFORE its norm to the
+    next layer's router.  Float32 throughout at ``HIGHEST`` matmul
+    precision, whatever the blocks compute in: one pick decides a token's
+    whole expert output, and the four products are 0.7 M parameters."""
+
+    width: int
+    outputs: int
+    top: int
+    norm_eps: float
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, g: jnp.ndarray, r: Optional[jnp.ndarray]):
+        f32 = jnp.float32
+        dense = functools.partial(
+            nn.Dense, dtype=f32, param_dtype=self.param_dtype,
+            precision=lax.Precision.HIGHEST,
+        )
+        if not self.is_initializing():
+            _note_router_form(
+                tuple(g.shape), "mlp", self.width, self.outputs, self.top, r is not None
+            )
+        with jax.named_scope("zaya_router"):
+            state = dense(self.width, name="reduce")(g.astype(f32))
+            if r is not None:
+                state = state + self.param("carry_scale", nn.initializers.ones, (self.width,), f32) * r
+            scale = self.param("norm_scale", nn.initializers.ones, (self.width,), f32)
+            ms = jnp.mean(jnp.square(state), axis=-1, keepdims=True)
+            z = state * lax.rsqrt(ms + self.norm_eps) * scale
+            z = jax.nn.gelu(dense(self.width, name="fc1")(z), approximate=False)
+            z = jax.nn.gelu(dense(self.width, name="fc2")(z), approximate=False)
+            logits = dense(self.outputs, use_bias=False, name="score")(z)
+        return logits, state
+
+
 class RoutedExperts(nn.Module):
     """``[B, T, d] -> [B, T, d]``: router, exact top-k, SwiGLU experts.
 
     ``num_experts`` computed experts and ``zero_experts`` identity ones
     share one router of ``num_experts + zero_experts`` outputs; of the
     computed ones this module holds ``held`` (0: all), from
-    ``first_expert`` on (see the module docstring)."""
+    ``first_expert`` on (see the module docstring).  ``logits [B, T, R]``:
+    the router's scores where a scorer in front of this module made them
+    (:class:`MLPRouter`); this module then has no router matrix."""
 
     num_experts: int
     experts_per_token: int
@@ -190,23 +260,27 @@ class RoutedExperts(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
+    def __call__(self, h: jnp.ndarray, logits: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         B, T, d = h.shape
         E, k, f = self.num_experts, self.experts_per_token, self.width
         R = E + self.zero_experts  # the router's outputs
         held, first = self.held or E, self.first_expert
-        router = self.param(
-            "router", nn.initializers.lecun_normal(), (d, R), self.param_dtype
-        )
+        if logits is None:
+            router = self.param(
+                "router", nn.initializers.lecun_normal(), (d, R), self.param_dtype
+            )
         w_gate = None
         if self.act == "swiglu":
             w_gate = self.param("w_gate", _bank_init(), (held, d, f), self.param_dtype)
         w_up = self.param("w_up", _bank_init(), (held, d, f), self.param_dtype)
         w_down = self.param("w_down", _bank_init(), (held, f, d), self.param_dtype)
         x = h.reshape(B * T, d).astype(self.dtype)
-        logits = jnp.dot(
-            x, router.astype(self.dtype), preferred_element_type=jnp.float32
-        )
+        if logits is None:
+            logits = jnp.dot(
+                x, router.astype(self.dtype), preferred_element_type=jnp.float32
+            )
+        else:
+            logits = logits.reshape(B * T, R).astype(jnp.float32)
         # float32, over all R: a softmax, or each output's own sigmoid
         if self.scoring == "sigmoid":
             probs = jax.nn.sigmoid(logits)

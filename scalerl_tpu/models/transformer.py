@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from scalerl_tpu.models.routed_ffn import RoutedExperts
+from scalerl_tpu.models.routed_ffn import MLPRouter, RoutedExperts
 from scalerl_tpu.ops.pallas_attention import flash_attention
 from scalerl_tpu.ops.pallas_gdn import gdn_decode_update_pallas, heads_per_block
 from scalerl_tpu.ops.pallas_paged_attention import (
@@ -100,6 +100,20 @@ class BlockSpec:
     the attention's output, ``shared_gate`` puts the shared expert behind
     a sigmoid scalar, and ``norm_zero_centered`` stores every RMSNorm
     scale as ``w`` in ``1 + w``.
+
+    ``attention="cca"`` is compressed convolutional attention
+    (:class:`_CompressedConvAttention`, the ``zaya`` family): an attention
+    with K and V pages like ``mha``'s AND a lane-indexed window, because
+    its queries and keys are convolved over the ``cca_time0 + cca_time1 -
+    2`` tokens before them (a depthwise convolution of ``cca_time0`` taps,
+    then a grouped one of ``cca_time1`` that mixes a head's channels) and
+    half of its values are the previous token's.  Such a layer owns one
+    ``k``, one ``v`` and one ``conv`` array and no ``ssm``.
+    ``router="mlp"`` puts :class:`~scalerl_tpu.models.routed_ffn.MLPRouter`
+    (``router_width`` wide) in front of the routed experts: its state goes
+    up the stack from layer to layer beside the residual stream (the layer
+    contract's ``r``).  ``residual_scale`` gives both sides of every
+    residual add a learned scale and bias: ``(s x + c) + (s' y + c')``.
     """
 
     norm: str = "layernorm"  # layernorm | rmsnorm
@@ -113,7 +127,7 @@ class BlockSpec:
     experts_per_token: int = 0
     expert_width: int = 0
     norm_topk_prob: bool = False
-    attention: str = "mha"  # mha | mla
+    attention: str = "mha"  # mha | mla | cca
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -146,11 +160,23 @@ class BlockSpec:
     attn_gate: bool = False
     shared_gate: bool = False
     norm_zero_centered: bool = False
+    cca_time0: int = 0  # taps of a cca attention's depthwise convolution ...
+    cca_time1: int = 0  # ... and of its grouped one
+    router: str = "linear"  # linear (one matrix) | mlp (MLPRouter, router_width wide)
+    router_width: int = 0
+    residual_scale: bool = False
 
     @property
     def recurrent(self) -> bool:
-        """Whether the layer carries a state that no page table describes."""
+        """Whether the layer's mixer is a recurrence (its state: ``ssm``)."""
         return self.mixer in ("mamba", "gdn")
+
+    @property
+    def lane_state(self) -> bool:
+        """Whether a lane carries rows of this layer: arrays indexed by
+        lane, which no page table describes (a recurrent mixer's state
+        and window, a ``cca`` attention's window beside its pages)."""
+        return bool(self.owns.keys() & _LANE_FIELDS)
 
     @property
     def state_shape(self) -> Tuple[int, ...]:
@@ -171,13 +197,16 @@ class BlockSpec:
         """How many arrays of each of :class:`ModelCache`'s fields the
         layer caches into: a recurrent mixer one state and one window of
         taps, an attention one K and one V pool (or one latent pool, and
-        the double layer's two attentions two), any other mixer none."""
+        the double layer's two attentions two; a ``cca`` attention its
+        two pools AND one window), any other mixer none."""
         if self.recurrent:
             return {"ssm": 1, "conv": 1}
         if self.layer == "mixer" and self.mixer != "attention":
             return {}
         if self.attention == "mla":
             return {"rows": 2 if self.layer == "scmoe" else 1}
+        if self.attention == "cca":
+            return {"k": 1, "v": 1, "conv": 1}
         return {"k": 1, "v": 1}
 
     @property
@@ -187,8 +216,8 @@ class BlockSpec:
         FFN)."""
         if self.layer == "mixer":
             return f"{self.layer}/{self.mixer}"
-        if self.mixer:
-            return f"{self.layer}/{self.mixer}/{self.ffn}"
+        if self.mixer or self.attention == "cca":
+            return f"{self.layer}/{self.mixer or self.attention}/{self.ffn}"
         return f"{self.layer}/{self.ffn}"
 
 
@@ -224,6 +253,9 @@ def block_spec(
     ssm_conv: int = 4,
     ssm_chunk: int = 128,
     rotary_dim: int = 0,
+    cca_time0: int = 0,
+    cca_time1: int = 0,
+    router_width: int = 0,
 ) -> BlockSpec:
     """The block a named family stacks; the sizes only the family reads
     are ignored by the others (``gpt2`` keeps its own epsilon).  For a
@@ -231,7 +263,8 @@ def block_spec(
     that repeats (``joyai``: the routed layer) and :func:`layer_specs`
     gives the stack; for ``nemotron_h`` it is the expert layer, and
     :func:`pattern_specs` gives the stack; for ``qwen3_next`` it is the
-    full-attention layer, and :func:`interval_specs` gives the stack."""
+    full-attention layer, and :func:`interval_specs` gives the stack;
+    ``zaya``'s stack is one kind of layer."""
     if family == "gpt2":
         return BlockSpec(head_dim=head_dim)
     if family == "olmoe":
@@ -413,9 +446,41 @@ def block_spec(
             ssm_chunk=ssm_chunk, rotary_dim=rotary_dim, attn_gate=True,
             shared_gate=bool(shared_experts), norm_zero_centered=True,
         )
+    if family == "zaya":
+        sizes = (head_dim or 0, expert_width, router_width)
+        if min(sizes) < 1 or min(cca_time0, cca_time1) < 2:
+            raise ValueError(
+                "the zaya block needs a head size, an expert width, a router "
+                "width and two convolutions of 2 taps or more, got "
+                f"{sizes}/{cca_time0}/{cca_time1}"
+            )
+        if not (
+            1 <= experts_per_token <= num_experts
+            and kv_heads >= 1
+            and kv_heads % 2 == 0
+            and rotary_dim >= 0
+            and rotary_dim % 2 == 0
+            and rotary_dim <= (head_dim or 0)
+        ):
+            raise ValueError(
+                "the zaya router picks experts_per_token of num_experts, its "
+                "values' two halves (this token's, the previous one's) are cut "
+                "into an even number of kv_heads, and an even rotary_dim of a "
+                "head's features rotates, got "
+                f"{experts_per_token}/{num_experts}/{kv_heads}/{rotary_dim}"
+            )
+        return BlockSpec(
+            norm="rmsnorm", norm_eps=norm_eps, positions="rope",
+            rope_theta=rope_theta, head_dim=head_dim, ffn="experts",
+            num_experts=num_experts, experts_per_token=experts_per_token,
+            expert_width=expert_width, norm_topk_prob=norm_topk_prob,
+            attention="cca", router_bias=True, kv_heads=kv_heads,
+            rotary_dim=rotary_dim, cca_time0=cca_time0, cca_time1=cca_time1,
+            router="mlp", router_width=router_width, residual_scale=True,
+        )
     raise ValueError(
         "block family must be gpt2 | olmoe | longcat | joyai | nemotron_h | "
-        f"qwen3_next, got {family!r}"
+        f"qwen3_next | zaya, got {family!r}"
     )
 
 
@@ -531,17 +596,31 @@ class ModelCache(NamedTuple):
     Sharing, forks and the prefix cache are page-index facts and do not
     see the kind.
 
-    **Lanes** (``ssm``, ``conv``): the state of the recurrent layers
-    whatever their kind, float32, indexed by LANE and of a size that does
+    **Lanes** (``ssm``, ``conv``): what a layer carries from token to
+    token beside pages, float32, indexed by LANE and of a size that does
     not depend on a lane's length: ``ssm [lanes, *spec.state_shape]`` (a
     Mamba-2 layer's ``[heads, head_dim, state]``, which
     :func:`ssm_decode_update` updates in place; a Gated DeltaNet layer's
     ``[heads, key, value]``, which :func:`gdn_decode_update` does) and
-    ``conv [lanes, taps - 1, channels]``.  Written by the prefill at the
-    prompt's true length, updated in place by every decode substep and
-    copied leader to member by the group fork (:func:`fork_cache`); a
-    page table says nothing about it, so a prefix-cache hit and a
-    page-cursor rollback cannot serve a model that has one.
+    ``conv [lanes, rows, channels]``, the last ``rows`` inputs of a
+    layer's causal convolution (a recurrent mixer's ``taps - 1``).
+    ``conv`` may stand without ``ssm``: a ``cca`` attention layer owns a
+    ``k`` and a ``v`` pool AND one ``conv`` array, ``[lanes, rows x
+    channels]`` with ``rows = cca_time0 + cca_time1 - 2`` and ``channels =
+    (heads + kv_heads) x head_dim + kv_heads x head_dim / 2``, what its two
+    convolutions and its value shift read of the tokens before this one:
+    a RING of ``rows`` rows side by side on the minor axis, of which row
+    ``p mod rows`` holds ``[q~ | k~ | h W_v2]`` of the token at position
+    ``p`` (zeros before a sequence's start), so that a decoded token
+    overwrites the oldest row where it lies and nothing shifts (a
+    recurrent mixer's window is kept oldest first, ``[lanes, taps - 1,
+    channels]``, and shifts by a row a token, which costs a copy of the
+    window a layer a substep).
+    Written by the prefill at the prompt's true length, updated in place
+    by every decode substep and copied leader to member by the group fork
+    (:func:`fork_cache`); a page table says nothing about it, so a
+    prefix-cache hit and a page-cursor rollback cannot serve a model that
+    has one.
     """
 
     k: Tuple[jnp.ndarray, ...] = ()
@@ -552,6 +631,7 @@ class ModelCache(NamedTuple):
 
 
 _PAGE_FIELDS = ("k", "v", "rows")  # the others are indexed by lane
+_LANE_FIELDS = frozenset(ModelCache._fields) - frozenset(_PAGE_FIELDS)
 
 
 def _layer_entries(cache: ModelCache, specs) -> list:
@@ -584,7 +664,7 @@ def fork_cache(cache: ModelCache, src_page, dst_page, src_lane, dst_lane) -> Mod
 
 
 # the arguments that spell each form, beside ``positions`` (and, for a
-# prefill of a model with recurrent layers, ``state_lanes``)
+# prefill of a model whose lanes carry state, ``state_lanes``)
 _FORMS = {
     "causal": (),
     "masked": ("attn_mask",),
@@ -627,9 +707,9 @@ class Call:
       - ``prefill`` (``attn_mask``, no ``page_table``): fresh RIGHT-padded
         prompts (:func:`prompt_attention_mask`); the whole context is in
         the call, so attention is local and the pool write-only.  On a
-        recurrent model ``state_lanes [B]`` names the lanes whose state
-        rows the prompts write, at their true lengths (an id out of range
-        drops).
+        model whose lanes carry state ``state_lanes [B]`` names the lanes
+        whose state rows the prompts write, at their true lengths (an id
+        out of range drops).
       - ``decode`` (``page_table [B, M]``, ``attn_lengths [B]``, ``T =
         1``, row ``b`` is lane ``b``): attention gathers through the
         table by the model's ``paged_attn_fn``; a recurrent layer updates
@@ -645,12 +725,14 @@ class Call:
         (``genrl/continuous.py``) rides this form with ``T`` = draft
         bucket + 1: the mask keeps rejected slots' K/V (garbage past the
         cursor) out of every query, so a draft rollback never touches the
-        device.  A recurrent model has no such form: its state cannot be
-        entered at a page boundary or rewound by a page cursor.
+        device.  A model whose lanes carry state has no such form: the
+        state cannot be entered at a page boundary or rewound by a page
+        cursor.
 
-    On a model with recurrent layers ``real [B, T]`` says which tokens
-    are real and ``runs [B, T]`` (:func:`run_ids`) where a recurrence
-    starts anew: the packed rows' segments, or the diagonal of a mask (a
+    On a model whose lanes carry state (``lane_state``: a recurrent layer,
+    or a ``cca`` attention's window) ``real [B, T]`` says which tokens
+    are real and ``runs [B, T]`` (:func:`run_ids`) where a recurrence, or
+    a window, starts anew: the packed rows' segments, or the diagonal of a mask (a
     token that may attend itself is real); a ``causal`` call's rows are
     one run of real tokens each, and ``decode`` reads neither.
     """
@@ -674,18 +756,19 @@ class Call:
 
     @classmethod
     def of(
-        cls, *, recurrent: bool, segment_kernel: bool, mtp: bool, paged_cache, **arrays
+        cls, *, lane_state: bool, segment_kernel: bool, mtp: bool, paged_cache, **arrays
     ) -> "Call":
         """The form the arguments spell (``arrays``: the fields above that
-        a caller passes, None where it did not), for a model that has
-        ``recurrent`` layers or none and a ``segment_kernel`` or none;
-        ``ValueError`` for a set that spells none of the six."""
+        a caller passes, None where it did not), for a model whose lanes
+        carry state (``lane_state``) or none and that has a
+        ``segment_kernel`` or none; ``ValueError`` for a set that spells
+        none of the six."""
         given = {name for name, a in arrays.items() if a is not None}
         if paged_cache is None:
             mode = "packed" if "segment_ids" in given else "masked" if "attn_mask" in given else "causal"
         else:
             mode = "prefill" if "page_table" not in given else "tail" if "prefix_starts" in given else "decode"
-        takes = set(_FORMS[mode]) | ({"state_lanes"} if recurrent and mode == "prefill" else set())
+        takes = set(_FORMS[mode]) | ({"state_lanes"} if lane_state and mode == "prefill" else set())
         if mtp and paged_cache is not None:
             given.add("mtp")  # the module runs on no cache
         if given != takes:
@@ -693,18 +776,18 @@ class Call:
                 f"a {mode} call {'on' if paged_cache is not None else 'without'} a "
                 f"paged_cache takes {sorted(takes)} beside positions, got {sorted(given)}"
             )
-        if recurrent and mode == "tail":
+        if lane_state and mode == "tail":
             raise ValueError(
-                "a recurrent layer has no tail prefill over a cached "
-                "prefix and no speculative verify: its state cannot be "
+                "a layer that carries lane state has no tail prefill over a "
+                "cached prefix and no speculative verify: its state cannot be "
                 "entered at a page boundary or rewound by a page cursor"
             )
         attn_mask, segment_ids = arrays["attn_mask"], arrays["segment_ids"]
         runs = real = None
-        if recurrent and mode == "packed":
+        if lane_state and mode == "packed":
             real = segment_ids > 0
             runs = run_ids(segment_ids)
-        elif recurrent and mode in ("masked", "prefill"):
+        elif lane_state and mode in ("masked", "prefill"):
             real = jnp.diagonal(attn_mask, axis1=1, axis2=2)
             runs = run_ids(real.astype(jnp.int32))
         if mode == "packed" and not segment_kernel:
@@ -901,6 +984,40 @@ def _repeat_kv(x: jnp.ndarray, num_heads: int) -> jnp.ndarray:
     return x if kv == num_heads else jnp.repeat(x, num_heads // kv, axis=2)
 
 
+def _attend(mod, q, k, v, call: Call, cache: Optional[ModelCache]):
+    """The one attention core under every multi-head kind: ``q [B, T, H,
+    D]`` against ``k``/``v`` ``[B, T, KV, D]`` (normed and rotated by the
+    caller: K enters a cache as it is read), in the call's form.  On a
+    cache the call's keys and values are written into the layer's pools
+    first.  Returns ``(out [B, T, H, D], the layer's K and V pools
+    written, or None)``."""
+    T, H = q.shape[1], q.shape[2]
+    KV = k.shape[2]
+    if call.paged:
+        kp, vp = _write_pages(call, (cache.k[0], cache.v[0]), (k, v))
+        cache = ModelCache(k=(kp,), v=(vp,))
+    if call.mode == "tail":
+        # the heads are split out of the gathered rows: reshaping the
+        # pool itself would bring its relayout copy back
+        kg = _repeat_kv(gather_pages(kp, call.page_table, KV), H)
+        vg = _repeat_kv(gather_pages(vp, call.page_table, KV), H)
+        out = _masked_attention(q, kg, vg, _tail_mask(call, T, kg.shape[1]), mod.dtype)
+    elif call.mode == "decode":
+        paged_attn = mod.paged_attn_fn or paged_attention_reference
+        out = paged_attn(q, kp, vp, call.page_table, call.attn_lengths)
+        out = out.astype(mod.dtype)
+    elif call.mode == "packed":
+        out = mod.segment_attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H), call.segment_ids)
+        out = out.astype(mod.dtype)
+    elif call.mode == "causal":
+        out = mod.attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H))
+    else:  # masked, prefill: the call's own keys under its mask
+        out = _masked_attention(
+            q, _repeat_kv(k, H), _repeat_kv(v, H), call.attn_mask, mod.dtype
+        )
+    return out, cache
+
+
 def _mha(mod, h, call: Call, cache: Optional[ModelCache]):
     """Multi-head attention on a normed input ``h [B, T, d]``, inside the
     compact ``__call__`` of ``mod`` (a :class:`_Block` or a
@@ -953,28 +1070,7 @@ def _mha(mod, h, call: Call, cache: Optional[ModelCache]):
     if mod.rotary is not None:
         # before every cache write: K is stored normed and rotated
         q, k = mod.rotary(q), mod.rotary(k)
-    if call.paged:
-        kp, vp = _write_pages(call, (cache.k[0], cache.v[0]), (k, v))
-        cache = ModelCache(k=(kp,), v=(vp,))
-    if call.mode == "tail":
-        # the heads are split out of the gathered rows: reshaping the
-        # pool itself would bring its relayout copy back
-        kg = _repeat_kv(gather_pages(kp, call.page_table, KV), H)
-        vg = _repeat_kv(gather_pages(vp, call.page_table, KV), H)
-        out = _masked_attention(q, kg, vg, _tail_mask(call, T, kg.shape[1]), mod.dtype)
-    elif call.mode == "decode":
-        paged_attn = mod.paged_attn_fn or paged_attention_reference
-        out = paged_attn(q, kp, vp, call.page_table, call.attn_lengths)
-        out = out.astype(mod.dtype)
-    elif call.mode == "packed":
-        out = mod.segment_attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H), call.segment_ids)
-        out = out.astype(mod.dtype)
-    elif call.mode == "causal":
-        out = mod.attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H))
-    else:  # masked, prefill: the call's own keys under its mask
-        out = _masked_attention(
-            q, _repeat_kv(k, H), _repeat_kv(v, H), call.attn_mask, mod.dtype
-        )
+    out, cache = _attend(mod, q, k, v, call, cache)
     if gate is not None:
         out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
     out = nn.Dense(mod.d_model, use_bias=False, name="proj", **dt)(
@@ -985,9 +1081,16 @@ def _mha(mod, h, call: Call, cache: Optional[ModelCache]):
 
 class _Layer(nn.Module):
     """What every layer class is built from, and the one contract: ``(x [B,
-    T, d], call, cache) -> (x, cache)``, ``cache`` the layer's own arrays
-    (:func:`_layer_entries`) where ``call.paged`` and None elsewhere, in and
-    out.  A mixer under a layer takes the normed input the same way."""
+    T, d], call, cache, r) -> (x, cache, r)``, ``cache`` the layer's own
+    arrays (:func:`_layer_entries`) where ``call.paged`` and None
+    elsewhere, in and out.  ``r`` is the second stream that goes up the
+    stack beside ``x``: the router's state a ``router="mlp"`` layer reads
+    from the layer before it and hands to the one after (``[B, T,
+    router_width]`` float32; None into the first layer, and None all the
+    way up a stack without such a router, whose layers pass it by).  It is
+    an activation of this forward, not cache: nothing of it outlives the
+    call.  A mixer under a layer takes the normed input as ``(h, call,
+    cache) -> (out, cache)``."""
 
     d_model: int
     num_heads: int
@@ -1006,12 +1109,28 @@ class _Layer(nn.Module):
 
 class _Block(_Layer):
     """A plain layer (``layer="plain"``): ``x + Mixer(N(x))``, then ``x +
-    FFN(N(x))``; the mixer this spec's attention (:func:`_mha`, or
-    :class:`_LatentAttention` under ``attention="mla"``) or, under
-    ``mixer="gdn"``, a Gated DeltaNet (:class:`_GatedDeltaMixer`)."""
+    FFN(N(x))``; the mixer this spec's attention (:func:`_mha`,
+    :class:`_LatentAttention` under ``attention="mla"``,
+    :class:`_CompressedConvAttention` under ``"cca"``) or, under
+    ``mixer="gdn"``, a Gated DeltaNet (:class:`_GatedDeltaMixer`).  Under
+    ``spec.residual_scale`` both adds are ``(s x + c) + (s' y + c')``
+    (:meth:`_merge`); under ``spec.router == "mlp"`` the experts' router
+    reads and hands on the stack's second stream ``r``."""
+
+    def _merge(self, name: str, x, y):
+        """A residual add; under ``spec.residual_scale`` with a learned
+        scale and bias on both sides (``[2, d]`` each: the stream's, the
+        sublayer's), in float32 and rounded once."""
+        if not self.spec.residual_scale:
+            return x + y
+        f32 = jnp.float32
+        s = self.param(f"{name}_res_scale", nn.initializers.ones, (2, self.d_model), f32)
+        c = self.param(f"{name}_res_bias", nn.initializers.zeros, (2, self.d_model), f32)
+        merged = (s[0] * x.astype(f32) + c[0]) + (s[1] * y.astype(f32) + c[1])
+        return merged.astype(x.dtype)
 
     @nn.compact
-    def __call__(self, x, call: Call, cache: Optional[ModelCache] = None):
+    def __call__(self, x, call: Call, cache: Optional[ModelCache] = None, r=None):
         spec = self.spec
         rms = spec.norm == "rmsnorm"
         dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
@@ -1020,12 +1139,21 @@ class _Block(_Layer):
             out, cache = _GatedDeltaMixer(self.d_model, spec, name="mixer", **dt)(h, call, cache)
         elif spec.attention == "mla":
             out, cache = _latent_attention(self, "attn")(h, call, cache)
+        elif spec.attention == "cca":
+            out, cache = _sub_attention(_CompressedConvAttention, self, "attn")(h, call, cache)
         else:
             out, cache = _mha(self, h, call, cache)
-        x = x + out
+        x = self._merge("attn", x, out)
         h = _norm(spec, self.dtype, "ffn_norm" if rms else None)(x)
         if spec.ffn == "experts":
-            y = _routed_experts(spec, dt)(h)
+            logits = None
+            if spec.router == "mlp":
+                logits, r = MLPRouter(
+                    spec.router_width, spec.num_experts + spec.zero_experts,
+                    spec.experts_per_token, spec.norm_eps,
+                    param_dtype=self.param_dtype, name="router",
+                )(h, r)
+            y = _routed_experts(spec, dt)(h, logits)
             if spec.shared_experts:
                 # always on, computed where the token lives
                 shared = _GatedMLP(
@@ -1047,7 +1175,7 @@ class _Block(_Layer):
             h = nn.Dense(self.mlp_ratio * self.d_model, name="mlp_in", **dt)(h)
             h = nn.gelu(h)
             h = nn.Dense(self.d_model, name="mlp_out", **dt)(h)
-        return x + h, cache
+        return self._merge("ffn", x, h), cache, r
 
 
 class _LatentAttention(nn.Module):
@@ -1194,13 +1322,19 @@ class _LatentAttention(nn.Module):
         return out, cache
 
 
-def _latent_attention(mod: _Layer, name: str) -> _LatentAttention:
-    """A layer's latent attention, under ``name`` among its children."""
-    return _LatentAttention(
+def _sub_attention(kind, mod: _Layer, name: str):
+    """A layer's attention of class ``kind`` (one that is a module of its
+    own), under ``name`` among the layer's children."""
+    return kind(
         mod.d_model, mod.num_heads, mod.spec, mod.attn_fn, dtype=mod.dtype,
         param_dtype=mod.param_dtype, paged_attn_fn=mod.paged_attn_fn,
         segment_attn_fn=mod.segment_attn_fn, rotary=mod.rotary, name=name,
     )
+
+
+def _latent_attention(mod: _Layer, name: str) -> _LatentAttention:
+    """A layer's latent attention, under ``name`` among its children."""
+    return _sub_attention(_LatentAttention, mod, name)
 
 
 class _GatedMLP(nn.Module):
@@ -1396,16 +1530,22 @@ def _causal_taps(u, conv_w, runs, bias=None):
     """The causal depthwise convolution ``bias + sum_j w_j u_{t-K+1+j}`` of
     ``u [Bt, T, C]`` by taps ``conv_w [K, C]``; an input of another run
     (``runs [Bt, T]``) is not read."""
-    K, T = conv_w.shape[0], u.shape[1]
+    K = conv_w.shape[0]
     out = u * conv_w[K - 1]
     if bias is not None:
         out = bias + out
     for back in range(1, K):
-        # the input ``back`` tokens earlier, if it is of this run
-        earlier = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :T]
-        near = jnp.pad(runs, ((0, 0), (back, 0)), constant_values=-1)[:, :T] == runs
-        out = out + jnp.where(near[..., None], earlier, 0.0) * conv_w[K - 1 - back]
+        out = out + _earlier(u, runs, back) * conv_w[K - 1 - back]
     return out
+
+
+def _earlier(a, runs, back: int, fill=0.0):
+    """``a [Bt, T, C]`` as it stood ``back`` tokens earlier, where that
+    token is of this token's run (``runs [Bt, T]``); ``fill`` elsewhere."""
+    T = a.shape[1]
+    earlier = jnp.pad(a, ((0, 0), (back, 0), (0, 0)))[:, :T]
+    near = jnp.pad(runs, ((0, 0), (back, 0)), constant_values=-1)[:, :T] == runs
+    return jnp.where(near[..., None], earlier, fill)
 
 
 def _runs_and_real(call: Call, rows: int, T: int):
@@ -1743,6 +1883,181 @@ class _GatedDeltaMixer(nn.Module):
         return out, cache
 
 
+def cca_window_shape(spec: BlockSpec, num_heads: int, head_dim: int) -> Tuple[int, int]:
+    """``(rows, channels)`` of a ``cca`` attention's window: the
+    ``cca_time0 + cca_time1 - 2`` tokens its two convolutions reach back
+    over, each ``[q~ | k~ | h W_v2]`` (the query and key latents every
+    head, and the half of the values that the NEXT token takes)."""
+    kv = spec.kv_heads or num_heads
+    return (
+        spec.cca_time0 + spec.cca_time1 - 2,
+        (num_heads + kv) * head_dim + kv * head_dim // 2,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _note_cca_form(shape, heads, taps, rotary_dim, window_shape, path) -> None:
+    """Which path of the compressed convolutional attention a traced
+    shape took: one zero-length program span a shape (the cache is the
+    "once")."""
+    from scalerl_tpu.runtime import tracing
+
+    with tracing.span(
+        "cca.form", kind="model", shape=list(shape), heads=list(heads),
+        taps=list(taps), rotary_dim=rotary_dim, window_shape=list(window_shape),
+        window_dtype="float32", path=path,
+    ):
+        pass
+
+
+class _CompressedConvAttention(nn.Module):
+    """Compressed convolutional attention (CCA, the ``zaya`` family) on a
+    normed input ``h [B, T, d]``: ``H`` query heads over ``KV`` key/value
+    heads of ``D``; anything before a sequence's (a run's) first token is
+    zero::
+
+        q~_t = h_t W_q;  k~_t = h_t W_k;  u_t = [q~_t | k~_t]        (H + KV heads of D)
+        v_t  = [h_t W_v1 | h_{t-1} W_v2], cut into the KV heads in that order   (the value shift)
+        c_t  = b + sum_j w_j u_{t-K0+1+j}                             depthwise, K0 taps
+        d_t[g] = b'_g + sum_i c_{t-K1+1+i}[g] M_{i,g}                 grouped, K1 taps; M_{i,g} [D, D]
+        q_t[i] = d_t[i] + (q~_t[i] + k~_t[i // (H / KV)]) / 2         the q-k mean, on the latents
+        k_t[j] = d_t[H + j] + (mean_{i // (H / KV) = j} q~_t[i] + k~_t[j]) / 2
+        q <- sqrt(D) q / |q|;  k <- tau_j sqrt(D) k / |k|             float32; tau a key head
+        rotary on a head's first ``rotary_dim`` features of q and k, then :func:`_attend`
+        out = o W_o                                                   (H D -> d)
+
+    The input is padded ONCE, by ``K0 + K1 - 2`` zeros: ``c`` before a
+    sequence's start is ``b``, not zero.  The grouped convolution mixes a
+    head's ``D`` channels and never two heads.  Everything between the
+    projections and the rotation is float32 (the grouped products at
+    ``HIGHEST`` precision).
+
+    ONE set of parameters, the recurrent mixers' two paths:
+
+    - **whole sequences** (every form but ``decode``): shifted arrays cut
+      at every run's start (``call.runs``: :func:`_causal_taps`,
+      :func:`_earlier`); a ``prefill`` writes the last ``K0 + K1 - 2``
+      REAL tokens' ``[u | h W_v2]`` to the window (:func:`_last_taps`: the
+      prompt's true length, whatever its bucket).
+    - **one token a lane** (``decode``): the carried window and this
+      token's row are the ``K0 + K1 - 1`` tokens both convolutions read.
+
+    ``cache`` holds the layer's ``k`` and ``v`` pools (``KV x D`` a token,
+    K normed and rotated) and ``conv [lanes, (K0 + K1 - 2) x ((H + KV) D +
+    KV D / 2)]`` float32 (:func:`cca_window_shape`'s rows side by side on
+    the minor axis, so that the lanes tile and a row is a whole number of
+    128-lane tiles; the older rows' value part is carried and not read),
+    a ring: the token at position ``p`` lies in row ``p mod rows``, a
+    decoded token is selected over the oldest row (an elementwise pass
+    over the carried buffer) and no row moves.  Returns ``(out [B,
+    T, d], cache)``."""
+
+    d_model: int
+    num_heads: int
+    spec: BlockSpec
+    attn_fn: AttentionFn
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    paged_attn_fn: Optional[Callable] = None
+    segment_attn_fn: Optional[Callable] = None
+    rotary: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, h, call: Call, cache: Optional[ModelCache]):
+        B, T, _ = h.shape
+        s, H = self.spec, self.num_heads
+        KV = s.kv_heads or H
+        D = s.head_dim or self.d_model // H
+        K0, K1 = s.cca_time0, s.cca_time1
+        G, per, half = H + KV, H // KV, KV * D // 2
+        R, C = cca_window_shape(s, H, D)[0], G * D  # the window's rows, the convolutions' channels
+        f32, hi = jnp.float32, lax.Precision.HIGHEST
+
+        def dense(width, name):
+            return nn.Dense(
+                width, use_bias=False, name=name, dtype=self.dtype, param_dtype=self.param_dtype
+            )
+
+        conv_w = self.param("conv_w", _tap_init(K0), (K0, C))
+        conv_b = self.param("conv_b", _tap_init(K0), (C,))
+        mix_w = self.param(
+            "mix_w",
+            nn.initializers.variance_scaling(
+                1.0, "fan_in", "truncated_normal", in_axis=(0, 2), out_axis=3, batch_axis=1
+            ),
+            (K1, G, D, D), f32,
+        )
+        mix_b = self.param("mix_b", _tap_init(K1 * D), (C,))
+        k_temp = self.param("k_temp", nn.initializers.ones, (KV,), f32)
+        if not self.is_initializing():
+            _note_cca_form(
+                tuple(h.shape), (H, KV, D), (K0, K1), s.rotary_dim or D, cca_window_shape(s, H, D),
+                call.mode if call.mode in ("decode", "prefill", "packed") else "whole",
+            )
+
+        u = jnp.concatenate([dense(H * D, "q")(h), dense(KV * D, "k")(h)], axis=-1).astype(f32)
+        v1, v2 = dense(half, "v1")(h), dense(half, "v2")(h).astype(f32)
+        with jax.named_scope("cca_window"):
+            row = jnp.concatenate([u, v2], axis=-1)  # what the window carries of a token
+            if call.mode == "decode":
+                ring = jnp.split(cache.conv[0], R, axis=-1)  # rows side by side
+                at = call.attn_lengths - 1  # this token's position (a dead lane's: 0)
+                # oldest first: the token ``R - i`` back lies in row ``(at +
+                # i) mod R``; selects over the rows, not a gather, so that
+                # the ring is read and then written where it lies by
+                # elementwise passes
+                lies_in = ((at[:, None] + jnp.arange(R)) % R)[:, :, None] == jnp.arange(R)
+                earlier = [
+                    sum(jnp.where(lies_in[:, i, j, None], ring[j], 0.0) for j in range(R))
+                    for i in range(R)
+                ]
+                window = jnp.stack(earlier + [row[:, 0]], axis=1)  # [lanes, K0 + K1 - 1, .]
+                taps = window[..., :C]
+                # c of the K1 latest tokens, each from its own K0 rows
+                cs = jnp.stack(
+                    [conv_b + jnp.sum(taps[:, i : i + K0] * conv_w, axis=1) for i in range(K1)],
+                    axis=1,
+                )[:, None]  # [lanes, 1, K1, C]
+                v2_prev = window[:, -2:-1, C:]
+            else:
+                runs, real = _runs_and_real(call, B, T)
+                c = _causal_taps(u, conv_w, runs, conv_b)
+                # before a run's start c is the bias alone
+                cs = jnp.stack(
+                    [_earlier(c, runs, back, conv_b) for back in range(K1 - 1, 0, -1)] + [c],
+                    axis=2,
+                )  # [B, T, K1, C]
+                v2_prev = _earlier(v2, runs, 1)
+            d = mix_b + jnp.einsum(
+                "btkgd,kgde->btge", cs.reshape(B, T, K1, G, D), mix_w, precision=hi
+            ).reshape(B, T, C)
+            q_lat = u[..., : H * D].reshape(B, T, KV, per, D)
+            k_lat = u[..., H * D :].reshape(B, T, KV, D)
+            q = d[..., : H * D].reshape(B, T, KV, per, D) + (q_lat + k_lat[:, :, :, None]) / 2
+            k = d[..., H * D :].reshape(B, T, KV, D) + (jnp.mean(q_lat, axis=3) + k_lat) / 2
+            unit = lambda a: a * lax.rsqrt(jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6) * D ** 0.5  # noqa: E731
+            q = unit(q).reshape(B, T, H, D)
+            k = unit(k) * k_temp[:, None]
+            v = jnp.concatenate([v1, v2_prev.astype(self.dtype)], axis=-1).reshape(B, T, KV, D)
+        # before every cache write: K is stored normed and rotated
+        q, k = self.rotary(q).astype(self.dtype), self.rotary(k).astype(self.dtype)
+        out, pools = _attend(self, q, k, v, call, cache)
+        if call.mode == "decode":
+            # over the oldest row, where it lies: nothing shifts
+            oldest = (at % R)[:, None] == jnp.arange(R)
+            ring = [jnp.where(oldest[:, i, None], row[:, 0], ring[i]) for i in range(R)]
+            pools = pools._replace(conv=(jnp.concatenate(ring, axis=-1),))
+        elif call.mode == "prefill":
+            tail = _last_taps(row, real, R)  # oldest first, of each row's TRUE length
+            order = (jnp.arange(R)[None, :] - jnp.sum(real, axis=1)[:, None]) % R
+            tail = jnp.take_along_axis(tail, order[..., None], axis=1).reshape(B, -1)
+            pools = pools._replace(
+                conv=(cache.conv[0].at[call.state_lanes].set(tail, mode="drop"),)
+            )
+        out = dense(self.d_model, "proj")(out.reshape(B, T, H * D))
+        return out, pools
+
+
 class _MixerBlock(_Layer):
     """A layer of one mixer (``layer="mixer"``): ``x + Mixer(N(x))``, the
     mixer ``spec.mixer``'s: a Mamba-2 mixer, this spec's attention alone
@@ -1750,7 +2065,7 @@ class _MixerBlock(_Layer):
     dense FFN (the last two cache nothing: their entry is empty)."""
 
     @nn.compact
-    def __call__(self, x, call: Call, cache: Optional[ModelCache] = None):
+    def __call__(self, x, call: Call, cache: Optional[ModelCache] = None, r=None):
         spec = self.spec
         dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         h = _norm(spec, self.dtype, "norm")(x)
@@ -1769,7 +2084,7 @@ class _MixerBlock(_Layer):
                 )(h)
         else:
             out = _dense_ffn(spec, self.d_model, spec.ffn_hidden, "ffn", dt)(h)
-        return x + out, cache
+        return x + out, cache, r
 
 
 class _ShortcutBlock(_Layer):
@@ -1788,7 +2103,7 @@ class _ShortcutBlock(_Layer):
     ``spec.ffn_hidden`` wide.)"""
 
     @nn.compact
-    def __call__(self, x, call: Call, cache: Optional[ModelCache] = None):
+    def __call__(self, x, call: Call, cache: Optional[ModelCache] = None, r=None):
         spec = self.spec
         dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
 
@@ -1808,7 +2123,7 @@ class _ShortcutBlock(_Layer):
         x = x + ffn(0, h)
         x, second = attention(1, x)
         x = x + ffn(1, _norm(spec, self.dtype, "ffn_norm_1")(x)) + m
-        return x, _join((first, second)) if call.paged else None
+        return x, _join((first, second)) if call.paged else None, r
 
 
 # the class of each ``BlockSpec.layer``
@@ -1838,7 +2153,7 @@ class _MTPModule(_Layer):
             self.d_model, use_bias=False, name="eh_proj", dtype=self.dtype,
             param_dtype=self.param_dtype,
         )(jnp.concatenate([h, e], axis=-1))
-        y, _none = _Block(
+        y, _none, _r = _Block(
             self.d_model, self.num_heads, self.mlp_ratio, self.attn_fn,
             dtype=self.dtype, param_dtype=self.param_dtype,
             segment_attn_fn=self.segment_attn_fn, spec=self.spec,
@@ -1953,9 +2268,16 @@ class TransformerPolicy(nn.Module):
 
     @property
     def recurrent(self) -> bool:
-        """Whether a layer of the stack carries a state that no page table
-        describes (a Mamba-2 or a Gated DeltaNet mixer)."""
+        """Whether a layer of the stack is a recurrence (a Mamba-2 or a
+        Gated DeltaNet mixer)."""
         return any(s.recurrent for s in self.layer_specs)
+
+    @property
+    def lane_state(self) -> bool:
+        """Whether a lane carries rows of any layer (``BlockSpec.
+        lane_state``): what the engine asks before it serves a prefix hit
+        or a speculative draft, which a page table alone must describe."""
+        return any(s.lane_state for s in self.layer_specs)
 
     def init_paged_cache(
         self, num_pages: int, page_size: int, dtype=jnp.float32, lanes: int = 0
@@ -1965,14 +2287,17 @@ class TransformerPolicy(nn.Module):
         by layer (``BlockSpec.owns``), and everything that holds it (the
         engine and its programs) treats it as one pytree.  Page pools are
         ``[num_pages, page_size, width]`` of ``dtype`` (page 0 = the
-        never-read null page); a recurrent layer's state is float32 and
-        indexed by ``lanes``."""
-        if self.recurrent and lanes < 1:
-            raise ValueError("a recurrent model's cache is sized by its lanes")
+        never-read null page); what a lane carries (a recurrent layer's
+        state, a window) is float32 and indexed by ``lanes``."""
+        if self.lane_state and lanes < 1:
+            raise ValueError("a cache that lanes carry rows of is sized by its lanes")
 
         def shape(s: BlockSpec, name: str):
             if name == "ssm":
                 return (lanes,) + s.state_shape
+            if name == "conv" and s.attention == "cca":
+                rows, channels = cca_window_shape(s, self.num_heads, self.head_dim)
+                return (lanes, rows * channels)
             if name == "conv":
                 return (lanes, s.ssm_conv - 1, s.conv_channels)
             if name == "rows":
@@ -2041,7 +2366,7 @@ class TransformerPolicy(nn.Module):
                 has_next = has_next & (seg > 0) & (jnp.roll(seg, -1, axis=1) == seg)
             has_next = jnp.broadcast_to(has_next, (B, T))
         call = Call.of(
-            recurrent=self.recurrent, segment_kernel=self.segment_attn_fn is not None,
+            lane_state=self.lane_state, segment_kernel=self.segment_attn_fn is not None,
             mtp=mtp, paged_cache=paged_cache, attn_mask=attn_mask,
             segment_ids=segment_ids, page_ids=page_ids, page_offsets=page_offsets,
             page_table=page_table, attn_lengths=attn_lengths,
@@ -2081,6 +2406,7 @@ class TransformerPolicy(nn.Module):
             x = x + pos_tab[positions].astype(self.dtype)
         x = c(x)
         entries = _layer_entries(paged_cache, specs) if call.paged else [None] * len(specs)
+        r = None  # the stack's second stream (:class:`_Layer`)
         for i, layer in enumerate(specs):
             block = _LAYERS[layer.layer](
                 self.d_model, self.num_heads, self.mlp_ratio, attn,
@@ -2089,7 +2415,7 @@ class TransformerPolicy(nn.Module):
                 segment_attn_fn=self.segment_attn_fn, spec=layer, rotary=rotary,
                 name=f"block_{i}",
             )
-            x, entries[i] = block(x, call, entries[i])
+            x, entries[i], r = block(x, call, entries[i], r)
             x = c(x)
         final_norm = functools.partial(_norm, spec, jnp.float32)
         policy_head = nn.Dense(self.num_actions, name="policy_head")

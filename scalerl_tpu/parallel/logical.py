@@ -121,6 +121,18 @@ PARAM_LOGICAL_AXES: Dict[Tuple[str, ...], Tuple[Optional[str], ...]] = {
     # the shared expert's scalar ``shared_gate`` replicate like them.
     ("ba_proj", "kernel"): ("embed", None),
     ("shared_gate", "kernel"): ("embed", None),
+    # The zaya stack: its compressed convolutional attention's ``q`` and
+    # ``proj`` take the rules above; ``k`` (two key heads) and the two
+    # half-width value projections ``v1`` / ``v2`` replicate like ``kv``,
+    # with the convolutions' taps, the grouped taps (a head's own matrix:
+    # sharding them would follow ``q``'s heads, which no mesh here asks
+    # for), the key temperature and the residual merges' vectors (no
+    # rule).  The router is an MLP of width 256 (``reduce``, ``fc1``,
+    # ``fc2``, ``score``), replicated like every other router.
+    ("k", "kernel"): ("embed", None),
+    ("v1", "kernel"): ("embed", None),
+    ("v2", "kernel"): ("embed", None),
+    ("reduce", "kernel"): ("embed", None),
 }
 
 

@@ -116,6 +116,9 @@ def build_genrl_model(args: GenRLArguments) -> TransformerPolicy:
         ssm_conv=args.ssm_conv,
         ssm_chunk=args.ssm_chunk,
         rotary_dim=args.rotary_dim,
+        cca_time0=args.cca_time0,
+        cca_time1=args.cca_time1,
+        router_width=args.router_hidden,
     )
     if args.layer_pattern:
         layers = pattern_specs(spec, args.layer_pattern)

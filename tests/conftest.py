@@ -44,3 +44,13 @@ assert jax.default_backend() == "cpu", (
     "tests must run on CPU; got " + jax.default_backend()
 )
 assert len(jax.devices()) == 8, "expected 8 virtual CPU devices"
+
+# Files that take minutes and sort last by name: under ``--dist loadfile`` a
+# file is one worker's from start to end and files are handed out in
+# collection order, so such a file would start when the others are nearly
+# done and run on alone.  They go first; every other file keeps its place.
+_STARTS_FIRST = ("test_zaya_block.py",)
+
+
+def pytest_collection_modifyitems(items):
+    items.sort(key=lambda item: item.path.name not in _STARTS_FIRST)

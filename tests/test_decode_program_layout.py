@@ -22,8 +22,10 @@ its train state that a post-hoc guard costs (ISSUE 33), and a hybrid
 stack's decode, prefill and fork programs are read for a copy of a Mamba
 layer's recurrent state, which has to be carried in place beside the
 pools (ISSUE 40), and a Gated DeltaNet stack's for a second read or a
-copy of a layer's matrix state (ISSUE 42): this is the one tier-1 file that loads the TPU's
-compiler outside ``tests/benchmark``.
+copy of a layer's matrix state (ISSUE 42), and a compressed convolutional
+attention stack's for a copy of a layer's window, which a decoded token
+has to be written into where it lies (ISSUE 46): this is the one tier-1
+file that loads the TPU's compiler outside ``tests/benchmark``.
 """
 
 import contextlib
@@ -420,6 +422,95 @@ def test_delta_prefill_and_fork_leave_the_state_in_place(delta_engine, one_chip,
         fn, extra = eng._fork_fn(A), [(A,)] * 4
     text = _compiled_text(eng, one_chip, fn, params=program != "fork", extra=extra)
     _assert_delta_state_in_place(text, eng)
+
+
+# -- a window beside a layer's own pools (ISSUE 46) ---------------------------
+
+
+@pytest.fixture(scope="module")
+def cca_engine():
+    """Two ``zaya`` layers at ZAYA1's attention sizes (8 query heads over 2
+    key/value heads of 128, 64 rotating features, both convolutions at 2
+    taps: a window of ``[4, 2, 1408]`` float32 a layer, ring-ordered) on a
+    hidden size of 256, 4 experts of 128 behind a router of width 64, 4
+    lanes; pools ``[301, 8, 256]``.  The paged kernel is pinned compiled."""
+    vocab = 128
+    spec = block_spec(
+        "zaya", head_dim=128, norm_eps=1e-5, rope_theta=5e6, num_experts=4,
+        experts_per_token=1, expert_width=128, kv_heads=2, rotary_dim=64,
+        cca_time0=2, cca_time1=2, router_width=64,
+    )
+    model = TransformerPolicy(
+        num_actions=vocab, vocab_size=vocab, d_model=256, num_heads=8, num_layers=2,
+        max_len=256, block=spec,
+        paged_attn_fn=functools.partial(paged_decode_attention, interpret=False),
+    )
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
+    return ContinuousEngine(
+        model, params,
+        ContinuousConfig(
+            vocab_size=vocab, max_prompt_len=64, max_new_tokens=64,
+            lanes=LANES, page_size=PAGE, num_pages=PAGES, steps_per_macro=2,
+        ),
+        iter_mode="scan",
+    )
+
+
+def _assert_window_in_place(text, eng):
+    """No ``copy`` or ``transpose`` of the shape of a layer's window or of
+    a pool: a decoded token is scattered over the window's oldest row
+    where it lies (a ring), so nothing shifts."""
+    cache = eng._pools
+    whole = {",".join(str(d) for d in x.shape) for x in cache.k + cache.v + cache.conv}
+    moved = [
+        line.strip()[:160]
+        for line in text.splitlines()
+        for m in [re.search(r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(", line)]
+        if m and m.group(1) in whole
+    ]
+    assert not moved, f"{len(moved)} whole-window or whole-pool copies, the first: {moved[0]}"
+
+
+def test_cca_decode_carries_the_window_in_place(cca_engine, one_chip):
+    """The decode macro-step of a stack of compressed convolutional
+    attention layers: one ``paged_decode`` a layer at 8 query heads over 2
+    key/value heads of 128, every pool and window donated and returned as
+    itself, none copied (a window kept oldest-first shifts by a row a
+    token and is copied whole, twice a layer a substep: PERF.md, PR 46),
+    and the two scopes the benchmark's AOT script counts by are in the
+    compiled text's ``op_name``."""
+    eng = cca_engine
+    M = eng._table.shape[1]
+    text = _compiled_text(eng, one_chip, eng._decode_fn, params=True, extra=[(LANES, M), "key"])
+    assert len(re.findall(r"custom-call\(.*paged_decode", text)) == 2
+    assert "/cca_window/" in text and "/zaya_router/" in text
+    _assert_window_in_place(text, eng)
+    _assert_pools_read_in_place(text, eng)
+    leaves = len(jax.tree_util.tree_leaves(eng._snapshot_params()[0]))
+    header = text[text.index("input_output_alias={"):].split("\n", 1)[0]
+    aliased = {
+        int(out): int(param)
+        for out, param in re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
+    }
+    cache = len(jax.tree_util.tree_leaves(eng._pools))
+    assert cache == 2 + 2 + 2 and eng._pools.ssm == ()  # K, V and a window a layer
+    for i in range(cache):
+        assert aliased.get(i) == leaves + i, (i, aliased)
+
+
+@pytest.mark.parametrize("program", ["local_prefill", "fork"])
+def test_cca_prefill_and_fork_leave_the_window_in_place(cca_engine, one_chip, program):
+    """The prefill (the window written at the true length, ring-ordered)
+    and the fork compile for the chip and copy no whole window or pool."""
+    eng = cca_engine
+    A, P = 2, 64
+    if program == "local_prefill":
+        fn = eng._prefill_fn(("local", P, A))
+        extra = [(A, P), (A,), (A,), (A, P), (A, P)]
+    else:
+        fn, extra = eng._fork_fn(A), [(A,)] * 4
+    text = _compiled_text(eng, one_chip, fn, params=program != "fork", extra=extra)
+    _assert_window_in_place(text, eng)
 
 
 # -- the fused classic loop (ISSUE 31) ---------------------------------------

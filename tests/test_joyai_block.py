@@ -693,7 +693,7 @@ def test_the_shares_add_up_to_the_uncut_layer(n_tokens):
     apply = jax.jit(
         lambda w, first: block(
             dataclasses.replace(spec, experts_held=1, first_expert=first)
-        ).apply({"params": w}, x, masked)[0],  # (out, no cache)
+        ).apply({"params": w}, x, masked)[0],  # (out, no cache, no second stream)
         static_argnums=1,
     )
     total = sum(apply(sliced(first, 1), first) for first in range(shares))

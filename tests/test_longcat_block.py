@@ -522,7 +522,7 @@ def test_the_shares_add_up_to_the_uncut_layer(n_tokens):
     total = 0.0
     for first in (0, HELD):
         share = dataclasses.replace(spec, experts_held=HELD, first_expert=first)
-        out, none = block(share).apply({"params": sliced(first, HELD)}, x, masked)
+        out, none, _r = block(share).apply({"params": sliced(first, HELD)}, x, masked)  # no second stream
         assert none is None  # a layer on no cache hands none back
         total = total + out
     nothing, _p, _w, _g = ref.layer(

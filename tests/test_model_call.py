@@ -4,9 +4,9 @@ into (:class:`ModelCache`), on one tiny model a layer family
 (``tests/tiny_families.py``).
 
 The cache's leaves (shapes, dtypes, order) are written out below as they
-were at 2f98510, when the cache was three classes: a program's arguments
-are the cache's leaves, so a leaf that moved would move every rollout
-program's arguments with it.
+were at 2f98510, when the cache was three classes (``zaya``: as the PR
+that added it made them): a program's arguments are the cache's leaves, so
+a leaf that moved would move every rollout program's arguments with it.
 """
 
 import dataclasses
@@ -32,6 +32,9 @@ LEAVES = {
     ),
     "qwen3next": dict(  # L L L F L: one attention, four delta-rule layers
         k=[_POOL(32)], v=[_POOL(32)], ssm=[(LANES, 4, 8, 8)] * 4, conv=[(LANES, 3, 64)] * 4,
+    ),
+    "zaya": dict(  # every layer an attention with pages AND a window, no state (ISSUE 46)
+        k=[_POOL(16)] * 3, v=[_POOL(16)] * 3, conv=[(LANES, 2 * 56)] * 3,
     ),
 }
 FAMILIES = sorted(LEAVES)
@@ -63,7 +66,7 @@ def test_every_family_caches_into_the_one_class_with_the_leaves_it_had(family):
     model = MODELS[family]
     for name in ModelCache._fields:
         assert sum(s.owns.get(name, 0) for s in model.layer_specs) == len(want.get(name, []))
-    if model.recurrent:
+    if model.lane_state:
         with pytest.raises(ValueError, match="sized by its lanes"):
             model.init_paged_cache(PAGES, PAGE)
     else:  # no lanes to size anything by
@@ -97,7 +100,7 @@ def test_a_fork_copies_pages_and_lanes_whatever_the_family(family):
 
 def _refused(family):
     """Argument sets that spell none of the six forms, for this family."""
-    recurrent = MODELS[family].recurrent
+    recurrent = MODELS[family].lane_state  # a recurrent layer, or a window alone
     z = jnp.zeros((2, 4), jnp.int32)
     lanes = jnp.zeros((2,), jnp.int32)
     paged = dict(page_ids=z, page_offsets=z)
@@ -132,7 +135,7 @@ def test_an_argument_set_that_spells_no_form_is_refused_by_the_model(family, cas
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), tokens))
     if on_cache:
         kw = dict(kw, paged_cache=_cache(family))
-    with pytest.raises(ValueError, match="call|recurrent layer has no tail prefill"):
+    with pytest.raises(ValueError, match="call|carries lane state has no tail prefill"):
         jax.eval_shape(lambda: model.apply(params, tokens, positions=tokens, **kw))
 
 
@@ -142,9 +145,9 @@ _ARRAYS = dict.fromkeys(
 )
 
 
-def _of(recurrent=False, segment_kernel=False, mtp=False, paged_cache=None, **given):
+def _of(lane_state=False, segment_kernel=False, mtp=False, paged_cache=None, **given):
     return Call.of(
-        recurrent=recurrent, segment_kernel=segment_kernel, mtp=mtp, paged_cache=paged_cache,
+        lane_state=lane_state, segment_kernel=segment_kernel, mtp=mtp, paged_cache=paged_cache,
         **{**_ARRAYS, **given},
     )
 
@@ -171,17 +174,17 @@ def test_the_six_forms_are_spelled_by_their_arguments_and_by_nothing_else():
     assert dense.mode == "masked" and dense.segment_ids is None
     want = (seg[:, :, None] == seg[:, None, :]) & (seg[:, :, None] > 0) & np.tril(np.ones((4, 4), bool))
     np.testing.assert_array_equal(dense.attn_mask, want)
-    assert dense.runs is None and dense.real is None  # only a recurrent model reads them
-    # a recurrent model is told which tokens are real and where runs start
-    rec = _of(recurrent=True, segment_ids=seg)
+    assert dense.runs is None and dense.real is None  # only a model whose lanes carry state reads them
+    # a model whose lanes carry state is told which tokens are real and where runs start
+    rec = _of(lane_state=True, segment_ids=seg)
     np.testing.assert_array_equal(rec.real, seg > 0)
     np.testing.assert_array_equal(rec.runs, run_ids(seg))
     prompt = mask & (jnp.arange(4)[None, None, :] < jnp.asarray([3, 4])[:, None, None])
-    pre = _of(recurrent=True, attn_mask=prompt, state_lanes=lanes, **paged)
+    pre = _of(lane_state=True, attn_mask=prompt, state_lanes=lanes, **paged)
     assert pre.mode == "prefill" and pre.state_lanes is lanes
     np.testing.assert_array_equal(pre.real, [[1, 1, 1, 0], [1, 1, 1, 1]])
     np.testing.assert_array_equal(pre.runs, [[1, 1, 1, 1], [1, 1, 1, 1]])  # a pad rides the last run
-    dec = _of(recurrent=True, page_table=table, attn_lengths=lanes, **paged)
+    dec = _of(lane_state=True, page_table=table, attn_lengths=lanes, **paged)
     assert dec.mode == "decode" and dec.runs is None and dec.real is None
     with pytest.raises(dataclasses.FrozenInstanceError):
         dec.mode = "tail"  # decided once

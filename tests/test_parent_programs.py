@@ -20,7 +20,8 @@ The digests were taken on the parent of the PR that added each family's
 successor (gpt2 and olmoe ``forward`` / ``decode`` / ``tail_prefill`` at
 fed845a; gpt2, olmoe ``packed`` / ``values`` and longcat at e6c85c7; joyai
 and ``paged_decode.kernel`` at 687c51e; nemotron and
-``paged_decode.grouped_kernel`` at 5a380d0; qwen3next at 2f98510) with this
+``paged_decode.grouped_kernel`` at 5a380d0; qwen3next at 2f98510; zaya on
+the tree of the PR that added it, ISSUE 46: the next PR's parent) with this
 environment's JAX, and have passed unchanged on every commit since.  After
 a JAX upgrade, take them again from a commit known to be unchanged.
 """
@@ -63,6 +64,10 @@ PARENT = {
     "qwen3next.prefill": "ba95ed35f45d7a32",
     "qwen3next.decode": "acc15eb4bd6b44ab",
     "qwen3next.values": "1807276d17a77dd9",
+    "zaya.packed": "3998a795aa8babb3",
+    "zaya.prefill": "089f5d1a6a55e63b",
+    "zaya.decode": "ac11e3213a475442",
+    "zaya.values": "c3b4e6916bc10194",
     "paged_decode.kernel": "a55e78ecad7aa6ba",
     "paged_decode.grouped_kernel": "4d009f7757b4473a",
 }

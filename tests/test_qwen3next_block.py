@@ -185,13 +185,13 @@ def test_program_arguments_choose_the_family(net):
 def test_arguments_the_family_refuses():
     with pytest.raises(ValueError, match="full_attention_interval in"):
         _args(cfg={**CFG, "full_attention_interval": 0})
-    with pytest.raises(ValueError, match="recurrent layer .* no cursor to rewind"):
+    with pytest.raises(ValueError, match="carries lane state .* no cursor to rewind"):
         _args("--spec-enable", "true")
     with pytest.raises(ValueError, match="qwen3_next family's"):
         parse_args(GenRLArguments, ["--full-attention-interval", "4"]).validate()
     with pytest.raises(ValueError, match="qwen3_next family's"):
         parse_args(GenRLArguments, ["--block-family", "olmoe", "--rotary-dim", "4"]).validate()
-    with pytest.raises(ValueError, match="one of the six families"):
+    with pytest.raises(ValueError, match="one of the seven families"):
         parse_args(GenRLArguments, ["--block-family", "qwen4"]).validate()
     with pytest.raises(ValueError, match="gpt2 \\| olmoe \\| longcat \\| joyai \\| nemotron_h \\| qwen3_next"):
         block_spec("qwen4")
@@ -609,7 +609,7 @@ def test_a_forked_member_continues_bit_for_bit_as_its_leader(net):
 
 def test_speculation_is_refused_for_a_recurrent_model(net):
     model, params = net
-    with pytest.raises(ValueError, match="a recurrent layer: .* no cursor to rewind"):
+    with pytest.raises(ValueError, match="a layer that carries lane state: .* no cursor to rewind"):
         _engine(model, params, spec_k=2)
     # and the model refuses the tail prefill a hit or a verify would ride
     cache = model.init_paged_cache(6, 4, lanes=2)

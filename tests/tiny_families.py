@@ -2,7 +2,9 @@
 every family to one rule (``test_parent_programs.py``, ``test_model_call.py``)
 run on.  Widths are the smallest at which every branch of a family's layer
 is taken: 4 heads, 8 experts with 3 a token of which 4 are held, latent
-ranks 24 and 16, 4 state heads of 8 in 2 groups and a chunk of 8."""
+ranks 24 and 16, 4 state heads of 8 in 2 groups and a chunk of 8; ``zaya``:
+4 heads over 2 of 8, both convolutions at 2 taps, a router of width 8 with
+one pick of 4 experts."""
 
 from scalerl_tpu.models.transformer import (
     TransformerPolicy,
@@ -50,5 +52,10 @@ MODELS = {
         head_dim=16, norm_eps=1e-6, rope_theta=1e7, num_experts=8, experts_per_token=3,
         expert_width=16, norm_topk_prob=True, experts_held=4, shared_experts=1,
         shared_width=16, kv_heads=2, ssm_state=8, rotary_dim=4, **_STATE,
+    ),
+    "zaya": _model(
+        "zaya", d_model=32, num_layers=3, head_dim=8, norm_eps=1e-5, rope_theta=5e6,
+        num_experts=4, experts_per_token=1, expert_width=16, kv_heads=2, rotary_dim=4,
+        cca_time0=2, cca_time1=2, router_width=8,
     ),
 }
