@@ -379,7 +379,10 @@ def _note_guard(leaves: int, state_bytes: int) -> None:
 
 
 def make_token_ppo_learn_fn(
-    model: TransformerPolicy, optimizer: optax.GradientTransformation, args
+    model: TransformerPolicy,
+    optimizer: optax.GradientTransformation,
+    args,
+    shard_update=None,
 ) -> Callable:
     """Build the pure ``(state, batch) -> (state, metrics)`` update, with
     the all-finite guard folded into it.
@@ -438,6 +441,18 @@ def make_token_ppo_learn_fn(
     guard out (``train_step.nonfinite_guard_enabled``);
     ``nonfinite_check_every`` is not read here: a select inside the
     update's own pass leaves nothing to amortise.
+
+    **The update sharded over ``dp``** (ISSUE 48).  ``shard_update`` is
+    ``parallel/logical.update_sharding``'s pair of closures, which
+    ``TokenPPOAgent.enable_mesh`` builds where the mesh's ``dp`` extent is
+    over 1 (None anywhere else: the program is then the one above, text for
+    text).  The gradients take the moments' layout BEFORE the norm reads
+    them, so each weight gradient's ``dp`` reduction is a reduce-scatter,
+    the norm a sum of squares over local shards and one scalar reduction,
+    and clip, Adam and the guard's select a pass over this replica's rows;
+    the chosen parameters are gathered back to the layout the forward
+    reads.  The arithmetic is the same to the last operation but for the
+    order in which two replicas' partial sums meet.
     """
     from scalerl_tpu.parallel.train_step import (
         nonfinite_guard_enabled,
@@ -467,6 +482,8 @@ def make_token_ppo_learn_fn(
             adv_norm=args.adv_norm,
             router_aux_coef=getattr(args, "router_aux_loss_coef", 0.0),
         )
+        if shard_update is not None:
+            grads = shard_update.scatter(grads)
         metrics["grad_norm"] = optax.global_norm(
             jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
         )
@@ -491,6 +508,8 @@ def make_token_ppo_learn_fn(
             bad = 1.0 - ok.astype(jnp.float32)
             metrics["nonfinite_grads"] = bad
             metrics["skipped_steps"] = bad
+        if shard_update is not None:
+            new["params"] = shard_update.gather(new["params"])
         return state.replace(**new), metrics
 
     return learn
@@ -550,15 +569,29 @@ class TokenPPOAgent:
             tx = fp32_optimizer_state(tx)
         return tx
 
-    def make_learn_fn(self) -> Callable:
+    def make_learn_fn(self, shard_update=None) -> Callable:
         """Learn fn from this agent's model/optimizer/args (the
         ``enable_mesh`` rebuild contract, ``agents/impala.py``)."""
-        return make_token_ppo_learn_fn(self.model, self.optimizer, self.args)
+        return make_token_ppo_learn_fn(
+            self.model, self.optimizer, self.args, shard_update=shard_update
+        )
 
     def enable_mesh(self, mesh_or_spec, batch_example=None) -> None:
         """Shard the learn step over a device mesh; with ``mp > 1`` the
         transformer's heads/mlp/vocab dims lay out per the logical rule
-        table and inter-layer activations pin batch-over-dp."""
+        table and inter-layer activations pin batch-over-dp.
+
+        With ``dp > 1`` the WEIGHT UPDATE is sharded over ``dp`` as well
+        (``parallel/logical.mp_param_sharding``'s ``update_axis``): each
+        replica keeps, beside what ``mp`` gave it, its ``1/dp`` of both Adam
+        moments, receives that share of the gradients' sum (a
+        reduce-scatter where the replicated update all-reduced), clips and
+        updates those rows and all-gathers the new parameters.  Parameters
+        and the frozen reference stay whole over ``dp`` at rest, so what the
+        forward, a checkpoint and ``get_weights`` read is laid out as
+        before.  A mesh whose ``dp`` is 1 builds the program it always
+        built; so does one with an ``fsdp`` or ``tp`` axis, whose state the
+        heuristic rule already shards over an axis of its own."""
         from scalerl_tpu.parallel import (
             activation_constraint,
             has_mp_params,
@@ -566,9 +599,14 @@ class TokenPPOAgent:
             mp_param_sharding,
             resolve_mesh,
         )
+        from scalerl_tpu.parallel.logical import (
+            update_sharding,
+            update_sharding_counts,
+        )
 
         mesh = resolve_mesh(mesh_or_spec)
         param_specs = None
+        shard_update = None
         meshed = {}  # model fields that depend on the mesh
         if self.model.segment_attn_fn is not None:
             # the packed-row kernel is a Mosaic call, which GSPMD cannot
@@ -578,7 +616,8 @@ class TokenPPOAgent:
             meshed["segment_attn_fn"] = shard_segment_attn(
                 self.model.segment_attn_fn, mesh
             )
-        if mesh.shape.get("mp", 1) > 1:
+        mp = mesh.shape.get("mp", 1) > 1
+        if mp:
             if not has_mp_params(self.state.params):
                 raise ValueError(
                     "mesh has mp > 1 but the model carries no "
@@ -586,16 +625,29 @@ class TokenPPOAgent:
                 )
             if self.model.constrain is None:
                 meshed["constrain"] = activation_constraint(mesh)
-            param_specs = mp_param_sharding(self.state, mesh)
+        if mp or all(mesh.shape.get(a, 1) == 1 for a in ("fsdp", "tp")):
+            shard_update = update_sharding(self.state.params, mesh, "dp")
+        if mp or shard_update is not None:
+            # (where ``dp`` is 1 the axis changes no leaf's spec)
+            param_specs = mp_param_sharding(self.state, mesh, update_axis="dp")
         if meshed:
             self.model = self.model.clone(**meshed)
-            self._learn_fn = self.make_learn_fn()
+        if meshed or shard_update is not None:
+            self._learn_fn = self.make_learn_fn(shard_update)
         plearn = make_parallel_learn_fn(
             self._learn_fn, mesh, self.state,
             batch_example=batch_example,
             batch_time_major=False,  # packed batches are [B, ...]
             param_specs=param_specs,
         )
+        if shard_update is not None:
+            # a layout engages on every step and has no hit rate: one
+            # zero-length span a sharded learn program built
+            with tracing.span(
+                "learn.update_sharding", kind="learn",
+                **update_sharding_counts(self.state.opt_state, param_specs.opt_state, "dp"),
+            ):
+                pass
         self.mesh = mesh
         self.state = plearn.shard_state(self.state)
         self._learn = plearn

@@ -26,12 +26,13 @@ models, small heads degrade gracefully).
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Tuple
+import math
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from scalerl_tpu.parallel.sharding import _path_names
+from scalerl_tpu.parallel.sharding import _path_names, holds_axis
 
 # The model-parallel mesh axis of the dp×mp learner plane.
 MP_AXIS = "mp"
@@ -186,16 +187,103 @@ def mp_param_spec(
     return logical_to_spec(axes, leaf.shape, mesh, rules)
 
 
+def with_update_axis(spec: P, shape: Tuple[int, ...], mesh: Mesh, axis: str) -> P:
+    """``spec`` with mesh axis ``axis`` on one more dimension: the largest
+    one that the axis's extent divides and that no mesh axis holds yet (the
+    first of equals).  ``spec`` itself where the extent is 1 or no such
+    dimension is left (a scalar, an odd width, a vector ``mp`` already has):
+    that leaf stays replicated over ``axis``."""
+    n = mesh.shape.get(axis, 1)
+    parts = list(spec) + [None] * (len(shape) - len(spec))
+    free = [d for d, held in enumerate(parts) if held is None and shape[d] % n == 0]
+    if n <= 1 or not free:
+        return spec
+    parts[max(free, key=lambda d: shape[d])] = axis
+    return P(*parts)
+
+
 def mp_param_sharding(
     tree: Any,
     mesh: Mesh,
     rules: Optional[Dict[str, Optional[str]]] = None,
+    update_axis: Optional[str] = None,
+    update_under: Optional[Tuple[str, ...]] = ("opt_state",),
 ) -> Any:
     """Per-leaf ``NamedSharding`` pytree for a train state under the
-    logical rule table (heads/mlp/vocab/experts over ``mp``)."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, x: NamedSharding(mesh, mp_param_spec(path, x, mesh, rules)),
-        tree,
+    logical rule table (heads/mlp/vocab/experts over ``mp``).
+
+    ``update_axis`` names the mesh axis the WEIGHT UPDATE is sharded over
+    (cross-replica weight-update sharding, Xu et al. arXiv 2004.13336; ZeRO
+    stage 1): every leaf whose path passes through a field of
+    ``update_under`` (the optimiser's moments; every leaf of ``tree`` where
+    it is None, for a gradient tree) takes that axis too, on the dimension
+    :func:`with_update_axis` names.  Without it, and on a mesh whose
+    ``update_axis`` has extent 1, every leaf is replicated over ``dp`` as
+    before."""
+
+    def leaf_sharding(path, x):
+        spec = mp_param_spec(path, x, mesh, rules)
+        if update_axis is not None and (
+            update_under is None or set(update_under) & set(_path_names(path))
+        ):
+            spec = with_update_axis(spec, getattr(x, "shape", ()), mesh, update_axis)
+        return NamedSharding(mesh, spec)
+
+    return jax.tree_util.tree_map_with_path(leaf_sharding, tree)
+
+
+class UpdateSharding(NamedTuple):
+    """The learn step's two layout changes where the weight update is
+    sharded over a mesh axis: ``scatter`` takes a parameter-shaped tree (the
+    gradients) to the moments' layout, which makes their reduction over that
+    axis a reduce-scatter and everything computed from them a shard's work;
+    ``gather`` takes the updated parameters back to the parameters' layout
+    (an all-gather of each updated shard)."""
+
+    scatter: Callable[[Any], Any]
+    gather: Callable[[Any], Any]
+
+
+def update_sharding(
+    params: Any,
+    mesh: Mesh,
+    axis: str = "dp",
+    rules: Optional[Dict[str, Optional[str]]] = None,
+) -> Optional[UpdateSharding]:
+    """:class:`UpdateSharding` closures for ``params`` on ``mesh``, or None
+    where ``axis`` has extent 1 (one replica has nobody to share with).
+    Like :func:`activation_constraint` they carry the mesh inside each
+    ``NamedSharding`` and work under a plain ``jax.jit``."""
+    if mesh.shape.get(axis, 1) <= 1:
+        return None
+    at_rest = mp_param_sharding(params, mesh, rules)
+    sharded = mp_param_sharding(params, mesh, rules, update_axis=axis, update_under=None)
+    return UpdateSharding(
+        scatter=lambda tree: jax.lax.with_sharding_constraint(tree, sharded),
+        gather=lambda tree: jax.lax.with_sharding_constraint(tree, at_rest),
+    )
+
+
+def update_sharding_counts(moments: Any, shardings: Any, axis: str) -> Dict[str, Any]:
+    """What a weight update sharded over ``axis`` did to the optimiser's
+    state, for the ``learn.update_sharding`` span: how many leaves took the
+    axis, how many it divides no free dimension of (they stay replicated),
+    and the state's bytes a device with the axis and without."""
+    leaves = jax.tree_util.tree_leaves(moments)
+    layouts = jax.tree_util.tree_leaves(shardings)
+    extent = layouts[0].mesh.shape[axis]
+    sharded, before, after = 0, 0, 0
+    for x, sh in zip(leaves, layouts, strict=True):
+        held = holds_axis(sh, axis)
+        local = math.prod(sh.shard_shape(x.shape)) * x.dtype.itemsize
+        sharded += held
+        after += local
+        before += local * (extent if held else 1)
+    return dict(
+        axis=axis, extent=extent, leaves_sharded=sharded,
+        leaves_replicated=len(leaves) - sharded,
+        moment_bytes_per_device_before=before, moment_bytes_per_device_after=after,
+        params_at_rest="gathered",
     )
 
 

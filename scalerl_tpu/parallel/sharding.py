@@ -21,6 +21,12 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+def holds_axis(sharding: Any, axis: str) -> bool:
+    """Does a ``NamedSharding``'s spec put mesh axis ``axis`` on any
+    dimension, alone or in a tuple of axes?"""
+    return axis in jax.tree_util.tree_leaves(tuple(getattr(sharding, "spec", ())))
+
+
 def batch_sharding(mesh: Mesh, batch_dim: int = 0) -> NamedSharding:
     """Shard dim ``batch_dim`` over the data-parallel axes ``(dp, fsdp)``.
 

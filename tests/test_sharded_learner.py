@@ -36,7 +36,13 @@ from scalerl_tpu.parallel import (
     mesh_spec_from_args,
     mp_param_sharding,
 )
-from scalerl_tpu.parallel.logical import logical_to_spec, mp_param_spec
+from scalerl_tpu.parallel.logical import (
+    logical_to_spec,
+    mp_param_spec,
+    update_sharding_counts,
+    with_update_axis,
+)
+from scalerl_tpu.parallel.sharding import holds_axis
 
 
 def _impala_args(**kw):
@@ -130,20 +136,75 @@ def test_logical_to_spec_never_double_maps_an_axis():
     assert named.count("mp") == 1
 
 
-def test_opt_state_moments_inherit_param_layout():
+@pytest.mark.parametrize("dp", [1, 2])
+def test_opt_state_moments_inherit_param_layout(dp):
+    """The moments take their parameter's ``mp`` layout and, where the
+    update is sharded over a ``dp`` of extent 2, ``dp`` on the largest
+    dimension it divides and ``mp`` does not hold; at extent 1 their specs
+    are the parameters' own.  Parameters never take it, and a leaf no
+    dimension of which ``dp`` divides stays replicated and is counted."""
     args = _transformer_args()
     agent = _make_agent(args)
-    mesh = make_mesh("dp=4,mp=2")
-    sh = mp_param_sharding(agent.state, mesh)
+    mesh = make_mesh(f"dp={dp},mp=2", jax.devices()[: 2 * dp])
+    sh = mp_param_sharding(agent.state, mesh, update_axis="dp")
     flat = {
         jax.tree_util.keystr(path): s
         for path, s in jax.tree_util.tree_flatten_with_path(sh)[0]
     }
-    qkv_param = [k for k in flat if "qkv" in k and "opt_state" not in k]
-    qkv_moment = [k for k in flat if "qkv" in k and "opt_state" in k]
-    assert qkv_param and qkv_moment
+    qkv_param = [k for k in flat if "qkv']['kernel" in k and "opt_state" not in k]
+    qkv_moment = [k for k in flat if "qkv']['kernel" in k and "opt_state" in k]
+    assert qkv_param and len(qkv_moment) == 2 * len(qkv_param)
     assert all(flat[k].spec == P(None, "mp") for k in qkv_param)
-    assert all(flat[k].spec == P(None, "mp") for k in qkv_moment)
+    held = "dp" if dp > 1 else None
+    assert all(flat[k].spec == P(held, "mp") for k in qkv_moment)
+    plain = mp_param_sharding(agent.state, mesh)
+    if dp == 1:
+        assert jax.tree_util.tree_leaves(sh) == jax.tree_util.tree_leaves(plain)
+        return
+    # everything outside the optimiser's state is laid out as without it
+    for k, s in flat.items():
+        if "opt_state" not in k:
+            assert not holds_axis(s, "dp"), k
+    # [32, 32] under P("mp", None): the free dimension; [4, 32]: the larger
+    (proj,) = {flat[k].spec for k in flat if "proj']['kernel" in k and "opt_state" in k}
+    assert proj == P("mp", "dp")
+    (embed,) = {flat[k].spec for k in flat if "obs_embed']['kernel" in k and "opt_state" in k}
+    assert embed == P(None, "dp")
+    counts = update_sharding_counts(agent.state.opt_state, sh.opt_state, "dp")
+    moments = jax.tree_util.tree_leaves(agent.state.opt_state)
+    undivided = [x for x in moments if x.ndim == 0 or all(d % 2 for d in x.shape)]
+    assert undivided  # the chain's ``count``, odd heads
+    # (a vector that ``mp`` holds has no dimension left for ``dp`` either)
+    assert counts["leaves_replicated"] >= len(undivided)
+    assert counts["leaves_sharded"] + counts["leaves_replicated"] == len(moments)
+    assert counts["axis"] == "dp" and counts["extent"] == 2
+    assert counts["params_at_rest"] == "gathered"
+    whole = sum(
+        int(np.prod(s.shard_shape(x.shape))) * x.dtype.itemsize
+        for x, s in zip(moments, jax.tree_util.tree_leaves(plain.opt_state))
+    )
+    assert counts["moment_bytes_per_device_before"] == whole
+    assert whole / 2 < counts["moment_bytes_per_device_after"] < whole
+
+
+@pytest.mark.parametrize(
+    "spec, shape, axes, want",
+    [
+        (P(None, "mp"), (1280, 3840), {"dp": 2, "mp": 2}, P("dp", "mp")),
+        (P(None, None), (1280, 50257), {"dp": 2, "mp": 2}, P("dp", None)),
+        (P(), (50257, 1280), {"dp": 2, "mp": 2}, P(None, "dp")),
+        (P("mp"), (3840,), {"dp": 2, "mp": 2}, P("mp")),  # no dimension left
+        (P(), (), {"dp": 2, "mp": 2}, P()),
+        (P(), (7, 3), {"dp": 2, "mp": 2}, P()),
+        (P(), (6, 8), {"dp": 4}, P(None, "dp")),  # 4 does not divide 6
+        (P("mp", None, None), (4, 32, 64), {"dp": 2, "mp": 2}, P("mp", None, "dp")),
+        (P(None, "mp"), (32, 96), {"dp": 1, "mp": 2}, P(None, "mp")),
+    ],
+)
+def test_update_axis_takes_the_largest_free_dimension_it_divides(spec, shape, axes, want):
+    from types import SimpleNamespace
+
+    assert with_update_axis(spec, shape, SimpleNamespace(shape=axes), "dp") == want
 
 
 def test_make_shard_and_gather_fns_roundtrip():
@@ -216,27 +277,179 @@ def test_mp_mesh_without_rules_is_rejected():
 
 
 # ---------------------------------------------------------------------------
+# the token learner's weight update, sharded over dp (ISSUE 48)
+
+
+def _token_agent(kind):
+    from tests import tiny_token_learner as tiny
+
+    if kind == "gpt2":
+        return tiny.agent()
+    return tiny.program_agent(*{"routed": tiny.ROUTED, "bf16": ("--bf16-params", "true")}[kind])
+
+
+def _replicated_update(agent, mesh):
+    """The parent's program for ``agent`` on ``mesh``: the learn fn without
+    the hook, the whole state replicated over ``dp``."""
+    from scalerl_tpu.parallel import make_parallel_learn_fn
+
+    return make_parallel_learn_fn(
+        agent.make_learn_fn(), mesh, agent.state, batch_time_major=False,
+        param_specs=mp_param_sharding(agent.state, mesh),
+    )
+
+
+def _dp_specs(tree):
+    return [holds_axis(x.sharding, "dp") for x in jax.tree_util.tree_leaves(tree)]
+
+
+@pytest.mark.parametrize("kind", ["gpt2", "routed", "bf16"])
+@pytest.mark.parametrize("spec", ["dp=2,mp=2", "dp=4"])
+def test_update_sharded_over_dp_equals_the_replicated_update(spec, kind):
+    """One packed learn step (and a second, from moments that have moved,
+    where the weights are float32) with the weight update sharded over
+    ``dp`` against the same step with the parent's layout on the same mesh:
+    parameters, both moments, the counters and every metric, leaf for leaf.
+    The two differ in which replica adds which rows and in nothing else, so
+    float32 leaves agree to a reordered two-term sum (the comparison below
+    says what that leaves open)."""
+    from tests.tiny_token_learner import packed_batch
+
+    mesh = make_mesh(spec, jax.devices()[:4])
+    sharded, plain = _token_agent(kind), _token_agent(kind)
+    sharded.enable_mesh(mesh)
+    want_fn = _replicated_update(plain, mesh)
+    want = want_fn.shard_state(plain.state)
+    # the layout is real: moments hold dp, parameters and the reference do not
+    assert sum(_dp_specs(sharded.state.opt_state)) >= 10
+    assert not any(_dp_specs((sharded.state.params, sharded.state.ref_params)))
+    assert not any(_dp_specs(want))
+    batch = packed_batch()
+    # (a second step of bfloat16 weights starts an ulp apart on a few of
+    # them and its metrics read that, not the layout: one step there)
+    steps = 1 if kind == "bf16" else 2
+    for _ in range(steps):
+        got_metrics = sharded.learn_device(dict(batch))
+        want, want_metrics = want_fn(want, want_fn.shard_batch(dict(batch)))
+    assert set(got_metrics) == set(want_metrics) and "grad_norm" in got_metrics
+    # (bfloat16 activations: the two programs fuse differently on the CPU
+    # and round differently, a part in a thousand of a metric)
+    rtol, atol = (2e-3, 2e-3) if kind == "bf16" else (2e-5, 1e-6)
+    for name in want_metrics:
+        np.testing.assert_allclose(
+            np.asarray(got_metrics[name], np.float32), np.asarray(want_metrics[name], np.float32),
+            rtol=rtol, atol=atol, err_msg=name,
+        )
+    assert int(sharded.state.step) == int(want.step) == steps
+    assert int(sharded.state.tokens_seen) == int(want.tokens_seen)
+    # A leaf agrees to a rounding but for a handful of elements whose
+    # gradient sits within a rounding of zero: Adam's first steps are
+    # ``lr * g / (|g| + eps)``, so there the reordered sum may move a weight
+    # by up to ``lr`` a step.  bfloat16 gradients keep 8 bits and XLA:CPU
+    # fuses the two programs' backward passes differently, so a small
+    # gradient (a norm's scale) reads a few roundings apart on a quarter of
+    # its elements: that case holds the dtypes, the layout and the
+    # ``fp32_optimizer_state`` path leaf by leaf, and each leaf as a whole
+    # to a fiftieth of its norm.
+    loose = 2 * steps * sharded.args.learning_rate
+    got_leaves = jax.tree_util.tree_flatten_with_path(sharded.state)[0]
+    for (path, g), w in zip(got_leaves, jax.tree_util.tree_leaves(want), strict=True):
+        name = jax.tree_util.keystr(path)
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        gap = np.abs(g - w)
+        if kind == "bf16":
+            assert np.linalg.norm(gap) <= 0.02 * np.linalg.norm(w) + 1e-6, name
+            continue
+        off = gap > 2e-6 + 1e-5 * np.abs(w)
+        assert off.mean() <= 1e-3 and gap.max(initial=0.0) <= loose, (
+            name, int(off.sum()), float(gap.max(initial=0.0)),
+        )
+    # after the step the layouts are the ones it started from
+    assert sum(_dp_specs(sharded.state.opt_state)) >= 10
+    assert not any(_dp_specs((sharded.state.params, sharded.state.ref_params)))
+
+
+@pytest.mark.parametrize("spec", ["dp=2,mp=2", "dp=4"])
+def test_a_refused_sharded_update_returns_its_input_state_on_every_shard(spec, monkeypatch):
+    """A NaN planted in ONE element of one weight gradient: one replica's
+    shard of one leaf sees it, the norm's scalar reduction tells every
+    shard, and each keeps its rows of the input state bit for bit."""
+    from tests.tiny_token_learner import agent, packed_batch
+
+    real = jax.value_and_grad
+
+    def planted(fn, **kw):
+        def run(*a, **k):
+            out, grads = real(fn, **kw)(*a, **k)
+            leaves, treedef = jax.tree_util.tree_flatten(grads)
+            wide = max(range(len(leaves)), key=lambda i: leaves[i].size)
+            leaves[wide] = leaves[wide].at[(0,) * leaves[wide].ndim].set(jnp.nan)
+            return out, treedef.unflatten(leaves)
+
+        return run
+
+    learner = agent()
+    learner.enable_mesh(make_mesh(spec, jax.devices()[:4]))
+    shards = lambda tree: [  # noqa: E731 - every device's rows of every leaf
+        (s.device.id, np.array(s.data))
+        for x in jax.tree_util.tree_leaves(tree) for s in x.addressable_shards
+    ]
+    before = shards(learner.state)
+    monkeypatch.setattr(jax, "value_and_grad", planted)
+    metrics = learner.learn(packed_batch())
+    monkeypatch.undo()
+    assert np.isfinite(metrics["total_loss"]) and np.isnan(metrics["grad_norm"])
+    assert metrics["nonfinite_grads"] == metrics["skipped_steps"] == 1.0
+    after = shards(learner.state)
+    assert len(after) == len(before)
+    for (dev_a, a), (dev_b, b) in zip(after, before):
+        assert dev_a == dev_b
+        np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
 # sharded checkpoints
 
 
-def test_sharded_checkpoint_save_restore_resume(tmp_path):
-    args = _transformer_args()
-    agent = _make_agent(args)
-    agent.enable_mesh("dp=4,mp=2")
-    traj = _traj()
+def _checkpoint_case(layout, key=0):
+    """``(agent, batch)``: the classic transformer policy under the ``mp``
+    layout alone, or the token learner with its update sharded over ``dp``
+    as well (``dp``-sharded moments beside ``mp``-sharded parameters)."""
+    if layout == "mp":
+        agent = _make_agent(_transformer_args(), key=key)
+        agent.enable_mesh("dp=4,mp=2")
+        return agent, _traj()
+    from tests.tiny_token_learner import packed_batch, program_agent
+
+    agent = program_agent("--seed", str(key))
+    agent.enable_mesh(make_mesh("dp=2,mp=2", jax.devices()[:4]))
+    return agent, packed_batch()
+
+
+@pytest.mark.parametrize("layout", ["mp", "mp+update_over_dp"])
+def test_sharded_checkpoint_save_restore_resume(tmp_path, layout):
+    agent, traj = _checkpoint_case(layout)
     agent.learn(traj)
     saved_step = int(agent.state.step)
-    saved_params = jax.tree_util.tree_map(np.asarray, agent.state.params)
+    saved = jax.tree_util.tree_map(np.asarray, (agent.state.params, agent.state.opt_state))
+    specs = [x.sharding.spec for x in jax.tree_util.tree_leaves(agent.state)]
     path = str(tmp_path / "ckpt")
     agent.save_checkpoint(path)
 
-    restored = _make_agent(args, key=7)  # different init
-    restored.enable_mesh("dp=4,mp=2")
+    restored, _ = _checkpoint_case(layout, key=7)  # different init
+    assert any(
+        np.any(np.asarray(a) != np.asarray(b))
+        for a, b in zip(
+            jax.tree_util.tree_leaves(saved[0]), jax.tree_util.tree_leaves(restored.state.params)
+        )
+    )
     restored.load_checkpoint(path)
     assert int(restored.state.step) == saved_step
     for a, b in zip(
-        jax.tree_util.tree_leaves(saved_params),
-        jax.tree_util.tree_leaves(restored.state.params),
+        jax.tree_util.tree_leaves(saved),
+        jax.tree_util.tree_leaves((restored.state.params, restored.state.opt_state)),
+        strict=True,
     ):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # layouts survive: the restored state is mp-sharded, not host-replicated
@@ -246,6 +459,10 @@ def test_sharded_checkpoint_save_restore_resume(tmp_path):
         if any(s == "mp" for s in leaf.sharding.spec if s is not None)
     )
     assert n_mp >= 4
+    # ... leaf for leaf, the moments' ``dp`` halves included
+    assert [x.sharding.spec for x in jax.tree_util.tree_leaves(restored.state)] == specs
+    if layout != "mp":
+        assert sum(_dp_specs(restored.state.opt_state)) >= 10
     # and the run RESUMES: the restored sharded state steps again
     m = restored.learn(traj)
     assert np.isfinite(m["total_loss"])
