@@ -125,7 +125,11 @@ from scalerl_tpu.models.transformer import (
     fork_cache,
     prompt_attention_mask,
 )
-from scalerl_tpu.ops.pallas_paged_attention import make_paged_attn_fn
+from scalerl_tpu.ops.pallas_paged_attention import (
+    make_paged_attn_fn,
+    pages_per_block,
+    table_copies,
+)
 from scalerl_tpu.runtime import telemetry, tracing
 from scalerl_tpu.runtime.device_loop import resolve_iter_mode
 from scalerl_tpu.runtime.dispatch import steady_state_guard
@@ -421,7 +425,18 @@ class ContinuousEngine(ParamSnapshotPlane):
             )
         self._pages_per_lane = -(-max_context // ps)  # table width (static)
         num_pages = config.num_pages or (L * self._pages_per_lane + 1)
-        self.allocator = PageAllocator(num_pages, ps)
+        # device state: pools + per-lane decode carry (donated through
+        # every program; the host rebinds after each dispatch).  The
+        # model describes its cache; here it is one pytree of pools
+        # (with lane state: pools and the lane-indexed arrays)
+        self._pools = model.init_paged_cache(num_pages, ps, lanes=L)
+        # what the decode kernels' walk of a pool goes by: a fresh run of
+        # pages starts where it can grow to a block of that walk
+        pool = (self._pools.k + self._pools.rows)[0]
+        self._copy_rule = (ps, pool.shape[2], pool.dtype.itemsize, num_pages)
+        self.allocator = PageAllocator(
+            num_pages, ps, stretch=pages_per_block(*self._copy_rule[:3])
+        )
         self._worst_pages = self.allocator.pages_for_tokens(max_context)
         self._prefix_cache: Optional[PrefixCache] = None
         if config.prefix_cache:
@@ -457,11 +472,6 @@ class ContinuousEngine(ParamSnapshotPlane):
         )
         self._expert_hits = 0  # held experts that received a token, summed
         self._expert_substeps = 0  # over this many (substep, layer) pairs
-        # device state: pools + per-lane decode carry (donated through
-        # every program; the host rebinds after each dispatch).  The
-        # model describes its cache; here it is one pytree of pools
-        # (with lane state: pools and the lane-indexed arrays)
-        self._pools = model.init_paged_cache(num_pages, ps, lanes=L)
         # bytes a lane carries beside pages, all layers together
         self._state_bytes_per_lane = (
             sum(a.nbytes for a in self._pools.ssm + self._pools.conv) // L
@@ -473,6 +483,10 @@ class ContinuousEngine(ParamSnapshotPlane):
             else {}
         )
         self.state_forks = 0  # members whose state rows a fork wrote
+        # how far the decode kernels' copies merge: live pages of the tables
+        # as uploaded, and the copies a pool's walk issues for them
+        self._table_pages = 0
+        self._table_copies = 0
         self.prefix_skipped_recurrent = 0  # admissions that skipped the cache
         self._logits_st = jnp.zeros((L, config.vocab_size), jnp.float32)
         self._value_st = jnp.zeros((L,), jnp.float32)
@@ -713,7 +727,7 @@ class ContinuousEngine(ParamSnapshotPlane):
                 self._shared_counter.inc(len(cached))
             tail_pages = self.allocator.alloc(
                 self.allocator.pages_for_tokens(m) - len(cached),
-                holder=holder,
+                holder=holder, after=cached[-1] if cached else None,
             )
             pages = cached + tail_pages
             self._occupy(leader, req, prompt, m, pages, worst, gen, now)
@@ -744,7 +758,11 @@ class ContinuousEngine(ParamSnapshotPlane):
                     self.allocator.share(mpages, holder=mh)
                     self._shared_counter.inc(n_full)
                 if partial is not None:
-                    copy = self.allocator.alloc(1, holder=mh)[0]
+                    # a run of the member's own: the page after the shared
+                    # ones is the leader's
+                    copy = self.allocator.alloc(
+                        1, holder=mh, after=mpages[-1] if mpages else None
+                    )[0]
                     mpages.append(copy)
                     forks.append((leader, member, partial, copy))
                 else:
@@ -1487,7 +1505,8 @@ class ContinuousEngine(ParamSnapshotPlane):
             delta = need - len(lane.pages)
             if delta > 0:
                 new_pages = self.allocator.alloc(
-                    delta, holder=f"lane[{lane_id}]"
+                    delta, holder=f"lane[{lane_id}]",
+                    after=lane.pages[-1] if lane.pages else None,
                 )
                 start = len(lane.pages)
                 lane.pages.extend(new_pages)
@@ -1533,7 +1552,7 @@ class ContinuousEngine(ParamSnapshotPlane):
                 # so a trace shows the first one on freshly pushed weights
                 with self._dispatch_guard(), tracing.span(
                     "genrl.dispatch", kind="genrl", generation=gen,
-                    **self._dispatch_attrs,
+                    pages_per_copy=self._note_table(), **self._dispatch_attrs,
                 ):
                     self._key, sub = jax.random.split(self._key)
                     # ONE explicit batched host->device upload per macro
@@ -1800,6 +1819,19 @@ class ContinuousEngine(ParamSnapshotPlane):
         self._expert_hits += int((counts[live][:, :, self._held] > 0).sum())
         self._expert_substeps += int(live.sum()) * counts.shape[1]
 
+    def _note_table(self) -> float:
+        """Live pages over copies for the table about to be uploaded (the
+        kernels' rule, reckoned on the host from the host's context
+        lengths), added to the lifetime sums; 1.0 is a page a copy."""
+        live = np.array(
+            [max(1, -(-l.context_len // self.config.page_size)) * l.busy for l in self._lanes]
+        )
+        pages = int(live.sum())
+        copies = table_copies(self._table, live, *self._copy_rule)
+        self._table_pages += pages
+        self._table_copies += copies
+        return round(pages / max(copies, 1), 3)
+
     def stats(self) -> Dict[str, Any]:
         """Engine-lifetime counters, batched from host state that already
         crossed the device boundary — reading this never adds a
@@ -1840,6 +1872,15 @@ class ContinuousEngine(ParamSnapshotPlane):
             "state_bytes_per_lane": self._state_bytes_per_lane,
             "state_forks": self.state_forks,
             "prefix_skipped_recurrent": self.prefix_skipped_recurrent,
+            # pages the allocator handed out next to their holder's last
+            # one, over all it handed out; live pages of the decode tables
+            # as uploaded over the copies the kernels' walk issues for
+            # them (1.0: a page a copy), and that ratio's two sums
+            "page_adjacent_share": self.allocator.adjacent
+            / max(self.allocator.allocated_total, 1),
+            "pages_per_copy": self._table_pages / max(self._table_copies, 1),
+            "table_pages": self._table_pages,
+            "table_copies": self._table_copies,
         }
 
     def _harvest(

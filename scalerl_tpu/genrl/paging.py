@@ -25,6 +25,17 @@ only at zero.  Every hold is labelled with its *holder* (``"lane[3]"``,
 ``"prefix-cache"``), so the double-free / foreign-free guards can name
 exactly who held what when the invariant broke.
 
+Pages are handed out **adjacent where they can be** (ISSUE 50): the decode
+kernels fetch a run of adjacent pool pages in one copy, and a copy's issue,
+not its bytes, is what a small page costs.  :meth:`alloc` takes the page
+after the holder's last one (``after=``) when it is free, and otherwise
+starts where a run can grow: at the first page of a wholly free stretch
+of ``stretch`` pages (the kernels' block), else at any free page.  It is a preference, never a promise: every free
+page is handed out before an ``alloc`` is refused, the kernels read
+adjacency off the table and are right for any table, and a churny run
+still fragments lane->page maps, which is why fragmentation-independence
+is a tested property, not an accident.
+
 Page 0 is the **null page**: never handed out, the routing target for
 dead-lane and pad writes, never read (reads are masked by true lengths).
 Double-free and foreign-free are hard errors — the no-aliasing invariant
@@ -50,7 +61,7 @@ class PageAllocator:
     counting against admission.
     """
 
-    def __init__(self, num_pages: int, page_size: int) -> None:
+    def __init__(self, num_pages: int, page_size: int, stretch: int = 1) -> None:
         if num_pages < 2:
             raise ValueError(
                 f"num_pages must be >= 2 (page 0 is the null page), got "
@@ -60,10 +71,22 @@ class PageAllocator:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         self.num_pages = num_pages
         self.page_size = page_size
-        # LIFO free list: recently-freed pages are reused first, so a long
-        # churny run naturally fragments lane->page maps — which is why
-        # fragmentation-independence is a tested property, not an accident
-        self._free: List[int] = list(range(num_pages - 1, 0, -1))
+        # the free list, LIFO (recently-freed pages are reused first), as
+        # a dict: a page's membership and its removal from the middle are
+        # O(1) beside the pop from the end
+        self._free: Dict[int, None] = dict.fromkeys(range(num_pages - 1, 0, -1))
+        # where a fresh run starts: stretch c is pages [1 + c * stretch,
+        # 1 + (c + 1) * stretch), the pages the decode kernels fetch at a
+        # step (``pages_per_block``); how many pages of each are free, and
+        # which stretches are wholly free (the lowest last: started first)
+        self.stretch = stretch
+        self._free_in = [
+            min(stretch, num_pages - 1 - first)
+            for first in range(0, num_pages - 1, stretch)
+        ]
+        self._whole = dict.fromkeys(reversed(range(len(self._free_in))))
+        self.allocated_total = 0  # pages handed out, ever
+        self.adjacent = 0  # ... of which the one after the holder's last
         self._refs: Dict[int, int] = {}  # live page -> refcount
         self._holders: Dict[int, List[str]] = {}  # live page -> holder labels
         self.reserved = 0
@@ -128,8 +151,12 @@ class PageAllocator:
         self.reserved -= n_pages
 
     # -- physical pages ------------------------------------------------
-    def alloc(self, n_pages: int, holder: str = "?") -> List[int]:
-        """Draw ``n_pages`` fresh physical pages at refcount 1.  Callers
+    def alloc(
+        self, n_pages: int, holder: str = "?", after: Optional[int] = None
+    ) -> List[int]:
+        """Draw ``n_pages`` fresh physical pages at refcount 1, each the
+        page after the one before it where that page is free; ``after`` is
+        the holder's last page, which the first continues.  Callers
         alloc only within their reservation; when the free list is short
         the reclaim hook (prefix-cache LRU eviction) is asked first, and
         an empty free list after that is a bookkeeping bug (aliasing
@@ -142,11 +169,31 @@ class PageAllocator:
                 f"{len(self._free)} free pages (reserved={self.reserved}) "
                 "— reservation accounting broken"
             )
-        pages = [self._free.pop() for _ in range(n_pages)]
-        for p in pages:
-            self._refs[p] = 1
-            self._holders[p] = [holder]
+        pages = []
+        for _ in range(n_pages):
+            after = self._take(after)
+            pages.append(after)
+            self._refs[after] = 1
+            self._holders[after] = [holder]
+        self.allocated_total += n_pages
         return pages
+
+    def _take(self, after: Optional[int]) -> int:
+        """One page off the free list: the one after ``after``; else where
+        a run can grow, the first page of a wholly free stretch; else the
+        last one freed."""
+        if after is not None and after + 1 in self._free:
+            self.adjacent += 1
+            page = after + 1
+        elif self._whole:
+            page = 1 + next(reversed(self._whole)) * self.stretch
+        else:
+            page = next(reversed(self._free))
+        del self._free[page]
+        c = (page - 1) // self.stretch
+        self._free_in[c] -= 1
+        self._whole.pop(c, None)
+        return page
 
     def share(self, pages: List[int], holder: str = "?") -> None:
         """Bump the refcount of already-live pages on behalf of a new
@@ -185,7 +232,11 @@ class PageAllocator:
             if self._refs[p] == 0:
                 del self._refs[p]
                 del self._holders[p]
-                self._free.append(p)
+                self._free[p] = None
+                c = (p - 1) // self.stretch
+                self._free_in[c] += 1
+                if self._free_in[c] == min(self.stretch, self.capacity - c * self.stretch):
+                    self._whole[c] = None
 
     # -- telemetry -----------------------------------------------------
     def stats(self) -> Dict[str, int]:
@@ -195,6 +246,8 @@ class PageAllocator:
             "allocated": self.allocated_pages,
             "shared": self.shared_pages,
             "reserved": self.reserved,
+            "allocated_total": self.allocated_total,
+            "adjacent": self.adjacent,
         }
 
 

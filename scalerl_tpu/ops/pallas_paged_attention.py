@@ -25,13 +25,18 @@ Two implementations behind one contract:
   (Pallas interpret mode would re-interpret the kernel per decode
   sub-step).
 - :func:`paged_decode_attention` — the Pallas kernel: grid ``(B,)``, one
-  lane a step with all its heads, the page table and lengths as
-  *scalar-prefetch* operands.  The pools stay in HBM; inside a lane the
+  lane a step with all its heads, the page table (as the list of the
+  copies that fetch it) and lengths as *scalar-prefetch* operands.  The pools stay in HBM; inside a lane the
   kernel walks the table in blocks of ``P`` pages
   (:func:`pages_per_block`: 128 tokens, from the pool's shape alone) to
-  ``cdiv(length, P * page_size)`` and no further, issuing one async copy a
-  *live* page into a double-buffered VMEM block while the previous block
-  is attended to, the next lane's first block included.  So both the HBM
+  ``cdiv(length, P * page_size)`` and no further, copying the *live* pages
+  into a double-buffered VMEM block while the previous block is attended
+  to, the next lane's first block included: one async copy a RUN of live
+  slots whose entries are adjacent pool pages (a copy's issue, not its
+  bytes, is what a page of 8 tokens costs), one page a copy where the
+  table holds no run.  Adjacency is read off the table, never assumed
+  (:func:`_copy_list`, in the program around the kernel): the kernel
+  is right for any table.  So both the HBM
   reads and the steps are O(live tokens): a table slot past a lane's
   length is never read, a dead lane (length 1) costs one page and one
   block.  Whether the *program around it* stays off the rest of the pool
@@ -66,6 +71,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -173,6 +179,30 @@ def pages_per_block(page_size: int, width: int, itemsize: int) -> int:
     return max(1, tokens // page_size)
 
 
+def table_copies(table, live, page_size: int, width: int, itemsize: int, pool_pages: int) -> int:
+    """Copies the decode kernels issue a pool for ``table`` (numpy, ``[lanes,
+    slots]``) where lane ``l`` has ``live[l]`` live slots:
+    :func:`_copy_list`'s rule reckoned on the host in one vectorised pass, for the engine's
+    ``pages_per_copy``.  A run ends where the next entry is not the next
+    page, at a block's end and at the largest size; what is left of it
+    goes out in the smaller sizes, largest first."""
+    P = pages_per_block(page_size, width, itemsize)
+    sizes = _run_sizes(P, pool_pages)
+    table = np.asarray(table)
+    slot = np.arange(table.shape[1])
+    is_live = slot[None, :] < np.asarray(live)[:, None]
+    # slot s opens a run unless s - 1 is the page before it in the same block
+    opens = np.ones(table.shape, bool)
+    opens[:, 1:] = (table[:, 1:] != table[:, :-1] + 1) | (slot[1:] % P == 0)
+    ids = np.cumsum(opens.reshape(-1))[is_live.reshape(-1)]
+    runs = np.bincount(ids)[1:] if ids.size else ids
+    copies = runs // sizes[0]
+    rest = runs % sizes[0]
+    for n in sizes[1:]:
+        copies, rest = copies + rest // n, rest % n
+    return int(copies.sum())
+
+
 def _bf16_terms(x):
     """``x`` as bfloat16 terms that sum to it exactly: a float32's 24-bit
     significand in three 8-bit pieces (high, middle, low), or ``x`` itself
@@ -221,16 +251,116 @@ def _dot_terms(a_terms, b_terms, contract):
     return total
 
 
+# pages one copy may fetch, largest first and ending in one page: a run of
+# adjacent pool pages is cut into these, and a block's share of them is
+# what divides it.  A size is a loop of the kernel at each of its three
+# sites; measured on the chip (PERF.md, PR 50) a block, a quarter of one at
+# the cells' page size and a page do what five sizes do, and two do not
+_RUN_SIZES = (16, 4, 1)
+# an entry of the copy list: the first page's id in the low bits, and above
+# them the slot of its block that the copy fills from
+_SLOT_SHIFT = 24
+
+
+def _run_sizes(block_pages: int, pool_pages: int):
+    """The sizes a block of ``block_pages`` cuts its runs into: none
+    larger than the block, nor than the pool a copy reads from."""
+    return tuple(n for n in _RUN_SIZES if n <= min(block_pages, pool_pages))
+
+
+def _copy_list(page_table, lengths, page_size: int, block_pages: int, pool_pages: int):
+    """The copies that fetch ``page_table``'s live pages, a block of
+    ``block_pages`` slots at a time, as the kernels walk them: ``(list,
+    ends)``.  A RUN is live slots of one block whose entries are adjacent
+    pool pages (``at, at + 1, ..``); it goes out in copies of
+    :func:`_run_sizes`' sizes, the largest first.  ``list [lanes, blocks *
+    block_pages]`` holds each block's copies in its own slots, the largest
+    size's first and a size's in slot order, an entry the first page's id
+    with the block's slot it fills from above it (``_SLOT_SHIFT``);
+    ``ends [lanes, blocks * sizes]`` holds, a block and a size, where that
+    size's copies end in the block's list (the block's copies of that size
+    or a larger one).  A table with no two entries adjacent lists its
+    pages as they stand, a page a copy.
+
+    Adjacency is read off the table, here, in vector operations of the
+    program around the kernel (the same for every layer that attends, so
+    XLA keeps one), and the kernel's scalar core, which issues the copies
+    and is what a small page costs, runs one counted loop a size with no
+    test inside it."""
+    assert pool_pages <= 1 << _SLOT_SHIFT, "a page id shares its entry with a slot"
+    sizes = _run_sizes(block_pages, pool_pages)
+    assert sizes[-1] == 1, "what no larger copy takes goes out a page at a time"
+    P = block_pages
+    lanes, slots = page_table.shape
+    blocks = -(-slots // P)
+    table = jnp.pad(page_table.astype(jnp.int32), ((0, 0), (0, blocks * P - slots)))
+    slot = jnp.arange(blocks * P, dtype=jnp.int32)[None, :]
+    live = jnp.clip(-(-lengths.astype(jnp.int32) // page_size), 1, slots)[:, None]
+    before = jnp.pad(table[:, :-1], ((0, 0), (1, 0)), constant_values=-2)
+    after = jnp.pad(table[:, 1:], ((0, 0), (0, 1)), constant_values=-2)
+    # a run's first slot: a block's first, or one that is not the page after
+    # its predecessor's; its last: the lane's last live one, a block's last,
+    # or one whose successor is not the next page
+    opens = (slot % P == 0) | (table != before + 1)
+    closes = (slot + 1 >= live) | ((slot + 1) % P == 0) | (after != table + 1)
+    first = jax.lax.cummax(jnp.where(opens, slot, 0), axis=1)
+    last = jax.lax.cummin(jnp.where(closes, slot, blocks * P), axis=1, reverse=True)
+    at, run = slot - first, last - first + 1  # this slot's place in its run of so many
+    # which size's copy starts at this slot (``len(sizes)``: none does)
+    kind = jnp.full_like(table, len(sizes))
+    rest = run  # the run's pages that no larger size took
+    for k, n in enumerate(sizes):
+        taken = run - rest
+        rest = rest % n
+        kind = jnp.where((at >= taken) & (at < run - rest) & ((at - taken) % n == 0), k, kind)
+    kind = jnp.where(slot < live, kind, len(sizes)).reshape(lanes, blocks, P)
+    # a copy's place in its block's list: after the larger sizes' copies and
+    # its own size's at earlier slots
+    is_kind = kind[..., None] == jnp.arange(len(sizes), dtype=jnp.int32)  # [.., P, sizes]
+    count = jnp.sum(is_kind, axis=2, dtype=jnp.int32)
+    ends = jnp.cumsum(count, axis=-1)
+    earlier = jnp.cumsum(is_kind, axis=2, dtype=jnp.int32) - is_kind
+    place = jnp.sum(jnp.where(is_kind, (ends - count)[:, :, None, :] + earlier, 0), axis=-1)
+    place = jnp.where(kind < len(sizes), place, P)  # a slot that starts no copy: nowhere
+    in_block = jnp.arange(P, dtype=jnp.int32)
+    entry = table.reshape(lanes, blocks, P) | (in_block << _SLOT_SHIFT)
+    listed = jnp.sum(
+        jnp.where(place[..., None] == in_block, entry[..., None], 0), axis=2
+    )
+    return listed.reshape(lanes, blocks * P), ends.reshape(lanes, blocks * len(sizes))
+
+
+def _each_copy(list_ref, ends_ref, lane, block, block_pages, sizes, copy):
+    """Block ``block`` of ``lane``'s row of a :func:`_copy_list`, a copy at
+    a time: ``copy(j, at, n)`` for the ``n`` pages ``at, at + 1, ..`` that
+    fill the block's slots ``j .. j + n``, ``n`` static and one of
+    ``sizes``; one counted loop a size.  Whoever starts a block's copies
+    and whoever waits for them read the same entries, so every start has
+    its wait, on the same semaphore with the same bytes."""
+    begin = jnp.int32(0)
+    for k, n in enumerate(sizes):
+        end = ends_ref[lane, block * len(sizes) + k]
+
+        def one(c, carry, n=n):
+            entry = list_ref[lane, block * block_pages + c]
+            copy(entry >> _SLOT_SHIFT, entry & ((1 << _SLOT_SHIFT) - 1), n)
+            return carry
+
+        jax.lax.fori_loop(begin, end, one, 0)
+        begin = end
+
+
 def _decode_kernel(
-    pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+    list_ref, ends_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     k_buf, v_buf, sems, q_sc, acc_sc, m_sc, l_sc, walked_sc,
-    *, scale, head_dim, group=1,
+    *, scale, head_dim, slots, group=1,
 ):
     """One lane a grid step, ALL heads, walking the lane's live pages a
     block of ``P`` at a time.  The pools stay in HBM; ``k_buf``/``v_buf``
     are ``[2, P, page, H*D]`` VMEM buffers that the kernel fills itself,
-    one async copy a live page, the next block (this lane's, or the next
-    lane's first) in flight while this one is attended to.  ``walked_sc``
+    one async copy a run of adjacent live pages, the next block (this
+    lane's, or the next lane's first) in flight while this one is attended
+    to.  ``walked_sc``
     counts the blocks walked so far over all lanes: its parity is the
     buffer the current block sits in.
 
@@ -257,7 +387,7 @@ def _decode_kernel(
     _, P, ps, width = k_buf.shape
     T = P * ps
     rows = acc_sc.shape[0]
-    slots = pt_ref.shape[1]
+    sizes = _run_sizes(P, k_hbm.shape[0])
 
     def live_pages(lane, i):
         # never a slot past the table's width, whatever the length says (an
@@ -265,19 +395,18 @@ def _decode_kernel(
         # no page at all: every lane's first block is some lane's prefetch
         return jnp.clip(pl.cdiv(len_ref[lane], ps), 1, slots) - i * P
 
-    def each_live_page(lane, i, buf, act):
-        def page(j, carry):
-            at = pt_ref[lane, i * P + j]
-            act(pltpu.make_async_copy(k_hbm.at[at], k_buf.at[buf, j], sems.at[0, buf]))
-            act(pltpu.make_async_copy(v_hbm.at[at], v_buf.at[buf, j], sems.at[1, buf]))
-            return carry
+    def each_live_run(lane, i, buf, act):
+        def copy(j, at, n):
+            to = pl.ds(j, n)
+            act(pltpu.make_async_copy(k_hbm.at[pl.ds(at, n)], k_buf.at[buf, to], sems.at[0, buf]))
+            act(pltpu.make_async_copy(v_hbm.at[pl.ds(at, n)], v_buf.at[buf, to], sems.at[1, buf]))
 
-        jax.lax.fori_loop(0, jnp.minimum(live_pages(lane, i), P), page, 0)
+        _each_copy(list_ref, ends_ref, lane, i, P, sizes, copy)
 
     @pl.when(b == 0)
     def _first_block():
         walked_sc[0] = 0
-        each_live_page(0, 0, 0, lambda copy: copy.start())
+        each_live_run(0, 0, 0, lambda copy: copy.start())
 
     # read after the reset above: what a scratch holds at entry is anyone's
     first = walked_sc[0]
@@ -313,12 +442,12 @@ def _decode_kernel(
 
         @pl.when(next_lane < lanes)
         def _prefetch():
-            each_live_page(
+            each_live_run(
                 next_lane, jnp.where(last, 0, i + 1), 1 - buf,
                 lambda copy: copy.start(),
             )
 
-        each_live_page(b, i, buf, lambda copy: copy.wait())
+        each_live_run(b, i, buf, lambda copy: copy.wait())
 
         def no_page(j, carry):
             v_buf[buf, j] = jnp.zeros((ps, width), v_buf.dtype)
@@ -366,16 +495,38 @@ def paged_decode_attention(
 ) -> jnp.ndarray:
     """Pallas paged decode attention; same contract as the reference.
 
-    The page table and lengths ride as scalar-prefetch operands
-    (``pltpu.PrefetchScalarGridSpec``): they land in SMEM before the
-    kernel body runs, which reads ``page_table[b, slot]`` to choose the
-    pool page each of its copies fetches.  The pools are handed over where
+    The page table, as :func:`_copy_list` lists its copies, and lengths
+    ride as scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``):
+    they land in SMEM before the kernel body runs, which reads a lane's
+    list to choose the pool pages each of its copies fetches.  The pools are handed over where
     they are (``pltpu.ANY``): no ``BlockSpec`` pipeline touches them.
+
+    The call runs under a ``jax.jit`` of its own: a model's layers call it
+    on the same shapes, so a program that holds it traces the kernel's
+    body and lowers it once, not once a layer (XLA inlines the calls: the
+    compiled program is the same).  ``parts`` only keys that ``jit``
+    (:func:`_kernel_parts`).
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if interpret is None:
         interpret = _interpret_default()
+    return _paged_decode(
+        q, k_pages, v_pages, page_table, lengths, float(scale), interpret, _kernel_parts()
+    )
+
+
+def _kernel_parts():
+    """What the kernels' bodies are built from, as this module and ``pltpu``
+    hold it now: part of the inner ``jit``'s key, so that a tool which
+    swaps one for a variant (``benchmark/tools/latent_decode_probe.py``:
+    copies only, arithmetic only) is given a trace of the variant and not
+    the kernel traced before it."""
+    return _bf16_terms, _dot_terms, pltpu.make_async_copy
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "parts"))
+def _paged_decode(q, k_pages, v_pages, page_table, lengths, scale, interpret, parts):
     B, T, H, D = q.shape
     if T != 1:
         raise ValueError(f"decode attention takes one query token, got T={T}")
@@ -393,17 +544,17 @@ def paged_decode_attention(
     if group == 1:
         # q and o go a lane's row at a time through the pipeline; Mosaic tiles
         # the last two block dims, and [1, H*D] spans both axes
-        row = pl.BlockSpec((None, 1, H * D), lambda b, pt, ln: (b, 0, 0))
+        row = pl.BlockSpec((None, 1, H * D), lambda b, *_: (b, 0, 0))
         q_in, out_shape = q.reshape(B, 1, H * D), (B, 1, H * D)
     else:
         # a head a row (to whole tiles of rows; a zero query row reads
         # uniformly and is sliced off)
-        row = pl.BlockSpec((None, rows, D), lambda b, pt, ln: (b, 0, 0))
+        row = pl.BlockSpec((None, rows, D), lambda b, *_: (b, 0, 0))
         q_in = jnp.pad(q.reshape(B, H, D), ((0, 0), (0, rows - H), (0, 0)))
         out_shape = (B, rows, D)
     pool = pl.BlockSpec(memory_space=pltpu.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,  # the copy list, its ends, the lengths
         grid=(B,),
         in_specs=[row, pool, pool],
         out_specs=row,
@@ -419,7 +570,9 @@ def paged_decode_attention(
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, head_dim=D, group=group),
+        functools.partial(
+            _decode_kernel, scale=scale, head_dim=D, slots=page_table.shape[1], group=group
+        ),
         name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
@@ -427,7 +580,7 @@ def paged_decode_attention(
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(
-        page_table.astype(jnp.int32),
+        *_copy_list(page_table, lengths, ps, P, k_pages.shape[0]),
         lengths.astype(jnp.int32),
         q_in,
         k_pages,
@@ -512,13 +665,13 @@ def _note_latent_tiling(q_shape, pool_shape, dtype: str, pages: int) -> None:
 
 
 def _latent_kernel(
-    pt_ref, len_ref, q_ref, pool_hbm, o_ref,
+    list_ref, ends_ref, len_ref, q_ref, pool_hbm, o_ref,
     buf, sems, q_sc, acc_sc, m_sc, l_sc, walked_sc,
-    *, scale, value_width,
+    *, scale, value_width, slots,
 ):
     """:func:`_decode_kernel`'s walk over ONE pool: a lane a grid step,
-    its live pages a block of ``P`` at a time, one async copy a live page
-    into the double-buffered ``buf [2, P, page, W]``, the next block (this
+    its live pages a block of ``P`` at a time, one async copy a run of
+    adjacent live pages into the double-buffered ``buf [2, P, page, W]``, the next block (this
     lane's, or the next lane's first) in flight while this one is attended
     to.  The one copied block serves scores AND values: its bfloat16 terms
     are made once, the score product contracts all ``W`` columns against
@@ -531,23 +684,23 @@ def _latent_kernel(
     lanes = pl.num_programs(0)
     _, P, ps, width = buf.shape
     T = P * ps
-    slots = pt_ref.shape[1]
+    sizes = _run_sizes(P, pool_hbm.shape[0])
 
     def live_pages(lane, i):
         return jnp.clip(pl.cdiv(len_ref[lane], ps), 1, slots) - i * P
 
-    def each_live_page(lane, i, at_buf, act):
-        def page(j, carry):
-            at = pt_ref[lane, i * P + j]
-            act(pltpu.make_async_copy(pool_hbm.at[at], buf.at[at_buf, j], sems.at[at_buf]))
-            return carry
+    def each_live_run(lane, i, at_buf, act):
+        def copy(j, at, n):
+            act(pltpu.make_async_copy(
+                pool_hbm.at[pl.ds(at, n)], buf.at[at_buf, pl.ds(j, n)], sems.at[at_buf]
+            ))
 
-        jax.lax.fori_loop(0, jnp.minimum(live_pages(lane, i), P), page, 0)
+        _each_copy(list_ref, ends_ref, lane, i, P, sizes, copy)
 
     @pl.when(b == 0)
     def _first_block():
         walked_sc[0] = 0
-        each_live_page(0, 0, 0, lambda copy: copy.start())
+        each_live_run(0, 0, 0, lambda copy: copy.start())
 
     first = walked_sc[0]
     length = len_ref[b]
@@ -566,12 +719,12 @@ def _latent_kernel(
 
         @pl.when(next_lane < lanes)
         def _prefetch():
-            each_live_page(
+            each_live_run(
                 next_lane, jnp.where(last, 0, i + 1), 1 - at_buf,
                 lambda copy: copy.start(),
             )
 
-        each_live_page(b, i, at_buf, lambda copy: copy.wait())
+        each_live_run(b, i, at_buf, lambda copy: copy.wait())
 
         def no_page(j, carry):
             buf[at_buf, j] = jnp.zeros((ps, width), buf.dtype)
@@ -613,9 +766,17 @@ def paged_decode_latent(
     """Pallas absorbed decode attention over a latent pool; same contract
     as :func:`paged_latent_attention_reference`.  Grid ``(lanes,)``, the
     pool left in HBM (``pltpu.ANY``), table and lengths scalar-prefetched,
-    float32 scores, softmax state and accumulator."""
+    float32 scores, softmax state and accumulator; under a ``jax.jit`` of
+    its own, like :func:`paged_decode_attention`."""
     if interpret is None:
         interpret = _interpret_default()
+    return _paged_latent(
+        q, pool, page_table, lengths, int(value_width), float(scale), interpret, _kernel_parts()
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("value_width", "scale", "interpret", "parts"))
+def _paged_latent(q, pool, page_table, lengths, value_width, scale, interpret, parts):
     B, T, H, W = q.shape
     if T != 1:
         raise ValueError(f"decode attention takes one query token, got T={T}")
@@ -635,13 +796,13 @@ def paged_decode_latent(
         qp = jnp.pad(qp, ((0, 0), (0, rows - H), (0, pool.shape[2] - W)))
         W = pool.shape[2]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,  # the copy list, its ends, the lengths
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((None, rows, W), lambda b, pt, ln: (b, 0, 0)),
+            pl.BlockSpec((None, rows, W), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.ANY),
         ],
-        out_specs=pl.BlockSpec((None, rows, value_width), lambda b, pt, ln: (b, 0, 0)),
+        out_specs=pl.BlockSpec((None, rows, value_width), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, P, ps, W), pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),  # one a buffer
@@ -653,14 +814,21 @@ def paged_decode_latent(
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_latent_kernel, scale=scale, value_width=value_width),
+        functools.partial(
+            _latent_kernel, scale=scale, value_width=value_width, slots=page_table.shape[1]
+        ),
         name="paged_decode_latent",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, rows, value_width), jnp.float32),
         # the buffers and the block count carry from lane to lane
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), qp, pool)
+    )(
+        *_copy_list(page_table, lengths, ps, P, pool.shape[0]),
+        lengths.astype(jnp.int32),
+        qp,
+        pool,
+    )
     return out[:, None, :H]
 
 

@@ -199,6 +199,125 @@ def test_fragmentation_independence_of_results(setup):
     )
 
 
+def test_a_pool_with_no_two_free_pages_adjacent_decodes_the_same_tokens(setup):
+    """The allocator prefers adjacent pages and never needs them (ISSUE
+    50): with every other page of the pool held by someone else, a lane's
+    table holds no run at all, and the prompt still decodes to the
+    full-forward reference's greedy tokens."""
+    cont, ref = setup["cont"], setup["ref"]
+    prompts, lengths = setup["prompts"], setup["lengths"]
+    a = cont.allocator
+    cont._prefix_cache.flush()
+    assert a.allocated_pages == 0
+    held = a.alloc(a.free_pages, holder="someone-else")
+    a.free(held[1::2], holder="someone-else")
+    before = a.stats()
+    cont.submit(prompts[0], lengths[0])
+    done = cont.run_until(1, max_macro_steps=40)
+    n = int(ref.response_len[0])
+    np.testing.assert_array_equal(done[0].response_tokens, ref.response_tokens[0, :n])
+    after = a.stats()
+    assert after["allocated_total"] - before["allocated_total"] >= 2
+    assert after["adjacent"] == before["adjacent"]  # not one page next to the last
+    cont._prefix_cache.flush()
+    a.free(held[0::2], holder="someone-else")
+    assert a.free_pages == a.capacity
+
+
+def _run_engine(lanes, **config):
+    m = TransformerPolicy(
+        num_actions=V, vocab_size=V, d_model=32, num_heads=2, num_layers=1, max_len=32,
+    )
+    params = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
+    return ContinuousEngine(
+        m, params,
+        ContinuousConfig(
+            vocab_size=V, max_prompt_len=12, max_new_tokens=12, temperature=1.0, seed=3,
+            lanes=lanes, page_size=2, steps_per_macro=2, steps_in_flight=1,
+            prefix_cache=False, **config,
+        ),
+    )
+
+
+def test_group_members_tables_are_a_shared_run_then_an_own_run():
+    """After a group admission the leader's prompt pages are one run, every
+    member's table is those shared pages then a run of its own that starts
+    at its copy of the partial page, and growth continues each lane's own
+    run (ISSUE 50)."""
+    eng = _run_engine(4, num_pages=1025)  # sixteen stretches of 64 pages: room for runs
+    prompt = np.arange(2, 11).astype(np.int32)  # 9 tokens: 4 full pages and a partial one
+    assert eng.submit_group(prompt, 4, len(prompt))
+    for _ in range(3):
+        eng.step()
+    tables = [np.asarray(l.pages) for l in eng._lanes]
+    assert all(len(t) >= 8 for t in tables)
+    leader = tables[0]
+    np.testing.assert_array_equal(np.diff(leader), 1)  # prompt and growth: one run
+    own_starts = set()
+    for member in tables[1:]:
+        np.testing.assert_array_equal(member[:4], leader[:4])  # the shared run
+        assert member[4] != leader[4]  # its own copy of the partial page
+        np.testing.assert_array_equal(np.diff(member[4:]), 1)  # ... starts its own run
+        own_starts.add(int(member[4]))
+    assert len(own_starts) == 3
+    np.testing.assert_array_equal(eng._table[1, : len(tables[1])], tables[1])
+
+
+def test_stats_count_adjacent_pages_and_pages_a_copy():
+    """``stats()``'s ``page_adjacent_share`` and ``pages_per_copy`` against
+    a table counted by hand: one lane, a prompt of 9 tokens on pages of 2.
+    Admission hands out pages 1-5 and the first macro-step's horizon (two
+    more tokens) page 6: six pages, five of them next to the one before.
+    The table as first uploaded has five live pages, 1 to 5, which the
+    kernels' rule fetches as a copy of four and a copy of one."""
+    eng = _run_engine(1)
+    prompt = np.arange(2, 11).astype(np.int32)
+    eng.submit(prompt, len(prompt))
+    eng.step()
+    np.testing.assert_array_equal(eng._table[0, :6], [1, 2, 3, 4, 5, 6])
+    s = eng.stats()
+    assert (s["table_pages"], s["table_copies"]) == (5, 2)
+    assert s["pages_per_copy"] == 2.5
+    assert s["page_adjacent_share"] == 5 / 6
+    assert eng.allocator.stats()["adjacent"] == 5
+    # a second macro-step: the context is 11 tokens, six live pages, 4 + 1 + 1
+    eng.step()
+    s = eng.stats()
+    assert (s["table_pages"], s["table_copies"]) == (5 + 6, 2 + 3)
+
+
+def test_decode_program_traces_the_kernel_body_once(monkeypatch):
+    """Set-up's guard, with no clock (ISSUE 50): the four attention layers
+    of a model call the paged decode kernel on the same shapes, and tracing
+    the decode program traces the kernel's body ONCE (the call runs under
+    a ``jax.jit`` of its own), not once a layer."""
+    from scalerl_tpu.ops import pallas_paged_attention as ppa
+
+    calls = []
+    real = ppa._decode_kernel
+
+    def counted(*args, **kwargs):
+        calls.append(1)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(ppa, "_decode_kernel", counted)
+    # a geometry no other test has: the kernel's own cache starts cold
+    m = TransformerPolicy(
+        num_actions=V, vocab_size=V, d_model=48, num_heads=3, num_layers=4, max_len=24,
+    )
+    params = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
+    eng = ContinuousEngine(
+        m, params,
+        ContinuousConfig(
+            vocab_size=V, max_prompt_len=8, max_new_tokens=8, lanes=3, page_size=4,
+            paged_attn="pallas",
+        ),
+    )
+    text = eng.lower_decode().as_text()
+    assert len(calls) == 1
+    assert text.count("paged_decode") >= 1
+
+
 def test_quantized_push_params_logits_parity(setup):
     """push_params(quantize="int8") stores the compressed snapshot and
     dequantizes on read: greedy decode tokens are unchanged and behavior

@@ -159,6 +159,97 @@ def test_allocator_reclaim_hook_fires_when_free_list_short():
     assert calls == [2] and len(got) == 2
 
 
+# -- adjacency is preferred and never required (ISSUE 50) --------------------
+
+
+def test_alloc_continues_the_holders_run_when_the_next_page_is_free():
+    a = PageAllocator(num_pages=65, page_size=8, stretch=16)
+    first = a.alloc(3, holder="lane[0]")
+    assert first == [1, 2, 3]  # a fresh run: one page after another
+    other = a.alloc(1, holder="lane[1]")  # starts where it can grow: not at 4
+    assert other[0] > 4 and (other[0] - 1) % 16 == 0
+    assert a.alloc(2, holder="lane[0]", after=first[-1]) == [4, 5]
+    # the page after lane[1]'s is taken: lane[1] cannot continue, and falls back
+    blocker = a.alloc(1, holder="lane[2]", after=other[0])
+    assert blocker == [other[0] + 1]
+    hop = a.alloc(1, holder="lane[1]", after=other[0])
+    assert hop[0] not in (other[0] + 1, 0) and a.refcount(hop[0]) == 1
+    s = a.stats()
+    # handed out next to the holder's last: 2 and 3, 4 and 5, the blocker
+    assert (s["allocated_total"], s["adjacent"]) == (8, 5)
+    # a rewound tail goes back to the free list and is taken again in order
+    pages = first + [4, 5]
+    assert rewind_pages(a, pages, 2, holder="lane[0]") == 3 and pages == [1, 2]
+    assert a.alloc(3, holder="lane[0]", after=pages[-1]) == [3, 4, 5]
+
+
+def test_a_fresh_run_starts_at_a_wholly_free_stretch():
+    """Stretches of ``stretch`` pages (the decode kernels' block): a run
+    starts at the first page of the lowest one that is wholly free, and
+    grows where it stands; without stretches (the default) a page comes
+    off the free list as it always did, the last one freed first."""
+    a = PageAllocator(num_pages=70, page_size=8, stretch=16)
+    starts = [a.alloc(1, holder=f"lane[{i}]")[0] for i in range(4)]
+    assert starts == [1, 17, 33, 49]
+    for i, p in enumerate(starts):
+        assert a.alloc(2, holder=f"lane[{i}]", after=p) == [p + 1, p + 2]
+    assert a.alloc(1, holder="lane[4]") == [65]  # the last, short stretch is whole too
+    a.free([18, 19], holder="lane[1]")
+    assert a.alloc(1, holder="lane[5]") == [19]  # none whole: the last page freed
+    a.free([17, 19])
+    assert a.alloc(1, holder="lane[5]") == [17]  # whole again
+    plain = PageAllocator(num_pages=70, page_size=8)
+    got = plain.alloc(5)
+    plain.free([got[1], got[3]])
+    assert got == [1, 2, 3, 4, 5] and plain.alloc(3) == [4, 2, 6]
+
+
+@pytest.mark.parametrize("stretch", [1, 16])
+def test_every_free_page_is_handed_out_before_alloc_raises(stretch):
+    """Whatever the hints and however fragmented the pool: ``alloc`` ends
+    with an empty free list, never with a refusal while a page is free."""
+    rng = np.random.default_rng(50)
+    a = PageAllocator(num_pages=100, page_size=8, stretch=stretch)
+    held = a.alloc(99)
+    rng.shuffle(held)
+    back, held = held[:60], held[60:]
+    a.free(back)
+    got = []
+    while a.free_pages:
+        hint = int(rng.integers(1, 100)) if rng.random() < 0.7 else None
+        got += a.alloc(min(int(rng.integers(1, 4)), a.free_pages), after=hint)
+        assert len(set(got)) == len(got) and not set(got) & set(held)
+    assert sorted(got) == sorted(back) and a.allocated_pages == 99
+    with pytest.raises(RuntimeError, match="reservation accounting broken"):
+        a.alloc(1, after=got[-1])
+    a.free(got + held)
+    assert a.free_pages == a.capacity
+    one, two = a.alloc(2)
+    assert two == one + 1  # whole again: a page and the one after it
+
+
+def test_the_reclaim_hook_is_asked_only_on_a_shortfall():
+    """No stretch wholly free is no shortfall: a run starts at the last
+    page freed and the cache keeps its pages; the hook hears of the pages
+    that are missing, as before, and of nothing else."""
+    a = PageAllocator(num_pages=33, page_size=8, stretch=16)
+    cached = a.alloc(32, holder="prefix-cache")
+    a.free(cached[3:5] + cached[20:22], holder="prefix-cache")  # 4, 5, 21, 22 free
+    del cached[20:22], cached[3:5]
+    calls = []
+
+    def reclaim(n):
+        calls.append(n)
+        a.free([cached.pop() for _ in range(n)], holder="prefix-cache")
+        return n
+
+    a.set_reclaim_hook(reclaim)
+    assert a.alloc(1, holder="lane[0]") == [22] and calls == []
+    assert a.alloc(2, holder="lane[0]", after=22) == [21, 5] and calls == []
+    assert a.alloc(3, holder="lane[1]") == [31, 32, 4] and calls == [2]
+    assert a.free_pages == 0
+
+
 # ---------------------------------------------------------------------------
 # page-cursor rewind (ISSUE 16): the speculative-decode rollback primitive
 

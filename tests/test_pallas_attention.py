@@ -509,3 +509,158 @@ def test_resolve_segment_attn(monkeypatch):
     # off-TPU auto resolves to the dense model path (None)
     if jax.default_backend() != "tpu":
         assert make_segment_attn_fn("auto") is None
+
+
+# ---------------------------------------------------------------------------
+# the paged decode kernels fetch a RUN of adjacent pool pages in one copy
+# (ISSUE 50).  Adjacency is read off the table, so every table is a case.
+
+_RUN_PS, _RUN_SLOTS, _RUN_POOL = 8, 40, 200  # a block is 16 pages; the pool's last page is 199
+
+
+def _run_tables():
+    """name -> (table rows, lengths): what a lane's table can look like."""
+    rng = np.random.default_rng(50)
+    M, N, ps = _RUN_SLOTS, _RUN_POOL, _RUN_PS
+    scattered = rng.permutation(np.arange(1, N))
+
+    def row(*pieces):
+        flat = np.concatenate([np.atleast_1d(p) for p in pieces])
+        return np.concatenate([flat, np.zeros(M - len(flat), np.int64)])[:M]
+
+    shared = np.arange(60, 80)  # a prompt's twenty pages: the break falls inside block 1
+    return {
+        "one_run": ([np.arange(1, 1 + M), np.arange(100, 100 + M)], [M * ps, 23 * ps]),
+        # no two live entries adjacent: pages two apart, going down
+        "fragmented": ([np.arange(N - 1, 0, -2)[:M], scattered[:M]], [M * ps, 300]),
+        "shared_run_then_own_run": (
+            [row(shared, np.arange(10, 30)), row(shared, np.arange(120, 140))],
+            [39 * ps + 3, 33 * ps],
+        ),
+        # adjacent pages from slot 10 to slot 25, across the block's end at 16
+        "run_across_a_block_boundary": (
+            [row(scattered[:10], np.arange(140, 156), scattered[10:24])], [M * ps - 1],
+        ),
+        # three live pages of a run that the table goes on with past the length
+        "partial_last_page": ([np.arange(30, 30 + M), row(np.arange(90, 100))], [2 * ps + 5, ps + 1]),
+        # ... and where going on would leave the pool
+        "run_ends_on_the_pools_last_page": (
+            [row(np.arange(N - 19, N)), row(np.arange(N - 3, N), N - 1, N - 1)], [19 * ps, 3 * ps],
+        ),
+        "dead_lane_between_live_ones": (
+            [np.arange(1, 1 + M), row(0), np.arange(150, 150 + M), row(0), row(np.arange(50, 70))],
+            [200, 1, 320, 1, 160],
+        ),
+    }
+
+
+_RUN_TABLES = _run_tables()
+
+
+def _run_case(kernel, table, lengths):
+    """(kernel's result, reference's result) on a seeded pool, in the TPU
+    interpreter with every scratch buffer NaN and reads out of bounds
+    raising: a position neither fetched nor zeroed, or a copy that leaves
+    the pool, shows."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from scalerl_tpu.ops import pallas_paged_attention as ppa
+
+    rng = np.random.default_rng(5)
+    B, N, ps = len(table), _RUN_POOL, _RUN_PS
+    t, ln = jnp.asarray(np.stack(table), jnp.int32), jnp.asarray(lengths, jnp.int32)
+    interpret = pltpu.InterpretParams()
+    if kernel == "latent":
+        H, W, VW = 4, 48, 32
+        q = jnp.asarray(rng.normal(size=(B, 1, H, W)), jnp.float32)
+        pool = jnp.asarray(rng.normal(size=(N, ps, ppa.latent_pool_width(W))), jnp.float32)
+        # the reference first and to its end: the interpreter computes in
+        # host callbacks, which deadlock against work dispatched beside them
+        ref = jax.block_until_ready(ppa.paged_latent_attention_reference(q, pool, t, ln, VW, 0.2))
+        return ppa.paged_decode_latent(q, pool, t, ln, VW, 0.2, interpret=interpret), ref
+    H, KV, D = (4, 2, 8) if kernel == "grouped" else (2, 2, 8)
+    q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(N, ps, KV * D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(N, ps, KV * D)), jnp.float32)
+    ref = jax.block_until_ready(ppa.paged_attention_reference(q, kp, vp, t, ln))
+    return ppa.paged_decode_attention(q, kp, vp, t, ln, interpret=interpret), ref
+
+
+@pytest.mark.parametrize("kernel", ["decode", "grouped", "latent"])
+@pytest.mark.parametrize("case", sorted(_RUN_TABLES))
+def test_paged_kernels_fetch_runs_of_adjacent_pages(case, kernel):
+    """Both decode kernels, and grouped heads, against their gather
+    references at 1e-5 on tables that are one run, no run at all, a shared
+    run then an own one, a run across a block's end, a part-filled last
+    page, a run that ends on the pool's last page and dead lanes between
+    live ones: whatever the table, the same result."""
+    out, ref = _run_case(kernel, *_RUN_TABLES[case])
+    out = np.asarray(out)
+    assert np.all(np.isfinite(out))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+@pytest.mark.parametrize("case", sorted(_RUN_TABLES))
+def test_host_counts_the_copies_the_kernels_rule_issues(case):
+    """The list the kernels walk (``_copy_list``) read back as they read it
+    (``_each_copy``: a block's entries, a size at a time up to that size's
+    end): every copy fetches pages the table names at the slots it fills,
+    every live slot is filled once and no other, and ``table_copies`` (the
+    engine's ``pages_per_copy``) is that list's length reckoned on the
+    host; no table costs more copies than it has live pages."""
+    from scalerl_tpu.ops import pallas_paged_attention as ppa
+
+    table, lengths = _RUN_TABLES[case]
+    table = np.stack(table).astype(np.int32)
+    ps, width = _RUN_PS, 16
+    P = ppa.pages_per_block(ps, width, 4)
+    live = np.clip(-(-np.asarray(lengths) // ps), 1, table.shape[1])
+    sizes = ppa._run_sizes(P, _RUN_POOL)
+    listed, ends = map(
+        np.asarray, ppa._copy_list(jnp.asarray(table), jnp.asarray(lengths), ps, P, _RUN_POOL)
+    )
+    filled = np.zeros(listed.shape, np.int64)
+    counted = np.zeros((len(table), 2), np.int64)
+    for lane in range(len(table)):
+        for block in range(-(-live[lane] // P)):
+            begin = 0
+            for k, n in enumerate(sizes):
+                end = ends[lane, block * len(sizes) + k]
+                for entry in listed[lane, block * P + begin:block * P + end]:
+                    j, at = block * P + (entry >> ppa._SLOT_SHIFT), entry & ((1 << ppa._SLOT_SHIFT) - 1)
+                    np.testing.assert_array_equal(table[lane, j:j + n], at + np.arange(n))
+                    filled[lane, j:j + n] += 1
+                    counted[lane] += 1, n
+                begin = end
+    np.testing.assert_array_equal(filled, np.arange(filled.shape[1])[None, :] < live[:, None])
+    copies = ppa.table_copies(table, live, ps, width, 4, _RUN_POOL)
+    assert copies == counted[:, 0].sum()
+    assert len(table) <= copies <= live.sum()
+    if case == "fragmented":
+        assert copies == live.sum()  # a page a copy: the parent's walk
+    if case == "one_run":
+        assert copies == 4 + 5  # 40 pages: 16, 16, 4 + 4; 23: 16, 4 + 1 + 1 + 1
+
+
+@pytest.mark.parametrize("kernel", ["decode", "latent"])
+def test_a_swapped_part_of_a_decode_kernel_is_traced_again(kernel, monkeypatch):
+    """``benchmark/tools/latent_decode_probe.py`` times "copies only" and
+    "arithmetic only" by swapping a part of the module between calls.  The
+    kernels run under a ``jax.jit`` of their own, which would hand such a
+    tool the trace it made first: the parts key that ``jit``
+    (``_kernel_parts``), so the variant is what runs, and the kernel proper
+    comes back with its parts."""
+    from scalerl_tpu.ops import pallas_paged_attention as ppa
+
+    table, lengths = _RUN_TABLES["shared_run_then_own_run"]
+    out, _ = _run_case(kernel, table, lengths)
+
+    def no_arithmetic(a_terms, b_terms, contract):
+        return jnp.zeros((a_terms.shape[0] // 3, b_terms[0].shape[1 - contract[1][0]]), jnp.float32)
+
+    with monkeypatch.context() as m:
+        m.setattr(ppa, "_dot_terms", no_arithmetic)
+        swapped, _ = _run_case(kernel, table, lengths)
+    assert not np.allclose(np.asarray(swapped), np.asarray(out), atol=1e-3)
+    again, _ = _run_case(kernel, table, lengths)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(out))

@@ -22,7 +22,10 @@ fed845a; gpt2, olmoe ``packed`` / ``values`` and longcat at e6c85c7; joyai
 and ``paged_decode.kernel`` at 687c51e; nemotron and
 ``paged_decode.grouped_kernel`` at 5a380d0; qwen3next at 2f98510; zaya on
 the tree of the PR that added it, ISSUE 46: the next PR's parent) with this
-environment's JAX, and have passed unchanged on every commit since.  After
+environment's JAX, and have passed unchanged on every commit since; both
+``paged_decode.*`` again on the tree of ISSUE 50, which meant to change the
+kernel (a run of adjacent pages in one copy, the call under its own
+``jax.jit``) and changed no family's program.  After
 a JAX upgrade, take them again from a commit known to be unchanged.
 """
 
@@ -68,8 +71,8 @@ PARENT = {
     "zaya.prefill": "089f5d1a6a55e63b",
     "zaya.decode": "ac11e3213a475442",
     "zaya.values": "c3b4e6916bc10194",
-    "paged_decode.kernel": "a55e78ecad7aa6ba",
-    "paged_decode.grouped_kernel": "4d009f7757b4473a",
+    "paged_decode.kernel": "57daef2ccfd90b3d",
+    "paged_decode.grouped_kernel": "817adece2559f7ff",
 }
 # [q heads, kv heads x head size] of the kernel-alone cases
 _KERNEL = {"kernel": (4, 32), "grouped_kernel": (8, 16)}

@@ -718,6 +718,60 @@ def test_paged_decode_grouped_heads_at_the_cells_geometry():
     assert float(jnp.max(jnp.abs(only_first[:, :, 16:] - out[:, :, 16:]))) > 1e-2
 
 
+def _mixed_runs_table(rng, B, M):
+    """Every pool page once, ``[B, M]``: runs of 1 to 37 adjacent pages in
+    shuffled order, so that a lane's table holds runs of every size, runs
+    cut by a block's end and single pages; the run that ends on the pool's
+    last page leads lane 0."""
+    runs, at = [], 1
+    while at <= B * M:
+        n = min(int(rng.choice([1, 1, 2, 3, 5, 8, 16, 20, 37])), B * M + 1 - at)
+        runs.append(np.arange(at, at + n))
+        at += n
+    last = runs.pop()
+    flat = np.concatenate([last] + [runs[i] for i in rng.permutation(len(runs))])
+    return flat.reshape(B, M)
+
+
+@pytest.mark.parametrize(
+    "kernel,B,H,KV,D",
+    [("decode", 16, 16, 16, 64), ("decode", 40, 8, 2, 128), ("latent", 128, 64, 1, 576)],
+    ids=["gpt2m_group_rollout", "zaya_group_rollout", "longcat_group_rollout"],
+)
+def test_paged_decode_kernels_on_a_table_of_mixed_runs(kernel, B, H, KV, D):
+    """One copy a run of adjacent pages (ISSUE 50), compiled at three
+    cells' geometries on a table of mixed runs: copies of 16, 4 and 1
+    pages in one kernel, from any page of the pool to any slot of a block,
+    so that a copy size Mosaic refuses shows here before the benchmark
+    does.  Lengths on and around the block's boundaries with dead lanes
+    between; against the gather reference at ``highest``."""
+    from scalerl_tpu.ops import pallas_paged_attention as ppa
+
+    ps, M = 8, 128
+    N = B * M + 1
+    rng = np.random.default_rng(50)
+    table = jnp.asarray(_mixed_runs_table(rng, B, M), jnp.int32)
+    mix = [1, 7, 64, 65, 511, 1023, 1024, 1, 127, 128, 129, 1, 256, 257, 640, 1, 385]
+    lengths = jnp.asarray([mix[b % len(mix)] for b in range(B)], jnp.int32)
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(50), 3)
+    if kernel == "latent":
+        q = _rand(k1, B, 1, H, D)
+        pool = _rand(k2, N, ps, ppa.latent_pool_width(D))
+        out = ppa.paged_decode_latent(q, pool, table, lengths, 512, 192 ** -0.5, interpret=False)
+        with jax.default_matmul_precision("highest"):
+            ref = ppa.paged_latent_attention_reference(q, pool, table, lengths, 512, 192 ** -0.5)
+    else:
+        q = _rand(k1, B, 1, H, D)
+        k_pages, v_pages = _rand(k2, N, ps, KV * D), _rand(k3, N, ps, KV * D)
+        out = ppa.paged_decode_attention(q, k_pages, v_pages, table, lengths, interpret=False)
+        with jax.default_matmul_precision("highest"):
+            ref = ppa.paged_attention_reference(q, k_pages, v_pages, table, lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    live = np.clip(-(-np.asarray(lengths) // ps), 1, M)
+    copies = ppa.table_copies(np.asarray(table), live, ps, KV * D if kernel == "decode" else 640, 4, N)
+    assert copies < live.sum() / 2  # the table really holds runs
+
+
 @pytest.mark.parametrize("lanes", [8, 96])
 def test_ssm_decode_update_compiled(lanes):
     """The Mamba-2 decode update (ISSUE 40; plain ``jax.numpy``, one XLA
