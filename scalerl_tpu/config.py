@@ -809,6 +809,17 @@ class GenRLArguments(RLArguments):
     # residual add.  The engine keeps the convolutions' window by lane
     # beside the layer's own KV pages, so such a model too is admitted by
     # local prefill and group fork alone.
+    # "xing4" = joyai's stack (``dense_layers`` leading dense layers, then
+    # MLA and a sigmoid router beside a shared expert) on a residual
+    # stream of ``hc_mult`` rows a token (manifold-constrained
+    # hyper-connections): every sublayer reads a learned mix of the rows
+    # and writes back by an ``hc_mult x hc_mult`` matrix made doubly
+    # stochastic by ``hc_sinkhorn_iters`` Sinkhorn iterations (``hc_eps``
+    # in the denominators, the logit clipped to ``hc_clamp_min`` ..
+    # ``hc_clamp_max`` before the exponential); its rotary is YaRN's where
+    # ``rope_factor`` > 1 (``rope_original_max`` positions, ``rope_beta_*``,
+    # ``rope_mscale*``).  The stream is an activation: the cache is
+    # joyai's, prefix cache and fork stay on.  It takes no ``mtp_layers``.
     block_family: str = "gpt2"
     head_dim: int = 0
     rms_norm_eps: float = 1e-5
@@ -843,6 +854,17 @@ class GenRLArguments(RLArguments):
     cca_time0: int = 0
     cca_time1: int = 0
     router_hidden: int = 0
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 1e-6
+    hc_clamp_min: float = -30.0
+    hc_clamp_max: float = 30.0
+    rope_factor: float = 1.0  # 1: plain rotary
+    rope_original_max: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     mtp_layers: int = 0
     mtp_loss_coef: float = 0.1
     # weight of the router's load-balancing loss in the learner's total
@@ -969,16 +991,18 @@ class GenRLArguments(RLArguments):
                 f"{self.temperature}"
             )
         if self.block_family not in (
-            "gpt2", "olmoe", "longcat", "joyai", "nemotron_h", "qwen3_next", "zaya"
+            "gpt2", "olmoe", "longcat", "joyai", "nemotron_h", "qwen3_next", "zaya",
+            "xing4",
         ):
             raise ValueError(
-                "block_family must be one of the seven families gpt2 | olmoe | "
-                "longcat | joyai | nemotron_h | qwen3_next | zaya, got "
+                "block_family must be one of the eight families gpt2 | olmoe | "
+                "longcat | joyai | nemotron_h | qwen3_next | zaya | xing4, got "
                 f"{self.block_family!r}"
             )
         hybrid = self.block_family == "nemotron_h"
         delta = self.block_family == "qwen3_next"
         cca = self.block_family == "zaya"
+        rows = self.block_family == "xing4"
         if hybrid and (
             not self.layer_pattern
             or set(self.layer_pattern) - set("ME*-")
@@ -1055,13 +1079,45 @@ class GenRLArguments(RLArguments):
                 "term lives in the packed loss"
             )
         if self.block_family != "joyai" and (
-            self.dense_layers or self.mtp_layers
-            or (self.moe_shared_experts and not (hybrid or delta))
+            (self.dense_layers and not rows) or self.mtp_layers
+            or (self.moe_shared_experts and not (hybrid or delta or rows))
         ):
             raise ValueError(
-                "dense_layers and mtp_layers are the joyai family's and "
-                "moe_shared_experts joyai's, nemotron_h's and qwen3_next's, "
-                f"got them with {self.block_family!r}"
+                "mtp_layers is the joyai family's, dense_layers joyai's and "
+                "xing4's (a stream of rows takes no multi-token-prediction "
+                "module) and moe_shared_experts theirs, nemotron_h's and "
+                f"qwen3_next's, got them with {self.block_family!r}"
+            )
+        if not 0 <= self.dense_layers <= self.n_layers:
+            raise ValueError(
+                "dense_layers must lie in 0..n_layers "
+                f"({self.n_layers}), got {self.dense_layers}"
+            )
+        scaled = self.rope_factor != 1.0
+        if rows and (
+            self.hc_mult < 2 or self.hc_sinkhorn_iters < 1 or self.hc_eps <= 0
+            or not self.hc_clamp_min < self.hc_clamp_max
+        ):
+            raise ValueError(
+                "the xing4 family needs hc_mult >= 2 rows, hc_sinkhorn_iters "
+                ">= 1, hc_eps > 0 and hc_clamp_min < hc_clamp_max, got "
+                f"{self.hc_mult}/{self.hc_sinkhorn_iters}/{self.hc_eps}/"
+                f"{self.hc_clamp_min}/{self.hc_clamp_max}"
+            )
+        if not rows and (self.hc_mult != 1 or self.hc_sinkhorn_iters or scaled):
+            raise ValueError(
+                "hc_mult, hc_sinkhorn_iters and rope_factor (YaRN) are the "
+                f"xing4 family's, got them with {self.block_family!r}"
+            )
+        if scaled and not (
+            self.rope_factor > 1.0 and self.rope_original_max >= 1
+            and self.rope_beta_fast > self.rope_beta_slow > 0
+        ):
+            raise ValueError(
+                "YaRN needs rope_factor > 1, rope_original_max >= 1 and "
+                "rope_beta_fast > rope_beta_slow > 0, got "
+                f"{self.rope_factor}/{self.rope_original_max}/"
+                f"{self.rope_beta_fast}/{self.rope_beta_slow}"
             )
         if self.head_dim < 0 or self.router_aux_loss_coef < 0:
             raise ValueError(

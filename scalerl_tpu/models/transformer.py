@@ -17,11 +17,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from scalerl_tpu.models.routed_ffn import MLPRouter, RoutedExperts
@@ -38,6 +40,19 @@ from scalerl_tpu.ops.ring_attention import full_attention
 
 # (q, k, v) -> attention output, all [B, T, H, D]
 AttentionFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray], jnp.ndarray]
+
+
+class RopeScaling(NamedTuple):
+    """YaRN's numbers, as a ``rope_scaling`` block of the DeepSeek family
+    gives them (:func:`yarn_terms` turns them into frequencies and the
+    two factors)."""
+
+    factor: float
+    original_max: int  # original_max_position_embeddings
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,6 +129,23 @@ class BlockSpec:
     up the stack from layer to layer beside the residual stream (the layer
     contract's ``r``).  ``residual_scale`` gives both sides of every
     residual add a learned scale and bias: ``(s x + c) + (s' y + c')``.
+
+    ``streams > 1`` (the ``xing4`` family) makes the residual stream a
+    STREAM OF ROWS, ``[B, T, streams, d]`` where every other family's is
+    ``[B, T, d]``: manifold-constrained hyper-connections.  The model
+    copies a token's embedding into every row after the embedding and sums
+    the rows before the final norm; in between every sublayer of a plain
+    layer has a :class:`_HyperMix` of its own, which READS the sublayer's
+    one-row input as a learned, input-dependent mix of the rows and WRITES
+    its output back by a ``streams x streams`` matrix on the rows
+    (``hc_iters`` Sinkhorn iterations from ``exp`` of a logit clipped to
+    ``hc_clamp``, ``hc_eps`` in every denominator: doubly stochastic) plus
+    a gated copy of the output into each row.  Mixers, FFNs, caches and
+    kernels see ``[B, T, d]`` as ever; with ``streams == 1`` nothing of
+    this is built and the tree and the program are the one-row ones.
+    ``rope_scaling`` (:class:`RopeScaling`): the rotary frequencies are
+    YaRN's blend and the latent attention's softmax scale carries
+    ``m^2`` (:func:`yarn_terms`, :func:`rotary_fn`); None: plain rotary.
     """
 
     norm: str = "layernorm"  # layernorm | rmsnorm
@@ -165,6 +197,18 @@ class BlockSpec:
     router: str = "linear"  # linear (one matrix) | mlp (MLPRouter, router_width wide)
     router_width: int = 0
     residual_scale: bool = False
+    streams: int = 1  # rows of the residual stream (hc_mult); 1: one row, a plain add
+    hc_iters: int = 0  # Sinkhorn iterations of a stream's row-mixing matrix
+    hc_eps: float = 1e-6
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)  # on the matrix's logit, before exp
+    rope_scaling: Optional[RopeScaling] = None
+
+    @property
+    def residual(self) -> str:
+        """The residual path as ``model.layers`` notes it."""
+        if self.streams > 1:
+            return f"mhc{self.streams}"
+        return "scaled" if self.residual_scale else "add"
 
     @property
     def recurrent(self) -> bool:
@@ -256,6 +300,11 @@ def block_spec(
     cca_time0: int = 0,
     cca_time1: int = 0,
     router_width: int = 0,
+    streams: int = 1,
+    hc_iters: int = 0,
+    hc_eps: float = 1e-6,
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0),
+    rope_scaling: Optional[RopeScaling] = None,
 ) -> BlockSpec:
     """The block a named family stacks; the sizes only the family reads
     are ignored by the others (``gpt2`` keeps its own epsilon).  For a
@@ -264,7 +313,9 @@ def block_spec(
     gives the stack; for ``nemotron_h`` it is the expert layer, and
     :func:`pattern_specs` gives the stack; for ``qwen3_next`` it is the
     full-attention layer, and :func:`interval_specs` gives the stack;
-    ``zaya``'s stack is one kind of layer."""
+    ``zaya``'s stack is one kind of layer; ``xing4`` is ``joyai``'s
+    routed layer (and :func:`layer_specs` its stack) on a residual stream
+    of ``streams`` rows, with YaRN's ``rope_scaling``."""
     if family == "gpt2":
         return BlockSpec(head_dim=head_dim)
     if family == "olmoe":
@@ -319,6 +370,38 @@ def block_spec(
             zero_experts=zero_experts, experts_held=held,
             first_expert=first_expert, router_bias=True,
             routed_scaling=routed_scaling, mla_scale=True,
+        )
+    if family == "xing4":
+        if streams < 2 or hc_iters < 1 or hc_eps <= 0 or not hc_clamp[0] < hc_clamp[1]:
+            raise ValueError(
+                "the xing4 block needs a residual stream of 2 rows or more, 1 "
+                "Sinkhorn iteration or more, a positive epsilon and a clamp "
+                f"(min < max), got {streams}/{hc_iters}/{hc_eps}/{hc_clamp}"
+            )
+        if rope_scaling is not None and not (
+            rope_scaling.factor >= 1
+            and rope_scaling.original_max >= 1
+            and rope_scaling.beta_fast > rope_scaling.beta_slow > 0
+        ):
+            raise ValueError(
+                "YaRN needs a factor of 1 or more, the original context "
+                f"length and beta_fast > beta_slow > 0, got {rope_scaling}"
+            )
+        base = block_spec(
+            "joyai", norm_eps=norm_eps, rope_theta=rope_theta,
+            num_experts=num_experts, experts_per_token=experts_per_token,
+            expert_width=expert_width, norm_topk_prob=norm_topk_prob,
+            q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+            qk_nope_head_dim=qk_nope_head_dim, qk_rope_head_dim=qk_rope_head_dim,
+            v_head_dim=v_head_dim, ffn_hidden=ffn_hidden,
+            experts_held=experts_held, first_expert=first_expert,
+            routed_scaling=routed_scaling, scoring=scoring,
+            shared_experts=shared_experts,
+        )
+        return dataclasses.replace(
+            base, streams=streams, hc_iters=hc_iters, hc_eps=hc_eps,
+            hc_clamp=(float(hc_clamp[0]), float(hc_clamp[1])),
+            rope_scaling=rope_scaling,
         )
     if family == "joyai":
         held = experts_held or num_experts
@@ -480,7 +563,7 @@ def block_spec(
         )
     raise ValueError(
         "block family must be gpt2 | olmoe | longcat | joyai | nemotron_h | "
-        f"qwen3_next | zaya, got {family!r}"
+        f"qwen3_next | zaya | xing4, got {family!r}"
     )
 
 
@@ -855,9 +938,11 @@ def _masked_attention(
     v: jnp.ndarray,
     mask: jnp.ndarray,
     out_dtype,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Explicit masked attention: q ``[B, T, H, D]`` against k/v
-    ``[B, S, H, D]`` with a ``[B, T, S]`` validity mask (True = attend).
+    ``[B, S, H, D]`` with a ``[B, T, S]`` validity mask (True = attend);
+    scores times ``scale`` (None: ``1 / sqrt(D)``).
 
     Scores/softmax run in float32 regardless of the compute dtype — the
     decode path feeds sampling logits, where bf16 softmax drift would show
@@ -866,7 +951,8 @@ def _masked_attention(
     by construction) instead of NaN.
     """
     head_dim = q.shape[-1]
-    scale = 1.0 / jnp.sqrt(jnp.float32(head_dim))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.float32(head_dim))
     scores = (
         jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32), k.astype(jnp.float32))
         * scale
@@ -905,8 +991,68 @@ def _norm(spec: BlockSpec, dtype, name: Optional[str] = None) -> nn.Module:
     return nn.LayerNorm(use_bias=False, dtype=dtype, name=name)
 
 
+class YarnTerms(NamedTuple):
+    """What YaRN makes of a :class:`RopeScaling` at one rotary size."""
+
+    inv_freq: Tuple[float, ...]  # the blended inverse frequencies, ``dim / 2``
+    low: int  # pairs below it keep their frequency ...
+    high: int  # ... pairs above it turn ``factor`` times slower
+    amplitude: float  # on cos and sin: m(s, mscale) / m(s, mscale_all_dim)
+    softmax_factor: float  # on the softmax scale: m(s, mscale_all_dim)^2
+
+
+@functools.lru_cache(maxsize=None)
+def yarn_terms(scaling: RopeScaling, dim: int, theta: float) -> YarnTerms:
+    """YaRN as the DeepSeek family computes it, from its numbers alone
+    (Python floats: fixed when a program is traced).  With ``f_i =
+    theta^(-2i/dim)`` and ``dim(r) = dim ln(L0 / (2 pi r)) / (2 ln
+    theta)`` (the pair that turns ``r`` times over the original context
+    ``L0``): ``low = max(floor(dim(beta_fast)), 0)``, ``high =
+    min(ceil(dim(beta_slow)), dim - 1)``, ``ramp_i = clip((i - low) /
+    (high - low), 0, 1)`` and ``inv_freq_i = f_i (1 - ramp_i) + (f_i /
+    factor) ramp_i``: fast pairs keep their frequency, slow ones are
+    interpolated.  ``m(s, a) = 0.1 a ln s + 1`` (1 at ``s <= 1``)."""
+    s, L0 = float(scaling.factor), float(scaling.original_max)
+
+    def pair(rotations: float) -> float:
+        return dim * math.log(L0 / (rotations * 2.0 * math.pi)) / (2.0 * math.log(theta))
+
+    def m(a: float) -> float:
+        return 0.1 * a * math.log(s) + 1.0 if s > 1.0 else 1.0
+
+    low = max(math.floor(pair(scaling.beta_fast)), 0)
+    high = min(math.ceil(pair(scaling.beta_slow)), dim - 1)
+    span = max(high - low, 1e-3)  # the family's guard against low == high
+    inv_freq = []
+    for i in range(dim // 2):
+        f = theta ** (-2.0 * i / dim)
+        ramp = min(max((i - low) / span, 0.0), 1.0)
+        inv_freq.append(f * (1.0 - ramp) + (f / s) * ramp)
+    return YarnTerms(
+        tuple(inv_freq), low, high, m(scaling.mscale) / m(scaling.mscale_all_dim),
+        m(scaling.mscale_all_dim) ** 2,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _note_rope_form(shape, factor, low, high, softmax_scale) -> None:
+    """A scaled rotary's numbers: one zero-length program span a traced
+    shape (the cache is the "once")."""
+    from scalerl_tpu.runtime import tracing
+
+    with tracing.span(
+        "rope.form", kind="model", shape=list(shape), scaling="yarn",
+        factor=factor, low=low, high=high, softmax_scale=softmax_scale,
+    ):
+        pass
+
+
 def rotary_fn(
-    positions: jnp.ndarray, head_dim: int, theta: float, pairing: str = "half"
+    positions: jnp.ndarray,
+    head_dim: int,
+    theta: float,
+    pairing: str = "half",
+    scaling: Optional[RopeScaling] = None,
 ) -> Callable:
     """``x [B, T, H, D] -> x`` rotated to ``positions [B, T]``: the
     rotate-half pairing (feature ``i`` with ``i + D/2``), ``inv_freq_i =
@@ -917,11 +1063,26 @@ def rotary_fn(
     then all second: the DeepSeek family's own arrangement), which q and k
     share, so every score is that of the interleaved rotation.  An ``x``
     wider than ``head_dim`` has its first ``head_dim`` features rotated
-    and the rest passed through (a partial rotary)."""
+    and the rest passed through (a partial rotary).
+
+    ``scaling`` (YaRN, :func:`yarn_terms`) changes two things and nothing
+    else: ``inv_freq`` is the blend of ``theta^(-2i/D)`` and that over
+    ``factor``, pair by pair, and cos and sin carry the ``amplitude``
+    (1 where ``mscale == mscale_all_dim``).  The third YaRN term, ``m^2``
+    on the softmax scale, is the attention's (:class:`_LatentAttention`).
+    The closure is the same."""
     half = head_dim // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / head_dim)
+    amplitude = 1.0
+    if scaling is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / head_dim)
+    else:
+        terms = yarn_terms(scaling, head_dim, float(theta))
+        inv_freq = jnp.asarray(terms.inv_freq, jnp.float32)
+        amplitude = terms.amplitude
     angle = positions.astype(jnp.float32)[:, :, None, None] * inv_freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
 
     def rotate(x):
         if x.shape[-1] > head_dim:
@@ -963,6 +1124,15 @@ def _tail_mask(call: Call, T: int, context: int) -> jnp.ndarray:
     pos = jnp.arange(context)[None, None, :]
     qpos = (call.prefix_starts[:, None] + jnp.arange(T)[None, :])[:, :, None]
     return pos <= qpos
+
+
+def _one_row(kind: str, spec: BlockSpec) -> None:
+    """A layer class that knows one residual row refuses a stream of them."""
+    if spec.streams > 1:
+        raise ValueError(
+            f"a {kind} layer takes a one-row residual stream; a stream of "
+            f"{spec.streams} rows (hyper-connections) runs through plain layers alone"
+        )
 
 
 def _routed_experts(spec: BlockSpec, dt) -> RoutedExperts:
@@ -1080,10 +1250,17 @@ def _mha(mod, h, call: Call, cache: Optional[ModelCache]):
 
 
 class _Layer(nn.Module):
-    """What every layer class is built from, and the one contract: ``(x [B,
-    T, d], call, cache, r) -> (x, cache, r)``, ``cache`` the layer's own
-    arrays (:func:`_layer_entries`) where ``call.paged`` and None
-    elsewhere, in and out.  ``r`` is the second stream that goes up the
+    """What every layer class is built from, and the one contract: ``(x,
+    call, cache, r) -> (x, cache, r)``, ``cache`` the layer's own arrays
+    (:func:`_layer_entries`) where ``call.paged`` and None elsewhere, in
+    and out.  ``x`` is the residual stream: ``[B, T, d]``, one row a
+    token, or under ``spec.streams > 1`` a STREAM OF ROWS ``[B, T,
+    streams, d]`` (hyper-connections), which the model
+    (:class:`TransformerPolicy`) expands from the embedding before the
+    first layer and sums into one row after the last; only a plain layer
+    (:class:`_Block`, through :class:`_HyperMix`) takes one.  Like ``r``
+    it is an activation: no cache field, no lane state, nothing a page
+    table or a fork has to know.  ``r`` is the second stream that goes up the
     stack beside ``x``: the router's state a ``router="mlp"`` layer reads
     from the layer before it and hands to the one after (``[B, T,
     router_width]`` float32; None into the first layer, and None all the
@@ -1107,6 +1284,120 @@ class _Layer(nn.Module):
     rotary: Optional[Callable] = None
 
 
+def _hc_bias_init(n: int):
+    """How a :class:`_HyperMix`'s bias is drawn: the read and write logits
+    (``2 n``) normal of deviation 1, the row-mixing matrix's ``2 I`` plus
+    normal of deviation 0.5 (row-major).  The published initialisation
+    (``alpha`` 0.01, an identity-like bias) would leave the
+    input-dependent half of every map invisible to a check on seeded
+    weights, and a matrix at the identity or at the uniform one would not
+    need its iterations; this draw is far from both."""
+
+    def init(key, shape, dtype=jnp.float32):
+        k_vec, k_mat = jax.random.split(key)
+        vec = jax.random.normal(k_vec, (2 * n,), jnp.float32)
+        mat = 2.0 * jnp.eye(n, dtype=jnp.float32) + 0.5 * jax.random.normal(
+            k_mat, (n, n), jnp.float32
+        )
+        return jnp.concatenate([vec, mat.reshape(-1)]).astype(dtype).reshape(shape)
+
+    return init
+
+
+def _hc_alpha_init(key, shape, dtype=jnp.float32):
+    """The three map gains, uniform in 0.5 .. 1.5 (see :func:`_hc_bias_init`)."""
+    return jax.random.uniform(key, shape, dtype, 0.5, 1.5)
+
+
+class _HyperMix(nn.Module):
+    """One sublayer's manifold-constrained hyper-connection, on a stream of
+    rows ``X [B, T, n, d]`` (``n = spec.streams``).  Per token::
+
+        u      = RMSNorm_{n d}(vec(X))                 a learned scale [n d]; vec row-major
+        z      = alpha * (u Phi) + b                   Phi [n d, n + n + n n]; alpha one gain a group
+        H_pre  = sigmoid(z[:n])                        the read weights
+        H_post = 2 sigmoid(z[n:2n])                    the write gates
+        M_0    = exp(clip(mat(z[2n:]), hc_clamp))      [n, n], row-major
+        M_t    = T_r(T_c(M_{t-1})),  t = 1..hc_iters   T_c: a column over (its sum + hc_eps); T_r: a row likewise
+        read:   h = sum_i H_pre[i] X[i]                the sublayer's one-row input
+        write:  X[i] <- sum_j M[i, j] X[j] + H_post[i] y
+
+    :meth:`read` makes the maps and the input, :meth:`write` takes the
+    sublayer's output back; a layer calls them where a one-row layer
+    calls its norm and :meth:`_Block._merge`.  Everything but the stream
+    is float32: the flattened norm, the projection (HIGHEST precision: 24
+    columns, no cost), the sigmoids, the iteration; the read and the
+    write accumulate in float32 and round once to the stream's dtype.
+    The iterations are a Python loop: a decode program holds no ``while``
+    of its own for them."""
+
+    spec: BlockSpec
+    d_model: int
+    dtype: jnp.dtype = jnp.float32
+
+    def setup(self):
+        n, width = self.spec.streams, self.spec.streams * self.d_model
+        f32 = jnp.float32
+        self.scale = self.param("scale", nn.initializers.ones, (width,), f32)
+        self.phi = self.param("phi", nn.initializers.lecun_normal(), (width, n * (n + 2)), f32)
+        self.b = self.param("b", _hc_bias_init(n), (n * (n + 2),), f32)
+        self.alpha = self.param("alpha", _hc_alpha_init, (3,), f32)
+
+    def maps(self, x):
+        """``(H_pre [B, T, n], H_post [B, T, n], M [B, T, n, n])`` of a
+        stream ``x [B, T, n, d]``, float32."""
+        spec, f32 = self.spec, jnp.float32
+        B, T, n, d = x.shape
+        exact = dict(precision=lax.Precision.HIGHEST)
+        with jax.named_scope("mhc_maps"):
+            flat = x.astype(f32).reshape(B, T, n * d)
+            ms = jnp.mean(jnp.square(flat), axis=-1, keepdims=True)
+            u = flat * lax.rsqrt(ms + spec.norm_eps) * self.scale
+            gain = self.alpha[np.repeat(np.arange(3), [n, n, n * n])]  # a gain a group
+            z = gain * jnp.dot(u, self.phi, **exact) + self.b
+            pre = jax.nn.sigmoid(z[..., :n])
+            post = 2.0 * jax.nn.sigmoid(z[..., n : 2 * n])
+            # the matrix's 16 entries side by side, row-major, and a
+            # normalisation's sums as ONE product with a constant 0/1
+            # matrix that adds the entries of a column (or a row) and hands
+            # each entry its sum back: a ``jnp.sum`` a normalisation stands
+            # alone as a ``reduce`` and its division as a second fusion
+            # (128 device operations a sublayer at the decode shape, 51 so;
+            # PERF.md, PR 51)
+            m = jnp.exp(jnp.clip(z[..., 2 * n :], *spec.hc_clamp))
+            k = np.arange(n * n)
+            same_col = jnp.asarray(k[:, None] % n == k[None, :] % n, f32)
+            same_row = jnp.asarray(k[:, None] // n == k[None, :] // n, f32)
+            for _ in range(spec.hc_iters):
+                m = m / (jnp.dot(m, same_col, **exact) + spec.hc_eps)  # columns
+                m = m / (jnp.dot(m, same_row, **exact) + spec.hc_eps)  # rows
+        return pre, post, m.reshape(B, T, n, n)
+
+    def read(self, x):
+        """``(h [B, T, d], maps)``: the sublayer's input and what
+        :meth:`write` needs."""
+        pre, post, m = self.maps(x)
+        with jax.named_scope("mhc_mix"):
+            h = sum(
+                pre[..., i, None] * x[:, :, i].astype(jnp.float32)
+                for i in range(x.shape[2])
+            )
+        return h.astype(self.dtype), (post, m)
+
+    def write(self, x, y, maps):
+        """The stream after the sublayer's output ``y [B, T, d]``."""
+        post, m = maps
+        n, f32 = x.shape[2], jnp.float32
+        with jax.named_scope("mhc_mix"):
+            rows = [x[:, :, j].astype(f32) for j in range(n)]
+            yf = y.astype(f32)
+            out = [
+                sum(m[..., i, j, None] * rows[j] for j in range(n)) + post[..., i, None] * yf
+                for i in range(n)
+            ]
+            return jnp.stack(out, axis=2).astype(x.dtype)
+
+
 class _Block(_Layer):
     """A plain layer (``layer="plain"``): ``x + Mixer(N(x))``, then ``x +
     FFN(N(x))``; the mixer this spec's attention (:func:`_mha`,
@@ -1115,7 +1406,10 @@ class _Block(_Layer):
     ``mixer="gdn"``, a Gated DeltaNet (:class:`_GatedDeltaMixer`).  Under
     ``spec.residual_scale`` both adds are ``(s x + c) + (s' y + c')``
     (:meth:`_merge`); under ``spec.router == "mlp"`` the experts' router
-    reads and hands on the stack's second stream ``r``."""
+    reads and hands on the stack's second stream ``r``.  Under
+    ``spec.streams > 1`` ``x`` is a stream of rows and each sublayer is
+    ``X <- write(X, F(N(read(X))))`` through a :class:`_HyperMix` of its
+    own (``attn_hc``, ``ffn_hc``) in place of the add."""
 
     def _merge(self, name: str, x, y):
         """A residual add; under ``spec.residual_scale`` with a learned
@@ -1129,12 +1423,23 @@ class _Block(_Layer):
         merged = (s[0] * x.astype(f32) + c[0]) + (s[1] * y.astype(f32) + c[1])
         return merged.astype(x.dtype)
 
+    def _sublayer(self, name: str, x):
+        """``(the sublayer's one-row input, how to take its output back)``
+        on a one-row stream (the row itself, :meth:`_merge`) or on a
+        stream of rows (a :class:`_HyperMix`'s read and write)."""
+        if self.spec.streams == 1:
+            return x, lambda y: self._merge(name, x, y)
+        mix = _HyperMix(self.spec, self.d_model, dtype=self.dtype, name=f"{name}_hc")
+        h, maps = mix.read(x)
+        return h, lambda y: mix.write(x, y, maps)
+
     @nn.compact
     def __call__(self, x, call: Call, cache: Optional[ModelCache] = None, r=None):
         spec = self.spec
         rms = spec.norm == "rmsnorm"
         dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
-        h = _norm(spec, self.dtype, "attn_norm" if rms else None)(x)
+        h, merge = self._sublayer("attn", x)
+        h = _norm(spec, self.dtype, "attn_norm" if rms else None)(h)
         if spec.mixer == "gdn":
             out, cache = _GatedDeltaMixer(self.d_model, spec, name="mixer", **dt)(h, call, cache)
         elif spec.attention == "mla":
@@ -1143,8 +1448,9 @@ class _Block(_Layer):
             out, cache = _sub_attention(_CompressedConvAttention, self, "attn")(h, call, cache)
         else:
             out, cache = _mha(self, h, call, cache)
-        x = self._merge("attn", x, out)
-        h = _norm(spec, self.dtype, "ffn_norm" if rms else None)(x)
+        x = merge(out)
+        h, merge = self._sublayer("ffn", x)
+        h = _norm(spec, self.dtype, "ffn_norm" if rms else None)(h)
         if spec.ffn == "experts":
             logits = None
             if spec.router == "mlp":
@@ -1175,7 +1481,7 @@ class _Block(_Layer):
             h = nn.Dense(self.mlp_ratio * self.d_model, name="mlp_in", **dt)(h)
             h = nn.gelu(h)
             h = nn.Dense(self.d_model, name="mlp_out", **dt)(h)
-        return self._merge("ffn", x, h), cache, r
+        return merge(h), cache, r
 
 
 class _LatentAttention(nn.Module):
@@ -1187,7 +1493,12 @@ class _LatentAttention(nn.Module):
     all heads share; scores ``(q_nope . k_nope + q_pe . k_pe) /
     sqrt(nope + rope)``, softmax in float32; ``o = concat(p v) W_o``.
     Under ``spec.mla_scale`` ``s_q = sqrt(d / q_lora_rank)`` and ``s_kv =
-    sqrt(d / kv_lora_rank)``; else both are 1.
+    sqrt(d / kv_lora_rank)``; else both are 1.  Under ``spec.rope_scaling``
+    (YaRN) the softmax scale is ``m^2 / sqrt(nope + rope)``
+    (:func:`yarn_terms`): ``scale`` below is its one source, handed to
+    every path that takes a scale (masked, prefill, tail, the segment
+    kernels, the paged decode) and multiplied into q for the one that
+    takes none (the causal ``attn_fn``).
 
     One set of parameters, two forms of the same product:
 
@@ -1265,6 +1576,11 @@ class _LatentAttention(nn.Module):
             "kv_b", up_init, (r_kv, H * (nope + vd)), self.param_dtype
         ).astype(self.dtype)
         scale = 1.0 / (nope + rope) ** 0.5
+        scaled = {}  # what a call that takes a scale is told (plain rotary: nothing)
+        if s.rope_scaling is not None:
+            m2 = yarn_terms(s.rope_scaling, rope, float(s.rope_theta)).softmax_factor
+            scale = m2 * scale
+            scaled = {"scale": scale}
         if call.paged:
             # the cached row, normed, scaled and rotated, zero to the
             # pool's whole tiles
@@ -1310,14 +1626,19 @@ class _LatentAttention(nn.Module):
             v = kvb[..., nope:]
             if call.mode == "packed":
                 # the segment kernels take a v narrower than q and k
-                out = self.segment_attn_fn(qf, k, v, call.segment_ids).astype(self.dtype)
+                out = self.segment_attn_fn(
+                    qf, k, v, call.segment_ids, **scaled
+                ).astype(self.dtype)
             elif call.mode == "causal":
                 # a causal ``attn_fn`` takes one head size: v padded to q
-                # and k's, the pad sliced off
+                # and k's, the pad sliced off; and no scale: YaRN's factor
+                # goes into q, in float32 and rounded once
+                if scaled:
+                    qf = (qf.astype(f32) * m2).astype(qf.dtype)
                 v = jnp.pad(v, ((0, 0),) * 3 + ((0, nope + rope - vd),))
                 out = self.attn_fn(qf, k, v)[..., :vd].astype(self.dtype)
             else:  # masked, prefill
-                out = _masked_attention(qf, k, v, call.attn_mask, self.dtype)
+                out = _masked_attention(qf, k, v, call.attn_mask, self.dtype, **scaled)
         out = dense(self.d_model, "proj")(out.reshape(B, T, H * vd))
         return out, cache
 
@@ -2067,6 +2388,7 @@ class _MixerBlock(_Layer):
     @nn.compact
     def __call__(self, x, call: Call, cache: Optional[ModelCache] = None, r=None):
         spec = self.spec
+        _one_row("mixer", spec)
         dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         h = _norm(spec, self.dtype, "norm")(x)
         if spec.mixer == "mamba":
@@ -2105,6 +2427,7 @@ class _ShortcutBlock(_Layer):
     @nn.compact
     def __call__(self, x, call: Call, cache: Optional[ModelCache] = None, r=None):
         spec = self.spec
+        _one_row("scmoe", spec)
         dt = dict(dtype=self.dtype, param_dtype=self.param_dtype)
 
         def attention(i, x):
@@ -2163,7 +2486,7 @@ class _MTPModule(_Layer):
 
 
 @functools.lru_cache(maxsize=None)
-def _note_layers(shape, kinds, attention, held, num_experts, mtp_layers) -> None:
+def _note_layers(shape, kinds, attention, held, num_experts, mtp_layers, residual) -> None:
     """A stack always runs the layers it was built from, so its counter is
     its make-up: one zero-length program span a traced shape (the cache is
     the "once"), so that a trace says which stack ran."""
@@ -2172,7 +2495,25 @@ def _note_layers(shape, kinds, attention, held, num_experts, mtp_layers) -> None
     with tracing.span(
         "model.layers", kind="model", shape=list(shape), layers=list(kinds),
         attention=attention, held=held, num_experts=num_experts,
-        mtp_layers=mtp_layers,
+        mtp_layers=mtp_layers, residual=residual,
+    ):
+        pass
+
+
+_MHC_PATHS = {"causal": "whole", "masked": "whole"}  # the other forms: their own names
+
+
+@functools.lru_cache(maxsize=None)
+def _note_mhc_form(shape, spec: BlockSpec, stream_dtype, sublayers, path) -> None:
+    """A stream of rows and what mixes it: one zero-length program span a
+    traced shape (the cache is the "once")."""
+    from scalerl_tpu.runtime import tracing
+
+    with tracing.span(
+        "mhc.form", kind="model", shape=list(shape), streams=spec.streams,
+        iters=spec.hc_iters, eps=spec.hc_eps, clamp=list(spec.hc_clamp),
+        map_dtype="float32", stream_dtype=stream_dtype, sublayers=sublayers,
+        path=path,
     ):
         pass
 
@@ -2235,6 +2576,10 @@ class TransformerPolicy(nn.Module):
     block: BlockSpec = BlockSpec()
     # The stack as a per-layer list (``layer_specs``); empty: ``block``,
     # ``num_layers`` times.  The layers share ``block``'s attention kind.
+    # Under ``block.streams > 1`` the residual stream between the layers is
+    # a stream of rows ``[B, T, streams, d]``: this class copies the
+    # embedding into every row before the first layer and sums the rows
+    # before the final norm (:class:`_HyperMix` mixes them in between).
     layers: Tuple[BlockSpec, ...] = ()
     # Multi-token-prediction modules (0 | 1): a layer of ``block``'s kind
     # with weights of its own under ``mtp/``, which a forward called with
@@ -2351,7 +2696,14 @@ class TransformerPolicy(nn.Module):
                 tuple(obs.shape),
                 tuple(s.kind for s in specs),
                 spec.attention, spec.experts_held or spec.num_experts,
-                spec.num_experts, self.mtp_layers,
+                spec.num_experts, self.mtp_layers, spec.residual,
+            )
+        if spec.streams > 1 and self.mtp_layers:
+            raise ValueError(
+                "mtp_layers > 0 with a residual stream of more than one row: "
+                "how a multi-token-prediction module reads a stream of rows is "
+                "no key of any configuration here, so none is built (a rollout "
+                "worker without speculation drops the module anyway)"
             )
         attn = self.attn_fn
         if attn is None:
@@ -2390,8 +2742,14 @@ class TransformerPolicy(nn.Module):
         if spec.attention == "mla":
             rotary = rotary_fn(
                 positions, spec.qk_rope_head_dim, spec.rope_theta,
-                spec.rope_pairing,
+                spec.rope_pairing, spec.rope_scaling,
             )
+            if spec.rope_scaling is not None and not self.is_initializing():
+                terms = yarn_terms(spec.rope_scaling, spec.qk_rope_head_dim, float(spec.rope_theta))
+                _note_rope_form(
+                    tuple(obs.shape), float(spec.rope_scaling.factor), terms.low, terms.high,
+                    terms.softmax_factor / self.head_dim ** 0.5,
+                )
         elif spec.positions == "rope":
             rotary = rotary_fn(
                 positions, spec.rotary_dim or self.head_dim, spec.rope_theta
@@ -2405,6 +2763,15 @@ class TransformerPolicy(nn.Module):
             )
             x = x + pos_tab[positions].astype(self.dtype)
         x = c(x)
+        if spec.streams > 1:
+            # the expansion: a token's embedding in every row of its stream
+            x = c(jnp.broadcast_to(x[:, :, None, :], (B, T, spec.streams, self.d_model)))
+            if not self.is_initializing():
+                _note_mhc_form(
+                    tuple(x.shape), spec, jnp.dtype(self.dtype).name,
+                    2 * len(specs),
+                    "packed" if segment_ids is not None else _MHC_PATHS.get(call.mode, call.mode),
+                )
         entries = _layer_entries(paged_cache, specs) if call.paged else [None] * len(specs)
         r = None  # the stack's second stream (:class:`_Layer`)
         for i, layer in enumerate(specs):
@@ -2417,6 +2784,9 @@ class TransformerPolicy(nn.Module):
             )
             x, entries[i], r = block(x, call, entries[i], r)
             x = c(x)
+        if spec.streams > 1:
+            # the read-out: the rows' sum, in the final norm's float32
+            x = jnp.sum(x.astype(jnp.float32), axis=2)
         final_norm = functools.partial(_norm, spec, jnp.float32)
         policy_head = nn.Dense(self.num_actions, name="policy_head")
         mtp_logits = None

@@ -134,6 +134,15 @@ PARAM_LOGICAL_AXES: Dict[Tuple[str, ...], Tuple[Optional[str], ...]] = {
     ("v1", "kernel"): ("embed", None),
     ("v2", "kernel"): ("embed", None),
     ("reduce", "kernel"): ("embed", None),
+    # The xing4 stack: joyai's rules, on a residual stream of rows ``[B, T,
+    # n, d]``.  The stream's row axis replicates and its ``d`` axis takes
+    # what a one-row ``x``'s takes (:func:`activation_constraint` pins the
+    # batch axis and replicates every other, whatever the rank).  A
+    # hyper-connection's ``phi`` ``[n d, n (n + 2)]`` contracts the
+    # flattened stream (its long axis is ``n d``: the stream's, replicated
+    # like ``embed``) into 24 columns; its ``scale``, ``b`` and ``alpha``
+    # match no rule and replicate.
+    ("phi",): ("embed", None),
 }
 
 

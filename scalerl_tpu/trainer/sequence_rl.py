@@ -52,6 +52,7 @@ from scalerl_tpu.genrl.rollout import (
 )
 from scalerl_tpu.genrl.task import TokenRecallTask
 from scalerl_tpu.models.transformer import (
+    RopeScaling,
     TransformerPolicy,
     block_spec,
     interval_specs,
@@ -119,6 +120,17 @@ def build_genrl_model(args: GenRLArguments) -> TransformerPolicy:
         cca_time0=args.cca_time0,
         cca_time1=args.cca_time1,
         router_width=args.router_hidden,
+        streams=args.hc_mult,
+        hc_iters=args.hc_sinkhorn_iters,
+        hc_eps=args.hc_eps,
+        hc_clamp=(args.hc_clamp_min, args.hc_clamp_max),
+        rope_scaling=(
+            RopeScaling(
+                args.rope_factor, args.rope_original_max, args.rope_beta_fast,
+                args.rope_beta_slow, args.rope_mscale, args.rope_mscale_all_dim,
+            )
+            if args.rope_factor != 1.0 else None
+        ),
     )
     if args.layer_pattern:
         layers = pattern_specs(spec, args.layer_pattern)

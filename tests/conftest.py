@@ -49,7 +49,7 @@ assert len(jax.devices()) == 8, "expected 8 virtual CPU devices"
 # file is one worker's from start to end and files are handed out in
 # collection order, so such a file would start when the others are nearly
 # done and run on alone.  They go first; every other file keeps its place.
-_STARTS_FIRST = ("test_zaya_block.py",)
+_STARTS_FIRST = ("test_zaya_block.py", "test_xing4_block.py")
 
 
 def pytest_collection_modifyitems(items):

@@ -513,6 +513,72 @@ def test_cca_prefill_and_fork_leave_the_window_in_place(cca_engine, one_chip, pr
     _assert_window_in_place(text, eng)
 
 
+
+# -- a residual stream of rows around a latent attention (ISSUE 51) ------------
+
+
+@pytest.fixture(scope="module")
+def rows_engine():
+    """A dense and a routed ``xing4`` layer at the latent attention's
+    published row (8 heads of 128 + 64, rows of 512 + 64 in 640 lanes) on a
+    hidden size of 512, a stream of 4 rows with 20 Sinkhorn iterations,
+    YaRN of factor 64 over 4,096 positions, 4 experts of 128 beside a
+    shared one, 4 lanes; two pools ``[301, 8, 640]``.  The latent kernel is
+    pinned compiled."""
+    from scalerl_tpu.models.transformer import RopeScaling, layer_specs
+
+    vocab = 128
+    spec = block_spec(
+        "xing4", norm_eps=1e-6, num_experts=4, experts_per_token=2, expert_width=128,
+        norm_topk_prob=True, q_lora_rank=256, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, ffn_hidden=256, routed_scaling=2.0,
+        scoring="sigmoid", shared_experts=1, streams=4, hc_iters=20,
+        rope_scaling=RopeScaling(64.0, 4096, 32.0, 1.0, 1.0, 1.0),
+    )
+    model = TransformerPolicy(
+        num_actions=vocab, vocab_size=vocab, d_model=512, num_heads=8, num_layers=2,
+        max_len=256, block=spec, layers=layer_specs(spec, 2, 1),
+        paged_attn_fn=functools.partial(paged_decode_latent, interpret=False),
+    )
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
+    return ContinuousEngine(
+        model, params,
+        ContinuousConfig(
+            vocab_size=vocab, max_prompt_len=64, max_new_tokens=64,
+            lanes=LANES, page_size=PAGE, num_pages=PAGES, steps_per_macro=2,
+        ),
+        iter_mode="scan",
+    )
+
+
+def test_rows_decode_holds_one_loop_and_no_lane_state(rows_engine, one_chip):
+    """The decode macro-step of a stack on a stream of rows: one
+    ``paged_decode_latent`` a layer, every pool donated and returned as
+    itself and none copied, NO array by lane beside them (the stream is an
+    activation), the substep loop the program's only ``while`` (the 20
+    iterations of four hyper-connections are unrolled), and the two scopes
+    the benchmark's AOT script counts by in the compiled text's
+    ``op_name``."""
+    eng = rows_engine
+    M = eng._table.shape[1]
+    text = _compiled_text(eng, one_chip, eng._decode_fn, params=True, extra=[(LANES, M), "key"])
+    assert len(re.findall(r"custom-call\(.*paged_decode_latent", text)) == 2
+    assert "/mhc_maps/" in text and "/mhc_mix/" in text
+    assert len(re.findall(r" while\(", text)) == 1
+    _assert_pools_read_in_place(text, eng)
+    cache = eng._pools
+    assert len(cache.rows) == 2 and not (cache.k or cache.v or cache.ssm or cache.conv)
+    assert eng.stats()["state_bytes_per_lane"] == 0
+    leaves = len(jax.tree_util.tree_leaves(eng._snapshot_params()[0]))
+    header = text[text.index("input_output_alias={"):].split("\n", 1)[0]
+    aliased = {
+        int(out): int(param)
+        for out, param in re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
+    }
+    for i in range(2):
+        assert aliased.get(i) == leaves + i, (i, aliased)
+
+
 # -- the fused classic loop (ISSUE 31) ---------------------------------------
 
 

@@ -36,6 +36,8 @@ LEAVES = {
     "zaya": dict(  # every layer an attention with pages AND a window, no state (ISSUE 46)
         k=[_POOL(16)] * 3, v=[_POOL(16)] * 3, conv=[(LANES, 2 * 56)] * 3,
     ),
+    # joyai's pools and nothing else: a stream of rows is an activation (ISSUE 51)
+    "xing4": dict(rows=[_POOL(128)] * 3),
 }
 FAMILIES = sorted(LEAVES)
 

@@ -25,7 +25,8 @@ the tree of the PR that added it, ISSUE 46: the next PR's parent) with this
 environment's JAX, and have passed unchanged on every commit since; both
 ``paged_decode.*`` again on the tree of ISSUE 50, which meant to change the
 kernel (a run of adjacent pages in one copy, the call under its own
-``jax.jit``) and changed no family's program.  After
+``jax.jit``) and changed no family's program; xing4 on the tree of the PR
+that added it, ISSUE 51, which changed no other family's.  After
 a JAX upgrade, take them again from a commit known to be unchanged.
 """
 
@@ -71,6 +72,10 @@ PARENT = {
     "zaya.prefill": "089f5d1a6a55e63b",
     "zaya.decode": "ac11e3213a475442",
     "zaya.values": "c3b4e6916bc10194",
+    "xing4.packed": "dcff4dd0d4e197f3",
+    "xing4.decode": "a28da17b6ab9895d",
+    "xing4.tail_prefill": "9147d7beb59a21d4",
+    "xing4.values": "c8159e7ccb0cb400",
     "paged_decode.kernel": "57daef2ccfd90b3d",
     "paged_decode.grouped_kernel": "817adece2559f7ff",
 }

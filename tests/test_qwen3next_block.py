@@ -191,7 +191,7 @@ def test_arguments_the_family_refuses():
         parse_args(GenRLArguments, ["--full-attention-interval", "4"]).validate()
     with pytest.raises(ValueError, match="qwen3_next family's"):
         parse_args(GenRLArguments, ["--block-family", "olmoe", "--rotary-dim", "4"]).validate()
-    with pytest.raises(ValueError, match="one of the seven families"):
+    with pytest.raises(ValueError, match="one of the eight families"):
         parse_args(GenRLArguments, ["--block-family", "qwen4"]).validate()
     with pytest.raises(ValueError, match="gpt2 \\| olmoe \\| longcat \\| joyai \\| nemotron_h \\| qwen3_next"):
         block_spec("qwen4")

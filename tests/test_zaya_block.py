@@ -167,7 +167,7 @@ def test_program_arguments_choose_the_family(net):
 
 
 def test_arguments_the_family_refuses():
-    with pytest.raises(ValueError, match="seven families"):
+    with pytest.raises(ValueError, match="eight families"):
         _args("--block-family", "zeya")
     for extra, match in (
         (("--spec-enable", "true"), "carries lane state"),

@@ -4,9 +4,11 @@ run on.  Widths are the smallest at which every branch of a family's layer
 is taken: 4 heads, 8 experts with 3 a token of which 4 are held, latent
 ranks 24 and 16, 4 state heads of 8 in 2 groups and a chunk of 8; ``zaya``:
 4 heads over 2 of 8, both convolutions at 2 taps, a router of width 8 with
-one pick of 4 experts."""
+one pick of 4 experts; ``xing4``: joyai's sizes with every expert held on a
+stream of 4 rows, 3 Sinkhorn iterations and YaRN of factor 4 over 8 positions."""
 
 from scalerl_tpu.models.transformer import (
+    RopeScaling,
     TransformerPolicy,
     block_spec,
     interval_specs,
@@ -57,5 +59,11 @@ MODELS = {
         "zaya", d_model=32, num_layers=3, head_dim=8, norm_eps=1e-5, rope_theta=5e6,
         num_experts=4, experts_per_token=1, expert_width=16, kv_heads=2, rotary_dim=4,
         cca_time0=2, cca_time1=2, router_width=8,
+    ),
+    "xing4": _model(
+        "xing4", num_layers=3, stack=lambda s: layer_specs(s, 3, 1), norm_eps=1e-6,
+        **_ROUTED, **_LATENT, norm_topk_prob=True, ffn_hidden=96, routed_scaling=2.0,
+        scoring="sigmoid", shared_experts=1, streams=4, hc_iters=3,
+        rope_scaling=RopeScaling(4.0, 8, 32.0, 1.0, 1.0, 1.0),
     ),
 }
