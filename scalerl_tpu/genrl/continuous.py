@@ -126,6 +126,7 @@ from scalerl_tpu.models.transformer import (
     prompt_attention_mask,
 )
 from scalerl_tpu.ops.pallas_paged_attention import (
+    largest_copy,
     make_paged_attn_fn,
     pages_per_block,
     table_copies,
@@ -431,12 +432,11 @@ class ContinuousEngine(ParamSnapshotPlane):
         # (with lane state: pools and the lane-indexed arrays)
         self._pools = model.init_paged_cache(num_pages, ps, lanes=L)
         # what the decode kernels' walk of a pool goes by: a fresh run of
-        # pages starts where it can grow to a block of that walk
+        # pages starts where it can grow to the largest copy of that walk
+        # (a block of a narrow pool holds several)
         pool = (self._pools.k + self._pools.rows)[0]
         self._copy_rule = (ps, pool.shape[2], pool.dtype.itemsize, num_pages)
-        self.allocator = PageAllocator(
-            num_pages, ps, stretch=pages_per_block(*self._copy_rule[:3])
-        )
+        self.allocator = PageAllocator(num_pages, ps, stretch=largest_copy(*self._copy_rule))
         self._worst_pages = self.allocator.pages_for_tokens(max_context)
         self._prefix_cache: Optional[PrefixCache] = None
         if config.prefix_cache:
@@ -1881,6 +1881,8 @@ class ContinuousEngine(ParamSnapshotPlane):
             "pages_per_copy": self._table_pages / max(self._table_copies, 1),
             "table_pages": self._table_pages,
             "table_copies": self._table_copies,
+            # tokens the kernels' walk fetches and attends to at a step
+            "block_tokens": pages_per_block(*self._copy_rule[:3]) * self.config.page_size,
         }
 
     def _harvest(

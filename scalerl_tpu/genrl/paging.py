@@ -30,7 +30,7 @@ kernels fetch a run of adjacent pool pages in one copy, and a copy's issue,
 not its bytes, is what a small page costs.  :meth:`alloc` takes the page
 after the holder's last one (``after=``) when it is free, and otherwise
 starts where a run can grow: at the first page of a wholly free stretch
-of ``stretch`` pages (the kernels' block), else at any free page.  It is a preference, never a promise: every free
+of ``stretch`` pages (the kernels' largest copy), else at any free page.  It is a preference, never a promise: every free
 page is handed out before an ``alloc`` is refused, the kernels read
 adjacency off the table and are right for any table, and a churny run
 still fragments lane->page maps, which is why fragmentation-independence
@@ -76,8 +76,8 @@ class PageAllocator:
         # O(1) beside the pop from the end
         self._free: Dict[int, None] = dict.fromkeys(range(num_pages - 1, 0, -1))
         # where a fresh run starts: stretch c is pages [1 + c * stretch,
-        # 1 + (c + 1) * stretch), the pages the decode kernels fetch at a
-        # step (``pages_per_block``); how many pages of each are free, and
+        # 1 + (c + 1) * stretch), the pages the decode kernels' largest copy
+        # fetches (``largest_copy``); how many pages of each are free, and
         # which stretches are wholly free (the lowest last: started first)
         self.stretch = stretch
         self._free_in = [

@@ -28,7 +28,8 @@ Two implementations behind one contract:
   lane a step with all its heads, the page table (as the list of the
   copies that fetch it) and lengths as *scalar-prefetch* operands.  The pools stay in HBM; inside a lane the
   kernel walks the table in blocks of ``P`` pages
-  (:func:`pages_per_block`: 128 tokens, from the pool's shape alone) to
+  (:func:`pages_per_block`: 128 tokens or, for a narrow row, the whole
+  multiples of 128 that fill 512 KiB of a pool; from the pool's shape alone) to
   ``cdiv(length, P * page_size)`` and no further, copying the *live* pages
   into a double-buffered VMEM block while the previous block is attended
   to, the next lane's first block included: one async copy a RUN of live
@@ -161,10 +162,26 @@ def paged_attention_reference(
     return out[:, None].astype(q.dtype)
 
 
-# tokens in one block of the walk: one whole 128-lane row of scores a head.
-# Measured on the chip (PERF.md, PR 26): 64 pays the block's fixed cost
-# twice as often, 256 computes more masked positions in dead lanes and tails
-_BLOCK_TOKENS = 128
+# a block of the walk is whole steps of 128 tokens (one 128-lane row of
+# scores a head), at least one, as many as fill ``_BLOCK_BYTES`` of a pool:
+# a block costs about half a microsecond whatever it holds (the counted
+# loops that start and wait for its copies, the serial chain product ->
+# mask -> max -> exp -> sum -> product), so a narrow row wants more tokens,
+# and every position of a block is computed, masked or not, so not too many.
+# Measured on the chip, the kernel alone on tables as the engine makes them,
+# us a call at 128 | 256 | 512 | 1,024 tokens (PERF.md, PR 53): rows of 1 KiB
+# (two key/value heads of 128 float32) 131 | 108 | 105 | 137 at 40 lanes and
+# 347 | 247 | 253 | 342 at 96; rows of 2 KiB 327 | 284 | 313 | 445; at rows
+# of 4 KiB (gpt2-medium, PERF.md, PR 26) 64 pays the block's cost twice as
+# often and 256 computes more masked positions in dead lanes and tails.
+# 512 KiB is 512 tokens of 1 KiB, 256 of 2 KiB, and 128 from 2.5 KiB up
+_BLOCK_STEP = 128
+_BLOCK_BYTES = 512 * 2**10
+# ... and no row, however narrow, is given more than the most that paid at
+# the narrowest row measured, 1 KiB (1,024 tokens read worse than 128 there).
+# A GUARD, not a measured optimum: no cell has a row under 1 KiB, and
+# without it a row of 64 B would get a block of 8,192 tokens
+_BLOCK_MOST = 512
 # bytes the K and V blocks may hold in VMEM, two buffers each: half of the
 # 16 MiB a v5e kernel gets by default
 _VMEM_BUDGET = 8 * 2**20
@@ -172,10 +189,14 @@ _VMEM_BUDGET = 8 * 2**20
 
 def pages_per_block(page_size: int, width: int, itemsize: int) -> int:
     """Pages the kernel fetches and attends to at a step, from the pool's
-    shape alone: ``_BLOCK_TOKENS`` tokens, fewer where ``width = H*D`` is so
-    large that four such blocks would pass ``_VMEM_BUDGET``, never under one
+    row alone (``width * itemsize`` bytes a token): the whole steps of 128
+    tokens that fill ``_BLOCK_BYTES`` of one pool, at least one step and at
+    most ``_BLOCK_MOST`` tokens; fewer where ``width = H*D`` is so large
+    that four such blocks would pass ``_VMEM_BUDGET``, never under one
     page."""
-    tokens = min(_BLOCK_TOKENS, _VMEM_BUDGET // (4 * width * itemsize))
+    row = width * itemsize
+    tokens = max(_BLOCK_STEP, _BLOCK_BYTES // row // _BLOCK_STEP * _BLOCK_STEP)
+    tokens = min(tokens, _BLOCK_MOST, _VMEM_BUDGET // (4 * row))
     return max(1, tokens // page_size)
 
 
@@ -268,6 +289,13 @@ def _run_sizes(block_pages: int, pool_pages: int):
     return tuple(n for n in _RUN_SIZES if n <= min(block_pages, pool_pages))
 
 
+def largest_copy(page_size: int, width: int, itemsize: int, pool_pages: int) -> int:
+    """Pages the walk's largest copy fetches from such a pool: the stretch
+    of adjacent free pages that lets a fresh run go out in whole copies
+    (a block may hold several)."""
+    return _run_sizes(pages_per_block(page_size, width, itemsize), pool_pages)[0]
+
+
 def _copy_list(page_table, lengths, page_size: int, block_pages: int, pool_pages: int):
     """The copies that fetch ``page_table``'s live pages, a block of
     ``block_pages`` slots at a time, as the kernels walk them: ``(list,
@@ -348,6 +376,22 @@ def _each_copy(list_ref, ends_ref, lane, block, block_pages, sizes, copy):
 
         jax.lax.fori_loop(begin, end, one, 0)
         begin = end
+
+
+@functools.lru_cache(maxsize=None)
+def _note_tiling(name: str, q_shape, pool_shape, dtype: str, pages: int) -> None:
+    """One zero-length program span a traced shape (the cache is the
+    "once"), ``paged_decode.tiling`` or ``latent_decode.tiling``, so that a
+    trace says which walk ran: lanes, heads, the row's width, tokens a
+    block."""
+    from scalerl_tpu.runtime import tracing
+
+    with tracing.span(
+        name, kind="kernel", shape=list(q_shape),
+        pool=list(pool_shape), dtype=dtype, pages_per_block=pages,
+        block_tokens=pages * pool_shape[1],
+    ):
+        pass
 
 
 def _decode_kernel(
@@ -540,6 +584,7 @@ def _paged_decode(q, k_pages, v_pages, page_table, lengths, scale, interpret, pa
         )
     P = pages_per_block(ps, width, k_pages.dtype.itemsize)
     rows = -(-H // 16) * 16  # whole bfloat16 sublane tiles of heads
+    _note_tiling("paged_decode.tiling", tuple(q.shape), tuple(k_pages.shape), str(k_pages.dtype), P)
 
     if group == 1:
         # q and o go a lane's row at a time through the pipeline; Mosaic tiles
@@ -647,21 +692,6 @@ def paged_latent_attention_reference(
     rows = gather_pages(pool, page_table, 1)[:, :, 0, : q.shape[-1]]  # [B, S, W]
     valid = jnp.arange(rows.shape[1])[None, :] < lengths[:, None]
     return latent_attention(q, rows, valid[:, None, :], value_width, scale)
-
-
-@functools.lru_cache(maxsize=None)
-def _note_latent_tiling(q_shape, pool_shape, dtype: str, pages: int) -> None:
-    """One zero-length program span a traced shape (the cache is the
-    "once"), so that a trace says which walk ran: lanes, heads, the row's
-    width, tokens a block."""
-    from scalerl_tpu.runtime import tracing
-
-    with tracing.span(
-        "latent_decode.tiling", kind="kernel", shape=list(q_shape),
-        pool=list(pool_shape), dtype=dtype, pages_per_block=pages,
-        block_tokens=pages * pool_shape[1],
-    ):
-        pass
 
 
 def _latent_kernel(
@@ -789,7 +819,7 @@ def _paged_latent(q, pool, page_table, lengths, value_width, scale, interpret, p
     ps = pool.shape[1]
     P = pages_per_block(ps, pool.shape[2], pool.dtype.itemsize)
     rows = -(-H // 16) * 16  # whole bfloat16 sublane tiles of heads
-    _note_latent_tiling(tuple(q.shape), tuple(pool.shape), str(pool.dtype), P)
+    _note_tiling("latent_decode.tiling", tuple(q.shape), tuple(pool.shape), str(pool.dtype), P)
     qp = q.reshape(B, H, W)
     if (rows, pool.shape[2]) != (H, W):
         # zeros meet the pool's pad columns, and the rows past the last head

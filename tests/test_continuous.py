@@ -244,7 +244,7 @@ def test_group_members_tables_are_a_shared_run_then_an_own_run():
     member's table is those shared pages then a run of its own that starts
     at its copy of the partial page, and growth continues each lane's own
     run (ISSUE 50)."""
-    eng = _run_engine(4, num_pages=1025)  # sixteen stretches of 64 pages: room for runs
+    eng = _run_engine(4, num_pages=1025)  # sixty-four stretches of 16 pages: room for runs
     prompt = np.arange(2, 11).astype(np.int32)  # 9 tokens: 4 full pages and a partial one
     assert eng.submit_group(prompt, 4, len(prompt))
     for _ in range(3):
@@ -316,6 +316,55 @@ def test_decode_program_traces_the_kernel_body_once(monkeypatch):
     text = eng.lower_decode().as_text()
     assert len(calls) == 1
     assert text.count("paged_decode") >= 1
+
+
+def test_a_narrow_pool_walks_long_blocks_and_decodes_the_references_tokens(monkeypatch):
+    """ISSUE 53: a pool of 1 KiB a token (two heads of 128 float32) is
+    walked 512 tokens a block, which ``stats()`` and one zero-length span a
+    traced shape say; the allocator's stretch stays the 16 pages of one
+    copy; and through the kernel the engine decodes the full forward's
+    greedy tokens."""
+    from scalerl_tpu.ops.pallas_paged_attention import pages_per_block
+    from scalerl_tpu.runtime import tracing
+
+    monkeypatch.setenv(tracing.ENV_SAMPLE, "1.0")
+    tracing.reset()
+    try:
+        m = TransformerPolicy(
+            num_actions=V, vocab_size=V, d_model=256, num_heads=2, num_layers=2, max_len=16,
+        )
+        params = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
+        rng = np.random.default_rng(53)
+        prompts = rng.integers(2, V, size=(3, P_MAX)).astype(np.int32)
+        lengths = np.array([6, 3, 1], np.int32)
+        ref = greedy_full_forward(m, params, prompts, lengths, P_MAX, R_MAX)
+        eng = ContinuousEngine(
+            m, params,
+            ContinuousConfig(
+                vocab_size=V, max_prompt_len=P_MAX, max_new_tokens=R_MAX, temperature=0.0,
+                seed=7, lanes=3, page_size=8, num_pages=129, steps_per_macro=3,
+                steps_in_flight=1, paged_attn="pallas",
+            ),
+        )
+        assert eng.stats()["block_tokens"] == pages_per_block(8, 2 * 128, 4) * 8 == 512
+        assert eng.allocator.stretch == 16
+        for i in range(3):
+            eng.submit(prompts[i], lengths[i])
+        done = _by_prompt(eng.run_until(3, max_macro_steps=20))
+        for i in range(3):
+            c = done[tuple(prompts[i][: lengths[i]].tolist())]
+            np.testing.assert_array_equal(c.response_tokens, ref.response_tokens[i])
+            np.testing.assert_allclose(c.behavior_logp, ref.behavior_logp[i], atol=1e-5)
+        spans = [
+            s for s in tracing.get_tracer().finished() if s["name"] == "paged_decode.tiling"
+        ]
+        assert len(spans) == 1, spans  # two layers, one traced shape
+        attrs = spans[0]["attrs"]
+        assert attrs["shape"] == [3, 1, 2, 128] and attrs["pool"] == [129, 8, 256]
+        assert (attrs["dtype"], attrs["pages_per_block"], attrs["block_tokens"]) == ("float32", 64, 512)
+    finally:
+        monkeypatch.delenv(tracing.ENV_SAMPLE)
+        tracing.reset()
 
 
 def test_quantized_push_params_logits_parity(setup):

@@ -21,6 +21,7 @@ from scalerl_tpu.models.transformer import (
 )
 from scalerl_tpu.ops.pallas_paged_attention import (
     _VMEM_BUDGET,
+    largest_copy,
     pages_per_block,
     paged_attention_reference,
     paged_decode_attention,
@@ -459,21 +460,29 @@ def test_paged_kernel_matches_reference_across_layouts(table, lengths):
 
 @pytest.mark.parametrize("scratch", ["plain", "nan"])
 @pytest.mark.parametrize(
-    "lengths",
+    "W,VW,block,M,lengths",
     [
-        [1, 7, 8, 130, 160],  # ends inside a page, on a page, inside the second block
-        [128, 129, 1, 256, 255],  # on and around the 128-token block's boundary
+        # the published row (576 -> 640 columns, 2.5 KiB): a block of 128 tokens
+        # ends inside a page, on a page, inside the second block
+        (576, 512, 16, 32, [1, 7, 8, 130, 160]),
+        # on and around the 128-token block's boundary
+        (576, 512, 16, 32, [128, 129, 1, 256, 255]),
+        # a narrow row (48 -> 128 columns, 512 B): a block of 512 tokens; on
+        # and around its first and second boundary, the table no multiple of it
+        (48, 32, 64, 136, [511, 512, 513, 1023, 1025]),
     ],
+    ids=["inside_blocks", "around_128", "around_512"],
 )
-def test_latent_kernel_matches_reference(lengths, scratch):
+def test_latent_kernel_matches_reference(W, VW, block, M, lengths, scratch):
     """``paged_decode_latent`` (the absorbed MLA decode over ONE pool of
     shared rows, ISSUE 30) in interpret mode against its XLA gather twin:
     fragmented tables whose slots past the length name other lanes' pages,
-    lengths that end inside a block, and, under ``nan``, the TPU
-    interpreter with every scratch buffer filled with NaN, so that a
-    position the kernel neither fetched nor zeroed would poison the
-    result.  The pool's pad columns (48 -> 128 lanes) hold junk: the
-    query is zero there."""
+    lengths that end inside a block and on and around its boundary, at the
+    block the rule gives the row (asserted: ISSUE 53), and, under ``nan``,
+    the TPU interpreter with every scratch buffer filled with NaN, so that
+    a position the kernel neither fetched nor zeroed would poison the
+    result.  The pool's pad columns (to whole tiles of 128 lanes) hold
+    junk: the query is zero there."""
     from jax.experimental.pallas import tpu as pltpu
 
     from scalerl_tpu.ops.pallas_paged_attention import (
@@ -483,13 +492,16 @@ def test_latent_kernel_matches_reference(lengths, scratch):
     )
 
     rng = np.random.default_rng(8)
-    B, H, W, VW, ps, N, M = len(lengths), 4, 48, 32, 8, 120, 32
+    B, H, ps = len(lengths), 4, 8
+    N = B * M // 2 + 8
+    assert pages_per_block(ps, latent_pool_width(W), 4) == block
     q = jnp.asarray(rng.normal(size=(B, 1, H, W)), jnp.float32)
     pool = jnp.asarray(rng.normal(size=(N, ps, latent_pool_width(W))), jnp.float32)
     table = jnp.asarray(rng.permutation(np.arange(1, N))[: B * M // 2].reshape(B, M // 2))
     table = jnp.concatenate([table, table[::-1]], axis=1).astype(jnp.int32)
     ln = jnp.asarray(lengths, jnp.int32)
-    ref = paged_latent_attention_reference(q, pool, table, ln, VW, 0.2)
+    # the reference to its end before the interpreter's host callbacks start
+    ref = jax.block_until_ready(paged_latent_attention_reference(q, pool, table, ln, VW, 0.2))
     interpret = pltpu.InterpretParams() if scratch == "nan" else True
     out = paged_decode_latent(q, pool, table, ln, VW, 0.2, interpret=interpret)
     assert out.shape == (B, 1, H, VW) and bool(jnp.all(jnp.isfinite(out)))
@@ -616,28 +628,52 @@ def test_four_d_entry_is_the_dense_one(H, D, ps):
 
 
 # the walk over a lane's live pages, a block of P pages at a step (ISSUE
-# 26).  A block is 128 tokens: 16 pages of 8, 8 pages of 16.
+# 26).  A block follows from the row's bytes (ISSUE 53), so each case names
+# the rows it is run at and the pages its block then holds: the test
+# asserts that block, so a later rule cannot leave the lengths below on no
+# boundary without failing here.
+
+# rows whose block is 128 tokens, 16 pages of 8 and 8 of 16: eight heads of
+# 128 (4 KiB, a whole number of 128-lane tiles) and nine of 72 (2,592 B,
+# not one: the block spans the axis)
+_WIDE_ROWS = [(8, 128), (9, 72)]
+# ... and 512 tokens, 64 pages of 8 and 32 of 16: two heads of 128 (1 KiB,
+# zaya's and nemotron's) and two of 8 (64 B: no row gets a longer block)
+_NARROW_ROWS = [(2, 128), (2, 8)]
 
 _WALK_CASES = {
-    # name: (page size, table slots M, the lanes' lengths)
-    # k*P*ps - 1, k*P*ps, k*P*ps + 1 for k = 1, 2; M = 40 is no multiple of P
-    "around_block_boundaries": (8, 40, [127, 128, 129, 255, 256, 257]),
-    "one_token_and_the_full_width": (8, 40, [1, 320, 1, 319, 313]),
-    "table_narrower_than_a_block": (8, 5, [1, 40, 17, 33]),  # M < P
-    "one_long_lane_among_dead_ones": (8, 40, [1, 1, 300, 1, 1]),
-    "pages_of_16": (16, 20, [127, 128, 129, 320, 1, 16, 17]),  # P = 8
+    # name: (rows, pages a block P, page size, table slots M, the lanes' lengths)
+    # k*P*ps - 1, k*P*ps, k*P*ps + 1 for k = 1, 2; M is no multiple of P
+    "around_block_boundaries": (_WIDE_ROWS, 16, 8, 40, [127, 128, 129, 255, 256, 257]),
+    "one_token_and_the_full_width": (_WIDE_ROWS, 16, 8, 40, [1, 320, 1, 319, 313]),
+    "table_narrower_than_a_block": (_WIDE_ROWS, 16, 8, 5, [1, 40, 17, 33]),  # M < P
+    "one_long_lane_among_dead_ones": (_WIDE_ROWS, 16, 8, 40, [1, 1, 300, 1, 1]),
+    "pages_of_16": (_WIDE_ROWS, 8, 16, 20, [127, 128, 129, 320, 1, 16, 17]),
+    "long_block_boundaries": (_NARROW_ROWS, 64, 8, 136, [511, 512, 513, 1023, 1025]),
+    "long_block_one_token_and_the_full_width": (_NARROW_ROWS[:1], 64, 8, 136, [1, 1088, 1, 1087, 1081]),
+    "long_block_table_narrower": (_NARROW_ROWS[:1], 64, 8, 40, [1, 320, 129, 257]),  # M < P
+    "long_block_one_long_lane_among_dead_ones": (_NARROW_ROWS[:1], 64, 8, 136, [1, 1, 1000, 1, 1]),
+    "long_block_pages_of_16": (_NARROW_ROWS, 32, 16, 72, [511, 512, 513, 1025, 1, 16, 17]),
 }
 
 
-@pytest.mark.parametrize("H,D", _DENSE_GEOMETRIES)
-@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+@pytest.mark.parametrize(
+    "case,H,D",
+    [(case, H, D) for case in sorted(_WALK_CASES) for H, D in _WALK_CASES[case][0]],
+)
 def test_kernel_walks_only_the_live_pages(case, H, D):
     """Lengths on and around the block's boundaries, tables that are no
-    multiple of a block or narrower than one, dead lanes beside a long one:
-    the kernel against the gather reference, and against itself on a table
-    whose slots past each lane's length hold other pages' ids where the
-    first holds the null page.  Neither may show in the result."""
-    ps, M, lengths = _WALK_CASES[case]
+    multiple of a block or narrower than one, dead lanes beside a long one,
+    at a block of 128 tokens and at one of 512: the kernel against the
+    gather reference, and against itself on a table whose slots past each
+    lane's length hold other pages' ids where the first holds the null
+    page.  Neither may show in the result.  The long blocks run in the TPU
+    interpreter with every scratch buffer NaN: a position of a block's
+    tail that the kernel neither fetched nor zeroed would show."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    _, P, ps, M, lengths = _WALK_CASES[case]
+    assert pages_per_block(ps, H * D, 4) == P
     B = len(lengths)
     N = B * M + 1
     rng = np.random.default_rng(26)
@@ -647,33 +683,79 @@ def test_kernel_walks_only_the_live_pages(case, H, D):
     junk = rng.permutation(np.arange(1, N)).reshape(B, M)
     live = np.arange(M)[None, :] * ps < np.asarray(lengths)[:, None]
     tables = [jnp.asarray(np.where(live, junk, fill), jnp.int32) for fill in (0, junk)]
-    ref = paged_attention_reference(q, kp, vp, tables[0], ln)
-    ker = [paged_decode_attention(q, kp, vp, t, ln, interpret=True) for t in tables]
-    np.testing.assert_allclose(np.asarray(ker[0]), np.asarray(ref), atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(ker[1]), np.asarray(ker[0]))
+    # the reference first and to its end: the interpreter computes in host
+    # callbacks, which deadlock against work dispatched beside them
+    ref = jax.block_until_ready(paged_attention_reference(q, kp, vp, tables[0], ln))
+    interpret = pltpu.InterpretParams() if P * ps > 128 else True
+    ker = [
+        np.asarray(paged_decode_attention(q, kp, vp, t, ln, interpret=interpret)) for t in tables
+    ]
+    assert np.all(np.isfinite(ker[0]))
+    np.testing.assert_allclose(ker[0], np.asarray(ref), atol=1e-5)
+    np.testing.assert_array_equal(ker[1], ker[0])
 
 
 @pytest.mark.parametrize(
     "ps,width,itemsize,pages",
     [
-        (8, 16 * 64, 4, 16),  # gpt2m_group_rollout: float32 pools, 512 KB of K+V a block
-        (8, 16 * 128, 4, 16),  # olmoe_group_rollout: 1 MB a block
-        (16, 4 * 128, 4, 8),
-        (8, 8 * 32, 2, 16),  # the chip_smoke shape on bfloat16 pools
-        (4, 2 * 8, 4, 32),  # this file's tiny pools: more pages than a table has
+        (8, 16 * 64, 4, 16),  # gpt2m_group_rollout: 4 KiB a token, 512 KiB a block of a pool
+        (8, 16 * 128, 4, 16),  # olmoe_group_rollout: 8 KiB; 64 tokens would fill it, held at 128
+        (8, 640, 4, 16),  # longcat / xing4: the latent row, 2.5 KiB; 204 tokens, whole steps of 128
+        (8, 2 * 256, 4, 32),  # qwen3next_group_rollout: 2 KiB, 256 tokens
+        (8, 2 * 128, 4, 64),  # zaya / nemotron_group_rollout: 1 KiB, 512 tokens
+        (16, 4 * 128, 4, 16),
+        (8, 8 * 32, 2, 64),  # the chip_smoke shape on bfloat16 pools: no block longer than 512 tokens
+        (4, 2 * 8, 4, 128),  # this file's tiny pools: more pages than a table has
         (8, 128 * 128, 4, 4),  # a row so wide that the budget cuts the block
         (256, 1024, 4, 1),  # never under one page
     ],
 )
 def test_pages_per_block_from_shapes(ps, width, itemsize, pages):
-    """The block is a function of the pool's shape alone: 128 tokens where
-    the K and V blocks, two buffers each, fit the VMEM budget."""
+    """The block is a function of the pool row's bytes alone: the whole
+    steps of 128 tokens that fill 512 KiB of a pool, at least one and at
+    most four, where the K and V blocks, two buffers each, fit the VMEM
+    budget."""
     P = pages_per_block(ps, width, itemsize)
     assert P == pages
     scratch = 4 * P * ps * width * itemsize
     assert scratch <= _VMEM_BUDGET or P == 1
     if scratch < _VMEM_BUDGET // 2:
-        assert P * ps >= 64
+        assert P * ps >= 128 and P * ps % 128 == 0
+        assert P * ps == 128 or P * ps * width * itemsize <= 512 * 2**10
+
+
+@pytest.mark.parametrize(
+    "ps,width,pool_pages,pages",
+    [
+        (8, 16 * 64, 513, 16),  # a block of 16 pages is one copy
+        (8, 2 * 128, 1025, 16),  # zaya, nemotron: a block of 64 pages holds four
+        (8, 2 * 256, 1025, 16),  # qwen3next: two
+        (8, 2 * 128, 10, 4),  # never more than the pool a copy reads from
+        (256, 1024, 2049, 1),
+    ],
+)
+def test_the_allocators_stretch_is_one_copy_not_one_block(ps, width, pool_pages, pages):
+    """A fresh run needs a free stretch of the walk's largest COPY (ISSUE
+    53): where a narrow pool's block grows to 32 or 64 pages, the engine
+    hands its allocator the 16 pages it handed it before."""
+    assert largest_copy(ps, width, 4, pool_pages) == pages
+    if (width, pool_pages) != (2 * 128, 1025):
+        return  # the rule alone; the engine over the one pool whose block is four copies
+    from scalerl_tpu.genrl.continuous import ContinuousConfig, ContinuousEngine
+
+    m = TransformerPolicy(
+        num_actions=11, vocab_size=11, d_model=width, num_heads=2, num_layers=1, max_len=16,
+    )
+    params = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
+    eng = ContinuousEngine(
+        m, params,
+        ContinuousConfig(
+            vocab_size=11, max_prompt_len=8, max_new_tokens=8, lanes=2, page_size=ps,
+            num_pages=pool_pages,
+        ),
+    )
+    assert eng.allocator.stretch == pages
+    assert eng.stats()["block_tokens"] == pages_per_block(ps, width, 4) * ps
 
 
 def test_resolve_paged_attn(monkeypatch):

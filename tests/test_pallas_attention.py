@@ -515,7 +515,8 @@ def test_resolve_segment_attn(monkeypatch):
 # the paged decode kernels fetch a RUN of adjacent pool pages in one copy
 # (ISSUE 50).  Adjacency is read off the table, so every table is a case.
 
-_RUN_PS, _RUN_SLOTS, _RUN_POOL = 8, 40, 200  # a block is 16 pages; the pool's last page is 199
+# a block is 16 pages of a wide pool and 64 of a narrow one; the pool's last page is 199
+_RUN_PS, _RUN_SLOTS, _RUN_POOL = 8, 40, 200
 
 
 def _run_tables():
@@ -524,9 +525,14 @@ def _run_tables():
     M, N, ps = _RUN_SLOTS, _RUN_POOL, _RUN_PS
     scattered = rng.permutation(np.arange(1, N))
 
-    def row(*pieces):
+    def row(*pieces, slots=M):
         flat = np.concatenate([np.atleast_1d(p) for p in pieces])
-        return np.concatenate([flat, np.zeros(M - len(flat), np.int64)])[:M]
+        return np.concatenate([flat, np.zeros(slots - len(flat), np.int64)])[:slots]
+
+    # a block of a narrow pool is 64 pages, 512 tokens (ISSUE 53): a lane of
+    # one token, one that ends inside the block's first page, one of exactly
+    # a block, one of a block and a page; a table of 72 slots is two blocks
+    two, block_lengths = 72, [1, ps - 3, 64 * ps, 65 * ps]
 
     shared = np.arange(60, 80)  # a prompt's twenty pages: the break falls inside block 1
     return {
@@ -551,6 +557,14 @@ def _run_tables():
             [np.arange(1, 1 + M), row(0), np.arange(150, 150 + M), row(0), row(np.arange(50, 70))],
             [200, 1, 320, 1, 160],
         ),
+        "block_lengths_on_runs": (
+            [row(1, slots=two), row(2, slots=two), row(np.arange(3, 67), slots=two), np.arange(100, 100 + two)],
+            block_lengths,
+        ),
+        # pages two apart, going down (lanes may read the same pages)
+        "block_lengths_on_scattered_pages": (
+            [np.arange(N - 1 - k, 0, -2)[:two] for k in range(4)], block_lengths,
+        ),
     }
 
 
@@ -571,14 +585,18 @@ def _run_case(kernel, table, lengths):
     t, ln = jnp.asarray(np.stack(table), jnp.int32), jnp.asarray(lengths, jnp.int32)
     interpret = pltpu.InterpretParams()
     if kernel == "latent":
-        H, W, VW = 4, 48, 32
+        H, W, VW = 4, 576, 512  # the published row: 2.5 KiB, a block of 128 tokens
+        assert ppa.pages_per_block(ps, ppa.latent_pool_width(W), 4) == 16
         q = jnp.asarray(rng.normal(size=(B, 1, H, W)), jnp.float32)
         pool = jnp.asarray(rng.normal(size=(N, ps, ppa.latent_pool_width(W))), jnp.float32)
         # the reference first and to its end: the interpreter computes in
         # host callbacks, which deadlock against work dispatched beside them
         ref = jax.block_until_ready(ppa.paged_latent_attention_reference(q, pool, t, ln, VW, 0.2))
         return ppa.paged_decode_latent(q, pool, t, ln, VW, 0.2, interpret=interpret), ref
-    H, KV, D = (4, 2, 8) if kernel == "grouped" else (2, 2, 8)
+    # rows of 2.5 KiB, whose block is 128 tokens as gpt2-medium's is;
+    # ``narrow``: two key/value heads of 128 float32, 1 KiB, whose block is 512
+    H, KV, D = {"decode": (5, 5, 128), "grouped": (10, 5, 128), "narrow": (8, 2, 128)}[kernel]
+    assert ppa.pages_per_block(ps, KV * D, 4) == (64 if kernel == "narrow" else 16)
     q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
     kp = jnp.asarray(rng.normal(size=(N, ps, KV * D)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(N, ps, KV * D)), jnp.float32)
@@ -586,34 +604,54 @@ def _run_case(kernel, table, lengths):
     return ppa.paged_decode_attention(q, kp, vp, t, ln, interpret=interpret), ref
 
 
-@pytest.mark.parametrize("kernel", ["decode", "grouped", "latent"])
-@pytest.mark.parametrize("case", sorted(_RUN_TABLES))
+# the tables that say something new at a block of 64 pages: the two made for
+# it, and those whose runs its longer stretch of slots cuts elsewhere
+_NARROW_TABLES = {
+    "block_lengths_on_runs", "block_lengths_on_scattered_pages", "one_run",
+    "shared_run_then_own_run", "run_ends_on_the_pools_last_page", "dead_lane_between_live_ones",
+}
+
+
+@pytest.mark.parametrize(
+    "case,kernel",
+    [
+        (case, kernel)
+        for case in sorted(_RUN_TABLES)
+        for kernel in ("decode", "grouped", "latent", "narrow")
+        if kernel != "narrow" or case in _NARROW_TABLES
+    ],
+)
 def test_paged_kernels_fetch_runs_of_adjacent_pages(case, kernel):
-    """Both decode kernels, and grouped heads, against their gather
+    """Both decode kernels, and grouped heads on a wide pool and on a
+    narrow one (whose block is four times as long), against their gather
     references at 1e-5 on tables that are one run, no run at all, a shared
     run then an own one, a run across a block's end, a part-filled last
-    page, a run that ends on the pool's last page and dead lanes between
-    live ones: whatever the table, the same result."""
+    page, a run that ends on the pool's last page, dead lanes between
+    live ones and lanes that end at a block's marks: whatever the table,
+    the same result."""
     out, ref = _run_case(kernel, *_RUN_TABLES[case])
     out = np.asarray(out)
     assert np.all(np.isfinite(out))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
+@pytest.mark.parametrize("width,block", [(16 * 64, 16), (2 * 128, 64)])
 @pytest.mark.parametrize("case", sorted(_RUN_TABLES))
-def test_host_counts_the_copies_the_kernels_rule_issues(case):
+def test_host_counts_the_copies_the_kernels_rule_issues(case, width, block):
     """The list the kernels walk (``_copy_list``) read back as they read it
     (``_each_copy``: a block's entries, a size at a time up to that size's
     end): every copy fetches pages the table names at the slots it fills,
     every live slot is filled once and no other, and ``table_copies`` (the
     engine's ``pages_per_copy``) is that list's length reckoned on the
-    host; no table costs more copies than it has live pages."""
+    host; no table costs more copies than it has live pages.  At a wide
+    pool's block of 16 pages and a narrow pool's of 64."""
     from scalerl_tpu.ops import pallas_paged_attention as ppa
 
     table, lengths = _RUN_TABLES[case]
     table = np.stack(table).astype(np.int32)
-    ps, width = _RUN_PS, 16
+    ps = _RUN_PS
     P = ppa.pages_per_block(ps, width, 4)
+    assert P == block
     live = np.clip(-(-np.asarray(lengths) // ps), 1, table.shape[1])
     sizes = ppa._run_sizes(P, _RUN_POOL)
     listed, ends = map(

@@ -25,7 +25,10 @@ the tree of the PR that added it, ISSUE 46: the next PR's parent) with this
 environment's JAX, and have passed unchanged on every commit since; both
 ``paged_decode.*`` again on the tree of ISSUE 50, which meant to change the
 kernel (a run of adjacent pages in one copy, the call under its own
-``jax.jit``) and changed no family's program; xing4 on the tree of the PR
+``jax.jit``) and changed no family's program, and once more on the tree of
+ISSUE 53, which sized a block of the walk by its bytes (these tiny pools'
+rows are 64 and 128 bytes: their block went from 128 tokens to 512) and
+changed no family's program either; xing4 on the tree of the PR
 that added it, ISSUE 51, which changed no other family's.  After
 a JAX upgrade, take them again from a commit known to be unchanged.
 """
@@ -76,8 +79,8 @@ PARENT = {
     "xing4.decode": "a28da17b6ab9895d",
     "xing4.tail_prefill": "9147d7beb59a21d4",
     "xing4.values": "c8159e7ccb0cb400",
-    "paged_decode.kernel": "57daef2ccfd90b3d",
-    "paged_decode.grouped_kernel": "817adece2559f7ff",
+    "paged_decode.kernel": "de191285954da127",
+    "paged_decode.grouped_kernel": "fd3482696fe7ed0d",
 }
 # [q heads, kv heads x head size] of the kernel-alone cases
 _KERNEL = {"kernel": (4, 32), "grouped_kernel": (8, 16)}
