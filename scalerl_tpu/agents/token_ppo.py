@@ -365,6 +365,13 @@ def token_ppo_packed_loss(
     return total, metrics
 
 
+# the learn step's phases outside the model, as a device trace's ``op_name``
+# shows them (``benchmark/op_scopes.py``, PERF.md section 3)
+_SCOPE_LOSS = "loss"
+_SCOPE_UPDATE = "update"
+_SCOPE_GUARD = "guard"
+
+
 @functools.lru_cache(maxsize=None)
 def _note_guard(leaves: int, state_bytes: int) -> None:
     """The guard engages on every step, so it has no hit rate: one
@@ -468,44 +475,47 @@ def make_token_ppo_learn_fn(
                 token_ppo_packed_loss,
                 mtp_coef=getattr(args, "mtp_loss_coef", 0.0),
             )
-        (loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(
-            state.params,
-            state.ref_params,
-            model,
-            batch,
-            clip_range=args.clip_range,
-            value_cost=args.value_cost,
-            entropy_cost=args.entropy_cost,
-            kl_cost=args.kl_cost,
-            adv_norm=args.adv_norm,
-            router_aux_coef=getattr(args, "router_aux_loss_coef", 0.0),
-        )
+        with jax.named_scope(_SCOPE_LOSS):
+            (loss, metrics), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(
+                state.params,
+                state.ref_params,
+                model,
+                batch,
+                clip_range=args.clip_range,
+                value_cost=args.value_cost,
+                entropy_cost=args.entropy_cost,
+                kl_cost=args.kl_cost,
+                adv_norm=args.adv_norm,
+                router_aux_coef=getattr(args, "router_aux_loss_coef", 0.0),
+            )
         if shard_update is not None:
             grads = shard_update.scatter(grads)
         metrics["grad_norm"] = optax.global_norm(
             jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
         )
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        new = dict(
-            params=optax.apply_updates(state.params, updates),
-            opt_state=opt_state,
-            step=state.step + 1,
-            tokens_seen=state.tokens_seen
-            + jnp.sum(batch["mask"]).astype(state.tokens_seen.dtype),
-        )
-        if guarded:
-            ok = tree_all_finite((loss, metrics["grad_norm"]))
-            old = {name: getattr(state, name) for name in new}
-            new = jax.tree_util.tree_map(
-                lambda n, o: jnp.where(ok, n, o), new, old
+        with jax.named_scope(_SCOPE_UPDATE):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params
             )
-            leaves = jax.tree_util.tree_leaves(old)
-            _note_guard(len(leaves), sum(x.size * x.dtype.itemsize for x in leaves))
-            bad = 1.0 - ok.astype(jnp.float32)
+            new = dict(
+                params=optax.apply_updates(state.params, updates),
+                opt_state=opt_state,
+                step=state.step + 1,
+                tokens_seen=state.tokens_seen
+                + jnp.sum(batch["mask"]).astype(state.tokens_seen.dtype),
+            )
+        if guarded:
+            with jax.named_scope(_SCOPE_GUARD):
+                ok = tree_all_finite((loss, metrics["grad_norm"]))
+                old = {name: getattr(state, name) for name in new}
+                new = jax.tree_util.tree_map(
+                    lambda n, o: jnp.where(ok, n, o), new, old
+                )
+                leaves = jax.tree_util.tree_leaves(old)
+                _note_guard(len(leaves), sum(x.size * x.dtype.itemsize for x in leaves))
+                bad = 1.0 - ok.astype(jnp.float32)
             metrics["nonfinite_grads"] = bad
             metrics["skipped_steps"] = bad
         if shard_update is not None:

@@ -143,6 +143,10 @@ from scalerl_tpu.serving.batcher import (
 from scalerl_tpu.utils import profiling  # noqa: F401  (installs the spans' profiler half)
 from scalerl_tpu.utils.buckets import bucket_for, default_buckets
 
+# the decode substep's phase that no module names, as a device trace's
+# ``op_name`` shows it (``benchmark/op_scopes.py``, PERF.md section 3)
+_SCOPE_SAMPLE = "sample"
+
 # module seams: tests monkeypatch these to count host transfers and assert
 # the one-upload-one-read-per-macro-step invariant
 _device_put = jax.device_put
@@ -1025,7 +1029,7 @@ class ContinuousEngine(ParamSnapshotPlane):
         scattered exactly like the local prefill."""
         model = self.model
 
-        def prefill(
+        def prefix_prefill(
             params, pools, logits_st, value_st, cl, done, resp,
             tokens, tail_lengths, lane_ids, page_ids, page_offsets,
             table, starts,
@@ -1057,7 +1061,7 @@ class ContinuousEngine(ParamSnapshotPlane):
             resp = resp.at[lane_ids].set(0, mode="drop")
             return pools, logits_st, value_st, cl, done, resp
 
-        return jax.jit(prefill, donate_argnums=(1, 2, 3, 4, 5, 6))
+        return jax.jit(prefix_prefill, donate_argnums=(1, 2, 3, 4, 5, 6))
 
     def _build_fork(self, F: int) -> Callable:
         """The CoW fork program at admit bucket ``F``: batched pool-page
@@ -1099,14 +1103,15 @@ class ContinuousEngine(ParamSnapshotPlane):
 
         def substep(params, table, carry, _t):
             pools, logits, value, cl, done, resp, key = carry
-            key, sub = jax.random.split(key)
-            adj = adjust_logits(
-                logits, cfg.temperature, cfg.top_k, cfg.vocab_size
-            )
-            token = sample_tokens(sub, adj, cfg.temperature)
-            logp = jnp.take_along_axis(
-                jax.nn.log_softmax(adj, axis=-1), token[:, None], axis=-1
-            )[:, 0]
+            with jax.named_scope(_SCOPE_SAMPLE):
+                key, sub = jax.random.split(key)
+                adj = adjust_logits(
+                    logits, cfg.temperature, cfg.top_k, cfg.vocab_size
+                )
+                token = sample_tokens(sub, adj, cfg.temperature)
+                logp = jnp.take_along_axis(
+                    jax.nn.log_softmax(adj, axis=-1), token[:, None], axis=-1
+                )[:, 0]
             alive = jnp.logical_not(done)
             resp2 = resp + alive.astype(jnp.int32)
             finished = resp2 >= budget

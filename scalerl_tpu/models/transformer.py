@@ -1100,6 +1100,12 @@ def rotary_fn(
     return rotate
 
 
+# what a layer's attention does around its projections, as a device trace's
+# ``op_name`` shows it (``benchmark/op_scopes.py``, PERF.md section 3)
+_SCOPE_KV_WRITE = "kv_write"
+_SCOPE_ATTEND = "attend"
+
+
 def _write_pages(call: Call, pools, rows):
     """This call's ``rows`` (one ``[B, T, ...]`` array a pool) into the
     pools' pages: a flat single-axis scatter (page id x page size +
@@ -1107,14 +1113,15 @@ def _write_pages(call: Call, pools, rows):
     XLA:CPU lowers 1-level row scatters measurably faster than the
     2-level fancy-index form."""
     N, ps, _ = pools[0].shape
-    flat_idx = (call.page_ids * ps + call.page_offsets).reshape(-1)
-    return tuple(
-        pool.reshape(N * ps, -1)
-        .at[flat_idx]
-        .set(new.astype(pool.dtype).reshape(-1, pool.shape[2]))
-        .reshape(pool.shape)
-        for pool, new in zip(pools, rows)
-    )
+    with jax.named_scope(_SCOPE_KV_WRITE):
+        flat_idx = (call.page_ids * ps + call.page_offsets).reshape(-1)
+        return tuple(
+            pool.reshape(N * ps, -1)
+            .at[flat_idx]
+            .set(new.astype(pool.dtype).reshape(-1, pool.shape[2]))
+            .reshape(pool.shape)
+            for pool, new in zip(pools, rows)
+        )
 
 
 def _tail_mask(call: Call, T: int, context: int) -> jnp.ndarray:
@@ -1166,25 +1173,26 @@ def _attend(mod, q, k, v, call: Call, cache: Optional[ModelCache]):
     if call.paged:
         kp, vp = _write_pages(call, (cache.k[0], cache.v[0]), (k, v))
         cache = ModelCache(k=(kp,), v=(vp,))
-    if call.mode == "tail":
-        # the heads are split out of the gathered rows: reshaping the
-        # pool itself would bring its relayout copy back
-        kg = _repeat_kv(gather_pages(kp, call.page_table, KV), H)
-        vg = _repeat_kv(gather_pages(vp, call.page_table, KV), H)
-        out = _masked_attention(q, kg, vg, _tail_mask(call, T, kg.shape[1]), mod.dtype)
-    elif call.mode == "decode":
-        paged_attn = mod.paged_attn_fn or paged_attention_reference
-        out = paged_attn(q, kp, vp, call.page_table, call.attn_lengths)
-        out = out.astype(mod.dtype)
-    elif call.mode == "packed":
-        out = mod.segment_attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H), call.segment_ids)
-        out = out.astype(mod.dtype)
-    elif call.mode == "causal":
-        out = mod.attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H))
-    else:  # masked, prefill: the call's own keys under its mask
-        out = _masked_attention(
-            q, _repeat_kv(k, H), _repeat_kv(v, H), call.attn_mask, mod.dtype
-        )
+    with jax.named_scope(_SCOPE_ATTEND):
+        if call.mode == "tail":
+            # the heads are split out of the gathered rows: reshaping the
+            # pool itself would bring its relayout copy back
+            kg = _repeat_kv(gather_pages(kp, call.page_table, KV), H)
+            vg = _repeat_kv(gather_pages(vp, call.page_table, KV), H)
+            out = _masked_attention(q, kg, vg, _tail_mask(call, T, kg.shape[1]), mod.dtype)
+        elif call.mode == "decode":
+            paged_attn = mod.paged_attn_fn or paged_attention_reference
+            out = paged_attn(q, kp, vp, call.page_table, call.attn_lengths)
+            out = out.astype(mod.dtype)
+        elif call.mode == "packed":
+            out = mod.segment_attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H), call.segment_ids)
+            out = out.astype(mod.dtype)
+        elif call.mode == "causal":
+            out = mod.attn_fn(q, _repeat_kv(k, H), _repeat_kv(v, H))
+        else:  # masked, prefill: the call's own keys under its mask
+            out = _masked_attention(
+                q, _repeat_kv(k, H), _repeat_kv(v, H), call.attn_mask, mod.dtype
+            )
     return out, cache
 
 

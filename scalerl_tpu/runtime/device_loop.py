@@ -75,6 +75,14 @@ class ActorCarry(NamedTuple):
     episode_count: jnp.ndarray  # [B] per-env completed-episode count
 
 
+# the fused iteration's phases, as a device trace's ``op_name`` shows them
+# (``benchmark/op_scopes.py``, PERF.md section 3)
+_SCOPE_ACT = "act"
+_SCOPE_ENV_STEP = "env_step"
+_SCOPE_STORE = "store"
+_SCOPE_LEARN = "learn"
+
+
 def _store_obs(obs: jnp.ndarray) -> jnp.ndarray:
     """``[B, *obs_shape]`` as the env hands it out -> ``[*obs_shape, B]``."""
     return jnp.moveaxis(obs, 0, -1)
@@ -355,23 +363,26 @@ class DeviceActorLearnerLoop:
         _note_traj_storage(buf_shape, dtype, t_axis)
 
         def write_row(buf, obs, t):
-            return _pin_row_major(
-                jax.lax.dynamic_update_index_in_dim(buf, obs, t, axis=t_axis)
-            )
+            with jax.named_scope(_SCOPE_STORE):
+                return _pin_row_major(
+                    jax.lax.dynamic_update_index_in_dim(buf, obs, t, axis=t_axis)
+                )
 
         def step(cb, kt):
             c, buf = cb
             k, t = kt
-            out, new_core = self.model.apply(
-                params, _load_obs(c.obs)[None], c.last_action[None],
-                c.reward[None], c.done[None], c.core_state,
-            )
-            logits = out.policy_logits[0]
-            k_act, k_env = jax.random.split(k)
-            action = jax.random.categorical(k_act, logits, axis=-1)
-            env_state, next_obs, reward, done = self.venv.step(
-                c.env_state, action, k_env
-            )
+            with jax.named_scope(_SCOPE_ACT):
+                out, new_core = self.model.apply(
+                    params, _load_obs(c.obs)[None], c.last_action[None],
+                    c.reward[None], c.done[None], c.core_state,
+                )
+                logits = out.policy_logits[0]
+                k_act, k_env = jax.random.split(k)
+                action = jax.random.categorical(k_act, logits, axis=-1)
+            with jax.named_scope(_SCOPE_ENV_STEP):
+                env_state, next_obs, reward, done = self.venv.step(
+                    c.env_state, action, k_env
+                )
             row = (c.last_action, c.reward, c.done, logits)
             ep_ret = c.episode_return + reward
             new_c = ActorCarry(
@@ -418,7 +429,8 @@ class DeviceActorLearnerLoop:
             state, carry = sc
             k_roll, _ = jax.random.split(k)
             carry, traj = self._unroll(state.params, carry, k_roll)
-            state, metrics = self.learn_fn(state, traj)
+            with jax.named_scope(_SCOPE_LEARN):
+                state, metrics = self.learn_fn(state, traj)
             return (state, carry), metrics
 
         keys = jax.random.split(key, self.iters_per_call)
